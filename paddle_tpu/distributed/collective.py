@@ -36,46 +36,24 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core.errors import InvalidArgumentError
 from ..framework.tensor import Tensor
 
-try:  # jax>=0.8
-    from jax import shard_map as _raw_shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _raw_shard_map  # type: ignore
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
-    """Version-compat shard_map with replication checking off (collectives
+    """``jax.shard_map`` with replication checking off (collectives
     intentionally change replication across the mapped axis).
 
     ``axis_names`` requests PARTIAL-manual mode: only those axes are
-    manual inside the body, the rest stay GSPMD-managed (jax>=0.8
-    spells this ``axis_names=``; older jax spells it ``auto=`` with the
-    complement set)."""
-    variants = [{"check_vma": False}, {"check_rep": False}]
-    if axis_names is not None:
-        manual = frozenset(axis_names)
-        auto = frozenset(mesh.axis_names) - manual
-        variants = [{"check_vma": False, "axis_names": manual},
-                    {"check_rep": False, "auto": auto}]
-    err = None
-    for kw in variants:
-        try:
-            return _raw_shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-        except TypeError as e:  # pragma: no cover - version-dependent kwarg
-            err = e
-    raise err
+    manual inside the body, the rest stay GSPMD-managed."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+        axis_names=frozenset() if axis_names is None
+        else frozenset(axis_names))
 
 
-def axis_size(axis_name: str):
-    """Version-compat ``lax.axis_size``: the (static) size of a bound
-    mapped axis.  Newer jax has ``lax.axis_size``; older releases spell
-    it ``lax.psum(1, axis_name)``, which constant-folds to a python int
-    for a literal operand.  Raises the axis-binding error either way
-    when the name is unbound (``_axis_bound`` relies on that)."""
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
+# the (static) size of a bound mapped axis; raises the axis-binding
+# error when the name is unbound (``_axis_bound`` relies on that)
+axis_size = lax.axis_size
 
 __all__ = [
     "axis_size", "shard_map",
@@ -175,23 +153,14 @@ def _bootstrap_multihost() -> None:
     nranks = int(os.environ.get("PADDLE_TRAINERS_NUM", "1") or "1")
     if nranks <= 1:
         return
-    try:
-        if jax._src.distributed.global_state.client is not None:
-            return  # already rendezvoused (runtime or a prior call)
-    except AttributeError:  # private API moved: fall through and attempt
-        pass
+    if jax.distributed.is_initialized():
+        return  # already rendezvoused (runtime or a prior call)
     rank = int(os.environ["PADDLE_TRAINER_ID"])
     coordinator = os.environ.get("PADDLE_MASTER") or \
         os.environ["PADDLE_TRAINER_ENDPOINTS"].split(",")[0]
-    # accelerator plugins pre-register and ignore the JAX_PLATFORMS env var;
-    # honor it explicitly so CPU gangs really run on cpu (bench.py does same)
     if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
         # cross-process CPU collectives need the gloo implementation
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator, num_processes=nranks, process_id=rank)
 

@@ -227,13 +227,11 @@ class CompressedAllReduceStep:
                 jax.tree.map(lambda l: P(axis, *((None,) * (l.ndim - 1))),
                              uv, is_leaf=lambda x: isinstance(x, jax.Array)),
             )
-            # version-compat wrapper (check_vma on jax>=0.8, check_rep
-            # on older) — same helper the collectives use
-            from .collective import shard_map as _compat_shard_map
+            # replication checking off — same helper the collectives use
+            from .collective import shard_map
 
-            fn = _compat_shard_map(per_device, mesh=self.mesh,
-                                   in_specs=in_specs,
-                                   out_specs=out_specs)
+            fn = shard_map(per_device, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs)
             return fn(param_vals, opt_states, buf_vals, uv, batch_leaves,
                       key, lr, compress_now)
 

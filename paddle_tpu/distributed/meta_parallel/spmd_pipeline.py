@@ -551,17 +551,15 @@ class PipelineTrainStep:
             # every other axis (mp, ...) remains GSPMD-managed inside the
             # region so stage math gets its TP collectives from the
             # parameter shardings — the pp×mp hybrid
-            # version-compat wrapper (axis_names= on jax>=0.8, auto=
-            # complement on older) — same helper the collectives use
-            from ..collective import shard_map as _compat_shard_map
+            from ..collective import shard_map
 
-            sharded_core = _compat_shard_map(
+            sharded_core = shard_map(
                 pipe_core, mesh=mesh, in_specs=in_specs, out_specs=P(),
                 axis_names=frozenset(manual))
         else:
-            from ..collective import shard_map as _compat_shard_map
+            from ..collective import shard_map
 
-            sharded_core = _compat_shard_map(
+            sharded_core = shard_map(
                 pipe_core, mesh=mesh, in_specs=in_specs, out_specs=P())
 
         n_outer = len(self._outer_params)

@@ -23,6 +23,7 @@ from typing import Any, Callable, Set
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src.core import trace_state_clean as _trace_state_clean
 
 from ..core import amp_state
 from . import engine
@@ -94,12 +95,6 @@ def _wrap_outputs(out, node=None):
 
 def _is_traced(v) -> bool:
     return isinstance(v, jax.core.Tracer)
-
-
-try:  # jax 0.9: not re-exported under jax.core
-    from jax._src.core import trace_state_clean as _trace_state_clean
-except ImportError:  # pragma: no cover - jax version drift
-    _trace_state_clean = getattr(jax.core, "trace_state_clean", lambda: True)
 
 
 def _trace_clean() -> bool:

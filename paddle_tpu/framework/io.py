@@ -72,10 +72,7 @@ class _ArrayRef:
 
 
 def _is_fully_addressable(v: jax.Array) -> bool:
-    try:
-        return v.is_fully_addressable
-    except AttributeError:  # pragma: no cover
-        return True
+    return v.is_fully_addressable
 
 
 def _hoist(obj, arrays: Dict[str, np.ndarray],

@@ -36,8 +36,8 @@ def _softmax_mask_fuse_upper_triangle(x):
             % (lq, lk))
     keep = jnp.tril(jnp.ones((lq, lk), bool), k=lk - lq)
     # where= keeps masked lanes out of the reduction and zeroes them in
-    # the output (this jax version has no `initial` kwarg; with Lk >= Lq
-    # every row has at least one kept key, so the max is well-defined)
+    # the output (with Lk >= Lq every row has at least one kept key, so
+    # the max is well-defined)
     return jax.nn.softmax(x, axis=-1, where=keep).astype(x.dtype)
 
 

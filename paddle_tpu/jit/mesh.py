@@ -15,7 +15,7 @@ the dp axis.  Sharding attention heads and the MLP hidden dimension
 over an ``mp`` axis splits the weights and the cache's head axis the
 way ``meta_parallel/mp_layers.py`` splits the training matmuls: XLA's
 SPMD partitioner inserts exactly the all-reduces the hand-written
-tensor-parallel layers would (the GSPMD design, SNIPPETS.md [1]–[3]).
+tensor-parallel layers would (the GSPMD design).
 Nothing about the traced step functions changes — :class:`DecodeMesh`
 only PLACES weights, cache, and per-step vectors with
 ``NamedSharding``/``PartitionSpec`` rules, and the compiler does the
@@ -23,7 +23,8 @@ rest.  The allocator side (per-dp-shard block partition, per-shard
 scratch blocks, logical→(shard, local-slot) slot mapping) lives in
 ``inference.GenerationPool``.
 
-Axis rules (the serving analog of SNIPPETS.md [3]'s DEFAULT_RULES):
+Axis rules (the serving analog of a GSPMD model's default sharding
+rules):
 
 ==========================  =======================  ==================
 array                        shape                    PartitionSpec

@@ -139,7 +139,8 @@ def gumbel_softmax(x, temperature: float = 1.0, hard: bool = False, axis: int = 
     if hard:
         idx = jnp.argmax(y, axis=axis, keepdims=True)
         hard_y = jnp.zeros_like(y)
-        hard_y = jnp.put_along_axis(hard_y, idx, 1.0, axis=axis) if hasattr(jnp, "put_along_axis") else hard_y.at[...].set(hard_y)
+        hard_y = jnp.put_along_axis(hard_y, idx, 1.0, axis=axis,
+                                    inplace=False)
         y = jax.lax.stop_gradient(hard_y - y) + y
     return y
 

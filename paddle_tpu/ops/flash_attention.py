@@ -201,11 +201,11 @@ DECODE_FLASH_MIN_CACHE = 16384
 #   backend, short chunk, MXU-tileable head_dim, cache past the
 #   crossover); everything else takes the XLA composition.
 # "composition": force the gather+dequant+attention composition.
-# "pallas": force the fused kernel wherever it structurally applies
-#   (Lq <= 8, float queries) — off-TPU it runs under the pallas
-#   INTERPRETER, which is how tier-1 tests pin numeric identity on CPU;
-#   shapes the kernel cannot take (the bucketed prefill's long chunk)
-#   silently keep the composition, so a forced session still prefills.
+# "pallas": force the fused kernel on every decode-sized chunk
+#   (Lq <= 8) — off-TPU it runs under the pallas INTERPRETER, which is
+#   how tier-1 tests pin numeric identity on CPU.  The bucketed
+#   prefill's long chunk keeps the composition, so a forced session
+#   still prefills.
 DECODE_ROUTES = ("auto", "composition", "pallas")
 
 # The ambient route is THREAD-LOCAL (the repo's convention for ambient
@@ -272,15 +272,25 @@ def reset_backend_memo() -> None:
     _backend_memo = None
 
 
-def _kernel_feasible(q_shape, dtype) -> bool:
-    """Structural floor for the fused kernel (what ``route='pallas'``
-    may force): 4-D queries, a decode/verify-sized chunk, float query
-    dtype.  The MXU/crossover conditions live in the ``*_supported``
-    gates — they decide WINNING, this decides EXISTING."""
-    from .pallas_decode import MAX_KERNEL_QUERY_CHUNK
+def _kernel_refusal(q_shape, dtype, tile, seq_len: int, has_bias: bool):
+    """Why the fused kernel cannot take this call, or None — what
+    ``route='pallas'`` may force.  Structure first (4-D float queries),
+    then, when the kernel would be COMPILED (TPU backend), Mosaic's
+    layout rules (``pallas_decode.mosaic_refusal``); the interpreter
+    has no layout to refuse.  The crossover conditions live in the
+    ``*_supported`` gates — they decide WINNING, this decides
+    EXISTING."""
+    from .pallas_decode import mosaic_refusal
 
-    return (len(q_shape) == 4 and q_shape[2] <= MAX_KERNEL_QUERY_CHUNK
-            and jnp.dtype(dtype) in _SUPPORTED_DTYPES)
+    if len(q_shape) != 4:
+        return "queries must be 4-D [B, H, Lq, D], got %r" % (
+            tuple(q_shape),)
+    if jnp.dtype(dtype) not in _SUPPORTED_DTYPES:
+        return "query dtype %s is not float32/bfloat16" % (
+            jnp.dtype(dtype).name,)
+    if _cached_backend() != "tpu" and tile is not None:
+        return None
+    return mosaic_refusal(q_shape[3], tile, seq_len, has_bias)
 
 
 def _bias_kernel_compatible(bias, b, h, lq, s) -> bool:
@@ -297,15 +307,26 @@ def _bias_kernel_compatible(bias, b, h, lq, s) -> bool:
     return bias_streamable(getattr(bias, "shape", ()), b, h, lq, s)
 
 
-def _resolve_route(route, supported: bool, feasible: bool) -> bool:
-    """True when this call takes the fused pallas kernel."""
+def _resolve_route(route, q_shape, supported: bool, refusal) -> bool:
+    """True when this call takes the fused pallas kernel.  ``refusal``
+    is ``_kernel_refusal``'s answer: under a forced route a refused
+    decode-sized chunk raises its reason — it never decodes on the
+    composition behind the caller's back."""
+    from .pallas_decode import MAX_KERNEL_QUERY_CHUNK
+
     r = _route_stack()[-1] if route is None \
         else normalize_decode_route(route)
     if r == "composition":
         return False
-    if r == "pallas":
-        return feasible
-    return supported
+    if r == "auto":
+        return supported
+    if len(q_shape) == 4 and q_shape[2] > MAX_KERNEL_QUERY_CHUNK:
+        return False  # prefill-shaped chunk: the composition by design
+    if refusal is not None:
+        raise InvalidArgumentError(
+            "route='pallas' cannot run this decode step on the fused "
+            "kernel: %s" % (refusal,))
+    return True
 
 
 def _qpos_bias(q_pos, s_len: int, dtype):
@@ -350,21 +371,25 @@ def _effective_qpos(q_pos, lengths, b: int, lq: int, s: int):
 def decode_attention_supported(q_shape, kv_len: int, dtype) -> bool:
     """Gate for the fused single-query/short-chunk pallas decode kernel
     (``ops.pallas_decode.decode_attention_kernel``): TPU backend, 4-D
-    [B, H, Lq, D] with a short query chunk, MXU-tileable head_dim and a
-    cache long enough to beat the fused XLA composition.  This is the
-    "auto" route's decision; ``route="pallas"``/``"composition"``
-    override it for tests and sweeps."""
-    from .pallas_decode import MAX_KERNEL_QUERY_CHUNK
+    [B, H, Lq, D] with a short query chunk, a cache long enough to beat
+    the fused XLA composition, and a geometry Mosaic compiles
+    (``mosaic_refusal``: head_dim, and a bounded sequence tile of whole
+    sublanes).  This is the "auto" route's decision;
+    ``route="pallas"``/``"composition"`` override it for tests and
+    sweeps."""
+    from .pallas_decode import (MAX_KERNEL_QUERY_CHUNK, dense_seq_block,
+                                mosaic_refusal)
 
     if _cached_backend() != "tpu":
         return False
     if len(q_shape) != 4 or q_shape[2] > MAX_KERNEL_QUERY_CHUNK:
         return False
-    if q_shape[3] not in (64, 128, 256):
-        return False
     if kv_len < DECODE_FLASH_MIN_CACHE:
         return False
-    return jnp.dtype(dtype) in _SUPPORTED_DTYPES
+    if jnp.dtype(dtype) not in _SUPPORTED_DTYPES:
+        return False
+    return mosaic_refusal(q_shape[3], dense_seq_block(kv_len),
+                          kv_len) is None
 
 
 def decode_attention(q, k, v, bias=None, sm_scale: Optional[float] = None,
@@ -403,12 +428,15 @@ def decode_attention(q, k, v, bias=None, sm_scale: Optional[float] = None,
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(d))
     s = k.shape[2]
+    from .pallas_decode import dense_seq_block
+
     if _resolve_route(
-            route,
+            route, q.shape,
             decode_attention_supported(q.shape, s, q.dtype)
             and _bias_kernel_compatible(bias, q.shape[0], q.shape[1],
                                         q.shape[2], s),
-            _kernel_feasible(q.shape, q.dtype)):
+            _kernel_refusal(q.shape, q.dtype, dense_seq_block(s), s,
+                            bias is not None)):
         # fused pallas route (docs/DESIGN.md §5l): stream cache tiles
         # through VMEM with an online softmax — int8 tiles dequantize
         # in VMEM, so the HBM read stays int8 and the gathered fp32
@@ -444,24 +472,24 @@ def paged_decode_attention_supported(q_shape, block_size: int,
                                      num_blocks: int, dtype) -> bool:
     """Gate for the fused pallas PAGED decode kernel
     (``ops.pallas_decode.paged_decode_attention_kernel``), mirroring
-    ``decode_attention_supported``: TPU backend, short query chunk,
-    MXU-tileable head_dim, sublane-aligned block_size, and a pool big
-    enough that the hand-tiled gather kernel beats the XLA
-    gather+composition.  The "auto" route's decision;
+    ``decode_attention_supported``: TPU backend, short query chunk, a
+    pool big enough that the hand-tiled gather kernel beats the XLA
+    gather+composition, and a geometry Mosaic compiles
+    (``mosaic_refusal``: head_dim, and a ``block_size`` of whole
+    sublanes).  The "auto" route's decision;
     ``route="pallas"``/``"composition"`` override it."""
-    from .pallas_decode import MAX_KERNEL_QUERY_CHUNK
+    from .pallas_decode import MAX_KERNEL_QUERY_CHUNK, mosaic_refusal
 
     if _cached_backend() != "tpu":
         return False
     if len(q_shape) != 4 or q_shape[2] > MAX_KERNEL_QUERY_CHUNK:
         return False
-    if q_shape[3] not in (64, 128, 256):
-        return False
-    if block_size < 8 or block_size % 8 != 0:
-        return False
     if block_size * num_blocks < DECODE_FLASH_MIN_CACHE:
         return False
-    return jnp.dtype(dtype) in _SUPPORTED_DTYPES
+    if jnp.dtype(dtype) not in _SUPPORTED_DTYPES:
+        return False
+    return mosaic_refusal(q_shape[3], block_size,
+                          block_size * num_blocks) is None
 
 
 def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
@@ -512,11 +540,11 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     if _resolve_route(
-            route,
+            route, q.shape,
             paged_decode_attention_supported(q.shape, bs, nb, q.dtype)
             and _bias_kernel_compatible(bias, b, q.shape[1], q.shape[2],
                                         s),
-            _kernel_feasible(q.shape, q.dtype)):
+            _kernel_refusal(q.shape, q.dtype, bs, s, bias is not None)):
         from .pallas_decode import paged_decode_attention_kernel
 
         qp = _effective_qpos(q_pos, lengths, b, q.shape[2], s)

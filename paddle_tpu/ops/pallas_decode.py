@@ -37,10 +37,30 @@ on CPU: numeric identity against the composition is pinned without a
 TPU (tests/test_pallas_decode.py), while the routing gates in
 ``flash_attention.py`` keep compiled-mode engagement TPU-only and
 measured-crossover honest.
+
+Compiled under Mosaic the body is held to the TPU's layout rules, and
+``mosaic_refusal`` names every geometry it cannot meet so the routing
+layer excludes it by reason instead of finding out from the compiler:
+
+- a block's last two dims are whole ``(8, 128)`` tiles or the full
+  array dims.  K/V blocks always span the full ``[bs, D]`` dims, which
+  is why Mosaic also takes int8 and bf16 blocks below their own 32- and
+  16-sublane tiles (compiled and matched on a v5e at ``bs`` 8 and 16);
+  the floor on ``block_size`` and the dense tile is 8 sublanes;
+- ``q_pos`` is a scalar-prefetch operand in SMEM, which serves scalar
+  reads only: the ``[Lq, bs]`` mask threshold is assembled from ``Lq``
+  scalar reads, never a vector load;
+- int8 scales stream as one ``[H, bs]`` block (all heads, lane-major)
+  and the head's ``[1, bs]`` row multiplies the ``[Lq, bs]`` score and
+  probability rows — ``(q·k)·s == q·(k·s)`` — so no lane vector is
+  ever turned into a sublane column;
+- the dense tile is bounded (``_DENSE_TILES``): a cache length no tile
+  divides is refused, never run as one whole-sequence VMEM block.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +70,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ..core.errors import InvalidArgumentError
 
 __all__ = ["decode_attention_kernel", "paged_decode_attention_kernel",
-           "MAX_KERNEL_QUERY_CHUNK", "bias_streamable"]
+           "MAX_KERNEL_QUERY_CHUNK", "bias_streamable", "dense_seq_block",
+           "mosaic_refusal"]
 
 # The longest query chunk the kernel accepts: 1 for autoregressive
 # decode, spec_k+1 for a speculative verify chunk.  Longer chunks are
@@ -65,14 +86,56 @@ MAX_KERNEL_QUERY_CHUNK = 8
 _M_FLOOR = -1e30
 
 
-def _dense_seq_block(s: int) -> int:
-    """Sequence tile for the dense variant: the largest sublane-friendly
-    power of two dividing ``s`` (falling back to one whole-sequence tile
-    when nothing divides — correctness never depends on the tile)."""
-    for cand in (512, 256, 128, 64, 32, 16, 8):
+# The (sublane, lane) tile every VMEM block is held to.
+_SUBLANES = 8
+_LANES = 128
+_HEAD_DIMS = (64, 128, 256)
+
+# Dense sequence tiles, largest first.  All are whole lane multiples so
+# the int8 scale block [H, tile] (sequence on the lane axis) is legal,
+# and the largest bounds the VMEM a K/V block can take (512 x 256 x 4 B
+# = 512 KiB, double-buffered for K and V = 2 MiB).
+_DENSE_TILES = (512, 256, 128)
+
+
+def dense_seq_block(s: int) -> Optional[int]:
+    """Sequence tile for the dense variant: the largest of
+    ``_DENSE_TILES`` dividing ``s``; a short cache (``s`` under the
+    largest tile) of whole sublanes runs as ONE tile equal to the array
+    dim.  None when neither holds — ``mosaic_refusal`` names that case
+    and the caller never builds an unbounded whole-sequence block."""
+    for cand in _DENSE_TILES:
         if s % cand == 0:
             return cand
-    return s
+    if s < _DENSE_TILES[0] and s % _SUBLANES == 0:
+        return s
+    return None
+
+
+def mosaic_refusal(head_dim: int, tile: Optional[int], seq_len: int,
+                   has_bias: bool = False) -> Optional[str]:
+    """Why Mosaic cannot compile the kernel at this geometry, or None.
+
+    ``tile`` is the K/V block's sequence extent: the paged pool's
+    ``block_size``, or ``dense_seq_block``'s answer (None = no tile).
+    THE feasibility rule for compiled mode — the ``*_supported`` gates
+    and the forced route both read it, so "auto" never picks a refused
+    geometry and ``route="pallas"`` raises its reason."""
+    if tile is None:
+        return ("cache length %d has no sequence tile: none of %s "
+                "divides it and it is not a short whole-sublane cache"
+                % (seq_len, list(_DENSE_TILES)))
+    if head_dim not in _HEAD_DIMS:
+        return ("head_dim %d is not one of %s (whole or half 128-lane "
+                "tiles the MXU takes)" % (head_dim, list(_HEAD_DIMS)))
+    if tile % _SUBLANES != 0:
+        return ("K/V block of %d positions is not a multiple of the %d "
+                "sublanes a tile holds" % (tile, _SUBLANES))
+    if has_bias and tile % _LANES != 0 and tile != seq_len:
+        return ("an additive bias streams as [Lq, %d] blocks with the "
+                "sequence on the lane axis, which needs a multiple of "
+                "%d (pass q_pos/lengths instead)" % (tile, _LANES))
+    return None
 
 
 def bias_streamable(bias_shape, b: int, h: int, lq: int, s: int) -> bool:
@@ -130,6 +193,7 @@ def _make_body(n_scalar: int, lq: int, bs: int, sm_scale: float,
         o_ref, m_ref, l_ref, acc_ref = refs[i:i + 4]
 
         bi = pl.program_id(0)
+        hi = pl.program_id(1)
         j = pl.program_id(2)
 
         @pl.when(j == 0)
@@ -139,26 +203,31 @@ def _make_body(n_scalar: int, lq: int, bs: int, sm_scale: float,
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
         qb = q_ref[0, 0].astype(jnp.float32)            # [Lq, D]
-        kb = k_ref[0, 0]                                # [bs, D]
-        vb = v_ref[0, 0]
-        if quant:
-            # VMEM dequant: the HBM read above was int8 — the up-cast
-            # happens here, on one block, never on the gathered cache
-            kb = kb.astype(jnp.float32) * ks_ref[0, 0][:, None]
-            vb = vb.astype(jnp.float32) * vs_ref[0, 0][:, None]
-        else:
-            kb = kb.astype(jnp.float32)
-            vb = vb.astype(jnp.float32)
+        # the HBM read above was the cache dtype — the up-cast happens
+        # here in VMEM, on one block, never on the gathered cache
+        kb = k_ref[0, 0].astype(jnp.float32)            # [bs, D]
+        vb = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             qb, kb, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [Lq, bs]
+            preferred_element_type=jnp.float32)         # [Lq, bs]
+        if quant:
+            # int8 dequant folded into the score row: (q·k)·s == q·(k·s)
+            # per key, and the head's [1, bs] scale row is already
+            # lane-major like the scores
+            s = s * ks_ref[0, pl.ds(hi, 1), :]
+        s = s * sm_scale
         if has_bias:
             s = s + bias_ref[0, 0].astype(jnp.float32)
         # mask keys past each query's position (lengths masking, stale
-        # table rows, the scratch block's garbage — all arrive as q_pos)
+        # table rows, the scratch block's garbage — all arrive as q_pos).
+        # q_pos sits in SMEM: one scalar read per query row, spread over
+        # that row's lanes
+        row = jax.lax.broadcasted_iota(jnp.int32, (lq, bs), 0)
+        qp = jnp.full((lq, bs), qpos_ref[bi, 0], jnp.int32)
+        for r in range(1, lq):
+            qp = jnp.where(row == r, qpos_ref[bi, r], qp)
         pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (lq, bs), 1)
-        allow = pos <= qpos_ref[bi][:, None]
-        s = jnp.where(allow, s, -jnp.inf)
+        s = jnp.where(pos <= qp, s, -jnp.inf)
         # online softmax: rescale the running sums by exp(m_old - m_new)
         m_prev = m_ref[...]                             # [Lq, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -166,6 +235,8 @@ def _make_body(n_scalar: int, lq: int, bs: int, sm_scale: float,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1,
                                                   keepdims=True)
+        if quant:
+            p = p * vs_ref[0, pl.ds(hi, 1), :]          # p·(v·s)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, vb, dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -180,6 +251,12 @@ def _make_body(n_scalar: int, lq: int, bs: int, sm_scale: float,
             o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
     return body
+
+
+# batch rows and heads are independent; the block axis carries the
+# online-softmax state in scratch, so it runs in order
+_GRID_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _scratch(lq: int, d: int):
@@ -209,7 +286,10 @@ def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
         return (tbl[bb, j], hh, 0, 0)
 
     def scale_map(bb, hh, j, tbl, qp):
-        return (tbl[bb, j], hh, 0)
+        # every head's scales for the block: [H, bs] is the smallest
+        # block whose last two dims are legal (a [1, bs] slice of the
+        # H axis is neither a whole sublane tile nor the full dim)
+        return (tbl[bb, j], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, lq, d), lambda bb, hh, j, tbl, qp:
@@ -219,7 +299,7 @@ def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
     ]
     args = [q, k_pool, v_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, bs), scale_map)] * 2
+        in_specs += [pl.BlockSpec((1, h, bs), scale_map)] * 2
         args += [k_scale, v_scale]
     if has_bias:
         in_specs.append(pl.BlockSpec((1, 1, lq, bs),
@@ -236,6 +316,7 @@ def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
         _make_body(2, lq, bs, sm_scale, quant, has_bias),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, lq, d), q.dtype),
+        compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
     )(table, q_pos, *args)
 
@@ -279,7 +360,7 @@ def _dense_call(q, k, v, q_pos, k_scale, v_scale, bias, sm_scale,
                 interpret):
     b, h, lq, d = q.shape
     s = k.shape[2]
-    bs = _dense_seq_block(s)
+    bs = dense_seq_block(s)
     mb = s // bs
     quant = k_scale is not None
     has_bias = bias is not None
@@ -295,8 +376,8 @@ def _dense_call(q, k, v, q_pos, k_scale, v_scale, bias, sm_scale,
     ]
     args = [q, k, v]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, bs), lambda bb, hh, j, qp:
-                                  (bb, hh, j))] * 2
+        in_specs += [pl.BlockSpec((1, h, bs), lambda bb, hh, j, qp:
+                                  (bb, 0, j))] * 2
         args += [k_scale, v_scale]
     if has_bias:
         in_specs.append(pl.BlockSpec((1, 1, lq, bs),
@@ -313,6 +394,7 @@ def _dense_call(q, k, v, q_pos, k_scale, v_scale, bias, sm_scale,
         _make_body(1, lq, bs, sm_scale, quant, has_bias),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, lq, d), q.dtype),
+        compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
     )(q_pos, *args)
 
@@ -333,6 +415,10 @@ def decode_attention_kernel(q, k, v, q_pos, sm_scale: float,
     if (k_scale is None) != (v_scale is None):
         raise InvalidArgumentError(
             "int8 caches carry BOTH k_scale and v_scale (got one)")
+    if dense_seq_block(k.shape[2]) is None:
+        raise InvalidArgumentError(
+            "dense decode kernel: %s"
+            % mosaic_refusal(q.shape[3], None, k.shape[2]))
     return _dense_call(q, k, v, jnp.asarray(q_pos, jnp.int32),
                        k_scale, v_scale, bias,
                        float(sm_scale), bool(interpret))

@@ -21,7 +21,7 @@ from typing import Optional
 import jax
 
 from ..core import flags as _flags
-from ..core.errors import InvalidArgumentError
+from ..core.errors import InvalidArgumentError, NotFoundError
 
 __all__ = ["start_profiler", "stop_profiler", "profiler", "xla_trace",
            "StepTimer", "is_profiling", "record_op_time"]
@@ -104,7 +104,9 @@ class StepTimer:
                  items_per_step: float = 0.0):
         self.flops_per_step = flops_per_step
         self.items_per_step = items_per_step
-        self.peak_flops = peak_flops or device_peak_flops()
+        # resolved on first use (``mfu``): the table raises for a device
+        # it does not list, and timing steps on such a device is fine
+        self.peak_flops = peak_flops
         self._t0 = None
         self.steps = 0
         self.total = 0.0
@@ -129,24 +131,39 @@ class StepTimer:
     def mfu(self) -> float:
         if not (self.flops_per_step and self.total):
             return 0.0
+        if self.peak_flops is None:
+            self.peak_flops = device_peak_flops()
         return self.flops_per_step / self.step_time / self.peak_flops
 
 
-def device_peak_flops() -> float:
-    """Per-chip bf16 peak FLOP/s by device generation (MFU convention)."""
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # pragma: no cover
-        return 1e12
-    if "v5 lite" in kind or "v5e" in kind:
-        return 197e12
-    if "v5p" in kind or "v5" in kind:
-        return 459e12
-    if "v4" in kind:
-        return 275e12
-    if "v6" in kind or "trillium" in kind:
-        return 918e12
-    return 1e12
+# THE peaks table: published per-chip peaks keyed by jax's
+# ``device_kind``.  bench.py's MFU and StepTimer.mfu both divide by it,
+# and a device that is not listed is an error, never a default — a
+# utilisation against an assumed peak is not a measurement.
+# Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s in bf16,
+# 16 GB of HBM at 819 GB/s.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_sec": 819e9},
+}
+
+
+def device_peaks(device_kind: Optional[str] = None) -> dict:
+    """``DEVICE_PEAKS`` row for ``device_kind`` (default: the first
+    device jax reports); raises for a device the table does not list."""
+    kind = jax.devices()[0].device_kind if device_kind is None \
+        else device_kind
+    peaks = DEVICE_PEAKS.get(kind)
+    if peaks is None:
+        raise NotFoundError(
+            "no published peaks for device_kind %r (known: %s); add the "
+            "device to profiler.DEVICE_PEAKS with its source, or pass "
+            "an explicit peak" % (kind, sorted(DEVICE_PEAKS)))
+    return peaks
+
+
+def device_peak_flops(device_kind: Optional[str] = None) -> float:
+    """Per-chip bf16 peak FLOP/s (MFU convention) from the peaks table."""
+    return device_peaks(device_kind)["bf16_flops"]
 
 
 from .visual import LogWriter, export_chrome_tracing  # noqa: E402,F401

@@ -1,11 +1,12 @@
 """The bench regression reporter (tools/bench_report.py).
 
 Pure-stdlib and fast — this module is part of the tier-1 CI wiring:
-``test_check_passes_on_repo_history`` runs the real
-``python -m tools.bench_report --check`` contract against the repo's
-own BENCH_HISTORY.jsonl + BENCH_r*.json (in-process, no subprocess, no
-jax import), and the synthetic cases pin that the gate actually FAILS
-on a regressed record — a reporter that always passes is not a gate."""
+``test_check_passes_with_no_history_present`` runs the real
+``python -m tools.bench_report --check`` contract at the reporter's
+default paths (in-process, no subprocess, no jax import) — the repo
+commits no history file, and a fresh history passes by definition — and
+the synthetic cases pin that the gate actually FAILS on a regressed
+record — a reporter that always passes is not a gate."""
 import copy
 import io
 import json
@@ -51,28 +52,18 @@ def _run(argv):
     return rc, out.getvalue()
 
 
-# -- the CI gate against the repo's real artifacts ------------------------
+# -- the CI gate at the default paths -------------------------------------
 
-def test_check_passes_on_repo_history():
-    # the acceptance contract: the gate is green on the history as
-    # committed (a red gate would block every PR on day one)
+def test_check_passes_with_no_history_present():
+    # the repo commits no history file: the reporter must keep working
+    # and say out loud that nothing was gated
+    assert not os.path.exists(DEFAULT_HISTORY)
+    assert load_history(DEFAULT_HISTORY) == []
+    assert load_round_files(DEFAULT_ROUNDS) == []
     rc, out = _run(["--history", DEFAULT_HISTORY,
                     "--rounds", DEFAULT_ROUNDS, "--check"])
     assert rc == 0, out
-    assert "--check: pass" in out
-    # the committed history's two lines are the SAME run written
-    # twice: the collapse (and therefore what was and wasn't gated)
-    # must be said out loud, never silent
-    assert "collapsed" in out
-
-
-def test_repo_artifacts_parse():
-    # the parsers actually read the committed artifacts (0 records
-    # would make the green gate above vacuous)
-    assert len(load_history(DEFAULT_HISTORY)) >= 2
-    # round wrappers are best-effort: truncated tails skip, parsed
-    # results load — just assert no crash and a list comes back
-    assert isinstance(load_round_files(DEFAULT_ROUNDS), list)
+    assert "nothing to diff" in out
 
 
 # -- synthetic regression / improvement cases -----------------------------

@@ -174,6 +174,13 @@ def test_inplace_activations(rng):
     np.testing.assert_allclose(np.asarray(y.value), ref, rtol=1e-6)
     y.sum().backward()
     assert x.grad is not None
+    # gumbel_softmax(hard=True): one-hot forward (the installed jax needs
+    # put_along_axis(inplace=False)), straight-through gradient
+    pt.seed(0)
+    hard = F.gumbel_softmax(x, hard=True)
+    h = np.asarray(hard.value)
+    np.testing.assert_array_equal(np.sort(h, -1)[:, -1], np.ones(3))
+    np.testing.assert_array_equal(h.sum(-1), np.ones(3))
 
 
 def test_pairwise_distance_and_unfold(rng):

@@ -100,14 +100,15 @@ def test_paged_kernel_scalar_lengths_and_qpos():
 
 @pytest.mark.parametrize("quant", [False, True], ids=["fp32", "int8"])
 def test_dense_kernel_matches_composition(quant):
-    # the dense-cache variant on the same inner loop, including a
-    # sequence length no power-of-two tile divides (S=40 -> tile 8)
+    # the dense-cache variant on the same inner loop, over several
+    # sequence tiles (S=640 -> five tiles of 128, the largest of the
+    # kernel's bounded tile set that divides it)
     import jax.numpy as jnp
 
     from paddle_tpu.ops import quantize_kv
 
     rng = np.random.RandomState(2)
-    b, h, s, d, lq = 2, 3, 40, 16, 4
+    b, h, s, d, lq = 2, 3, 640, 16, 4
     q = jnp.asarray(rng.randn(b, h, lq, d).astype(np.float32))
     k = rng.randn(b, h, s, d).astype(np.float32)
     v = rng.randn(b, h, s, d).astype(np.float32)
@@ -252,6 +253,52 @@ def test_forced_pallas_keeps_composition_for_long_chunks():
     got = np.asarray(fa.decode_attention(q, k, v, route="pallas"))
     want = np.asarray(fa.decode_attention(q, k, v, route="composition"))
     np.testing.assert_array_equal(got, want)  # same path, same bytes
+
+
+def test_forced_pallas_refuses_by_name(monkeypatch):
+    # a decode-sized chunk the kernel cannot take is REFUSED under the
+    # forced route, naming the reason — it never decodes on the
+    # composition behind the caller's back; "auto" just keeps the
+    # composition.  The interpreter refuses only a cache length with no
+    # bounded sequence tile; compiled mode adds Mosaic's layout rules.
+    import jax
+    import jax.numpy as jnp
+
+    assert pd.dense_seq_block(1024) == 512 and pd.dense_seq_block(640) == 128
+    assert pd.dense_seq_block(40) == 40        # short: one whole tile
+    assert pd.dense_seq_block(520) is None     # long, nothing divides
+    assert pd.dense_seq_block(36) is None      # short but not whole sublanes
+    q = jnp.zeros((1, 2, 1, 16), jnp.float32)
+    kv = jnp.zeros((1, 2, 520, 16), jnp.float32)
+    with pytest.raises(InvalidArgumentError, match="no sequence tile"):
+        fa.decode_attention(q, kv, kv, route="pallas")
+    fa.decode_attention(q, kv, kv, route="auto")     # composition, quietly
+    for args, match in (((128, 12, 48), "multiple of the 8"),
+                        ((48, 32, 128), "head_dim 48"),
+                        ((128, None, 520), "no sequence tile"),
+                        ((128, 32, 128, True), "additive bias")):
+        assert match in pd.mosaic_refusal(*args)
+    for args in ((128, 32, 1024), (64, 8, 1024), (256, 128, 128, True),
+                 (128, 64, 64, True)):
+        assert pd.mosaic_refusal(*args) is None
+    # compiled mode: the same call that the interpreter takes is refused
+    pool = jnp.zeros((3, 2, 12, 16), jnp.float32)     # block of 12, D=16
+    table = jnp.zeros((1, 2), jnp.int32)
+    fa.paged_decode_attention(q, pool, pool, table, route="pallas")
+    fa.reset_backend_memo()
+    try:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        fa.reset_backend_memo()
+        with pytest.raises(InvalidArgumentError, match="head_dim 16"):
+            fa.paged_decode_attention(q, pool, pool, table,
+                                      route="pallas")
+        # the prefill-shaped chunk keeps the composition by design
+        long_q = jnp.zeros((1, 2, pd.MAX_KERNEL_QUERY_CHUNK + 1, 16))
+        fa.paged_decode_attention(long_q, pool, pool, table,
+                                  route="pallas")
+    finally:
+        monkeypatch.undo()
+        fa.reset_backend_memo()
 
 
 def _tiny_model(vocab=128, hidden=64, heads=4, layers=2):

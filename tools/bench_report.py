@@ -1,9 +1,10 @@
 """Bench regression reporter: diff the perf history, gate on it.
 
-``BENCH_HISTORY.jsonl`` accumulates one record per verified on-chip
-bench run and ``BENCH_r*.json`` wrap each round's harness output, but
-until now no tool ever DIFFED them — a 20% decode regression would sit
-in the artifact unread.  This module closes the loop:
+A history file (``BENCH_HISTORY.jsonl`` at the repo root by default; the
+repo commits none) holds one bench record per line and ``BENCH_r*.json``
+wrap a round's harness output.  This module DIFFS them — a 20% decode
+regression must not sit in an artifact unread.  With no history present
+it reports that there is nothing to diff and ``--check`` passes.
 
     python -m tools.bench_report            # markdown report
     python -m tools.bench_report --json     # machine-readable

@@ -6,7 +6,7 @@ reads dominate HBM; the prefill is compute-bound and scales with bucket
 length.  This sweep measures both axes of ``jit.DecodeSession``:
 
 - per-token decode time at batch x cache-length points (the marginal
-  t(N_tokens) discipline of ``ceiling_probe.py``: a 1-token generation
+  t(N_tokens) discipline: a 1-token generation
   isolates the prefill term, differences isolate pure decode);
 - prefill latency per bucket (one compile per bucket — the compile
   counts are recorded so a bucket-policy regression is visible in the
@@ -100,7 +100,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 import numpy as np
 
-REPEATS = 3  # median-of-N, same noise discipline as ceiling_probe.py
+REPEATS = 3  # median-of-N
 
 
 def sweep(pt, cfg, batches, buckets, gen, block_sizes, cache_dtypes,
@@ -677,10 +677,9 @@ def main():
             sys.exit("--mesh needs dp >= 1 and mp >= 1, got %r" % spec)
         meshes.append((dp, mp))
 
-    from bench import _acquire_chip_lock, _peak_flops, force_host_devices
+    from bench import _peak_flops, force_host_devices
+    from tools.compile_cache import ensure_compile_cache
 
-    if not args.cpu_smoke and _acquire_chip_lock(timeout_s=600.0) is None:
-        sys.exit("another process holds the chip lock; not contending")
     if args.cpu_smoke:
         os.environ["JAX_PLATFORMS"] = "cpu"
         if meshes:
@@ -691,12 +690,14 @@ def main():
 
     import jax
 
+    ensure_compile_cache()
+
     import paddle_tpu as pt
     from paddle_tpu.models import gpt_1p3b_config
 
-    on_tpu = jax.default_backend() != "cpu"
+    on_tpu = jax.devices()[0].platform == "tpu"
     if not on_tpu and not args.cpu_smoke:
-        sys.exit("accelerator not reachable; refusing to 'measure' CPU")
+        sys.exit("no TPU found; refusing to 'measure' CPU")
 
     cfg = gpt_1p3b_config()
     if args.cpu_smoke:

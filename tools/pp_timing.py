@@ -89,6 +89,10 @@ def main():
     import jax
     from jax.sharding import Mesh
 
+    from tools.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
+
     import paddle_tpu as pt
     import paddle_tpu.nn.functional as F
     import paddle_tpu.tensor as T
