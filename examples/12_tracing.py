@@ -6,20 +6,30 @@ observability leg (docs/DESIGN.md §5g):
 
 1. **tracing**: ``engine.start_trace()`` installs a bounded flight
    recorder; every tick runs as a numbered span with per-phase children
-   (admit / prefill / decode / sample / deliver), and lifecycle
-   transitions, compile events, fault injections, recoveries and sheds
-   land in the ring.  Tracing off is a module-level no-op on the hot
-   path;
+   (govern / admit / prefill / decode / sample / deliver / observe /
+   journal) that say what the tick did (``queued``, ``admitted``,
+   ``finished`` on ``tick``; ``live`` of ``slots`` on ``tick.decode``;
+   ``bucket`` beside ``prompt_tokens`` on ``tick.prefill``), and
+   lifecycle transitions, compile events, fault injections, recoveries
+   and sheds land in the ring.  Tracing off is a module-level no-op on
+   the hot path;
 2. **per-request timelines**: ``engine.request_trace(rid)`` — the
    ``GET /debug/trace?rid=`` body — shows one request's path, including
    the injection → recovery → completion sequence of a faulted run;
 3. **Chrome export**: ``engine.export_chrome_trace(path)`` writes
    trace-event JSON (one track per request + per tick phase) that
    chrome://tracing / Perfetto load directly;
-4. **deep timing**: an opt-in mode that syncs phase edges
-   (``block_until_ready``) for honest device attribution — every span
-   carries its ``deep`` flag so dispatch time can never masquerade as
-   device time.
+4. **the request's own timeline, tracer or not**: every terminal status
+   (and the terminal ndjson line of ``POST /generate``) carries
+   ``lock_wait_s``, how long ``submit()`` stood before the engine lock,
+   which ``ttft_s``/``total_s`` leave out, and ``queue_wait_s``, from
+   admission to its first slot;
+5. **open the profile and see the tick**: while a tracer is installed
+   every span is also a ``jax.profiler.TraceAnnotation``, so a profile
+   anyone takes holds ``tick``, ``tick.*`` and ``submit.lock_wait`` in
+   its host plane, on the trace's own clock, beside the device
+   operations, whose ``op_name`` carries the module tree
+   (``encoder/layers/0/self_attn/q_proj``, ``lm_head``, ``sample``).
 
 Run: python examples/12_tracing.py [--tokens 8]
 """
@@ -30,6 +40,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 
 import argparse
+import glob
 import json
 import tempfile
 
@@ -104,16 +115,43 @@ def main():
     print("chrome trace: %d events -> %s" % (len(doc["traceEvents"]),
                                              path))
 
-    # -- deep timing: phase edges synced, spans flagged honest
+    # -- what the ticks did, from the spans' meta
+    decode = [e.meta for e in events if e.name == "tick.decode"]
+    ticks = [e.meta for e in events if e.name == "tick"]
+    print("ticks: %d, admitted %d, finished %d; mean live rows %.2f of %d"
+          % (len(ticks), sum(m["admitted"] for m in ticks),
+             sum(m["finished"] for m in ticks),
+             sum(m["live"] for m in decode) / len(decode),
+             decode[0]["slots"]))
+
+    # -- the request's own timeline needs no tracer: it is on the status
     engine2 = build_engine(model)
-    engine2.start_trace(capacity=512, deep_timing=True)
+    for st in run(engine2, prompts[:2], args.tokens):
+        print("%s: lock_wait_s=%.6f queue_wait_s=%.6f ttft_s=%.6f "
+              "(ttft_s runs from admission: add lock_wait_s)"
+              % (st.request_id, st.lock_wait_s, st.queue_wait_s,
+                 st.ttft_s))
+
+    # -- open the profile and see the tick: the spans are in it
+    import jax
+    from jax.profiler import ProfileData
+    profile_dir = tempfile.mkdtemp(prefix="paddle_tpu_profile_")
+    engine2.start_trace(capacity=512)
+    jax.profiler.start_trace(profile_dir)
     run(engine2, prompts[:1], args.tokens)
+    jax.profiler.stop_trace()
     engine2.stop_trace()
-    deep = json.loads(engine2.export_chrome_trace())
-    phase = [e for e in deep["traceEvents"]
-             if e.get("cat") == "phase" and e["name"] == "tick.decode"]
-    print("deep-timing tick.decode spans: %d, all flagged deep=%s"
-          % (len(phase), all(e["args"]["deep"] for e in phase)))
+    (pb,) = glob.glob(os.path.join(profile_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    seen = {}
+    for plane in ProfileData.from_file(pb).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(("tick", "submit.")):
+                        seen[ev.name] = seen.get(ev.name, 0) + 1
+    print("annotations in the profile's host plane:",
+          dict(sorted(seen.items())))
     print("done.")
 
 

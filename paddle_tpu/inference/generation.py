@@ -801,12 +801,18 @@ class GenerationPool:
                                             adapter,
                                             collective_seam=True)
         temp, tk, tp, seed = samp
-        tok = sample_logits_data(logits[:, 0], temp, tk, tp, seed, step)
+        # scopes by hand where no Layer runs: they name these
+        # operations in a device profile as the module tree names the
+        # model's (nn.Layer.__call__)
+        with jax.named_scope("sample"):
+            tok = sample_logits_data(logits[:, 0], temp, tk, tp, seed,
+                                     step)
         step = step + active.astype(step.dtype)
         # layout-owned freeze (jit.cache): positional layouts merge the
         # index; the recurrent layout must also restore inactive slots'
         # state carry (a recurrence updates every row every step)
-        new_cache = self._layout.freeze_step(new_cache, cache, active)
+        with jax.named_scope("cache_freeze"):
+            new_cache = self._layout.freeze_step(new_cache, cache, active)
         if tables is not None:
             new_cache = [c._replace(table=t)
                          for c, t in zip(new_cache, tables)]
@@ -867,7 +873,9 @@ class GenerationPool:
         last = jax.lax.dynamic_index_in_dim(logits[0], length - 1,
                                             axis=0, keepdims=False)
         temp, tk, tp, seed, step = samp
-        tok = sample_logits_data(last[None], temp, tk, tp, seed, step)
+        with jax.named_scope("sample"):
+            tok = sample_logits_data(last[None], temp, tk, tp, seed,
+                                     step)
         out = [c._replace(k=v.k, v=v.v, k_scale=v.k_scale,
                           v_scale=v.v_scale,
                           index=c.index.at[slot].set(
@@ -2526,14 +2534,14 @@ class GenerationPool:
                 row_cache, tok, _ = self._session.prefill(
                     req.ids[None], samp)
             else:
+                # ``bucket``: the length the prompt is padded to, so
+                # 1 - prompt_tokens / bucket is the prefill's padding
                 with tr.span("tick.prefill", rid=req.rid,
-                             prompt_tokens=len(req.ids)):
+                             prompt_tokens=len(req.ids),
+                             bucket=self._session._bucket_for(
+                                 len(req.ids))):
                     row_cache, tok, _ = self._session.prefill(
                         req.ids[None], samp)
-                    if tr.deep:
-                        # deep-timing honesty: the prefill span ends at
-                        # the fusion boundary, not at dispatch return
-                        jax.block_until_ready(row_cache)
             slot = self._pop_free_slot(shard)
             first = int(np.asarray(tok)[0])
             if self.cache_layout == "paged":
@@ -2594,16 +2602,13 @@ class GenerationPool:
                 jnp.asarray(n, jnp.int32), samp, adpt)
         else:
             with tr.span("tick.prefill", rid=st.rid, chunk_tokens=n,
-                         pos=st.pos, prompt_tokens=len(st.ids)):
+                         pos=st.pos, prompt_tokens=len(st.ids),
+                         bucket=self._chunk_tokens):
                 self._cache, tok_dev = self._chunk_jit(
                     params, bufs, self._cache, jnp.asarray(toks),
                     jnp.asarray(slot, jnp.int32),
                     jnp.asarray(st.pos, jnp.int32),
                     jnp.asarray(n, jnp.int32), samp, adpt)
-                if tr.deep:
-                    # deep-timing honesty: close the chunk span at the
-                    # device edge, not at dispatch return
-                    jax.block_until_ready(tok_dev)
         self._chunks_total += 1
         self._chunk_tokens_total += n
         st.pos += n
@@ -2685,10 +2690,12 @@ class GenerationPool:
 
         With a tracer installed (serving/trace.py) each phase of the
         tick is spanned — admit (refill incl. per-request prefill),
-        decode (the batched dispatch; ``deep_timing`` syncs it at the
-        edge), sample (the per-tick host download of the sampled ids),
-        deliver (the host loop committing tokens and firing hooks) —
-        through the tracing-off-is-a-no-op branches below."""
+        decode (the batched dispatch, which returns before the device
+        has finished; its meta says how many of the ``slots`` rows were
+        ``live``), sample (the per-tick host download of the sampled
+        ids, where the host waits for the device), deliver (the host
+        loop committing tokens and firing hooks) — through the
+        tracing-off-is-a-no-op branches below."""
         _fire("pool.step")
         tr = _trace_active()
         if tr is None:
@@ -2709,12 +2716,9 @@ class GenerationPool:
             tok_dev = self._dispatch(params, bufs)
             tok = np.asarray(tok_dev)
         else:
-            with tr.span("tick.decode"):
+            with tr.span("tick.decode", live=len(self._active),
+                         slots=self.slots):
                 tok_dev = self._dispatch(params, bufs)
-                if tr.deep:
-                    # deep-timing honesty: close the decode span at the
-                    # device edge, not at dispatch return
-                    jax.block_until_ready(tok_dev)
             with tr.span("tick.sample"):
                 # the per-tick host download of the sampled ids — the
                 # designed sync point whether or not it is spanned

@@ -433,13 +433,10 @@ class SpeculativePool(GenerationPool):
                 params, bufs, dparams, dbufs)
             emitted, m_host = jax.device_get((emitted_dev, m_dev))
         else:
-            with tr.span("tick.decode", spec_k=self.spec_k):
+            with tr.span("tick.decode", spec_k=self.spec_k,
+                         live=len(self._active), slots=self.slots):
                 emitted_dev, m_dev, pending_dev = self._spec_round(
                     params, bufs, dparams, dbufs)
-                if tr.deep:
-                    # deep-timing honesty: close the round's span at
-                    # the device edge, not at dispatch return
-                    jax.block_until_ready(m_dev)
             with tr.span("tick.sample"):
                 emitted, m_host = jax.device_get((emitted_dev, m_dev))
         if tr is None:

@@ -420,7 +420,12 @@ class TrainStep:
                     Tensor(l, stop_gradient=True) if isinstance(l, jax.Array) else l
                     for l in batch_leaves
                 ]
-                with rng_guard(key):
+                # ``loss`` and ``optimizer`` name the step's two
+                # halves in a device profile; inside ``loss`` the module
+                # tree names the layers (nn.Layer.__call__), and backward
+                # operations keep their forward scope under
+                # ``transpose(jvp(...))``
+                with rng_guard(key), jax.named_scope("loss"):
                     loss = self._loss_fn(self._model, *batch)
                 loss_raw = _unwrap(loss)
             finally:
@@ -437,9 +442,10 @@ class TrainStep:
                 lambda x, s: x if s is False else jax.device_put(
                     x, s.with_memory_kind("device")),
                 opt_states, host_sh)
-        new_diff_vals, new_states = opt._functional_step(
-            diff_params, diff_vals, grads, opt_states, lr
-        )
+        with jax.named_scope("optimizer"):
+            new_diff_vals, new_states = opt._functional_step(
+                diff_params, diff_vals, grads, opt_states, lr
+            )
         # (transfer back to host happens outside the jit boundary in
         # __call__ — in-trace device_put-to-host is not reliably reflected
         # in the executable's output memory space)

@@ -529,8 +529,9 @@ class DecodeSession:
         """One per-row draw under the as-data config; advances each
         row's draw counter (the traced bodies never read the session's
         scalar defaults — that would bake them into the executable)."""
-        tok = sample_logits_data(logits, samp.temperature, samp.top_k,
-                                 samp.top_p, samp.seed, samp.step)
+        with jax.named_scope("sample"):
+            tok = sample_logits_data(logits, samp.temperature, samp.top_k,
+                                     samp.top_p, samp.seed, samp.step)
         return tok, samp._replace(step=samp.step + jnp.uint32(1))
 
     def _prefill(self, param_vals, buf_vals, ids, true_len, samp):

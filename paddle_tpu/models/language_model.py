@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from .. import tensor as T
@@ -205,13 +206,17 @@ class TransformerLM(Layer):
         if cache is not None:
             h, new_cache = self.encode(input_ids, attn_mask, token_type_ids,
                                        cache)
-            logits = T.matmul(h, self.word_embeddings.weight,
-                              transpose_y=True)
-            return logits, new_cache
-        h = self.encode(input_ids, attn_mask, token_type_ids)
-        # tied LM head: logits = h @ E^T
-        logits = T.matmul(h, self.word_embeddings.weight, transpose_y=True)
-        return logits
+            return self._lm_head(h), new_cache
+        return self._lm_head(self.encode(input_ids, attn_mask,
+                                         token_type_ids))
+
+    def _lm_head(self, h):
+        # tied LM head: logits = h @ E^T.  No Layer runs here, so the
+        # scope that names the vocabulary-wide matmul in a device
+        # profile is opened by hand
+        with jax.named_scope("lm_head"):
+            return T.matmul(h, self.word_embeddings.weight,
+                            transpose_y=True)
 
     def flops_per_token(self, seq_len: int) -> float:
         """Analytic fwd+bwd FLOPs/token for MFU accounting (PaLM appendix B).

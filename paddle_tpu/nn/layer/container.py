@@ -42,7 +42,23 @@ class Sequential(Layer):
         return x
 
 
-class LayerList(Layer):
+class _IteratedContainer(Layer):
+    """A container whose owner iterates it and calls the members itself
+    (``for layer in self.layers``): it never runs, so it cannot open its
+    own ``jax.named_scope``.  Its members carry its scope in theirs
+    (``layers/3``) instead, whichever of the two is registered first."""
+
+    def _set_scope(self, scope: str) -> None:
+        super()._set_scope(scope)
+        for name, layer in self._sub_layers.items():
+            if layer is not None:
+                layer._set_scope(self._child_scope(name))
+
+    def _child_scope(self, name: str) -> str:
+        return name if self._scope is None else self._scope + "/" + name
+
+
+class LayerList(_IteratedContainer):
     def __init__(self, sublayers: Iterable[Layer] = None):
         super().__init__()
         if sublayers is not None:
@@ -60,7 +76,7 @@ class LayerList(Layer):
 
     def __setitem__(self, idx, layer):
         keys = list(self._sub_layers.keys())
-        self._sub_layers[keys[idx]] = layer
+        self.add_sublayer(keys[idx], layer)
 
     def __iter__(self):
         return iter(self._sub_layers.values())
@@ -74,7 +90,7 @@ class LayerList(Layer):
         layers.insert(index, layer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
     def extend(self, sublayers: Iterable[Layer]) -> "LayerList":
         for layer in sublayers:
@@ -104,7 +120,7 @@ class ParameterList(Layer):
         return self
 
 
-class LayerDict(Layer):
+class LayerDict(_IteratedContainer):
     def __init__(self, sublayers=None):
         super().__init__()
         if sublayers is not None:

@@ -14,6 +14,7 @@ from __future__ import annotations
 import collections
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -21,6 +22,14 @@ from ...core.dtype import convert_dtype, get_default_dtype
 from ...core.errors import InvalidArgumentError
 from ...framework.tensor import Parameter, Tensor
 from .. import initializer as I
+
+# ``Layer.__call__`` names operations by ``jax.named_scope``, which is
+# metadata, and JAX's persistent compile cache leaves metadata out of its
+# key unless told otherwise: an executable cached before a scope existed
+# (or under another name) would be served again without it, and a device
+# profile would read stale names.  The price is a recompile when a traced
+# line moves.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 class ParamAttr:
@@ -70,6 +79,14 @@ class HookRemoveHelper:
 
 class Layer:
     """Base class for all network layers (fluid/dygraph/layers.py:81 analog)."""
+
+    # the name this layer's parent registered it under (None for a root):
+    # ``__call__`` runs ``forward`` under ``jax.named_scope`` of it, so
+    # every jitted step carries the module tree in its operations'
+    # ``op_name`` (``encoder/layers/3/self_attn/q_proj/dot_general``) and
+    # a device profile can be read by layer.  Metadata only: it changes
+    # no executable's name and no compile count.
+    _scope: Optional[str] = None
 
     def __init__(self, name_scope: Optional[str] = None, dtype=None):
         self.training = True
@@ -124,7 +141,18 @@ class Layer:
         if not isinstance(sublayer, Layer):
             raise InvalidArgumentError("add_sublayer expects a Layer, got %r" % type(sublayer))
         self._sub_layers[str(name)] = sublayer
+        sublayer._set_scope(self._child_scope(str(name)))
         return sublayer
+
+    def _set_scope(self, scope: str) -> None:
+        object.__setattr__(self, "_scope", scope)
+
+    def _child_scope(self, name: str) -> str:
+        """The scope of a sublayer registered as ``name``.  A layer that
+        is called contributes its own scope when it runs, so its
+        children carry their bare names (containers that are only
+        iterated differ: see ``LayerList``)."""
+        return name
 
     def register_buffer(self, name: str, tensor: Optional[Tensor], persistable: bool = True) -> None:
         if tensor is not None and not isinstance(tensor, Tensor):
@@ -154,6 +182,7 @@ class Layer:
                 if d is not None:
                     d.pop(name, None)
             layers[name] = value
+            value._set_scope(self._child_scope(name))
         elif buffers is not None and name in buffers:
             if value is not None and not isinstance(value, Tensor):
                 value = Tensor(value, stop_gradient=True, name=name)
@@ -367,7 +396,11 @@ class Layer:
             result = hook(self, inputs)
             if result is not None:
                 inputs = result if isinstance(result, tuple) else (result,)
-        outputs = self.forward(*inputs, **kwargs)
+        if self._scope is None:
+            outputs = self.forward(*inputs, **kwargs)
+        else:
+            with jax.named_scope(self._scope):
+                outputs = self.forward(*inputs, **kwargs)
         for hook in list(self._forward_post_hooks.values()):
             result = hook(self, inputs, outputs)
             if result is not None:

@@ -348,11 +348,12 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, q_pos,
     if (k_scale is None) != (v_scale is None):
         raise InvalidArgumentError(
             "int8 pools carry BOTH k_scale and v_scale (got one)")
-    return _paged_call(q, k_pool, v_pool,
-                       jnp.asarray(table, jnp.int32),
-                       jnp.asarray(q_pos, jnp.int32),
-                       k_scale, v_scale, bias,
-                       float(sm_scale), bool(interpret))
+    with jax.named_scope("paged_attn"):
+        return _paged_call(q, k_pool, v_pool,
+                           jnp.asarray(table, jnp.int32),
+                           jnp.asarray(q_pos, jnp.int32),
+                           k_scale, v_scale, bias,
+                           float(sm_scale), bool(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))

@@ -30,8 +30,9 @@ surface, ``supervisor`` the liveness surface, and ``trace`` the
 request-scoped one — a bounded flight recorder plus span/event tracing
 of the full request path (lifecycle transitions, tick phases, compile
 events, fault injections, recoveries, sheds, restarts), a module-level
-no-op when off, with an opt-in deep-timing mode that syncs phase edges
-for honest device attribution.  Export via
+no-op when off; every span is also a ``jax.profiler.TraceAnnotation``,
+so a profile taken while tracing holds the tick phases beside the
+device operations.  Export via
 ``ServingEngine.export_chrome_trace()`` (Chrome/Perfetto JSON),
 ``GET /debug/trace?rid=<id>`` / ``GET /debug/flightrec`` on the HTTP
 front end, and automatic post-mortem dumps into ``EngineHealth`` when

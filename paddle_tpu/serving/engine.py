@@ -208,11 +208,12 @@ class _Record:
     __slots__ = ("rid", "stream", "state", "prompt", "prompt_len",
                  "max_new", "deadline_abs", "submit_t", "first_t",
                  "last_t", "tokens", "retries", "priority", "tenant",
-                 "preempts", "preempted_at", "sampling", "adapter")
+                 "preempts", "preempted_at", "sampling", "adapter",
+                 "lock_wait_s", "admit_t")
 
     def __init__(self, rid, stream, prompt, max_new, deadline_abs,
                  submit_t, priority=0, tenant=None, sampling=None,
-                 adapter=0):
+                 adapter=0, lock_wait_s=None):
         self.rid = rid
         self.stream = stream
         self.state = RequestState.QUEUED
@@ -235,6 +236,14 @@ class _Record:
         # the request's own stream and adapter, never a pool global
         self.sampling = sampling
         self.adapter = adapter
+        # the request's own timeline, tracer or not: how long submit()
+        # stood before the engine lock (perf_counter seconds; None for
+        # a request that came in another way: deferred, replayed,
+        # adopted) and the engine-clock instant it first took a slot.
+        # ``submit_t`` is stamped INSIDE the lock, so ttft_s/total_s
+        # and the SLO plane run from admission: add ``lock_wait_s``
+        self.lock_wait_s = lock_wait_s
+        self.admit_t = None
 
 
 class ServingEngine:
@@ -427,6 +436,10 @@ class ServingEngine:
         self._wake = threading.Event()
         self._timer = StepTimer()  # profiler's step-time/throughput helper
         self._tokens_total = 0
+        # slot-takes and terminals ever: a traced tick reads them
+        # before and after itself for its ``admitted``/``finished`` meta
+        self._n_admitted = 0
+        self._n_finalized = 0
         # tracing state (serving/trace.py): the last tracer a tick
         # observed (or start_trace installed) stays referenced so
         # export_chrome_trace()/post-mortem dumps work after
@@ -602,9 +615,15 @@ class ServingEngine:
         self._g_step = m.gauge(
             "serving_step_time_s", "mean batched decode step wall time")
         self._h_ttft = m.histogram(
-            "serving_ttft_seconds", "submit-to-first-token latency")
+            "serving_ttft_seconds",
+            "admission-to-first-token latency (lock wait excluded; see "
+            "serving_submit_lock_wait_seconds)")
         self._h_itl = m.histogram(
             "serving_inter_token_seconds", "gap between consecutive tokens")
+        self._h_lock_wait = m.histogram(
+            "serving_submit_lock_wait_seconds",
+            "submit()/cancel() entry to engine lock acquired (not part "
+            "of serving_ttft_seconds, which runs from admission)")
         # cost attribution read off the compiled artifacts (jit.aot):
         # what one batched step ASKS the hardware for, per the
         # compiler's own cost/memory analyses — refreshed only when an
@@ -705,7 +724,22 @@ class ServingEngine:
             raise InvalidArgumentError(
                 "deadline_s must be > 0 (or None for no deadline), "
                 "got %r" % (deadline_s,))
+        # the wait for the engine lock is the request's, tracer or not:
+        # one clock read before the lock, one inside it.  With a tracer
+        # the same wait is a ``submit.lock_wait`` span on the CALLING
+        # thread, closed by hand at the first line under the lock so
+        # that the body stays textually inside ``with self._lock``
+        t_enter = time.perf_counter()
+        tr = trace.active()
+        waiting = None
+        if tr is not None:
+            waiting = tr.span("submit.lock_wait", rid=request_id)
+            waiting.__enter__()
         with self._lock:
+            lock_wait = time.perf_counter() - t_enter
+            if waiting is not None:
+                waiting.__exit__(None, None, None)
+            self._h_lock_wait.observe(lock_wait)
             if self._draining:
                 raise PreconditionNotMetError(
                     "engine is draining/shut down: admissions are "
@@ -835,7 +869,7 @@ class ServingEngine:
             self._live[rid] = _Record(
                 rid, stream, ids.astype(np.int32), int(max_new_tokens),
                 deadline_abs, now, priority=priority, tenant=tenant,
-                sampling=samp, adapter=adapter)
+                sampling=samp, adapter=adapter, lock_wait_s=lock_wait)
             if self._journal is not None:
                 # WAL discipline: the admission is durable BEFORE the
                 # request can commit a token.  A failed (retried)
@@ -860,7 +894,8 @@ class ServingEngine:
                           prompt_tokens=int(ids.shape[0]),
                           max_new_tokens=int(max_new_tokens),
                           deadline_s=deadline_s,
-                          priority=priority or None, tenant=tenant)
+                          priority=priority or None, tenant=tenant,
+                          lock_wait_s=lock_wait)
             # the req.admitted log line is emitted at POOL admission
             # (_on_admit, when the request takes a slot): only there is
             # the prefix-hit outcome known, and the line must carry it
@@ -872,7 +907,13 @@ class ServingEngine:
     def _on_admit(self, rid, slot, prompt_len):
         rec = self._live.get(rid)
         if rec is not None:
+            self._n_admitted += 1
             rec.state = RequestState.PREFILLING
+            if rec.admit_t is None:
+                # the FIRST time the request takes a slot (a recovery's
+                # re-admission does not move it): queue_wait_s on the
+                # terminal record = this - submit_t
+                rec.admit_t = self._clock()
             # matched prefix tokens of THIS admission (the pool stamps
             # it right before firing the hook; None = sharing off, and
             # the logger drops None fields)
@@ -1425,6 +1466,7 @@ class ServingEngine:
         toks = np.asarray(tokens if tokens is not None else rec.tokens,
                           np.int32)
         rec.state = state
+        self._n_finalized += 1
         if self._journal is not None:
             # commit-before-terminal ordering: this rid's same-tick
             # token deltas must hit the journal before the record that
@@ -1456,7 +1498,10 @@ class ServingEngine:
             new_tokens=int(toks.size),
             ttft_s=(None if rec.first_t is None
                     else rec.first_t - rec.submit_t),
-            total_s=now - rec.submit_t, error=error))
+            total_s=now - rec.submit_t, error=error,
+            lock_wait_s=rec.lock_wait_s,
+            queue_wait_s=(None if rec.admit_t is None
+                          else rec.admit_t - rec.submit_t)))
 
     def cancel(self, request_id) -> bool:
         """Abort a live request: its slot and paged blocks are freed
@@ -1464,7 +1509,9 @@ class ServingEngine:
         tokens emitted so far ride in the status record).  False if the
         id is not live (already terminal or unknown) — idempotent, so
         callers can cancel on a races-with-completion path safely."""
+        t_enter = time.perf_counter()
         with self._lock:
+            self._h_lock_wait.observe(time.perf_counter() - t_enter)
             rec = self._live.pop(request_id, None)
             if rec is None:
                 if request_id is not None:
@@ -2176,13 +2223,15 @@ class ServingEngine:
     def _tick(self) -> bool:
         tr = trace.active()
         if tr is None:
-            return self._run_tick()
+            return self._run_tick(None)
         return self._run_tick_traced(tr)
 
     def _run_tick_traced(self, tr) -> bool:
         """The traced twin of the tick: same ``_run_tick`` body inside a
-        numbered ``tick`` span, plus compile-event diffing and the
-        drop-counter mirror.  All tracer bookkeeping writes re-take the
+        numbered ``tick`` span that says what the tick did (``queued``
+        at its start, requests ``admitted`` to a slot and ``finished``
+        inside it), plus compile-event diffing and the drop-counter
+        mirror.  All tracer bookkeeping writes re-take the
         (reentrant) engine lock the driving thread already holds, so the
         lock discipline stays textual."""
         if tr is not self._tracer:
@@ -2195,8 +2244,12 @@ class ServingEngine:
                 # baseline BEFORE the tick so a cold engine's very first
                 # traced tick reports its own compiles as events
                 self._compile_seen = self._pool.compile_counts()
-        with tr.span("tick", tick=tr.next_tick()):
-            work = self._run_tick()
+        with tr.span("tick", tick=tr.next_tick(),
+                     queued=self._pool.queue_depth) as span:
+            admitted, finished = self._n_admitted, self._n_finalized
+            work = self._run_tick(tr)
+            span.set(admitted=self._n_admitted - admitted,
+                     finished=self._n_finalized - finished)
         counts = self._pool.compile_counts()
         if counts != self._compile_seen:
             for key, n in counts.items():
@@ -2211,18 +2264,26 @@ class ServingEngine:
                 self._trace_dropped_seen = dropped
         return work
 
-    def _run_tick(self) -> bool:
+    def _run_tick(self, tr) -> bool:
+        """One tick.  ``tr`` is the installed tracer or None: the tick's
+        own work around ``pool.step()`` is spanned by the pool's
+        pattern, one ``is None`` test per phase when tracing is off —
+        ``tick.govern`` (deadlines, the degradation ladder),
+        ``tick.observe`` (handoff sweep, gauges), ``tick.journal``
+        (flush, SLO roll, heartbeat)."""
         self._health.note_tick_start(self._clock())
         try:
-            self._expire()
-            # ladder BEFORE the pool step: it reads the alert state the
-            # previous tick's window roll produced, and a preemption it
-            # performs frees capacity THIS tick's refill can hand to
-            # waiting high-priority work — and it must also run on idle
-            # ticks, or a drained engine could never step back up
-            self._degrade_eval()
+            if tr is None:
+                self._govern()
+            else:
+                with tr.span("tick.govern"):
+                    self._govern()
             if not self._live:
-                self._observe_gauges()
+                if tr is None:
+                    self._observe_gauges()
+                else:
+                    with tr.span("tick.observe"):
+                        self._observe_gauges()
                 return False
             self._h_queue.observe(self._pool.queue_depth)
             try:
@@ -2232,24 +2293,47 @@ class ServingEngine:
                 self._health.note_error(self._clock(), e,
                                         faults.classify_error(e))
                 self._recover(e)
-            # prefill-role tick edge: export every prefill that
-            # completed this step and hand it off (no-op otherwise)
-            self._export_sweep()
-            self._observe_gauges()
+            if tr is None:
+                self._observe()
+            else:
+                with tr.span("tick.observe"):
+                    self._observe()
             return bool(self._live)
         finally:
-            # the tick's journal flush rides the same finally: commits
-            # and terminals from a recovered tick are recorded too, and
-            # a flush failure leaves records PENDING — the journal
-            # falls behind, the engine never dies for it
-            self._journal_flush()
-            # the heartbeat closes even when recovery re-raises: the
-            # loop thread dying is the DEAD-LOOP signal, not a stall —
-            # and the SLO windows roll on EVERY tick (idle included),
-            # so an alert drains while the engine sits healthy-idle
-            if self._slo is not None:
-                self._slo.note_tick()
-            self._health.note_tick_end(self._clock())
+            if tr is None:
+                self._close_tick()
+            else:
+                with tr.span("tick.journal"):
+                    self._close_tick()
+
+    def _govern(self) -> None:
+        self._expire()
+        # ladder BEFORE the pool step: it reads the alert state the
+        # previous tick's window roll produced, and a preemption it
+        # performs frees capacity THIS tick's refill can hand to
+        # waiting high-priority work — and it must also run on idle
+        # ticks, or a drained engine could never step back up
+        self._degrade_eval()
+
+    def _observe(self) -> None:
+        # prefill-role tick edge: export every prefill that
+        # completed this step and hand it off (no-op otherwise)
+        self._export_sweep()
+        self._observe_gauges()
+
+    def _close_tick(self) -> None:
+        # the tick's journal flush rides the tick's finally: commits
+        # and terminals from a recovered tick are recorded too, and
+        # a flush failure leaves records PENDING — the journal
+        # falls behind, the engine never dies for it
+        self._journal_flush()
+        # the heartbeat closes even when recovery re-raises: the
+        # loop thread dying is the DEAD-LOOP signal, not a stall —
+        # and the SLO windows roll on EVERY tick (idle included),
+        # so an alert drains while the engine sits healthy-idle
+        if self._slo is not None:
+            self._slo.note_tick()
+        self._health.note_tick_end(self._clock())
 
     def _observe_gauges(self) -> None:
         pool = self._pool
@@ -2581,14 +2665,11 @@ class ServingEngine:
                 self._journal.close()
 
     # -- tracing / flight recorder ---------------------------------------
-    def start_trace(self, capacity: int = 4096,
-                    deep_timing: bool = False) -> "trace.Tracer":
+    def start_trace(self, capacity: int = 4096) -> "trace.Tracer":
         """Build + install a process-wide tracer (serving/trace.py) and
-        bind it to this engine for export; returns it.  ``deep_timing``
-        opts into the honest-device-attribution mode (phase-edge
-        ``block_until_ready`` syncs; every span flagged ``deep``).
-        Refuses to stack on an already-installed tracer."""
-        t = trace.Tracer(capacity=capacity, deep_timing=deep_timing)
+        bind it to this engine for export; returns it.  Refuses to
+        stack on an already-installed tracer."""
+        t = trace.Tracer(capacity=capacity)
         trace.install(t)
         with self._lock:
             self._tracer = t
@@ -2629,9 +2710,8 @@ class ServingEngine:
     def export_chrome_trace(self, path: Optional[str] = None) -> str:
         """Chrome/Perfetto trace-event JSON of the flight recorder —
         one track per request (lifecycle spans closed by the terminal
-        mark) and one per tick phase, every phase span carrying its
-        ``deep`` honesty flag.  Returns the JSON string; also writes
-        ``path`` when given.  Exports the ACTIVE tracer, falling back
+        mark) and one per tick phase.  Returns the JSON string; also
+        writes ``path`` when given.  Exports the ACTIVE tracer, falling back
         to the last tracer this engine saw (so export-after-stop
         works)."""
         return trace.export_chrome_trace(
@@ -2656,14 +2736,13 @@ class ServingEngine:
 
     def flight_recorder(self) -> dict:
         """The flight recorder's full state as JSON-safe dicts — the
-        ``GET /debug/flightrec`` body: capacity, drop count, the
-        deep-timing flag, and every retained event oldest-first."""
+        ``GET /debug/flightrec`` body: capacity, drop count, and every
+        retained event oldest-first."""
         tr = self._trace_source()
         rec = tr.recorder
         return {"capacity": rec.capacity,
                 "dropped": rec.dropped,
                 "total_events": rec.total_events,
-                "deep_timing": tr.deep,
                 "events": [e.to_dict() for e in rec.snapshot()]}
 
     # -- passthroughs / introspection ------------------------------------

@@ -10,7 +10,10 @@ HTTP onto ``ServingEngine.submit`` and ``metrics.render_prometheus``:
   response STREAMS one JSON line per token (``{"token": id}``,
   ``application/x-ndjson``) the moment the batched decode step emits
   it, then one terminal line carrying the ``StreamStatus`` record
-  (state, finish reason, counts, TTFT).  A client that disconnects
+  (state, finish reason, counts; ``ttft_s`` and ``total_s`` from
+  admission, and beside them ``lock_wait_s``, which ``submit()`` stood
+  before the engine lock and which they leave out, and ``queue_wait_s``,
+  admission to the first slot).  A client that disconnects
   mid-stream gets its request CANCELLED — its slot and paged KV blocks
   go back to the allocator instead of decoding for nobody.
 - ``GET /metrics`` — the Prometheus text exposition of the engine's
@@ -31,9 +34,9 @@ HTTP onto ``ServingEngine.submit`` and ``metrics.render_prometheus``:
   (``ServingEngine.request_trace``): 400 without ``rid``, 404 for an
   unknown id or when no tracer was ever active.
 - ``GET /debug/flightrec`` — the whole flight recorder
-  (``ServingEngine.flight_recorder``): capacity, drop count, the
-  deep-timing flag, every retained event; 404 when no tracer was ever
-  active (docs/DESIGN.md §5g).
+  (``ServingEngine.flight_recorder``): capacity, drop count, every
+  retained event; 404 when no tracer was ever active (docs/DESIGN.md
+  §5g).
 
 Error mapping is the engine's typed-error vocabulary, not guesswork:
 ``InvalidArgumentError`` → 400, ``DuplicateRequestError`` → 409,
@@ -337,6 +340,8 @@ def _make_handler(engine: ServingEngine, quiet: bool = True):
                     "tokens": [int(t) for t in st.tokens],
                     "ttft_s": st.ttft_s,
                     "total_s": st.total_s,
+                    "lock_wait_s": st.lock_wait_s,
+                    "queue_wait_s": st.queue_wait_s,
                     "error": st.error,
                 }) + "\n").encode())
             except OSError:

@@ -64,11 +64,18 @@ class RequestState:
 # the terminal record delivered once per request: finish_reason is the
 # decode layer's eos/length for DONE, else the scheduler's
 # cancelled/deadline/error; ttft_s is None when the request never
-# produced a token (expired in the queue, cancelled pre-admission)
+# produced a token (expired in the queue, cancelled pre-admission).
+# ttft_s and total_s run from ADMISSION (``submit()`` holding the engine
+# lock): the wait for that lock is ``lock_wait_s``, to be added for what
+# the caller saw; ``queue_wait_s`` is admission to the first slot-take
+# (None when the request never took one).  Both are None on the
+# statuses the fleet and the disaggregated front build themselves.
 StreamStatus = collections.namedtuple(
     "StreamStatus",
     ["request_id", "state", "finish_reason", "tokens", "prompt_tokens",
-     "new_tokens", "ttft_s", "total_s", "error"])
+     "new_tokens", "ttft_s", "total_s", "error", "lock_wait_s",
+     "queue_wait_s"],
+    defaults=(None, None))
 
 _TERMINAL = object()
 

@@ -11,8 +11,9 @@ tick, and WHAT happened in the moments before a wedge.  Two pieces:
   ``serving_trace_events_dropped_total`` counter), so a recorder can run
   forever on a production engine without growing.
 - :class:`Tracer` — the emitter the instrumented code paths talk to:
-  ``span(name)`` context managers for the tick phases (admit / prefill
-  / decode step / sample / deliver) and ``instant(name)`` marks for the
+  ``span(name)`` context managers for the tick phases (govern / admit /
+  prefill / decode step / sample / deliver / observe / journal) and
+  ``submit()``'s wait for the engine lock, and ``instant(name)`` marks for the
   request lifecycle (QUEUED→PREFILLING→DECODING→terminal, plus the
   PREEMPTED detour), compile events, fault injections, recoveries,
   shed decisions, supervisor stall/restart actions, and the
@@ -26,18 +27,18 @@ global against ``None`` (or call :func:`instant`, which does exactly
 that), so the decode tick pays nothing and the ``tools/analysis``
 host-sync rule stays clean when no tracer is installed.
 
-**Deep-timing honesty contract.**  By default spans time HOST-side
-dispatch: an async decode dispatch returns before the device finishes,
-so a phase span brackets python work plus whatever sync the phase
-already contains (the per-tick token download is one).  "Operator
-Fusion in XLA" (PAPERS.md) is blunt about this: host-side phase
-attribution is meaningless unless spans are synced at the boundaries
-the compiler actually honors.  ``Tracer(deep_timing=True)`` therefore
-makes the instrumented phases call ``jax.block_until_ready`` at their
-edges — honest device attribution, bought with lost pipelining — and
-EVERY exported span carries its ``deep`` flag, so a trace can never
-present dispatch time as device time (the flag is the tools/analysis
-``unblocked-timing`` discipline, applied to traces).
+**Spans time the HOST.**  An async decode dispatch returns before the
+device finishes, so a phase span brackets python work plus whatever
+sync the phase already contains (the per-tick token download is one).
+Device time is read from the profiler's device trace, never from a
+span — and so that the two can be read together, every span is ALSO a
+``jax.profiler.TraceAnnotation`` of the same name (meta as keyword
+stats): a profile anyone takes with ``jax.profiler`` while a tracer is
+installed holds ``tick``, ``tick.*`` and ``submit.lock_wait`` in its
+``/host:`` plane, on the trace's own clock, beside the device
+operations (whose ``op_name`` carries the module tree: ``Layer.__call__``
+runs under ``jax.named_scope``).  With no profiler session an annotation
+costs one flag test.
 
 Export: :func:`export_chrome_trace` converts a recorder snapshot to
 Chrome/Perfetto trace-event JSON — one track per request (lifecycle
@@ -56,6 +57,8 @@ import threading
 import time
 from collections import deque
 from typing import List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..core.errors import InvalidArgumentError, PreconditionNotMetError
 from ..profiler.visual import chrome_trace_json
@@ -85,19 +88,16 @@ TERMINAL_EVENTS = frozenset((
 
 class TraceEvent:
     """One recorded event.  ``dur_s`` is None for instant marks; spans
-    carry their duration plus the ``deep`` honesty flag of the tracer
-    that timed them.  ``rid`` ties an event to a request (None for
+    carry their duration.  ``rid`` ties an event to a request (None for
     engine-/tick-scoped events); ``meta`` is a small JSON-safe dict."""
 
-    __slots__ = ("ts", "name", "rid", "dur_s", "deep", "meta")
+    __slots__ = ("ts", "name", "rid", "dur_s", "meta")
 
-    def __init__(self, ts, name, rid=None, dur_s=None, deep=False,
-                 meta=None):
+    def __init__(self, ts, name, rid=None, dur_s=None, meta=None):
         self.ts = ts
         self.name = name
         self.rid = rid
         self.dur_s = dur_s
-        self.deep = deep
         self.meta = meta
 
     def to_dict(self) -> dict:
@@ -106,7 +106,6 @@ class TraceEvent:
             out["rid"] = self.rid
         if self.dur_s is not None:
             out["dur_s"] = self.dur_s
-            out["deep"] = bool(self.deep)
         if self.meta:
             out["meta"] = self.meta
         return out
@@ -176,9 +175,11 @@ class _Span:
     """The span context manager ``Tracer.span`` hands out: times the
     block on the tracer's clock and records ONE complete event at exit
     (start timestamp + duration), so a span costs two clock reads and
-    one ring append."""
+    one ring append — plus the ``TraceAnnotation`` twin that puts the
+    same span into a running ``jax.profiler`` trace (one flag test when
+    no profiler session is on)."""
 
-    __slots__ = ("_tr", "_name", "_rid", "_meta", "_t0")
+    __slots__ = ("_tr", "_name", "_rid", "_meta", "_t0", "_ann")
 
     def __init__(self, tr, name, rid, meta):
         self._tr = tr
@@ -186,14 +187,26 @@ class _Span:
         self._rid = rid
         self._meta = meta
 
+    def set(self, **meta) -> None:
+        """Add what the block learned while it ran (how many slots were
+        live, how many requests the tick admitted) to the span's meta
+        and to its annotation's stats."""
+        self._meta.update(meta)
+        self._ann.set_metadata(**meta)
+
     def __enter__(self):
+        stats = self._meta if self._rid is None \
+            else dict(self._meta, rid=str(self._rid))
+        self._ann = TraceAnnotation(self._name, **stats)
+        self._ann.__enter__()
         self._t0 = self._tr._clock()
         return self
 
     def __exit__(self, *exc):
         tr = self._tr
-        tr._emit(TraceEvent(self._t0, self._name, self._rid,
-                            tr._clock() - self._t0, tr.deep,
+        dur = tr._clock() - self._t0
+        self._ann.__exit__(*exc)
+        tr._emit(TraceEvent(self._t0, self._name, self._rid, dur,
                             self._meta or None))
         return False
 
@@ -202,18 +215,12 @@ class Tracer:
     """The emitter instrumented code talks to; owns one
     :class:`FlightRecorder`.
 
-    ``deep_timing=True`` is the opt-in honest-device-attribution mode:
-    the instrumented phases sync (``jax.block_until_ready``) at their
-    edges, and every span this tracer records carries ``deep=True`` so
-    the export can never pass dispatch time off as device time.
     ``clock`` defaults to ``time.perf_counter`` — ALL trace timestamps
     live in this one clock domain, so cross-event ordering is
     meaningful even on engines driven by an injected deadline clock."""
 
-    def __init__(self, capacity: int = 4096, deep_timing: bool = False,
-                 clock=None):
+    def __init__(self, capacity: int = 4096, clock=None):
         self.recorder = FlightRecorder(capacity)
-        self.deep = bool(deep_timing)
         self._clock = clock if clock is not None else time.perf_counter
         self._ticks = 0
 
@@ -244,11 +251,12 @@ class Tracer:
     def instant(self, name: str, rid=None, **meta) -> None:
         """Record a point event (lifecycle transition, compile, fault
         injection, recovery, shed, stall, restart)."""
-        self._emit(TraceEvent(self._clock(), name, rid, None, self.deep,
+        self._emit(TraceEvent(self._clock(), name, rid, None,
                               meta or None))
 
     def span(self, name: str, rid=None, **meta) -> _Span:
-        """Context manager timing one tick phase (or any block)."""
+        """Context manager timing one tick phase (or any block); the
+        span it yields takes late meta through ``set(**meta)``."""
         return _Span(self, name, rid, meta)
 
     def _emit(self, event: TraceEvent) -> None:
@@ -318,8 +326,7 @@ def to_chrome_events(events: List[TraceEvent]) -> List[dict]:
     mark closes the last one and lands as its own instant — so a
     drained/shut-down engine exports timelines with no open spans; a
     request still live at export time gets its trailing span flagged
-    ``"open": true`` instead of silently truncated.  Every phase span
-    carries its ``deep`` honesty flag in ``args``.  Events are sorted
+    ``"open": true`` instead of silently truncated.  Events are sorted
     by timestamp per track (monotonic within every (pid, tid))."""
     evs = sorted(events, key=lambda e: e.ts)
     out: List[dict] = []
@@ -360,7 +367,6 @@ def to_chrome_events(events: List[TraceEvent]) -> List[dict]:
             args["rid"] = e.rid if isinstance(e.rid, (str, int, float)) \
                 else str(e.rid)
         if e.dur_s is not None:
-            args["deep"] = bool(e.deep)
             out.append({"name": e.name, "ph": "X", "cat": "phase",
                         "pid": 0, "tid": phase_tid(e.name),
                         "ts": e.ts * 1e6,

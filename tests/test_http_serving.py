@@ -143,6 +143,11 @@ def test_post_generate_streams_tokens_and_status(model):
     assert final["finish_reason"] == "length"
     assert final["tokens"] == toks and len(toks) == 5
     assert final["prompt_tokens"] == 6 and final["new_tokens"] == 5
+    # the request's own timeline rides the terminal line with no tracer
+    # installed: the wait for the engine lock, which ttft_s and total_s
+    # leave out, and the wait for a slot, which they include
+    assert final["lock_wait_s"] >= 0
+    assert 0 <= final["queue_wait_s"] <= final["ttft_s"] <= final["total_s"]
     # token-identical to the engine-free baseline
     from paddle_tpu.jit import DecodeSession
     want = DecodeSession(model, max_len=64, buckets=[16]).generate(
@@ -228,6 +233,8 @@ def test_get_metrics_renders_prometheus(model):
     assert headers["Content-Type"].startswith("text/plain")
     text = payload.decode()
     assert "# TYPE serving_ttft_seconds histogram" in text
+    assert "# TYPE serving_submit_lock_wait_seconds histogram" in text
+    assert "serving_submit_lock_wait_seconds_count 1" in text
     assert "serving_requests_completed_total 1" in text
     assert text == eng.metrics.render_prometheus()
 
@@ -403,17 +410,25 @@ def test_debug_trace_and_flightrec_endpoints(model):
         assert code == 200
         tl = json.loads(payload)
         names = [e["name"] for e in tl["events"]]
-        assert names[0] == "req.queued" and names[-1] == "req.done"
+        # the caller's own id ties the wait for the engine lock to
+        # the request: its timeline begins before it is queued
+        assert names[:2] == ["submit.lock_wait", "req.queued"]
+        assert names[-1] == "req.done"
         # missing rid -> 400; unknown rid -> 404
         code, _, payload = _http(eng, "GET", "/debug/trace")
         assert code == 400 and b"rid" in payload
         assert _http(eng, "GET", "/debug/trace?rid=ghost")[0] == 404
-        # the whole recorder, with its bounds and honesty flags
+        # the lock wait is on the queued mark of the timeline too
+        assert tl["events"][1]["meta"]["lock_wait_s"] >= 0
+        # the whole recorder, with its bounds
         code, _, payload = _http(eng, "GET", "/debug/flightrec")
         assert code == 200
         rec = json.loads(payload)
-        assert rec["capacity"] == 512 and rec["deep_timing"] is False
+        assert rec["capacity"] == 512 and "deep_timing" not in rec
         assert rec["dropped"] == 0 and rec["events"]
+        spans = {e["name"] for e in rec["events"] if "dur_s" in e}
+        assert {"submit.lock_wait", "tick", "tick.govern",
+                "tick.observe", "tick.journal"} <= spans
     finally:
         eng.stop_trace()
     # the engine keeps the last tracer: export still served post-stop

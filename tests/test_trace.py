@@ -16,11 +16,19 @@ The contracts pinned here, in order of load-bearing-ness:
    request timelines;
 4. the ring is bounded and its overflow observable
    (``serving_trace_events_dropped_total``);
-5. the deep-timing honesty flag rides every span;
+5. the request's own timeline (``lock_wait_s``, ``queue_wait_s``) is on
+   its status with NO tracer installed, and a tracer adds the
+   ``submit.lock_wait`` span; the tick says what it did (``live``,
+   ``queued``/``admitted``/``finished``, ``bucket``) and its own work
+   has phases; every span is a ``jax.profiler.TraceAnnotation`` too;
 6. terminal trace events exist for every request after drain/shutdown
-   (timelines never end mid-span).
+   (timelines never end mid-span);
+7. every compiled step carries the module tree and the hand-placed
+   scopes in its operations' ``op_name``, at unchanged compile counts.
 """
 import json
+import re
+import threading
 
 import numpy as np
 import pytest
@@ -79,10 +87,19 @@ def _prompts(n=3, seed=0):
 
 # -- 1. tracing off is a true no-op ---------------------------------------
 
-def test_trace_off_buffer_untouched(model):
+def test_trace_off_buffer_untouched(model, monkeypatch):
     tracer = Tracer(capacity=64)  # built but never installed
+    # with no tracer, submit(), pool.step() and _run_tick build no span
+    # and no profiler annotation: constructing either one fails the run
+    built = []
+    monkeypatch.setattr(trace._Span, "__init__",
+                        lambda self, *a: built.append(a))
+    monkeypatch.setattr(trace, "TraceAnnotation",
+                        lambda *a, **k: built.append(a))
     eng = _engine(model)
-    _run(eng, _prompts(2), 5)
+    statuses = _run(eng, _prompts(2), 5)
+    assert all(st.state == RequestState.DONE for st in statuses)
+    assert built == []
     assert len(tracer.recorder) == 0
     assert tracer.recorder.total_events == 0
     assert tracer.recorder.dropped == 0
@@ -112,7 +129,8 @@ def test_lifecycle_and_phase_events(model):
     evs = tracer.recorder.snapshot()
     names = {e.name for e in evs}
     for phase in ("tick", "tick.admit", "tick.prefill", "tick.decode",
-                  "tick.sample", "tick.deliver"):
+                  "tick.sample", "tick.deliver", "tick.govern",
+                  "tick.observe", "tick.journal"):
         assert phase in names, phase
     # per-request lifecycle in timestamp order
     for st in statuses:
@@ -122,32 +140,284 @@ def test_lifecycle_and_phase_events(model):
                         "req.done"]
         ts = [e.ts for e in mine]
         assert ts == sorted(ts)
-    # spans carry durations and the (off) deep flag; ticks are numbered
+    # the lock wait rides the queued mark, as it rides the status
+    queued = {e.rid: e.meta for e in evs if e.name == "req.queued"}
+    for st in statuses:
+        assert queued[st.request_id]["lock_wait_s"] == st.lock_wait_s >= 0
+    # spans carry durations; ticks are numbered
     spans = [e for e in evs if e.dur_s is not None]
     assert spans and all(e.dur_s >= 0 for e in spans)
-    assert all(e.deep is False for e in spans)
+    assert all("deep" not in e.to_dict() for e in spans)
     ticks = [e.meta["tick"] for e in evs if e.name == "tick"]
     assert ticks == list(range(1, len(ticks) + 1))
     # the cold engine's compiles surfaced as compile events
     assert "compile" in names
 
 
-def test_deep_timing_flag_rides_every_span(model):
+# -- the request's timeline, tracer or not --------------------------------
+
+def test_status_carries_lock_and_queue_wait_without_a_tracer(model):
+    eng = _engine(model, slots=1)
+    statuses = _run(eng, _prompts(3), 4)
+    assert trace.active() is None and eng._tracer is None
+    for st in statuses:
+        assert st.state == RequestState.DONE
+        assert st.lock_wait_s >= 0 and st.queue_wait_s >= 0
+        # ttft_s runs from admission: the queue wait lies inside it
+        assert st.queue_wait_s <= st.ttft_s <= st.total_s
+    hist = eng.metrics.snapshot()["serving_submit_lock_wait_seconds"]
+    assert hist["count"] == 3
+
+
+def test_queue_wait_is_none_for_a_request_that_never_took_a_slot(model):
+    eng = _engine(model, slots=1)
+    first = eng.submit(_prompts(1)[0], 4)
+    waiting = eng.submit(_prompts(2)[1], 4, request_id="never")
+    assert eng.cancel("never")
+    st = waiting.result(timeout_s=0)
+    assert st.state == RequestState.CANCELLED
+    assert st.queue_wait_s is None and st.lock_wait_s >= 0
+    while eng.pump(8):
+        pass
+    assert first.result(timeout_s=0).queue_wait_s >= 0
+    # cancel()'s own wait for the lock goes to the same histogram
+    assert eng.metrics.snapshot()[
+        "serving_submit_lock_wait_seconds"]["count"] == 3
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_submit_behind_a_held_lock_reports_the_wait(model, traced):
     eng = _engine(model)
-    tracer = eng.start_trace(capacity=1024, deep_timing=True)
+    tracer = eng.start_trace(capacity=256) if traced else None
+    held, release = threading.Event(), threading.Event()
+
+    def hold():
+        with eng._lock:
+            held.set()
+            release.wait(5.0)
+
+    holder = threading.Thread(target=hold)
+    holder.start()
+    assert held.wait(5.0)
+    # held for 80 ms from here: submit() enters within a few of them, so
+    # it waits the 50 ms asserted below with room for a slow thread start
+    threading.Timer(0.08, release.set).start()
     try:
-        statuses = _run(eng, _prompts(1), 4)
+        stream = eng.submit(_prompts(1)[0], 3, request_id="late")
+        holder.join()
+        while eng.pump(8):
+            pass
+    finally:
+        release.set()
+        if traced:
+            eng.stop_trace()
+    st = stream.result(timeout_s=0)
+    assert st.state == RequestState.DONE and st.lock_wait_s >= 0.05
+    if traced:
+        waits = [e for e in tracer.recorder.snapshot()
+                 if e.name == "submit.lock_wait"]
+        assert [e.rid for e in waits] == ["late"]
+        assert waits[0].dur_s >= 0.05
+        assert abs(waits[0].dur_s - st.lock_wait_s) < 0.02
+
+
+# -- the tick says what it did ---------------------------------------------
+
+@pytest.mark.parametrize("speculative", [False, True])
+def test_tick_meta_says_what_the_tick_did(model, speculative):
+    kw = {}
+    if speculative:
+        pt.seed(1)
+        kw = dict(draft_model=_tiny_model(), spec_k=3)
+    eng = _engine(model, buckets=[8, 32], **kw)
+    tracer = eng.start_trace(capacity=4096)
+    try:
+        statuses = _run(eng, _prompts(5), 6)
     finally:
         eng.stop_trace()
-    assert statuses[0].state == RequestState.DONE
+    assert all(st.state == RequestState.DONE for st in statuses)
+    evs = tracer.recorder.snapshot()
+    decode = [e.meta for e in evs if e.name == "tick.decode"]
+    assert decode and all(1 <= m["live"] <= m["slots"] == 2
+                          for m in decode)
+    assert max(m["live"] for m in decode) == 2
+    ticks = [e.meta for e in evs if e.name == "tick"]
+    assert sum(m["admitted"] for m in ticks) == 5
+    assert sum(m["finished"] for m in ticks) == 5
+    # five submitted before the first tick on two slots: it finds five
+    # queued, and the queue only drains from there
+    depths = [m["queued"] for m in ticks]
+    assert depths[0] == 5 and depths == sorted(depths, reverse=True)
+    prefill = [e.meta for e in evs if e.name == "tick.prefill"]
+    assert len(prefill) == 5
+    assert all(m["bucket"] >= m["prompt_tokens"] for m in prefill)
+    # prompts of 5, 9, 7, 4, 6 tokens on buckets of 8 and 32
+    assert sorted(m["bucket"] for m in prefill) == [8, 8, 8, 8, 32]
+
+
+def test_housekeeping_phases_lie_inside_their_tick(model):
+    eng = _engine(model)
+    tracer = eng.start_trace(capacity=2048)
+    try:
+        _run(eng, _prompts(2), 4)
+        eng.pump(1)                 # an idle tick has the phases too
+    finally:
+        eng.stop_trace()
+    evs = tracer.recorder.snapshot()
+    ticks = [(e.ts, e.ts + e.dur_s) for e in evs if e.name == "tick"]
+    for phase in ("tick.govern", "tick.observe", "tick.journal"):
+        mine = [e for e in evs if e.name == phase]
+        assert len(mine) == len(ticks), phase
+        for e in mine:
+            assert any(a <= e.ts and e.ts + e.dur_s <= b
+                       for a, b in ticks), phase
+    # and in a tick's order: govern, the pool's phases, observe, journal
+    order = [e.name for e in evs
+             if e.dur_s is not None and e.name != "tick"
+             and ticks[0][0] <= e.ts < ticks[0][1]]
+    assert order[0] == "tick.govern" and order[-1] == "tick.journal"
+    assert order.index("tick.observe") > order.index("tick.admit")
+
+
+def test_span_set_adds_late_meta():
+    clock = iter([1.0, 3.5])
+    tr = Tracer(capacity=4, clock=lambda: next(clock))
+    with tr.span("tick", tick=7) as span:
+        span.set(admitted=2, finished=1)
+    (ev,) = tr.recorder.snapshot()
+    assert (ev.ts, ev.dur_s) == (1.0, 2.5)
+    assert ev.meta == {"tick": 7, "admitted": 2, "finished": 1}
+
+
+def test_every_span_is_a_profiler_annotation_too(model, monkeypatch):
+    seen = []
+
+    class Annotation:
+        def __init__(self, name, **stats):
+            self.entry = [name, dict(stats), "built"]
+            seen.append(self.entry)
+
+        def set_metadata(self, **stats):
+            self.entry[1].update(stats)
+
+        def __enter__(self):
+            self.entry[2] = "open"
+
+        def __exit__(self, *exc):
+            self.entry[2] = "closed"
+
+    monkeypatch.setattr(trace, "TraceAnnotation", Annotation)
+    eng = _engine(model)
+    tracer = eng.start_trace(capacity=1024)
+    try:
+        eng.submit(_prompts(1)[0], 3, request_id="r0")
+        while eng.pump(8):
+            pass
+    finally:
+        eng.stop_trace()
     spans = [e for e in tracer.recorder.snapshot() if e.dur_s is not None]
-    assert spans and all(e.deep is True for e in spans)
-    # and in the export: every phase span's args say deep=true
-    d = json.loads(eng.export_chrome_trace())
-    phase_spans = [e for e in d["traceEvents"]
-                   if e.get("ph") == "X" and e.get("cat") == "phase"]
-    assert phase_spans
-    assert all(e["args"]["deep"] is True for e in phase_spans)
+    assert [n for n, _, _ in seen] == [e.name for e in sorted(
+        spans, key=lambda e: e.ts)]
+    assert all(state == "closed" for _, _, state in seen)
+    by_name = {}
+    for name, stats, _ in seen:
+        by_name.setdefault(name, stats)
+    assert by_name["submit.lock_wait"] == {"rid": "r0"}
+    assert by_name["tick.decode"] == {"live": 1, "slots": 2}
+    assert by_name["tick.prefill"] == {"rid": "r0", "prompt_tokens": 5,
+                                       "bucket": 32}
+    assert by_name["tick"] == {"tick": 1, "queued": 1, "admitted": 1,
+                               "finished": 0}
+
+
+# -- scope names in the compiled steps ---------------------------------------
+
+def _two_layer(causal):
+    pt.seed(0)
+    return TransformerLM(vocab_size=64, hidden_size=32, num_layers=2,
+                         num_heads=2, intermediate_size=64,
+                         max_position=64, causal=causal, dropout=0.0)
+
+
+def _scopes(text):
+    """The ``op_name`` of every operation in a compiled or lowered
+    step's text, less its last part (the primitive)."""
+    found = re.findall(r'op_name="([^"]*)"', text) \
+        or re.findall(r'loc\("([^"]*)"', text)
+    return {n.rsplit("/", 1)[0] for n in found if "/" in n}
+
+
+def test_pool_decode_carries_the_module_tree_and_hand_scopes():
+    from paddle_tpu.inference import GenerationPool
+    pool = GenerationPool(_two_layer(True), max_len=32, slots=2,
+                          cache_layout="paged", block_size=8,
+                          num_blocks=16, buckets=[8])
+    pool.submit(np.arange(5, dtype=np.int32), 4)
+    pool.run()
+    # metadata only: the compile counts are what they were
+    assert pool.compile_counts() == {"prefill": 1, "decode": 0,
+                                     "pool_decode": 1, "slot_insert": 1}
+    (exe,) = pool._decode_jit._exes.values()
+    scopes = _scopes(exe.as_text())
+    for want in ("jit(_pool_decode)/lm_head", "jit(_pool_decode)/sample",
+                 "jit(_pool_decode)/encoder/layers/1/self_attn/q_proj",
+                 "jit(_pool_decode)/encoder/layers/0/linear2",
+                 "jit(_pool_decode)/final_norm"):
+        assert want in scopes, want
+    assert any(s.startswith("jit(_pool_decode)/cache_freeze")
+               for s in scopes)
+    (pre,) = pool._session._prefill_jit._exes.values()
+    assert "jit(_prefill)/sample" in _scopes(pre.as_text())
+
+
+def test_train_step_carries_loss_optimizer_and_layer_scopes():
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models import TransformerLMCriterion
+    model = _two_layer(False)
+    crit = TransformerLMCriterion(shift_labels=False)
+    opt = pt.optimizer.AdamW(1e-3, parameters=model.parameters())
+    step = TrainStep(model, lambda m, ids, lab: crit(m(ids), lab), opt)
+    lowered = []
+    jitted = step._jitted
+
+    def spy(*args):
+        lowered.append(jitted.lower(*args).as_text(debug_info=True))
+        return jitted(*args)
+
+    step._jitted = spy
+    ids = pt.to_tensor(np.arange(16, dtype=np.int32).reshape(2, 8) % 64)
+    step(ids, ids)
+    step(ids, ids)
+    assert jitted._cache_size() == 1            # one compile, as before
+    scopes = _scopes(lowered[0])
+    for want in ("jit(_step)/optimizer", "jit(_step)/jvp(loss)",
+                 "jit(_step)/jvp(loss)/lm_head",
+                 "jit(_step)/jvp(loss)/encoder/layers/1/linear1",
+                 # backward operations keep their forward scope
+                 "jit(_step)/transpose(jvp(loss))/lm_head",
+                 "jit(_step)/transpose(jvp(loss))/encoder/layers/0/"
+                 "self_attn/k_proj"):
+        assert want in scopes, want
+
+
+def test_iterated_containers_pass_their_scope_to_their_members():
+    from paddle_tpu import nn
+
+    class Net(nn.Layer):
+        def __init__(self):
+            super().__init__()
+            blocks = nn.LayerList([nn.Linear(2, 2)])
+            blocks.append(nn.Linear(2, 2))      # before it has a name
+            self.blocks = blocks
+            self.blocks.append(nn.Linear(2, 2))     # and after
+            self.head = nn.Sequential(nn.Linear(2, 2))
+
+    net = Net()
+    assert net._scope is None                   # a root has no scope
+    assert [b._scope for b in net.blocks] == ["blocks/0", "blocks/1",
+                                              "blocks/2"]
+    # a Sequential is called, so it opens its own scope around "0"
+    assert (net.head._scope, net.head[0]._scope) == ("head", "0")
 
 
 # -- ring bounds + drop observability -------------------------------------

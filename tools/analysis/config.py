@@ -218,7 +218,8 @@ EXTRA_EDGES = {
     # the fault plane's
     "instant": ("Tracer.instant",),
     "ServingEngine._run_tick_traced": ("Tracer.span", "Tracer.instant"),
-    "Tracer.span": ("_Span.__enter__", "_Span.__exit__"),
+    "ServingEngine.submit": ("Tracer.span",),
+    "Tracer.span": ("_Span.__enter__", "_Span.__exit__", "_Span.set"),
     "_Span.__exit__": ("Tracer._emit",),
     "Tracer.instant": ("Tracer._emit",),
     "Tracer._emit": ("FlightRecorder.append",),
@@ -238,7 +239,8 @@ EXTRA_EDGES = {
     # behind is-None guards; the tracker's own emission (alert flips
     # into the trace + structured log) is declared so the whole seam
     # is hot-path-audited like the fault/trace planes
-    "ServingEngine._run_tick": ("SLOTracker.note_tick",),
+    "ServingEngine._run_tick": ("Tracer.span",),
+    "ServingEngine._close_tick": ("SLOTracker.note_tick",),
     "ServingEngine._on_token": ("SLOTracker.observe_latency",),
     "SLOTracker.note_tick": ("_ObjectiveState.roll", "instant",
                              "emit"),

@@ -2,7 +2,10 @@
 
 ``chip_smoke.py``, ``bench.py`` and the ``tools/*.py`` scripts that
 touch JAX call :func:`ensure_compile_cache` before their first compile.
-``import paddle_tpu`` never does: a library import configures nothing.
+``import paddle_tpu`` never does: a library import chooses no directory.
+(It does put metadata into the cache's key, ``nn/layer/layers.py``: the
+named scopes a profile is read by are metadata, and an executable cached
+under other names must not be served for this tree.)
 
 The cache directory is part of the cache key's environment, so it must
 not move between runs:
