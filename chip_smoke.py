@@ -13,7 +13,8 @@ comes out by the repo's own means.  Phases:
            round, 16 ``POST /generate`` over loopback, every stream
            ``DONE``, zero recoveries, the compile-count contract, tokens
            against ``DecodeSession.generate`` and cached against
-           uncached logits (margin-gated);
+           uncached logits (margin-gated); the optimized program of
+           the engine's decode step holds no copy of a K/V pool;
 - train    BERT-base bf16 O2 ``TrainStep``, batch 40 x 512, five steps,
            finite falling loss;
 - kernels  the fused decode kernels forced by ``route="pallas"`` on a
@@ -43,6 +44,7 @@ import gc
 import importlib
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -160,6 +162,43 @@ def check_kernel_in_program(text: str, platform: str, what: str) -> None:
     check(any(m in text for m in kernel_markers(platform)),
           "%s: route='pallas' was asked for but none of %r is in the "
           "compiled decode program" % (what, kernel_markers(platform)))
+
+
+_HLO_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", re.M)
+
+
+def pool_shaped_ops(text: str, pool_shape) -> list:
+    """``(name, opcode)`` of every instruction in a compiled program's
+    text, fused computations included, whose result has the shape
+    ``pool_shape`` (any element type, any layout)."""
+    dims = ",".join(str(int(n)) for n in pool_shape)
+    return [(name, op) for name, shape, op in _HLO_RESULT.findall(text)
+            if shape == dims]
+
+
+def pool_shaped_moves(text: str, pool_shape) -> list:
+    """The names of the ``copy`` and ``transpose`` instructions whose
+    result has a K/V pool's shape.  The step writes a few rows of a
+    donated pool, so every such instruction moves the whole pool for
+    nothing: the scatter that kept ``H`` as a window dimension cost four
+    a layer (``ops.flash_attention.paged_cache_write``)."""
+    return [name for name, op in pool_shaped_ops(text, pool_shape)
+            if op in ("copy", "transpose")]
+
+
+def check_no_pool_moves(text: str, pool_shape, platform: str,
+                        what: str) -> None:
+    """On the TPU the decode program may not copy or re-lay a pool.  The
+    CPU backend donates nothing, so there the step's own copy of each
+    pool is counted and said, not judged."""
+    moves = pool_shaped_moves(text, pool_shape)
+    say("[%s] %d copy/transpose instruction(s) with a K/V pool's shape "
+        "%r in the optimized decode program"
+        % (what, len(moves), tuple(pool_shape)))
+    check(platform != "tpu" or not moves,
+          "%s: the decode program moves a whole K/V pool %d time(s): %s"
+          % (what, len(moves), ", ".join(moves[:8])))
 
 
 def check_greedy_against_logits(tokens, logits, gate: float,
@@ -356,6 +395,10 @@ def serve_requests(jax, model, sz: dict, mesh=None,
                jax.devices()[0].device_kind))
         cache_arrays = [a for c in engine._pool._cache
                         for a in (c.k, c.v)]
+        if mesh is None:
+            check_no_pool_moves(_decode_program_text(engine._pool),
+                                cache_arrays[0].shape,
+                                jax.devices()[0].platform, tag)
     finally:
         if front is not None:
             front.shutdown()
@@ -567,7 +610,11 @@ def _kernel_variant(jax, model, sz: dict, layout: str, dtype: str,
     # the session's real decode step, forced onto the kernel
     cache, tok, samp = sess._decode_jit(params, bufs, cache, tok, samp)
     jax.block_until_ready(tok)
-    check_kernel_in_program(_decode_program_text(sess), platform, what)
+    text = _decode_program_text(sess)
+    check_kernel_in_program(text, platform, what)
+    if layout == "paged":
+        check_no_pool_moves(text, cache[0].k.shape, platform,
+                            "kernels " + what)
     say("[kernels] %-13s Lq=1 and Lq=5 compiled under %s, kernel found in "
         "the decode program, max |diff| vs composition %.3g of scale "
         "(tolerance %.3g)"

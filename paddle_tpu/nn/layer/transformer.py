@@ -362,7 +362,8 @@ class MultiHeadAttention(Layer):
         import jax.numpy as jnp
 
         from ...framework.tensor import Tensor as _T
-        from ...ops.flash_attention import (paged_decode_attention,
+        from ...ops.flash_attention import (paged_cache_write,
+                                            paged_decode_attention,
                                             quantize_kv)
 
         def raw(x):
@@ -391,20 +392,10 @@ class MultiHeadAttention(Layer):
         s = table.shape[1] * bs
         if idx.ndim == 0:
             # aligned batch (DecodeSession): every row writes the same
-            # chunk positions; one scatter over [B, L] (pos, block) pairs
-            pos = idx + jnp.arange(length)                      # [L]
-            phys = table[:, pos // bs]                          # [B, L]
-            off = jnp.broadcast_to((pos % bs)[None, :], (b, length))
-            k_pool = k_pool.at[phys, :, off, :].set(
-                k_new.transpose(0, 2, 1, 3).astype(k_pool.dtype))
-            v_pool = v_pool.at[phys, :, off, :].set(
-                v_new.transpose(0, 2, 1, 3).astype(v_pool.dtype))
-            if quant:
-                ks_pool = ks_pool.at[phys, :, off].set(
-                    k_s.transpose(0, 2, 1))
-                vs_pool = vs_pool.at[phys, :, off].set(
-                    v_s.transpose(0, 2, 1))
-            q_pos = pos                                         # [L]
+            # chunk positions through its own table row
+            q_pos = idx + jnp.arange(length)                    # [L]
+            phys = table[:, q_pos // bs]                        # [B, L]
+            off = jnp.broadcast_to((q_pos % bs)[None, :], (b, length))
         else:
             # slot-batched decode/verify: each row writes its L-token
             # chunk at its OWN position, addressed through ITS table row
@@ -413,20 +404,15 @@ class MultiHeadAttention(Layer):
             # scratch block — the same masking discipline as slot churn
             # — so a speculative tail can never clamp onto a real block.
             rows = jnp.arange(b)[:, None]                       # [B,1]
-            pos = idx[:, None] + jnp.arange(length)[None, :]    # [B,L]
-            logical = jnp.minimum(pos // bs, table.shape[1] - 1)
-            phys = jnp.where(pos < s, table[rows, logical], 0)  # [B,L]
-            off = pos % bs
-            k_pool = k_pool.at[phys, :, off, :].set(
-                k_new.transpose(0, 2, 1, 3).astype(k_pool.dtype))
-            v_pool = v_pool.at[phys, :, off, :].set(
-                v_new.transpose(0, 2, 1, 3).astype(v_pool.dtype))
-            if quant:
-                ks_pool = ks_pool.at[phys, :, off].set(
-                    k_s.transpose(0, 2, 1))
-                vs_pool = vs_pool.at[phys, :, off].set(
-                    v_s.transpose(0, 2, 1))
-            q_pos = pos                                         # [B,L]
+            q_pos = idx[:, None] + jnp.arange(length)[None, :]  # [B,L]
+            logical = jnp.minimum(q_pos // bs, table.shape[1] - 1)
+            phys = jnp.where(q_pos < s, table[rows, logical], 0)
+            off = q_pos % bs
+        k_pool = paged_cache_write(k_pool, k_new, phys, off)
+        v_pool = paged_cache_write(v_pool, v_new, phys, off)
+        if quant:
+            ks_pool = paged_cache_write(ks_pool, k_s, phys, off)
+            vs_pool = paged_cache_write(vs_pool, v_s, phys, off)
         # masking travels in index form (see _decode_forward): the
         # composition rebuilds the inline additive mask op-for-op; the
         # fused route walks the table in-kernel and masks in-register

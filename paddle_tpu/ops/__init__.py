@@ -16,6 +16,7 @@ from .flash_attention import (  # noqa: F401
     flash_attention,
     flash_attention_supported,
     normalize_decode_route,
+    paged_cache_write,
     paged_decode_attention,
     paged_decode_attention_supported,
     quantize_kv,
@@ -29,7 +30,7 @@ from .pallas_decode import (  # noqa: F401
 __all__ = ["flash_attention", "flash_attention_supported",
            "decode_attention", "decode_attention_supported",
            "paged_decode_attention", "paged_decode_attention_supported",
-           "quantize_kv", "dequantize_kv",
+           "paged_cache_write", "quantize_kv", "dequantize_kv",
            "decode_attention_kernel", "paged_decode_attention_kernel",
            "decode_route", "normalize_decode_route", "DECODE_ROUTES",
            "reset_backend_memo"]

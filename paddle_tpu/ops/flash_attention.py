@@ -27,7 +27,7 @@ from ..core.errors import InvalidArgumentError
 __all__ = ["flash_attention", "flash_attention_supported",
            "decode_attention", "decode_attention_supported",
            "paged_decode_attention", "paged_decode_attention_supported",
-           "quantize_kv", "dequantize_kv",
+           "paged_cache_write", "quantize_kv", "dequantize_kv",
            "decode_route", "normalize_decode_route", "DECODE_ROUTES",
            "reset_backend_memo"]
 
@@ -490,6 +490,36 @@ def paged_decode_attention_supported(q_shape, block_size: int,
         return False
     return mosaic_refusal(q_shape[3], block_size,
                           block_size * num_blocks) is None
+
+
+def paged_cache_write(pool, new, phys, off):
+    """Write a chunk's rows into a block pool where the pool lies.
+
+    ``pool``: ``[num_blocks, H, block_size, D]`` K or V pool, or the
+    ``[num_blocks, H, block_size]`` scale pool of an int8 cache.
+    ``new``: the chunk, ``[B, H, L, D]`` (scales ``[B, H, L]``), cast to
+    the pool's dtype.  ``phys``/``off``: ``[B, L]`` int32, the physical
+    block and the offset inside it of row ``b``'s position ``l``.
+    Returns the pool with ``pool[phys[b, l], h, off[b, l]] = new[b, h,
+    l]`` for every ``(b, h, l)``.
+
+    Every dimension but the minor-most is INDEXED, so the scatter's
+    window is ``D`` alone (empty for a scale pool).  A TPU scatter wants
+    its window dimensions minor-most: ``D`` is that already in the
+    row-major layout the fused kernel (``_paged_call``) is pinned to,
+    so the donated pool is updated in place and handed on as it lies.
+    Leaving ``H`` as a window dimension (``pool.at[phys, :, off, :]``)
+    makes layout assignment want ``{3,1,2,0}`` and copy the whole pool
+    there and back for sixteen rows (docs/DESIGN.md §5b).
+
+    An index outside the pool is DROPPED, never clamped onto a live
+    block.  Indices may repeat (inactive slots all write the scratch
+    block, several at one offset): which row lands there is not
+    defined, and nothing reads it."""
+    heads = jnp.arange(pool.shape[1], dtype=jnp.int32)
+    with jax.named_scope("cache_write"):
+        return pool.at[phys[..., None], heads, off[..., None]].set(
+            jnp.moveaxis(new, 1, 2).astype(pool.dtype), mode="drop")
 
 
 def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,

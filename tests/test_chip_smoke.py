@@ -167,3 +167,31 @@ def test_peaks_table_is_shared_and_raises_for_unknown_devices():
         pass
     with pytest.raises(NotFoundError):
         t.mfu
+
+
+def test_a_pool_shaped_copy_in_the_decode_program_fails_on_the_tpu():
+    # two lines of the decode program the v5e's compiler made of the
+    # scatter that kept H as a window dimension (PERF.md, PR 26), the
+    # second inside a fusion; then the write that replaced it
+    moved = """
+  %copy.46 = f32[512,16,32,128]{3,1,2,0:T(8,128)} copy(%cache_0__k.1), sharding={replicated}
+  %fusion.5 = f32[512,16,32,128]{3,1,2,0:T(8,128)} fusion(%copy.46, %fusion.322), kind=kCustom
+  ROOT %transpose.9 = f32[512,16,32,128]{3,2,1,0} transpose(%param_0.3), dimensions={0,2,1,3}
+  %copy.47 = f32[16,16,1,128]{3,2,1,0} copy(%bitcast.7)
+"""
+    clean = """
+  ROOT %scatter.9 = f32[512,16,32,128]{3,2,1,0:T(8,128)} scatter(%param_0.15, %custom-call.7, %transpose.108), update_window_dims={2}
+  %fusion.5 = f32[512,16,32,128]{3,2,1,0:T(8,128)} fusion(%cache_0__k.1, %reshape.558), kind=kCustom
+  %copy-done.37 = s8[512,16,32,128]{3,2,1,0:T(8,128)(4,1)S(1)} copy-done(%copy-start.37)
+"""
+    shape = (512, 16, 32, 128)
+    assert chip_smoke.pool_shaped_moves(moved, shape) == \
+        ["copy.46", "transpose.9"]
+    assert chip_smoke.pool_shaped_moves(moved, (16, 16, 1, 128)) == \
+        ["copy.47"]
+    assert chip_smoke.pool_shaped_moves(clean, shape) == []
+    with pytest.raises(chip_smoke.SmokeFailure, match="2 time"):
+        chip_smoke.check_no_pool_moves(moved, shape, "tpu", "x")
+    # the CPU backend donates nothing: its copy is said, not judged
+    chip_smoke.check_no_pool_moves(moved, shape, "cpu", "x")
+    chip_smoke.check_no_pool_moves(clean, shape, "tpu", "x")

@@ -15,7 +15,10 @@ Pins the contracts the paged layout lives on:
   occupancy below full max_len);
 - ``paged_decode_attention`` is the gather+mask composition of the
   dense ``decode_attention`` (the math is shared, so layouts can only
-  differ by float-reduction noise).
+  differ by float-reduction noise);
+- ``paged_cache_write`` puts the bytes where the plain
+  ``pool.at[phys, :, off, :].set`` put them, and the decode step makes
+  nothing else of a pool's shape.
 """
 import numpy as np
 import pytest
@@ -232,6 +235,161 @@ def test_paged_decode_attention_matches_dense_composition():
         jnp.asarray(q), jnp.asarray(k_poison), jnp.asarray(v_pool),
         jnp.asarray(table), lengths=jnp.asarray(lengths)))
     np.testing.assert_allclose(got2, want, atol=1e-6)
+
+
+def _write_addresses(table, idx, length, bs):
+    """``(phys, off)`` [B, L] of a chunk, as ``_paged_decode_forward``
+    hands them to the write: a scalar index is the aligned batch
+    (every row the same positions), a vector the slot-batched step,
+    where a position past the table's span goes to the scratch block."""
+    b, mb = table.shape
+    if np.ndim(idx) == 0:
+        pos = np.broadcast_to(idx + np.arange(length), (b, length))
+        return table[np.arange(b)[:, None], pos // bs], pos % bs
+    pos = np.asarray(idx)[:, None] + np.arange(length)[None, :]
+    logical = np.minimum(pos // bs, mb - 1)
+    phys = np.where(pos < mb * bs, table[np.arange(b)[:, None], logical],
+                    0)
+    return phys, pos % bs
+
+
+# b rows of mb=3 blocks of bs=4; ``scratch`` rows are inactive slots,
+# their table row zeroed for the step as ``_masked_tables`` does
+_WRITE_CASES = {
+    "per_row_L1": dict(idx=[5, 0, 11], length=1),
+    "per_row_L5_verify_chunk": dict(idx=[2, 7, 3], length=5),
+    "scalar_L8_across_a_block_edge": dict(idx=2, length=8),
+    "int8_with_both_scale_pools": dict(idx=[5, 0, 9], length=2,
+                                       int8=True),
+    # row 0 sends 12, 13, 14 of a span of 12 to the scratch block
+    "past_the_tables_span": dict(idx=[10, 4, 1], length=5, past_span=3),
+    "two_inactive_rows_one_scratch_offset": dict(idx=[6, 6, 1, 6],
+                                                 length=1,
+                                                 scratch=(1, 3)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WRITE_CASES))
+def test_paged_cache_write_is_the_plain_scatter_bit_for_bit(case):
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import paged_cache_write, quantize_kv
+
+    spec = _WRITE_CASES[case]
+    rng = np.random.RandomState(sorted(_WRITE_CASES).index(case))
+    idx, length = spec["idx"], spec["length"]
+    b = 3 if np.ndim(idx) == 0 else len(idx)
+    h, bs, d, mb = 2, 4, 8, 3
+    nb = 1 + b * mb
+    table = 1 + np.arange(b * mb, dtype=np.int32).reshape(b, mb)
+    table[list(spec.get("scratch", ()))] = 0
+    phys, off = _write_addresses(table, np.asarray(idx, np.int32), length,
+                                 bs)
+    k_new = rng.randn(b, h, length, d).astype(np.float32)
+    v_new = rng.randn(b, h, length, d).astype(np.float32)
+    if spec.get("int8"):
+        (k_new, k_s), (v_new, v_s) = quantize_kv(k_new), quantize_kv(v_new)
+        dtype = np.int8
+        pairs = [(k_new, 4), (v_new, 4), (k_s, 3), (v_s, 3)]
+    else:
+        dtype = np.float32
+        pairs = [(k_new, 4), (v_new, 4)]
+    assert (phys == 0).sum() == spec.get("past_span", 0) \
+        + len(spec.get("scratch", ())) * length
+    repeats = len(set(zip(phys.ravel(), off.ravel()))) < phys.size
+    assert repeats == ("scratch" in spec)
+    for new, rank in pairs:
+        new = np.asarray(new)
+        shape = (nb, h, bs, d)[:rank]
+        before = (rng.randn(*shape) * 50).astype(
+            dtype if rank == 4 else np.float32)
+        got = np.asarray(paged_cache_write(
+            jnp.asarray(before), jnp.asarray(new), jnp.asarray(phys),
+            jnp.asarray(off)))
+        assert got.dtype == before.dtype and got.shape == before.shape
+        # what the write replaced
+        if rank == 4:
+            plain = jnp.asarray(before).at[phys, :, off, :].set(
+                jnp.asarray(new).transpose(0, 2, 1, 3))
+        else:
+            plain = jnp.asarray(before).at[phys, :, off].set(
+                jnp.asarray(new).transpose(0, 2, 1))
+        plain = np.asarray(plain)
+        # and the same thing said row by row
+        want = before.copy()
+        for bi in range(b):
+            for li in range(length):
+                want[phys[bi, li], :, off[bi, li]] = new[bi, :, li]
+        # where rows repeat (only ever in the scratch block) the winner
+        # is not defined; every live block is, to the bit
+        rows = np.arange(1 if repeats else 0, nb)
+        np.testing.assert_array_equal(got[rows], plain[rows])
+        np.testing.assert_array_equal(got[rows], want[rows])
+        # no write strayed: blocks no address names are as they were
+        untouched = np.setdiff1d(np.arange(nb), phys.ravel())
+        np.testing.assert_array_equal(got[untouched], before[untouched])
+        if repeats:
+            # the scratch row holds one of the rows sent there
+            sent = [new[bi, :, 0] for bi in spec["scratch"]]
+            assert any(np.array_equal(got[0, :, off[1, 0]], r)
+                       for r in sent)
+
+
+def test_paged_cache_write_drops_an_index_outside_the_pool():
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import paged_cache_write
+
+    rng = np.random.RandomState(0)
+    before = rng.randn(5, 2, 4, 8).astype(np.float32)
+    new = rng.randn(2, 2, 1, 8).astype(np.float32)
+    phys = np.array([[5], [2]], np.int32)       # 5 is one past the pool
+    off = np.array([[1], [3]], np.int32)
+    got = np.asarray(paged_cache_write(
+        jnp.asarray(before), jnp.asarray(new), jnp.asarray(phys),
+        jnp.asarray(off)))
+    want = before.copy()
+    want[2, :, 3] = new[1, :, 0]                # nothing clamped onto 4
+    np.testing.assert_array_equal(got, want)
+
+
+def test_pool_decode_makes_nothing_pool_shaped_but_the_writes(model):
+    # structural, from a CPU lowering: per layer the only operations
+    # whose result has a K/V pool's shape are the two writes, each a
+    # scatter whose window is head_dim alone.  It guards the SOURCE
+    # against a transpose, select or copy of a pool creeping back in;
+    # what layout the TPU then picks is chip_smoke.py's to check
+    import jax
+    from jax._src.lib.mlir import ir
+
+    pool = GenerationPool(model, max_len=48, slots=3, buckets=[16],
+                          cache_layout="paged", block_size=8,
+                          num_blocks=11)
+    n = pool.slots
+    params, bufs = pool._session._state_vals()
+    samp = (np.zeros(n, np.float32), np.zeros(n, np.int32),
+            np.ones(n, np.float32), np.zeros(n, np.uint32))
+    lowered = jax.jit(pool._pool_decode).lower(
+        params, bufs, pool._cache, np.zeros(n, np.int32),
+        np.ones(n, bool), samp, np.zeros(n, np.uint32),
+        np.zeros(n, np.int32))
+    shape = list(pool._cache[0].k.shape)
+    assert shape == [11, 4, 8, 16]      # no other array of the step's
+    made, windows = [], []
+
+    def visit(op):
+        if any(isinstance(r.type, ir.RankedTensorType)
+               and list(r.type.shape) == shape for r in op.results):
+            made.append(op.name)
+            if op.name == "stablehlo.scatter":
+                windows.append(
+                    str(op.attributes["scatter_dimension_numbers"]))
+        return ir.WalkResult.ADVANCE
+
+    lowered.compiler_ir(dialect="stablehlo").operation.walk(visit)
+    assert made == ["stablehlo.scatter"] * (2 * len(pool._cache))
+    for dims in windows:
+        assert "inserted_window_dims = [0, 1, 2]" in dims, dims
 
 
 def test_paged_decode_attention_gate_conditions(monkeypatch):

@@ -1,0 +1,105 @@
+"""What the TPU's compiler makes of the decode step, with no TPU.
+
+The compiler for the v5e is installed here and compiles for a chip that
+is described, not attached (nothing runs).  These tests compile the
+serving cells' decode step at their real cache geometry and read the
+optimized program: a layout the CPU backend never chooses is the thing
+to guard.  ``chip_smoke.py`` makes the same check on the chip.
+
+Keep every such compile in THIS file: one process may hold the TPU's
+library, the worker that is given this file loads it inside the fixture,
+and a second file could land on a worker where it cannot.
+"""
+import importlib
+
+import numpy as np
+import pytest
+
+import chip_smoke
+import paddle_tpu as pt
+from paddle_tpu.inference import GenerationPool
+from paddle_tpu.models import TransformerLM
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any reason is a skip
+        pytest.skip("no v5e topology can be described here: %s" % e)
+    # a compile for a described chip is written to the persistent cache
+    # and can never be read back from it: keep it out
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+LAYERS = 2
+
+
+@pytest.fixture(scope="module")
+def model():
+    # gpt-1p3b's width and heads, two layers, a small vocabulary:
+    # neither depth nor the head changes a write
+    pt.seed(0)
+    model = TransformerLM(vocab_size=512, hidden_size=2048,
+                          num_layers=LAYERS, num_heads=16,
+                          intermediate_size=8192, max_position=1024,
+                          causal=True, dropout=0.0)
+    model.eval()
+    return model
+
+
+@pytest.mark.parametrize("cache_dtype", ["float32", "int8"])
+def test_pool_decode_on_the_v5e_moves_no_pool(one_chip, model, monkeypatch,
+                                              cache_dtype):
+    # the cells' cache: 512 blocks x 16 heads x 32 positions x 128, 16
+    # slots, on the fused kernel's route.  The write must update the
+    # donated pool where it lies, in the row-major layout the kernel is
+    # pinned to: with ``H`` left as a window dimension of the scatter
+    # this program held four copies of a 134 MB pool a layer, a third of
+    # the step (PERF.md, PR 26 and 27)
+    import jax
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    pool = GenerationPool(model, max_len=1024, slots=16, buckets=[128],
+                          cache_layout="paged", block_size=32,
+                          num_blocks=512, cache_dtype=cache_dtype)
+    n = pool.slots
+    params, bufs = pool._session._state_vals()
+    samp = (np.zeros(n, np.float32), np.zeros(n, np.int32),
+            np.ones(n, np.float32), np.zeros(n, np.uint32))
+    args = (params, bufs, pool._cache, np.zeros(n, np.int32),
+            np.ones(n, bool), samp, np.zeros(n, np.uint32),
+            np.zeros(n, np.int32))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip), args)
+    # the route's gate asks for the backend, which is the CPU here:
+    # answer for the chip this compile is for
+    monkeypatch.setattr(fa, "_backend_memo", "tpu")
+    text = jax.jit(pool._pool_decode, donate_argnums=(2,)) \
+        .lower(*shapes).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
+    pool_shape = pool._cache[0].k.shape
+    assert pool_shape == (512, 16, 32, 128)
+    assert chip_smoke.pool_shaped_moves(text, pool_shape) == []
+    # and the writes are there, on the pool as the step was given it
+    made = [op for _, op in chip_smoke.pool_shaped_ops(text, pool_shape)]
+    assert made.count("scatter") == 2 * LAYERS
+    # (copy-start/copy-done: the scheduler's prefetch of an int8 pool
+    # into another memory space for the kernel, the parent's too; no
+    # layout changes hands there)
+    assert set(made) <= {"parameter", "scatter", "fusion", "bitcast",
+                         "copy-start", "copy-done"}, sorted(set(made))
+    assert made.count("fusion") == made.count("scatter")
