@@ -29,7 +29,7 @@ __all__ = ["Config", "Predictor", "PredictorTensor", "Tensor",
            "get_num_bytes_of_data_type",
            "GenerationPool", "create_generation_pool",
            "kv_reachable_bytes", "DuplicateRequestError",
-           "SpeculativePool"]
+           "SpeculativePool", "BlockDiffusionPool"]
 
 
 class DataType:
@@ -256,6 +256,7 @@ class PredictorPool:
 from .generation import (  # noqa: E402,F401
     DuplicateRequestError, GenerationPool, kv_reachable_bytes)
 from .speculative import SpeculativePool  # noqa: E402,F401
+from .block_diffusion import BlockDiffusionPool  # noqa: E402,F401
 
 
 def create_generation_pool(model, max_len: int, **kwargs) -> GenerationPool:

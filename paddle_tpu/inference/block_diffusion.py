@@ -1,0 +1,439 @@
+"""Continuous batching for a model that generates by diffusion over
+blocks (``models.BlockDiffusionMoELM``): the ``GenerationPool`` variant
+whose step commits 0..``block_length`` tokens a sequence, not one.
+
+A sequence is cut into blocks of ``B = model.block_length`` positions
+aligned to position 0.  The prompt's whole blocks are prefilled once
+(bucketed, batch 1, under the block-causal mask) and spliced into the
+pool's cache.  Every further block starts as the prompt's remaining
+tokens, held fixed, and mask ids; the pool's ONE step executable runs the
+model over every slot's current block, ``[slots, B]`` positions against
+the cache of all earlier blocks, and is of two kinds by its per-slot
+control data:
+
+- **denoise**: of the positions still masked, the ``n`` with the largest
+  softmax probability take their argmax token (static low-confidence
+  remasking: a committed token is never masked again).  The block's noisy
+  K/V is written at the slot's cache index, which does not advance, so
+  the next step overwrites it.  A block of ``m`` positions to fill takes
+  ``min(T, m)`` such steps (``T = model.denoise_steps``), step ``t``
+  committing ``m // steps`` tokens, one more in the first ``m % steps``.
+- **store**: the block is clean; one more forward writes its K/V for
+  good and the index advances by ``B``.  A request's last block is not
+  stored: nothing comes after it.
+
+Slots are not in lockstep: in one tick some denoise and some store.  One
+packed upload and one packed download a tick.  Tokens leave in position
+order as the committed prefix of the block grows; budgets, EOS and finish
+count tokens, not steps.  A last block commits only the positions that
+were asked for; the rest stay mask ids and are never delivered.
+
+What this variant does not do is refused at construction with a typed
+error (``docs/DESIGN.md``): prefix sharing, chunked prefill, preemption
+and spill, a prefill-only tier, a mesh, an int8 cache, sampling with a
+temperature, LoRA adapters.
+"""
+from __future__ import annotations
+
+from typing import Dict, List, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..core.errors import InvalidArgumentError, PreconditionNotMetError
+from ..jit import aot
+from .generation import (GenerationPool, _fire, _SlotState,
+                         _trace_active)
+
+__all__ = ["BlockDiffusionPool", "commit_plan"]
+
+
+def commit_plan(to_fill: int, denoise_steps: int) -> List[int]:
+    """Tokens each denoising step of one block commits: ``to_fill``
+    positions over ``min(denoise_steps, to_fill)`` steps, as evenly as
+    whole numbers allow, the larger counts first."""
+    steps = min(int(denoise_steps), int(to_fill))
+    if steps < 1:
+        return []
+    base, extra = divmod(int(to_fill), steps)
+    return [base + (1 if t < extra else 0) for t in range(steps)]
+
+
+class _Block:
+    """Host mirror of one slot's current block, in plain lists (a block
+    is a handful of positions: numpy would cost more than it saves).
+    ``toks``/``masked`` are what the next step is given; ``limit`` ends
+    the positions this request may fill (block-local); ``delivered`` is
+    how far tokens have left; ``plan`` holds the commit counts of the
+    denoising steps still to run; ``steps[i]`` is the step that committed
+    position ``i``."""
+
+    __slots__ = ("toks", "masked", "limit", "delivered", "plan", "step",
+                 "steps")
+
+    def __init__(self, toks, masked, limit, delivered, plan):
+        self.toks, self.masked = toks, masked
+        self.limit, self.delivered, self.plan = limit, delivered, plan
+        self.step = 0                       # denoising steps run so far
+        self.steps = [0] * len(toks)
+
+
+_REFUSED = {
+    "prefill_chunk_tokens": "chunked prefill (a chunk would have to end on "
+                            "a block boundary and carry the block mask)",
+    "prefix_sharing": "prefix sharing (it rides on chunked prefill)",
+    "mesh": "a mesh (the expert layer has no ep axis yet)",
+    "prefill_only": "a prefill-only tier (a half-denoised block has no "
+                    "K/V hand-off)",
+    "spill_dir": "a spill tier (a half-denoised block is not spilled)",
+    "collective_quant": "quantized collectives (there is no mesh)",
+    "collective_quant_scale": "quantized collectives (there is no mesh)",
+}
+
+
+class BlockDiffusionPool(GenerationPool):
+    """See the module docstring.  Takes ``GenerationPool``'s keyword set
+    as it is (``slots``, ``buckets``, ``cache_layout``, ``block_size``,
+    ``num_blocks``, ``eos_id``, ``cache_dtype``, ``route``, ...) and
+    refuses by name what it cannot honour."""
+
+    def __init__(self, model, max_len: int, **pool_kwargs):
+        if getattr(model, "generation", None) != "block_diffusion":
+            raise InvalidArgumentError(
+                "BlockDiffusionPool serves a model that declares "
+                "generation='block_diffusion' (models.BlockDiffusionMoELM)"
+                "; got %s" % type(model).__name__)
+        for key, what in _REFUSED.items():
+            if pool_kwargs.get(key) not in (None, False):
+                raise InvalidArgumentError(
+                    "%s=%r: generation by diffusion over blocks does not "
+                    "support %s" % (key, pool_kwargs[key], what))
+        if pool_kwargs.get("spill_tier", "host") != "host":
+            raise InvalidArgumentError(
+                "spill_tier=%r: generation by diffusion over blocks does "
+                "not support %s" % (pool_kwargs["spill_tier"],
+                                    _REFUSED["spill_dir"]))
+        if pool_kwargs.get("temperature", 0.0):
+            raise InvalidArgumentError(
+                "generation by diffusion over blocks commits argmax "
+                "tokens: temperature must be 0, got %r"
+                % (pool_kwargs["temperature"],))
+        pool_kwargs.setdefault("cache_dtype", "bfloat16")
+        if jnp.dtype(pool_kwargs["cache_dtype"]) == jnp.int8:
+            raise InvalidArgumentError(
+                "cache_dtype='int8': generation by diffusion over blocks "
+                "keeps a float K/V cache (no grouped-head int8 kernel)")
+        self._B = int(model.block_length)
+        self._T = int(model.denoise_steps)
+        self._mask_id = int(model.mask_token_id)
+        if int(max_len) % self._B:
+            raise InvalidArgumentError(
+                "max_len=%d is no multiple of block_length=%d: a block "
+                "may not straddle the cache's end" % (max_len, self._B))
+        if pool_kwargs.get("cache_layout") == "paged" \
+                and int(pool_kwargs.get("block_size", 32)) % self._B:
+            raise InvalidArgumentError(
+                "block_size=%d is no multiple of block_length=%d: a "
+                "block of positions may not straddle two cache blocks"
+                % (pool_kwargs.get("block_size", 32), self._B))
+        super().__init__(model, max_len, **pool_kwargs)
+        donate = pool_kwargs.get("donate")
+        if donate is None:
+            donate = jax.default_backend() != "cpu"
+        # the pool's two executables of its own, in place of the batched
+        # one-token step and the session's sampling prefill (which are
+        # never compiled here); ``slot_insert`` is the base pool's
+        self._decode_jit = aot.AotFunction(
+            jax.jit(self._block_step,
+                    donate_argnums=(2,) if donate else ()),
+            key_fn=lambda *a: "block_step", name="block_step",
+            meta_fn=lambda p, b, cache, *r: {
+                "kv_cache_bytes": aot.kv_arg_bytes(cache)})
+        self._prefill_jit = aot.AotFunction(
+            jax.jit(self._block_prefill),
+            key_fn=lambda p, b, ids, *r: aot.shape_key(ids),
+            name="block_prefill")
+        self._blocks: Dict[int, _Block] = {}
+        # a free slot's row of the packed upload: mask ids, nothing to do
+        self._idle_row = [self._mask_id] * self._B + [0] * (self._B + 3)
+        # what the step did, ever: read by the engine's counters
+        self.forwards_denoise = 0
+        self.forwards_store = 0
+        self.tokens_committed = 0
+        # the denoising step that committed the token ``on_token`` is
+        # being called with (read by the engine inside that call)
+        self.token_commit_step: Optional[int] = None
+
+    # -- traced bodies ---------------------------------------------------
+    def _block_prefill(self, param_vals, buf_vals, ids, whole):
+        """The batch-1 cache of a bucket-padded prompt with the index at
+        ``whole``, the length of the prompt's whole blocks.  The block
+        mask keeps every row of a whole block from seeing anything past
+        its block, so the rows after ``whole`` (a trailing partial block,
+        the padding) write K/V that nothing attends and the first step
+        overwrites.  No token is taken here, and no logits: the head is
+        dead code in this program."""
+        cache = self._model.gen_decode_cache(
+            1, self.max_len, self._cache_dtype, layout=self.cache_layout,
+            block_size=self._block_size)
+        _, cache = self._session._run_model(param_vals, buf_vals, ids,
+                                            cache)
+        return self._layout.finalize_prefill(
+            cache, jnp.asarray(whole, jnp.int32), self.max_len)
+
+    def _block_step(self, param_vals, buf_vals, cache, ctl):
+        """One forward over every slot's current block.  ``ctl`` is the
+        tick's packed upload, int32 ``[slots, 2B + 3]``: the block's
+        tokens, which of them are still to fill, how many to commit
+        (0 for a store), whether the index advances (a store), whether
+        the slot is live.  Returns the cache and the packed download
+        ``[slots, 2B]``: the tokens after the commit and what is still
+        masked."""
+        bl = self._B
+        toks, masked = ctl[:, :bl], ctl[:, bl:2 * bl] != 0
+        count = ctl[:, 2 * bl]
+        advance = (ctl[:, 2 * bl + 1] != 0) & (ctl[:, 2 * bl + 2] != 0)
+        active = ctl[:, 2 * bl + 2] != 0
+        tables = None
+        if self.cache_layout == "paged":
+            tables = [c.table for c in cache]
+            cache = self._masked_tables(cache, active)
+        logits, new_cache = self._session._run_model(
+            param_vals, buf_vals, toks, cache)
+        with jax.named_scope("sample"):
+            lf = logits.astype(jnp.float32)
+            best = jnp.argmax(lf, axis=-1).astype(jnp.int32)
+            # log of the largest softmax probability: the confidence
+            conf = jnp.max(lf, axis=-1) - jax.nn.logsumexp(lf, axis=-1)
+            score = jnp.where(masked, conf, -jnp.inf)
+            at = jnp.arange(bl)
+            # rank among the block's positions, the earlier first in a tie
+            ahead = (score[:, None, :] > score[:, :, None]) | (
+                (score[:, None, :] == score[:, :, None])
+                & (at[None, None, :] < at[None, :, None]))
+            rank = jnp.sum(ahead, axis=-1)
+            commit = masked & (rank < count[:, None])
+            out = jnp.concatenate(
+                [jnp.where(commit, best, toks),
+                 (masked & ~commit).astype(jnp.int32)], axis=1)
+        with jax.named_scope("cache_freeze"):
+            # the forward moved every index by B: keep that for a live
+            # slot's store and undo it everywhere else
+            new_cache = self._layout.freeze_step(new_cache, cache, advance)
+        if tables is not None:
+            new_cache = [c._replace(table=t)
+                         for c, t in zip(new_cache, tables)]
+        return new_cache, out
+
+    # -- host API --------------------------------------------------------
+    def submit(self, input_ids, max_new_tokens: int, request_id=None,
+               **kwargs):
+        if kwargs.get("temperature") or (
+                kwargs.get("_sampling") is not None
+                and kwargs["_sampling"].temperature):
+            raise InvalidArgumentError(
+                "generation by diffusion over blocks commits argmax "
+                "tokens: a request's temperature must be 0")
+        if kwargs.get("adapter"):
+            raise InvalidArgumentError(
+                "generation by diffusion over blocks serves the base "
+                "model only (adapter must be 0)")
+        return super().submit(input_ids, max_new_tokens, request_id,
+                              **kwargs)
+
+    def can_preempt(self, request_id) -> bool:
+        return False
+
+    def preempt(self, request_id) -> dict:
+        raise PreconditionNotMetError(
+            "generation by diffusion over blocks does not preempt: a "
+            "half-denoised block is not spilled")
+
+    def _new_block(self, fixed, remaining: int) -> _Block:
+        """The block that starts with the tokens ``fixed`` (a prompt's
+        trailing partial block, else none) and may fill ``remaining``
+        more."""
+        bl, r = self._B, len(fixed)
+        fill = min(bl - r, remaining)
+        toks = [int(t) for t in fixed] + [self._mask_id] * (bl - r)
+        masked = [0] * r + [1] * fill + [0] * (bl - r - fill)
+        return _Block(toks, masked, r + fill, r,
+                      commit_plan(fill, self._T))
+
+    def _weights(self):
+        """The parameter and buffer value lists, walked once and kept
+        (``refresh_weights`` drops them)."""
+        if self._state_cache is None:
+            self._state_cache = self._session._state_vals()
+        return self._state_cache
+
+    def _refill(self):
+        tr = _trace_active()
+        self.admission_blocked = False
+        while self._queue and self._free:
+            pick = self._pick_candidate(self._tenant_counts())
+            if pick is None:
+                break
+            _, req = pick
+            need = shard = None
+            if self.cache_layout == "paged":
+                need = self._blocks_needed(len(req.ids),
+                                           req.max_new_tokens)
+                shard = self._choose_shard(req, need)[0]
+                if shard is None:
+                    self.admission_blocked = True
+                    break
+            for i, q in enumerate(self._queue):
+                if q is req:
+                    del self._queue[i]
+                    break
+            _fire("pool.prefill")
+            n = len(req.ids)
+            whole = n // self._B * self._B
+            bucket = self._session._bucket_for(n)
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :n] = req.ids
+            params, bufs = self._weights()
+            if tr is None:
+                row_cache = self._prefill_jit(params, bufs,
+                                              jnp.asarray(padded), whole)
+            else:
+                with tr.span("tick.prefill", rid=req.rid, prompt_tokens=n,
+                             bucket=bucket):
+                    row_cache = self._prefill_jit(
+                        params, bufs, jnp.asarray(padded), whole)
+            slot = self._pop_free_slot(shard)
+            args = (self._cache, row_cache, jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(whole, jnp.int32))
+            if self.cache_layout == "paged":
+                _fire("pool.alloc_blocks")
+                blocks = self._alloc_blocks(need, shard)
+                self._slot_blocks[slot] = blocks
+                row = np.full(self._max_blocks,
+                              self._shard_scratch(shard), np.int32)
+                row[:need] = blocks
+                args += (jnp.asarray(row),)
+            self._cache = self._insert_jit(*args)
+            self.last_admit_prefix_tokens = None
+            if self.on_admit is not None:
+                self.on_admit(req.rid, slot, n)
+            self._active[slot] = _SlotState(
+                req.rid, req.ids, [], req.max_new_tokens,
+                priority=req.priority, tenant=req.tenant,
+                deadline=req.deadline, seq=req.seq, sampling=req.sampling,
+                adapter=req.adapter)
+            self._blocks[slot] = self._new_block(req.ids[whole:],
+                                                 req.max_new_tokens)
+
+    def _control(self) -> np.ndarray:
+        """The tick's packed upload (see ``_block_step``)."""
+        rows = [self._idle_row] * self.slots
+        for slot in self._active:
+            blk = self._blocks[slot]
+            rows[slot] = blk.toks + blk.masked + (
+                [blk.plan[0], 0, 1] if blk.plan else [0, 1, 1])
+        return np.array(rows, np.int32)
+
+    def step(self) -> bool:
+        """Refill free slots, run ONE forward over every live slot's
+        block, commit and deliver.  False when the pool is drained."""
+        _fire("pool.step")
+        tr = _trace_active()
+        if tr is None:
+            self._refill()
+        else:
+            with tr.span("tick.admit"):
+                self._refill()
+        if not self._active:
+            return bool(self._queue)
+        params, bufs = self._weights()
+        ctl = self._control()
+        bl, live = self._B, len(self._active)
+        stores = int(ctl[:, 2 * bl + 1].sum())
+        # a denoising step commits exactly what its plan asks: known
+        # before the dispatch, so the span carries it
+        committed = int(ctl[:, 2 * bl].sum())
+        if tr is None:
+            self._cache, out_dev = self._decode_jit(params, bufs,
+                                                    self._cache, ctl)
+            self._deliver_blocks(np.asarray(out_dev))
+        else:
+            kind = "store" if stores == live else \
+                "denoise" if not stores else "mixed"
+            with tr.span("tick.decode", live=live, slots=self.slots,
+                         kind=kind, rows=live * bl, store=stores,
+                         denoise=live - stores, committed=committed,
+                         tokens_per_forward=committed / live):
+                self._cache, out_dev = self._decode_jit(
+                    params, bufs, self._cache, ctl)
+            with tr.span("tick.sample"):
+                out = np.asarray(out_dev)
+            with tr.span("tick.deliver"):
+                self._deliver_blocks(out)
+        self.forwards_store += stores
+        self.forwards_denoise += live - stores
+        self.tokens_committed += committed
+        return bool(self._active or self._queue)
+
+    def _deliver_blocks(self, out: np.ndarray) -> None:
+        """Take the step's download into every live slot's block: note
+        the commits, let the tokens of the grown committed prefix go in
+        position order, finish on EOS or budget, and start the next block
+        after a store."""
+        bl = self._B
+        rows = out.tolist()
+        for slot in list(self._active):
+            st, blk = self._active[slot], self._blocks[slot]
+            if not blk.plan:
+                # that was the store: the block's K/V is kept, the index
+                # stands at the next block, which starts all masked
+                self._blocks[slot] = self._new_block((), st.remaining)
+                continue
+            blk.plan.pop(0)
+            blk.step += 1
+            toks, masked = rows[slot][:bl], rows[slot][bl:]
+            for i in range(bl):
+                if blk.masked[i] and not masked[i]:
+                    blk.steps[i] = blk.step
+            blk.toks, blk.masked = toks, masked
+            done = False
+            while blk.delivered < blk.limit \
+                    and not masked[blk.delivered]:
+                t = toks[blk.delivered]
+                self.token_commit_step = blk.steps[blk.delivered]
+                blk.delivered += 1
+                st.tokens.append(t)
+                st.remaining -= 1
+                if self.on_token is not None:
+                    self.on_token(st.rid, t)
+                if st.remaining == 0 or t == self.eos_id:
+                    done = True
+                    break
+            self.token_commit_step = None
+            if done:
+                self._blocks.pop(slot, None)
+                self._finish(slot)
+
+    def release(self, slot: int):
+        self._blocks.pop(slot, None)
+        return super().release(slot)
+
+    def reset(self):
+        super().reset()
+        self._blocks.clear()
+
+    def compile_counts(self) -> dict:
+        return {"block_prefill": int(self._prefill_jit._cache_size()),
+                "block_step": int(self._decode_jit._cache_size()),
+                "slot_insert": int(self._insert_jit._cache_size())}
+
+    def cost_version(self) -> int:
+        return (self._prefill_jit.compiles + self._decode_jit.compiles
+                + self._insert_jit.compiles)
+
+    def block_stats(self) -> dict:
+        """Forwards by kind and tokens committed, ever."""
+        return {"forwards_denoise": self.forwards_denoise,
+                "forwards_store": self.forwards_store,
+                "tokens_committed": self.tokens_committed}

@@ -6,6 +6,10 @@ BERT-base → ERNIE → GPT-1.3B); the transformer stack mirrors what
 TransformerEncoder:622) is used for in the reference's NLP model zoo.
 Vision CNNs live in ``paddle_tpu.vision.models``.
 """
+from .block_diffusion import (  # noqa: F401
+    BlockDiffusionDecoderLayer,
+    BlockDiffusionMoELM,
+)
 from .language_model import (  # noqa: F401
     TransformerForSequenceClassification,
     TransformerLM,

@@ -31,7 +31,8 @@ from .layer.loss import (  # noqa: F401
 )
 from .layer.norm import (  # noqa: F401
     BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D, GroupNorm, InstanceNorm1D,
-    InstanceNorm2D, InstanceNorm3D, LayerNorm, LocalResponseNorm, SpectralNorm,
+    InstanceNorm2D, InstanceNorm3D, LayerNorm, LocalResponseNorm, RMSNorm,
+    SpectralNorm,
     SyncBatchNorm,
 )
 from .layer.pooling import (  # noqa: F401
@@ -44,8 +45,9 @@ from .layer.rnn import (  # noqa: F401
     GRU, LSTM, RNN, BiRNN, GRUCell, LSTMCell, RNNCellBase, SimpleRNN,
     SimpleRNNCell,
 )
+from .layer.moe import SparseExperts  # noqa: F401
 from .layer.transformer import (  # noqa: F401
-    MultiHeadAttention, Transformer, TransformerDecoder, TransformerDecoderLayer,
+    GroupedQueryAttention, MultiHeadAttention, Transformer, TransformerDecoder, TransformerDecoderLayer,
     TransformerEncoder, TransformerEncoderLayer,
 )
 from .ssm import GatedSSMBlock, RecurrentDecodeCache, SSMLM  # noqa: F401

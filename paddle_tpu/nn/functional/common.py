@@ -21,6 +21,26 @@ def linear(x, weight, bias=None):
     return out
 
 
+def rotary_embedding(x, positions, theta: float = 10000.0):
+    """Rotary position embedding (Su et al. 2021) in the halves-rotated
+    form the Llama/Qwen family uses: channel ``i`` pairs with ``i + D/2``
+    and the pair turns by ``position * theta^(-2i/D)``.  ``x``:
+    ``[B, H, L, D]``; ``positions``: ``[L]``, or ``[B, L]`` where each
+    row stands at positions of its own (a slot-batched decode step).
+    The angles and the turn are taken in float32; the result has ``x``'s
+    type."""
+    d = x.shape[-1]
+    half = d // 2
+    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / d))
+    ang = jnp.asarray(positions).astype(jnp.float32)[..., None] * inv
+    ang = ang[None, None] if ang.ndim == 2 else ang[:, None]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
 def dropout(
     x,
     p: float = 0.5,

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 
 from ...core.errors import InvalidArgumentError
@@ -31,6 +32,18 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon: float = 1e-
 
 def jax_rsqrt(v):
     return jnp.reciprocal(jnp.sqrt(v))
+
+
+def rms_norm(x, weight=None, epsilon: float = 1e-6):
+    """Root-mean-square norm over the last axis (Zhang & Sennrich 2019):
+    ``x / sqrt(mean(x^2) + epsilon) * weight``, no mean taken off and no
+    offset.  The statistic is taken in float32 whatever ``x`` is stored
+    in, and the result goes back to ``x``'s type before the learned
+    scale, as the Llama/Qwen family's layers do."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    out = (xf * jax.lax.rsqrt(var + epsilon)).astype(x.dtype)
+    return out if weight is None else out * weight
 
 
 def batch_norm_stats(x, data_format: str = "NCHW"):

@@ -250,3 +250,23 @@ class SpectralNorm(Layer):
         return _spectral_normalize(
             weight, self.weight_u, self.weight_v, self._dim,
             self._power_iters, self._eps)
+
+
+class RMSNorm(Layer):
+    """Root-mean-square norm over the last axis with a learned scale
+    (``F.rms_norm``): the norm of the Llama/Qwen family, no mean, no
+    offset."""
+
+    def __init__(self, hidden_size: int, epsilon: float = 1e-6,
+                 weight_attr=None, name=None):
+        super().__init__()
+        self._epsilon = epsilon
+        self.weight = self.create_parameter(
+            [hidden_size], attr=weight_attr,
+            default_initializer=I.Constant(1.0))
+
+    def forward(self, x):
+        return F.rms_norm(x, self.weight, self._epsilon)
+
+    def extra_repr(self):
+        return "epsilon=%s" % self._epsilon

@@ -19,7 +19,7 @@ from .. import functional as F
 from .. import initializer as I
 from .common import Dropout, Linear
 from .layers import Layer
-from .norm import LayerNorm
+from .norm import LayerNorm, RMSNorm
 
 
 def _convert_attn_mask(mask, dtype):
@@ -124,6 +124,18 @@ class MultiHeadAttention(Layer):
         self.v_proj = Linear(self.vdim, embed_dim, weight_attr, bias_attr)
         self.out_proj = Linear(embed_dim, embed_dim, weight_attr, bias_attr)
         self._sep_attn = None  # set by enable_sequence_parallel
+
+    @property
+    def kv_heads(self) -> int:
+        """Heads the decode cache holds: one K/V head a query head here;
+        ``GroupedQueryAttention`` holds fewer."""
+        return self.num_heads
+
+    def _last_visible(self, pos):
+        """The last key position a query at ``pos`` may attend, the form
+        the cached forwards hand the attention ops their mask in: its own
+        position for a causal decoder."""
+        return pos
 
     def enable_sequence_parallel(self, group=None, mode: str = "ring",
                                  causal: bool = False):
@@ -242,7 +254,7 @@ class MultiHeadAttention(Layer):
         index = (jnp.zeros((batch_size,), jnp.int32) if per_slot
                  else jnp.zeros((), jnp.int32))
         if layout == "dense":
-            shape = (batch_size, self.num_heads, max_length, self.head_dim)
+            shape = (batch_size, self.kv_heads, max_length, self.head_dim)
             scales = ((jnp.zeros(shape[:-1], jnp.float32),) * 2 if quant
                       else (None, None))
             return self.DecodeCache(jnp.zeros(shape, dtype),
@@ -265,7 +277,7 @@ class MultiHeadAttention(Layer):
                     "paged cache needs num_blocks >= 2 (block 0 is the "
                     "reserved scratch block), got %d" % num_blocks)
             table = jnp.zeros((batch_size, max_blocks), jnp.int32)
-        shape = (num_blocks, self.num_heads, block_size, self.head_dim)
+        shape = (num_blocks, self.kv_heads, block_size, self.head_dim)
         scales = ((jnp.zeros(shape[:-1], jnp.float32),) * 2 if quant
                   else (None, None))
         return self.PagedDecodeCache(jnp.zeros(shape, dtype),
@@ -309,7 +321,7 @@ class MultiHeadAttention(Layer):
                                                       (0, 0, idx))
                 vs_buf = jax.lax.dynamic_update_slice(vs_buf, v_s,
                                                       (0, 0, idx))
-            q_pos = idx + jnp.arange(length)                    # [L]
+            q_pos = self._last_visible(idx + jnp.arange(length))  # [L]
         else:
             # slot-batched decode/verify: each row writes its L-token
             # chunk at its OWN position — a scatter over [B, L]
@@ -330,7 +342,7 @@ class MultiHeadAttention(Layer):
                     k_s.transpose(0, 2, 1), mode="drop")
                 vs_buf = vs_buf.at[rows, :, pos].set(
                     v_s.transpose(0, 2, 1), mode="drop")
-            q_pos = pos                                         # [B,L]
+            q_pos = self._last_visible(pos)                     # [B,L]
         if attn_mask is not None:
             # a caller's mask is keyed to the CHUNK length while the
             # score axis here is the cache length max_len — combining
@@ -417,7 +429,7 @@ class MultiHeadAttention(Layer):
         # composition rebuilds the inline additive mask op-for-op; the
         # fused route walks the table in-kernel and masks in-register
         out = paged_decode_attention(q_, k_pool, v_pool, table,
-                                     q_pos=q_pos,
+                                     q_pos=self._last_visible(q_pos),
                                      k_scale=ks_pool, v_scale=vs_pool)
         return out, cache._replace(
             k=k_pool, v=v_pool, k_scale=ks_pool, v_scale=vs_pool,
@@ -482,6 +494,120 @@ class MultiHeadAttention(Layer):
         if self.need_weights:
             return out, None
         return out
+
+
+class GroupedQueryAttention(MultiHeadAttention):
+    """Self-attention of the Llama/Qwen3 family: ``num_heads`` query heads
+    on ``num_kv_heads`` K/V heads of ``head_dim`` (query head ``n`` reads
+    K/V head ``n // (num_heads / num_kv_heads)``), RMSNorm over each
+    head's channels of q and k (``qk_norm``), rotary positions, no bias.
+
+    The decode caches (``gen_decode_cache``, dense and paged) hold the
+    K/V heads, so their bytes and the decode step's reads fall by the
+    group size; positions come from the cache index, per slot.
+
+    ``block_length`` set: generation by diffusion over blocks.  Positions
+    ``[k * block_length, (k + 1) * block_length)`` are one block whose
+    rows see each other and every earlier block: the mask is block-causal,
+    with and without a cache."""
+
+    def __init__(self, embed_dim: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int, rope_theta: float = 10000.0,
+                 qk_norm: bool = True, norm_epsilon: float = 1e-6,
+                 block_length: Optional[int] = None):
+        Layer.__init__(self)
+        if num_kv_heads < 1 or num_heads % num_kv_heads:
+            raise InvalidArgumentError(
+                "num_heads %d is not a whole multiple of num_kv_heads %d"
+                % (num_heads, num_kv_heads))
+        if head_dim % 2:
+            raise InvalidArgumentError(
+                "rotary positions turn pairs of channels: head_dim %d is "
+                "odd" % head_dim)
+        self.embed_dim = self.kdim = self.vdim = embed_dim
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim = head_dim
+        self.dropout, self.need_weights = 0.0, False
+        self.rope_theta = float(rope_theta)
+        self.block_length = None if block_length is None \
+            else int(block_length)
+        self.q_proj = Linear(embed_dim, num_heads * head_dim,
+                             bias_attr=False)
+        self.k_proj = Linear(embed_dim, num_kv_heads * head_dim,
+                             bias_attr=False)
+        self.v_proj = Linear(embed_dim, num_kv_heads * head_dim,
+                             bias_attr=False)
+        self.out_proj = Linear(num_heads * head_dim, embed_dim,
+                               bias_attr=False)
+        self.q_norm = RMSNorm(head_dim, norm_epsilon) if qk_norm else None
+        self.k_norm = RMSNorm(head_dim, norm_epsilon) if qk_norm else None
+        self._sep_attn = None
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads
+
+    def enable_sequence_parallel(self, group=None, mode: str = "ring",
+                                 causal: bool = False):
+        raise InvalidArgumentError(
+            "GroupedQueryAttention has no sequence-parallel form")
+
+    def _last_visible(self, pos):
+        if self.block_length is None:
+            return pos
+        b = self.block_length
+        return pos // b * b + (b - 1)
+
+    def _heads(self, x, n: int):
+        from ... import tensor as T
+
+        b, l = x.shape[0], x.shape[1]
+        return T.transpose(T.reshape(x, [b, l, n, self.head_dim]),
+                           [0, 2, 1, 3])
+
+    def forward(self, x, attn_mask=None, cache=None):
+        import jax
+        import jax.numpy as jnp
+
+        from ...framework.tensor import Tensor as _T
+
+        if attn_mask is not None:
+            raise InvalidArgumentError(
+                "GroupedQueryAttention derives its mask from positions "
+                "(causal, or block-causal with block_length); pass "
+                "attn_mask=None")
+        q = self._heads(self.q_proj(x), self.num_heads)
+        k = self._heads(self.k_proj(x), self.num_kv_heads)
+        v = self._heads(self.v_proj(x), self.num_kv_heads)
+        if self.q_norm is not None:
+            with jax.named_scope("qk_norm"):
+                q, k = self.q_norm(q), self.k_norm(k)
+        steps = jnp.arange(x.shape[1], dtype=jnp.int32)
+        if cache is None:
+            pos = steps
+        else:
+            idx = jnp.asarray(cache.index, jnp.int32)
+            pos = idx + steps if idx.ndim == 0 \
+                else idx[:, None] + steps[None, :]
+        with jax.named_scope("rope"):
+            q = F.rotary_embedding(q, pos, self.rope_theta)
+            k = F.rotary_embedding(k, pos, self.rope_theta)
+        if cache is not None:
+            fwd = (self._decode_forward
+                   if isinstance(cache, self.DecodeCache)
+                   else self._paged_decode_forward)
+            out, cache = fwd(q, k, v, None, cache)
+            out = self.out_proj(self._merge_heads(
+                _T(out, stop_gradient=True)))
+            return out, cache
+        from ...ops.flash_attention import decode_attention
+
+        # no cache: the same composition over the sequence's own K/V
+        out = decode_attention(q.value, k.value, v.value,
+                               q_pos=self._last_visible(pos),
+                               route="composition")
+        return self.out_proj(self._merge_heads(_T(out,
+                                                  stop_gradient=True)))
 
 
 def _row_parallel_seam(linear, x):

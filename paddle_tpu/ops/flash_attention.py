@@ -368,6 +368,26 @@ def _effective_qpos(q_pos, lengths, b: int, lq: int, s: int):
     return qp
 
 
+def fold_query_groups(q, kv_heads: int, q_pos=None):
+    """Grouped K/V heads: query head ``n`` reads K/V head ``n // g`` with
+    ``g = Hq / Hkv``, so the ``g`` query heads of one K/V head and their
+    ``Lq`` positions are ONE block of ``g * Lq`` rows against that head's
+    keys.  ``q`` [B, Hq, Lq, D] -> [B, Hkv, g * Lq, D] (row ``i * Lq + l``
+    is query head ``n = kv * g + i`` at chunk position ``l``) and ``q_pos``
+    ([Lq] or [B, Lq]) tiled to match.  Returns ``(q, q_pos)``; undo with
+    ``out.reshape(B, Hq, Lq, D)``."""
+    b, hq, lq, d = q.shape
+    if kv_heads < 1 or hq % kv_heads:
+        raise InvalidArgumentError(
+            "query heads %d are not a whole multiple of the cache's K/V "
+            "heads %d" % (hq, kv_heads))
+    g = hq // kv_heads
+    if q_pos is not None:
+        qp = jnp.asarray(q_pos, jnp.int32)
+        q_pos = jnp.tile(qp, (g,) if qp.ndim == 1 else (1, g))
+    return q.reshape(b, kv_heads, g * lq, d), q_pos
+
+
 def decode_attention_supported(q_shape, kv_len: int, dtype) -> bool:
     """Gate for the fused single-query/short-chunk pallas decode kernel
     (``ops.pallas_decode.decode_attention_kernel``): TPU backend, 4-D
@@ -393,7 +413,8 @@ def decode_attention_supported(q_shape, kv_len: int, dtype) -> bool:
 
 
 def decode_attention(q, k, v, bias=None, sm_scale: Optional[float] = None,
-                     k_scale=None, v_scale=None, q_pos=None, route=None):
+                     k_scale=None, v_scale=None, q_pos=None, route=None,
+                     score_dtype=None):
     """Decode-step attention: [B, H, Lq, D] queries against a FULL
     preallocated cache [B, H, S, D] (S = max_len), with ``bias`` masking
     the invalid tail (positions at or beyond the cache index) to -inf.
@@ -423,10 +444,28 @@ def decode_attention(q, k, v, bias=None, sm_scale: Optional[float] = None,
     mask in-register instead of streaming a materialized bias; the
     composition builds the exact additive mask the callers used to
     build inline.  ``route`` overrides the ambient :func:`decode_route`
-    ("auto" | "composition" | "pallas")."""
+    ("auto" | "composition" | "pallas").  ``score_dtype`` (composition
+    only; None keeps the queries' type) is the type the scores and the
+    softmax are taken in: the grouped-head callers pass float32, as the
+    fused kernel computes, so that a bfloat16 model's attention does not
+    round its probabilities to eight bits."""
     d = q.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(d))
+    if q.ndim == 4 and k.ndim == 4 and q.shape[1] != k.shape[1]:
+        # grouped K/V heads: the heads that share a K/V head fold into
+        # the chunk axis, and the chunk is then past the kernel's
+        # length, so a dense cache of K/V heads takes the composition
+        if bias is not None:
+            raise InvalidArgumentError(
+                "grouped K/V heads take their mask as q_pos, not as an "
+                "additive bias")
+        qf, qp = fold_query_groups(q, k.shape[1], q_pos)
+        out = decode_attention(qf, k, v, sm_scale=sm_scale,
+                               k_scale=k_scale, v_scale=v_scale, q_pos=qp,
+                               route="composition",
+                               score_dtype=jnp.float32)
+        return out.reshape(q.shape)
     s = k.shape[2]
     from .pallas_decode import dense_seq_block
 
@@ -455,11 +494,17 @@ def decode_attention(q, k, v, bias=None, sm_scale: Optional[float] = None,
     if q_pos is not None:
         pos_bias = _qpos_bias(q_pos, s, q.dtype)
         bias = pos_bias if bias is None else bias + pos_bias
-    scores = jnp.einsum("...qd,...kd->...qk", q, k) * jnp.asarray(
-        sm_scale, q.dtype)
+    if score_dtype is None:
+        scores = jnp.einsum("...qd,...kd->...qk", q, k) * jnp.asarray(
+            sm_scale, q.dtype)
+    else:
+        scores = jnp.einsum("...qd,...kd->...qk", q, k,
+                            preferred_element_type=score_dtype) * sm_scale
     if bias is not None:
         scores = scores + bias.astype(scores.dtype)
     weights = jax.nn.softmax(scores, axis=-1)
+    if score_dtype is not None:
+        weights = weights.astype(q.dtype)
     return jnp.einsum("...qk,...kd->...qd", weights, v)
 
 
@@ -525,7 +570,7 @@ def paged_cache_write(pool, new, phys, off):
 def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
                            sm_scale: Optional[float] = None,
                            k_scale=None, v_scale=None, q_pos=None,
-                           route=None):
+                           route=None, score_dtype=None):
     """Decode-step attention against a BLOCK-TABLE KV cache.
 
     ``q``: [B, H, Lq, D] queries (Lq = 1 for autoregressive decode,
@@ -569,6 +614,11 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
     s = mb * bs
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    grouped = q.shape[1] != h
+    if grouped and bias is not None:
+        raise InvalidArgumentError(
+            "grouped K/V heads take their mask as q_pos/lengths, not as "
+            "an additive bias")
     if _resolve_route(
             route, q.shape,
             paged_decode_attention_supported(q.shape, bs, nb, q.dtype)
@@ -582,6 +632,16 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
             q, k_pool, v_pool, jnp.asarray(table, jnp.int32), qp,
             float(sm_scale), k_scale=k_scale, v_scale=v_scale,
             bias=bias, interpret=_cached_backend() != "tpu")
+    if grouped:
+        # the composition on the folded rows: [B, Hkv, g * Lq, D]
+        # against the gathered [B, Hkv, S, D], the mask tiled per head
+        qf, qp = fold_query_groups(
+            q, h, _effective_qpos(q_pos, lengths, b, q.shape[2], s))
+        out = paged_decode_attention(
+            qf, k_pool, v_pool, table, sm_scale=sm_scale, k_scale=k_scale,
+            v_scale=v_scale, q_pos=qp, route="composition",
+            score_dtype=jnp.float32)
+        return out.reshape(q.shape)
     # gather the row's blocks: [B, MB, H, bs, D] -> [B, H, MB*bs, D];
     # XLA lowers the fancy-index to one gather over the pool's leading
     # axis, the only data-dependent op in the step
@@ -611,7 +671,8 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
     # would run the dense kernel on K/V already materialized in HBM,
     # the exact traffic the kernel exists to avoid
     return decode_attention(q, k, v, bias=bias, sm_scale=sm_scale,
-                            k_scale=ks, v_scale=vs, route="composition")
+                            k_scale=ks, v_scale=vs, route="composition",
+                            score_dtype=score_dtype)
 
 
 # id(mask) → (weakref(mask), verdict); masks are immutable jax arrays built

@@ -174,10 +174,16 @@ def _check_common(q, q_pos, bias, s: int):
 
 
 def _make_body(n_scalar: int, lq: int, bs: int, sm_scale: float,
-               quant: bool, has_bias: bool):
+               quant: bool, has_bias: bool, group: int = 1):
     """The shared inner loop.  Ref order after the ``n_scalar``
     scalar-prefetch refs (q_pos always last among them): q, k, v,
-    [k_scale, v_scale,] [bias,] out, then m/l/acc VMEM scratch."""
+    [k_scale, v_scale,] [bias,] out, then m/l/acc VMEM scratch.
+
+    ``group`` > 1: the q block holds the ``group`` query heads that
+    share this K/V head, ``lq`` rows each (row ``i * lq + l``), and
+    ``q_pos`` still has ``lq`` entries a batch row: row ``r`` is held to
+    entry ``r % lq``."""
+    rows = group * lq
 
     def body(*refs):
         qpos_ref = refs[n_scalar - 1]
@@ -222,11 +228,13 @@ def _make_body(n_scalar: int, lq: int, bs: int, sm_scale: float,
         # table rows, the scratch block's garbage — all arrive as q_pos).
         # q_pos sits in SMEM: one scalar read per query row, spread over
         # that row's lanes
-        row = jax.lax.broadcasted_iota(jnp.int32, (lq, bs), 0)
-        qp = jnp.full((lq, bs), qpos_ref[bi, 0], jnp.int32)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0)
+        if group > 1:
+            row = row % lq
+        qp = jnp.full((rows, bs), qpos_ref[bi, 0], jnp.int32)
         for r in range(1, lq):
             qp = jnp.where(row == r, qpos_ref[bi, r], qp)
-        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (lq, bs), 1)
+        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
         s = jnp.where(pos <= qp, s, -jnp.inf)
         # online softmax: rescale the running sums by exp(m_old - m_new)
         m_prev = m_ref[...]                             # [Lq, 1]
@@ -273,10 +281,14 @@ def _bias_index_map(bias_shape, paged: bool):
     return lambda b, h, j, qp: (b if bb else 0, h if hb else 0, 0, j)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "interpret", "group"))
 def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
-                sm_scale, interpret):
-    b, h, lq, d = q.shape
+                sm_scale, interpret, group=1):
+    # grouped K/V heads: ``q`` comes folded, [B, Hkv, group * Lq, D],
+    # one grid step a K/V head with its whole group's rows as the block
+    b, h, rows, d = q.shape
+    lq = rows // group
     _, _, bs, _ = k_pool.shape
     mb = table.shape[1]
     quant = k_scale is not None
@@ -292,7 +304,7 @@ def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
         return (tbl[bb, j], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, lq, d), lambda bb, hh, j, tbl, qp:
+        pl.BlockSpec((1, 1, rows, d), lambda bb, hh, j, tbl, qp:
                      (bb, hh, 0, 0)),
         pl.BlockSpec((1, 1, bs, d), pool_map),
         pl.BlockSpec((1, 1, bs, d), pool_map),
@@ -309,13 +321,13 @@ def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
         num_scalar_prefetch=2,
         grid=(b, h, mb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, lq, d), lambda bb, hh, j, tbl, qp:
+        out_specs=pl.BlockSpec((1, 1, rows, d), lambda bb, hh, j, tbl, qp:
                                (bb, hh, 0, 0)),
-        scratch_shapes=_scratch(lq, d))
+        scratch_shapes=_scratch(rows, d))
     return pl.pallas_call(
-        _make_body(2, lq, bs, sm_scale, quant, has_bias),
+        _make_body(2, lq, bs, sm_scale, quant, has_bias, group),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, lq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, rows, d), q.dtype),
         compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
     )(table, q_pos, *args)
@@ -341,6 +353,16 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, q_pos,
     nb, h, bs, d = k_pool.shape
     s = table.shape[1] * bs
     _check_common(q, q_pos, bias, s)
+    group = 1
+    if q.shape[1] != h:
+        # a pool of K/V heads under more query heads: the query heads
+        # that share a K/V head are one block of the kernel's rows
+        if q.shape[1] % h or bias is not None or k_scale is not None:
+            raise InvalidArgumentError(
+                "grouped K/V heads need query heads (%d) a whole multiple "
+                "of the pool's (%d), a float pool and no additive bias"
+                % (q.shape[1], h))
+        group = q.shape[1] // h
     if table.ndim != 2 or table.shape[0] != q.shape[0]:
         raise InvalidArgumentError(
             "table must be [B, max_blocks] int32 (got %r for q %r)"
@@ -349,11 +371,14 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, q_pos,
         raise InvalidArgumentError(
             "int8 pools carry BOTH k_scale and v_scale (got one)")
     with jax.named_scope("paged_attn"):
-        return _paged_call(q, k_pool, v_pool,
-                           jnp.asarray(table, jnp.int32),
-                           jnp.asarray(q_pos, jnp.int32),
-                           k_scale, v_scale, bias,
-                           float(sm_scale), bool(interpret))
+        # one head a head: both reshapes are the identity
+        b, _, lq, _ = q.shape
+        out = _paged_call(q.reshape(b, h, group * lq, d), k_pool, v_pool,
+                          jnp.asarray(table, jnp.int32),
+                          jnp.asarray(q_pos, jnp.int32),
+                          k_scale, v_scale, bias,
+                          float(sm_scale), bool(interpret), group=group)
+        return out.reshape(q.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))

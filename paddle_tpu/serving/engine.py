@@ -209,7 +209,7 @@ class _Record:
                  "max_new", "deadline_abs", "submit_t", "first_t",
                  "last_t", "tokens", "retries", "priority", "tenant",
                  "preempts", "preempted_at", "sampling", "adapter",
-                 "lock_wait_s", "admit_t")
+                 "lock_wait_s", "admit_t", "commit_steps")
 
     def __init__(self, rid, stream, prompt, max_new, deadline_abs,
                  submit_t, priority=0, tenant=None, sampling=None,
@@ -244,6 +244,9 @@ class _Record:
         # and the SLO plane run from admission: add ``lock_wait_s``
         self.lock_wait_s = lock_wait_s
         self.admit_t = None
+        # block-diffusion pools only: per committed token, the denoising
+        # step of its block that committed it (None on every other pool)
+        self.commit_steps = None
 
 
 class ServingEngine:
@@ -341,8 +344,15 @@ class ServingEngine:
                 "degrade_dwell_ticks and degrade_clear_ticks must be "
                 ">= 1 tick, got %r / %r"
                 % (degrade_dwell_ticks, degrade_clear_ticks))
+        by_blocks = getattr(model, "generation", None) == "block_diffusion"
         if draft_model is not None:
             from ..inference.speculative import SpeculativePool
+
+            if by_blocks:
+                raise InvalidArgumentError(
+                    "draft_model: generation by diffusion over blocks "
+                    "does not support speculative drafts (a block step "
+                    "already commits several tokens)")
 
             self._pool = SpeculativePool(model, draft_model, max_len,
                                          spec_k=4 if spec_k is None
@@ -357,6 +367,20 @@ class ServingEngine:
                 "decoding needs the draft — pass draft_model= (spec_k "
                 "then defaults to 4), or drop spec_k for a plain "
                 "engine" % (spec_k,))
+        elif by_blocks:
+            # the model declares how it generates: by diffusion over
+            # blocks, several tokens a step (inference/block_diffusion.py);
+            # the scheduler is unchanged, the pool refuses by name what
+            # that kind of step cannot do
+            from ..inference.block_diffusion import BlockDiffusionPool
+
+            if role != "fused":
+                raise InvalidArgumentError(
+                    "role=%r: generation by diffusion over blocks has no "
+                    "K/V hand-off between tiers — use role='fused'"
+                    % (role,))
+            self._pool = BlockDiffusionPool(model, max_len, slots=slots,
+                                            **pool_kwargs)
         else:
             self._pool = GenerationPool(model, max_len, slots=slots,
                                         **pool_kwargs)
@@ -609,6 +633,20 @@ class ServingEngine:
             "serving_acceptance_rate",
             "accepted draft tokens / drafted (speculative pool)") \
             if hasattr(self._pool, "acceptance_stats") else None
+        # block-diffusion pools: forwards by kind and tokens committed
+        # (a step commits 0..block_length tokens a sequence)
+        self._c_block = None
+        if hasattr(self._pool, "block_stats"):
+            self._c_block = {
+                "forwards_denoise": m.counter(
+                    "serving_block_forwards_denoise_total",
+                    "slot forwards that denoised a block"),
+                "forwards_store": m.counter(
+                    "serving_block_forwards_store_total",
+                    "slot forwards that stored a clean block's K/V"),
+                "tokens_committed": m.counter(
+                    "serving_block_tokens_committed_total",
+                    "tokens committed by denoising steps")}
         self._g_tps = m.gauge(
             "serving_tokens_per_sec",
             "tokens emitted / cumulative step time (StepTimer)")
@@ -957,6 +995,11 @@ class ServingEngine:
                                           now - rec.last_t)
         rec.last_t = now
         rec.tokens.append(int(tok))
+        step = getattr(self._pool, "token_commit_step", None)
+        if step is not None:
+            if rec.commit_steps is None:
+                rec.commit_steps = []
+            rec.commit_steps.append(step)
         if self._journal is not None:
             # buffered, not written: the tick's deltas ride ONE commit
             # record at flush (journal bandwidth stays O(ticks), not
@@ -1501,7 +1544,8 @@ class ServingEngine:
             total_s=now - rec.submit_t, error=error,
             lock_wait_s=rec.lock_wait_s,
             queue_wait_s=(None if rec.admit_t is None
-                          else rec.admit_t - rec.submit_t)))
+                          else rec.admit_t - rec.submit_t),
+            commit_steps=rec.commit_steps))
 
     def cancel(self, request_id) -> bool:
         """Abort a live request: its slot and paged blocks are freed
@@ -2357,6 +2401,11 @@ class ServingEngine:
         if self._g_accept is not None:
             self._g_accept.set(
                 pool.acceptance_stats()["acceptance_rate"])
+        if self._c_block is not None:
+            # the pool's totals only grow: a counter moves by what its
+            # total gained since the counter last read it
+            for key, now in pool.block_stats().items():
+                self._c_block[key].inc(now - self._c_block[key].value)
         if self._g_prefix_hit is not None or self._c_chunks is not None:
             pstats = pool.prefix_stats()
             if self._g_prefix_hit is not None:

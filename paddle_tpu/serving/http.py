@@ -330,6 +330,10 @@ def _make_handler(engine: ServingEngine, quiet: bool = True):
                         (json.dumps({"token": int(tok)}) + "\n").encode())
                     self.wfile.flush()
                 st = stream.result(timeout_s=None)
+                # per token, the denoising step that committed it: only
+                # a block-diffusion engine's line carries the key
+                steps = {} if st.commit_steps is None else {
+                    "commit_steps": [int(x) for x in st.commit_steps]}
                 self.wfile.write((json.dumps({
                     "done": True,
                     "request_id": st.request_id,
@@ -343,6 +347,7 @@ def _make_handler(engine: ServingEngine, quiet: bool = True):
                     "lock_wait_s": st.lock_wait_s,
                     "queue_wait_s": st.queue_wait_s,
                     "error": st.error,
+                    **steps,
                 }) + "\n").encode())
             except OSError:
                 # the consumer hung up (BrokenPipe/ConnectionReset/

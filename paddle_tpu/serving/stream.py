@@ -70,12 +70,15 @@ class RequestState:
 # the caller saw; ``queue_wait_s`` is admission to the first slot-take
 # (None when the request never took one).  Both are None on the
 # statuses the fleet and the disaggregated front build themselves.
+# ``commit_steps`` is set only by a pool that generates by diffusion over
+# blocks: per token of ``tokens``, the denoising step of its block that
+# committed it.
 StreamStatus = collections.namedtuple(
     "StreamStatus",
     ["request_id", "state", "finish_reason", "tokens", "prompt_tokens",
      "new_tokens", "ttft_s", "total_s", "error", "lock_wait_s",
-     "queue_wait_s"],
-    defaults=(None, None))
+     "queue_wait_s", "commit_steps"],
+    defaults=(None, None, None))
 
 _TERMINAL = object()
 

@@ -11,6 +11,7 @@ library, the worker that is given this file loads it inside the fixture,
 and a second file could land on a worker where it cannot.
 """
 import importlib
+import re
 
 import numpy as np
 import pytest
@@ -103,3 +104,38 @@ def test_pool_decode_on_the_v5e_moves_no_pool(one_chip, model, monkeypatch,
     assert set(made) <= {"parameter", "scatter", "fusion", "bitcast",
                          "copy-start", "copy-done"}, sorted(set(made))
     assert made.count("fusion") == made.count("scatter")
+
+
+def test_grouped_heads_kernel_and_grouped_matmul_on_the_v5e(one_chip,
+                                                            monkeypatch):
+    """The block-diffusion cell's two kernels at its real shapes: the
+    paged kernel with the 8 query heads of a K/V head as one block of 32
+    rows over a bfloat16 pool [641, 4, 128, 128], and the expert layer's
+    grouped matmuls at a 2,048-row prefill (one ``ragged-dot`` custom
+    call each) beside its every-expert route at a 128-row step (none).
+    Interpret mode cannot refuse a tile; this compiler can."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional import moe
+    from paddle_tpu.ops import pallas_decode
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    bf = jnp.bfloat16
+    text = jax.jit(
+        lambda q, k, v, t, p: pallas_decode.paged_decode_attention_kernel(
+            q, k, v, t, p, 128 ** -0.5)).lower(
+        shape((32, 32, 4, 128), bf), shape((641, 4, 128, 128), bf),
+        shape((641, 4, 128, 128), bf), shape((32, 20), jnp.int32),
+        shape((32, 4), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    experts = [shape((2048, 128), bf), shape((128, 2048, 768), bf),
+               shape((128, 2048, 768), bf), shape((128, 768, 2048), bf)]
+    layer = jax.jit(lambda x, *w: moe.sparse_experts(x, *w, top_k=8))
+    for rows, grouped in ((2048, 3), (128, 0)):
+        text = layer.lower(shape((rows, 2048), bf),
+                           *experts).compile().as_text()
+        assert len(set(re.findall(r"ragged-dot-none[\w.]* = ",
+                                  text))) == grouped, rows
