@@ -20,7 +20,9 @@ comes out by the repo's own means.  Phases:
 - kernels  the fused decode kernels forced by ``route="pallas"`` on a
            two-layer model at the serving geometry: dense and paged,
            fp32 and int8, Lq 1 and 5, against the composition, with the
-           TPU custom call found in the compiled decode program; one
+           TPU custom call found in the compiled decode program; the
+           paged kernel's grouped-rows form on a bfloat16 pool at
+           sdar-30b-a3b's cache geometry; one
            forward-and-backward step at sequence 8192 through the
            library flash kernel;
 - mesh     (four devices or more) the same requests under
@@ -100,6 +102,8 @@ def sizes(toy: bool) -> dict:
             requests=6, new_tokens=6, spill_tokens=12,
             bert=bert, train_batch=4, train_seq=32,
             kernel_lm=lm, kernel_slots=2, kernel_max_len=64,
+            grouped=dict(slots=2, q_heads=4, kv_heads=2, rows=4, block=8,
+                         head_dim=16, table=4),
             flash=dict(vocab_size=256, hidden_size=64, num_layers=1,
                        num_heads=2, intermediate_size=128,
                        max_position=128, causal=True),
@@ -114,6 +118,11 @@ def sizes(toy: bool) -> dict:
         bert=bert_base_config(), train_batch=40, train_seq=512,
         kernel_lm=dict(lm, num_layers=2), kernel_slots=8,
         kernel_max_len=256,
+        # sdar-30b-a3b's cache: 32 query heads on 4 K/V heads of 128,
+        # blocks of 128 positions, 32 slots x 20 table entries, a block
+        # of 4 query positions
+        grouped=dict(slots=32, q_heads=32, kv_heads=4, rows=4, block=128,
+                     head_dim=128, table=20),
         # bench_longseq_flash's on-chip configuration (head_dim 128)
         flash=dict(vocab_size=32000, hidden_size=1024, num_layers=4,
                    num_heads=8, intermediate_size=4096,
@@ -622,6 +631,53 @@ def _kernel_variant(jax, model, sz: dict, layout: str, dtype: str,
            worst, tol))
 
 
+def _grouped_kernel(jax, sz: dict, tol: float, platform: str) -> None:
+    """The paged kernel's grouped form on a bfloat16 pool: the query
+    heads that share a K/V head are one block of rows, every row of a
+    slot sees to the end of its block of positions.  Slots hold
+    different lengths, one of them a single block, so the kernel skips
+    most of the table; against the composition on the gathered cache."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import paged_decode_attention
+
+    g = sz["grouped"]
+    b, mb, bs, d = g["slots"], g["table"], g["block"], g["head_dim"]
+    rng = np.random.RandomState(3)
+    q = jnp.asarray(rng.randn(b, g["q_heads"], g["rows"], d), jnp.bfloat16)
+    nb = 1 + b * mb
+    k_pool, v_pool = (jnp.asarray(rng.randn(nb, g["kv_heads"], bs, d),
+                                  jnp.bfloat16) for _ in range(2))
+    table = jnp.asarray(1 + np.arange(b * mb).reshape(b, mb), jnp.int32)
+    ends = rng.randint(1, mb * bs // g["rows"], b) * g["rows"] - 1
+    ends[0] = g["rows"] - 1
+    q_pos = jnp.asarray(np.repeat(ends[:, None], g["rows"], 1), jnp.int32)
+
+    def both(q, k_pool, v_pool, table, q_pos):
+        return [paged_decode_attention(q, k_pool, v_pool, table,
+                                       q_pos=q_pos, route=route)
+                for route in ("pallas", "composition")]
+
+    compiled = jax.jit(both).lower(q, k_pool, v_pool, table,
+                                   q_pos).compile()
+    check_kernel_in_program(compiled.as_text(), platform, "grouped/bf16")
+    pal, ref = (np.asarray(x.astype(jnp.float32))
+                for x in compiled(q, k_pool, v_pool, table, q_pos))
+    check(np.isfinite(pal).all(), "grouped/bf16: kernel output not finite")
+    # both sides round their float32 sums to bfloat16 at the end: an
+    # ulp is up to 2**-7 of a value, so two of them
+    tol = max(tol, 2.0 ** -6)
+    scale = max(1.0, float(np.abs(ref).max()))
+    diff = float(np.abs(pal - ref).max())
+    check(diff <= tol * scale,
+          "grouped/bf16: kernel vs composition differ by %.3g (> %.3g)"
+          % (diff, tol * scale))
+    say("[kernels] grouped/bf16   %d query heads on %d K/V heads, %d rows, "
+        "blocks of %d: max |diff| vs composition %.3g of scale (tolerance "
+        "%.3g)" % (g["q_heads"], g["kv_heads"], g["rows"], bs,
+                   diff / scale, tol))
+
+
 def _flash_step(pt, jax, sz: dict, platform: str) -> None:
     """One forward-and-backward step of the long-sequence configuration
     through the library's Pallas flash attention."""
@@ -694,6 +750,7 @@ def phase_kernels(pt, jax, sz: dict, tol: float, state: dict) -> None:
     for layout in ("dense", "paged"):
         for dtype in ("float32", "int8"):
             _kernel_variant(jax, model, sz, layout, dtype, tol, platform)
+    _grouped_kernel(jax, sz, tol, platform)
     # a geometry the kernel cannot take: the forced route must refuse
     # it by name — never decode on the composition instead
     heads = sz["kernel_lm"]["num_heads"]

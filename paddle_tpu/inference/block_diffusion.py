@@ -195,9 +195,8 @@ class BlockDiffusionPool(GenerationPool):
         count = ctl[:, 2 * bl]
         advance = (ctl[:, 2 * bl + 1] != 0) & (ctl[:, 2 * bl + 2] != 0)
         active = ctl[:, 2 * bl + 2] != 0
-        tables = None
+        given = cache
         if self.cache_layout == "paged":
-            tables = [c.table for c in cache]
             cache = self._masked_tables(cache, active)
         logits, new_cache = self._session._run_model(
             param_vals, buf_vals, toks, cache)
@@ -220,10 +219,10 @@ class BlockDiffusionPool(GenerationPool):
         with jax.named_scope("cache_freeze"):
             # the forward moved every index by B: keep that for a live
             # slot's store and undo it everywhere else
-            new_cache = self._layout.freeze_step(new_cache, cache, advance)
-        if tables is not None:
-            new_cache = [c._replace(table=t)
-                         for c, t in zip(new_cache, tables)]
+            new_cache = self._layout.freeze_step(new_cache, given, advance)
+        if cache is not given:
+            new_cache = [c._replace(table=g.table)
+                         for c, g in zip(new_cache, given)]
         return new_cache, out
 
     # -- host API --------------------------------------------------------
@@ -326,6 +325,13 @@ class BlockDiffusionPool(GenerationPool):
             self._blocks[slot] = self._new_block(req.ids[whole:],
                                                  req.max_new_tokens)
 
+    def _last_position(self, slot: int, state) -> int:
+        # the end of the slot's current block: every row of it sees that
+        # far (tokens of the block that already left are in ``tokens``)
+        start = len(state.ids) + len(state.tokens) \
+            - self._blocks[slot].delivered
+        return start + self._B - 1
+
     def _control(self) -> np.ndarray:
         """The tick's packed upload (see ``_block_step``)."""
         rows = [self._idle_row] * self.slots
@@ -364,7 +370,8 @@ class BlockDiffusionPool(GenerationPool):
             with tr.span("tick.decode", live=live, slots=self.slots,
                          kind=kind, rows=live * bl, store=stores,
                          denoise=live - stores, committed=committed,
-                         tokens_per_forward=committed / live):
+                         tokens_per_forward=committed / live,
+                         **self._block_meta()):
                 self._cache, out_dev = self._decode_jit(
                     params, bufs, self._cache, ctl)
             with tr.span("tick.sample"):

@@ -787,14 +787,16 @@ class GenerationPool:
         Paged: an inactive slot's table row is zeroed FOR THE STEP so
         its (discarded) write lands in the scratch block — its old blocks
         may already belong to a refilled request, and a stale-table write
-        would corrupt that request's cache.  The ORIGINAL rows are
-        restored in the returned cache: under chunked prefill an
-        inactive slot can be mid-prompt, and persisting the zeroed row
-        would wipe the mapping its next chunk writes through."""
+        would corrupt that request's cache — and its index reads 0, so
+        the kernel walks one block of scratch for it and not its last
+        request's length.  The ORIGINAL rows and index are restored in
+        the returned cache: under chunked prefill an inactive slot can
+        be mid-prompt, and persisting the masked row would wipe the
+        mapping its next chunk writes through and the position it
+        writes at."""
         sess = self._session
-        tables = None
+        given = cache
         if self.cache_layout == "paged":
-            tables = [c.table for c in cache]
             cache = self._masked_tables(cache, active)
         logits, new_cache = sess._run_model(param_vals, buf_vals,
                                             toks[:, None], cache,
@@ -812,10 +814,10 @@ class GenerationPool:
         # index; the recurrent layout must also restore inactive slots'
         # state carry (a recurrence updates every row every step)
         with jax.named_scope("cache_freeze"):
-            new_cache = self._layout.freeze_step(new_cache, cache, active)
-        if tables is not None:
-            new_cache = [c._replace(table=t)
-                         for c, t in zip(new_cache, tables)]
+            new_cache = self._layout.freeze_step(new_cache, given, active)
+        if cache is not given:
+            new_cache = [c._replace(table=g.table)
+                         for c, g in zip(new_cache, given)]
         return new_cache, jnp.where(active, tok, 0), step
 
     def _masked_tables(self, cache, active):
@@ -823,11 +825,16 @@ class GenerationPool:
         scratch block for the step (all zeros when dp == 1 — the
         legacy global scratch): a stale write may not land in blocks a
         refilled request now owns, and under a mesh it may not cross
-        into another shard's partition either.  Traced helper, shared
-        with the speculative verify step."""
+        into another shard's partition either.  Their index reads 0 for
+        the step: the attention kernel's cost follows the positions a
+        row says it holds, and a free slot's index is its last
+        request's length.  The caller restores both from the cache it
+        was given.  Traced helper, shared with the speculative verify
+        step and the block-diffusion step."""
         scratch = jnp.asarray(self._scratch_row)[:, None]
         return [c._replace(table=jnp.where(active[:, None], c.table,
-                                           scratch))
+                                           scratch),
+                           index=jnp.where(active, c.index, 0))
                 for c in cache]
 
     def _admit(self, cache, slot, row, index):
@@ -2717,7 +2724,7 @@ class GenerationPool:
             tok = np.asarray(tok_dev)
         else:
             with tr.span("tick.decode", live=len(self._active),
-                         slots=self.slots):
+                         slots=self.slots, **self._block_meta()):
                 tok_dev = self._dispatch(params, bufs)
             with tr.span("tick.sample"):
                 # the per-tick host download of the sampled ids — the
@@ -2732,6 +2739,23 @@ class GenerationPool:
                 self._deliver(tok)
         return bool(self._active or self._queue or self._prefilling
                     or self._spilled or self._prefill_done)
+
+    def _last_position(self, slot: int, state) -> int:
+        """The last position the coming step lets ``slot`` see: the one
+        it writes."""
+        return len(state.ids) + len(state.tokens) - 1
+
+    def _block_meta(self) -> dict:
+        """``tick.decode``'s meta on a paged pool: ``live_blocks``, the
+        table entries the live slots' positions reach (what the
+        attention kernel fetches and computes: ``ops/pallas_decode.py``
+        skips the rest), and ``table_blocks``, slots x table width."""
+        if self.cache_layout != "paged":
+            return {}
+        bs = self._block_size
+        return {"live_blocks": sum(self._last_position(slot, st) // bs + 1
+                                   for slot, st in self._active.items()),
+                "table_blocks": self.slots * self._max_blocks}
 
     def _dispatch(self, params, bufs):
         """The one batched decode dispatch (cache donated and rebound in

@@ -260,23 +260,23 @@ class SpeculativePool(GenerationPool):
         zeroed, index unchanged."""
         sess = self._session
         idx0 = cache[0].index                                # [slots]
-        tables = None
+        given = cache
         if self.cache_layout == "paged":
             # inactive rows' tables are scratch-routed FOR the step
-            # (each slot to ITS shard's scratch block) but restored in
-            # the returned cache: under chunked prefill an inactive
-            # slot can be mid-prompt, and persisting the masked row
-            # would wipe its mapping
-            tables = [c.table for c in cache]
+            # (each slot to ITS shard's scratch block) and their index
+            # reads 0, but both are restored in the returned cache
+            # (the index from ``idx0``): under chunked prefill an
+            # inactive slot can be mid-prompt, and persisting the
+            # masked row would wipe its mapping
             cache = self._masked_tables(cache, active)
         logits, new_cache = sess._run_model(param_vals, buf_vals, chunk,
                                             cache, adapter)
         m, emitted = greedy_accept(logits, chunk, active)    # [S], [S,K+1]
         new_idx = jnp.where(active, idx0 + m + 1, idx0)
         new_cache = [c._replace(index=new_idx) for c in new_cache]
-        if tables is not None:
-            new_cache = [c._replace(table=t)
-                         for c, t in zip(new_cache, tables)]
+        if cache is not given:
+            new_cache = [c._replace(table=g.table)
+                         for c, g in zip(new_cache, given)]
         # pending = each row's LAST emitted token, the next round's
         # draft input — computed here so the steady state feeds straight
         # back on-device

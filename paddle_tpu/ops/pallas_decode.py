@@ -9,12 +9,17 @@ the paged path's data-dependent table gather MATERIALIZES the gathered
 dequantize up-casts the whole gathered cache to fp32 there too — 4-8x
 the bytes the cache actually holds.
 
-This kernel crosses both boundaries by hand.  Per ``(batch row, head,
-logical block)`` grid step it
+This kernel crosses both boundaries by hand.  Per ``(batch row, chunk
+of heads, logical block)`` grid step it
 
 - reads the row's block table (a scalar-prefetch operand, so the block
   index feeds the DMA descriptor *before* the body runs) and streams
-  that ONE physical K/V block from the pool in HBM into VMEM;
+  that ONE physical K/V block from the pool in HBM into VMEM, for every
+  head of the chunk at once (``head_chunk``: all of them where they fit;
+  the pool keeps a block's heads contiguous);
+- costs what the live K/V costs: a block past the row's last visible
+  position (``_last_entry``, from ``q_pos``) is neither fetched (the
+  index maps name the last live block again) nor computed;
 - dequantizes int8 rows in VMEM — the per-head scales are gathered
   through the SAME table row, so a remapped block always carries its
   own scales;
@@ -50,8 +55,9 @@ layer excludes it by reason instead of finding out from the compiler:
 - ``q_pos`` is a scalar-prefetch operand in SMEM, which serves scalar
   reads only: the ``[Lq, bs]`` mask threshold is assembled from ``Lq``
   scalar reads, never a vector load;
-- int8 scales stream as one ``[H, bs]`` block (all heads, lane-major)
-  and the head's ``[1, bs]`` row multiplies the ``[Lq, bs]`` score and
+- int8 scales stream as one ``[hc, bs]`` block (the chunk's heads on
+  the sublanes: a multiple of 8 or all of them, lane-major) and a
+  head's ``[1, bs]`` row multiplies its ``[Lq, bs]`` score and
   probability rows — ``(q·k)·s == q·(k·s)`` — so no lane vector is
   ever turned into a sublane column;
 - the dense tile is bounded (``_DENSE_TILES``): a cache length no tile
@@ -173,16 +179,59 @@ def _check_common(q, q_pos, bias, s: int):
                 % ((b, h, lq, s), tuple(bs_)))
 
 
+# VMEM the K and V blocks of one grid step may take: each double-buffered
+# by the pipeline in the cache dtype, plus the float32 copy the body makes
+# of each.  A quarter of the 16 MiB a v5e kernel is given, so q, the
+# output, scores and scratch fit beside them at any geometry.
+_KV_VMEM_BUDGET = 4 * 1024 * 1024
+
+
+def head_chunk(h: int, bs: int, d: int, itemsize: int,
+               quant: bool = False) -> int:
+    """How many heads of a K/V block one grid step takes: the largest
+    divisor of ``h`` whose K and V blocks ``[hc, bs, d]`` fit
+    ``_KV_VMEM_BUDGET``.  From shapes alone, so every caller at one
+    geometry gets one kernel.
+
+    An int8 cache's scale block is ``[hc, bs]`` with ``hc`` on the
+    sublanes: a multiple of 8 or all of ``h`` (the smallest such when
+    none fits)."""
+    per_head = 2 * bs * d * (2 * itemsize + 4)
+    legal = [c for c in range(1, h + 1) if h % c == 0
+             and (not quant or c % _SUBLANES == 0 or c == h)]
+    fit = [c for c in legal if c * per_head <= _KV_VMEM_BUDGET]
+    return max(fit) if fit else min(legal)
+
+
+def _last_entry(qpos_ref, bi, lq: int, bs: int):
+    """The last K/V block any query of batch row ``bi`` may see, from
+    ``q_pos`` in SMEM.  0 for a row that sees nothing (``q_pos`` < 0):
+    its one block is computed, wholly masked."""
+    top = qpos_ref[bi, 0]
+    for r in range(1, lq):
+        top = jnp.maximum(top, qpos_ref[bi, r])
+    # (not negative, so the truncating division is the floor)
+    return jax.lax.div(jnp.maximum(top, 0), jnp.int32(bs))
+
+
 def _make_body(n_scalar: int, lq: int, bs: int, sm_scale: float,
                quant: bool, has_bias: bool, group: int = 1):
-    """The shared inner loop.  Ref order after the ``n_scalar``
-    scalar-prefetch refs (q_pos always last among them): q, k, v,
-    [k_scale, v_scale,] [bias,] out, then m/l/acc VMEM scratch.
+    """The shared inner loop over a chunk of ``hc`` heads.  Ref order
+    after the ``n_scalar`` scalar-prefetch refs (q_pos always last among
+    them): q ``[1, hc, rows, D]``, k, v ``[1, hc, bs, D]``, [k_scale,
+    v_scale ``[1, hc, bs]``,] [bias ``[1, hc|1, Lq, bs]``,] out, then
+    m/l/acc VMEM scratch with a leading ``hc``.
 
     ``group`` > 1: the q block holds the ``group`` query heads that
-    share this K/V head, ``lq`` rows each (row ``i * lq + l``), and
+    share each K/V head, ``lq`` rows each (row ``i * lq + l``), and
     ``q_pos`` still has ``lq`` entries a batch row: row ``r`` is held to
-    entry ``r % lq``."""
+    entry ``r % lq``.
+
+    Blocks past the row's last visible one (``_last_entry``) are dead:
+    the index maps name the last live block again, so nothing is
+    fetched, and the arithmetic is skipped.  A wholly masked block
+    leaves m/l/acc as they are (``alpha`` = 1, ``p`` = 0), so the result
+    is the same to the bit."""
     rows = group * lq
 
     def body(*refs):
@@ -199,7 +248,6 @@ def _make_body(n_scalar: int, lq: int, bs: int, sm_scale: float,
         o_ref, m_ref, l_ref, acc_ref = refs[i:i + 4]
 
         bi = pl.program_id(0)
-        hi = pl.program_id(1)
         j = pl.program_id(2)
 
         @pl.when(j == 0)
@@ -208,47 +256,51 @@ def _make_body(n_scalar: int, lq: int, bs: int, sm_scale: float,
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        qb = q_ref[0, 0].astype(jnp.float32)            # [Lq, D]
-        # the HBM read above was the cache dtype — the up-cast happens
-        # here in VMEM, on one block, never on the gathered cache
-        kb = k_ref[0, 0].astype(jnp.float32)            # [bs, D]
-        vb = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            qb, kb, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [Lq, bs]
-        if quant:
-            # int8 dequant folded into the score row: (q·k)·s == q·(k·s)
-            # per key, and the head's [1, bs] scale row is already
-            # lane-major like the scores
-            s = s * ks_ref[0, pl.ds(hi, 1), :]
-        s = s * sm_scale
-        if has_bias:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        # mask keys past each query's position (lengths masking, stale
-        # table rows, the scratch block's garbage — all arrive as q_pos).
-        # q_pos sits in SMEM: one scalar read per query row, spread over
-        # that row's lanes
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0)
-        if group > 1:
-            row = row % lq
-        qp = jnp.full((rows, bs), qpos_ref[bi, 0], jnp.int32)
-        for r in range(1, lq):
-            qp = jnp.where(row == r, qpos_ref[bi, r], qp)
-        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
-        s = jnp.where(pos <= qp, s, -jnp.inf)
-        # online softmax: rescale the running sums by exp(m_old - m_new)
-        m_prev = m_ref[...]                             # [Lq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                          # masked -> 0
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1,
-                                                  keepdims=True)
-        if quant:
-            p = p * vs_ref[0, pl.ds(hi, 1), :]          # p·(v·s)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, vb, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        @pl.when(j <= _last_entry(qpos_ref, bi, lq, bs))
+        def _():
+            qb = q_ref[0].astype(jnp.float32)           # [hc, rows, D]
+            # the HBM read was the cache dtype — the up-cast happens
+            # here in VMEM, on one block, never on the gathered cache
+            kb = k_ref[0].astype(jnp.float32)           # [hc, bs, D]
+            vb = v_ref[0].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                qb, kb, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)     # [hc, rows, bs]
+            if quant:
+                # int8 dequant folded into the score rows: (q·k)·s ==
+                # q·(k·s) per key, and a head's [1, bs] scale row is
+                # lane-major like its scores
+                s = s * ks_ref[0][:, None, :]
+            s = s * sm_scale
+            if has_bias:
+                s = s + bias_ref[0].astype(jnp.float32)
+            # mask keys past each query's position (lengths masking,
+            # stale table rows, the scratch block's garbage — all arrive
+            # as q_pos).  q_pos sits in SMEM: one scalar read per query
+            # row, spread over that row's lanes
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0)
+            if group > 1:
+                row = row % lq
+            qp = jnp.full((rows, bs), qpos_ref[bi, 0], jnp.int32)
+            for r in range(1, lq):
+                qp = jnp.where(row == r, qpos_ref[bi, r], qp)
+            pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs),
+                                                    1)
+            s = jnp.where((pos <= qp)[None], s, -jnp.inf)
+            # online softmax: rescale the running sums by
+            # exp(m_old - m_new)
+            m_prev = m_ref[...]                         # [hc, rows, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+            p = jnp.exp(s - m_new)                      # masked -> 0
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2,
+                                                      keepdims=True)
+            if quant:
+                p = p * vs_ref[0][:, None, :]           # p·(v·s)
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                p, vb, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)     # [hc, rows, D]
+            m_ref[...] = m_new
 
         @pl.when(j == pl.num_programs(2) - 1)
         def _():
@@ -256,29 +308,37 @@ def _make_body(n_scalar: int, lq: int, bs: int, sm_scale: float,
             # a row with no visible key (q_pos < 0 everywhere) emits 0
             # rather than NaN; real decode rows always see position 0
             l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
     return body
 
 
-# batch rows and heads are independent; the block axis carries the
+# batch rows and head chunks are independent; the block axis carries the
 # online-softmax state in scratch, so it runs in order
 _GRID_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _scratch(lq: int, d: int):
-    return [pltpu.VMEM((lq, 1), jnp.float32),   # running max
-            pltpu.VMEM((lq, 1), jnp.float32),   # running normalizer
-            pltpu.VMEM((lq, d), jnp.float32)]   # weighted-V accumulator
+def _scratch(hc: int, rows: int, d: int):
+    return [pltpu.VMEM((hc, rows, 1), jnp.float32),   # running max
+            pltpu.VMEM((hc, rows, 1), jnp.float32),   # running normalizer
+            pltpu.VMEM((hc, rows, d), jnp.float32)]   # weighted-V sum
 
 
-def _bias_index_map(bias_shape, paged: bool):
+def _live_block(lq: int, bs: int):
+    """``live(b, j, *scalars)`` for the index maps: ``j`` held to the
+    row's last live block (``q_pos`` is the last scalar-prefetch ref),
+    so a dead step names the block the pipeline already holds."""
+    return lambda b, j, *sc: jnp.minimum(j, _last_entry(sc[-1], b, lq, bs))
+
+
+def _bias_spec(bias_shape, hc: int, lq: int, bs: int, live):
+    """The bias block of a head chunk, ``j`` clamped by ``live``."""
     bb, hb = bias_shape[0] > 1, bias_shape[1] > 1
-    if paged:
-        return lambda b, h, j, tbl, qp: (b if bb else 0,
-                                         h if hb else 0, 0, j)
-    return lambda b, h, j, qp: (b if bb else 0, h if hb else 0, 0, j)
+    return pl.BlockSpec(
+        (1, hc if hb else 1, lq, bs),
+        lambda b, h, j, *sc: (b if bb else 0, h if hb else 0, 0,
+                              live(b, j, *sc)))
 
 
 @functools.partial(jax.jit,
@@ -286,44 +346,42 @@ def _bias_index_map(bias_shape, paged: bool):
 def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
                 sm_scale, interpret, group=1):
     # grouped K/V heads: ``q`` comes folded, [B, Hkv, group * Lq, D],
-    # one grid step a K/V head with its whole group's rows as the block
+    # a K/V head's whole group its block of rows
     b, h, rows, d = q.shape
     lq = rows // group
     _, _, bs, _ = k_pool.shape
     mb = table.shape[1]
     quant = k_scale is not None
     has_bias = bias is not None
+    hc = head_chunk(h, bs, d, k_pool.dtype.itemsize, quant)
+    live = _live_block(lq, bs)
 
     def pool_map(bb, hh, j, tbl, qp):
-        return (tbl[bb, j], hh, 0, 0)
+        return (tbl[bb, live(bb, j, tbl, qp)], hh, 0, 0)
 
-    def scale_map(bb, hh, j, tbl, qp):
-        # every head's scales for the block: [H, bs] is the smallest
-        # block whose last two dims are legal (a [1, bs] slice of the
-        # H axis is neither a whole sublane tile nor the full dim)
-        return (tbl[bb, j], 0, 0)
+    def row_map(bb, hh, j, tbl, qp):
+        return (bb, hh, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, rows, d), lambda bb, hh, j, tbl, qp:
-                     (bb, hh, 0, 0)),
-        pl.BlockSpec((1, 1, bs, d), pool_map),
-        pl.BlockSpec((1, 1, bs, d), pool_map),
+        pl.BlockSpec((1, hc, rows, d), row_map),
+        pl.BlockSpec((1, hc, bs, d), pool_map),
+        pl.BlockSpec((1, hc, bs, d), pool_map),
     ]
     args = [q, k_pool, v_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, h, bs), scale_map)] * 2
+        in_specs += [pl.BlockSpec(
+            (1, hc, bs), lambda bb, hh, j, tbl, qp:
+            (tbl[bb, live(bb, j, tbl, qp)], hh, 0))] * 2
         args += [k_scale, v_scale]
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, 1, lq, bs),
-                                     _bias_index_map(bias.shape, True)))
+        in_specs.append(_bias_spec(bias.shape, hc, lq, bs, live))
         args.append(bias)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, mb),
+        grid=(b, h // hc, mb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rows, d), lambda bb, hh, j, tbl, qp:
-                               (bb, hh, 0, 0)),
-        scratch_shapes=_scratch(rows, d))
+        out_specs=pl.BlockSpec((1, hc, rows, d), row_map),
+        scratch_shapes=_scratch(hc, rows, d))
     return pl.pallas_call(
         _make_body(2, lq, bs, sm_scale, quant, has_bias, group),
         grid_spec=grid_spec,
@@ -390,32 +448,35 @@ def _dense_call(q, k, v, q_pos, k_scale, v_scale, bias, sm_scale,
     mb = s // bs
     quant = k_scale is not None
     has_bias = bias is not None
+    hc = head_chunk(h, bs, d, k.dtype.itemsize, quant)
+    # a tile past the row's last visible key is dead like a paged block
+    live = _live_block(lq, bs)
 
     def seq_map(bb, hh, j, qp):
-        return (bb, hh, j, 0)
+        return (bb, hh, live(bb, j, qp), 0)
+
+    def row_map(bb, hh, j, qp):
+        return (bb, hh, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, lq, d), lambda bb, hh, j, qp:
-                     (bb, hh, 0, 0)),
-        pl.BlockSpec((1, 1, bs, d), seq_map),
-        pl.BlockSpec((1, 1, bs, d), seq_map),
+        pl.BlockSpec((1, hc, lq, d), row_map),
+        pl.BlockSpec((1, hc, bs, d), seq_map),
+        pl.BlockSpec((1, hc, bs, d), seq_map),
     ]
     args = [q, k, v]
     if quant:
-        in_specs += [pl.BlockSpec((1, h, bs), lambda bb, hh, j, qp:
-                                  (bb, 0, j))] * 2
+        in_specs += [pl.BlockSpec((1, hc, bs), lambda bb, hh, j, qp:
+                                  (bb, hh, live(bb, j, qp)))] * 2
         args += [k_scale, v_scale]
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, 1, lq, bs),
-                                     _bias_index_map(bias.shape, False)))
+        in_specs.append(_bias_spec(bias.shape, hc, lq, bs, live))
         args.append(bias)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, h, mb),
+        grid=(b, h // hc, mb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, lq, d), lambda bb, hh, j, qp:
-                               (bb, hh, 0, 0)),
-        scratch_shapes=_scratch(lq, d))
+        out_specs=pl.BlockSpec((1, hc, lq, d), row_map),
+        scratch_shapes=_scratch(hc, lq, d))
     return pl.pallas_call(
         _make_body(1, lq, bs, sm_scale, quant, has_bias),
         grid_spec=grid_spec,

@@ -12,6 +12,10 @@ route is FASTER) is left to on-chip sweeps:
   verify shapes), scalar and per-row ``lengths``;
 - masking: scratch-block garbage and stale table rows past the valid
   prefix never leak into the softmax;
+- cost follows the live K/V: the head chunk is a function of shapes,
+  blocks past a row's last visible one are never computed (NaN there
+  stays there), a row that sees nothing emits zeros, and the step that
+  masks an inactive slot's table masks its index and restores both;
 - routing: ``route=`` forcing and the ambient ``decode_route`` context,
   typed errors on unknown routes, the backend-lookup memo + reset hook;
 - the serving contract: a ``GenerationPool`` slot-churn run with
@@ -73,6 +77,154 @@ def test_paged_kernel_matches_composition(lq, quant):
         q, k_pool, v_pool, table, lengths=lengths, k_scale=ks,
         v_scale=vs, route="composition"))
     np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+def _kv_budget(monkeypatch, heads, bs, d, itemsize):
+    """Hold the K/V blocks of a grid step to ``heads`` heads (the chunk
+    comes from shapes and this one constant) and drop the traces made
+    under another budget."""
+    monkeypatch.setattr(pd, "_KV_VMEM_BUDGET",
+                        heads * 2 * bs * d * (2 * itemsize + 4))
+    pd._paged_call.clear_cache()
+    pd._dense_call.clear_cache()
+
+
+@pytest.mark.parametrize("lq", [1, 4, 8])
+@pytest.mark.parametrize("form,quant", [
+    ("plain", False), ("plain", True), ("bias", False), ("bias", True),
+    ("grouped", False)],
+    ids=["plain-fp32", "plain-int8", "bias-fp32", "bias-int8",
+         "grouped-fp32"])
+@pytest.mark.parametrize("chunk", ["one", "part", "all"])
+def test_paged_kernel_head_chunks_match_composition(monkeypatch, chunk,
+                                                    form, quant, lq):
+    # every head chunk the rule can choose (a float pool of 4 heads: 1,
+    # 2, 4; an int8 pool of 32: 8, 16, 32, its scale block's sublanes),
+    # every row count, scales, bias and grouped rows: one answer
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(11)
+    b, bs, d, mb = 3, 8, 16, 4
+    h = 32 if quant else 4
+    hc = {"one": h // 4, "part": h // 2, "all": h}[chunk]
+    if quant:
+        assert hc % 8 == 0
+    _kv_budget(monkeypatch, hc, bs, d, 1 if quant else 4)
+    assert pd.head_chunk(h, bs, d, 1 if quant else 4, quant) == hc
+    hq = 2 * h if form == "grouped" else h
+    q, k_pool, v_pool, table, ks, vs = _paged_case(rng, b, h, bs, d, mb,
+                                                   lq, quant)
+    q = jnp.asarray(rng.randn(b, hq, lq, d).astype(np.float32))
+    q_pos = np.array([3, 17, 24], np.int32)[:, None] + (
+        0 if form == "grouped" else np.arange(lq, dtype=np.int32))
+    kwargs = dict(k_scale=ks, v_scale=vs,
+                  q_pos=jnp.asarray(np.broadcast_to(q_pos, (b, lq))))
+    if form == "bias":
+        bias = np.where(rng.rand(b, h, lq, mb * bs) < 0.2,
+                        np.finfo(np.float32).min, 0.0).astype(np.float32)
+        bias[..., 0] = 0.0  # every softmax keeps at least one key
+        kwargs["bias"] = jnp.asarray(bias)
+    got = np.asarray(fa.paged_decode_attention(
+        q, k_pool, v_pool, table, route="pallas", **kwargs))
+    want = np.asarray(fa.paged_decode_attention(
+        q, k_pool, v_pool, table, route="composition", **kwargs))
+    np.testing.assert_allclose(got, want, atol=4e-6)
+    pd._paged_call.clear_cache()
+
+
+@pytest.mark.parametrize("h,bs,d,itemsize,quant,want", [
+    (16, 32, 128, 4, False, 16),    # gpt-1p3b: 4 x 256 KB, every head
+    (16, 32, 128, 1, True, 16),     # its int8 pool
+    (4, 128, 128, 2, False, 4),     # sdar-30b-a3b: 4 x 128 KB
+    (32, 128, 128, 4, False, 8),    # 4 x 2 MB a step would not fit: split
+    (32, 128, 128, 1, True, 16),    # int8: a multiple of 8
+    (12, 512, 256, 1, True, 12),    # int8, nothing legal fits: all heads
+    (12, 512, 256, 4, False, 1),    # a dense tile of 512 x 256: one head
+    (2, 8, 16, 4, False, 2),
+], ids=["gpt", "gpt-int8", "sdar", "split", "split-int8", "int8-whole",
+        "dense-tile", "toy"])
+def test_head_chunk_comes_from_shapes(h, bs, d, itemsize, quant, want):
+    got = pd.head_chunk(h, bs, d, itemsize, quant)
+    assert got == want and h % got == 0
+    if quant:
+        assert got % 8 == 0 or got == h
+
+
+@pytest.mark.parametrize("case", ["lq1", "lq4", "int8", "grouped", "bias",
+                                  "dense"])
+def test_dead_blocks_are_never_computed(case):
+    # every block past a row's last visible one is NaN (K, V and
+    # scales): were it fetched into the arithmetic, 0 x NaN would be in
+    # the sums.  The output is finite and the clean run's, to the bit
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(12)
+    b, h, bs, d, mb = 3, 2, 8, 16, 4
+    lq = 1 if case in ("lq1", "int8") else 4
+    quant = case == "int8"
+    last = np.array([0, 2, 1])                  # last live block a row
+    q_pos = last[:, None] * bs + np.array([[2], [7], [0]]) \
+        - np.arange(lq)[::-1][None, :] * (case != "grouped")
+    q_pos = np.maximum(q_pos, 0).astype(np.int32)
+    hq = 2 * h if case == "grouped" else h
+    q = jnp.asarray(rng.randn(b, hq, lq, d).astype(np.float32))
+    kwargs = dict(q_pos=jnp.asarray(q_pos))
+    if case == "bias":
+        kwargs["bias"] = jnp.asarray(
+            rng.randn(b, 1, lq, mb * bs).astype(np.float32))
+    if case == "dense":
+        s = 640                                 # five tiles of 128
+        k = rng.randn(b, h, s, d).astype(np.float32)
+        v = rng.randn(b, h, s, d).astype(np.float32)
+        kwargs["q_pos"] = jnp.asarray(q_pos * 16)   # tiles 0, 2, 1
+        dead = (np.arange(s)[None, :] // 128) > last[:, None]
+        clean = fa.decode_attention(q, jnp.asarray(k), jnp.asarray(v),
+                                    route="pallas", **kwargs)
+        k[np.broadcast_to(dead[:, None, :], (b, h, s))] = np.nan
+        v[np.broadcast_to(dead[:, None, :], (b, h, s))] = np.nan
+        got = fa.decode_attention(q, jnp.asarray(k), jnp.asarray(v),
+                                  route="pallas", **kwargs)
+    else:
+        _, k_pool, v_pool, table, ks, vs = _paged_case(rng, b, h, bs, d,
+                                                       mb, lq, quant)
+        kwargs.update(k_scale=ks, v_scale=vs)
+        clean = fa.paged_decode_attention(q, k_pool, v_pool, table,
+                                          route="pallas", **kwargs)
+        dead = np.asarray(table)[np.arange(mb)[None, :] > last[:, None]]
+        if quant:
+            # an int8 pool holds no NaN: its scales do
+            kwargs.update(k_scale=ks.at[dead].set(jnp.nan),
+                          v_scale=vs.at[dead].set(jnp.nan))
+        else:
+            k_pool = k_pool.at[dead].set(jnp.nan)
+            v_pool = v_pool.at[dead].set(jnp.nan)
+        got = fa.paged_decode_attention(q, k_pool, v_pool, table,
+                                        route="pallas", **kwargs)
+    got, clean = np.asarray(got), np.asarray(clean)
+    assert np.isfinite(got).all(), "a dead block reached the arithmetic"
+    np.testing.assert_array_equal(got, clean)
+
+
+@pytest.mark.parametrize("lq", [1, 5])
+def test_row_that_sees_nothing_emits_zeros(lq):
+    # q_pos < 0: no key is visible.  The row costs one block (wholly
+    # masked) and emits 0, never NaN; the rows beside it are exact
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(13)
+    b, h, bs, d, mb = 3, 2, 8, 16, 4
+    q, k_pool, v_pool, table, _, _ = _paged_case(rng, b, h, bs, d, mb,
+                                                 lq, False)
+    q_pos = np.array([[20], [-1], [9]], np.int32) + np.zeros(
+        (1, lq), np.int32)
+    got = np.asarray(fa.paged_decode_attention(
+        q, k_pool, v_pool, table, q_pos=jnp.asarray(q_pos),
+        route="pallas"))
+    want = np.asarray(fa.paged_decode_attention(
+        q, k_pool, v_pool, table, q_pos=jnp.asarray(np.maximum(q_pos, 0)),
+        route="composition"))
+    assert np.all(got[1] == 0.0)
+    np.testing.assert_allclose(got[[0, 2]], want[[0, 2]], atol=2e-6)
 
 
 def test_paged_kernel_scalar_lengths_and_qpos():
@@ -363,6 +515,116 @@ def test_pool_slot_churn_route_identity(model):
     assert (route_c, route_p) == ("composition", "pallas")
     assert counts_p == counts_c
     for a, b in zip(toks_c, toks_p):
+        np.testing.assert_array_equal(a, b)
+
+
+class _StepSpy:
+    """Stands before a pool's step executable (``fn(params, bufs,
+    cache, ...)`` returning the new cache first).  After every step:
+    each table row is what it was, and an inactive slot's index is what
+    it was, whatever the step was shown in their place."""
+
+    def __init__(self, fn, active_of):
+        self.fn, self.active_of = fn, active_of
+        self.stale = 0      # steps with an inactive slot off position 0
+
+    def __call__(self, *args):
+        before = [(np.asarray(c.table), np.asarray(c.index))
+                  for c in args[2]]
+        active = np.asarray(self.active_of(args)).astype(bool)
+        out = self.fn(*args)
+        for (table, index), c in zip(before, out[0]):
+            np.testing.assert_array_equal(np.asarray(c.table), table)
+            np.testing.assert_array_equal(np.asarray(c.index)[~active],
+                                          index[~active])
+        self.stale += bool((index[~active] > 0).any())
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self.fn, name)
+
+
+def _parent_masked_tables(self, cache, active):
+    # GenerationPool._masked_tables before the index was masked too
+    import jax.numpy as jnp
+
+    scratch = jnp.asarray(self._scratch_row)[:, None]
+    return [c._replace(table=jnp.where(active[:, None], c.table, scratch))
+            for c in cache]
+
+
+def _blockdiff_model():
+    from paddle_tpu.models import BlockDiffusionMoELM
+
+    pt.seed(3)
+    m = BlockDiffusionMoELM(
+        vocab_size=256, hidden_size=64, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=16, expert_size=32, num_experts=4,
+        top_k=2, block_length=4, mask_token_id=255, denoise_steps=2)
+    m.eval()
+    return m
+
+
+@pytest.mark.parametrize("kind", ["churn", "chunked", "speculative",
+                                  "blockdiff"])
+def test_masked_index_is_restored_and_tokens_are_the_parents(
+        model, monkeypatch, kind):
+    # an inactive slot is shown to the step with its table on scratch
+    # AND its index at 0 (the kernel walks what the index reaches); the
+    # returned cache has both as they were: a free slot's stale length,
+    # a slot mid-prompt under chunked prefill, a slot between requests
+    # of a verify chunk or a block step.  Tokens are those of the pool
+    # that masked the table alone
+    from paddle_tpu.inference import BlockDiffusionPool, SpeculativePool
+
+    rng = np.random.RandomState(14)
+    paged = dict(max_len=64, buckets=[16, 32], cache_layout="paged",
+                 block_size=8, route="pallas")
+    lens, budgets = (5, 11, 7, 3, 14), (6,) * 5
+    active_of = lambda args: args[4]                        # noqa: E731
+    attr = "_decode_jit"
+    if kind == "churn":
+        build = lambda: GenerationPool(model, slots=2, **paged)  # noqa
+    elif kind == "chunked":
+        # a 40-token prompt takes five chunks beside a decoding slot:
+        # four steps see it inactive at positions 8..32
+        lens = (5, 40, 7)
+        build = lambda: GenerationPool(                     # noqa: E731
+            model, slots=2, prefill_chunk_tokens=8,
+            **dict(paged, buckets=[64]))
+    elif kind == "speculative":
+        attr = "_verify_jit"
+        build = lambda: SpeculativePool(                    # noqa: E731
+            model, model, spec_k=3, slots=2, **paged)
+    else:
+        bmodel = _blockdiff_model()
+        # the second request outlasts the others: its slot steps on
+        # beside a finished one
+        lens, budgets = (8, 13, 6), (4, 16, 8)
+        active_of = lambda args: np.asarray(args[3])[:, -1] != 0  # noqa
+        build = lambda: BlockDiffusionPool(                 # noqa: E731
+            bmodel, slots=2, cache_dtype="float32", **paged)
+    prompts = [rng.randint(0, 128, (n,)).astype("int32") for n in lens]
+
+    def run(spied):
+        pool = build()
+        spy = _StepSpy(getattr(pool, attr), active_of)
+        if spied:
+            setattr(pool, attr, spy)
+        work = list(zip(prompts, budgets))
+        rids = [pool.submit(p, n) for p, n in work[:2]]
+        for _ in range(3):
+            pool.step()
+        rids += [pool.submit(p, n) for p, n in work[2:]]
+        res = pool.run()
+        return [res[r] for r in rids], spy.stale
+
+    toks, stale = run(True)
+    assert stale > 0, "no step saw an inactive slot with a stale index"
+    monkeypatch.setattr(GenerationPool, "_masked_tables",
+                        _parent_masked_tables)
+    parents, _ = run(False)
+    for a, b in zip(toks, parents):
         np.testing.assert_array_equal(a, b)
 
 

@@ -106,6 +106,37 @@ def test_pool_decode_on_the_v5e_moves_no_pool(one_chip, model, monkeypatch,
     assert made.count("fusion") == made.count("scatter")
 
 
+@pytest.mark.parametrize("lq", [1, 5])
+@pytest.mark.parametrize("cache_dtype", ["float32", "int8"])
+def test_paged_kernel_at_the_gpt_cells_geometry_on_the_v5e(one_chip, lq,
+                                                           cache_dtype):
+    """The kernel alone at ``gpt-1p3b``'s cache: every head of a block in
+    one grid step ([16, 32, 128] K and V blocks, the int8 pool's [16, 32]
+    scale blocks), one row and a verify chunk's five, the index maps
+    clamped by ``q_pos``.  One custom call each."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import pallas_decode
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = shape((512, 16, 32, 128), jnp.dtype(cache_dtype))
+    args = [shape((16, 16, lq, 128), jnp.float32), pool, pool,
+            shape((16, 32), jnp.int32), shape((16, lq), jnp.int32)]
+    if cache_dtype == "int8":
+        args += [shape((512, 16, 32), jnp.float32)] * 2
+    assert pallas_decode.head_chunk(16, 32, 128, pool.dtype.itemsize,
+                                    cache_dtype == "int8") == 16
+    text = jax.jit(
+        lambda q, k, v, t, p, *scales:
+        pallas_decode.paged_decode_attention_kernel(
+            q, k, v, t, p, 128 ** -0.5, *scales)).lower(
+        *args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
 def test_grouped_heads_kernel_and_grouped_matmul_on_the_v5e(one_chip,
                                                             monkeypatch):
     """The block-diffusion cell's two kernels at its real shapes: the
