@@ -43,8 +43,7 @@ import numpy as np
 
 from ..core.errors import InvalidArgumentError, PreconditionNotMetError
 from ..jit import aot
-from .generation import (GenerationPool, _fire, _SlotState,
-                         _trace_active)
+from .generation import GenerationPool, _SlotState
 
 __all__ = ["BlockDiffusionPool", "commit_plan"]
 
@@ -260,70 +259,28 @@ class BlockDiffusionPool(GenerationPool):
         return _Block(toks, masked, r + fill, r,
                       commit_plan(fill, self._T))
 
-    def _weights(self):
-        """The parameter and buffer value lists, walked once and kept
-        (``refresh_weights`` drops them)."""
-        if self._state_cache is None:
-            self._state_cache = self._session._state_vals()
-        return self._state_cache
+    def _prefill_row(self, req):
+        """The prompt's whole blocks, prefilled under the block mask; no
+        token is taken (``tok`` is None)."""
+        n = len(req.ids)
+        padded = np.zeros((1, self._session._bucket_for(n)), np.int32)
+        padded[0, :n] = req.ids
+        whole = n // self._B * self._B
+        params, bufs = self._weights()
+        return self._prefill_jit(params, bufs, jnp.asarray(padded),
+                                 whole), whole, None
 
-    def _refill(self):
-        tr = _trace_active()
-        self.admission_blocked = False
-        while self._queue and self._free:
-            pick = self._pick_candidate(self._tenant_counts())
-            if pick is None:
-                break
-            _, req = pick
-            need = shard = None
-            if self.cache_layout == "paged":
-                need = self._blocks_needed(len(req.ids),
-                                           req.max_new_tokens)
-                shard = self._choose_shard(req, need)[0]
-                if shard is None:
-                    self.admission_blocked = True
-                    break
-            for i, q in enumerate(self._queue):
-                if q is req:
-                    del self._queue[i]
-                    break
-            _fire("pool.prefill")
-            n = len(req.ids)
-            whole = n // self._B * self._B
-            bucket = self._session._bucket_for(n)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :n] = req.ids
-            params, bufs = self._weights()
-            if tr is None:
-                row_cache = self._prefill_jit(params, bufs,
-                                              jnp.asarray(padded), whole)
-            else:
-                with tr.span("tick.prefill", rid=req.rid, prompt_tokens=n,
-                             bucket=bucket):
-                    row_cache = self._prefill_jit(
-                        params, bufs, jnp.asarray(padded), whole)
-            slot = self._pop_free_slot(shard)
-            args = (self._cache, row_cache, jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(whole, jnp.int32))
-            if self.cache_layout == "paged":
-                _fire("pool.alloc_blocks")
-                blocks = self._alloc_blocks(need, shard)
-                self._slot_blocks[slot] = blocks
-                row = np.full(self._max_blocks,
-                              self._shard_scratch(shard), np.int32)
-                row[:need] = blocks
-                args += (jnp.asarray(row),)
-            self._cache = self._insert_jit(*args)
-            self.last_admit_prefix_tokens = None
-            if self.on_admit is not None:
-                self.on_admit(req.rid, slot, n)
-            self._active[slot] = _SlotState(
-                req.rid, req.ids, [], req.max_new_tokens,
-                priority=req.priority, tenant=req.tenant,
-                deadline=req.deadline, seq=req.seq, sampling=req.sampling,
-                adapter=req.adapter)
-            self._blocks[slot] = self._new_block(req.ids[whole:],
-                                                 req.max_new_tokens)
+    def _start_slot(self, slot: int, req, tok) -> None:
+        """No first token: the slot goes live with the block that starts
+        with the prompt's trailing partial block."""
+        self._active[slot] = _SlotState(
+            req.rid, req.ids, [], req.max_new_tokens,
+            priority=req.priority, tenant=req.tenant,
+            deadline=req.deadline, seq=req.seq, sampling=req.sampling,
+            adapter=req.adapter)
+        self._blocks[slot] = self._new_block(
+            req.ids[len(req.ids) // self._B * self._B:],
+            req.max_new_tokens)
 
     def _last_position(self, slot: int, state) -> int:
         # the end of the slot's current block: every row of it sees that
@@ -341,86 +298,70 @@ class BlockDiffusionPool(GenerationPool):
                 [blk.plan[0], 0, 1] if blk.plan else [0, 1, 1])
         return np.array(rows, np.int32)
 
-    def step(self) -> bool:
-        """Refill free slots, run ONE forward over every live slot's
-        block, commit and deliver.  False when the pool is drained."""
-        _fire("pool.step")
-        tr = _trace_active()
-        if tr is None:
-            self._refill()
-        else:
-            with tr.span("tick.admit"):
-                self._refill()
-        if not self._active:
-            return bool(self._queue)
-        params, bufs = self._weights()
-        ctl = self._control()
-        bl, live = self._B, len(self._active)
-        stores = int(ctl[:, 2 * bl + 1].sum())
+    # -- the tick's hooks: ONE forward over every live slot's block ------
+    def _sync_step_inputs(self):
+        return self._weights() + (self._control(),)
+
+    def _decode_meta(self, params, bufs, ctl) -> dict:
         # a denoising step commits exactly what its plan asks: known
         # before the dispatch, so the span carries it
+        bl, live = self._B, len(self._active)
+        stores = int(ctl[:, 2 * bl + 1].sum())
         committed = int(ctl[:, 2 * bl].sum())
-        if tr is None:
-            self._cache, out_dev = self._decode_jit(params, bufs,
-                                                    self._cache, ctl)
-            self._deliver_blocks(np.asarray(out_dev))
-        else:
-            kind = "store" if stores == live else \
-                "denoise" if not stores else "mixed"
-            with tr.span("tick.decode", live=live, slots=self.slots,
-                         kind=kind, rows=live * bl, store=stores,
-                         denoise=live - stores, committed=committed,
-                         tokens_per_forward=committed / live,
-                         **self._block_meta()):
-                self._cache, out_dev = self._decode_jit(
-                    params, bufs, self._cache, ctl)
-            with tr.span("tick.sample"):
-                out = np.asarray(out_dev)
-            with tr.span("tick.deliver"):
-                self._deliver_blocks(out)
-        self.forwards_store += stores
-        self.forwards_denoise += live - stores
-        self.tokens_committed += committed
-        return bool(self._active or self._queue)
+        kind = "store" if stores == live else \
+            "denoise" if not stores else "mixed"
+        return dict(live=live, slots=self.slots, kind=kind,
+                    rows=live * bl, store=stores, denoise=live - stores,
+                    committed=committed,
+                    tokens_per_forward=committed / live,
+                    **self._block_meta())
 
-    def _deliver_blocks(self, out: np.ndarray) -> None:
+    def _launch(self, params, bufs, ctl):
+        self._cache, out_dev = self._decode_jit(params, bufs, self._cache,
+                                                ctl)
+        return out_dev
+
+    def _deliver(self, out: np.ndarray) -> None:
         """Take the step's download into every live slot's block: note
         the commits, let the tokens of the grown committed prefix go in
-        position order, finish on EOS or budget, and start the next block
-        after a store."""
+        position order (``_commit`` finishes on EOS or budget), and
+        start the next block after a store."""
         bl = self._B
         rows = out.tolist()
+        live, stores, committed = len(self._active), 0, 0
         for slot in list(self._active):
             st, blk = self._active[slot], self._blocks[slot]
             if not blk.plan:
                 # that was the store: the block's K/V is kept, the index
                 # stands at the next block, which starts all masked
+                stores += 1
                 self._blocks[slot] = self._new_block((), st.remaining)
                 continue
-            blk.plan.pop(0)
+            committed += blk.plan.pop(0)
             blk.step += 1
             toks, masked = rows[slot][:bl], rows[slot][bl:]
             for i in range(bl):
                 if blk.masked[i] and not masked[i]:
                     blk.steps[i] = blk.step
             blk.toks, blk.masked = toks, masked
-            done = False
-            while blk.delivered < blk.limit \
-                    and not masked[blk.delivered]:
-                t = toks[blk.delivered]
-                self.token_commit_step = blk.steps[blk.delivered]
-                blk.delivered += 1
-                st.tokens.append(t)
-                st.remaining -= 1
-                if self.on_token is not None:
-                    self.on_token(st.rid, t)
-                if st.remaining == 0 or t == self.eos_id:
-                    done = True
-                    break
+            self._commit(slot, self._leaving(blk))
             self.token_commit_step = None
-            if done:
-                self._blocks.pop(slot, None)
-                self._finish(slot)
+        self.forwards_store += stores
+        self.forwards_denoise += live - stores
+        self.tokens_committed += committed
+
+    def _leaving(self, blk: _Block):
+        """The tokens of ``blk``'s committed prefix that have not left
+        yet, in position order, ``token_commit_step`` set to the step
+        that committed each before it is handed on."""
+        while blk.delivered < blk.limit and not blk.masked[blk.delivered]:
+            self.token_commit_step = blk.steps[blk.delivered]
+            blk.delivered += 1
+            yield blk.toks[blk.delivered - 1]
+
+    def _finish(self, slot: int):
+        self._blocks.pop(slot, None)
+        super()._finish(slot)
 
     def release(self, slot: int):
         self._blocks.pop(slot, None)

@@ -87,6 +87,7 @@ Traffic-grade scheduling rides the same allocator (docs/DESIGN.md §5j):
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
 import os
 from typing import Dict, List, Optional, Sequence
@@ -148,6 +149,20 @@ def _trace_active():
         from ..serving import trace as _trace_mod
         _trace = _trace_mod
     return _trace.active()
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def tick_phase(tr, name: str, meta=None):
+    """The context one phase of a tick runs in: ``tr.span(name,
+    **meta())`` under the tracer ``tr``, one shared no-op when ``tr`` is
+    None.  ``meta`` is a callable, so with tracing off no span is made
+    and no meta is built.  The engine spans its own phases with it."""
+    if tr is not None:
+        return tr.span(name, **meta()) if meta is not None \
+            else tr.span(name)
+    return _NO_SPAN
 
 
 _transfer = None
@@ -2523,34 +2538,18 @@ class GenerationPool:
                 self._admit_chunked(req, need, matched_blocks,
                                     matched_len, chain_key, shard)
                 continue
-            # bucketed batch-1 prefill (compiled per bucket, shared with
-            # DecodeSession.generate) emits the request's FIRST token;
-            # runs BEFORE the slot is popped so a prefill failure can
-            # never leak a slot
+            # the batch-1 prefill runs BEFORE the slot is popped so a
+            # prefill failure can never leak a slot.  ``bucket``: the
+            # length the prompt is padded to, so 1 - prompt_tokens /
+            # bucket is the prefill's padding
             _fire("pool.prefill")
-            # the request's resolved config rides the batch-1 prefill as
-            # a [1] SamplingState (prefill draw = stream step 0); the
-            # advanced state it returns is discarded — the slot's draw
-            # counter is derived from len(tokens) at membership sync
-            samp = make_sampling_state(
-                1, temperature=req.sampling.temperature,
-                top_k=req.sampling.top_k, top_p=req.sampling.top_p,
-                seed=req.sampling.seed, step=req.sampling.draws,
-                adapter=req.adapter)
-            if tr is None:
-                row_cache, tok, _ = self._session.prefill(
-                    req.ids[None], samp)
-            else:
-                # ``bucket``: the length the prompt is padded to, so
-                # 1 - prompt_tokens / bucket is the prefill's padding
-                with tr.span("tick.prefill", rid=req.rid,
-                             prompt_tokens=len(req.ids),
-                             bucket=self._session._bucket_for(
-                                 len(req.ids))):
-                    row_cache, tok, _ = self._session.prefill(
-                        req.ids[None], samp)
+            with tick_phase(tr, "tick.prefill", lambda: {
+                    "rid": req.rid, "prompt_tokens": len(req.ids),
+                    "bucket": self._session._bucket_for(len(req.ids))}):
+                row_cache, length, tok = self._prefill_row(req)
             slot = self._pop_free_slot(shard)
-            first = int(np.asarray(tok)[0])
+            args = (self._cache, row_cache, jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(length, jnp.int32))
             if self.cache_layout == "paged":
                 _fire("pool.alloc_blocks")
                 blocks = self._alloc_blocks(need, shard)
@@ -2562,22 +2561,43 @@ class GenerationPool:
                 padded = np.full(self._max_blocks,
                                  self._shard_scratch(shard), np.int32)
                 padded[:need] = blocks
-                self._cache = self._insert_jit(
-                    self._cache, row_cache, jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(len(req.ids), jnp.int32),
-                    jnp.asarray(padded))
-            else:
-                self._cache = self._insert_jit(
-                    self._cache, row_cache, jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(len(req.ids), jnp.int32))
+                args += (jnp.asarray(padded),)
+            self._cache = self._insert_jit(*args)
             self.last_admit_prefix_tokens = None
             if self.on_admit is not None:
                 self.on_admit(req.rid, slot, len(req.ids))
-            self._activate(slot, req.rid, req.ids, first,
-                           req.max_new_tokens, priority=req.priority,
-                           tenant=req.tenant, deadline=req.deadline,
-                           seq=req.seq, sampling=req.sampling,
-                           adapter=req.adapter)
+            self._start_slot(slot, req, tok)
+
+    def _prefill_row(self, req: _Request):
+        """Dispatch the batch-1 prefill of an admitted request:
+        ``(row_cache, length, tok)``, the cache to splice into its slot,
+        the index the slot starts at, and the device handle of what
+        ``_start_slot`` needs from the prefill.  Here the bucketed
+        prefill (compiled per bucket, shared with
+        ``DecodeSession.generate``), whose ``tok`` is the request's
+        FIRST token."""
+        # the request's resolved config rides the batch-1 prefill as
+        # a [1] SamplingState (prefill draw = stream step 0); the
+        # advanced state it returns is discarded — the slot's draw
+        # counter is derived from len(tokens) at membership sync
+        samp = make_sampling_state(
+            1, temperature=req.sampling.temperature,
+            top_k=req.sampling.top_k, top_p=req.sampling.top_p,
+            seed=req.sampling.seed, step=req.sampling.draws,
+            adapter=req.adapter)
+        row_cache, tok, _ = self._session.prefill(req.ids[None], samp)
+        return row_cache, len(req.ids), tok
+
+    def _start_slot(self, slot: int, req: _Request, tok) -> None:
+        """Make ``slot`` live for the request just spliced into it; here
+        the host fetches the prefill's token (one ``[1]`` transfer an
+        admitted request) and commits it."""
+        first = np.asarray(tok)[0]
+        self._activate(slot, req.rid, req.ids, int(first),
+                       req.max_new_tokens, priority=req.priority,
+                       tenant=req.tenant, deadline=req.deadline,
+                       seq=req.seq, sampling=req.sampling,
+                       adapter=req.adapter)
 
     def _chunk_work(self, tr) -> None:
         """At most ``prefill_chunk_tokens`` of prompt work this tick:
@@ -2592,30 +2612,22 @@ class GenerationPool:
         n = min(self._chunk_tokens, len(st.ids) - st.pos)
         toks = np.zeros(self._chunk_tokens, np.int32)
         toks[:n] = st.ids[st.pos:st.pos + n]
-        if self._state_cache is None:
-            self._state_cache = self._session._state_vals()
-        params, bufs = self._state_cache
+        params, bufs = self._weights()
         _fire("pool.prefill")
         # the request's resolved config as [1] vectors; every chunk
         # passes the same (seed, step 0) stream, so only the FINAL
         # chunk's kept sample matters and it matches the bucketed path
         samp = self._samp_vec(st.sampling)
         adpt = jnp.asarray([st.adapter], jnp.int32)
-        if tr is None:
+        with tick_phase(tr, "tick.prefill", lambda: {
+                "rid": st.rid, "chunk_tokens": n, "pos": st.pos,
+                "prompt_tokens": len(st.ids),
+                "bucket": self._chunk_tokens}):
             self._cache, tok_dev = self._chunk_jit(
                 params, bufs, self._cache, jnp.asarray(toks),
                 jnp.asarray(slot, jnp.int32),
                 jnp.asarray(st.pos, jnp.int32),
                 jnp.asarray(n, jnp.int32), samp, adpt)
-        else:
-            with tr.span("tick.prefill", rid=st.rid, chunk_tokens=n,
-                         pos=st.pos, prompt_tokens=len(st.ids),
-                         bucket=self._chunk_tokens):
-                self._cache, tok_dev = self._chunk_jit(
-                    params, bufs, self._cache, jnp.asarray(toks),
-                    jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(st.pos, jnp.int32),
-                    jnp.asarray(n, jnp.int32), samp, adpt)
         self._chunks_total += 1
         self._chunk_tokens_total += n
         st.pos += n
@@ -2637,10 +2649,11 @@ class GenerationPool:
                        sampling=st.sampling, adapter=st.adapter)
 
     def _sync_step_inputs(self):
-        """The shared pre-step protocol (also the speculative pool's):
-        rebuild the device-resident token/active vectors when slot
-        membership changed, and lazily cache the weight value lists.
-        Returns ``(params, bufs)``.
+        """The host work before a launch (the speculative pool adds its
+        draft's weights to it): rebuild the device-resident token/active
+        vectors when slot membership changed, and lazily cache the
+        weight value lists.  Returns ``_launch``'s arguments, here
+        ``(params, bufs)``.
 
         The per-slot AS-DATA vectors (docs §5q) rebuild on the same
         dirty flag: the sampling config stack ``_samp_dev`` =
@@ -2652,7 +2665,7 @@ class GenerationPool:
         IS ``cfg.draws + len(st.tokens)`` (the submission's stream
         offset plus the tokens committed since — the prefill draw was
         step ``draws``), so the rebuild here and the on-device feedback
-        in ``_dispatch`` agree by construction."""
+        in ``_launch`` agree by construction."""
         if self._membership_dirty:
             active = np.zeros(self.slots, bool)
             active[list(self._active)] = True
@@ -2687,56 +2700,60 @@ class GenerationPool:
             self._step_dev = place(step)
             self._adapter_dev = place(adpt)
             self._membership_dirty = False
+        return self._weights()
+
+    def _weights(self):
+        """The parameter and buffer value lists, walked once and kept
+        (``refresh_weights`` drops them)."""
         if self._state_cache is None:
             self._state_cache = self._session._state_vals()
         return self._state_cache
 
     def step(self) -> bool:
-        """Refill free slots, run ONE batched decode step; False when the
-        pool is drained (no queued or active requests).
+        """One tick, the same for every kind of pool: refill free slots,
+        do the bounded prompt work of a chunking pool, launch ONE step
+        on the device, download what it produced, commit tokens; False
+        when the pool is drained (``_pending()``).
 
-        With a tracer installed (serving/trace.py) each phase of the
-        tick is spanned — admit (refill incl. per-request prefill),
-        decode (the batched dispatch, which returns before the device
-        has finished; its meta says how many of the ``slots`` rows were
-        ``live``), sample (the per-tick host download of the sampled
-        ids, where the host waits for the device), deliver (the host
-        loop committing tokens and firing hooks) — through the
-        tracing-off-is-a-no-op branches below."""
+        A kind of pool differs in four hooks, never in this skeleton:
+        ``_sync_step_inputs`` (host work before the launch; what it
+        returns are the launch's arguments), ``_launch`` (the step's
+        dispatches, cache donated and rebound, returning the device
+        arrays the host needs), ``_deliver`` (the downloaded arrays
+        into ``_commit``, 0..n tokens a slot) and ``_decode_meta``.
+
+        With a tracer installed (serving/trace.py) each phase is a span
+        — admit (refill incl. per-request prefill), decode (the
+        dispatches, which return before the device has finished; its
+        meta says how many of the ``slots`` rows were ``live``), sample
+        (the tick's ONE host download, where the host waits for the
+        device), deliver (the host loop committing tokens and firing
+        hooks) — and without one ``tick_phase`` is a shared no-op."""
         _fire("pool.step")
         tr = _trace_active()
-        if tr is None:
+        with tick_phase(tr, "tick.admit"):
             self._refill()
-        else:
-            with tr.span("tick.admit"):
-                self._refill()
         if self._chunk_tokens is not None:
             # bounded prompt work BEFORE the decode dispatch: a freshly
             # completed short prompt still gets its first decode step
             # this same tick (no TTFT penalty vs the one-shot prefill)
             self._chunk_work(tr)
         if not self._active:
-            return bool(self._queue or self._prefilling
-                        or self._spilled or self._prefill_done)
-        params, bufs = self._sync_step_inputs()
-        if tr is None:
-            tok_dev = self._dispatch(params, bufs)
-            tok = np.asarray(tok_dev)
-        else:
-            with tr.span("tick.decode", live=len(self._active),
-                         slots=self.slots, **self._block_meta()):
-                tok_dev = self._dispatch(params, bufs)
-            with tr.span("tick.sample"):
-                # the per-tick host download of the sampled ids — the
-                # designed sync point whether or not it is spanned
-                tok = np.asarray(tok_dev)
-        self._tok_dev = tok_dev  # feeds straight back next step
-        self._last_tok = tok.astype(np.int32)
-        if tr is None:
-            self._deliver(tok)
-        else:
-            with tr.span("tick.deliver"):
-                self._deliver(tok)
+            return self._pending()
+        inputs = self._sync_step_inputs()
+        with tick_phase(tr, "tick.decode",
+                        lambda: self._decode_meta(*inputs)):
+            handles = self._launch(*inputs)
+        with tick_phase(tr, "tick.sample"):
+            # every array of the step in one batched transfer: the
+            # designed sync point whether or not it is spanned
+            host = jax.device_get(handles)
+        with tick_phase(tr, "tick.deliver"):
+            self._deliver(host)
+        return self._pending()
+
+    def _pending(self) -> bool:
+        """Whether any request is still anywhere in the pool."""
         return bool(self._active or self._queue or self._prefilling
                     or self._spilled or self._prefill_done)
 
@@ -2757,28 +2774,44 @@ class GenerationPool:
                                    for slot, st in self._active.items()),
                 "table_blocks": self.slots * self._max_blocks}
 
-    def _dispatch(self, params, bufs):
+    def _decode_meta(self, *inputs) -> dict:
+        """``tick.decode``'s meta, from what ``_launch`` is about to be
+        given; built only under a tracer."""
+        return dict(live=len(self._active), slots=self.slots,
+                    **self._block_meta())
+
+    def _launch(self, params, bufs):
         """The one batched decode dispatch (cache donated and rebound in
-        the same statement).  The draw counter feeds back on-device like
-        the token vector — active rows advanced inside the step."""
-        self._cache, tok_dev, self._step_dev = self._decode_jit(
+        the same statement).  The token vector and the draw counter feed
+        straight back on-device — active rows advanced inside the
+        step."""
+        self._cache, self._tok_dev, self._step_dev = self._decode_jit(
             params, bufs, self._cache, self._tok_dev, self._active_dev,
             self._samp_dev, self._step_dev, self._adapter_dev)
-        return tok_dev
+        return self._tok_dev
 
     def _deliver(self, tok) -> None:
-        """Commit the step's sampled token to every active slot: append,
-        fire ``on_token``, finish rows hitting EOS/budget."""
+        """Commit the step's sampled token to every active slot."""
+        self._last_tok = tok.astype(np.int32)
         for slot in list(self._active):
-            state = self._active[slot]
-            t = int(tok[slot])
+            self._commit(slot, (int(tok[slot]),))
+
+    def _commit(self, slot: int, tokens) -> None:
+        """Commit the tokens a step produced for ``slot``, in order and
+        0..n of them: append, count the budget down, fire ``on_token``,
+        and finish the slot at EOS or at the end of its budget, leaving
+        what comes after uncommitted.  ``tokens`` is iterated one commit
+        at a time, so a pool may pass a generator that does its own
+        bookkeeping before each token leaves."""
+        state = self._active[slot]
+        for t in tokens:
             state.tokens.append(t)
             state.remaining -= 1
             if self.on_token is not None:
                 self.on_token(state.rid, t)
-            if state.remaining == 0 or \
-                    (self.eos_id is not None and t == self.eos_id):
+            if state.remaining == 0 or t == self.eos_id:
                 self._finish(slot)
+                return
 
     def refresh_weights(self):
         """Drop the cached parameter/buffer value lists — call after

@@ -16,7 +16,7 @@ slot-churn masking of docs/DESIGN.md §5b — scales included, §5d).
 Per ``step()``, each active slot emits between 1 and ``spec_k + 1``
 tokens (all of them EXACTLY what target-only greedy decode would have
 emitted); EOS inside an accepted chunk truncates the commit AT the EOS
-(``jit.truncate_at_eos``) — the accepted tail behind it is never
+(``GenerationPool._commit``) — the accepted tail behind it is never
 emitted, matching the one-token-at-a-time loop's stopping point.
 
 Fixed compile budget on top of the base pool's: one draft prefill per
@@ -33,7 +33,6 @@ slots verbatim; the engine only gains an ``acceptance_rate`` gauge.
 from __future__ import annotations
 
 import time
-from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -41,10 +40,10 @@ import numpy as np
 
 from ..core.errors import InvalidArgumentError
 from ..jit import aot
-from ..jit.decode import DecodeSession, truncate_at_eos
+from ..jit.decode import DecodeSession
 from ..jit.speculative import (acceptance_summary, check_draft_compatible,
                                greedy_accept)
-from .generation import GenerationPool, _fire, _trace_active
+from .generation import GenerationPool
 
 __all__ = ["SpeculativePool"]
 
@@ -71,19 +70,8 @@ class SpeculativePool(GenerationPool):
     """
 
     def __init__(self, model, draft_model, max_len: int, spec_k: int = 4,
-                 slots: int = 4, buckets: Optional[Sequence[int]] = None,
-                 eos_id: Optional[int] = None, cache_dtype="float32",
-                 donate: Optional[bool] = None, seed: int = 0,
-                 cache_layout: str = "dense", block_size: int = 32,
-                 num_blocks: Optional[int] = None,
-                 temperature: float = 0.0, top_k: int = 0,
-                 top_p: float = 1.0, time_split: bool = False,
-                 prefill_chunk_tokens: Optional[int] = None,
-                 prefix_sharing: bool = False, mesh=None,
-                 route: str = "auto", spill_tier: str = "host",
-                 spill_dir: Optional[str] = None,
-                 collective_quant: Optional[str] = None,
-                 collective_quant_scale: Optional[str] = None):
+                 time_split: bool = False, **pool_kwargs):
+        temperature = pool_kwargs.pop("temperature", 0.0)
         if float(temperature) != 0.0:
             raise InvalidArgumentError(
                 "speculative decoding is greedy-only (temperature=0): "
@@ -93,7 +81,7 @@ class SpeculativePool(GenerationPool):
             raise InvalidArgumentError(
                 "spec_k must be >= 1 draft tokens per round, got %r"
                 % (spec_k,))
-        if cache_layout == "recurrent":
+        if pool_kwargs.get("cache_layout") == "recurrent":
             raise InvalidArgumentError(
                 "speculative decoding does not support "
                 "cache_layout='recurrent': verify-rewind moves a "
@@ -102,27 +90,24 @@ class SpeculativePool(GenerationPool):
                 "vector — there is no earlier position to rewind to "
                 "without re-running the prefix; use GenerationPool for "
                 "recurrent/SSM models")
+        if pool_kwargs.get("prefill_only"):
+            raise InvalidArgumentError(
+                "prefill_only=True: the speculative pool's draft state "
+                "does not cross the K/V hand-off — the prefill tier "
+                "runs a plain GenerationPool")
         check_draft_compatible(draft_model, model)
-        # top_k/top_p are accepted (and forwarded) so the pool stays a
-        # DROP-IN for GenerationPool under ServingEngine's **pool_kwargs
-        # — at temperature=0 the base pool ignores them exactly as the
-        # plain pool does, rather than dying on an untyped TypeError
-        # chunked prefill + prefix sharing apply to the TARGET cache
-        # verbatim (the base pool machinery); the draft twin keeps its
-        # bucketed dense prefill — the draft is small by design, and its
-        # prompt forward runs once at activation, not per tick
-        super().__init__(model, max_len, slots=slots, buckets=buckets,
-                         eos_id=eos_id, cache_dtype=cache_dtype,
-                         donate=donate, seed=seed, top_k=top_k,
-                         top_p=top_p,
-                         cache_layout=cache_layout, block_size=block_size,
-                         num_blocks=num_blocks,
-                         prefill_chunk_tokens=prefill_chunk_tokens,
-                         prefix_sharing=prefix_sharing, mesh=mesh,
-                         route=route, spill_tier=spill_tier,
-                         spill_dir=spill_dir,
-                         collective_quant=collective_quant,
-                         collective_quant_scale=collective_quant_scale)
+        # every other keyword is the base pool's, taken as it is (a
+        # DROP-IN for GenerationPool under ServingEngine's
+        # **pool_kwargs): top_k/top_p are ignored at temperature=0
+        # exactly as the plain pool ignores them; chunked prefill +
+        # prefix sharing apply to the TARGET cache verbatim, while the
+        # draft twin keeps its bucketed dense prefill — the draft is
+        # small by design, and its prompt forward runs once at
+        # activation, not per tick
+        super().__init__(model, max_len, **pool_kwargs)
+        buckets, donate, mesh, route = (
+            pool_kwargs.get("buckets"), pool_kwargs.get("donate"),
+            pool_kwargs.get("mesh"), pool_kwargs.get("route", "auto"))
         # the mode is accepted (drop-in under ServingEngine's
         # **pool_kwargs) and validated by the target session, but the
         # speculative VERIFY step keeps dense collectives this PR: its
@@ -398,64 +383,29 @@ class SpeculativePool(GenerationPool):
             jnp.asarray(slot, jnp.int32),
             jnp.asarray(len(ids), jnp.int32))
 
-    def step(self) -> bool:
-        """Refill free slots, run ONE speculative round (K draft steps,
-        one verify, one draft fixup); every active slot commits 1 to
-        ``spec_k + 1`` tokens.  False when the pool is drained.
-
-        With a tracer installed (serving/trace.py) the round gets the
-        same phase spans as the plain pool's tick — admit, decode (the
-        whole draft+verify+fixup device round), sample (the batched
-        download), deliver — through tracing-off-is-a-no-op branches."""
-        _fire("pool.step")  # same seam as the plain pool: the serving
-        # engine's recovery treats a failed round exactly like a failed
-        # decode step (rebuild + resubmit, token-identical greedy)
-        tr = _trace_active()
-        if tr is None:
-            self._refill()
-        else:
-            with tr.span("tick.admit"):
-                self._refill()
-        if self._chunk_tokens is not None:
-            # bounded target-side prompt work before the round, exactly
-            # the base pool's interleaving (draft prefill still happens
-            # at activation, via _on_activated)
-            self._chunk_work(tr)
-        if not self._active:
-            return bool(self._queue or self._prefilling
-                        or self._spilled)
-        params, bufs = self._sync_step_inputs()
+    # -- the tick's hooks: a ROUND for the step --------------------------
+    # Each active slot commits 1 to ``spec_k + 1`` tokens a tick.  The
+    # engine's recovery treats a failed round exactly like a failed
+    # decode step (rebuild + resubmit, token-identical greedy); under
+    # chunked prefill the target-side prompt work runs before the round
+    # as in the base pool (draft prefill still happens at activation,
+    # via _on_activated).
+    def _sync_step_inputs(self):
         if self._draft_state_cache is None:
             self._draft_state_cache = self._draft_session._state_vals()
-        dparams, dbufs = self._draft_state_cache
-        if tr is None:
-            emitted_dev, m_dev, pending_dev = self._spec_round(
-                params, bufs, dparams, dbufs)
-            emitted, m_host = jax.device_get((emitted_dev, m_dev))
-        else:
-            with tr.span("tick.decode", spec_k=self.spec_k,
-                         live=len(self._active), slots=self.slots):
-                emitted_dev, m_dev, pending_dev = self._spec_round(
-                    params, bufs, dparams, dbufs)
-            with tr.span("tick.sample"):
-                emitted, m_host = jax.device_get((emitted_dev, m_dev))
-        if tr is None:
-            self._deliver_round(emitted, m_host)
-        else:
-            with tr.span("tick.deliver"):
-                self._deliver_round(emitted, m_host)
-        if not self._membership_dirty:
-            # steady state: every slot committed its full round, so the
-            # device-resident pending vector is already next round's
-            # draft input
-            self._tok_dev = pending_dev
-        return bool(self._active or self._queue or self._prefilling
-                    or self._spilled)
+        return super()._sync_step_inputs() + self._draft_state_cache
 
-    def _spec_round(self, params, bufs, dparams, dbufs):
+    def _decode_meta(self, *inputs) -> dict:
+        return dict(spec_k=self.spec_k, live=len(self._active),
+                    slots=self.slots)
+
+    def _launch(self, params, bufs, dparams, dbufs):
         """The round's device work: K draft steps, one verify, one
         draft fixup (K = the runtime ``spec_k_active``).  Returns
-        ``(emitted_dev, m_dev, pending_dev)``."""
+        ``(emitted_dev, m_dev)``, which the tick downloads together:
+        both transfers start before the host blocks, where two fetches
+        in turn would pay two round trips a round over a thin
+        transport."""
         k = self._spec_k_active
         t0 = time.perf_counter() if self._time_split else 0.0
         d_toks = []
@@ -472,7 +422,12 @@ class SpeculativePool(GenerationPool):
             jax.block_until_ready(chunk)
             t1 = time.perf_counter()
             self._draft_time_s += t1 - t0
-        self._cache, emitted_dev, m_dev, pending_dev = self._verify_jit(
+        # the pending vector (each row's last emitted token) is next
+        # round's draft input, fed straight back on-device: it stands
+        # while every slot commits its full round, and a slot that
+        # commits less finishes, which marks the membership dirty and
+        # has the next round rebuild the vector from ``_last_tok``
+        self._cache, emitted_dev, m_dev, self._tok_dev = self._verify_jit(
             params, bufs, self._cache, chunk, self._active_dev,
             self._adapter_dev)
         if self._time_split:
@@ -484,36 +439,20 @@ class SpeculativePool(GenerationPool):
         self._draft_cache = self._draft_fixup_jit(
             dparams, dbufs, self._draft_cache, d_toks[-1], m_dev,
             self._active_dev, jnp.asarray(k, jnp.int32))
-        return emitted_dev, m_dev, pending_dev
+        return emitted_dev, m_dev
 
-    def _deliver_round(self, emitted, m_host) -> None:
-        """Commit each slot's accepted chunk: acceptance accounting,
-        per-token ``on_token`` hooks, EOS/budget finishes.
-
-        The caller already did the round's ONE batched download
-        (tools/analysis host-sync-in-hot-path): ``jax.device_get``
-        starts both transfers before blocking, where two np.asarray
-        calls would pay two sequential host round trips per round over
-        a thin transport."""
-        n_active = len(self._active)
+    def _deliver(self, host) -> None:
+        """Acceptance accounting, then each slot's accepted chunk into
+        ``_commit``, which cuts it at EOS and at the budget."""
+        emitted, m_host = host
         self._rounds += 1
-        self._drafted += self._spec_k_active * n_active
+        self._drafted += self._spec_k_active * len(self._active)
         self._accepted += int(m_host[list(self._active)].sum())
         for slot in list(self._active):
             state = self._active[slot]
-            take = emitted[slot, :int(m_host[slot]) + 1] \
-                .astype(np.int32)[:state.remaining]
-            take = truncate_at_eos(take, self.eos_id)
-            state.tokens.extend(int(x) for x in take)
-            state.remaining -= len(take)
-            if self.on_token is not None:
-                for x in take:
-                    self.on_token(state.rid, int(x))
-            self._last_tok[slot] = int(take[-1])
-            if state.remaining == 0 or \
-                    (self.eos_id is not None and
-                     int(take[-1]) == self.eos_id):
-                self._finish(slot)
+            self._commit(
+                slot, map(int, emitted[slot, :int(m_host[slot]) + 1]))
+            self._last_tok[slot] = state.tokens[-1]
 
     def refresh_weights(self):
         """Drop BOTH models' cached weight value lists (hot swap)."""

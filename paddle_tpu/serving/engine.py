@@ -99,7 +99,7 @@ import numpy as np
 from ..core.errors import (InvalidArgumentError, NotFoundError,
                            PreconditionNotMetError, UnavailableError)
 from ..inference.generation import (DuplicateRequestError, GenerationPool,
-                                    _SamplingConfig)
+                                    _SamplingConfig, tick_phase)
 from ..profiler import StepTimer
 from . import faults, trace
 from . import log as slog
@@ -2310,24 +2310,18 @@ class ServingEngine:
 
     def _run_tick(self, tr) -> bool:
         """One tick.  ``tr`` is the installed tracer or None: the tick's
-        own work around ``pool.step()`` is spanned by the pool's
-        pattern, one ``is None`` test per phase when tracing is off —
+        own work around ``pool.step()`` is spanned the pool's way
+        (``tick_phase``: a shared no-op when tracing is off) —
         ``tick.govern`` (deadlines, the degradation ladder),
         ``tick.observe`` (handoff sweep, gauges), ``tick.journal``
         (flush, SLO roll, heartbeat)."""
         self._health.note_tick_start(self._clock())
         try:
-            if tr is None:
+            with tick_phase(tr, "tick.govern"):
                 self._govern()
-            else:
-                with tr.span("tick.govern"):
-                    self._govern()
             if not self._live:
-                if tr is None:
+                with tick_phase(tr, "tick.observe"):
                     self._observe_gauges()
-                else:
-                    with tr.span("tick.observe"):
-                        self._observe_gauges()
                 return False
             self._h_queue.observe(self._pool.queue_depth)
             try:
@@ -2337,18 +2331,12 @@ class ServingEngine:
                 self._health.note_error(self._clock(), e,
                                         faults.classify_error(e))
                 self._recover(e)
-            if tr is None:
+            with tick_phase(tr, "tick.observe"):
                 self._observe()
-            else:
-                with tr.span("tick.observe"):
-                    self._observe()
             return bool(self._live)
         finally:
-            if tr is None:
+            with tick_phase(tr, "tick.journal"):
                 self._close_tick()
-            else:
-                with tr.span("tick.journal"):
-                    self._close_tick()
 
     def _govern(self) -> None:
         self._expire()

@@ -11,11 +11,14 @@ Contracts pinned:
    quantize/dequantize round-trips (including the padded-block and
    all-zero-block paths); the wire-byte helpers return the exact ring
    figures.
-2. TOKEN IDENTITY: ``collective_quant="int8"`` decode is greedy
-   token-identical to the unquantized mesh on 1×2 and 2×2 meshes
-   across paged × {fp32, int8-KV} for the pinned test model, with
-   identical ``compile_counts()`` (python-static seam — the mode
-   selects which ops get TRACED, never a new executable kind).
+2. TOKEN IDENTITY, AS FAR AS INT8 CAN PROMISE IT:
+   ``collective_quant="int8"`` decode emits the unquantized mesh's
+   greedy tokens on 1×2 and 2×2 meshes across paged × {fp32, int8-KV}
+   until a step whose top-2 logit margin is a near-tie (under
+   ``_NEAR_TIE`` of the logit scale), parts from them only there and
+   only for a token as near the top, with identical
+   ``compile_counts()`` (python-static seam — the mode selects which
+   ops get TRACED, never a new executable kind).
 3. BYTE-IDENTITY OF "none": a mesh pool with the default mode decodes
    byte-identically to the unsharded pool (the seam is recording-only:
    the traced jaxpr is the GSPMD path's).
@@ -30,6 +33,7 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+import chip_smoke
 import paddle_tpu as pt
 from paddle_tpu.core.errors import InvalidArgumentError
 from paddle_tpu.distributed import qcollectives as qc
@@ -43,15 +47,17 @@ CFG = dict(vocab_size=96, hidden_size=32, num_layers=2, num_heads=4,
            intermediate_size=64, max_position=64, causal=True,
            dropout=0.0)
 
-# The greedy-identity model seed.  Identity through a quantized
-# collective is a MARGIN property: the top-1 logit gap must exceed the
-# quantization perturbation.  A random-init model has near-tie logits,
-# and seeds 0-1 of this config hold gaps below the int8 error floor —
-# real (trained) models don't decode on coin-flip margins, so the
-# contract is pinned on a seed whose margins are sane (2..7 all pass);
-# the PRIMITIVE tests below bound the perturbation itself analytically
-# for every seed.
+# The model seed.  Identity through a quantized collective is a MARGIN
+# property: the top-1 logit gap must exceed the quantization
+# perturbation.  A random-init model has near-tie logits at some step
+# of nearly every seed (real, trained models don't decode on coin-flip
+# margins), so the identity tests hold the quantized run to the
+# reference's tokens until a step whose margin is under ``_NEAR_TIE`` of
+# the logit scale: the gate this repo puts on two numerically different
+# paths (chip_smoke.py).  The PRIMITIVE tests below bound the
+# perturbation itself analytically for every seed.
 SEED = 2
+_NEAR_TIE = 2.0 ** -5
 
 
 def _fresh_model(seed=SEED):
@@ -72,6 +78,25 @@ def _pool(mesh=None, dtype="float32", **kw):
                           buckets=[16], cache_layout="paged",
                           block_size=4, cache_dtype=dtype, mesh=mesh,
                           **kw)
+
+
+def _assert_same_until_near_tie(prompts, want, got):
+    """``got`` is ``want`` until a near-tie of the float model's logits
+    (one uncached forward over prompt + ``want``: causality makes its
+    per-position logits the ones each greedy step saw); where they
+    part, the token taken is within the gate of the top one."""
+    model = _fresh_model()
+    model.eval()
+    for i, (p, w, g) in enumerate(zip(prompts, want, got)):
+        seq = np.concatenate([p, w])[None]
+        logits = np.asarray(model(pt.to_tensor(seq)).value)[0,
+                                                            len(p) - 1:-1]
+        gate = _NEAR_TIE * float(np.abs(logits).max())
+        top2 = np.sort(logits, axis=-1)[:, -2:]
+        n = chip_smoke.check_same_until_near_tie(
+            g, w, top2[:, 1] - top2[:, 0], gate, "prompt %d" % i)
+        if n < len(w):
+            assert logits[n, w[n]] - logits[n, g[n]] < gate, (i, n)
 
 
 # -- contract 1: primitives --------------------------------------------------
@@ -206,9 +231,9 @@ QMESHES = [(1, 2), (2, 2)]
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
 @pytest.mark.parametrize("dp,mp", QMESHES)
 def test_int8_token_identity_and_compile_counts(dp, mp, dtype):
-    """Contract 2: the quantized mesh decodes the same greedy tokens
-    as the unquantized mesh, compiles the same executables, and stamps
-    quantized bytes strictly below the dense ring's."""
+    """Contract 2: the quantized mesh decodes the unquantized mesh's
+    greedy tokens until a near-tie, compiles the same executables, and
+    stamps quantized bytes strictly below the dense ring's."""
     prompts = _prompts()
     ref = _pool(mesh=DecodeMesh(dp, mp), dtype=dtype)
     want = ref.generate(prompts, 8)
@@ -216,8 +241,7 @@ def test_int8_token_identity_and_compile_counts(dp, mp, dtype):
     pool = _pool(mesh=DecodeMesh(dp, mp, collective_quant="int8"),
                  dtype=dtype)
     got = pool.generate(prompts, 8)
-    for w, g in zip(want, got):
-        np.testing.assert_array_equal(w, g)
+    _assert_same_until_near_tie(prompts, want, got)
     assert pool.compile_counts() == ref.compile_counts()
 
     stats = pool.cache_stats()
@@ -326,8 +350,8 @@ def test_engine_threads_collective_quant():
     streams = [eng.submit(p, 8) for p in prompts]
     while eng.pump(4):
         pass
-    for s, w in zip(streams, want):
-        np.testing.assert_array_equal(s.result(timeout_s=0).tokens, w)
+    _assert_same_until_near_tie(
+        prompts, want, [s.result(timeout_s=0).tokens for s in streams])
     assert eng.cache_stats()["collective_quant"] == "int8"
     assert eng.compile_counts() == ref.compile_counts()
 

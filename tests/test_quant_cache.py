@@ -7,10 +7,11 @@ Pins the contracts the int8 cache lives on (docs/DESIGN.md §5d):
   attention compositions (dense and paged) equal the explicit
   dequantize-then-attend reference exactly — the dtype changes BYTES
   STREAMED, never the math graph;
-- greedy int8 generation is TOKEN-IDENTICAL to fp32 over the
-  short-horizon corpus, for dense AND paged layouts, session and pool
+- greedy int8 generation emits fp32's tokens over the short-horizon
+  corpus until a step whose fp32 top-2 margin is under the bounded
+  quantization error, for dense AND paged layouts, session and pool
   (the acceptance contract), and cached int8 logits diverge from the
-  fp32 full forward by a bounded quantization error;
+  fp32 full forward by no more than that bound;
 - ``DecodeSession(cache_dtype="int8")`` still compiles exactly two
   functions — the scales are just more donated carry leaves;
 - a freed paged slot's writes (values AND scales) are masked to the
@@ -26,6 +27,7 @@ Pins the contracts the int8 cache lives on (docs/DESIGN.md §5d):
 import numpy as np
 import pytest
 
+import chip_smoke
 import paddle_tpu as pt
 from paddle_tpu.core.errors import InvalidArgumentError
 from paddle_tpu.inference import GenerationPool, kv_reachable_bytes
@@ -151,28 +153,28 @@ def test_int8_paged_decode_attention_matches_dense_int8():
 
 # -- greedy agreement (the acceptance contract) --------------------------
 
-# The short-horizon corpus is MARGIN-GATED: int8 quantization perturbs
-# logits by up to ~0.02 on this model (see the divergence bound below),
-# so a prompt whose fp32 top-2 decision margin sits UNDER that noise
-# floor at some step is a genuine coin-flip — no cache dtype can promise
-# its argmax (a random-init toy model's margins are occasionally ~1e-3;
-# a trained model's are orders of magnitude wider).  Prompts whose every
-# decision clears the floor must match token-for-token; the corpus is
-# sized so enough prompts qualify for the check to have teeth.
-_MARGIN_FLOOR = 5e-3
+# What an int8 cache can promise about greedy tokens is a MARGIN
+# property.  Quantization moves a cached logit by at most
+# ``_INT8_LOGIT_TOL`` on this model (the bound
+# ``test_int8_logit_divergence_bounded`` holds it to; about 0.02 is
+# observed), so the int8 run emits the fp32 run's tokens until a step
+# whose fp32 top-2 margin is under that tolerance: such a step is a
+# near-tie no cache dtype can promise (a random-init toy model has them;
+# a trained model's margins are orders of magnitude wider).  Where the
+# two runs part, they part there, and on a token whose fp32 logit is
+# within the tolerance of the top.
+_INT8_LOGIT_TOL = 0.08
 
 
-def _fp32_greedy_with_margin(model, sess_fp32, ids, gen):
-    """(fp32 greedy tokens, min top-2 logit margin over every decision)
-    — the margin read from ONE uncached full forward over the generated
+def _fp32_greedy_with_logits(model, sess_fp32, ids, gen):
+    """(fp32 greedy tokens, the logits each greedy step saw) — the
+    logits read from ONE uncached full forward over the generated
     sequence (causality makes its per-position logits the ones each
     greedy step saw)."""
     got = sess_fp32.generate(ids, gen)
     full_seq = np.concatenate([np.asarray(ids), got], axis=1)
     logits = np.asarray(model(pt.to_tensor(full_seq)).value)
-    steps = logits[:, ids.shape[1] - 1:-1]  # the gen emitting positions
-    top2 = np.sort(steps, axis=-1)[..., -2:]
-    return got, float((top2[..., 1] - top2[..., 0]).min())
+    return got, logits[:, ids.shape[1] - 1:-1]  # the emitting positions
 
 
 @pytest.mark.parametrize("layout_kw", [
@@ -184,19 +186,29 @@ def test_int8_greedy_token_identical_short_horizon(model, sess_fp32,
     sess8 = DecodeSession(model, max_len=64, buckets=[16],
                           cache_dtype="int8", **layout_kw)
     model.eval()
-    checked = 0
+    same = total = 0
     for seed in range(8):
         rng = np.random.RandomState(seed)
         length = int(rng.randint(3, 15))
         ids = rng.randint(0, 128, (2, length)).astype("int32")
-        want, margin = _fp32_greedy_with_margin(model, sess_fp32, ids, 8)
-        if margin < _MARGIN_FLOOR:
-            continue  # a genuine near-tie: argmax undefined under quant
-        np.testing.assert_array_equal(
-            sess8.generate(ids, 8), want,
-            err_msg="seed %d margin %.4f" % (seed, margin))
-        checked += 1
-    assert checked >= 5, "corpus too thin: only %d prompts" % checked
+        want, logits = _fp32_greedy_with_logits(model, sess_fp32, ids, 8)
+        got = sess8.generate(ids, 8)
+        top2 = np.sort(logits, axis=-1)[..., -2:]
+        margins = top2[..., 1] - top2[..., 0]
+        for row in range(len(ids)):
+            what = "seed %d row %d" % (seed, row)
+            n = chip_smoke.check_same_until_near_tie(
+                got[row], want[row], margins[row], _INT8_LOGIT_TOL, what)
+            if n < len(want[row]):
+                step = logits[row, n]
+                gap = step[want[row, n]] - step[got[row, n]]
+                assert gap < _INT8_LOGIT_TOL, (what, n, gap)
+            same += n
+            total += len(want[row])
+    # near-ties are the exception: the check has teeth only if most of
+    # the corpus is compared token for token
+    assert same >= 0.9 * total, "only %d of %d tokens compared" % (
+        same, total)
 
 
 def test_int8_logit_divergence_bounded(model):
@@ -218,7 +230,7 @@ def test_int8_logit_divergence_bounded(model):
             parts.append(np.asarray(lg.value))
         got = np.concatenate(parts, axis=1)
         err = float(np.abs(got - full).max())
-        assert err < 0.08, err
+        assert err < _INT8_LOGIT_TOL, err
         assert err > 0.0  # int8 is genuinely lossy: exact == not-int8
 
 

@@ -24,7 +24,11 @@ The contracts pinned here, in order of load-bearing-ness:
 6. terminal trace events exist for every request after drain/shutdown
    (timelines never end mid-span);
 7. every compiled step carries the module tree and the hand-placed
-   scopes in its operations' ``op_name``, at unchanged compile counts.
+   scopes in its operations' ``op_name``, at unchanged compile counts;
+8. the tick is written once: every kind of pool runs
+   ``GenerationPool.step``, with the same phases in the same order and
+   its own ``tick.decode`` meta, and without a tracer makes no span and
+   commits the same tokens.
 """
 import json
 import re
@@ -36,7 +40,9 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu.core.errors import (NotFoundError,
                                     PreconditionNotMetError)
-from paddle_tpu.models import TransformerLM
+from paddle_tpu.inference import (BlockDiffusionPool, GenerationPool,
+                                  SpeculativePool)
+from paddle_tpu.models import BlockDiffusionMoELM, TransformerLM
 from paddle_tpu.serving import (MetricsRegistry, RequestState,
                                 ServingEngine, Supervisor, faults,
                                 trace)
@@ -789,3 +795,120 @@ def test_recorder_tail_dicts_bounded():
     assert len(tail) == 10
     assert tail[-1]["name"] == "e49"
     json.dumps(tail)
+
+
+# -- 8. one tick for every kind of pool -------------------------------------
+
+# ``tick.decode``'s meta by kind of pool (paged, so the block counts ride)
+_DECODE_META = {
+    "plain": {"live", "slots", "live_blocks", "table_blocks"},
+    "speculative": {"spec_k", "live", "slots"},
+    "block": {"live", "slots", "kind", "rows", "store", "denoise",
+              "committed", "tokens_per_forward", "live_blocks",
+              "table_blocks"},
+}
+_KINDS = sorted(_DECODE_META)
+
+
+def _pool_of(kind, model):
+    kw = dict(slots=2, buckets=[32], cache_layout="paged", block_size=8)
+    if kind == "plain":
+        return GenerationPool(model, 64, **kw)
+    if kind == "speculative":
+        return SpeculativePool(model, model, 64, spec_k=2, **kw)
+    pt.seed(0)
+    return BlockDiffusionPool(BlockDiffusionMoELM(
+        vocab_size=128, hidden_size=32, num_layers=1, num_heads=2,
+        num_kv_heads=1, head_dim=16, expert_size=16, num_experts=4,
+        top_k=2, block_length=4, mask_token_id=127, denoise_steps=2,
+        dtype="float32"), 64, cache_dtype="float32", **kw)
+
+
+def _drain_by_steps(pool, budget=6):
+    """Three requests through two slots, one ``step()`` at a time:
+    ({rid: tokens}, what each step returned beside ``_pending()`` as it
+    returned)."""
+    for p in _prompts(3):
+        pool.submit(p, budget)
+    returned = []
+    while True:
+        more = pool.step()
+        returned.append((more, pool._pending()))
+        if not more:
+            break
+    return {rid: t.tolist() for rid, t in pool.run().items()}, returned
+
+
+@pytest.mark.parametrize("kind", _KINDS)
+def test_every_pool_runs_the_one_step(model, kind):
+    pool = _pool_of(kind, model)
+    assert type(pool).step is GenerationPool.step
+    assert type(pool)._commit is GenerationPool._commit
+    assert type(pool)._pending is GenerationPool._pending
+    # drained from the start: nothing pending, nothing to do
+    assert pool.step() is False and pool._pending() is False
+    # a request that waits for a slot is pending work all the same
+    _, returned = _drain_by_steps(pool)
+    assert all(more == pending for more, pending in returned)
+    assert [more for more, _ in returned][-2:] == [True, False]
+    assert len(returned) > 2
+
+
+@pytest.mark.parametrize("kind", _KINDS)
+def test_a_ticks_phases_in_order_with_the_pools_meta(model, kind):
+    pool = _pool_of(kind, model)
+    for p in _prompts(3):
+        pool.submit(p, 6)
+    tracer = trace.install(Tracer(capacity=4096))
+    ticks, seen = [], 0
+    more = True
+    while more:
+        more = pool.step()
+        events = tracer.recorder.snapshot()
+        ticks.append(sorted(events[seen:], key=lambda e: e.ts))
+        seen = len(events)
+    trace.uninstall()
+    admitted = 0
+    for events in ticks:
+        names = " ".join(e.name for e in events)
+        assert re.fullmatch(
+            r"tick\.admit( tick\.prefill)*"
+            r"( tick\.decode tick\.sample tick\.deliver)?", names), names
+        admit = events[0]
+        for e in events[1:]:
+            if e.name == "tick.prefill":
+                # nested in the admit phase, one per admitted request
+                assert e.ts + e.dur_s <= admit.ts + admit.dur_s
+                assert e.rid is not None
+                assert set(e.meta) == {"prompt_tokens", "bucket"}
+                assert e.meta["bucket"] == 32
+                admitted += 1
+            elif e.name == "tick.decode":
+                assert set(e.meta) == _DECODE_META[kind], e.meta
+                assert 1 <= e.meta["live"] <= e.meta["slots"] == 2
+            else:
+                assert e.meta is None, (e.name, e.meta)
+    assert admitted == 3
+    # the tick that finished the last request found nothing pending
+    assert ticks[-1][-1].name == "tick.deliver"
+    assert sum(len(t) > 1 for t in ticks) >= 3
+
+
+@pytest.mark.parametrize("kind", _KINDS)
+def test_untraced_tick_makes_no_span_and_the_same_tokens(
+        model, kind, monkeypatch):
+    with trace.tracing(Tracer(capacity=4096)):
+        traced, _ = _drain_by_steps(_pool_of(kind, model))
+    built = []
+    monkeypatch.setattr(trace._Span, "__init__",
+                        lambda self, *a: built.append(a))
+    monkeypatch.setattr(trace, "TraceAnnotation",
+                        lambda *a, **k: built.append(a))
+    pool = _pool_of(kind, model)
+    # nor is the decode span's meta built when nobody reads it
+    monkeypatch.setattr(type(pool), "_decode_meta",
+                        lambda self, *a: built.append(a))
+    untraced, _ = _drain_by_steps(pool)
+    assert built == []
+    assert untraced == traced
+    assert all(len(t) == 6 for t in untraced.values())

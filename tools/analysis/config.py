@@ -22,7 +22,6 @@ HOT_ROOTS = (
     "DecodeSession._prefill",
     "DecodeSession._decode",
     "GenerationPool.step",
-    "SpeculativePool.step",
     "ServingEngine._tick",
     # host-driven seq2seq decode loop (nn/decode.py): eager by design,
     # but its per-step body is hot all the same
@@ -71,14 +70,19 @@ EXTRA_EDGES = {
     "paged_decode_attention": ("paged_decode_attention_kernel",),
     "TransformerEncoder.forward": ("TransformerEncoderLayer.forward",),
     "TransformerDecoder.forward": ("TransformerDecoderLayer.forward",),
+    # the one tick (docs "The tick"): ``step`` is GenerationPool's for
+    # every kind of pool and reaches a kind's step through hooks the
+    # AST resolves to the base class only, so the speculative round's
+    # overrides are declared (the block pool's step was never a root)
     "GenerationPool.step": ("ServingEngine._on_token",
                             "ServingEngine._on_finish",
-                            "Tracer.span"),
+                            "SpeculativePool._sync_step_inputs",
+                            "SpeculativePool._launch",
+                            "SpeculativePool._deliver"),
     "GenerationPool._refill": ("ServingEngine._on_admit",
                                "ServingEngine._on_token",
                                "ServingEngine._on_finish",
-                               "GenerationPool._resume",
-                               "Tracer.span"),
+                               "GenerationPool._resume"),
     # prefix-sharing admission + chunked prefill (docs §5i): the
     # admission match and the chunk dispatch are new hot-path seams —
     # the admission write and the chunk executable dispatch through
@@ -87,8 +91,7 @@ EXTRA_EDGES = {
     # prefill, so the whole path stays hot-path-audited
     "GenerationPool._admit_chunked": ("AotFunction.__call__",
                                       "ServingEngine._on_admit"),
-    "GenerationPool._chunk_work": ("AotFunction.__call__",
-                                   "Tracer.span"),
+    "GenerationPool._chunk_work": ("AotFunction.__call__",),
     "GenerationPool._activate": ("ServingEngine._on_token",
                                  "ServingEngine._on_finish",
                                  "SpeculativePool._on_activated",
@@ -109,9 +112,6 @@ EXTRA_EDGES = {
     "GenerationPool._resume": ("ServingEngine._on_resume",
                                "SpeculativePool._on_resumed",
                                "GenerationPool._reclaim_one_spilled"),
-    "SpeculativePool.step": ("ServingEngine._on_token",
-                             "ServingEngine._on_finish",
-                             "Tracer.span"),
     # sharded serving (docs §5k): the mesh placement helpers are
     # reached through ``self._mesh`` — assigned from a constructor
     # ARGUMENT, so the AST's local-constructor type inference cannot
@@ -218,6 +218,9 @@ EXTRA_EDGES = {
     # the fault plane's
     "instant": ("Tracer.instant",),
     "ServingEngine._run_tick_traced": ("Tracer.span", "Tracer.instant"),
+    # every phase of a tick, the pool's and the engine's, is spanned
+    # through this one helper
+    "tick_phase": ("Tracer.span",),
     "ServingEngine.submit": ("Tracer.span",),
     "Tracer.span": ("_Span.__enter__", "_Span.__exit__", "_Span.set"),
     "_Span.__exit__": ("Tracer._emit",),
@@ -231,15 +234,14 @@ EXTRA_EDGES = {
     # the same hot path and is declared here so the host-sync rule
     # audits it; the compile-miss path runs once per executable, never
     # in steady state, but is reachable and therefore audited too
-    "GenerationPool._dispatch": ("AotFunction.__call__",),
-    "SpeculativePool._spec_round": ("AotFunction.__call__",),
+    "GenerationPool._launch": ("AotFunction.__call__",),
+    "SpeculativePool._launch": ("AotFunction.__call__",),
     "AotFunction.__call__": ("AotFunction._compile_miss",),
     "AotFunction._compile_miss": ("analyze_compiled", "kv_arg_bytes"),
     # SLO plane (serving/slo.py): fed from the engine's tick path
     # behind is-None guards; the tracker's own emission (alert flips
     # into the trace + structured log) is declared so the whole seam
     # is hot-path-audited like the fault/trace planes
-    "ServingEngine._run_tick": ("Tracer.span",),
     "ServingEngine._close_tick": ("SLOTracker.note_tick",),
     "ServingEngine._on_token": ("SLOTracker.observe_latency",),
     "SLOTracker.note_tick": ("_ObjectiveState.roll", "instant",
