@@ -327,6 +327,51 @@ def _prompts(sz: dict, rng) -> list:
             for i in range(sz["requests"])]
 
 
+def check_step_ahead(jax, engine, prompt, new_tokens: int,
+                     tag: str) -> None:
+    """The host runs one step behind the device (docs/DESIGN.md 5t): on
+    a steady tick the pool launches step t+1 with step t still in
+    flight (``tick.decode``'s ``ahead`` is 1), so the tick's period is
+    the step's device time and not that plus the host's turn.  One
+    request in pump mode under the tracer; the period is printed beside
+    one step timed alone (launched on an idle device and blocked on), so
+    a bring-up on another chip sees a lost overlap at once."""
+    pool = engine._pool
+    tracer = engine.start_trace()
+    try:
+        stream = engine.submit(prompt, new_tokens,
+                               request_id="%s-ahead" % tag)
+        while engine.request_state(stream.request_id) != "DECODING":
+            check(engine.pump(1), "the request never reached DECODING")
+        engine.pump(2)
+        engine.settle()                         # the device is idle now
+        t0 = time.perf_counter()
+        pool._launch_step(None)
+        check(pool._flights, "no step to launch for a decoding request")
+        jax.block_until_ready(pool._flights[-1][0])
+        alone_ms = (time.perf_counter() - t0) * 1e3
+        mark = time.perf_counter()
+        done = stream.result()
+    finally:
+        engine.stop_trace()
+    check(done.state == "DONE", "the request ended %r: %s"
+          % (done.state, done.error))
+    spans = sorted((e for e in tracer.recorder.snapshot()
+                    if e.dur_s is not None and e.ts >= mark),
+                   key=lambda e: e.ts)
+    aheads = [e.meta["ahead"] for e in spans if e.name == "tick.decode"]
+    delivers = [e.ts for e in spans if e.name == "tick.deliver"]
+    check(len(aheads) >= 4, "only %d launches traced" % len(aheads))
+    check(all(a == 1 for a in aheads),
+          "a steady tick launched with nothing in flight: ahead %r"
+          % (aheads,))
+    period_ms = float(np.median(np.diff(delivers))) * 1e3
+    say("[%s] host one step behind the device: %d of %d steady launches "
+        "made with a step in flight; tick period %.2f ms beside %.2f ms "
+        "for one step launched alone and blocked on"
+        % (tag, sum(aheads), len(aheads), period_ms, alone_ms))
+
+
 def serve_requests(jax, model, sz: dict, mesh=None,
                    tag: str = "one-chip") -> dict:
     """One engine over ``model`` (optionally on ``mesh``): the spill
@@ -377,6 +422,7 @@ def serve_requests(jax, model, sz: dict, mesh=None,
             "%d tokens identical"
             % (tag, len(spilled), stats["spill_bytes_total"],
                len(resumed.tokens)))
+        check_step_ahead(jax, engine, prompts[0], sz["spill_tokens"], tag)
         warm_counts = engine.compile_counts()
 
         # -- the traffic: owned step loop + HTTP over loopback

@@ -28,6 +28,18 @@ order as the committed prefix of the block grows; budgets, EOS and finish
 count tokens, not steps.  A last block commits only the positions that
 were asked for; the rest stay mask ids and are never delivered.
 
+The host runs one step behind the device (``GenerationPool.step``,
+docs/DESIGN.md §5t), so a block's state, its tokens and what of it is
+still masked, feeds back ON THE DEVICE: the step's packed output is the
+next step's input.  The upload holds only what the host knows ahead of
+the download: how many tokens the step commits, whether it stores, which
+rows are live, and the rows that START a block (a prompt's trailing
+partial block, the all-masked block after a store), which the step takes
+from the host.  With a fixed number of denoising steps and no confidence
+threshold all of that is settled when a request is admitted: ``_Cursor``
+walks it on the launch side, a step ahead of ``_Block``, which follows
+the downloads.
+
 What this variant does not do is refused at construction with a typed
 error (``docs/DESIGN.md``): prefix sharing, chunked prefill, preemption
 and spill, a prefill-only tier, a mesh, an int8 cache, sampling with a
@@ -76,6 +88,20 @@ class _Block:
         self.limit, self.delivered, self.plan = limit, delivered, plan
         self.step = 0                       # denoising steps run so far
         self.steps = [0] * len(toks)
+
+
+class _Cursor:
+    """Where the LAUNCHES of one slot stand, a step ahead of the slot's
+    ``_Block``: ``plan`` holds the commit counts of the current block's
+    denoising steps still to launch; ``left`` is what the request may
+    fill after this block; ``row`` is the block's first state, which
+    the next launch hands to the device (None once it has: the state
+    then feeds back there); ``pos`` is where the block starts."""
+
+    __slots__ = ("plan", "left", "row", "pos")
+
+    def __init__(self, plan, left, row, pos):
+        self.plan, self.left, self.row, self.pos = plan, left, row, pos
 
 
 _REFUSED = {
@@ -154,8 +180,14 @@ class BlockDiffusionPool(GenerationPool):
             key_fn=lambda p, b, ids, *r: aot.shape_key(ids),
             name="block_prefill")
         self._blocks: Dict[int, _Block] = {}
-        # a free slot's row of the packed upload: mask ids, nothing to do
-        self._idle_row = [self._mask_id] * self._B + [0] * (self._B + 3)
+        self._cursors: Dict[int, _Cursor] = {}
+        # the blocks' state on the device, ``[slots, 2B]`` as the step
+        # returns it (None until the first launch)
+        self._carry = None
+        # a row of the packed upload that hands the device no state: a
+        # free slot's, and the front of a live one whose state feeds
+        # back on the device
+        self._no_row = [0] * (2 * self._B)
         # what the step did, ever: read by the engine's counters
         self.forwards_denoise = 0
         self.forwards_store = 0
@@ -181,16 +213,21 @@ class BlockDiffusionPool(GenerationPool):
         return self._layout.finalize_prefill(
             cache, jnp.asarray(whole, jnp.int32), self.max_len)
 
-    def _block_step(self, param_vals, buf_vals, cache, ctl):
-        """One forward over every slot's current block.  ``ctl`` is the
-        tick's packed upload, int32 ``[slots, 2B + 3]``: the block's
-        tokens, which of them are still to fill, how many to commit
-        (0 for a store), whether the index advances (a store), whether
-        the slot is live.  Returns the cache and the packed download
-        ``[slots, 2B]``: the tokens after the commit and what is still
-        masked."""
+    def _block_step(self, param_vals, buf_vals, cache, carry, ctl):
+        """One forward over every slot's current block.  ``carry`` is
+        the last step's packed output, int32 ``[slots, 2B]``: every
+        block's tokens and which of them are still to fill.  ``ctl`` is
+        the tick's packed upload, int32 ``[slots, 2B + 4]``: a block's
+        first state in ``carry``'s form, how many tokens to commit (0
+        for a store), whether the index advances (a store), whether the
+        slot is live, and whether the slot takes its state from ``ctl``
+        (it starts a block) and not from ``carry``.  Returns the cache
+        and the packed output ``[slots, 2B]``, the tokens after the
+        commit and what is still masked: the download, and the next
+        step's ``carry``."""
         bl = self._B
-        toks, masked = ctl[:, :bl], ctl[:, bl:2 * bl] != 0
+        state = jnp.where(ctl[:, 2 * bl + 3:] != 0, ctl[:, :2 * bl], carry)
+        toks, masked = state[:, :bl], state[:, bl:] != 0
         count = ctl[:, 2 * bl]
         advance = (ctl[:, 2 * bl + 1] != 0) & (ctl[:, 2 * bl + 2] != 0)
         active = ctl[:, 2 * bl + 2] != 0
@@ -278,59 +315,84 @@ class BlockDiffusionPool(GenerationPool):
             priority=req.priority, tenant=req.tenant,
             deadline=req.deadline, seq=req.seq, sampling=req.sampling,
             adapter=req.adapter)
-        self._blocks[slot] = self._new_block(
-            req.ids[len(req.ids) // self._B * self._B:],
-            req.max_new_tokens)
+        whole = len(req.ids) // self._B * self._B
+        blk = self._blocks[slot] = self._new_block(
+            req.ids[whole:], req.max_new_tokens)
+        self._cursors[slot] = _Cursor(
+            list(blk.plan), req.max_new_tokens - sum(blk.plan),
+            blk.toks + blk.masked, whole)
 
-    def _last_position(self, slot: int, state) -> int:
-        # the end of the slot's current block: every row of it sees that
-        # far (tokens of the block that already left are in ``tokens``)
-        start = len(state.ids) + len(state.tokens) \
-            - self._blocks[slot].delivered
-        return start + self._B - 1
+    def _launchable(self, slot: int, state) -> bool:
+        # until the last denoising step of the request's last block has
+        # been launched (that block is not stored)
+        cur = self._cursors[slot]
+        return bool(cur.plan) or cur.left > 0
 
     def _control(self) -> np.ndarray:
-        """The tick's packed upload (see ``_block_step``)."""
-        rows = [self._idle_row] * self.slots
-        for slot in self._active:
-            blk = self._blocks[slot]
-            rows[slot] = blk.toks + blk.masked + (
-                [blk.plan[0], 0, 1] if blk.plan else [0, 1, 1])
+        """The tick's packed upload (see ``_block_step``), every cursor
+        of the step's rows moved past it."""
+        rows = [self._no_row + [0, 0, 0, 0]] * self.slots
+        for slot, st in self._rows:
+            cur = self._cursors[slot]
+            front = self._no_row if cur.row is None else cur.row
+            take = 0 if cur.row is None else 1
+            cur.row = None
+            if cur.plan:
+                rows[slot] = front + [cur.plan.pop(0), 0, 1, take]
+                continue
+            # the block is clean: store it; the next starts all masked
+            # where the index will stand
+            rows[slot] = front + [0, 1, 1, take]
+            blk = self._new_block((), cur.left)
+            cur.plan, cur.left = list(blk.plan), cur.left - sum(blk.plan)
+            cur.row, cur.pos = blk.toks + blk.masked, cur.pos + self._B
         return np.array(rows, np.int32)
 
     # -- the tick's hooks: ONE forward over every live slot's block ------
     def _sync_step_inputs(self):
+        if self._carry is None:
+            self._carry = jnp.zeros((self.slots, 2 * self._B), jnp.int32)
         return self._weights() + (self._control(),)
 
     def _decode_meta(self, params, bufs, ctl) -> dict:
         # a denoising step commits exactly what its plan asks: known
         # before the dispatch, so the span carries it
-        bl, live = self._B, len(self._active)
+        bl, live = self._B, len(self._rows)
         stores = int(ctl[:, 2 * bl + 1].sum())
         committed = int(ctl[:, 2 * bl].sum())
         kind = "store" if stores == live else \
             "denoise" if not stores else "mixed"
-        return dict(live=live, slots=self.slots, kind=kind,
+        meta = dict(live=live, slots=self.slots, kind=kind,
                     rows=live * bl, store=stores, denoise=live - stores,
                     committed=committed,
-                    tokens_per_forward=committed / live,
-                    **self._block_meta())
+                    tokens_per_forward=committed / live)
+        if self.cache_layout == "paged":
+            # every row of a block sees to the block's end; the cursor
+            # of a row that stores stands a block further already
+            bs = self._block_size
+            meta["live_blocks"] = sum(
+                (self._cursors[slot].pos - 1
+                 + bl * (1 - int(ctl[slot, 2 * bl + 1]))) // bs + 1
+                for slot, _ in self._rows)
+            meta["table_blocks"] = self.slots * self._max_blocks
+        return meta
 
     def _launch(self, params, bufs, ctl):
-        self._cache, out_dev = self._decode_jit(params, bufs, self._cache,
-                                                ctl)
-        return out_dev
+        self._cache, self._carry = self._decode_jit(
+            params, bufs, self._cache, self._carry, ctl)
+        return self._carry
 
     def _deliver(self, out: np.ndarray) -> None:
-        """Take the step's download into every live slot's block: note
-        the commits, let the tokens of the grown committed prefix go in
-        position order (``_commit`` finishes on EOS or budget), and
-        start the next block after a store."""
+        """Take the step's download into the block of every row of it
+        that is still live: note the commits, let the tokens of the
+        grown committed prefix go in position order (``_commit``
+        finishes on EOS or budget), and start the next block after a
+        store."""
         bl = self._B
         rows = out.tolist()
-        live, stores, committed = len(self._active), 0, 0
-        for slot in list(self._active):
-            st, blk = self._active[slot], self._blocks[slot]
+        live, stores, committed = len(self._rows), 0, 0
+        for slot, st in self._rows:
+            blk = self._blocks[slot]
             if not blk.plan:
                 # that was the store: the block's K/V is kept, the index
                 # stands at the next block, which starts all masked
@@ -361,15 +423,20 @@ class BlockDiffusionPool(GenerationPool):
 
     def _finish(self, slot: int):
         self._blocks.pop(slot, None)
+        self._cursors.pop(slot, None)
         super()._finish(slot)
 
     def release(self, slot: int):
+        rid = super().release(slot)
         self._blocks.pop(slot, None)
-        return super().release(slot)
+        self._cursors.pop(slot, None)
+        return rid
 
     def reset(self):
         super().reset()
         self._blocks.clear()
+        self._cursors.clear()
+        self._carry = None
 
     def compile_counts(self) -> dict:
         return {"block_prefill": int(self._prefill_jit._cache_size()),

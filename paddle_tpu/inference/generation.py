@@ -10,16 +10,21 @@ scheme production LLM servers use (PAPERS.md: compiler-first O(1)
 autoregressive caching; the batching analog of the reference's
 ``PredictorPool``, which multiplexes predictors rather than cache slots).
 
-Dataflow per ``step()``:
+Dataflow per ``step()``, the host one step behind the device
+(docs/DESIGN.md §5t):
 
-1. free slots are refilled: each queued request runs a BUCKETED batch-1
-   prefill (compiled once per bucket, shared with every later request),
-   and its row cache is spliced into the slot by a tiny jitted insert
-   (slot id is a traced scalar — one compile total);
-2. one batched decode dispatch advances EVERY active slot a token;
-   inactive slots are masked — their cache index does not advance;
-3. the sampled token ids (the only host round-trip) are appended
-   per-request; rows hitting EOS or their token budget release the slot.
+1. one batched decode dispatch advances EVERY live slot a token, from
+   the token vector the step in flight leaves on the device; inactive
+   slots are masked — their cache index does not advance;
+2. the sampled token ids of the step BEFORE it (the only host
+   round-trip, which returns while the new step runs) are appended
+   per-request; rows hitting EOS or their token budget release the slot;
+3. free slots are refilled under the step in flight: each queued
+   request runs a BUCKETED batch-1 prefill (compiled once per bucket,
+   shared with every later request), and its row cache is spliced into
+   the slot by a tiny jitted insert (slot id is a traced scalar — one
+   compile total); its first token stays on the device and rides the
+   next download.
 
 ``cache_layout="paged"`` swaps the dense per-slot K/V slabs for the
 vLLM block-table scheme (docs/DESIGN.md §5b): K/V live in a global pool
@@ -257,13 +262,17 @@ class _SlotState:
     preemption can spill and resume without the serving layer's help:
     the cache index to restore is ``len(ids) + len(tokens) - 1``, and
     the speculative pool's draft twin re-prefills from it.
-    ``sampling``/``adapter`` are the request's as-data config; the
-    row's next draw counter is ``sampling.draws + len(tokens)`` (the
-    prefill draw was step ``draws``), so no separate step mirror is
+    ``sampling``/``adapter`` are the request's as-data config.
+    ``ahead`` counts the tokens dispatched for this row that the host
+    has not committed yet (the prefill's first token, one a step in
+    flight): the host runs a step behind the device, so the row's next
+    draw counter is ``sampling.draws + len(tokens) + ahead`` (the
+    prefill draw was step ``draws``) and no separate step mirror is
     kept."""
 
     __slots__ = ("rid", "ids", "tokens", "remaining", "priority",
-                 "tenant", "deadline", "seq", "sampling", "adapter")
+                 "tenant", "deadline", "seq", "sampling", "adapter",
+                 "ahead")
 
     def __init__(self, rid, ids, tokens, remaining: int,
                  priority: int = 0, tenant=None, deadline=None,
@@ -278,6 +287,7 @@ class _SlotState:
         self.seq = seq
         self.sampling = sampling
         self.adapter = adapter
+        self.ahead = 0
 
 
 class _PrefillState:
@@ -735,22 +745,34 @@ class GenerationPool:
         # request's slot is re-activated (fires inside _refill, like
         # on_admit)
         self.on_resume = None
-        self._last_tok = np.zeros(self.slots, np.int32)
-        # device-resident copies of the step inputs: in steady state the
-        # decoded token vector feeds straight back and the active mask is
-        # unchanged, so the only per-step host traffic is the DOWNLOAD of
-        # the sampled ids; membership changes (refill/finish) mark these
-        # dirty for a one-off re-upload
+        # the step's CARRY lives on the device (docs §5t): the decoded
+        # token vector and the per-row draw counter feed straight back
+        # from one step into the next, and a row that joins takes its
+        # values by a device-side select (``_patch_carry``), never from
+        # a host mirror — with a step in flight the host's copy would be
+        # a step stale.  What the host knows without the device — which
+        # rows are live, their sampling config and adapter ids (docs
+        # §5q) — is uploaded whole whenever ``_live_sig``, the (slot,
+        # arrival) pairs of the rows a launch takes, changes
         self._tok_dev = None
-        self._active_dev = None
-        # per-slot as-data vectors (docs §5q): sampling config + adapter
-        # ids re-uploaded only on membership changes; the per-row draw
-        # counter (_step_dev) feeds back on-device from the decode step
-        # (inactive rows frozen), exactly like the token vector
-        self._samp_dev = None
         self._step_dev = None
+        self._active_dev = None
+        self._samp_dev = None
         self._adapter_dev = None
-        self._membership_dirty = True
+        self._live_sig = None
+        carry_to = {} if mesh is None else {
+            "out_shardings": (mesh.sharding("dp"), mesh.sharding("dp"))}
+        self._patch_jit = jax.jit(self._patch, **carry_to)
+        # the host runs one step behind the device: ``_flights`` holds
+        # the steps launched and not yet downloaded, oldest first, each
+        # ``(handles, rows)`` with ``rows`` the ``(slot, state)`` pairs
+        # the step was launched for; ``_firsts`` the prefills' first
+        # tokens still on the device, ``(slot, state, token)``: they
+        # ride the next download.  ``_rows`` is the rows of the step the
+        # running hook is about
+        self._flights: collections.deque = collections.deque()
+        self._firsts: List[tuple] = []
+        self._rows: Sequence[tuple] = ()
         self._results: Dict[object, np.ndarray] = {}
         self._finish_reasons: Dict[object, str] = {}
         # serving-layer lifecycle hooks (paddle_tpu.serving sets these):
@@ -785,6 +807,15 @@ class GenerationPool:
         """
         return self._layout.insert_row(pool_cache, row_cache, slot,
                                        length, blocks)
+
+    @staticmethod
+    def _patch(toks, steps, slot, tok, step):
+        """A row joins the step's carry ON THE DEVICE: the token its
+        next step consumes (a prefill's own output array, or a resumed
+        request's last token) and its draw counter, at ``slot``; every
+        other row keeps what the step in flight computes for it."""
+        return (toks.at[slot].set(jnp.reshape(tok, ()).astype(toks.dtype)),
+                steps.at[slot].set(step))
 
     def _pool_decode(self, param_vals, buf_vals, cache, toks, active,
                      samp, step, adapter):
@@ -1250,7 +1281,6 @@ class GenerationPool:
         # table row is masked to the scratch block inside every decode
         # step until a refill overwrites it; shared blocks stay resident
         self._release_blocks(slot)
-        self._membership_dirty = True
         if self.on_finish is not None:
             self.on_finish(state.rid, tokens, reason)
 
@@ -1262,6 +1292,7 @@ class GenerationPool:
         to the scratch block inside every decode step until a refill
         overwrites it, and shared blocks outlive the release via their
         refcount.  Returns the request id the slot was serving."""
+        self._settle()
         state = self._active.pop(slot, None) \
             or self._prefilling.pop(slot, None)
         if state is None:
@@ -1272,7 +1303,6 @@ class GenerationPool:
         self._free.append(slot)
         self._release_blocks(slot)
         self._used_rids.discard(state.rid)
-        self._membership_dirty = True
         return state.rid
 
     def cancel(self, request_id):
@@ -1288,6 +1318,9 @@ class GenerationPool:
                 del self._queue[i]
                 self._used_rids.discard(request_id)
                 return "queued"
+        # past the queue the request may hold a slot: level with the
+        # device first (it may have ended in the step in flight)
+        self._settle()
         for slot, state in list(self._active.items()) \
                 + list(self._prefilling.items()):
             if state.rid == request_id:
@@ -1315,7 +1348,6 @@ class GenerationPool:
             self._free.append(slot)
             self._release_blocks(slot)
             self._used_rids.discard(request_id)
-            self._membership_dirty = True
             return "prefill-done"
         if request_id in self._results:
             del self._results[request_id]
@@ -1387,6 +1419,7 @@ class GenerationPool:
         this instead of catching mid-tick errors."""
         if not self._layout.spillable:
             return False
+        self._settle()
         for slot, st in self._active.items():
             if st.rid == request_id:
                 try:
@@ -1419,6 +1452,9 @@ class GenerationPool:
                 "preemption spills per-slot decode state to the host "
                 "tier; a dense pool has no spill granularity — use "
                 "cache_layout='paged' (or 'recurrent')")
+        # the spill reads the victim's K/V and its committed count: both
+        # must be level with the device
+        self._settle()
         slot = next((s for s, st in self._active.items()
                      if st.rid == request_id), None)
         if slot is None:
@@ -1472,7 +1508,6 @@ class GenerationPool:
             host = None  # the file is the survivor, not process RAM
         self._active.pop(slot)
         self._free.append(slot)
-        self._membership_dirty = True
         self._prefix_epoch += 1
         sp = _SpillState(st, len(blocks), written, host, host_bytes,
                          shard=shard)
@@ -1521,7 +1556,6 @@ class GenerationPool:
             host = None
         self._active.pop(slot)
         self._free.append(slot)
-        self._membership_dirty = True
         sp = _SpillState(st, 0, 0, host, host_bytes,
                          shard=self._shard_of_slot(slot))
         sp.host_path = host_path
@@ -1571,8 +1605,7 @@ class GenerationPool:
                            deadline=sp.deadline, seq=sp.seq,
                            sampling=sp.sampling, adapter=sp.adapter)
         self._active[slot] = state
-        self._last_tok[slot] = sp.tokens[-1]
-        self._membership_dirty = True
+        self._patch_carry(slot, np.int32(sp.tokens[-1]), state)
         self._resumes_total += 1
         self._upload_bytes_total += sp.host_bytes
         self._spill_drop(sp)
@@ -1677,8 +1710,7 @@ class GenerationPool:
                            deadline=sp.deadline, seq=sp.seq,
                            sampling=sp.sampling, adapter=sp.adapter)
         self._active[slot] = state
-        self._last_tok[slot] = sp.tokens[-1]
-        self._membership_dirty = True
+        self._patch_carry(slot, np.int32(sp.tokens[-1]), state)
         self._prefix_epoch += 1
         self._resumes_total += 1
         if upload:
@@ -2042,6 +2074,7 @@ class GenerationPool:
         the request parked and the pool untouched — the caller can
         retry or fall back to prompt+committed hand-off.  Unknown or
         not-parked ids raise :class:`NotFoundError`."""
+        self._settle()
         parked = self._prefill_done.get(request_id)
         if parked is None:
             raise NotFoundError(
@@ -2070,7 +2103,6 @@ class GenerationPool:
         self._free.append(slot)
         self._release_blocks(slot)
         self._used_rids.discard(request_id)
-        self._membership_dirty = True
         cfg = st.sampling if st.sampling is not None \
             else _SamplingConfig(0.0, 0, 1.0, 0)
         return {"rid": request_id, "path": path,
@@ -2198,47 +2230,75 @@ class GenerationPool:
         and the chunked path's final chunk).  The speculative pool uses
         it to prefill its draft twin."""
 
-    def _activate(self, slot: int, rid, ids, first: int,
+    def _activate(self, slot: int, rid, ids, first,
                   max_new_tokens: int, priority: int = 0, tenant=None,
                   deadline=None, seq: int = 0, sampling=None,
                   adapter: int = 0) -> None:
         """Promote a slot to decoding: its prompt is fully resident and
-        ``first`` (the token sampled at the last prompt position) is
-        committed.  One code path for both prefill modes, so the hook
-        order (``on_admit`` at slot-take, then ``_on_activated``, then
-        ``on_token``) cannot diverge between them."""
-        self._active[slot] = _SlotState(
-            rid, ids, [first], max_new_tokens - 1, priority=priority,
+        ``first``, the token sampled at the last prompt position, is
+        still ON THE DEVICE.  It joins the step's carry there and rides
+        the next download, where ``_commit_first`` commits it: no host
+        sync here, which would wait out the step in flight and the
+        prefill with the device idle behind them.  One code path for
+        both prefill modes, so the hook order (``on_admit`` at
+        slot-take, then ``_on_activated``, then ``on_token``) cannot
+        diverge between them."""
+        state = _SlotState(
+            rid, ids, [], max_new_tokens, priority=priority,
             tenant=tenant, deadline=deadline, seq=seq,
             sampling=sampling, adapter=adapter)
-        self._last_tok[slot] = first
-        self._membership_dirty = True
-        finishes = max_new_tokens - 1 == 0 or \
-            (self.eos_id is not None and first == self.eos_id)
-        if self._prefill_only and not finishes:
-            # prefill tier (docs §5n): the request's prompt is fully
-            # resident and its first token committed — exactly the
-            # state export_kv() hands off — so PARK it instead of
-            # decoding.  A request that finishes on its first token
-            # never hands off: it completes here like any other (the
-            # decode tier has nothing to do for it).
-            st = self._active.pop(slot)
-            self._prefill_done[rid] = (slot, st)
-            self._membership_dirty = True
-            if self.on_token is not None:
-                self.on_token(rid, first)
-            if self.on_prefill_done is not None:
-                self.on_prefill_done(rid)
-            return
-        if not finishes:
-            # a slot that finishes on its very first token never
-            # decodes, so the subclass hook (the speculative pool's
-            # draft prefill + splice) would be pure wasted device work
+        state.ahead = 1
+        self._active[slot] = state
+        self._firsts.append((slot, state, first))
+        self._patch_carry(slot, first, state)
+        if max_new_tokens > 1 and not self._prefill_only:
+            # a slot that ends on its budget's one token never decodes
+            # (and a prefill tier never does), so the subclass hook
+            # (the speculative pool's draft prefill + splice) would be
+            # pure wasted device work
             self._on_activated(slot, rid, ids)
-        if self.on_token is not None:
-            self.on_token(rid, first)
-        if finishes:
-            self._finish(slot)
+
+    def _patch_carry(self, slot: int, tok, state: _SlotState) -> None:
+        """Give ``slot``'s row of the device-resident carry the token
+        its next step consumes and its draw counter (see ``_patch``)."""
+        if self._tok_dev is None:
+            self._tok_dev = self._place(np.zeros(self.slots, np.int32))
+            self._step_dev = self._place(np.zeros(self.slots, np.uint32))
+        cfg = state.sampling
+        step = (0 if cfg is None else cfg.draws) \
+            + len(state.tokens) + state.ahead
+        self._tok_dev, self._step_dev = self._patch_jit(
+            self._tok_dev, self._step_dev, np.int32(slot), tok,
+            np.uint32(step))
+
+    def _place(self, arr):
+        """Upload one ``[slots]`` step vector; under a mesh committed to
+        its dp sharding up front: an uncommitted input would let the
+        compiled executable pick (and pay a reshard per call)."""
+        if self._mesh is not None:
+            return self._mesh.place(arr, "dp")
+        return jnp.asarray(arr)
+
+    def _commit_first(self, slot: int, state: _SlotState,
+                      first: int) -> None:
+        """Commit a prefill's first token, downloaded with the step
+        before its row's first.  On a prefill tier (docs §5n) a request
+        that survives it PARKS instead of decoding: its prompt is fully
+        resident and its first token committed, exactly the state
+        ``export_kv()`` hands off.  One that finishes on its first
+        token never hands off: it completes here like any other."""
+        state.ahead -= 1
+        if self._prefill_only and state.remaining > 1 \
+                and first != self.eos_id:
+            state.tokens.append(first)
+            state.remaining -= 1
+            self._prefill_done[state.rid] = (slot, self._active.pop(slot))
+            if self.on_token is not None:
+                self.on_token(state.rid, first)
+            if self.on_prefill_done is not None:
+                self.on_prefill_done(state.rid)
+            return
+        self._commit(slot, (first,))
 
     def _match_prefix(self, ids, shard: int = 0):
         """Longest resident block-aligned prefix of ``ids`` in the
@@ -2472,6 +2532,7 @@ class GenerationPool:
     def _refill(self):
         tr = _trace_active()
         self.admission_blocked = False
+        spliced = None
         while (self._queue or self._spilled) and self._free:
             pick = self._pick_candidate(self._tenant_counts())
             if pick is None:
@@ -2543,10 +2604,22 @@ class GenerationPool:
             # length the prompt is padded to, so 1 - prompt_tokens /
             # bucket is the prefill's padding
             _fire("pool.prefill")
+            if spliced is not None:
+                # a burst throttles itself on the device: a FURTHER
+                # prefill of this phase is dispatched when the one before
+                # it is done, so that two row caches are alive at most
+                # (each a whole row's K/V: 0.4 GB at gpt-1.3b's widths),
+                # where nothing else bounds what a burst of admissions
+                # has in flight.  The first never waits, so a steady
+                # tick, which admits one, makes no sync; and where one
+                # waits the device has the splice before it to run
+                # while the next prefill is dispatched
+                jax.block_until_ready(spliced)
             with tick_phase(tr, "tick.prefill", lambda: {
                     "rid": req.rid, "prompt_tokens": len(req.ids),
                     "bucket": self._session._bucket_for(len(req.ids))}):
                 row_cache, length, tok = self._prefill_row(req)
+            spliced = row_cache
             slot = self._pop_free_slot(shard)
             args = (self._cache, row_cache, jnp.asarray(slot, jnp.int32),
                     jnp.asarray(length, jnp.int32))
@@ -2579,7 +2652,7 @@ class GenerationPool:
         # the request's resolved config rides the batch-1 prefill as
         # a [1] SamplingState (prefill draw = stream step 0); the
         # advanced state it returns is discarded — the slot's draw
-        # counter is derived from len(tokens) at membership sync
+        # counter is derived from its tokens when it joins the carry
         samp = make_sampling_state(
             1, temperature=req.sampling.temperature,
             top_k=req.sampling.top_k, top_p=req.sampling.top_p,
@@ -2589,11 +2662,9 @@ class GenerationPool:
         return row_cache, len(req.ids), tok
 
     def _start_slot(self, slot: int, req: _Request, tok) -> None:
-        """Make ``slot`` live for the request just spliced into it; here
-        the host fetches the prefill's token (one ``[1]`` transfer an
-        admitted request) and commits it."""
-        first = np.asarray(tok)[0]
-        self._activate(slot, req.rid, req.ids, int(first),
+        """Make ``slot`` live for the request just spliced into it; its
+        first token stays on the device (``_activate``)."""
+        self._activate(slot, req.rid, req.ids, tok,
                        req.max_new_tokens, priority=req.priority,
                        tenant=req.tenant, deadline=req.deadline,
                        seq=req.seq, sampling=req.sampling,
@@ -2639,67 +2710,51 @@ class GenerationPool:
         if st.pos < len(st.ids):
             return
         # prompt fully resident: the chunk's sample IS the first token
-        # (the one host sync of the chunk path — intermediate chunks'
-        # samples are never fetched)
+        # (intermediate chunks' samples are never fetched)
         self._prefilling.pop(slot)
-        first = int(np.asarray(tok_dev))
-        self._activate(slot, st.rid, st.ids, first, st.max_new_tokens,
+        self._activate(slot, st.rid, st.ids, tok_dev, st.max_new_tokens,
                        priority=st.priority, tenant=st.tenant,
                        deadline=st.deadline, seq=st.seq,
                        sampling=st.sampling, adapter=st.adapter)
 
     def _sync_step_inputs(self):
         """The host work before a launch (the speculative pool adds its
-        draft's weights to it): rebuild the device-resident token/active
-        vectors when slot membership changed, and lazily cache the
-        weight value lists.  Returns ``_launch``'s arguments, here
-        ``(params, bufs)``.
+        draft's weights to it): upload what the host knows of the rows
+        the step takes (``_rows``) when they are not the last launch's,
+        and lazily cache the weight value lists.  Returns ``_launch``'s
+        arguments, here ``(params, bufs)``.
 
-        The per-slot AS-DATA vectors (docs §5q) rebuild on the same
-        dirty flag: the sampling config stack ``_samp_dev`` =
-        (temperature, top_k, top_p, seed), the draw counter
-        ``_step_dev`` and the adapter ids ``_adapter_dev``.  A free
-        slot's row is greedy/base (temp 0, adapter 0) — its output is
-        discarded anyway, and greedy is the cheapest row.  The draw
-        counter needs no separate host mirror: a slot's next draw index
-        IS ``cfg.draws + len(st.tokens)`` (the submission's stream
-        offset plus the tokens committed since — the prefill draw was
-        step ``draws``), so the rebuild here and the on-device feedback
-        in ``_launch`` agree by construction."""
-        if self._membership_dirty:
+        Uploaded WHOLE, on a change of ``_live_sig``: the active mask
+        and the per-slot AS-DATA vectors (docs §5q), the sampling config
+        stack ``_samp_dev`` = (temperature, top_k, top_p, seed) and the
+        adapter ids ``_adapter_dev``.  A row the step does not take is
+        greedy/base (temp 0, adapter 0) — its output is discarded
+        anyway, and greedy is the cheapest row.  The token vector and
+        the draw counter are NOT uploaded: they are the step's carry,
+        patched on the device where a row joins (``_patch_carry``)."""
+        sig = tuple((slot, st.seq) for slot, st in self._rows)
+        if sig != self._live_sig:
             active = np.zeros(self.slots, bool)
-            active[list(self._active)] = True
             temp = np.zeros(self.slots, np.float32)
             tk = np.zeros(self.slots, np.int32)
             tp = np.ones(self.slots, np.float32)
             seed = np.zeros(self.slots, np.uint32)
-            step = np.zeros(self.slots, np.uint32)
             adpt = np.zeros(self.slots, np.int32)
-            for slot, st in self._active.items():
+            for slot, st in self._rows:
+                active[slot] = True
                 cfg = st.sampling
-                draws = 0
                 if cfg is not None:
                     temp[slot] = cfg.temperature
                     tk[slot] = cfg.top_k
                     tp[slot] = cfg.top_p
                     seed[slot] = cfg.seed & 0xFFFFFFFF
-                    draws = cfg.draws
-                step[slot] = draws + len(st.tokens)
                 adpt[slot] = st.adapter
-            if self._mesh is not None:
-                # commit the step vectors to their dp sharding up
-                # front: uncommitted inputs would let the compiled
-                # executable pick (and pay a reshard per call)
-                place = lambda a: self._mesh.place(a, "dp")
-            else:
-                place = jnp.asarray
-            self._tok_dev = place(self._last_tok)
+            place = self._place
             self._active_dev = place(active)
             self._samp_dev = (place(temp), place(tk), place(tp),
                               place(seed))
-            self._step_dev = place(step)
             self._adapter_dev = place(adpt)
-            self._membership_dirty = False
+            self._live_sig = sig
         return self._weights()
 
     def _weights(self):
@@ -2709,58 +2764,140 @@ class GenerationPool:
             self._state_cache = self._session._state_vals()
         return self._state_cache
 
-    def step(self) -> bool:
-        """One tick, the same for every kind of pool: refill free slots,
-        do the bounded prompt work of a chunking pool, launch ONE step
-        on the device, download what it produced, commit tokens; False
-        when the pool is drained (``_pending()``).
+    # how many steps may be in flight when ``step`` returns: 1 where the
+    # step's carry lives on the device, so that the next launch needs
+    # nothing of the host (this pool, the block pool); 0 where a launch
+    # reads what the last download brought.  A property of the KIND of
+    # pool, read by the skeleton below: no option sets it
+    _depth = 1
 
-        A kind of pool differs in four hooks, never in this skeleton:
-        ``_sync_step_inputs`` (host work before the launch; what it
-        returns are the launch's arguments), ``_launch`` (the step's
+    def step(self) -> bool:
+        """One tick, the same for every kind of pool, with the host one
+        step BEHIND the device (docs §5t): launch step t+1 from the
+        carry on the device, download step t (the tick's ONE
+        ``device_get``, which returns while t+1 runs) and commit its
+        tokens, refill the slots that freed (prefill and splice are
+        dispatched behind t+1; the new row joins step t+2), and return
+        with exactly one step in flight, so that whatever the caller
+        does between two ticks runs under it too.  One call delivers one
+        step's tokens: a call that finds nothing in flight refills
+        first and launches twice.  False when the pool is drained
+        (``_pending()``).
+
+        A kind of pool differs in its hooks and in ``_depth``, never in
+        this skeleton: ``_launchable`` (may this row take another
+        step), ``_sync_step_inputs`` (host work before the launch; what
+        it returns are the launch's arguments), ``_launch`` (the step's
         dispatches, cache donated and rebound, returning the device
         arrays the host needs), ``_deliver`` (the downloaded arrays
         into ``_commit``, 0..n tokens a slot) and ``_decode_meta``.
+        The hooks find the rows their step is about in ``_rows``.
 
         With a tracer installed (serving/trace.py) each phase is a span
         — admit (refill incl. per-request prefill), decode (the
         dispatches, which return before the device has finished; its
-        meta says how many of the ``slots`` rows were ``live``), sample
-        (the tick's ONE host download, where the host waits for the
+        meta says how many of the ``slots`` rows were ``live`` and
+        whether the launch was made ``ahead`` of a step in flight),
+        sample (the host download, where the host waits for the
         device), deliver (the host loop committing tokens and firing
-        hooks) — and without one ``tick_phase`` is a shared no-op."""
+        hooks; its meta counts the step's ``rows`` and those ``ended``
+        before it was launched) — and without one ``tick_phase`` is a
+        shared no-op."""
         _fire("pool.step")
         tr = _trace_active()
+        idle = not self._flights
+        if idle:
+            self._admit_phase(tr)
+            self._launch_step(tr)
+        if self._flights and self._depth:
+            self._launch_step(tr)
+        self._settle_one(tr)
+        if self._flights:
+            if not idle:
+                self._admit_phase(tr)
+            if not any(self._active.get(slot) is st
+                       for slot, st in self._flights[0][1]):
+                # every row of it has ended: dropped, never awaited
+                self._flights.clear()
+        return self._pending()
+
+    def _admit_phase(self, tr) -> None:
+        """Refill free slots, then the bounded prompt work of a chunking
+        pool; once a tick, under the step in flight where there is one."""
         with tick_phase(tr, "tick.admit"):
             self._refill()
         if self._chunk_tokens is not None:
-            # bounded prompt work BEFORE the decode dispatch: a freshly
-            # completed short prompt still gets its first decode step
-            # this same tick (no TTFT penalty vs the one-shot prefill)
             self._chunk_work(tr)
-        if not self._active:
-            return self._pending()
+
+    def _launchable(self, slot: int, state) -> bool:
+        """Whether ``state`` takes (another) step: not on a prefill
+        tier, and not once its budget's last token is in flight — a
+        budget end is known at launch, an EOS is not."""
+        return state.ahead < state.remaining and not self._prefill_only
+
+    def _launch_step(self, tr) -> None:
+        """Launch ONE step for every launchable row, if there is one,
+        and put it in flight."""
+        rows = [(slot, st) for slot, st in self._active.items()
+                if self._launchable(slot, st)]
+        if not rows:
+            return
+        self._rows = rows
+        ahead = len(self._flights)
         inputs = self._sync_step_inputs()
-        with tick_phase(tr, "tick.decode",
-                        lambda: self._decode_meta(*inputs)):
+        with tick_phase(tr, "tick.decode", lambda: dict(
+                self._decode_meta(*inputs), ahead=ahead)):
             handles = self._launch(*inputs)
+        self._flights.append((handles, rows))
+
+    def _settle_one(self, tr) -> None:
+        """Download the oldest step in flight, and every prefill's first
+        token with it, in ONE batched transfer (the designed sync point
+        whether or not it is spanned), and commit: the first tokens,
+        then the step's for the rows that have not ended since it was
+        launched (an EOS at step t is seen only after t+1 was launched:
+        that row computed once more inside its own reservation, and its
+        download is discarded)."""
+        if not (self._flights or self._firsts):
+            return
+        handles, rows = self._flights.popleft() if self._flights \
+            else (None, ())
+        firsts, self._firsts = self._firsts, []
         with tick_phase(tr, "tick.sample"):
-            # every array of the step in one batched transfer: the
-            # designed sync point whether or not it is spanned
-            host = jax.device_get(handles)
-        with tick_phase(tr, "tick.deliver"):
-            self._deliver(host)
-        return self._pending()
+            host, first_toks = jax.device_get(
+                (handles, [tok for _, _, tok in firsts]))
+        with tick_phase(tr, "tick.deliver") as span:
+            for (slot, st, _), tok in zip(firsts, first_toks):
+                self._commit_first(slot, st, int(tok.reshape(-1)[0]))
+            self._rows = [(slot, st) for slot, st in rows
+                          if self._active.get(slot) is st]
+            if handles is not None:
+                self._deliver(host)
+            if span is not None:
+                # known only here: set on the span as the engine's
+                # ``tick`` span sets what the tick did
+                span.set(rows=len(rows), ended=len(rows) - len(self._rows))
+
+    def _settle(self) -> None:
+        """Bring the host level with the device: download and deliver
+        whatever is in flight.  Called by everything that reads or moves
+        a slot's device state outside the tick (cancel, preempt and the
+        spill path, ``export_kv``, ``release``, a weight or adapter
+        swap): rare, and none runs in a steady state."""
+        while self._flights or self._firsts:
+            self._settle_one(_trace_active())
 
     def _pending(self) -> bool:
-        """Whether any request is still anywhere in the pool."""
+        """Whether any request is still anywhere in the pool (a step in
+        flight counts through its live rows, which are in ``_active``;
+        one whose rows have all ended was dropped)."""
         return bool(self._active or self._queue or self._prefilling
                     or self._spilled or self._prefill_done)
 
     def _last_position(self, slot: int, state) -> int:
-        """The last position the coming step lets ``slot`` see: the one
-        it writes."""
-        return len(state.ids) + len(state.tokens) - 1
+        """The last position the step being launched lets ``slot`` see:
+        the one it writes."""
+        return len(state.ids) + len(state.tokens) + state.ahead - 1
 
     def _block_meta(self) -> dict:
         """``tick.decode``'s meta on a paged pool: ``live_blocks``, the
@@ -2771,13 +2908,13 @@ class GenerationPool:
             return {}
         bs = self._block_size
         return {"live_blocks": sum(self._last_position(slot, st) // bs + 1
-                                   for slot, st in self._active.items()),
+                                   for slot, st in self._rows),
                 "table_blocks": self.slots * self._max_blocks}
 
     def _decode_meta(self, *inputs) -> dict:
         """``tick.decode``'s meta, from what ``_launch`` is about to be
         given; built only under a tracer."""
-        return dict(live=len(self._active), slots=self.slots,
+        return dict(live=len(self._rows), slots=self.slots,
                     **self._block_meta())
 
     def _launch(self, params, bufs):
@@ -2788,12 +2925,15 @@ class GenerationPool:
         self._cache, self._tok_dev, self._step_dev = self._decode_jit(
             params, bufs, self._cache, self._tok_dev, self._active_dev,
             self._samp_dev, self._step_dev, self._adapter_dev)
+        for _, st in self._rows:
+            st.ahead += 1
         return self._tok_dev
 
     def _deliver(self, tok) -> None:
-        """Commit the step's sampled token to every active slot."""
-        self._last_tok = tok.astype(np.int32)
-        for slot in list(self._active):
+        """Commit the step's sampled token to every row of it that is
+        still live."""
+        for slot, st in self._rows:
+            st.ahead -= 1
             self._commit(slot, (int(tok[slot]),))
 
     def _commit(self, slot: int, tokens) -> None:
@@ -2816,8 +2956,11 @@ class GenerationPool:
     def refresh_weights(self):
         """Drop the cached parameter/buffer value lists — call after
         mutating the model's weights (e.g. ``set_state_dict``) so later
-        decode steps see the new values."""
+        decode steps see the new values.  The step in flight was
+        launched with the old ones: it is delivered first, so every
+        token after this call comes of the new."""
         _fire("weights.refresh")
+        self._settle()
         self._state_cache = None
 
     # -- multi-LoRA hot-swap (nn.lora; docs §5q) -------------------------
@@ -2872,17 +3015,13 @@ class GenerationPool:
         self._active.clear()
         self._prefilling.clear()
         self._free = list(range(self.slots))
-        self._last_tok = np.zeros(self.slots, np.int32)
+        # whatever is in flight is of the cache being discarded:
+        # dropped, never awaited; the carry starts over with it
+        self._flights.clear()
+        self._firsts.clear()
         self._tok_dev = None
-        self._active_dev = None
-        # per-slot as-data vectors (docs §5q): sampling config + adapter
-        # ids re-uploaded only on membership changes; the per-row draw
-        # counter (_step_dev) feeds back on-device from the decode step
-        # (inactive rows frozen), exactly like the token vector
-        self._samp_dev = None
         self._step_dev = None
-        self._adapter_dev = None
-        self._membership_dirty = True
+        self._live_sig = None
         self._results.clear()
         self._finish_reasons.clear()
         self._used_rids.clear()

@@ -390,13 +390,18 @@ class SpeculativePool(GenerationPool):
     # chunked prefill the target-side prompt work runs before the round
     # as in the base pool (draft prefill still happens at activation,
     # via _on_activated).
+    # a round's draft rewind reads the verify it follows, and a row that
+    # commits less than its round ends: nothing of that is shown correct
+    # one round ahead, so the host stays level with the device here
+    _depth = 0
+
     def _sync_step_inputs(self):
         if self._draft_state_cache is None:
             self._draft_state_cache = self._draft_session._state_vals()
         return super()._sync_step_inputs() + self._draft_state_cache
 
     def _decode_meta(self, *inputs) -> dict:
-        return dict(spec_k=self.spec_k, live=len(self._active),
+        return dict(spec_k=self.spec_k, live=len(self._rows),
                     slots=self.slots)
 
     def _launch(self, params, bufs, dparams, dbufs):
@@ -425,8 +430,8 @@ class SpeculativePool(GenerationPool):
         # the pending vector (each row's last emitted token) is next
         # round's draft input, fed straight back on-device: it stands
         # while every slot commits its full round, and a slot that
-        # commits less finishes, which marks the membership dirty and
-        # has the next round rebuild the vector from ``_last_tok``
+        # commits less finishes; the row that takes its place joins the
+        # vector on the device (``_patch_carry``)
         self._cache, emitted_dev, m_dev, self._tok_dev = self._verify_jit(
             params, bufs, self._cache, chunk, self._active_dev,
             self._adapter_dev)
@@ -446,13 +451,12 @@ class SpeculativePool(GenerationPool):
         ``_commit``, which cuts it at EOS and at the budget."""
         emitted, m_host = host
         self._rounds += 1
-        self._drafted += self._spec_k_active * len(self._active)
-        self._accepted += int(m_host[list(self._active)].sum())
-        for slot in list(self._active):
-            state = self._active[slot]
+        self._drafted += self._spec_k_active * len(self._rows)
+        self._accepted += int(m_host[[slot for slot, _ in self._rows]]
+                              .sum())
+        for slot, _ in self._rows:
             self._commit(
                 slot, map(int, emitted[slot, :int(m_host[slot]) + 1]))
-            self._last_tok[slot] = state.tokens[-1]
 
     def refresh_weights(self):
         """Drop BOTH models' cached weight value lists (hot swap)."""

@@ -1283,6 +1283,9 @@ class ServingEngine:
         let the peer continue the request's own stream under its own
         adapter, docs §5q)."""
         with self._lock:
+            # the entry carries the committed tokens: level with the
+            # device first (the request may end in the step in flight)
+            self._settle_pool()
             rec = self._live.get(request_id)
             if rec is None:
                 raise NotFoundError(
@@ -1345,6 +1348,9 @@ class ServingEngine:
         propagate: ``NotFoundError`` for unknown/non-decoding requests,
         the pool's preconditions otherwise."""
         with self._lock:
+            # a victim is chosen among what is live once the step in
+            # flight has been delivered (its hooks change ``_live``)
+            self._settle_pool()
             if request_id is None:
                 victims = [r for r in self._live.values()
                            if r.state == RequestState.DECODING
@@ -1476,6 +1482,7 @@ class ServingEngine:
                 and not pool.admission_blocked:
             return
         pmax = max(r.priority for r in queued)
+        self._settle_pool()  # its hooks change ``_live``: not under the scan
         victims = [r for r in self._live.values()
                    if r.state == RequestState.DECODING
                    and r.priority < pmax
@@ -1556,6 +1563,11 @@ class ServingEngine:
         t_enter = time.perf_counter()
         with self._lock:
             self._h_lock_wait.observe(time.perf_counter() - t_enter)
+            held = self._live.get(request_id)
+            if held is not None and held.state != RequestState.QUEUED:
+                # it may end in the step in flight: then there is nothing
+                # left to cancel, and what it streamed stands
+                self._settle_pool()
             rec = self._live.pop(request_id, None)
             if rec is None:
                 if request_id is not None:
@@ -1591,13 +1603,38 @@ class ServingEngine:
 
     def _expire(self) -> None:
         now = self._clock()
-        for rid, rec in list(self._live.items()):
-            if rec.deadline_abs is not None and now >= rec.deadline_abs:
-                self._live.pop(rid)
+        late = [rid for rid, rec in self._live.items()
+                if rec.deadline_abs is not None and now >= rec.deadline_abs]
+        if late:
+            self._settle_pool()     # one of them may end in the step in flight
+        for rid in late:
+            rec = self._live.pop(rid, None)
+            if rec is not None:
                 self._pool.cancel(rid)
                 self._c_expired.inc()
                 self._finalize(rec, RequestState.EXPIRED, "deadline",
                                rec.tokens)
+
+    def settle(self) -> None:
+        """Deliver whatever step the pool has in flight (it runs a step
+        ahead of the host, docs §5t): after this every stream and every
+        record is level with the device.  For a caller about to read
+        them from outside the tick (the fleet, before it moves a
+        request); the engine's own out-of-tick paths call it
+        themselves."""
+        with self._lock:
+            self._settle_pool()
+
+    def _settle_pool(self) -> None:
+        """``pool._settle()`` under the blast radius of a step (caller
+        holds the lock): its download and its hooks are the tick's, so
+        a failure in them is recovered as the tick recovers one."""
+        try:
+            self._pool._settle()
+        except Exception as e:  # noqa: BLE001 - step is the blast radius
+            self._health.note_error(self._clock(), e,
+                                    faults.classify_error(e))
+            self._recover(e)
 
     def _fail_record(self, rec: _Record, exc: BaseException,
                      why: str) -> None:
@@ -2034,12 +2071,14 @@ class ServingEngine:
         never a recompile; in-flight requests on other adapter rows are
         untouched (their ids index unchanged rows)."""
         with self._lock:
+            self._settle_pool()
             self._pool.load_adapter(idx, weights)
 
     def unload_adapter(self, idx: int) -> None:
         """Zero adapter ``idx``'s bank row; refuses (typed) while any
         live request is pinned to it."""
         with self._lock:
+            self._settle_pool()
             self._pool.unload_adapter(idx)
 
     def has_adapter(self, idx: int) -> bool:
@@ -2788,6 +2827,7 @@ class ServingEngine:
         parameter values so the next decode step reads the model's
         current weights (call after ``set_state_dict``)."""
         with self._lock:
+            self._settle_pool()
             self._pool.refresh_weights()
             trace.instant("weights.refresh")
 

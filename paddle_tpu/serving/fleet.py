@@ -481,14 +481,22 @@ class ServingFleet:
                         target: Optional[_EngineHandle],
                         reason: str) -> bool:
         """Graceful migration of one live request (caller holds the
-        invariant that ``target`` is not the owner).  Drains the donor
-        stream FIRST — everything the donor committed reaches the
-        caller before the hand-off, so the fleet record and the donor's
-        journal agree on the resume point — then donor ``migrate_out``
-        → peer ``adopt_migration``.  True when the K/V file was
-        adopted (vs prompt+committed resubmit)."""
+        invariant that ``target`` is not the owner).  Has the donor
+        deliver the step it has in flight and drains its stream FIRST —
+        everything the donor committed reaches the caller before the
+        hand-off, so the fleet record and the donor's journal agree on
+        the resume point — then donor ``migrate_out`` → peer
+        ``adopt_migration``.  True when the K/V file was adopted (vs
+        prompt+committed resubmit)."""
         donor = self._handles[rec.engine_id]
-        self._forward(rec, rec.engine_stream)
+        donor.engine.settle()
+        if self._forward(rec, rec.engine_stream):
+            # it ended in the step the donor had in flight: nothing is
+            # left to move
+            st = rec.engine_stream.status
+            self._finalize_front(rec, st.state, st.finish_reason,
+                                 error=st.error)
+            return False
         entry = donor.engine.migrate_out(rec.rid)
         if target is None:
             self._finalize_front(

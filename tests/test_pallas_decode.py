@@ -601,7 +601,9 @@ def test_masked_index_is_restored_and_tokens_are_the_parents(
         # the second request outlasts the others: its slot steps on
         # beside a finished one
         lens, budgets = (8, 13, 6), (4, 16, 8)
-        active_of = lambda args: np.asarray(args[3])[:, -1] != 0  # noqa
+        # block_step(params, bufs, cache, carry, ctl): ``live`` is the
+        # column before the last (``take``)
+        active_of = lambda args: np.asarray(args[4])[:, -2] != 0  # noqa
         build = lambda: BlockDiffusionPool(                 # noqa: E731
             bmodel, slots=2, cache_dtype="float32", **paged)
     prompts = [rng.randint(0, 128, (n,)).astype("int32") for n in lens]

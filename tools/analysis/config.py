@@ -73,16 +73,29 @@ EXTRA_EDGES = {
     # the one tick (docs "The tick"): ``step`` is GenerationPool's for
     # every kind of pool and reaches a kind's step through hooks the
     # AST resolves to the base class only, so the speculative round's
-    # overrides are declared (the block pool's step was never a root)
+    # and the block pool's overrides are declared: the launch-side
+    # cursor walk (``_control``) and the deliver loop are hot like the
+    # plain pool's.  The skeleton reaches the hooks through
+    # ``_launch_step`` and ``_settle_one``, direct self-calls
     "GenerationPool.step": ("ServingEngine._on_token",
                             "ServingEngine._on_finish",
-                            "SpeculativePool._sync_step_inputs",
-                            "SpeculativePool._launch",
-                            "SpeculativePool._deliver"),
+                            "ServingEngine._on_prefill_done"),
+    "GenerationPool._launch_step": ("SpeculativePool._sync_step_inputs",
+                                    "SpeculativePool._launch",
+                                    "SpeculativePool._decode_meta",
+                                    "BlockDiffusionPool._launchable",
+                                    "BlockDiffusionPool._sync_step_inputs",
+                                    "BlockDiffusionPool._decode_meta",
+                                    "BlockDiffusionPool._launch"),
+    "GenerationPool._settle_one": ("SpeculativePool._deliver",
+                                   "BlockDiffusionPool._deliver"),
+    "GenerationPool._commit": ("BlockDiffusionPool._leaving",
+                               "BlockDiffusionPool._finish"),
     "GenerationPool._refill": ("ServingEngine._on_admit",
-                               "ServingEngine._on_token",
-                               "ServingEngine._on_finish",
-                               "GenerationPool._resume"),
+                               "ServingEngine._on_resume",
+                               "GenerationPool._resume",
+                               "BlockDiffusionPool._prefill_row",
+                               "BlockDiffusionPool._start_slot"),
     # prefix-sharing admission + chunked prefill (docs §5i): the
     # admission match and the chunk dispatch are new hot-path seams —
     # the admission write and the chunk executable dispatch through
@@ -92,10 +105,9 @@ EXTRA_EDGES = {
     "GenerationPool._admit_chunked": ("AotFunction.__call__",
                                       "ServingEngine._on_admit"),
     "GenerationPool._chunk_work": ("AotFunction.__call__",),
-    "GenerationPool._activate": ("ServingEngine._on_token",
-                                 "ServingEngine._on_finish",
-                                 "SpeculativePool._on_activated",
-                                 "ServingEngine._on_prefill_done"),
+    "GenerationPool._activate": ("SpeculativePool._on_activated",),
+    "GenerationPool._commit_first": ("ServingEngine._on_token",
+                                     "ServingEngine._on_prefill_done"),
     # traffic-grade scheduling (docs §5j): the degradation ladder's
     # preempt decision dispatches into the pool's spill path (victim
     # K/V → host pool, the one deliberate spill-boundary device_get),
@@ -116,13 +128,13 @@ EXTRA_EDGES = {
     # reached through ``self._mesh`` — assigned from a constructor
     # ARGUMENT, so the AST's local-constructor type inference cannot
     # see DecodeMesh behind it.  Declaring the seams keeps the
-    # step-input re-placement (fires on membership changes inside the
-    # tick), the shard-mapped admission chain (_choose_shard →
+    # step-input re-placement (fires where a launch takes other rows
+    # than the last), the shard-mapped admission chain (_choose_shard →
     # per-shard prefix match), and the cache re-placement inside
     # recovery/reset hot-path-audited like every other dynamic seam
     # (the _refill → _choose_shard → per-shard match chain is direct
     # self-calls the AST already resolves — no edge needed there)
-    "GenerationPool._sync_step_inputs": ("DecodeMesh.place",),
+    "GenerationPool._place": ("DecodeMesh.place",),
     "GenerationPool._new_cache": ("DecodeMesh.place_cache",),
     "SpeculativePool._new_draft_cache": ("DecodeMesh.place_cache",),
     "DecodeMesh.place_cache": ("DecodeMesh.place",),
@@ -236,6 +248,8 @@ EXTRA_EDGES = {
     # in steady state, but is reachable and therefore audited too
     "GenerationPool._launch": ("AotFunction.__call__",),
     "SpeculativePool._launch": ("AotFunction.__call__",),
+    "BlockDiffusionPool._launch": ("AotFunction.__call__",),
+    "BlockDiffusionPool._prefill_row": ("AotFunction.__call__",),
     "AotFunction.__call__": ("AotFunction._compile_miss",),
     "AotFunction._compile_miss": ("analyze_compiled", "kv_arg_bytes"),
     # SLO plane (serving/slo.py): fed from the engine's tick path
