@@ -57,7 +57,7 @@ import urllib.request
 
 import numpy as np
 
-PHASES = ("serve", "train", "kernels", "mesh")
+PHASES = ("serve", "train", "kernels", "retention", "mesh")
 
 # fp32 operands pass through the MXU in bf16 (nothing under paddle_tpu/
 # sets a matmul precision): 8 significand bits, so two paths that order
@@ -107,7 +107,9 @@ def sizes(toy: bool) -> dict:
             flash=dict(vocab_size=256, hidden_size=64, num_layers=1,
                        num_heads=2, intermediate_size=128,
                        max_position=128, causal=True),
-            flash_seq=128)
+            flash_seq=128,
+            retention=dict(slots=2, q_heads=4, kv_heads=2, head_dim=16,
+                           chunk_tokens=24, timed_steps=2))
     lm = gpt_1p3b_config()          # 24 layers, width 2048, 16 heads x 128
     # fp32: ~5.3 GB of weights; the paged cache costs ~393 KB per token
     # over 24 layers, so 8 slots x 256 positions is ~0.8 GB — weights,
@@ -127,7 +129,11 @@ def sizes(toy: bool) -> dict:
         flash=dict(vocab_size=32000, hidden_size=1024, num_layers=4,
                    num_heads=8, intermediate_size=4096,
                    max_position=8192, causal=True),
-        flash_seq=8192)
+        flash_seq=8192,
+        # brumby-14b's state: 40 query heads on 8 K/V heads of 128, 16
+        # slots; 256 positions are two chunks of the prefill scan
+        retention=dict(slots=16, q_heads=40, kv_heads=8, head_dim=128,
+                       chunk_tokens=256, timed_steps=20))
 
 
 # ---------------------------------------------------------------------------
@@ -818,6 +824,90 @@ def phase_kernels(pt, jax, sz: dict, tol: float, state: dict) -> None:
 # mesh (four devices or more)
 # ---------------------------------------------------------------------------
 
+def phase_retention(pt, jax, sz: dict, tol: float, state: dict) -> None:
+    """Gated power retention (``ops/power_retention.py``) at the benchmark
+    configuration's head geometry: one prefill of two chunks, as the chunked
+    form and from an empty state, and one decode step from the state it
+    leaves, each against the quadratic definition;
+    the step on the route a server takes here (the Pallas kernel on a TPU)
+    and as the XLA composition, both timed."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import power_retention as pr
+
+    r = sz["retention"]
+    b, hq, hkv, d = r["slots"], r["q_heads"], r["kv_heads"], r["head_dim"]
+    t = r["chunk_tokens"]
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    bf = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)
+    q = bf(jax.random.normal(ks[0], (b, hq, t + 1, d))) * d ** -0.5
+    k = bf(jax.random.normal(ks[1], (b, hkv, t + 1, d)))
+    v = bf(jax.random.normal(ks[2], (b, hkv, t + 1, d)))
+    lg = jax.nn.log_sigmoid(
+        4.0 + 2.0 * jax.random.normal(ks[3], (b, hkv, t + 1)))
+    d_phi = pr.phi_size(d)
+    zeros = (jnp.zeros((b, hkv, d, d_phi), jnp.float32),
+             jnp.zeros((b, hkv, 1, d_phi), jnp.float32))
+    # the definition on two rows (every row runs the same program)
+    want = jax.jit(pr.power_retention_quadratic)(q[:2], k[:2], v[:2], lg[:2])
+    head = lambda x: x[:, :, :t]
+    scale = float(jnp.max(jnp.abs(want)))
+    forms = {
+        "chunked": lambda: jax.jit(pr.power_retention_chunked)(
+            head(q), head(k), head(v), head(lg), *zeros),
+        # from an empty state, as a server's prefill: on a TPU the
+        # quadratic kernel, elsewhere the chunked form again
+        "from empty": lambda: jax.jit(pr.power_retention_prefill)(
+            head(q), head(k), head(v), head(lg))}
+    states = {}
+    for name, form in forms.items():
+        y, s, z = form()
+        err = float(jnp.max(jnp.abs(y[:2] - want[:, :, :t])))
+        say("[retention] %s prefill of %d positions vs the quadratic "
+            "form: max |err| %.3g of scale %.3g" % (name, t, err, scale))
+        check(err <= tol * scale, "the %s prefill differs from the "
+              "quadratic form by %.3g (gate %.3g)"
+              % (name, err, tol * scale))
+        states[name] = s
+    top = float(jnp.max(jnp.abs(states["chunked"])))
+    err = float(jnp.max(jnp.abs(states["chunked"] - states["from empty"])))
+    check(err <= tol * top, "the two prefills leave states %.3g apart "
+          "(gate %.3g)" % (err, tol * top))
+    keep = jnp.arange(b) != b - 1       # the last row is a free slot
+    last = lambda x: x[:, :, t]
+    routes = {}
+    for route in ("auto", "composition"):
+        step = jax.jit(lambda s_, z_, route=route: pr.power_retention_step(
+            last(q), last(k), last(v), last(lg), s_, z_, keep, route=route),
+            donate_argnums=(0, 1))
+        text = step.lower(s, z).compile().as_text()
+        s_in, z_in = jnp.copy(s), jnp.copy(z)
+        y1, s1, z1 = step(s_in, z_in)
+        err = float(jnp.max(jnp.abs(y1[:1] - want[:1, :, t])))
+        check(err <= tol * scale, "the %s step differs from the quadratic "
+              "form by %.3g (gate %.3g)" % (route, err, tol * scale))
+        check(bool(jnp.all(s1[-1] == s[-1])) and bool(jnp.all(z1[-1]
+                                                                == z[-1])),
+              "the %s step moved a free slot's state" % route)
+        n = r["timed_steps"]
+        jax.block_until_ready(s1)
+        t0 = time.perf_counter()
+        for _ in range(n):
+            y1, s1, z1 = step(s1, z1)
+        jax.block_until_ready((y1, s1))
+        dt = (time.perf_counter() - t0) / n
+        moved = 2 * 4 * b * hkv * d_phi * (d + 1)
+        routes[route] = dt
+        copies = pool_shaped_moves(text, s.shape)
+        say("[retention] step, route %s: max |err| %.3g; %.3f ms a call, "
+            "%.1f GB/s of the state's 2 x %.1f MB; pool-shaped moves %s"
+            % (route, err, 1e3 * dt, moved / dt / 1e9, moved / 2e6,
+               copies or "none"))
+        check(not copies or jax.devices()[0].platform == "cpu",
+              "the %s step copies the state: %s" % (route, copies))
+    state["retention_ms"] = {k_: 1e3 * v_ for k_, v_ in routes.items()}
+
+
 def _check_spans(arrays, devices, what: str) -> None:
     want = {d.id for d in devices}
     for a in arrays:
@@ -993,7 +1083,8 @@ def main(argv=None) -> int:
             % len(jax.devices()))
         phases.remove("mesh")
     runners = {"serve": phase_serve, "train": phase_train,
-               "kernels": phase_kernels, "mesh": phase_mesh}
+               "kernels": phase_kernels, "retention": phase_retention,
+               "mesh": phase_mesh}
     failed = {}
     state: dict = {}
     for name in PHASES:

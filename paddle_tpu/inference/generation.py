@@ -583,6 +583,9 @@ class GenerationPool:
              for g in range(self.slots)], np.int32) \
             if cache_layout == "paged" else None
         self._cache = self._new_cache()
+        self._state_bytes_slot = 0 if self._layout.positional else \
+            self._layout.state_bytes_per_slot(self._cache, self.slots,
+                                              self.max_len)
         if donate is None:
             donate = jax.default_backend() != "cpu"
         self._decode_jit = jax.jit(self._pool_decode,
@@ -842,8 +845,9 @@ class GenerationPool:
         writes at."""
         sess = self._session
         given = cache
-        if self.cache_layout == "paged":
-            cache = self._masked_tables(cache, active)
+        paged = self.cache_layout == "paged"
+        cache = self._masked_tables(cache, active) if paged \
+            else self._layout.begin_step(cache, active)
         logits, new_cache = sess._run_model(param_vals, buf_vals,
                                             toks[:, None], cache,
                                             adapter,
@@ -857,11 +861,13 @@ class GenerationPool:
                                      step)
         step = step + active.astype(step.dtype)
         # layout-owned freeze (jit.cache): positional layouts merge the
-        # index; the recurrent layout must also restore inactive slots'
-        # state carry (a recurrence updates every row every step)
+        # index; the recurrent layout also re-opens the update window
+        # that ``begin_step`` closed on the inactive slots (their carry
+        # came through the step as identity steps, so nothing of the
+        # state's size is selected here)
         with jax.named_scope("cache_freeze"):
             new_cache = self._layout.freeze_step(new_cache, given, active)
-        if cache is not given:
+        if paged:
             new_cache = [c._replace(table=g.table)
                          for c, g in zip(new_cache, given)]
         return new_cache, jnp.where(active, tok, 0), step
@@ -1545,7 +1551,8 @@ class GenerationPool:
         and greedy decode continues byte-identically."""
         # the carry covers positions [0, pos): the last committed token
         # is the next step's input, exactly the positional convention
-        host = jax.device_get([(np.asarray(c.state[slot]),)
+        fields = self._layout.state_fields(self._cache[0])
+        host = jax.device_get([tuple(getattr(c, f)[slot] for f in fields)
                                for c in self._cache])
         host_bytes = sum(arr.nbytes for layer in host for arr in layer)
         host_path = None
@@ -1595,10 +1602,12 @@ class GenerationPool:
         slot = self._pop_free_slot()
         pos = len(sp.ids) + len(sp.tokens) - 1
         pos_dev = jnp.asarray(pos, jnp.int32)
+        fields = self._layout.state_fields(self._cache[0])
         self._cache = [
-            c._replace(state=c.state.at[slot].set(
-                           jnp.asarray(host_src[layer][0])),
-                       index=c.index.at[slot].set(pos_dev))
+            c._replace(index=c.index.at[slot].set(pos_dev),
+                       **{f: getattr(c, f).at[slot].set(
+                              jnp.asarray(host_src[layer][j]))
+                          for j, f in enumerate(fields)})
             for layer, c in enumerate(self._cache)]
         state = _SlotState(sp.rid, sp.ids, sp.tokens, sp.remaining,
                            priority=sp.priority, tenant=sp.tenant,
@@ -1808,7 +1817,7 @@ class GenerationPool:
                              int(cfg.draws)],
                 "adapter": int(st.adapter)}
         if recurrent:
-            meta["d_state"] = int(self._cache[0].state.shape[-1])
+            meta.update(self._layout.fingerprint_extra(self))
         else:
             meta["block_size"] = self._block_size
         return _transfer_mod().write_transfer(
@@ -1883,7 +1892,7 @@ class GenerationPool:
             # the carry is O(1): no block math, no capacity gate — a
             # free slot is the only resource resume needs
             written = total = 0
-            nf = 1
+            nf = len(self._layout.state_fields(first))
         else:
             bs = self._block_size
             pos = int(len(ids)) + len(tokens) - 1
@@ -1954,10 +1963,10 @@ class GenerationPool:
                 and meta.get("cache_dtype")
                 == self._layout.cache_dtype_str(self._cache))
             if recurrent:
+                geometry = self._layout.fingerprint_extra(self)
                 structural_ok = (
                     structural_ok
-                    and meta.get("d_state")
-                    == int(first.state.shape[-1])
+                    and all(meta.get(k) == v for k, v in geometry.items())
                     and tuple(r.arrays["l0_f0"].shape)
                     == tuple(first.state.shape[1:]))
             else:
@@ -2615,9 +2624,8 @@ class GenerationPool:
                 # waits the device has the splice before it to run
                 # while the next prefill is dispatched
                 jax.block_until_ready(spliced)
-            with tick_phase(tr, "tick.prefill", lambda: {
-                    "rid": req.rid, "prompt_tokens": len(req.ids),
-                    "bucket": self._session._bucket_for(len(req.ids))}):
+            with tick_phase(tr, "tick.prefill",
+                            lambda: self._prefill_meta(req)):
                 row_cache, length, tok = self._prefill_row(req)
             spliced = row_cache
             slot = self._pop_free_slot(shard)
@@ -2899,11 +2907,28 @@ class GenerationPool:
         the one it writes."""
         return len(state.ids) + len(state.tokens) + state.ahead - 1
 
+    def _prefill_meta(self, req) -> dict:
+        """``tick.prefill``'s meta for a bucketed prefill; ``chunks`` where
+        the model's prefill is a scan over chunks of the bucket (a
+        recurrent state's: ``models.PowerRetentionLM.prefill_chunks``)."""
+        bucket = self._session._bucket_for(len(req.ids))
+        meta = {"rid": req.rid, "prompt_tokens": len(req.ids),
+                "bucket": bucket}
+        chunks = getattr(self._session._model, "prefill_chunks", None)
+        if chunks is not None:
+            meta["chunks"] = chunks(bucket)
+        return meta
+
     def _block_meta(self) -> dict:
         """``tick.decode``'s meta on a paged pool: ``live_blocks``, the
         table entries the live slots' positions reach (what the
         attention kernel fetches and computes: ``ops/pallas_decode.py``
-        skips the rest), and ``table_blocks``, slots x table width."""
+        skips the rest), and ``table_blocks``, slots x table width; on
+        a recurrent pool ``state_bytes``."""
+        if not self._layout.positional:
+            # a recurrent state: what the step reads AND writes of it,
+            # from shapes (live rows x the bytes a slot's state takes)
+            return {"state_bytes": len(self._rows) * self._state_bytes_slot}
         if self.cache_layout != "paged":
             return {}
         bs = self._block_size
@@ -3171,16 +3196,15 @@ class GenerationPool:
             # model-class argument, quantified).  state_bytes_per_slot
             # is the capacity planner's figure: slots/GB falls out as
             # 2**30 // it (the bench leg's slots_per_gb stamp).
-            state_total = sum(int(c.state.size) * c.state.dtype.itemsize
-                              for c in self._cache)
+            per_slot = self._state_bytes_slot
+            state_total = per_slot * self.slots
             stats = {
                 "cache_layout": self.cache_layout,
                 "cache_dtype": self._layout.cache_dtype_str(self._cache),
                 "decode_route": self._session.route,
                 "d_state": int(first.state.shape[-1]),
                 "num_layers": len(self._cache),
-                "state_bytes_per_slot": self._layout.state_bytes_per_slot(
-                    self._cache, self.slots, self.max_len),
+                "state_bytes_per_slot": per_slot,
                 "reachable_bytes": state_total,
                 "pool_bytes": state_total,
             }

@@ -63,7 +63,7 @@ def kv_arg_bytes(cache) -> int:
         # "state" is the recurrent layout's whole payload (jit.cache):
         # positional caches have no such field, so the transformer
         # figures are unchanged
-        for field in ("k", "v", "k_scale", "v_scale", "state"):
+        for field in ("k", "v", "k_scale", "v_scale", "state", "norm"):
             a = getattr(c, field, None)
             if a is not None:
                 total += int(a.size) * a.dtype.itemsize

@@ -28,11 +28,14 @@ operation            who calls it / what it decides
                      recurrent layout also re-opens its update window)
 ``insert_row``       GenerationPool._insert — splice a batch-1 prefilled
                      row cache into a pool slot (traced; ONE compile)
+``begin_step``       GenerationPool._pool_decode — layout prep before the
+                     batched step (the recurrent layout closes the update
+                     window of inactive slots: a recurrence updates every
+                     row every step, and a closed window makes the row an
+                     identity step, so no state is ever copied back)
 ``freeze_step``      GenerationPool._pool_decode — merge a decode step's
                      cache for INACTIVE slots back to the pre-step value
-                     (positional layouts freeze the index; the recurrent
-                     layout must also restore the state carry, because a
-                     recurrence updates every row every step)
+                     (the index; the recurrent layout its window too)
 ``field_axes``       DecodeMesh.place_cache — PartitionSpec axes per
                      cache field (k/v shard ('dp','mp'); a recurrence
                      state shards ('dp', None): slots over dp, the state
@@ -111,6 +114,12 @@ class CacheLayout:
     # -- pool splice / step freeze (traced) ------------------------------
     def insert_row(self, pool_cache, row_cache, slot, length, blocks=None):
         raise NotImplementedError
+
+    def begin_step(self, cache, active):
+        """Layout prep before a pool's batched decode step (identity
+        here; the paged pool masks its tables itself; the recurrent
+        layout closes the update window of the free slots)."""
+        return cache
 
     def freeze_step(self, new_cache, prev_cache, active):
         """Merge a decode step's cache back to the pre-step value for
@@ -225,9 +234,14 @@ class PagedLayout(CacheLayout):
 
 
 class RecurrentLayout(CacheLayout):
-    """Constant-size recurrence carry (``nn.ssm.RecurrentDecodeCache``:
-    ``state [B, d_state]`` + ``index`` + ``limit`` per layer): O(1)
-    state per token, no block table, no paging, no prefix tree.
+    """Constant-size recurrence carry: O(1) state per token, no block
+    table, no paging, no prefix tree.  Two caches live on it:
+    ``nn.ssm.RecurrentDecodeCache`` (``state [B, d_state]``) and
+    ``nn.RetentionDecodeCache`` (``state [B, Hkv, dv, D]`` and ``norm [B,
+    Hkv, 1, D]``).  Every field of a layer's cache but ``index`` and
+    ``limit`` is a STATE FIELD, slots leading (:meth:`state_fields`): the
+    splice, the spill and the accounting run over them all, so a cache of
+    another shape needs no code here.
 
     ``limit`` is the layout's pad-garbage discipline.  A positional
     cache can write garbage K/V for padded bucket positions because the
@@ -235,41 +249,70 @@ class RecurrentLayout(CacheLayout):
     afterthought — every update folds into the one carry forever.  So
     the prefill hook narrows the update window to the true prompt
     length (positions past it are identity steps), and finalize re-opens
-    it to max_len for decode.
+    it to max_len for decode.  A pool's step closes it on its free slots
+    (:meth:`begin_step`): the rows the step must not move are identity
+    steps INSIDE the recurrence, so nothing of the size of the pool's
+    state is ever selected or copied to put them back.
     """
 
     name = "recurrent"
     positional = False
     spillable = True
 
+    @staticmethod
+    def state_fields(layer_cache) -> tuple:
+        return tuple(f for f in layer_cache._fields
+                     if f not in ("index", "limit"))
+
     def begin_prefill(self, cache, true_len):
-        return [c._replace(limit=true_len) for c in cache]
+        # the window narrows to the true length; and a cache type that
+        # asks for it (``empty_as_none``) is told STATICALLY that the
+        # prefill starts from nothing: its state fields go in as None,
+        # so a layer need not read (or multiply by) a state of zeros
+        out = []
+        for c in cache:
+            c = c._replace(limit=true_len)
+            if getattr(c, "empty_as_none", False):
+                c = c._replace(**{f: None for f in self.state_fields(c)})
+            out.append(c)
+        return out
 
     def finalize_prefill(self, cache, true_len, max_len):
         lim = jnp.asarray(max_len, jnp.int32)
         return [c._replace(index=true_len, limit=lim) for c in cache]
 
+    def begin_step(self, cache, active):
+        """The update window of a pool's step, per slot: a free slot's is
+        closed, so its row is an identity step of the recurrence."""
+        return [c._replace(limit=jnp.where(active, c.limit, 0))
+                for c in cache]
+
     def insert_row(self, pool_cache, row_cache, slot, length, blocks=None):
-        return [cp._replace(
-            state=cp.state.at[slot].set(
-                cr.state[0].astype(cp.state.dtype)),
-            index=cp.index.at[slot].set(jnp.asarray(length, jnp.int32)))
-            for cp, cr in zip(pool_cache, row_cache)]
+        out = []
+        for cp, cr in zip(pool_cache, row_cache):
+            upd = {f: getattr(cp, f).at[slot].set(
+                       getattr(cr, f)[0].astype(getattr(cp, f).dtype))
+                   for f in self.state_fields(cp)}
+            out.append(cp._replace(
+                index=cp.index.at[slot].set(jnp.asarray(length, jnp.int32)),
+                **upd))
+        return out
 
     def freeze_step(self, new_cache, prev_cache, active):
-        # the recurrence updated EVERY row's carry this step; an
-        # inactive slot's update folds its stale last token into state
-        # a resumed/refilled request would then inherit — restore the
-        # carry, not just the index
+        # the recurrence updates EVERY row's carry every step, and an
+        # inactive slot's update would fold its stale last token into
+        # state a resumed/refilled request then inherits.  It did not:
+        # ``begin_step`` closed those rows' windows, so their carry came
+        # through the step untouched.  What is put back is the index and
+        # the window itself
         return [c._replace(
-            state=jnp.where(active[:, None], c.state, old.state),
-            index=jnp.where(active, c.index, old.index))
+            index=jnp.where(active, c.index, old.index), limit=old.limit)
             for c, old in zip(new_cache, prev_cache)]
 
     def field_axes(self, field: str):
-        if field == "state":
-            # slots over dp; the state vector stays whole per slot (no
-            # head axis to split — replicated within an mp group)
+        if field in ("state", "norm"):
+            # slots over dp; a slot's state stays whole (replicated
+            # within an mp group)
             return ("dp", None)
         if field == "index":
             return ("dp",)
@@ -285,11 +328,18 @@ class RecurrentLayout(CacheLayout):
     def state_bytes_per_slot(self, cache, slots: int, max_len: int) -> int:
         # constant in max_len — the whole point
         return sum(
-            int(np.prod(c.state.shape)) * c.state.dtype.itemsize // int(slots)
-            for c in cache)
+            int(np.prod(a.shape)) * a.dtype.itemsize // int(slots)
+            for c in cache
+            for a in (getattr(c, f) for f in self.state_fields(c)))
 
     def fingerprint_extra(self, pool) -> dict:
-        return {"d_state": int(pool._cache[0].state.shape[-1])}
+        # the shape of every state field of a slot: a toy recurrence's
+        # spill file ([d_state]) can never be adopted by a model whose
+        # state is a matrix a head, nor the other way round
+        first = pool._cache[0]
+        return {"d_state": int(first.state.shape[-1]),
+                "state_shapes": [list(getattr(first, f).shape[1:])
+                                 for f in self.state_fields(first)]}
 
 
 CACHE_LAYOUTS = {

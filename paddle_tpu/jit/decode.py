@@ -484,7 +484,7 @@ class DecodeSession:
         self._collective_trace = rec
 
     def _run_model(self, param_vals, buf_vals, ids, cache, adapter=None,
-                   collective_seam: bool = False):
+                   collective_seam: bool = False, last=None):
         """One cached forward with the session's weights swapped in.
 
         Decode is ALWAYS inference: the training flag is forced off for
@@ -515,9 +515,12 @@ class DecodeSession:
             # mp-collective seam the same way (prefill stays dense)
             seam = self._collective_seam() if collective_seam \
                 else contextlib.nullcontext()
+            # ``last``: the one position whose logits the caller wants,
+            # for a model that can leave the others out (``logits_at``)
+            only = {} if last is None else {"last": last}
             with decode_route(self.route), adapter_ids(adapter), seam:
                 logits, new_cache = self._model(
-                    Tensor(ids, stop_gradient=True), cache=cache)
+                    Tensor(ids, stop_gradient=True), cache=cache, **only)
             raw = logits.value if isinstance(logits, Tensor) else logits
         finally:
             for l, t in zip(binding.sublayers, modes):
@@ -551,12 +554,18 @@ class DecodeSession:
         # positional layouts; the recurrent layout narrows its update
         # window to the true length so pad positions are identity steps
         cache = self._layout.begin_prefill(cache, true_len)
-        logits, cache = self._run_model(param_vals, buf_vals, ids, cache,
-                                        samp.adapter)
+        # a model that declares ``logits_at`` runs its head on the last
+        # real position alone: the logits of a whole 4096-position
+        # bucket over a 151,936-wide vocabulary are 1.2 GB that only
+        # this one row is read from
+        at_last = getattr(self._model, "logits_at", False)
+        logits, cache = self._run_model(
+            param_vals, buf_vals, ids, cache, samp.adapter,
+            last=true_len - 1 if at_last else None)
         cache = self._layout.finalize_prefill(cache, true_len,
                                               self.max_len)
-        last = jax.lax.dynamic_index_in_dim(logits, true_len - 1, axis=1,
-                                            keepdims=False)  # [B, V]
+        last = logits[:, 0] if at_last else jax.lax.dynamic_index_in_dim(
+            logits, true_len - 1, axis=1, keepdims=False)  # [B, V]
         tok, samp = self._sample(last, samp)
         return cache, tok, samp
 

@@ -140,7 +140,7 @@ class DecodeMesh:
             return ("dp", "mp")
         if field in ("table", "index"):
             return ("dp",)
-        if field == "state":
+        if field in ("state", "norm"):
             return ("dp", None)
         if field == "limit":
             return ()

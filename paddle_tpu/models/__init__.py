@@ -10,6 +10,10 @@ from .block_diffusion import (  # noqa: F401
     BlockDiffusionDecoderLayer,
     BlockDiffusionMoELM,
 )
+from .power_retention import (  # noqa: F401
+    PowerRetentionDecoderLayer,
+    PowerRetentionLM,
+)
 from .language_model import (  # noqa: F401
     TransformerForSequenceClassification,
     TransformerLM,

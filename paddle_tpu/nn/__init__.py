@@ -47,9 +47,10 @@ from .layer.rnn import (  # noqa: F401
 )
 from .layer.moe import SparseExperts  # noqa: F401
 from .layer.transformer import (  # noqa: F401
-    GroupedQueryAttention, MultiHeadAttention, Transformer, TransformerDecoder, TransformerDecoderLayer,
+    GatedMLP, GroupedQueryAttention, MultiHeadAttention, Transformer, TransformerDecoder, TransformerDecoderLayer,
     TransformerEncoder, TransformerEncoderLayer,
 )
+from .layer.retention import PowerRetention, RetentionDecodeCache  # noqa: F401
 from .ssm import GatedSSMBlock, RecurrentDecodeCache, SSMLM  # noqa: F401
 from . import lora  # noqa: F401
 from .lora import attach_lora, load_adapter, unload_adapter  # noqa: F401

@@ -75,6 +75,14 @@ def _install():
 
 _install()
 
+# gated power retention (ops/power_retention.py): raw arrays in and out, as
+# the decode-attention ops; imported after ``_install`` so that the state
+# pytree a step returns is not wrapped
+from ...ops.power_retention import (  # noqa: E402,F401
+    power_retention_chunked, power_retention_quadratic,
+    power_retention_step, symmetric_square,
+)
+
 
 def _install_inplace_acts():
     """F.elu_/softmax_/tanh_ (reference inplace activations) via the shared

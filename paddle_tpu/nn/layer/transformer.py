@@ -565,17 +565,15 @@ class GroupedQueryAttention(MultiHeadAttention):
         return T.transpose(T.reshape(x, [b, l, n, self.head_dim]),
                            [0, 2, 1, 3])
 
-    def forward(self, x, attn_mask=None, cache=None):
+    def _qkv(self, x, cache=None):
+        """The projections, the heads' RMSNorm and the rotary turn at the
+        positions the cache index gives (from 0 without a cache): ``q``
+        ``[B, H, L, D]``, ``k``, ``v`` ``[B, Hkv, L, D]`` and the
+        positions.  Shared by every token mixer built on these
+        projections (``nn.PowerRetention`` replaces only the product)."""
         import jax
         import jax.numpy as jnp
 
-        from ...framework.tensor import Tensor as _T
-
-        if attn_mask is not None:
-            raise InvalidArgumentError(
-                "GroupedQueryAttention derives its mask from positions "
-                "(causal, or block-causal with block_length); pass "
-                "attn_mask=None")
         q = self._heads(self.q_proj(x), self.num_heads)
         k = self._heads(self.k_proj(x), self.num_kv_heads)
         v = self._heads(self.v_proj(x), self.num_kv_heads)
@@ -592,6 +590,17 @@ class GroupedQueryAttention(MultiHeadAttention):
         with jax.named_scope("rope"):
             q = F.rotary_embedding(q, pos, self.rope_theta)
             k = F.rotary_embedding(k, pos, self.rope_theta)
+        return q, k, v, pos
+
+    def forward(self, x, attn_mask=None, cache=None):
+        from ...framework.tensor import Tensor as _T
+
+        if attn_mask is not None:
+            raise InvalidArgumentError(
+                "GroupedQueryAttention derives its mask from positions "
+                "(causal, or block-causal with block_length); pass "
+                "attn_mask=None")
+        q, k, v, pos = self._qkv(x, cache)
         if cache is not None:
             fwd = (self._decode_forward
                    if isinstance(cache, self.DecodeCache)
@@ -608,6 +617,24 @@ class GroupedQueryAttention(MultiHeadAttention):
                                route="composition")
         return self.out_proj(self._merge_heads(_T(out,
                                                   stop_gradient=True)))
+
+
+class GatedMLP(Layer):
+    """The dense gated feed-forward of the Llama/Qwen family: ``down(
+    silu(gate(x)) * up(x))``, no bias.  (``nn.SparseExperts`` is the
+    routed form of the same product.)"""
+
+    def __init__(self, hidden_size: int, intermediate_size: int):
+        super().__init__()
+        self.gate_proj = Linear(hidden_size, intermediate_size,
+                                bias_attr=False)
+        self.up_proj = Linear(hidden_size, intermediate_size,
+                              bias_attr=False)
+        self.down_proj = Linear(intermediate_size, hidden_size,
+                                bias_attr=False)
+
+    def forward(self, x):
+        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
 
 
 def _row_parallel_seam(linear, x):

@@ -26,6 +26,12 @@ from .pallas_decode import (  # noqa: F401
     decode_attention_kernel,
     paged_decode_attention_kernel,
 )
+from .power_retention import (  # noqa: F401
+    power_retention_chunked,
+    power_retention_quadratic,
+    power_retention_step,
+    symmetric_square,
+)
 
 __all__ = ["flash_attention", "flash_attention_supported",
            "decode_attention", "decode_attention_supported",
@@ -33,4 +39,6 @@ __all__ = ["flash_attention", "flash_attention_supported",
            "paged_cache_write", "quantize_kv", "dequantize_kv",
            "decode_attention_kernel", "paged_decode_attention_kernel",
            "decode_route", "normalize_decode_route", "DECODE_ROUTES",
-           "reset_backend_memo"]
+           "reset_backend_memo", "power_retention_chunked",
+           "power_retention_step", "power_retention_quadratic",
+           "symmetric_square"]

@@ -199,6 +199,34 @@ class AdmissionTightenedError(UnavailableError):
         self.retry_after_s = float(retry_after_s)
 
 
+class _Callers:
+    """Who stands before the engine lock.  The step loop gives the lock
+    up after a tick and takes it again at once, before a caller it woke
+    can: ``submit`` then stands there for seconds and a full engine's
+    queue fills by luck.  So callers are counted, and between two ticks
+    the loop waits, for at most ``turn_s``, until those that stood there
+    have had the lock."""
+
+    def __init__(self):
+        self._cv = threading.Condition()
+        self._n = 0
+
+    def arrive(self) -> None:
+        with self._cv:
+            self._n += 1
+
+    def admitted(self) -> None:
+        with self._cv:
+            self._n -= 1
+            if not self._n:
+                self._cv.notify_all()
+
+    def let_in(self, turn_s: float) -> None:
+        if self._n:
+            with self._cv:
+                self._cv.wait_for(lambda: not self._n, turn_s)
+
+
 class _Record:
     """Engine-side per-request state (the pool keeps only slot state).
     ``prompt`` is retained host-side because it IS the recovery story:
@@ -454,6 +482,7 @@ class ServingEngine:
         # cancel may race the background step loop; in pump mode it is
         # uncontended and costs nothing
         self._lock = threading.RLock()
+        self._callers = _Callers()
         self._draining = False
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
@@ -584,6 +613,11 @@ class ServingEngine:
             "serving_kv_resident_bytes",
             "KV cache bytes resident on device (whole pool allocation, "
             "dtype-aware: int8 caches count int8 K/V + fp32 scales)")
+        self._g_state_slot = m.gauge(
+            "serving_state_bytes_per_slot",
+            "bytes of recurrent state one slot holds, every layer (a "
+            "constant of the model, whatever the context)") \
+            if self._pool.cache_layout == "recurrent" else None
         self._g_kv_free = m.gauge(
             "serving_kv_free_blocks",
             "paged allocator free blocks") \
@@ -773,7 +807,9 @@ class ServingEngine:
         if tr is not None:
             waiting = tr.span("submit.lock_wait", rid=request_id)
             waiting.__enter__()
+        self._callers.arrive()
         with self._lock:
+            self._callers.admitted()
             lock_wait = time.perf_counter() - t_enter
             if waiting is not None:
                 waiting.__exit__(None, None, None)
@@ -1561,7 +1597,9 @@ class ServingEngine:
         id is not live (already terminal or unknown) — idempotent, so
         callers can cancel on a races-with-completion path safely."""
         t_enter = time.perf_counter()
+        self._callers.arrive()
         with self._lock:
+            self._callers.admitted()
             self._h_lock_wait.observe(time.perf_counter() - t_enter)
             held = self._live.get(request_id)
             if held is not None and held.state != RequestState.QUEUED:
@@ -2414,6 +2452,8 @@ class ServingEngine:
         stats = pool.cache_stats()
         self._g_kv_bytes.set(stats["reachable_bytes"])
         self._g_kv_resident.set(stats["pool_bytes"])
+        if self._g_state_slot is not None:
+            self._g_state_slot.set(stats["state_bytes_per_slot"])
         if self._g_kv_free is not None:
             self._g_kv_free.set(stats["free_blocks"])
         if self._g_kv_resident_shard is not None:
@@ -2523,6 +2563,9 @@ class ServingEngine:
                     self._health.note_error(self._clock(), e, "loop")
                     self._dump_flight("loop-error")
                 work = False
+            # submit and cancel get their turn between two ticks; the
+            # step in flight covers the wait (at most the idle wait's)
+            self._callers.let_in(0.002)
             if not work:
                 self._wake.wait(0.002)
                 self._wake.clear()

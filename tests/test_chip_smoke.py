@@ -40,6 +40,18 @@ def test_toy_run_passes_and_says_cpu(capsys):
     assert "refused by name" in out
 
 
+def test_toy_retention_phase_checks_both_forms(capsys):
+    rc = chip_smoke.main(["--cpu-toy", "--phases", "retention"])
+    out = capsys.readouterr().out
+    assert rc == 0, out
+    assert _last_json(out)["phases"] == ["retention"]
+    assert "chunked prefill of 24 positions" in out
+    assert "from empty prefill of 24 positions" in out
+    for route in ("auto", "composition"):
+        assert "step, route %s" % route in out
+    assert "retention" in chip_smoke.PHASES
+
+
 def test_without_the_toy_argument_a_cpu_machine_fails():
     # the sandbox exports JAX_PLATFORMS=cpu: a machine that inherits it
     # must produce a failure naming the platform, never a CPU run

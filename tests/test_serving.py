@@ -18,6 +18,9 @@ the modes cannot diverge and get one slow-marked test).  The contracts:
   the TTFT and queue-depth series, and ``render_prometheus()`` emits
   well-formed text exposition.
 """
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -393,6 +396,57 @@ def test_pump_refused_while_thread_owns_engine(model):
     try:
         with pytest.raises(PreconditionNotMetError, match="pump"):
             eng.pump(1)
+    finally:
+        eng.shutdown()
+
+
+def test_callers_turn_waits_for_those_before_the_lock():
+    # the count behind the loop's turn for submit and cancel, alone:
+    # nobody waiting costs nothing, a caller that was let in ends the
+    # wait, and one that never gets the lock holds the loop turn_s only
+    from paddle_tpu.serving.engine import _Callers
+    callers = _Callers()
+    t0 = time.perf_counter()
+    callers.let_in(5.0)
+    assert time.perf_counter() - t0 < 1.0
+    callers.arrive()
+    threading.Timer(0.05, callers.admitted).start()
+    t0 = time.perf_counter()
+    callers.let_in(30.0)
+    assert 0.04 <= time.perf_counter() - t0 < 10.0
+    callers.arrive()
+    t0 = time.perf_counter()
+    callers.let_in(0.05)
+    assert 0.04 <= time.perf_counter() - t0 < 10.0
+
+
+def test_submit_gets_the_lock_between_two_ticks(model):
+    # the step loop gives the lock up and takes it again at once: a
+    # submit used to stand before it for tens of ticks while the engine
+    # was busy (benchmark, PR 32: the closed-loop cells' queues filled
+    # by luck).  Counted in TICKS, so a loaded host does not change it
+    eng = ServingEngine(model, max_len=256, slots=2, buckets=[8])
+    ticks = [0]
+    real_step = eng._pool.step
+
+    def step(*a, **k):
+        ticks[0] += 1
+        return real_step(*a, **k)
+
+    eng._pool.step = step
+    eng.start()
+    try:
+        prompt = np.arange(5, dtype="int32")
+        busy = eng.submit(prompt, 240)
+        while ticks[0] < 3:
+            time.sleep(0.001)
+        waited = []
+        for _ in range(9):
+            before = ticks[0]
+            eng.submit(prompt, 1)
+            waited.append(ticks[0] - before)
+        assert sorted(waited)[len(waited) // 2] <= 2, waited
+        assert busy.result(timeout_s=120.0).state == RequestState.DONE
     finally:
         eng.shutdown()
 

@@ -170,3 +170,52 @@ def test_grouped_heads_kernel_and_grouped_matmul_on_the_v5e(one_chip,
                            *experts).compile().as_text()
         assert len(set(re.findall(r"ragged-dot-none[\w.]* = ",
                                   text))) == grouped, rows
+
+
+def test_retention_step_on_the_v5e_updates_the_state_where_it_lies(
+        one_chip, monkeypatch):
+    """The power-retention cell's decode step at its real state geometry
+    (16 slots x 8 K/V heads x [128, 9216] float32, 38 MB a slot a layer):
+    one Pallas kernel a layer, the state aliased in and out, and no
+    instruction anywhere in the program whose result has the state's shape
+    but the parameters and what the kernels return: no copy, no select.
+    Two layers at the published widths, a small vocabulary."""
+    import jax
+
+    from paddle_tpu.models import PowerRetentionLM
+    from paddle_tpu.ops import power_retention as pr
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    pt.seed(0)
+    model = PowerRetentionLM(vocab_size=512, hidden_size=5120,
+                             num_layers=LAYERS, num_heads=40,
+                             num_kv_heads=8, head_dim=128,
+                             intermediate_size=17408)
+    model.eval()
+    pool = GenerationPool(model, max_len=4864, slots=16, buckets=[1024],
+                          cache_layout="recurrent")
+    n = pool.slots
+    params, bufs = pool._session._state_vals()
+    samp = (np.zeros(n, np.float32), np.zeros(n, np.int32),
+            np.ones(n, np.float32), np.zeros(n, np.uint32))
+    args = (params, bufs, pool._cache, np.zeros(n, np.int32),
+            np.ones(n, bool), samp, np.zeros(n, np.uint32),
+            np.zeros(n, np.int32))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip), args)
+    monkeypatch.setattr(fa, "_backend_memo", "tpu")
+    state_shape = pool._cache[0].state.shape
+    assert state_shape == (16, 8, 128, 9216)
+    assert pr.step_kernel_refusal(state_shape) is None
+    assert pr.step_tile(9216, 128) == 9216
+    compiled = jax.jit(pool._pool_decode, donate_argnums=(2,)) \
+        .lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
+    made = {op for _, op in chip_smoke.pool_shaped_ops(text, state_shape)}
+    assert made <= {"parameter", "get-tuple-element", "custom-call",
+                    "bitcast"}, sorted(made)
+    # every byte of S and z goes out in the buffer it came in
+    state_bytes = LAYERS * 16 * pr.state_bytes(8, 128, 128)
+    assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes
