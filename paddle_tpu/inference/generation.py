@@ -763,6 +763,11 @@ class GenerationPool:
         self._samp_dev = None
         self._adapter_dev = None
         self._live_sig = None
+        # whether a row of the uploaded config draws (temperature > 0):
+        # what the step's sampler branches on, known here without the
+        # device; ``steps_drawing`` counts the launches that held one
+        self._draws = False
+        self.steps_drawing = 0
         carry_to = {} if mesh is None else {
             "out_shardings": (mesh.sharding("dp"), mesh.sharding("dp"))}
         self._patch_jit = jax.jit(self._patch, **carry_to)
@@ -2762,6 +2767,7 @@ class GenerationPool:
             self._samp_dev = (place(temp), place(tk), place(tp),
                               place(seed))
             self._adapter_dev = place(adpt)
+            self._draws = bool((temp > 0).any())
             self._live_sig = sig
         return self._weights()
 
@@ -2940,7 +2946,7 @@ class GenerationPool:
         """``tick.decode``'s meta, from what ``_launch`` is about to be
         given; built only under a tracer."""
         return dict(live=len(self._rows), slots=self.slots,
-                    **self._block_meta())
+                    greedy=int(not self._draws), **self._block_meta())
 
     def _launch(self, params, bufs):
         """The one batched decode dispatch (cache donated and rebound in
@@ -2952,6 +2958,7 @@ class GenerationPool:
             self._samp_dev, self._step_dev, self._adapter_dev)
         for _, st in self._rows:
             st.ahead += 1
+        self.steps_drawing += self._draws
         return self._tok_dev
 
     def _deliver(self, tok) -> None:

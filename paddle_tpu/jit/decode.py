@@ -206,8 +206,9 @@ def make_sampling_state(batch: int, temperature=0.0, top_k=0, top_p=1.0,
 def sample_logits_data(logits, temperature, top_k, top_p, seed, step):
     """Sample token ids [B] from logits [B, V] with the config as per-row
     traced DATA (the vectors of :class:`SamplingState`) — the as-data
-    twin of :func:`sample_logits`, branch-free so every row of one
-    compiled step can carry a different config.
+    twin of :func:`sample_logits`: ONE trace with one data-dependent
+    ``lax.cond``, so every row of one compiled step can carry a
+    different config and a step in which no row draws pays for no draw.
 
     Row semantics match the scalar sampler: ``temperature == 0`` is
     greedy argmax (seed unused); otherwise temperature scaling, top-k
@@ -217,37 +218,53 @@ def sample_logits_data(logits, temperature, top_k, top_p, seed, step):
     are dropped; ``top_p == 1`` keeps all), then a categorical draw
     under ``fold_in(PRNGKey(seed[r]), step[r])``.  ONE descending sort
     serves both truncations — the masks are arithmetic over it, never a
-    Python branch, so the trace is config-independent."""
-    v = logits.shape[-1]
-    lf = logits.astype(jnp.float32)
+    Python branch, so the trace is config-independent.
+
+    The argmax over the logits as given is taken outside any branch;
+    the sort, the softmax and the draw over ``[B, V]`` sit behind
+    ``lax.cond(any(temperature > 0))``, a predicate over the vector the
+    step is given (rows a step does not take are uploaded greedy).  A
+    step with a drawing row runs the whole draw for every row and its
+    greedy rows keep their argmax: the ids are the same either way.
+    Under ``vmap`` a ``cond`` becomes a select that runs both branches:
+    batch the rows through ``logits``, never by vmapping this."""
     temp = jnp.asarray(temperature, jnp.float32)
-    tk = jnp.asarray(top_k, jnp.int32)
-    tp = jnp.asarray(top_p, jnp.float32)
-    neg = jnp.float32(jnp.finfo(jnp.float32).min)
-    # temperature 0 rows scale by 1 (their draw is discarded for argmax)
-    safe_t = jnp.where(temp > 0, temp, jnp.float32(1.0))
-    scaled = lf / safe_t[:, None]
-    sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
-    # top-k: the row's k-th largest value is the keep threshold
-    kk = jnp.clip(tk, 1, v)
-    kth = jnp.take_along_axis(sorted_desc, (kk - 1)[:, None], axis=-1)
-    apply_k = ((tk > 0) & (tk < v))[:, None]
-    keep = jnp.where(apply_k, scaled >= kth, True)
-    # top-p: smallest set covering top_p mass (exclusive-prefix cut);
-    # rows with top_p == 1 never cut, so kept_min is the row minimum
-    probs = jax.nn.softmax(sorted_desc, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    cut = (cum - probs) >= tp[:, None]
-    kept_min = jnp.min(jnp.where(cut, jnp.inf, sorted_desc), axis=-1,
-                       keepdims=True)
-    keep = keep & (scaled >= kept_min)
-    masked = jnp.where(keep, scaled, neg)
-    keys = jax.vmap(
-        lambda s, t: jax.random.fold_in(jax.random.PRNGKey(s), t))(
-            jnp.asarray(seed, jnp.uint32), jnp.asarray(step, jnp.uint32))
-    drawn = jax.vmap(jax.random.categorical)(keys, masked)
-    greedy = jnp.argmax(logits, axis=-1)
-    return jnp.where(temp == 0, greedy, drawn).astype(jnp.int32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def draw():
+        v = logits.shape[-1]
+        lf = logits.astype(jnp.float32)
+        tk = jnp.asarray(top_k, jnp.int32)
+        tp = jnp.asarray(top_p, jnp.float32)
+        neg = jnp.float32(jnp.finfo(jnp.float32).min)
+        # temperature 0 rows scale by 1 (their draw is discarded for
+        # argmax)
+        safe_t = jnp.where(temp > 0, temp, jnp.float32(1.0))
+        scaled = lf / safe_t[:, None]
+        sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
+        # top-k: the row's k-th largest value is the keep threshold
+        kk = jnp.clip(tk, 1, v)
+        kth = jnp.take_along_axis(sorted_desc, (kk - 1)[:, None], axis=-1)
+        apply_k = ((tk > 0) & (tk < v))[:, None]
+        keep = jnp.where(apply_k, scaled >= kth, True)
+        # top-p: smallest set covering top_p mass (exclusive-prefix
+        # cut); rows with top_p == 1 never cut, so kept_min is the row
+        # minimum
+        probs = jax.nn.softmax(sorted_desc, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        cut = (cum - probs) >= tp[:, None]
+        kept_min = jnp.min(jnp.where(cut, jnp.inf, sorted_desc), axis=-1,
+                           keepdims=True)
+        keep = keep & (scaled >= kept_min)
+        masked = jnp.where(keep, scaled, neg)
+        keys = jax.vmap(
+            lambda s, t: jax.random.fold_in(jax.random.PRNGKey(s), t))(
+                jnp.asarray(seed, jnp.uint32),
+                jnp.asarray(step, jnp.uint32))
+        drawn = jax.vmap(jax.random.categorical)(keys, masked)
+        return jnp.where(temp == 0, greedy, drawn).astype(jnp.int32)
+
+    return jax.lax.cond(jnp.any(temp > 0), draw, lambda: greedy)
 
 
 def default_buckets(max_len: int, lo: int = 64) -> List[int]:

@@ -534,6 +534,10 @@ class ServingEngine:
             "ticks that exceeded the supervisor's stall timeout")
         self._c_tokens = m.counter(
             "serving_tokens_emitted_total", "tokens streamed to callers")
+        self._c_drawing = m.counter(
+            "serving_decode_steps_drawing_total",
+            "decode steps launched with a row that draws (temperature "
+            "> 0): the steps whose sampler runs its sort and draw")
         # traffic-grade scheduling surface (docs §5j): preemption /
         # spill-tier / degradation accounting.  The spill gauges exist
         # only on paged pools (the spill tier is block-granular), like
@@ -2468,9 +2472,10 @@ class ServingEngine:
         if self._g_accept is not None:
             self._g_accept.set(
                 pool.acceptance_stats()["acceptance_rate"])
+        # the pool's totals only grow: a counter moves by what its
+        # total gained since the counter last read it
+        self._c_drawing.inc(pool.steps_drawing - self._c_drawing.value)
         if self._c_block is not None:
-            # the pool's totals only grow: a counter moves by what its
-            # total gained since the counter last read it
             for key, now in pool.block_stats().items():
                 self._c_block[key].inc(now - self._c_block[key].value)
         if self._g_prefix_hit is not None or self._c_chunks is not None:

@@ -19,7 +19,8 @@ import paddle_tpu as pt
 from paddle_tpu.core.errors import InvalidArgumentError
 from paddle_tpu.inference import GenerationPool, create_generation_pool
 from paddle_tpu.jit import DecodeSession
-from paddle_tpu.jit.decode import default_buckets, sample_logits
+from paddle_tpu.jit.decode import (default_buckets, sample_logits,
+                                   sample_logits_data)
 from paddle_tpu.models import TransformerLM
 
 
@@ -203,6 +204,129 @@ def test_sample_logits_filtering_invariants_under_jit():
             tok = int(topp(jnp.asarray(logits), jax.random.PRNGKey(s))[0])
             assert tok in nucleus, (p, tok, nucleus)
         assert int(logits.argmax()) in nucleus
+
+
+def _branch_free_sampler(logits, temperature, top_k, top_p, seed, step):
+    """``sample_logits_data`` as it stood before the draw went behind a
+    ``cond``: the whole draw for every row of every step.  Kept here as
+    the yardstick the conditional form must match id for id."""
+    import jax
+    import jax.numpy as jnp
+
+    v = logits.shape[-1]
+    lf = logits.astype(jnp.float32)
+    temp = jnp.asarray(temperature, jnp.float32)
+    tk = jnp.asarray(top_k, jnp.int32)
+    tp = jnp.asarray(top_p, jnp.float32)
+    neg = jnp.float32(jnp.finfo(jnp.float32).min)
+    safe_t = jnp.where(temp > 0, temp, jnp.float32(1.0))
+    scaled = lf / safe_t[:, None]
+    sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
+    kk = jnp.clip(tk, 1, v)
+    kth = jnp.take_along_axis(sorted_desc, (kk - 1)[:, None], axis=-1)
+    apply_k = ((tk > 0) & (tk < v))[:, None]
+    keep = jnp.where(apply_k, scaled >= kth, True)
+    probs = jax.nn.softmax(sorted_desc, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    cut = (cum - probs) >= tp[:, None]
+    kept_min = jnp.min(jnp.where(cut, jnp.inf, sorted_desc), axis=-1,
+                       keepdims=True)
+    keep = keep & (scaled >= kept_min)
+    masked = jnp.where(keep, scaled, neg)
+    keys = jax.vmap(
+        lambda s, t: jax.random.fold_in(jax.random.PRNGKey(s), t))(
+            jnp.asarray(seed, jnp.uint32), jnp.asarray(step, jnp.uint32))
+    drawn = jax.vmap(jax.random.categorical)(keys, masked)
+    greedy = jnp.argmax(logits, axis=-1)
+    return jnp.where(temp == 0, greedy, drawn).astype(jnp.int32)
+
+
+def _sampler_inputs(batch, rows, truncate, dtype, vocab=384):
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(batch * 7 + len(rows))
+    logits = jnp.asarray(rng.randn(batch, vocab) * 3.0, dtype)
+    draws = {"greedy": np.zeros(batch, bool),
+             "drawing": np.ones(batch, bool),
+             "mixed": np.arange(batch) % 3 == 1}[rows]
+    temp = np.where(draws, rng.uniform(0.5, 1.3, batch), 0.0)
+    top_k = rng.randint(2, 40, batch) if truncate else np.zeros(batch)
+    top_p = rng.uniform(0.5, 0.95, batch) if truncate else np.ones(batch)
+    return (logits, temp.astype(np.float32), top_k.astype(np.int32),
+            top_p.astype(np.float32),
+            rng.randint(0, 2 ** 31, batch).astype(np.uint32),
+            rng.randint(0, 50, batch).astype(np.uint32))
+
+
+@pytest.mark.parametrize("truncate,dtype", [
+    (False, "float32"), (True, "float32"),
+    (False, "bfloat16"), (True, "bfloat16")])
+@pytest.mark.parametrize("batch,rows", [
+    (1, "greedy"), (1, "drawing"),
+    (16, "greedy"), (16, "drawing"), (16, "mixed")])
+def test_sampler_behind_the_cond_gives_the_branch_free_ids(
+        batch, rows, truncate, dtype):
+    import jax
+
+    args = _sampler_inputs(batch, rows, truncate, dtype)
+    want = np.asarray(jax.jit(_branch_free_sampler)(*args))
+    got = jax.jit(sample_logits_data)(*args)
+    assert got.dtype == np.int32 and got.shape == (batch,)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # and outside a jit, where the cond picks its branch eagerly
+    np.testing.assert_array_equal(np.asarray(sample_logits_data(*args)),
+                                  want)
+    greedy_rows = args[1] == 0
+    np.testing.assert_array_equal(
+        want[greedy_rows], np.asarray(args[0]).argmax(-1)[greedy_rows])
+    if rows != "greedy" and batch > 1:
+        # the draw is a draw: of several rows some leave their argmax
+        assert (want != np.asarray(args[0]).argmax(-1)).any()
+
+
+def _primitives(jaxpr):
+    """Every primitive's name in ``jaxpr`` and whatever it nests."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in _subjaxprs(eqn):
+            names |= _primitives(sub)
+    return names
+
+
+def _subjaxprs(eqn):
+    for val in eqn.params.values():
+        for item in (val if isinstance(val, (tuple, list)) else (val,)):
+            inner = getattr(item, "jaxpr", item)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_sampler_keeps_its_sort_inside_one_cond(dtype):
+    """A greedy step pays for no sort: at the top level of the jitted
+    sampler there is the argmax, the predicate and ONE ``cond``; the
+    sort, the cumulative sum and the random bits live in its drawing
+    branch alone, and the other branch computes nothing."""
+    import jax
+
+    args = _sampler_inputs(16, "mixed", True, dtype)
+    outer = jax.make_jaxpr(jax.jit(sample_logits_data))(*args).jaxpr
+    (call,) = outer.eqns
+    top = next(_subjaxprs(call))
+    names = [e.primitive.name for e in top.eqns]
+    heavy = {"sort", "cumsum", "random_bits"}
+    assert not heavy & set(names), names
+    assert names.count("cond") == 1 and "argmax" in names
+    cond = top.eqns[names.index("cond")]
+    inside = [_primitives(b.jaxpr) for b in cond.params["branches"]]
+    drawing = [b for b in inside if "sort" in b]
+    assert len(drawing) == 1 and heavy <= drawing[0], inside
+    (other,) = [b for b in inside if "sort" not in b]
+    assert not other & (heavy | {"argmax", "exp", "div"}), other
+    # the cast to float32 moved into the draw with the rest: bfloat16
+    # logits meet the argmax as given
+    assert top.eqns[names.index("argmax")].invars[0].aval.dtype == dtype
 
 
 def test_bucket_error_names_available_buckets():

@@ -412,3 +412,99 @@ def test_admission_edge_refusals():
     bankless = _pool(_model())
     with pytest.raises(InvalidArgumentError):
         bankless.submit(ids, 4, adapter=1)  # no bank at all
+
+
+# -- 7. a greedy step pays for no draw: one cond, one executable ---------
+
+def _parent_tokens(requests, **pool_kw):
+    """The tokens of ``requests`` from a pool whose sampler always takes
+    its drawing branch: the branch-free sampler as it stood before the
+    draw went behind ``lax.cond``, for every row of every step."""
+    import jax
+
+    taken = []
+
+    def always_draw(pred, draw, other):
+        taken.append(pred)
+        return draw()
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax.lax, "cond", always_draw)
+        pool = _pool(_model(), **pool_kw)
+        for rid, (ids, budget, cfg) in requests.items():
+            pool.submit(ids, budget, request_id=rid, **cfg)
+        out = pool.run()
+    # the sampler's own, once in the prefill's trace and once in the
+    # step's: no other cond was rerouted
+    assert len(taken) == 2
+    return {rid: toks.tolist() for rid, toks in out.items()}
+
+
+def test_drawing_request_among_greedy_one_executable_meta_and_counter():
+    """One request that draws joins two greedy ones and leaves: every
+    request's tokens are the branch-free sampler's, ``tick.decode``'s
+    ``greedy`` reads 1, then 0 while it lives, then 1, the counter
+    counts the middle steps, and nothing retraces across the three."""
+    p = _prompts(5, (7, 19, 12))
+    requests = {"g0": (p[0], 24, {}), "g1": (p[1], 24, {}),
+                "d": (p[2], 5, dict(temperature=0.9, top_k=20, seed=7))}
+    eng = ServingEngine(_model(), max_len=64, slots=4, buckets=[32])
+    tracer = eng.start_trace(capacity=4096)
+    try:
+        streams = {rid: eng.submit(ids, n, request_id=rid, **cfg)
+                   for rid, (ids, n, cfg) in requests.items()
+                   if rid != "d"}
+        eng.pump(4)
+        counts = eng._pool.compile_counts()
+        assert counts["prefill"] == 1 and counts["pool_decode"] == 1
+        assert eng.metrics.snapshot()[
+            "serving_decode_steps_drawing_total"] == 0
+        ids, n, cfg = requests["d"]
+        streams["d"] = eng.submit(ids, n, request_id="d", **cfg)
+        _drain(eng)
+    finally:
+        eng.stop_trace()
+    got = {rid: s.result(timeout_s=0).tokens.tolist()
+           for rid, s in streams.items()}
+    assert got == _parent_tokens(requests, slots=4)
+    assert eng._pool.compile_counts() == counts  # joined, left: no trace
+    flags = [e.meta["greedy"] for e in tracer.recorder.snapshot()
+             if e.name == "tick.decode"]
+    drawing = flags.count(0)
+    # the first token is the prefill's: four decode steps hold the row
+    assert drawing == 4
+    first = flags.index(0)
+    assert first >= 4 and flags[first:first + drawing] == [0] * drawing
+    assert set(flags[:first]) == {1} == set(flags[first + drawing:])
+    assert eng.metrics.snapshot()[
+        "serving_decode_steps_drawing_total"] == drawing
+    assert eng._pool.steps_drawing == drawing
+
+
+def test_drawing_row_in_one_shard_of_a_dp_mesh():
+    """Under ``DecodeMesh(dp=2)`` the slots are split over two devices
+    and only one of them holds a row that draws: the predicate is one value
+    for the step, and every row's tokens are the unsharded pool's."""
+    from paddle_tpu.jit.mesh import DecodeMesh
+
+    p = _prompts(6, (7, 19, 12, 9))
+    cfgs = [dict(temperature=0.8, top_p=0.9, seed=11), {}, {}, {}]
+
+    def run(mesh):
+        pool = _pool(_model(), slots=4, mesh=mesh)
+        for i, (ids, cfg) in enumerate(zip(p, cfgs)):
+            pool.submit(ids, 8, request_id=i, **cfg)
+        # slots 0-1 are one shard's rows and 2-3 the other's: the one
+        # request that draws leaves a whole shard greedy throughout
+        pool.step()
+        assert len(pool._active) == 4
+        assert len({slot // 2 for slot, st in pool._active.items()
+                    if st.sampling.temperature > 0}) == 1
+        out = pool.run()
+        assert pool.compile_counts()["pool_decode"] == 1
+        return [out[i].tolist() for i in range(4)], pool.steps_drawing
+
+    want, steps = run(None)
+    got, mesh_steps = run(DecodeMesh(2, 1))
+    assert got == want
+    assert mesh_steps == steps == 7

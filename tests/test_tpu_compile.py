@@ -104,6 +104,13 @@ def test_pool_decode_on_the_v5e_moves_no_pool(one_chip, model, monkeypatch,
     assert set(made) <= {"parameter", "scatter", "fusion", "bitcast",
                          "copy-start", "copy-done"}, sorted(set(made))
     assert made.count("fusion") == made.count("scatter")
+    # the sampler's sort over [slots, vocabulary] stays inside the
+    # conditional's drawing branch: the TPU's compiler keeps the
+    # ``cond`` a conditional, and a greedy step's entry computation (the
+    # module's last) holds no sort (docs/DESIGN.md 5q)
+    entry = text[text.index("\nENTRY "):]
+    assert " sort(" in text and " sort(" not in entry
+    assert entry.count(" conditional(") == 1
 
 
 @pytest.mark.parametrize("lq", [1, 5])

@@ -329,7 +329,8 @@ def test_every_span_is_a_profiler_annotation_too(model, monkeypatch):
     for name, stats, _ in seen:
         by_name.setdefault(name, stats)
     assert by_name["submit.lock_wait"] == {"rid": "r0"}
-    assert by_name["tick.decode"] == {"live": 1, "slots": 2, "ahead": 0}
+    assert by_name["tick.decode"] == {"live": 1, "slots": 2, "greedy": 1,
+                                      "ahead": 0}
     assert by_name["tick.deliver"] == {"rows": 1, "ended": 0}
     assert by_name["tick.prefill"] == {"rid": "r0", "prompt_tokens": 5,
                                        "bucket": 32}
@@ -802,7 +803,8 @@ def test_recorder_tail_dicts_bounded():
 
 # ``tick.decode``'s meta by kind of pool (paged, so the block counts ride)
 _DECODE_META = {
-    "plain": {"live", "slots", "ahead", "live_blocks", "table_blocks"},
+    "plain": {"live", "slots", "greedy", "ahead", "live_blocks",
+              "table_blocks"},
     "speculative": {"spec_k", "live", "slots", "ahead"},
     "block": {"live", "slots", "ahead", "kind", "rows", "store", "denoise",
               "committed", "tokens_per_forward", "live_blocks",
