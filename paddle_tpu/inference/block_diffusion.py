@@ -206,7 +206,7 @@ class BlockDiffusionPool(GenerationPool):
         overwrites.  No token is taken here, and no logits: the head is
         dead code in this program."""
         cache = self._model.gen_decode_cache(
-            1, self.max_len, self._cache_dtype, layout=self.cache_layout,
+            1, self.max_len, self._cache_dtype, layout=self._kv_layout,
             block_size=self._block_size)
         _, cache = self._session._run_model(param_vals, buf_vals, ids,
                                             cache)
@@ -232,7 +232,7 @@ class BlockDiffusionPool(GenerationPool):
         advance = (ctl[:, 2 * bl + 1] != 0) & (ctl[:, 2 * bl + 2] != 0)
         active = ctl[:, 2 * bl + 2] != 0
         given = cache
-        if self.cache_layout == "paged":
+        if self._layout.paged:
             cache = self._masked_tables(cache, active)
         logits, new_cache = self._session._run_model(
             param_vals, buf_vals, toks, cache)
@@ -366,7 +366,7 @@ class BlockDiffusionPool(GenerationPool):
                     rows=live * bl, store=stores, denoise=live - stores,
                     committed=committed,
                     tokens_per_forward=committed / live)
-        if self.cache_layout == "paged":
+        if self._layout.paged:
             # every row of a block sees to the block's end; the cursor
             # of a row that stores stands a block further already
             bs = self._block_size

@@ -440,57 +440,14 @@ class GenerationPool:
             raise InvalidArgumentError(
                 "tenant_slot_cap must be >= 1 slots per tenant (or None "
                 "for no fairness cap), got %r" % (tenant_slot_cap,))
-        # resolve the layout FIRST (jit.cache registry — typed error
-        # naming the registry for an unknown string), so every guard
-        # below can dispatch on layout capabilities instead of string
-        # comparisons, and a non-positional layout combined with a
-        # positional-only knob fails HERE naming the layout — never a
-        # silent no-op faking hit rates downstream
-        self._layout = get_layout(cache_layout)
-        if prefill_chunk_tokens is not None and cache_layout != "paged":
-            # the chunk path writes through the block table (per-slot
-            # scatter routed to the scratch block past the reservation);
-            # the dense layout keeps its one-shot bucketed prefill, so
-            # dense pools are byte-for-byte unaffected by this feature
-            if not self._layout.positional:
-                raise InvalidArgumentError(
-                    "prefill_chunk_tokens cannot apply to cache_layout="
-                    "'recurrent': a recurrence has no positional K/V to "
-                    "chunk into — its whole prefill is one O(L·d_state) "
-                    "scan, already cheap enough to run in-tick")
-            raise InvalidArgumentError(
-                "prefill_chunk_tokens is a paged-cache knob (chunk "
-                "writes route through the block table); pass "
-                "cache_layout='paged' (got %r)" % (cache_layout,))
+        # the string is validated FIRST (jit.cache registry — typed
+        # error naming the registry for an unknown one)
+        get_layout(cache_layout)
         if prefill_chunk_tokens is not None \
                 and int(prefill_chunk_tokens) < 1:
             raise InvalidArgumentError(
                 "prefill_chunk_tokens must be >= 1 tokens of prompt "
                 "work per tick, got %r" % (prefill_chunk_tokens,))
-        if prefix_sharing and cache_layout != "paged":
-            if not self._layout.positional:
-                raise InvalidArgumentError(
-                    "prefix_sharing cannot apply to cache_layout="
-                    "'recurrent': the recurrence folds the whole prefix "
-                    "into one carry, so there are no per-position "
-                    "blocks two requests could share — every request's "
-                    "state is already O(1)")
-            raise InvalidArgumentError(
-                "prefix_sharing shares physical KV blocks through the "
-                "block table; pass cache_layout='paged' (got %r)"
-                % (cache_layout,))
-        if prefix_sharing and prefill_chunk_tokens is None:
-            # the win of a prefix hit is skipping straight to the
-            # unmatched suffix, and ONLY the chunk executable can start
-            # a prompt mid-way (bucketed prefill always runs from token
-            # 0, which would recompute the shared prefix it just
-            # mapped) — so sharing without chunking is a misconfig, not
-            # a degraded mode
-            raise InvalidArgumentError(
-                "prefix_sharing needs prefill_chunk_tokens: admission "
-                "skips the matched prefix and chunk-prefills only the "
-                "suffix — pass prefill_chunk_tokens=<tokens per tick> "
-                "(e.g. the block size or a small multiple)")
         # the session owns the model binding, the sampling config and the
         # bucketed batch-1 prefill; the pool adds the slot-batched layer.
         # The session shares the pool's cache layout so a paged pool gets
@@ -511,6 +468,19 @@ class GenerationPool:
             block_size=block_size, mesh=mesh, route=route,
             collective_quant=collective_quant,
             collective_quant_scale=collective_quant_scale)
+        # the layout every guard below and every hook dispatches on is
+        # what the model's cache ENTRIES are (the session derived it:
+        # jit.cache.layout_of), so they ask capabilities and never
+        # compare strings, and a layout that cannot address positions
+        # combined with a positional-only knob fails HERE naming the
+        # layers — never a silent no-op faking hit rates downstream.
+        # ``cache_layout`` (public) is the layout's name: the caller's
+        # string for a model with one kind of entry, derived
+        # ('paged+recurrent') for a model that mixes kinds
+        self._layout = self._session._layout
+        self._kv_layout = cache_layout
+        cache_layout = self._layout.name
+        self._check_positional_knobs(prefill_chunk_tokens, prefix_sharing)
         self._model = model
         self._cache_dtype = cache_dtype
         from ..jit.speculative import model_vocab_size
@@ -526,7 +496,7 @@ class GenerationPool:
         self._block_size = int(block_size)
         # paged: ceil so a ragged final block still holds max_len
         self._max_blocks = -(-self.max_len // self._block_size)
-        if cache_layout == "paged":
+        if self._layout.paged:
             # physical block s*(num_blocks/dp) is shard s's reserved
             # SCRATCH block — that shard's unmapped table entries point
             # at it, its inactive-slot writes land in it (with dp=1
@@ -581,11 +551,17 @@ class GenerationPool:
         self._scratch_row = np.asarray(
             [self._shard_scratch(self._shard_of_slot(g))
              for g in range(self.slots)], np.int32) \
-            if cache_layout == "paged" else None
+            if self._layout.paged else None
         self._cache = self._new_cache()
-        self._state_bytes_slot = 0 if self._layout.positional else \
-            self._layout.state_bytes_per_slot(self._cache, self.slots,
-                                              self.max_len)
+        # layers and bytes a slot, by the entries' kind; what a step
+        # reads and writes of recurrent state is the recurrent entries'
+        self._by_kind = self._layout.bytes_per_slot_by_kind(
+            self._cache, self.slots, self.max_len)
+        self._state_bytes_slot = self._by_kind.get("recurrent", (0, 0))[1]
+        # the same, as ``cache_stats()`` hands it out every tick
+        self._by_kind_stats = {
+            "bytes_per_slot": {k: b for k, (_, b) in self._by_kind.items()},
+            "cache_layers": {k: n for k, (n, _) in self._by_kind.items()}}
         if donate is None:
             donate = jax.default_backend() != "cpu"
         self._decode_jit = jax.jit(self._pool_decode,
@@ -698,6 +674,13 @@ class GenerationPool:
                     "(paged K/V blocks, or a recurrent state carry); a "
                     "dense pool has no spill granularity — pass "
                     "cache_layout='paged' or 'recurrent'")
+            if not self._layout.transferable:
+                raise InvalidArgumentError(
+                    "spill_tier='disk' writes one kind of cache entry to "
+                    "a PTKV file (K/V blocks or state rows); "
+                    "cache_layout=%r has both (%s) — keep spill_tier="
+                    "'host', which carries both in memory"
+                    % (cache_layout, self._layout.recurrent_layers()))
             if spill_dir is None:
                 raise InvalidArgumentError(
                     "spill_tier='disk' needs spill_dir= (the directory "
@@ -721,12 +704,13 @@ class GenerationPool:
                 "prefill_only=True exports finished prefills over the "
                 "K/V transfer contract, which lives in the disk spill "
                 "tier — pass spill_tier='disk' (and spill_dir=)")
-        if prefill_only and cache_layout == "recurrent":
+        if prefill_only and self._layout.recurrent:
             raise InvalidArgumentError(
                 "prefill_only=True (the disaggregated prefill tier) is "
-                "not wired for cache_layout='recurrent': a recurrent "
+                "not wired for cache_layout=%r (%s): a recurrent "
                 "prefill is one cheap O(L·d_state) scan, so there is "
-                "nothing to disaggregate — run a fused engine")
+                "nothing to disaggregate — run a fused engine"
+                % (cache_layout, self._layout.recurrent_layers()))
         self._prefill_only = bool(prefill_only)
         # rid -> (slot, _SlotState) for prefill-complete parked
         # requests awaiting export_kv()
@@ -802,6 +786,56 @@ class GenerationPool:
         # would sit on the decode hot path
         self._state_cache = None
 
+    def _check_positional_knobs(self, prefill_chunk_tokens,
+                                prefix_sharing) -> None:
+        """Chunked prefill and prefix sharing write and share through a
+        block table over EVERY layer's positions: typed errors for a
+        layout without one, naming the layers that keep a recurrent
+        state where that is why."""
+        lay = self._layout
+        if prefill_chunk_tokens is not None \
+                and not (lay.paged and lay.positional):
+            # the chunk path writes through the block table (per-slot
+            # scatter routed to the scratch block past the reservation);
+            # the dense layout keeps its one-shot bucketed prefill, so
+            # dense pools are byte-for-byte unaffected by this feature
+            if not lay.positional:
+                raise InvalidArgumentError(
+                    "prefill_chunk_tokens cannot apply to cache_layout="
+                    "%r (%s): a recurrence has no positional K/V to "
+                    "chunk into — its whole prefill is one O(L·d_state) "
+                    "scan, already cheap enough to run in-tick"
+                    % (lay.name, lay.recurrent_layers()))
+            raise InvalidArgumentError(
+                "prefill_chunk_tokens is a paged-cache knob (chunk "
+                "writes route through the block table); pass "
+                "cache_layout='paged' (got %r)" % (lay.name,))
+        if prefix_sharing and not (lay.paged and lay.positional):
+            if not lay.positional:
+                raise InvalidArgumentError(
+                    "prefix_sharing cannot apply to cache_layout="
+                    "%r (%s): the recurrence folds the whole prefix "
+                    "into one carry, so there are no per-position "
+                    "blocks two requests could share — every request's "
+                    "state is already O(1)"
+                    % (lay.name, lay.recurrent_layers()))
+            raise InvalidArgumentError(
+                "prefix_sharing shares physical KV blocks through the "
+                "block table; pass cache_layout='paged' (got %r)"
+                % (lay.name,))
+        if prefix_sharing and prefill_chunk_tokens is None:
+            # the win of a prefix hit is skipping straight to the
+            # unmatched suffix, and ONLY the chunk executable can start
+            # a prompt mid-way (bucketed prefill always runs from token
+            # 0, which would recompute the shared prefix it just
+            # mapped) — so sharing without chunking is a misconfig, not
+            # a degraded mode
+            raise InvalidArgumentError(
+                "prefix_sharing needs prefill_chunk_tokens: admission "
+                "skips the matched prefix and chunk-prefills only the "
+                "suffix — pass prefill_chunk_tokens=<tokens per tick> "
+                "(e.g. the block size or a small multiple)")
+
     # -- traced bodies ---------------------------------------------------
     def _insert(self, pool_cache, row_cache, slot, length, blocks=None):
         """Splice a batch-1 prefilled row cache into ``slot``; the slot
@@ -850,9 +884,11 @@ class GenerationPool:
         writes at."""
         sess = self._session
         given = cache
-        paged = self.cache_layout == "paged"
-        cache = self._masked_tables(cache, active) if paged \
-            else self._layout.begin_step(cache, active)
+        # each entry by its own layout: a paged entry's table and index
+        # masked, a recurrent entry's update window closed on the free
+        # slots (jit.cache), whichever kinds this model's layers keep
+        cache = self._layout.begin_step(self._masked_tables(cache, active),
+                                        active)
         logits, new_cache = sess._run_model(param_vals, buf_vals,
                                             toks[:, None], cache,
                                             adapter,
@@ -872,9 +908,9 @@ class GenerationPool:
         # state's size is selected here)
         with jax.named_scope("cache_freeze"):
             new_cache = self._layout.freeze_step(new_cache, given, active)
-        if paged:
-            new_cache = [c._replace(table=g.table)
-                         for c, g in zip(new_cache, given)]
+        new_cache = [c._replace(table=g.table) if lay.paged else c
+                     for lay, c, g in zip(self._layout.layouts(given),
+                                          new_cache, given)]
         return new_cache, jnp.where(active, tok, 0), step
 
     def _masked_tables(self, cache, active):
@@ -886,13 +922,17 @@ class GenerationPool:
         the step: the attention kernel's cost follows the positions a
         row says it holds, and a free slot's index is its last
         request's length.  The caller restores both from the cache it
-        was given.  Traced helper, shared with the speculative verify
-        step and the block-diffusion step."""
+        was given.  Entries that are not paged pass through.  Traced
+        helper, shared with the speculative verify step and the
+        block-diffusion step."""
+        if not self._layout.paged:
+            return cache
         scratch = jnp.asarray(self._scratch_row)[:, None]
         return [c._replace(table=jnp.where(active[:, None], c.table,
                                            scratch),
                            index=jnp.where(active, c.index, 0))
-                for c in cache]
+                if lay.paged else c
+                for lay, c in zip(self._layout.layouts(cache), cache)]
 
     def _admit(self, cache, slot, row, index):
         """Map an admitted request's table row (shared prefix blocks +
@@ -1077,7 +1117,7 @@ class GenerationPool:
         # largest bucket are servable there
         if self._chunk_tokens is None:
             self._session._bucket_for(len(ids))
-        if self.cache_layout == "paged":
+        if self._layout.paged:
             # a request must fit an EMPTY pool — one SHARD's partition,
             # since a slot's blocks never span shards — else _refill
             # could never admit it and the pool would stall forever on
@@ -1195,9 +1235,8 @@ class GenerationPool:
         them."""
         cache = self._model.gen_decode_cache(
             self.slots, self.max_len, self._cache_dtype, per_slot=True,
-            layout=self.cache_layout, block_size=self._block_size,
-            num_blocks=(self._num_blocks if self.cache_layout == "paged"
-                        else None))
+            layout=self._kv_layout, block_size=self._block_size,
+            num_blocks=(self._num_blocks if self._layout.paged else None))
         if self._mesh is not None:
             cache = self._mesh.place_cache(cache)
         return cache
@@ -1269,7 +1308,7 @@ class GenerationPool:
         (an index entry must always name a RESIDENT block).  A block
         another slot still shares stays resident — the refcount is what
         makes mid-generation release safe under sharing."""
-        if self.cache_layout != "paged":
+        if not self._layout.paged:
             return
         self._prefix_epoch += 1
         for b in self._slot_blocks.pop(slot, ()):
@@ -1477,7 +1516,7 @@ class GenerationPool:
                    sorted(str(st.rid) for st in self._active.values())))
         st = self._active[slot]
         self._preempt_guard(slot, st)
-        if self.cache_layout == "recurrent":
+        if not self._layout.paged:
             return self._preempt_recurrent(slot, st)
         bs = self._block_size
         shard = self._shard_of_slot(slot)
@@ -1496,15 +1535,23 @@ class GenerationPool:
         gidx[:written] = blocks[:written]
         gather = jnp.asarray(gidx)
         # ONE batched download of everything resume must be able to
-        # restore — the spill boundary's deliberate host sync
+        # restore — the spill boundary's deliberate host sync.  An entry
+        # that is not paged (a recurrent layer of a model that mixes
+        # kinds) gives the slot's state rows whole
+        lays = self._layout.layouts(self._cache)
         host = jax.device_get([
             (c.k[gather], c.v[gather])
             + ((c.k_scale[gather], c.v_scale[gather])
                if c.k_scale is not None else ())
-            for c in self._cache])
+            if lay.paged else
+            tuple(getattr(c, f)[slot] for f in lay.state_fields(c))
+            for lay, c in zip(lays, self._cache)])
         # honest byte accounting: the pad rows are not spilled content
-        host_bytes = sum(arr[:written].nbytes
-                         for layer in host for arr in layer)
+        state_bytes = sum(arr.nbytes for lay, layer in zip(lays, host)
+                          if not lay.paged for arr in layer)
+        host_bytes = state_bytes + sum(
+            arr[:written].nbytes for lay, layer in zip(lays, host)
+            if lay.paged for arr in layer)
         host_path = None
         if self.spill_tier == "disk":
             # the disk write happens BEFORE any allocator mutation, so
@@ -1542,9 +1589,12 @@ class GenerationPool:
         self._spilled[st.rid] = sp
         self._preempts_total += 1
         self._spill_bytes_total += host_bytes
-        return {"rid": st.rid, "slot": slot, "blocks_spilled": written,
+        info = {"rid": st.rid, "slot": slot, "blocks_spilled": written,
                 "blocks_freed": freed, "spill_bytes": host_bytes,
                 "committed_tokens": len(st.tokens)}
+        if self._layout.recurrent:
+            info["state_bytes"] = state_bytes
+        return info
 
     def _preempt_recurrent(self, slot: int, st: _SlotState) -> dict:
         """Recurrent-layout preemption: the victim's entire decode
@@ -1646,7 +1696,7 @@ class GenerationPool:
         # applies — resubmit is always available and always correct —
         # so the loss is contained to THIS victim: its device copies
         # free, and prompt+committed re-queues under its identity.
-        if self.cache_layout == "recurrent":
+        if not self._layout.paged:
             return self._resume_recurrent(sp)
         host_src = sp.host
         if host_src is None and any(
@@ -1705,7 +1755,19 @@ class GenerationPool:
             ids[:n_up] = [b for _, b in upload]
             ids_dev = jnp.asarray(ids)
         new_cache = []
-        for layer, c in enumerate(self._cache):
+        lays = self._layout.layouts(self._cache)
+        for layer, (lay, c) in enumerate(zip(lays, self._cache)):
+            if not lay.paged:
+                # a recurrent layer of a model that mixes kinds: its
+                # state rows come back whole from the host copy (the
+                # host tier always holds one: such a layout has no disk
+                # tier)
+                new_cache.append(c._replace(
+                    index=c.index.at[slot].set(pos_dev),
+                    **{f: getattr(c, f).at[slot].set(
+                           jnp.asarray(host_src[layer][j]))
+                       for j, f in enumerate(lay.state_fields(c))}))
+                continue
             upd = dict(table=c.table.at[slot].set(row),
                        index=c.index.at[slot].set(pos_dev))
             if upload:
@@ -1730,19 +1792,27 @@ class GenerationPool:
         if upload:
             # honest byte accounting: pad rows are not paged-in content
             self._upload_bytes_total += sum(
-                fields[i][sel[:n_up]].nbytes for fields in host_src
-                for i in range(len(fields)))
+                arr[sel[:n_up]].nbytes
+                for lay, fields in zip(lays, host_src) if lay.paged
+                for arr in fields)
+        state_bytes = sum(arr.nbytes for lay, fields in zip(lays, host_src)
+                          if not lay.paged for arr in fields) \
+            if self._layout.recurrent else 0
+        self._upload_bytes_total += state_bytes
         # the parked copy is consumed: a disk-tier file is deleted the
         # moment its request decodes again (a crash after this point
         # restores via the journal's prompt+committed replay instead)
         self._spill_drop(sp)
         self._on_resumed(slot, sp)
         if self.on_resume is not None:
-            self.on_resume(sp.rid, {
+            info = {
                 "slot": slot, "blocks_remapped": len(blocks) - len(upload)
                 - (sp.total_blocks - sp.written),
                 "blocks_uploaded": len(upload),
-                "committed_tokens": len(sp.tokens)})
+                "committed_tokens": len(sp.tokens)}
+            if self._layout.recurrent:
+                info["state_bytes"] = state_bytes
+            self.on_resume(sp.rid, info)
 
     def _on_resumed(self, slot: int, sp: _SpillState) -> None:
         """Subclass hook: a preempted request just resumed decoding in
@@ -1799,7 +1869,7 @@ class GenerationPool:
         leaves the pool untouched."""
         path = self._spill_path(st.rid)
         arrays = {}
-        recurrent = self.cache_layout == "recurrent"
+        recurrent = not self._layout.paged
         for i, layer in enumerate(host):
             for j, arr in enumerate(layer):
                 # recurrent payload is whole state rows, not a written-
@@ -1876,7 +1946,7 @@ class GenerationPool:
         the file is STALE), shape/dtype/block-size mismatch against
         this pool's cache, or a subclass veto.  Never raises for a bad
         file: resubmit is always available and always correct."""
-        if self.spill_tier != "disk" or not self._layout.spillable:
+        if self.spill_tier != "disk" or not self._layout.transferable:
             return False
         if request_id in self._used_rids:
             return False
@@ -1891,7 +1961,7 @@ class GenerationPool:
         path = self._spill_path(request_id)
         if not os.path.exists(path):
             return False
-        recurrent = self.cache_layout == "recurrent"
+        recurrent = not self._layout.paged
         first = self._cache[0]
         if recurrent:
             # the carry is O(1): no block math, no capacity gate — a
@@ -2179,7 +2249,7 @@ class GenerationPool:
     def _shared_block_count(self) -> int:
         """Blocks currently referenced beyond their first owner — the
         live HBM the prefix index is saving (0 for dense pools)."""
-        if self.cache_layout != "paged":
+        if not self._layout.paged:
             return 0
         return sum(r - 1 for r in self._block_refs.values() if r > 1)
 
@@ -2553,7 +2623,7 @@ class GenerationPool:
                 break  # every candidate is tenant-capped right now
             kind, item = pick
             if kind == "resume":
-                if self.cache_layout == "recurrent":
+                if not self._layout.paged:
                     # an O(1) carry holds no device blocks and is not
                     # shard-pinned (its restorable copy is host/disk
                     # bytes): any free slot resumes it, and the while
@@ -2586,7 +2656,7 @@ class GenerationPool:
             req = item
             matched_blocks, matched_len, chain_key = [], 0, None
             shard = None
-            if self.cache_layout == "paged":
+            if self._layout.paged:
                 # admission control: the chosen candidate waits until
                 # enough blocks are free (+reclaimable from the spill
                 # tier) for its whole reservation IN SOME SHARD with a
@@ -2636,7 +2706,7 @@ class GenerationPool:
             slot = self._pop_free_slot(shard)
             args = (self._cache, row_cache, jnp.asarray(slot, jnp.int32),
                     jnp.asarray(length, jnp.int32))
-            if self.cache_layout == "paged":
+            if self._layout.paged:
                 _fire("pool.alloc_blocks")
                 blocks = self._alloc_blocks(need, shard)
                 self._slot_blocks[slot] = blocks
@@ -2926,21 +2996,31 @@ class GenerationPool:
         return meta
 
     def _block_meta(self) -> dict:
-        """``tick.decode``'s meta on a paged pool: ``live_blocks``, the
+        """``tick.decode``'s meta, each figure over its own layers: where
+        entries are paged (``kv_layers`` of them) ``live_blocks``, the
         table entries the live slots' positions reach (what the
         attention kernel fetches and computes: ``ops/pallas_decode.py``
-        skips the rest), and ``table_blocks``, slots x table width; on
-        a recurrent pool ``state_bytes``."""
-        if not self._layout.positional:
+        skips the rest), and ``table_blocks``, slots x table width;
+        where entries are recurrent (``state_layers``) ``state_bytes``.
+        A model that mixes kinds carries both in the one span, and the
+        layer counts that say what each figure is over."""
+        meta = {}
+        if self._layout.recurrent:
             # a recurrent state: what the step reads AND writes of it,
             # from shapes (live rows x the bytes a slot's state takes)
-            return {"state_bytes": len(self._rows) * self._state_bytes_slot}
-        if self.cache_layout != "paged":
-            return {}
-        bs = self._block_size
-        return {"live_blocks": sum(self._last_position(slot, st) // bs + 1
-                                   for slot, st in self._rows),
-                "table_blocks": self.slots * self._max_blocks}
+            meta["state_bytes"] = len(self._rows) * self._state_bytes_slot
+        if self._layout.paged:
+            bs = self._block_size
+            meta.update(
+                live_blocks=sum(self._last_position(slot, st) // bs + 1
+                                for slot, st in self._rows),
+                table_blocks=self.slots * self._max_blocks)
+        if len(self._by_kind) > 1:
+            meta.update(
+                state_layers=self._by_kind.get("recurrent", (0,))[0],
+                kv_layers=sum(n for k, (n, _) in self._by_kind.items()
+                              if k != "recurrent"))
+        return meta
 
     def _decode_meta(self, *inputs) -> dict:
         """``tick.decode``'s meta, from what ``_launch`` is about to be
@@ -3072,7 +3152,7 @@ class GenerationPool:
         # being discarded; the engine resubmits them like any survivor
         self._prefill_done.clear()
         self.admission_blocked = False
-        if self.cache_layout == "paged":
+        if self._layout.paged:
             self._free_by_shard = [
                 list(range(s * self._blocks_per_shard + 1,
                            (s + 1) * self._blocks_per_shard))
@@ -3195,8 +3275,10 @@ class GenerationPool:
         the bytes a decode step can reach RIGHT NOW vs what a dense
         preallocation of the same pool would pin — the paged win,
         quantified from the allocator state rather than asserted."""
-        first = self._cache[0]
-        if self.cache_layout == "recurrent":
+        # bytes a slot and layers, by the entries' kind (a model with one
+        # kind of entry has one key)
+        by_kind = self._by_kind_stats
+        if set(self._by_kind) == {"recurrent"}:
             # O(1)-state accounting: the whole cache is [slots, d_state]
             # per layer — no positional axis, so reachable == resident
             # == the state pytree, independent of sequence length (the
@@ -3209,11 +3291,12 @@ class GenerationPool:
                 "cache_layout": self.cache_layout,
                 "cache_dtype": self._layout.cache_dtype_str(self._cache),
                 "decode_route": self._session.route,
-                "d_state": int(first.state.shape[-1]),
+                "d_state": self._layout.fingerprint_extra(self)["d_state"],
                 "num_layers": len(self._cache),
                 "state_bytes_per_slot": per_slot,
                 "reachable_bytes": state_total,
                 "pool_bytes": state_total,
+                **by_kind,
             }
             if self._mesh is not None:
                 stats["mesh"] = self._mesh.describe()
@@ -3231,7 +3314,14 @@ class GenerationPool:
                 stats["pool_bytes_per_device"] = \
                     state_total // self._mesh.dp
             return stats
-        dims = dict(max_len=self.max_len, num_layers=len(self._cache),
+        # the K/V figures run over the entries that hold K/V; a model
+        # that mixes kinds adds its recurrent entries' whole state (the
+        # same at any context) to what is resident and reachable
+        kv = [c for lay, c in zip(self._layout.layouts(self._cache),
+                                  self._cache) if lay.positional]
+        first = kv[0]
+        state_total = self._state_bytes_slot * self.slots
+        dims = dict(max_len=self.max_len, num_layers=len(kv),
                     num_heads=first.k.shape[1], head_dim=first.k.shape[3],
                     dtype=first.k.dtype)
         dense_bytes = kv_reachable_bytes([self.max_len] * self.slots,
@@ -3241,7 +3331,7 @@ class GenerationPool:
         # and the dtype is stamped so a serving record can never present
         # an int8 byte count as an fp32 one
         stats = {"cache_layout": self.cache_layout,
-                 "cache_dtype": str(np.dtype(first.k.dtype)),
+                 "cache_dtype": self._layout.cache_dtype_str(self._cache),
                  # the decode-attention route (§5l) is provenance the
                  # same way layout/dtype are: a tok/s or byte figure
                  # from the fused kernel must never be presented as a
@@ -3250,9 +3340,9 @@ class GenerationPool:
                  # worst-case cache bytes one slot pins at max_len —
                  # comparable across model classes (the recurrent
                  # branch stamps the same key for its O(1) state)
-                 "state_bytes_per_slot": self._layout.state_bytes_per_slot(
-                     self._cache, self.slots, self.max_len),
-                 "dense_equiv_bytes": dense_bytes}
+                 "state_bytes_per_slot": sum(
+                     b for _, b in self._by_kind.values()),
+                 "dense_equiv_bytes": dense_bytes, **by_kind}
         if self._mesh is not None:
             stats["mesh"] = self._mesh.describe()
             # the mp-collective mode is provenance like layout/route: a
@@ -3262,7 +3352,7 @@ class GenerationPool:
             # derived from traced collective shapes, never faked
             stats["collective_quant"] = self._session.collective_quant
             stats.update(self._session.collective_report())
-        if self.cache_layout == "paged":
+        if self._layout.paged:
             bs = self._block_size
             # resident = unique blocks some live slot's table row maps
             # (== the refcounted set); spilled device copies are a
@@ -3284,10 +3374,10 @@ class GenerationPool:
                 for j, b in enumerate(blocks):
                     seen.setdefault(b, j)
             per_token = dense_bytes // (self.slots * self.max_len)
-            reachable = per_token * sum(
+            reachable = state_total + per_token * sum(
                 max(0, min((j + 1) * bs, self.max_len) - j * bs)
                 for j in seen.values())
-            pool_bytes = self._num_blocks * bs * per_token
+            pool_bytes = state_total + self._num_blocks * bs * per_token
             stats.update(
                 block_size=bs,
                 num_blocks=self._num_blocks,
@@ -3334,6 +3424,7 @@ class GenerationPool:
                 "pool_bytes": pool_bytes // self._dp,
             } for s in range(self._dp)]
         else:
+            dense_bytes += state_total
             stats.update(reachable_bytes=dense_bytes,
                          pool_bytes=dense_bytes)
             stats["per_shard"] = [

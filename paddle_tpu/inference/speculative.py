@@ -48,6 +48,16 @@ from .generation import GenerationPool
 __all__ = ["SpeculativePool"]
 
 
+def _refuse_recurrent(layout: str, layers: str):
+    raise InvalidArgumentError(
+        "speculative decoding does not support cache_layout=%r (%s): "
+        "verify-rewind moves a POSITIONAL index pointer back over "
+        "rejected drafts, but a recurrent carry folds every step into "
+        "one state vector — there is no earlier position to rewind to "
+        "without re-running the prefix; use GenerationPool for "
+        "recurrent/SSM models" % (layout, layers))
+
+
 class SpeculativePool(GenerationPool):
     """Continuous batching whose step is a draft/verify round.
 
@@ -82,14 +92,8 @@ class SpeculativePool(GenerationPool):
                 "spec_k must be >= 1 draft tokens per round, got %r"
                 % (spec_k,))
         if pool_kwargs.get("cache_layout") == "recurrent":
-            raise InvalidArgumentError(
-                "speculative decoding does not support "
-                "cache_layout='recurrent': verify-rewind moves a "
-                "POSITIONAL index pointer back over rejected drafts, "
-                "but a recurrent carry folds every step into one state "
-                "vector — there is no earlier position to rewind to "
-                "without re-running the prefix; use GenerationPool for "
-                "recurrent/SSM models")
+            _refuse_recurrent("recurrent",
+                              "every layer keeps a state of constant size")
         if pool_kwargs.get("prefill_only"):
             raise InvalidArgumentError(
                 "prefill_only=True: the speculative pool's draft state "
@@ -105,6 +109,11 @@ class SpeculativePool(GenerationPool):
         # small by design, and its prompt forward runs once at
         # activation, not per tick
         super().__init__(model, max_len, **pool_kwargs)
+        if not self._layout.positional:
+            # a model that mixes kinds: asked for by its K/V layout, so
+            # only its cache entries say that some layers cannot rewind
+            _refuse_recurrent(self.cache_layout,
+                              self._layout.recurrent_layers())
         buckets, donate, mesh, route = (
             pool_kwargs.get("buckets"), pool_kwargs.get("donate"),
             pool_kwargs.get("mesh"), pool_kwargs.get("route", "auto"))
@@ -246,7 +255,7 @@ class SpeculativePool(GenerationPool):
         sess = self._session
         idx0 = cache[0].index                                # [slots]
         given = cache
-        if self.cache_layout == "paged":
+        if self._layout.paged:
             # inactive rows' tables are scratch-routed FOR the step
             # (each slot to ITS shard's scratch block) and their index
             # reads 0, but both are restored in the returned cache

@@ -12,7 +12,21 @@ Portable O(1) Autoregressive Caching" direction in PAPERS.md: a
 recurrence carry instead of an attention prefix).
 
 This module names the operations those consumers actually perform as a
-:class:`CacheLayout` protocol and registers one singleton per layout:
+:class:`CacheLayout` protocol and registers one singleton per layout.
+
+**The layout is a property of a layer's cache ENTRY, not of the model.**
+A decode cache is a list with one entry a layer, and an entry's own type
+says which layout it is (:func:`entry_layout`: a ``table`` field is paged,
+a ``limit`` field recurrent, else dense).  Every hook below is written
+for ONE entry (``*_entry``), and the list-level hooks the consumers call
+walk the list and hand each entry to its own layout.  A model whose
+layers all keep one kind gets the registered singleton
+(:func:`layout_of` returns it, so nothing changes for them); a model
+that mixes kinds (``models.HybridMambaLM``: 26 recurrent entries and 2
+paged ones in one list) gets a :class:`ComposedLayout`, whose
+capabilities are the MEET of its entries': it has a block table if any
+entry has one, addresses positions only if every entry does, spills only
+if every entry spills.  The consumers ask the layout, never a string.
 
 ==================  =====================================================
 operation            who calls it / what it decides
@@ -42,9 +56,10 @@ operation            who calls it / what it decides
                      vector replicated within an mp group)
 ``cache_dtype_str``  cache_stats()/config_fingerprint() provenance — the
                      payload dtype without assuming a ``.k`` field
-``state_bytes_per_slot``  cache_stats() — the decode-state HBM one slot
-                     pins at full span, the figure the slots-per-GB
-                     capacity comparison is made of
+``bytes_per_slot_by_kind``  cache_stats() — layers and the decode-state
+                     HBM one slot pins at full span, by the entries'
+                     kind: the figure the slots-per-GB capacity
+                     comparison is made of
 ``fingerprint_extra``  config_fingerprint() — layout-private geometry
                      (paged: block_size/num_blocks; recurrent: d_state)
                      so the PTKV fingerprint check can never let one
@@ -62,7 +77,12 @@ layouts, so a pool kwarg that silently no-ops is impossible:
   scratch-block masking, block-granular spill live in the pool — they
   are paged POLICY, not protocol).
 - ``spillable``: preempt/resume/adopt can move a slot's state through
-  the host/disk tiers and the PTKV transfer contract.
+  the host tier.
+- ``transferable``: the state also goes through the disk tier and the
+  PTKV transfer contract, whose file carries ONE kind of entry (a composed
+  layout is not).
+- ``recurrent``: some entry is a state of constant size (it has a
+  ``limit`` window, a ``state_bytes`` figure and no block).
 
 The traced-method bodies (``insert_row``/``freeze_step``/the prefill
 hooks) are the EXACT code the pool and session inlined before this
@@ -78,7 +98,8 @@ import numpy as np
 from ..core.errors import InvalidArgumentError
 
 __all__ = ["CacheLayout", "DenseLayout", "PagedLayout", "RecurrentLayout",
-           "CACHE_LAYOUTS", "get_layout"]
+           "ComposedLayout", "CACHE_LAYOUTS", "get_layout", "entry_layout",
+           "layout_of"]
 
 
 class CacheLayout:
@@ -97,36 +118,74 @@ class CacheLayout:
     positional: bool = True
     #: cache is a block pool behind a per-slot table
     paged: bool = False
-    #: preempt/resume/adopt can move per-slot state through the
-    #: host/disk spill tiers and PTKV transfer files
+    #: preempt/resume can move per-slot state through the host tier
     spillable: bool = False
+    #: ... and through the disk tier and PTKV transfer files
+    transferable: bool = False
+    #: some entry is a state of constant size
+    recurrent: bool = False
+
+    def layouts(self, cache) -> tuple:
+        """The layout of every entry of ``cache``, in order (a singleton:
+        itself throughout)."""
+        return (self,) * len(cache)
+
+    def entries(self, cache, kind: str) -> list:
+        """The entries of ``cache`` whose own layout is ``kind``."""
+        return [c for lay, c in zip(self.layouts(cache), cache)
+                if lay.name == kind]
+
+    def recurrent_layers(self) -> str:
+        """How a refusal names the layers that keep a recurrent state."""
+        return "every layer keeps a state of constant size"
 
     # -- prefill hooks (traced) ------------------------------------------
-    def begin_prefill(self, cache, true_len):
+    def begin_prefill_entry(self, c, true_len):
         """Layout prep before the prefill forward (identity for
         positional layouts: pad K/V is written but never attended)."""
-        return cache
+        return c
+
+    def finalize_prefill_entry(self, c, true_len, max_len):
+        """Commit the true prompt length after the prefill forward."""
+        return c._replace(index=true_len)
+
+    def begin_prefill(self, cache, true_len):
+        return [lay.begin_prefill_entry(c, true_len)
+                for lay, c in zip(self.layouts(cache), cache)]
 
     def finalize_prefill(self, cache, true_len, max_len):
-        """Commit the true prompt length after the prefill forward."""
-        return [c._replace(index=true_len) for c in cache]
+        return [lay.finalize_prefill_entry(c, true_len, max_len)
+                for lay, c in zip(self.layouts(cache), cache)]
 
     # -- pool splice / step freeze (traced) ------------------------------
-    def insert_row(self, pool_cache, row_cache, slot, length, blocks=None):
+    def insert_entry(self, cp, cr, slot, length, blocks=None):
         raise NotImplementedError
 
-    def begin_step(self, cache, active):
+    def begin_step_entry(self, c, active):
         """Layout prep before a pool's batched decode step (identity
         here; the paged pool masks its tables itself; the recurrent
         layout closes the update window of the free slots)."""
-        return cache
+        return c
 
-    def freeze_step(self, new_cache, prev_cache, active):
+    def freeze_step_entry(self, c, old, active):
         """Merge a decode step's cache back to the pre-step value for
         inactive slots (positional layouts: only the index advances
         per step, so only the index needs freezing)."""
-        return [c._replace(index=jnp.where(active, c.index, old.index))
-                for c, old in zip(new_cache, prev_cache)]
+        return c._replace(index=jnp.where(active, c.index, old.index))
+
+    def insert_row(self, pool_cache, row_cache, slot, length, blocks=None):
+        return [lay.insert_entry(cp, cr, slot, length, blocks)
+                for lay, cp, cr in zip(self.layouts(pool_cache), pool_cache,
+                                       row_cache)]
+
+    def begin_step(self, cache, active):
+        return [lay.begin_step_entry(c, active)
+                for lay, c in zip(self.layouts(cache), cache)]
+
+    def freeze_step(self, new_cache, prev_cache, active):
+        return [lay.freeze_step_entry(c, old, active)
+                for lay, c, old in zip(self.layouts(new_cache), new_cache,
+                                       prev_cache)]
 
     # -- placement / accounting ------------------------------------------
     def field_axes(self, field: str):
@@ -140,32 +199,45 @@ class CacheLayout:
             "unknown decode-cache field %r for layout %r"
             % (field, self.name))
 
+    def entry_dtype_str(self, c) -> str:
+        return str(np.dtype(c.k.dtype))
+
     def cache_dtype_str(self, cache) -> str:
         """Payload dtype as provenance (``cache_stats`` /
-        ``config_fingerprint`` stamp this)."""
-        return str(np.dtype(cache[0].k.dtype))
+        ``config_fingerprint`` stamp this): every entry's own, each
+        named once."""
+        return "+".join(dict.fromkeys(
+            lay.entry_dtype_str(c)
+            for lay, c in zip(self.layouts(cache), cache)))
 
-    def state_bytes_per_slot(self, cache, slots: int, max_len: int) -> int:
-        """Decode-state bytes ONE slot pins at full span — the
-        denominator of the slots-per-GB capacity figure.  For the
-        positional layouts this is the dense-equivalent per-slot K/V
-        slab (scales included): what admitting one more concurrent
+    def entry_bytes_per_slot(self, c, slots: int, max_len: int) -> int:
+        """Decode-state bytes ONE slot pins at full span in this entry.
+        For the positional layouts this is the dense-equivalent per-slot
+        K/V slab (scales included): what admitting one more concurrent
         request costs in HBM when every request can run to max_len."""
         total = 0
-        for c in cache:
-            for field in ("k", "v", "k_scale", "v_scale"):
-                a = getattr(c, field, None)
-                if a is None:
-                    continue
-                per_tok = int(np.prod(a.shape)) * a.dtype.itemsize
-                # dense: [slots, H, max_len, D] -> bytes / slots.
-                # paged: [blocks, H, bs, D] -> bytes-per-token * max_len
-                if self.paged:
-                    tokens = int(a.shape[0]) * int(a.shape[2])
-                    total += per_tok // tokens * max_len
-                else:
-                    total += per_tok // int(slots)
+        for field in ("k", "v", "k_scale", "v_scale"):
+            a = getattr(c, field, None)
+            if a is None:
+                continue
+            per_tok = int(np.prod(a.shape)) * a.dtype.itemsize
+            # dense: [slots, H, max_len, D] -> bytes / slots.
+            # paged: [blocks, H, bs, D] -> bytes-per-token * max_len
+            if self.paged:
+                tokens = int(a.shape[0]) * int(a.shape[2])
+                total += per_tok // tokens * max_len
+            else:
+                total += per_tok // int(slots)
         return total
+
+    def bytes_per_slot_by_kind(self, cache, slots: int, max_len: int) -> dict:
+        """``{layout name: (layers, bytes a slot)}`` over the entries."""
+        out: dict = {}
+        for lay, c in zip(self.layouts(cache), cache):
+            n, b = out.get(lay.name, (0, 0))
+            out[lay.name] = (n + 1, b + lay.entry_bytes_per_slot(
+                c, slots, max_len))
+        return out
 
     def fingerprint_extra(self, pool) -> dict:
         """Layout-private geometry for ``config_fingerprint()`` — keys
@@ -179,20 +251,16 @@ class DenseLayout(CacheLayout):
 
     name = "dense"
 
-    def insert_row(self, pool_cache, row_cache, slot, length, blocks=None):
-        out = []
-        for cp, cr in zip(pool_cache, row_cache):
-            upd = dict(
-                k=cp.k.at[slot].set(cr.k[0].astype(cp.k.dtype)),
-                v=cp.v.at[slot].set(cr.v[0].astype(cp.v.dtype)),
-                index=cp.index.at[slot].set(
-                    jnp.asarray(length, jnp.int32)))
-            if cp.k_scale is not None:
-                upd.update(
-                    k_scale=cp.k_scale.at[slot].set(cr.k_scale[0]),
-                    v_scale=cp.v_scale.at[slot].set(cr.v_scale[0]))
-            out.append(cp._replace(**upd))
-        return out
+    def insert_entry(self, cp, cr, slot, length, blocks=None):
+        upd = dict(
+            k=cp.k.at[slot].set(cr.k[0].astype(cp.k.dtype)),
+            v=cp.v.at[slot].set(cr.v[0].astype(cp.v.dtype)),
+            index=cp.index.at[slot].set(jnp.asarray(length, jnp.int32)))
+        if cp.k_scale is not None:
+            upd.update(
+                k_scale=cp.k_scale.at[slot].set(cr.k_scale[0]),
+                v_scale=cp.v_scale.at[slot].set(cr.v_scale[0]))
+        return cp._replace(**upd)
 
 
 class PagedLayout(CacheLayout):
@@ -203,30 +271,27 @@ class PagedLayout(CacheLayout):
     name = "paged"
     paged = True
     spillable = True
+    transferable = True
 
-    def insert_row(self, pool_cache, row_cache, slot, length, blocks=None):
+    def insert_entry(self, cp, cr, slot, length, blocks=None):
         # the row cache is an identity-tabled batch-1 pool (row block
         # 1+j holds logical block j), so the splice is ONE scatter
         # copying every logical block to the physical ids in ``blocks``;
         # entries past the reservation are 0, harmlessly dumping their
         # pad-garbage blocks into the scratch block
-        out = []
-        for cp, cr in zip(pool_cache, row_cache):
-            upd = dict(
-                k=cp.k.at[blocks].set(cr.k[1:].astype(cp.k.dtype)),
-                v=cp.v.at[blocks].set(cr.v[1:].astype(cp.v.dtype)),
-                table=cp.table.at[slot].set(blocks),
-                index=cp.index.at[slot].set(
-                    jnp.asarray(length, jnp.int32)))
-            if cp.k_scale is not None:
-                # int8 cache: the row's per-block scales splice with
-                # their blocks (same ids), so a spliced block can never
-                # be read under another request's scale
-                upd.update(
-                    k_scale=cp.k_scale.at[blocks].set(cr.k_scale[1:]),
-                    v_scale=cp.v_scale.at[blocks].set(cr.v_scale[1:]))
-            out.append(cp._replace(**upd))
-        return out
+        upd = dict(
+            k=cp.k.at[blocks].set(cr.k[1:].astype(cp.k.dtype)),
+            v=cp.v.at[blocks].set(cr.v[1:].astype(cp.v.dtype)),
+            table=cp.table.at[slot].set(blocks),
+            index=cp.index.at[slot].set(jnp.asarray(length, jnp.int32)))
+        if cp.k_scale is not None:
+            # int8 cache: the row's per-block scales splice with
+            # their blocks (same ids), so a spliced block can never
+            # be read under another request's scale
+            upd.update(
+                k_scale=cp.k_scale.at[blocks].set(cr.k_scale[1:]),
+                v_scale=cp.v_scale.at[blocks].set(cr.v_scale[1:]))
+        return cp._replace(**upd)
 
     def fingerprint_extra(self, pool) -> dict:
         return {"block_size": pool._block_size,
@@ -235,13 +300,15 @@ class PagedLayout(CacheLayout):
 
 class RecurrentLayout(CacheLayout):
     """Constant-size recurrence carry: O(1) state per token, no block
-    table, no paging, no prefix tree.  Two caches live on it:
-    ``nn.ssm.RecurrentDecodeCache`` (``state [B, d_state]``) and
+    table, no paging, no prefix tree.  Three caches live on it:
+    ``nn.ssm.RecurrentDecodeCache`` (``state [B, d_state]``),
     ``nn.RetentionDecodeCache`` (``state [B, Hkv, dv, D]`` and ``norm [B,
-    Hkv, 1, D]``).  Every field of a layer's cache but ``index`` and
+    Hkv, 1, D]``) and ``nn.MambaDecodeCache`` (``conv [B, (K-1) * C]`` and
+    ``ssm [B, N, C]``).  Every field of a layer's cache but ``index`` and
     ``limit`` is a STATE FIELD, slots leading (:meth:`state_fields`): the
-    splice, the spill and the accounting run over them all, so a cache of
-    another shape needs no code here.
+    splice, the spill, the placement and the accounting run over them
+    all, whatever they are called, so a cache of another shape needs no
+    code here.
 
     ``limit`` is the layout's pad-garbage discipline.  A positional
     cache can write garbage K/V for padded bucket positions because the
@@ -250,96 +317,129 @@ class RecurrentLayout(CacheLayout):
     the prefill hook narrows the update window to the true prompt
     length (positions past it are identity steps), and finalize re-opens
     it to max_len for decode.  A pool's step closes it on its free slots
-    (:meth:`begin_step`): the rows the step must not move are identity
-    steps INSIDE the recurrence, so nothing of the size of the pool's
-    state is ever selected or copied to put them back.
+    (:meth:`begin_step_entry`): the rows the step must not move are
+    identity steps INSIDE the recurrence, so nothing of the size of the
+    pool's state is ever selected or copied to put them back.
     """
 
     name = "recurrent"
     positional = False
     spillable = True
+    transferable = True
+    recurrent = True
 
     @staticmethod
     def state_fields(layer_cache) -> tuple:
         return tuple(f for f in layer_cache._fields
                      if f not in ("index", "limit"))
 
-    def begin_prefill(self, cache, true_len):
+    def begin_prefill_entry(self, c, true_len):
         # the window narrows to the true length; and a cache type that
         # asks for it (``empty_as_none``) is told STATICALLY that the
         # prefill starts from nothing: its state fields go in as None,
         # so a layer need not read (or multiply by) a state of zeros
-        out = []
-        for c in cache:
-            c = c._replace(limit=true_len)
-            if getattr(c, "empty_as_none", False):
-                c = c._replace(**{f: None for f in self.state_fields(c)})
-            out.append(c)
-        return out
+        c = c._replace(limit=true_len)
+        if getattr(c, "empty_as_none", False):
+            c = c._replace(**{f: None for f in self.state_fields(c)})
+        return c
 
-    def finalize_prefill(self, cache, true_len, max_len):
-        lim = jnp.asarray(max_len, jnp.int32)
-        return [c._replace(index=true_len, limit=lim) for c in cache]
+    def finalize_prefill_entry(self, c, true_len, max_len):
+        return c._replace(index=true_len,
+                          limit=jnp.asarray(max_len, jnp.int32))
 
-    def begin_step(self, cache, active):
+    def begin_step_entry(self, c, active):
         """The update window of a pool's step, per slot: a free slot's is
         closed, so its row is an identity step of the recurrence."""
-        return [c._replace(limit=jnp.where(active, c.limit, 0))
-                for c in cache]
+        return c._replace(limit=jnp.where(active, c.limit, 0))
 
-    def insert_row(self, pool_cache, row_cache, slot, length, blocks=None):
-        out = []
-        for cp, cr in zip(pool_cache, row_cache):
-            upd = {f: getattr(cp, f).at[slot].set(
-                       getattr(cr, f)[0].astype(getattr(cp, f).dtype))
-                   for f in self.state_fields(cp)}
-            out.append(cp._replace(
-                index=cp.index.at[slot].set(jnp.asarray(length, jnp.int32)),
-                **upd))
-        return out
+    def insert_entry(self, cp, cr, slot, length, blocks=None):
+        upd = {f: getattr(cp, f).at[slot].set(
+                   getattr(cr, f)[0].astype(getattr(cp, f).dtype))
+               for f in self.state_fields(cp)}
+        return cp._replace(
+            index=cp.index.at[slot].set(jnp.asarray(length, jnp.int32)),
+            **upd)
 
-    def freeze_step(self, new_cache, prev_cache, active):
+    def freeze_step_entry(self, c, old, active):
         # the recurrence updates EVERY row's carry every step, and an
         # inactive slot's update would fold its stale last token into
         # state a resumed/refilled request then inherits.  It did not:
         # ``begin_step`` closed those rows' windows, so their carry came
         # through the step untouched.  What is put back is the index and
         # the window itself
-        return [c._replace(
-            index=jnp.where(active, c.index, old.index), limit=old.limit)
-            for c, old in zip(new_cache, prev_cache)]
+        return c._replace(index=jnp.where(active, c.index, old.index),
+                          limit=old.limit)
 
     def field_axes(self, field: str):
-        if field in ("state", "norm"):
-            # slots over dp; a slot's state stays whole (replicated
-            # within an mp group)
-            return ("dp", None)
         if field == "index":
             return ("dp",)
         if field == "limit":
             return ()  # scalar window bound: replicated
-        raise InvalidArgumentError(
-            "unknown decode-cache field %r for layout 'recurrent'"
-            % (field,))
+        if field in ("k", "v", "k_scale", "v_scale", "table"):
+            raise InvalidArgumentError(
+                "unknown decode-cache field %r for layout 'recurrent'"
+                % (field,))
+        # a state field, whatever it is called: slots over dp; a slot's
+        # state stays whole (replicated within an mp group)
+        return ("dp", None)
 
-    def cache_dtype_str(self, cache) -> str:
-        return str(np.dtype(cache[0].state.dtype))
+    def entry_dtype_str(self, c) -> str:
+        return "+".join(dict.fromkeys(
+            str(np.dtype(getattr(c, f).dtype))
+            for f in self.state_fields(c)))
 
-    def state_bytes_per_slot(self, cache, slots: int, max_len: int) -> int:
+    def entry_bytes_per_slot(self, c, slots: int, max_len: int) -> int:
         # constant in max_len — the whole point
-        return sum(
-            int(np.prod(a.shape)) * a.dtype.itemsize // int(slots)
-            for c in cache
-            for a in (getattr(c, f) for f in self.state_fields(c)))
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize // int(slots)
+                   for a in (getattr(c, f) for f in self.state_fields(c)))
 
     def fingerprint_extra(self, pool) -> dict:
         # the shape of every state field of a slot: a toy recurrence's
         # spill file ([d_state]) can never be adopted by a model whose
         # state is a matrix a head, nor the other way round
-        first = pool._cache[0]
-        return {"d_state": int(first.state.shape[-1]),
+        first = pool._layout.entries(pool._cache, self.name)[0]
+        fields = self.state_fields(first)
+        return {"d_state": int(getattr(first, fields[0]).shape[-1]),
                 "state_shapes": [list(getattr(first, f).shape[1:])
-                                 for f in self.state_fields(first)]}
+                                 for f in fields]}
+
+
+class ComposedLayout(CacheLayout):
+    """The layout of a cache whose entries are not all of one kind: the
+    entries' own layouts in order, and the meet of their capabilities.
+    Built by :func:`layout_of` from the cache a model hands out; there is
+    nothing to register and nothing a user names."""
+
+    def __init__(self, layouts):
+        self._layouts = tuple(layouts)
+        kinds = sorted({lay.name for lay in self._layouts})
+        self.name = "+".join(kinds)
+        self.positional = all(lay.positional for lay in self._layouts)
+        self.paged = any(lay.paged for lay in self._layouts)
+        self.spillable = all(lay.spillable for lay in self._layouts)
+        self.recurrent = any(lay.recurrent for lay in self._layouts)
+        # a PTKV file carries blocks OR state rows, never both
+        self.transferable = False
+
+    def layouts(self, cache) -> tuple:
+        if len(cache) != len(self._layouts):
+            raise InvalidArgumentError(
+                "a cache of %d entries under a layout composed for %d"
+                % (len(cache), len(self._layouts)))
+        return self._layouts
+
+    def recurrent_layers(self) -> str:
+        at = [i for i, lay in enumerate(self._layouts) if lay.recurrent]
+        return ("%d of the %d layers keep a state of constant size (layers "
+                "%s%s)" % (len(at), len(self._layouts),
+                           ", ".join(map(str, at[:4])),
+                           ", ..." if len(at) > 4 else ""))
+
+    def fingerprint_extra(self, pool) -> dict:
+        out = {}
+        for lay in dict.fromkeys(self._layouts):
+            out.update(lay.fingerprint_extra(pool))
+        return out
 
 
 CACHE_LAYOUTS = {
@@ -358,3 +458,24 @@ def get_layout(name: str) -> CacheLayout:
             "cache_layout must be one of %s, got %r"
             % (sorted(CACHE_LAYOUTS), name))
     return layout
+
+
+def entry_layout(entry) -> CacheLayout:
+    """The layout of ONE layer's cache entry, from its type: a block
+    ``table`` is paged, an update window ``limit`` recurrent, else dense
+    K/V."""
+    fields = getattr(entry, "_fields", ())
+    if "table" in fields:
+        return CACHE_LAYOUTS["paged"]
+    if "limit" in fields:
+        return CACHE_LAYOUTS["recurrent"]
+    return CACHE_LAYOUTS["dense"]
+
+
+def layout_of(cache) -> CacheLayout:
+    """The layout of a whole cache list: the registered singleton where
+    every entry is of one kind, a :class:`ComposedLayout` otherwise."""
+    layouts = [entry_layout(c) for c in cache]
+    if len(set(layouts)) == 1:
+        return layouts[0]
+    return ComposedLayout(layouts)

@@ -430,7 +430,7 @@ class DecodeSession:
         # layout); "recurrent" is the O(1)-state carry of SSM decoders
         # (nn.ssm.SSMLM).  All compile exactly two functions per bucket
         # and are token-identical under greedy decoding.
-        from .cache import get_layout
+        from .cache import get_layout, layout_of
 
         self._layout = get_layout(cache_layout)
         supported = getattr(model, "cache_layouts", ("dense", "paged"))
@@ -449,6 +449,15 @@ class DecodeSession:
                 "block_size must be >= 1, got %r" % (block_size,))
         self.cache_layout = cache_layout
         self.block_size = int(block_size)
+        # ``cache_layout`` is what the caller chose; what the hooks
+        # dispatch on is the ENTRIES the model hands out for it: one kind
+        # throughout gives the registered singleton back, a model that
+        # mixes kinds (K/V in some layers, a recurrent state in others) a
+        # layout composed of its entries' own (jit.cache.layout_of)
+        self._layout = layout_of(jax.eval_shape(
+            lambda: model.gen_decode_cache(
+                1, self.max_len, self._cache_dtype, layout=cache_layout,
+                block_size=self.block_size)))
         if donate is None:
             donate = jax.default_backend() != "cpu"
         # argnum 2 = the cache pytree: every decode step consumes its

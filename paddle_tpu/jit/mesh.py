@@ -129,36 +129,24 @@ class DecodeMesh:
         return jax.device_put(arr, self.sharding(*axes))
 
     # -- cache placement -------------------------------------------------
-    def cache_field_axes(self, field: str):
-        """The partition axes for one decode-cache field (dense, paged
-        or recurrent — the leading axis is slots or blocks, both 'dp';
-        the head axis is 'mp'; the table/index carry only the slot
-        axis; a recurrence state shards slots over 'dp' with the state
-        vector whole per slot, and its scalar window bound
-        replicates)."""
-        if field in ("k", "v", "k_scale", "v_scale"):
-            return ("dp", "mp")
-        if field in ("table", "index"):
-            return ("dp",)
-        if field in ("state", "norm"):
-            return ("dp", None)
-        if field == "limit":
-            return ()
-        raise InvalidArgumentError(
-            "unknown decode-cache field %r" % (field,))
-
     def place_cache(self, cache):
         """Place every layer's cache entry by the axis rules; None
         leaves (float caches' scales) stay None.  Returns the placed
         pytree (same namedtuple types)."""
+        from .cache import entry_layout
+
         out = []
         for c in cache:
+            # the axis rules are the ENTRY's layout's (jit.cache: K/V
+            # ('dp', 'mp'), table/index ('dp'), a recurrent state field
+            # ('dp', None) with its scalar window replicated)
+            axes = entry_layout(c).field_axes
             upd = {}
             for field in c._fields:
                 a = getattr(c, field)
                 if a is None:
                     continue
-                upd[field] = self.place(a, *self.cache_field_axes(field))
+                upd[field] = self.place(a, *axes(field))
             out.append(c._replace(**upd))
         return out
 

@@ -166,6 +166,14 @@ class SpeculativeDecodeSession:
             cache_dtype=cache_dtype, donate=donate,
             cache_layout=cache_layout, block_size=block_size,
             route=route)
+        if not self._target._layout.positional:
+            raise InvalidArgumentError(
+                "speculative decoding does not support cache_layout=%r "
+                "(%s): verify-rewind moves a positional index back over "
+                "rejected drafts, and a recurrent state has no earlier "
+                "position to go back to"
+                % (self._target._layout.name,
+                   self._target._layout.recurrent_layers()))
         self._draft = DecodeSession(
             draft_model, max_len, buckets=buckets, temperature=0.0,
             donate=donate, route=route)

@@ -10,6 +10,10 @@ from .block_diffusion import (  # noqa: F401
     BlockDiffusionDecoderLayer,
     BlockDiffusionMoELM,
 )
+from .hybrid_mamba import (  # noqa: F401
+    HybridMambaDecoderLayer,
+    HybridMambaLM,
+)
 from .power_retention import (  # noqa: F401
     PowerRetentionDecoderLayer,
     PowerRetentionLM,

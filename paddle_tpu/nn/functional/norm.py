@@ -39,11 +39,13 @@ def rms_norm(x, weight=None, epsilon: float = 1e-6):
     ``x / sqrt(mean(x^2) + epsilon) * weight``, no mean taken off and no
     offset.  The statistic is taken in float32 whatever ``x`` is stored
     in, and the result goes back to ``x``'s type before the learned
-    scale, as the Llama/Qwen family's layers do."""
+    scale, as the Llama/Qwen family's layers do.  A scale kept in a wider
+    type than ``x`` (float32 under bfloat16 activations) multiplies in its
+    own type and the product goes back to ``x``'s."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     out = (xf * jax.lax.rsqrt(var + epsilon)).astype(x.dtype)
-    return out if weight is None else out * weight
+    return out if weight is None else (out * weight).astype(x.dtype)
 
 
 def batch_norm_stats(x, data_format: str = "NCHW"):

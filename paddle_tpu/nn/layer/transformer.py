@@ -500,7 +500,9 @@ class GroupedQueryAttention(MultiHeadAttention):
     """Self-attention of the Llama/Qwen3 family: ``num_heads`` query heads
     on ``num_kv_heads`` K/V heads of ``head_dim`` (query head ``n`` reads
     K/V head ``n // (num_heads / num_kv_heads)``), RMSNorm over each
-    head's channels of q and k (``qk_norm``), rotary positions, no bias.
+    head's channels of q and k (``qk_norm``), rotary positions
+    (``rope_theta=None``: no position term at all, for a model whose other
+    layers carry position), no bias.
 
     The decode caches (``gen_decode_cache``, dense and paged) hold the
     K/V heads, so their bytes and the decode step's reads fall by the
@@ -512,7 +514,7 @@ class GroupedQueryAttention(MultiHeadAttention):
     with and without a cache."""
 
     def __init__(self, embed_dim: int, num_heads: int, num_kv_heads: int,
-                 head_dim: int, rope_theta: float = 10000.0,
+                 head_dim: int, rope_theta: Optional[float] = 10000.0,
                  qk_norm: bool = True, norm_epsilon: float = 1e-6,
                  block_length: Optional[int] = None):
         Layer.__init__(self)
@@ -520,7 +522,7 @@ class GroupedQueryAttention(MultiHeadAttention):
             raise InvalidArgumentError(
                 "num_heads %d is not a whole multiple of num_kv_heads %d"
                 % (num_heads, num_kv_heads))
-        if head_dim % 2:
+        if rope_theta is not None and head_dim % 2:
             raise InvalidArgumentError(
                 "rotary positions turn pairs of channels: head_dim %d is "
                 "odd" % head_dim)
@@ -528,7 +530,7 @@ class GroupedQueryAttention(MultiHeadAttention):
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.head_dim = head_dim
         self.dropout, self.need_weights = 0.0, False
-        self.rope_theta = float(rope_theta)
+        self.rope_theta = None if rope_theta is None else float(rope_theta)
         self.block_length = None if block_length is None \
             else int(block_length)
         self.q_proj = Linear(embed_dim, num_heads * head_dim,
@@ -587,9 +589,10 @@ class GroupedQueryAttention(MultiHeadAttention):
             idx = jnp.asarray(cache.index, jnp.int32)
             pos = idx + steps if idx.ndim == 0 \
                 else idx[:, None] + steps[None, :]
-        with jax.named_scope("rope"):
-            q = F.rotary_embedding(q, pos, self.rope_theta)
-            k = F.rotary_embedding(k, pos, self.rope_theta)
+        if self.rope_theta is not None:
+            with jax.named_scope("rope"):
+                q = F.rotary_embedding(q, pos, self.rope_theta)
+                k = F.rotary_embedding(k, pos, self.rope_theta)
         return q, k, v, pos
 
     def forward(self, x, attn_mask=None, cache=None):

@@ -133,9 +133,11 @@ class SSMLM(Layer):
     The ``TransformerLM`` of the O(1)-cache class: same
     ``forward(input_ids, cache=...)`` / ``gen_decode_cache`` surface,
     so ``DecodeSession``/``GenerationPool``/``ServingEngine`` serve it
-    unchanged — but its only cache layout is ``"recurrent"`` (a typed
-    error names the mismatch for any other, and ``cache_layouts``
-    advertises the supported set the session checks at construction).
+    unchanged — but every layer's cache entry is of the ``"recurrent"``
+    layout (a typed error names the mismatch for any other, and
+    ``cache_layouts`` advertises the supported set the session checks at
+    construction; a model whose layers keep different kinds of entry is
+    ``models.HybridMambaLM``, ``jit.cache.layout_of``).
     No position embeddings: position is implicit in the recurrence, so
     ``max_len`` is bounded only by the caller's budget, not a table.
     """

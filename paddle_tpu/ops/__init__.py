@@ -32,6 +32,11 @@ from .power_retention import (  # noqa: F401
     power_retention_step,
     symmetric_square,
 )
+from .selective_scan import (  # noqa: F401
+    selective_scan_prefill,
+    selective_scan_reference,
+    selective_scan_step,
+)
 
 __all__ = ["flash_attention", "flash_attention_supported",
            "decode_attention", "decode_attention_supported",
@@ -41,4 +46,5 @@ __all__ = ["flash_attention", "flash_attention_supported",
            "decode_route", "normalize_decode_route", "DECODE_ROUTES",
            "reset_backend_memo", "power_retention_chunked",
            "power_retention_step", "power_retention_quadratic",
-           "symmetric_square"]
+           "symmetric_square", "selective_scan_prefill",
+           "selective_scan_reference", "selective_scan_step"]

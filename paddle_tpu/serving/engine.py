@@ -565,7 +565,7 @@ class ServingEngine:
             "serving_spilled_blocks",
             "paged KV blocks in the reclaimable spilled tier "
             "(device-resident copies of preempted requests' K/V)") \
-            if self._pool.cache_layout == "paged" else None
+            if self._pool._layout.paged else None
         self._g_degrade = m.gauge(
             "serving_degrade_level",
             "degradation ladder level (0 normal, 1 preempt, "
@@ -621,11 +621,18 @@ class ServingEngine:
             "serving_state_bytes_per_slot",
             "bytes of recurrent state one slot holds, every layer (a "
             "constant of the model, whatever the context)") \
-            if self._pool.cache_layout == "recurrent" else None
+            if self._pool._layout.recurrent else None
+        self._g_cache_layers = {
+            kind: m.gauge(
+                "serving_cache_layers",
+                "layers whose decode cache is of each layout (a model "
+                "that mixes kinds has more than one series)",
+                labels={"layout": kind})
+            for kind in self._pool.cache_stats()["cache_layers"]}
         self._g_kv_free = m.gauge(
             "serving_kv_free_blocks",
             "paged allocator free blocks") \
-            if self._pool.cache_layout == "paged" else None
+            if self._pool._layout.paged else None
         # sharded-serving surface (docs §5k): gauges exist only when
         # the pool runs over a DecodeMesh, like the paged-only gauges.
         # The per-shard resident gauge is the satellite fix: a
@@ -2457,7 +2464,9 @@ class ServingEngine:
         self._g_kv_bytes.set(stats["reachable_bytes"])
         self._g_kv_resident.set(stats["pool_bytes"])
         if self._g_state_slot is not None:
-            self._g_state_slot.set(stats["state_bytes_per_slot"])
+            self._g_state_slot.set(stats["bytes_per_slot"]["recurrent"])
+        for kind, g in self._g_cache_layers.items():
+            g.set(stats["cache_layers"][kind])
         if self._g_kv_free is not None:
             self._g_kv_free.set(stats["free_blocks"])
         if self._g_kv_resident_shard is not None:

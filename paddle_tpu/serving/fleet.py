@@ -1028,8 +1028,11 @@ class ServingFleet:
                 name, {"kind": kind, "help": help_, "series": []})
             g["series"].append((labels, metric))
 
-        for name, metric in self.metrics._metrics.items():
-            add(name, metric.kind, metric.help, None, metric)
+        # a series with labels of its own (``gauge(labels=...)``) is
+        # registered under name + labels: its family is ``metric.name``
+        for metric in self.metrics._metrics.values():
+            add(metric.name, metric.kind, metric.help,
+                metric.labels[1:-1] or None, metric)
         for reason in sorted(self._routed):
             add("fleet_requests_routed_total", "counter",
                 "requests placed by the router, by decision reason",
@@ -1038,8 +1041,10 @@ class ServingFleet:
         for eid in sorted(self._handles):
             h = self._handles[eid]
             lab = 'engine="%s"' % escape_label_value(str(eid))
-            for name, metric in h.registry._metrics.items():
-                add(name, metric.kind, metric.help, lab, metric)
+            for metric in h.registry._metrics.values():
+                own = metric.labels[1:-1]
+                add(metric.name, metric.kind, metric.help,
+                    lab + ("," + own if own else ""), metric)
 
         lines: List[str] = []
         for name, g in groups.items():

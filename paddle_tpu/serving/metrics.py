@@ -82,6 +82,10 @@ class _Metric:
                 "([a-zA-Z_:][a-zA-Z0-9_:]*)" % (name,))
         self.name = name
         self.help = help
+        #: ``{key="value",...}`` as the exposition prints it after the
+        #: name; "" for a metric without labels (``MetricsRegistry.gauge``
+        #: with ``labels=``: one series of a family each)
+        self.labels = ""
 
 
 class Counter(_Metric):
@@ -218,8 +222,21 @@ class MetricsRegistry:
     def counter(self, name: str, help: str = "") -> Counter:
         return self._get(Counter, name, help)
 
-    def gauge(self, name: str, help: str = "") -> Gauge:
-        return self._get(Gauge, name, help)
+    def gauge(self, name: str, help: str = "",
+              labels: Optional[Dict[str, str]] = None) -> Gauge:
+        """``labels``: one series of the family ``name``, registered (and
+        snapshot) under ``name{key="value"}``."""
+        if not labels:
+            return self._get(Gauge, name, help)
+        tag = "{%s}" % ",".join(
+            '%s="%s"' % (k, escape_label_value(v))
+            for k, v in sorted(labels.items()))
+        m = self._metrics.get(name + tag)
+        if m is None:
+            m = Gauge(name, help)
+            m.labels = tag
+            self._metrics[name + tag] = m
+        return m
 
     def histogram(self, name: str, help: str = "",
                   buckets: Sequence[float] = DEFAULT_TIME_BUCKETS
@@ -251,11 +268,14 @@ class MetricsRegistry:
         plus ``"`` in label values) — a metric whose help text quotes an
         error message must not be able to corrupt the whole scrape."""
         lines = []
+        headed = set()
         for m in self._metrics.values():
-            if m.help:
-                lines.append("# HELP %s %s"
-                             % (m.name, escape_help(m.help)))
-            lines.append("# TYPE %s %s" % (m.name, m.kind))
+            if m.name not in headed:     # once a family
+                headed.add(m.name)
+                if m.help:
+                    lines.append("# HELP %s %s"
+                                 % (m.name, escape_help(m.help)))
+                lines.append("# TYPE %s %s" % (m.name, m.kind))
             if isinstance(m, Histogram):
                 running = 0
                 for b, c in zip(m.buckets, m._counts):
@@ -269,5 +289,5 @@ class MetricsRegistry:
                 lines.append("%s_sum %s" % (m.name, _fmt(m.sum)))
                 lines.append("%s_count %d" % (m.name, m.count))
             else:
-                lines.append("%s %s" % (m.name, _fmt(m.value)))
+                lines.append("%s%s %s" % (m.name, m.labels, _fmt(m.value)))
         return "\n".join(lines) + "\n"

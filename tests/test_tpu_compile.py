@@ -226,3 +226,58 @@ def test_retention_step_on_the_v5e_updates_the_state_where_it_lies(
     # every byte of S and z goes out in the buffer it came in
     state_bytes = LAYERS * 16 * pr.state_bytes(8, 128, 128)
     assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes
+
+
+def test_hybrid_decode_step_on_the_v5e_updates_both_states_in_place(
+        one_chip, monkeypatch):
+    """``jamba2-3b``'s step at its widths and cache geometry, three layers
+    (Mamba, attention, Mamba): 64 slots, ``ssm`` [64, 16, 5120] float32 and
+    ``conv`` [64, 3, 5120] bfloat16 beside a paged bfloat16 pool of one K/V
+    head under 20 query heads (20 rows a block of the kernel: the block is
+    the whole array's rows, so Mosaic takes it unpadded).  One custom call
+    a layer, no ``copy`` of a scan state or of the K/V pool, and every
+    state byte goes out in the buffer it came in."""
+    import jax
+
+    from paddle_tpu.models import HybridMambaLM
+    from paddle_tpu.ops import selective_scan as ss
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    pt.seed(0)
+    model = HybridMambaLM(vocab_size=512, hidden_size=2560, num_layers=3,
+                          num_heads=20, num_kv_heads=1, head_dim=128,
+                          intermediate_size=8192, attn_layer_period=3,
+                          attn_layer_offset=1)
+    model.eval()
+    pool = GenerationPool(model, max_len=2304, slots=64, buckets=[1024],
+                          cache_layout="paged", block_size=128,
+                          cache_dtype="bfloat16")
+    assert pool.cache_layout == "paged+recurrent"
+    n = pool.slots
+    params, bufs = pool._session._state_vals()
+    samp = (np.zeros(n, np.float32), np.zeros(n, np.int32),
+            np.ones(n, np.float32), np.zeros(n, np.uint32))
+    args = (params, bufs, pool._cache, np.zeros(n, np.int32),
+            np.ones(n, bool), samp, np.zeros(n, np.uint32),
+            np.zeros(n, np.int32))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip), args)
+    monkeypatch.setattr(fa, "_backend_memo", "tpu")
+    ssm_shape = pool._cache[0].ssm.shape
+    kv_shape = pool._cache[1].k.shape
+    assert ssm_shape == (64, 16, 5120)
+    assert kv_shape == (64 * 18 + 1, 1, 128, 128)
+    assert ss.step_kernel_refusal(ssm_shape) is None
+    compiled = jax.jit(pool._pool_decode, donate_argnums=(2,)) \
+        .lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    made = {op for _, op in chip_smoke.pool_shaped_ops(text, ssm_shape)}
+    assert made <= {"parameter", "get-tuple-element", "custom-call",
+                    "bitcast"}, sorted(made)
+    assert chip_smoke.pool_shaped_moves(text, kv_shape) == []
+    assert chip_smoke.pool_shaped_moves(text, pool._cache[0].conv.shape) \
+        == []
+    state_bytes = 2 * 64 * 16 * 5120 * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes
