@@ -47,7 +47,7 @@ temperature, LoRA adapters.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -192,9 +192,6 @@ class BlockDiffusionPool(GenerationPool):
         self.forwards_denoise = 0
         self.forwards_store = 0
         self.tokens_committed = 0
-        # the denoising step that committed the token ``on_token`` is
-        # being called with (read by the engine inside that call)
-        self.token_commit_step: Optional[int] = None
 
     # -- traced bodies ---------------------------------------------------
     def _block_prefill(self, param_vals, buf_vals, ids, whole):

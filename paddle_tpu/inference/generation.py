@@ -622,6 +622,11 @@ class GenerationPool:
         # fresh as a recomputed one
         self._prefix_epoch = 0
         self._head_match = None
+        # the allocator's version (``alloc_version``): every slot take
+        # and release and every block allocation, free, share, spill and
+        # resume bumps it, so whoever reads ``cache_stats()`` a tick can
+        # tell by one int compare that it would read what it read
+        self._alloc_version = 0
         self._prefix_queries = 0
         self._prefix_hits = 0
         self._prefix_tokens_matched = 0
@@ -769,14 +774,27 @@ class GenerationPool:
         self._finish_reasons: Dict[object, str] = {}
         # serving-layer lifecycle hooks (paddle_tpu.serving sets these):
         # on_admit(rid, slot, prompt_len) when a queued request takes a
-        # slot; on_token(rid, token) for EVERY emitted token including
-        # the prefill's first; on_finish(rid, tokens, reason) when a
-        # request completes (NOT on cancel/release — aborting is the
-        # caller's act, not a completion).  Hooks fire inside step(), so
-        # the timings they record come from the real code path.
+        # slot; on_tokens(batch) ONCE for the tokens a download
+        # delivered, the prefills' first tokens among them, ``batch``
+        # the ``(rid, token, commit_step)`` triples in the order they
+        # were committed (a request's in its own order, 0..n of them;
+        # ``commit_step`` is the block pool's, else None);
+        # on_finish(rid, tokens, reason) after that call, when a request
+        # completes (NOT on cancel/release — aborting is the caller's
+        # act, not a completion).  Hooks fire inside step(), so the
+        # timings they record come from the real code path.  A pool used
+        # alone may set on_token(rid, token) instead: the batch hook a
+        # pool starts with hands it every token in turn.
         self.on_admit = None
         self.on_token = None
+        self.on_tokens = self._each_token
         self.on_finish = None
+        # the tokens committed and not yet handed on, and the slots
+        # whose request ended on one of them (``_hand_on``); calls into
+        # the token hooks since the deliver phase began
+        self._out: List[tuple] = []
+        self._ending: List[int] = []
+        self._hook_calls = 0
         # ids currently queued/active/uncollected, maintained
         # incrementally so submit stays O(1) in a long-lived pool
         self._used_rids: set = set()
@@ -1207,6 +1225,7 @@ class GenerationPool:
         ``self._free.pop()`` order), restricted to ``shard`` when the
         paged allocator needs the slot's blocks in a specific
         partition.  Callers check availability first."""
+        self._alloc_version += 1
         if shard is None or self._dp == 1:
             return self._free.pop()
         for i in range(len(self._free) - 1, -1, -1):
@@ -1216,6 +1235,12 @@ class GenerationPool:
             "no free slot in dp shard %d (free slots: %s) — callers "
             "must check shard availability before popping"
             % (shard, sorted(self._free)))
+
+    def _free_slot(self, slot: int) -> None:
+        """Give ``slot`` back: every slot release, like every take,
+        moves the allocator's version."""
+        self._alloc_version += 1
+        self._free.append(slot)
 
     @property
     def _free_blocks(self) -> List[int]:
@@ -1256,6 +1281,7 @@ class GenerationPool:
         its host copy is the survivor, so the preempted request stays
         resumable, just via the upload path)."""
         self._prefix_epoch += 1
+        self._alloc_version += 1
         fl = self._free_by_shard[shard]
         blocks = []
         for _ in range(n):
@@ -1287,7 +1313,19 @@ class GenerationPool:
         sp.dev_blocks[j] = None
         self._spill_owner.pop(b, None)
         self._free_by_shard[shard].append(b)
+        self._alloc_version += 1
         self._spill_reclaims_total += 1
+
+    def _drop_device_copies(self, sp) -> None:
+        """Return a parked victim's still-device-resident spilled blocks
+        to their free lists (its host copy, where one is kept, is the
+        survivor)."""
+        self._prefix_epoch += 1
+        self._alloc_version += 1
+        for b in sp.dev_blocks:
+            if b is not None:
+                self._spill_owner.pop(b, None)
+                self._free_by_shard[self._shard_of_block(b)].append(b)
 
     def _forget_block_key(self, b: int) -> None:
         """Remove ``b`` from the prefix index (an index entry must
@@ -1311,6 +1349,7 @@ class GenerationPool:
         if not self._layout.paged:
             return
         self._prefix_epoch += 1
+        self._alloc_version += 1
         for b in self._slot_blocks.pop(slot, ()):
             left = self._block_refs.get(b, 1) - 1
             if left > 0:
@@ -1326,7 +1365,7 @@ class GenerationPool:
         self._results[state.rid] = tokens
         reason = classify_finish(tokens, self.eos_id)
         self._finish_reasons[state.rid] = reason
-        self._free.append(slot)
+        self._free_slot(slot)
         # refcount-0 blocks are immediately reusable: the slot's stale
         # table row is masked to the scratch block inside every decode
         # step until a refill overwrites it; shared blocks stay resident
@@ -1350,7 +1389,7 @@ class GenerationPool:
                 "slot %r is not active or prefilling (active slots: "
                 "%s, prefilling: %s)"
                 % (slot, sorted(self._active), sorted(self._prefilling)))
-        self._free.append(slot)
+        self._free_slot(slot)
         self._release_blocks(slot)
         self._used_rids.discard(state.rid)
         return state.rid
@@ -1381,11 +1420,7 @@ class GenerationPool:
             # a parked victim dies in place: its still-device-resident
             # spilled blocks return to the free list, its host copies
             # drop with the record
-            self._prefix_epoch += 1
-            for b in sp.dev_blocks:
-                if b is not None:
-                    self._spill_owner.pop(b, None)
-                    self._free_by_shard[self._shard_of_block(b)].append(b)
+            self._drop_device_copies(sp)
             self._used_rids.discard(request_id)
             self._spill_drop(sp)
             return "preempted"
@@ -1395,7 +1430,7 @@ class GenerationPool:
             # slot and blocks free like an active cancel (no transfer
             # file exists yet — export_kv writes it)
             slot, _st = parked
-            self._free.append(slot)
+            self._free_slot(slot)
             self._release_blocks(slot)
             self._used_rids.discard(request_id)
             return "prefill-done"
@@ -1565,7 +1600,7 @@ class GenerationPool:
                 raise
             host = None  # the file is the survivor, not process RAM
         self._active.pop(slot)
-        self._free.append(slot)
+        self._free_slot(slot)
         self._prefix_epoch += 1
         sp = _SpillState(st, len(blocks), written, host, host_bytes,
                          shard=shard)
@@ -1617,7 +1652,7 @@ class GenerationPool:
             host_path = self._spill_write(st, host, written=0)
             host = None
         self._active.pop(slot)
-        self._free.append(slot)
+        self._free_slot(slot)
         sp = _SpillState(st, 0, 0, host, host_bytes,
                          shard=self._shard_of_slot(slot))
         sp.host_path = host_path
@@ -1704,12 +1739,7 @@ class GenerationPool:
             try:
                 host_src = self._spill_read(sp)
             except Exception:  # noqa: BLE001 - per-victim fallback
-                self._prefix_epoch += 1
-                for b in sp.dev_blocks:
-                    if b is not None:
-                        self._spill_owner.pop(b, None)
-                        self._free_by_shard[
-                            self._shard_of_block(b)].append(b)
+                self._drop_device_copies(sp)
                 self._spill_drop(sp)
                 self._used_rids.discard(sp.rid)
                 ids = np.concatenate(
@@ -2118,11 +2148,7 @@ class GenerationPool:
                 "file) — only disk-tier victims detach for migration"
                 % (request_id,))
         del self._spilled[request_id]
-        self._prefix_epoch += 1
-        for b in sp.dev_blocks:
-            if b is not None:
-                self._spill_owner.pop(b, None)
-                self._free_by_shard[self._shard_of_block(b)].append(b)
+        self._drop_device_copies(sp)
         self._used_rids.discard(request_id)
         path, sp.host_path = sp.host_path, None
         return {"rid": request_id, "path": path,
@@ -2184,7 +2210,7 @@ class GenerationPool:
                              for layer in host for arr in layer)
         path = self._spill_write(st, host, written, seam="xfer.write")
         del self._prefill_done[request_id]
-        self._free.append(slot)
+        self._free_slot(slot)
         self._release_blocks(slot)
         self._used_rids.discard(request_id)
         cfg = st.sampling if st.sampling is not None \
@@ -2355,13 +2381,14 @@ class GenerationPool:
             self._tok_dev, self._step_dev, np.int32(slot), tok,
             np.uint32(step))
 
-    def _place(self, arr):
-        """Upload one ``[slots]`` step vector; under a mesh committed to
-        its dp sharding up front: an uncommitted input would let the
-        compiled executable pick (and pay a reshard per call)."""
+    def _place(self, arrs):
+        """Upload ``[slots]`` step vectors, one or a list of them in ONE
+        transfer call; under a mesh committed to their dp sharding up
+        front: an uncommitted input would let the compiled executable
+        pick (and pay a reshard per call)."""
         if self._mesh is not None:
-            return self._mesh.place(arr, "dp")
-        return jnp.asarray(arr)
+            return self._mesh.place(arrs, "dp")
+        return jax.device_put(arrs)
 
     def _commit_first(self, slot: int, state: _SlotState,
                       first: int) -> None:
@@ -2377,8 +2404,9 @@ class GenerationPool:
             state.tokens.append(first)
             state.remaining -= 1
             self._prefill_done[state.rid] = (slot, self._active.pop(slot))
-            if self.on_token is not None:
-                self.on_token(state.rid, first)
+            self._out.append((state.rid, first, None))
+            # handed on before the park is announced, as it always was
+            self._hand_on()
             if self.on_prefill_done is not None:
                 self.on_prefill_done(state.rid)
             return
@@ -2832,11 +2860,10 @@ class GenerationPool:
                     tp[slot] = cfg.top_p
                     seed[slot] = cfg.seed & 0xFFFFFFFF
                 adpt[slot] = st.adapter
-            place = self._place
-            self._active_dev = place(active)
-            self._samp_dev = (place(temp), place(tk), place(tp),
-                              place(seed))
-            self._adapter_dev = place(adpt)
+            # one packed upload for a changed row set, not six
+            self._active_dev, *samp, self._adapter_dev = self._place(
+                [active, temp, tk, tp, seed, adpt])
+            self._samp_dev = tuple(samp)
             self._draws = bool((temp > 0).any())
             self._live_sig = sig
         return self._weights()
@@ -2854,6 +2881,11 @@ class GenerationPool:
     # reads what the last download brought.  A property of the KIND of
     # pool, read by the skeleton below: no option sets it
     _depth = 1
+    # the step that committed the token being handed on, where a pool
+    # commits a token some steps after it was first computed (the block
+    # pool's ``_leaving`` sets it a token); it rides beside each token
+    # of the batch
+    token_commit_step: Optional[int] = None
 
     def step(self) -> bool:
         """One tick, the same for every kind of pool, with the host one
@@ -2951,16 +2983,26 @@ class GenerationPool:
             host, first_toks = jax.device_get(
                 (handles, [tok for _, _, tok in firsts]))
         with tick_phase(tr, "tick.deliver") as span:
+            self._hook_calls = 0
             for (slot, st, _), tok in zip(firsts, first_toks):
                 self._commit_first(slot, st, int(tok.reshape(-1)[0]))
+            if self._ending:
+                # a request that ended on its first token leaves its
+                # slot before the step's rows are read: what the step
+                # computed for it is discarded
+                self._hand_on()
             self._rows = [(slot, st) for slot, st in rows
                           if self._active.get(slot) is st]
             if handles is not None:
                 self._deliver(host)
+            self._hand_on()
             if span is not None:
                 # known only here: set on the span as the engine's
-                # ``tick`` span sets what the tick did
-                span.set(rows=len(rows), ended=len(rows) - len(self._rows))
+                # ``tick`` span sets what the tick did; ``hook_calls``
+                # the calls into the token hooks (1 where a batch hook
+                # took the download's tokens whole)
+                span.set(rows=len(rows), ended=len(rows) - len(self._rows),
+                         hook_calls=self._hook_calls)
 
     def _settle(self) -> None:
         """Bring the host level with the device: download and deliver
@@ -3044,26 +3086,56 @@ class GenerationPool:
     def _deliver(self, tok) -> None:
         """Commit the step's sampled token to every row of it that is
         still live."""
+        toks = tok.tolist()
         for slot, st in self._rows:
             st.ahead -= 1
-            self._commit(slot, (int(tok[slot]),))
+            self._commit(slot, (toks[slot],))
 
     def _commit(self, slot: int, tokens) -> None:
         """Commit the tokens a step produced for ``slot``, in order and
-        0..n of them: append, count the budget down, fire ``on_token``,
-        and finish the slot at EOS or at the end of its budget, leaving
-        what comes after uncommitted.  ``tokens`` is iterated one commit
-        at a time, so a pool may pass a generator that does its own
+        0..n of them: append, count the budget down, put the token with
+        those ``_hand_on`` hands to the serving layer, and at EOS or at
+        the end of the budget mark the slot as ending, leaving what
+        comes after uncommitted.  ``tokens`` is iterated one commit at a
+        time, so a pool may pass a generator that does its own
         bookkeeping before each token leaves."""
         state = self._active[slot]
+        rid, out = state.rid, self._out
         for t in tokens:
             state.tokens.append(t)
             state.remaining -= 1
-            if self.on_token is not None:
-                self.on_token(state.rid, t)
+            out.append((rid, t, self.token_commit_step))
             if state.remaining == 0 or t == self.eos_id:
-                self._finish(slot)
+                self._ending.append(slot)
                 return
+
+    def _hand_on(self) -> None:
+        """Hand the tokens committed since the last call to the serving
+        layer in ONE call of ``on_tokens``, then finish the slots whose
+        request ended on one of them, in the order they ended: a
+        request's ``on_finish`` follows its last token's delivery.  What
+        the tick does for a download it does once, not once a row
+        (docs §5t)."""
+        if self._out:
+            batch, self._out = self._out, []
+            calls = self.on_tokens(batch)
+            self._hook_calls += 1 if calls is None else calls
+        if self._ending:
+            ending, self._ending = self._ending, []
+            for slot in ending:
+                self._finish(slot)
+
+    def _each_token(self, batch) -> int:
+        """The batch hook a pool starts with: every token to
+        ``on_token(rid, token)`` in turn, ``token_commit_step`` standing
+        at the token's own while it runs.  Returns the calls made."""
+        if self.on_token is None:
+            return 0
+        for rid, t, step in batch:
+            self.token_commit_step = step
+            self.on_token(rid, t)
+        self.token_commit_step = None
+        return len(batch)
 
     def refresh_weights(self):
         """Drop the cached parameter/buffer value lists — call after
@@ -3127,10 +3199,13 @@ class GenerationPool:
         self._active.clear()
         self._prefilling.clear()
         self._free = list(range(self.slots))
+        self._alloc_version += 1
         # whatever is in flight is of the cache being discarded:
         # dropped, never awaited; the carry starts over with it
         self._flights.clear()
         self._firsts.clear()
+        self._out.clear()
+        self._ending.clear()
         self._tok_dev = None
         self._step_dev = None
         self._live_sig = None
@@ -3208,6 +3283,16 @@ class GenerationPool:
         if self._chunk_jit is not None:
             version += self._chunk_jit.compiles + self._admit_jit.compiles
         return version
+
+    def alloc_version(self) -> int:
+        """The allocator's version: it moves with every slot take and
+        release and every block allocation, free, share, spill and
+        resume, and with nothing else.  A reservation is taken whole at
+        admission, so between two of its values ``cache_stats()``
+        returns what it returned: the serving engine polls it a tick
+        and recomputes the cache gauges only when it moved (the
+        ``cost_version()`` pattern)."""
+        return self._alloc_version
 
     def _derived_costs(self, step_entry: Optional[dict],
                        tokens_per_step_per_slot: float = 1.0,

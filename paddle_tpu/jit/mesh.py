@@ -123,7 +123,8 @@ class DecodeMesh:
         return NamedSharding(self.mesh, P(*axes))
 
     def place(self, arr, *axes):
-        """``device_put`` one array under ``PartitionSpec(*axes)``."""
+        """``device_put`` one array, or a list of them in one transfer
+        call, under ``PartitionSpec(*axes)``."""
         import jax
 
         return jax.device_put(arr, self.sharding(*axes))

@@ -28,7 +28,7 @@ Design points (docs/DESIGN.md §5c):
   and its paged KV blocks return to the allocator mid-generation
   (``cache_stats()`` returns to baseline — pinned by tests).
 - **Metrics from the real path.** TTFT is observed by the pool's
-  ``on_token`` hook at the actual first-token moment inside ``step()``;
+  ``on_tokens`` hook at the actual first-token moment inside ``step()``;
   queue depth/occupancy are read per tick; the step loop reuses
   ``profiler.StepTimer`` for sustained tokens/s.
 - **Request-level blast radius.** A failed ``pool.step()`` no longer
@@ -427,6 +427,9 @@ class ServingEngine:
         # cost-attribution fingerprint: gauges refresh only when the
         # pool's executable set changes (jit.aot cost_version)
         self._cost_seen = 0
+        # the allocator's version the cache gauges were last set at
+        # (None: never): they are recomputed only when it moved
+        self._alloc_seen = None
         # degradation ladder (docs §5j): level 0 = normal service;
         # each alert-active tick past the dwell steps DOWN one rung
         # (1 preempt low-priority, 2 +reduce spec-K, 3 +tighten
@@ -538,6 +541,11 @@ class ServingEngine:
             "serving_decode_steps_drawing_total",
             "decode steps launched with a row that draws (temperature "
             "> 0): the steps whose sampler runs its sort and draw")
+        self._c_gauge_refreshes = m.counter(
+            "serving_cache_gauge_refreshes_total",
+            "ticks on which the cache gauges were recomputed from "
+            "cache_stats(): those on which the allocator's version had "
+            "moved")
         # traffic-grade scheduling surface (docs §5j): preemption /
         # spill-tier / degradation accounting.  The spill gauges exist
         # only on paged pools (the spill tier is block-granular), like
@@ -730,7 +738,7 @@ class ServingEngine:
 
         # the engine IS the pool's lifecycle observer
         self._pool.on_admit = self._on_admit
-        self._pool.on_token = self._on_token
+        self._pool.on_tokens = self._on_tokens
         self._pool.on_finish = self._on_finish
         self._pool.on_resume = self._on_resume
 
@@ -1015,45 +1023,61 @@ class ServingEngine:
                       queue_depth=self._pool.queue_depth,
                       prefix_hit_tokens=hit)
 
-    def _on_token(self, rid, tok):
-        rec = self._live.get(rid)
-        if rec is None:  # pool used standalone alongside the engine
-            return
-        # deliver BEFORE committing: if stream delivery faults (the
-        # `stream.deliver` injection seam, or a real consumer-side
-        # error surfacing through the queue), the token is not yet in
-        # rec.tokens, so recovery re-prefills WITHOUT it and greedy
-        # decode regenerates exactly this token — delivered-once and
-        # committed stay equal, never one ahead of the other
-        rec.stream._put_token(int(tok))
+    def _on_tokens(self, batch) -> None:
+        """The tokens one download delivered, ``(rid, token,
+        commit_step)`` in the order the pool committed them, in ONE call
+        (docs §5t): one clock read stands for all of them, the
+        histograms, the SLO objectives and the token counter move once
+        for the batch.  What stays a token's own: the stream's put and
+        the record's append, in that order."""
         now = self._clock()
-        if rec.first_t is None:
-            rec.first_t = now
-            rec.state = RequestState.DECODING
-            trace.instant("req.decoding", rid=rid,
-                          ttft_s=now - rec.submit_t)
-            self._h_ttft.observe(now - rec.submit_t)
+        live, journal = self._live, self._journal is not None
+        ttfts, gaps = [], []
+        try:
+            for rid, tok, step in batch:
+                rec = live.get(rid)
+                if rec is None:  # pool used standalone alongside the engine
+                    continue
+                # deliver BEFORE committing: if stream delivery faults
+                # (the `stream.deliver` injection seam, or a real
+                # consumer-side error surfacing through the queue), the
+                # token is not yet in rec.tokens, so recovery re-prefills
+                # WITHOUT it and greedy decode regenerates exactly this
+                # token — delivered-once and committed stay equal, never
+                # one ahead of the other.  A fault at token k of a batch
+                # leaves the tokens before k delivered and committed and
+                # k onward neither
+                rec.stream._put_token(tok)
+                rec.tokens.append(tok)
+                if rec.first_t is None:
+                    rec.first_t = now
+                    rec.state = RequestState.DECODING
+                    trace.instant("req.decoding", rid=rid,
+                                  ttft_s=now - rec.submit_t)
+                    ttfts.append(now - rec.submit_t)
+                else:
+                    gaps.append(now - rec.last_t)
+                rec.last_t = now
+                if step is not None:
+                    if rec.commit_steps is None:
+                        rec.commit_steps = []
+                    rec.commit_steps.append(step)
+                if journal:
+                    # buffered, not written: the tick's deltas ride ONE
+                    # commit record at flush (journal bandwidth stays
+                    # O(ticks), not O(tokens)), and a lost tail only
+                    # re-decodes at restore
+                    self._jl_tick_toks.setdefault(rid, []).append(tok)
+        finally:
+            # what was delivered is observed, a faulted batch's too
+            n = len(ttfts) + len(gaps)
+            self._h_ttft.observe_many(ttfts)
+            self._h_itl.observe_many(gaps)
             if self._slo is not None:
-                self._slo.observe_latency("ttft", now - rec.submit_t)
-        else:
-            self._h_itl.observe(now - rec.last_t)
-            if self._slo is not None:
-                self._slo.observe_latency("inter_token",
-                                          now - rec.last_t)
-        rec.last_t = now
-        rec.tokens.append(int(tok))
-        step = getattr(self._pool, "token_commit_step", None)
-        if step is not None:
-            if rec.commit_steps is None:
-                rec.commit_steps = []
-            rec.commit_steps.append(step)
-        if self._journal is not None:
-            # buffered, not written: the tick's deltas ride ONE commit
-            # record at flush (journal bandwidth stays O(ticks), not
-            # O(tokens)), and a lost tail only re-decodes at restore
-            self._jl_tick_toks.setdefault(rec.rid, []).append(int(tok))
-        self._c_tokens.inc()
-        self._tokens_total += 1
+                self._slo.observe_latencies("ttft", ttfts)
+                self._slo.observe_latencies("inter_token", gaps)
+            self._c_tokens.inc(n)
+            self._tokens_total += n
 
     def _on_finish(self, rid, tokens, reason):
         rec = self._live.pop(rid, None)
@@ -1722,6 +1746,14 @@ class ServingEngine:
         rec.state = RequestState.QUEUED
         rec.preempted_at = None
 
+    def _complete_reason(self, tokens, max_new: int) -> Optional[str]:
+        """Why a request that committed ``tokens`` has nothing left to
+        generate (``"eos"`` / ``"length"``), or None while it has."""
+        eos = self._pool.eos_id
+        if eos is not None and tokens and tokens[-1] == eos:
+            return "eos"
+        return "length" if len(tokens) >= max_new else None
+
     def _recover(self, exc: BaseException) -> None:
         """A pool step blew up mid-flight.  The batched step serves
         every live request, so none of the POOL's state can be trusted —
@@ -1743,6 +1775,14 @@ class ServingEngine:
         survivors = []
         for rid, rec in list(self._live.items()):
             self._live.pop(rid)
+            reason = self._complete_reason(rec.tokens, rec.max_new)
+            if reason is not None:
+                # its last token was delivered and committed in the
+                # batch that faulted at a later one, before the pool
+                # could finish it: nothing is left to resubmit
+                self._c_done.inc()
+                self._finalize(rec, RequestState.DONE, reason, rec.tokens)
+                continue
             if kind == "permanent":
                 self._fail_record(rec, exc, "permanent step error")
             elif rec.retries >= self.max_retries:
@@ -2225,7 +2265,6 @@ class ServingEngine:
                               seed=legacy_samp.seed)
                 live, counts = replay(records)
                 now = self._clock()
-                eos = self._pool.eos_id
                 for entry in live:
                     rid = entry["rid"]
                     ids = np.asarray(entry["ids"], np.int32)
@@ -2281,18 +2320,15 @@ class ServingEngine:
                     self._c_replayed.inc()
                     replayed += 1
                     tokens_replayed += len(toks)
-                    if len(toks) >= max_new or (
-                            eos is not None and toks
-                            and toks[-1] == eos):
+                    reason = self._complete_reason(toks, max_new)
+                    if reason is not None:
                         # budget exhausted / EOS committed but the
                         # terminal record was lost to the torn tail:
                         # the request is DONE, finish it here instead
                         # of resubmitting work the contract forbids
                         self._c_done.inc()
-                        self._finalize(rec, RequestState.DONE,
-                                       ("eos" if eos is not None
-                                        and toks and toks[-1] == eos
-                                        else "length"), rec.tokens)
+                        self._finalize(rec, RequestState.DONE, reason,
+                                       rec.tokens)
                         finished += 1
                         continue
                     if legacy_samp is None and self._pool.adopt_spill(
@@ -2401,15 +2437,15 @@ class ServingEngine:
         own work around ``pool.step()`` is spanned the pool's way
         (``tick_phase``: a shared no-op when tracing is off) —
         ``tick.govern`` (deadlines, the degradation ladder),
-        ``tick.observe`` (handoff sweep, gauges), ``tick.journal``
-        (flush, SLO roll, heartbeat)."""
+        ``tick.observe`` (handoff sweep, gauges; its meta ``refreshed``
+        is 1 when the cache gauges were recomputed, else 0),
+        ``tick.journal`` (flush, SLO roll, heartbeat)."""
         self._health.note_tick_start(self._clock())
         try:
             with tick_phase(tr, "tick.govern"):
                 self._govern()
             if not self._live:
-                with tick_phase(tr, "tick.observe"):
-                    self._observe_gauges()
+                self._observe(tr)
                 return False
             self._h_queue.observe(self._pool.queue_depth)
             try:
@@ -2419,8 +2455,7 @@ class ServingEngine:
                 self._health.note_error(self._clock(), e,
                                         faults.classify_error(e))
                 self._recover(e)
-            with tick_phase(tr, "tick.observe"):
-                self._observe()
+            self._observe(tr)
             return bool(self._live)
         finally:
             with tick_phase(tr, "tick.journal"):
@@ -2435,11 +2470,14 @@ class ServingEngine:
         # ticks, or a drained engine could never step back up
         self._degrade_eval()
 
-    def _observe(self) -> None:
-        # prefill-role tick edge: export every prefill that
-        # completed this step and hand it off (no-op otherwise)
-        self._export_sweep()
-        self._observe_gauges()
+    def _observe(self, tr) -> None:
+        with tick_phase(tr, "tick.observe") as span:
+            # prefill-role tick edge: export every prefill that
+            # completed this step and hand it off (no-op otherwise)
+            self._export_sweep()
+            refreshed = self._observe_gauges()
+            if span is not None:
+                span.set(refreshed=int(refreshed))
 
     def _close_tick(self) -> None:
         # the tick's journal flush rides the tick's finally: commits
@@ -2455,29 +2493,22 @@ class ServingEngine:
             self._slo.note_tick()
         self._health.note_tick_end(self._clock())
 
-    def _observe_gauges(self) -> None:
+    def _observe_gauges(self) -> bool:
+        """Set the gauges a tick; True when the cache gauges were among
+        them.  Those are recomputed only when the allocator changed
+        (``alloc_version``): between two of its events ``cache_stats()``
+        returns what it returned, and the steady-state price is one int
+        compare, as for the cost gauges below."""
         pool = self._pool
         self._g_queue.set(pool.queue_depth)
         self._g_active.set(pool.active_count)
         self._g_occupancy.set(pool.active_count / pool.slots)
-        stats = pool.cache_stats()
-        self._g_kv_bytes.set(stats["reachable_bytes"])
-        self._g_kv_resident.set(stats["pool_bytes"])
-        if self._g_state_slot is not None:
-            self._g_state_slot.set(stats["bytes_per_slot"]["recurrent"])
-        for kind, g in self._g_cache_layers.items():
-            g.set(stats["cache_layers"][kind])
-        if self._g_kv_free is not None:
-            self._g_kv_free.set(stats["free_blocks"])
-        if self._g_kv_resident_shard is not None:
-            self._g_mesh_devices.set(stats["mesh"]["devices"])
-            per_shard = stats["per_shard"]
-            self._g_kv_resident_shard.set(per_shard[0]["pool_bytes"])
-            self._g_kv_reachable_shard.set(
-                max(s["reachable_bytes"] for s in per_shard))
+        version = pool.alloc_version()
+        refreshed = version != self._alloc_seen
+        if refreshed:
+            self._alloc_seen = version
+            self._observe_cache_gauges(pool.cache_stats())
         self._g_preempted.set(pool.preempted_count)
-        if self._g_spilled_blocks is not None:
-            self._g_spilled_blocks.set(stats["spilled_blocks"])
         if self._g_accept is not None:
             self._g_accept.set(
                 pool.acceptance_stats()["acceptance_rate"])
@@ -2515,6 +2546,29 @@ class ServingEngine:
                     derived.get("step_bytes_accessed", 0.0))
                 self._g_hbm_reserved.set(
                     derived.get("hbm_reserved_bytes") or 0.0)
+        return refreshed
+
+    def _observe_cache_gauges(self, stats: dict) -> None:
+        """The gauges read off ``cache_stats()``: the K/V and state
+        bytes, the layers by layout, free, per-shard and spilled
+        blocks."""
+        self._c_gauge_refreshes.inc()
+        self._g_kv_bytes.set(stats["reachable_bytes"])
+        self._g_kv_resident.set(stats["pool_bytes"])
+        if self._g_state_slot is not None:
+            self._g_state_slot.set(stats["bytes_per_slot"]["recurrent"])
+        for kind, g in self._g_cache_layers.items():
+            g.set(stats["cache_layers"][kind])
+        if self._g_kv_free is not None:
+            self._g_kv_free.set(stats["free_blocks"])
+        if self._g_kv_resident_shard is not None:
+            self._g_mesh_devices.set(stats["mesh"]["devices"])
+            per_shard = stats["per_shard"]
+            self._g_kv_resident_shard.set(per_shard[0]["pool_bytes"])
+            self._g_kv_reachable_shard.set(
+                max(s["reachable_bytes"] for s in per_shard))
+        if self._g_spilled_blocks is not None:
+            self._g_spilled_blocks.set(stats["spilled_blocks"])
 
     # -- drive mode 1: synchronous pump (deterministic, test/bench) ------
     def pump(self, steps: int = 1) -> bool:

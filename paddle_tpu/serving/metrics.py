@@ -3,7 +3,7 @@
 A deliberately small registry — no labels, no metric vectors, no
 background collection — because the engine records everything from the
 REAL code path: admission increments the counters inside ``submit()``,
-TTFT is observed by the pool's ``on_token`` hook the moment the prefill
+TTFT is observed by the pool's ``on_tokens`` hook the moment the prefill
 emits a request's first token, the robustness counters
 (``serving_requests_recovered_total``, ``serving_recoveries_total``,
 ``serving_requests_shed_total``, ``serving_engine_restarts_total``,
@@ -153,6 +153,15 @@ class Histogram(_Metric):
         self.count += 1
         self.sum += v
         self._counts[bisect.bisect_left(self.buckets, v)] += 1
+
+    def observe_many(self, values: Sequence[float]) -> None:
+        """``observe`` for a batch (a tick's inter-token gaps): the
+        count and the sum move once."""
+        counts, buckets = self._counts, self.buckets
+        for v in values:
+            counts[bisect.bisect_left(buckets, v)] += 1
+        self.count += len(values)
+        self.sum += float(sum(values))
 
     def reset(self) -> None:
         """Zero all counts, keeping the bucket layout.  For callers that

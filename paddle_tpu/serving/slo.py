@@ -22,8 +22,8 @@ classic multi-window burn-rate pairing:
   multiwindow/multi-burn-rate policy, with ticks as the time base so
   deterministic pump-mode tests can drive it with no wall clock.)
 
-The tracker is FED FROM THE REAL PATH: the engine's ``_on_token`` hook
-reports each TTFT/inter-token observation at the moment it lands, every
+The tracker is FED FROM THE REAL PATH: the engine's ``_on_tokens`` hook
+reports a delivered step's TTFT/inter-token observations as they land, every
 terminal ``_finalize`` reports the request's final state, and each tick
 rolls the windows.  Uninstalled (``ServingEngine(slo=None)``, the
 default) the engine pays ONE ``is None`` test per seam — the fault-
@@ -188,12 +188,13 @@ class _ObjectiveState:
         self.total_bad = 0
 
     def observe(self, bad: bool) -> None:
-        if bad:
-            self.cur_bad += 1
-            self.total_bad += 1
-        else:
-            self.cur_good += 1
-            self.total_good += 1
+        self.observe_many(0 if bad else 1, 1 if bad else 0)
+
+    def observe_many(self, good: int, bad: int) -> None:
+        self.cur_good += good
+        self.total_good += good
+        self.cur_bad += bad
+        self.total_bad += bad
 
     def roll(self, fast_window: int, burn_threshold: float) -> Optional[bool]:
         """Close the current tick's bucket and re-evaluate both
@@ -283,11 +284,20 @@ class SLOTracker:
 
     # -- fed from the engine's real path ---------------------------------
     def observe_latency(self, kind: str, seconds: float) -> None:
-        """One TTFT or inter-token observation (engine ``_on_token``)."""
+        """One TTFT or inter-token observation."""
+        self.observe_latencies(kind, (seconds,))
+
+    def observe_latencies(self, kind: str, seconds: Sequence[float]) -> None:
+        """A delivered step's TTFT or inter-token observations (engine
+        ``_on_tokens``): one pass over the objectives a batch, not a
+        token."""
+        if not seconds:
+            return
         for st in self._states.values():
             o = st.objective
             if o.kind == kind:
-                st.observe(seconds > o.threshold_s)
+                bad = sum(1 for v in seconds if v > o.threshold_s)
+                st.observe_many(len(seconds) - bad, bad)
 
     def observe_terminal(self, state: str) -> None:
         """One request reached a terminal state (engine ``_finalize``)."""

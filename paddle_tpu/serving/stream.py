@@ -105,7 +105,7 @@ class ResponseStream:
         # `stream.deliver` is the injection seam for delivery failures;
         # the engine delivers BEFORE committing a token, so a fault here
         # means recovery regenerates exactly this token (no loss, no
-        # duplicate — see ServingEngine._on_token)
+        # duplicate — see ServingEngine._on_tokens)
         faults.fire("stream.deliver")
         self._q.put_nowait(tok)
 

@@ -331,7 +331,10 @@ def test_every_span_is_a_profiler_annotation_too(model, monkeypatch):
     assert by_name["submit.lock_wait"] == {"rid": "r0"}
     assert by_name["tick.decode"] == {"live": 1, "slots": 2, "greedy": 1,
                                       "ahead": 0}
-    assert by_name["tick.deliver"] == {"rows": 1, "ended": 0}
+    # one call into the engine for the download's tokens (docs 5t)
+    assert by_name["tick.deliver"] == {"rows": 1, "ended": 0,
+                                       "hook_calls": 1}
+    assert by_name["tick.observe"] == {"refreshed": 1}
     assert by_name["tick.prefill"] == {"rid": "r0", "prompt_tokens": 5,
                                        "bucket": 32}
     assert by_name["tick"] == {"tick": 1, "queued": 1, "admitted": 1,
@@ -903,7 +906,7 @@ def test_a_ticks_phases_in_order_with_the_pools_meta(model, kind):
                 assert 1 <= e.meta["live"] <= e.meta["slots"] == 2
                 aheads.append(e.meta["ahead"])
             elif e.name == "tick.deliver":
-                assert set(e.meta) == {"rows", "ended"}
+                assert set(e.meta) == {"rows", "ended", "hook_calls"}
                 assert 0 <= e.meta["ended"] <= e.meta["rows"] <= 2
             else:
                 assert e.meta is None, (e.name, e.meta)

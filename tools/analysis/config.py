@@ -77,7 +77,7 @@ EXTRA_EDGES = {
     # cursor walk (``_control``) and the deliver loop are hot like the
     # plain pool's.  The skeleton reaches the hooks through
     # ``_launch_step`` and ``_settle_one``, direct self-calls
-    "GenerationPool.step": ("ServingEngine._on_token",
+    "GenerationPool.step": ("ServingEngine._on_tokens",
                             "ServingEngine._on_finish",
                             "ServingEngine._on_prefill_done"),
     "GenerationPool._launch_step": ("SpeculativePool._sync_step_inputs",
@@ -89,8 +89,13 @@ EXTRA_EDGES = {
                                     "BlockDiffusionPool._launch"),
     "GenerationPool._settle_one": ("SpeculativePool._deliver",
                                    "BlockDiffusionPool._deliver"),
-    "GenerationPool._commit": ("BlockDiffusionPool._leaving",
-                               "BlockDiffusionPool._finish"),
+    "GenerationPool._commit": ("BlockDiffusionPool._leaving",),
+    # the download's tokens leave in one call of the batch hook (the
+    # engine's, or the per-token adapter a pool starts with), then the
+    # rows that ended are finished
+    "GenerationPool._hand_on": ("ServingEngine._on_tokens",
+                                "GenerationPool._each_token",
+                                "BlockDiffusionPool._finish"),
     "GenerationPool._refill": ("ServingEngine._on_admit",
                                "ServingEngine._on_resume",
                                "GenerationPool._resume",
@@ -106,8 +111,7 @@ EXTRA_EDGES = {
                                       "ServingEngine._on_admit"),
     "GenerationPool._chunk_work": ("AotFunction.__call__",),
     "GenerationPool._activate": ("SpeculativePool._on_activated",),
-    "GenerationPool._commit_first": ("ServingEngine._on_token",
-                                     "ServingEngine._on_prefill_done"),
+    "GenerationPool._commit_first": ("ServingEngine._on_prefill_done",),
     # traffic-grade scheduling (docs §5j): the degradation ladder's
     # preempt decision dispatches into the pool's spill path (victim
     # K/V → host pool, the one deliberate spill-boundary device_get),
@@ -221,7 +225,8 @@ EXTRA_EDGES = {
     "_fire": ("fire",),
     "fire": ("FaultPlane.fire",),
     "ResponseStream._put_token": ("fire",),
-    "ServingEngine._on_token": ("ResponseStream._put_token",),
+    "ServingEngine._on_tokens": ("ResponseStream._put_token",
+                                 "SLOTracker.observe_latencies"),
     # trace plane (serving/trace.py): the hot path's module-level no-op
     # check (`trace.instant` / `_trace_active()`) fans into the
     # installed Tracer; span context managers (`with tr.span(...)`) and
@@ -257,7 +262,6 @@ EXTRA_EDGES = {
     # into the trace + structured log) is declared so the whole seam
     # is hot-path-audited like the fault/trace planes
     "ServingEngine._close_tick": ("SLOTracker.note_tick",),
-    "ServingEngine._on_token": ("SLOTracker.observe_latency",),
     "SLOTracker.note_tick": ("_ObjectiveState.roll", "instant",
                              "emit"),
     # structured-log plane (serving/log.py): module-level `emit` is
