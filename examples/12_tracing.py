@@ -29,7 +29,15 @@ observability leg (docs/DESIGN.md §5g):
    anyone takes holds ``tick``, ``tick.*`` and ``submit.lock_wait`` in
    its host plane, on the trace's own clock, beside the device
    operations, whose ``op_name`` carries the module tree
-   (``encoder/layers/0/self_attn/q_proj``, ``lm_head``, ``sample``).
+   (``encoder/layers/0/self_attn/q_proj``, ``lm_head``, ``sample``);
+6. **how long a span's thread ran, and what the front did with a
+   token**: every span's meta carries ``cpu_s``, its thread's CPU time,
+   so ``dur_s - cpu_s`` is what the thread waited (device, interpreter
+   lock, a lock, a core); ``tick`` counts its thread's context switches
+   (``nvcsw`` its own, ``nivcsw`` the machine's); and each
+   ``POST /generate`` ends with ONE ``http.stream`` instant: the token
+   lines it flushed, what they waited between the engine's put and the
+   flush, and the handler thread's CPU time.
 
 Run: python examples/12_tracing.py [--tokens 8]
 """
@@ -48,7 +56,7 @@ import numpy as np
 
 import paddle_tpu as pt
 from paddle_tpu.models import TransformerLM
-from paddle_tpu.serving import ServingEngine, faults
+from paddle_tpu.serving import ServingEngine, ServingHTTPFrontend, faults
 
 
 def build_engine(model):
@@ -124,6 +132,13 @@ def main():
              sum(m["live"] for m in decode) / len(decode),
              decode[0]["slots"]))
 
+    # -- how long the ticking thread ran inside a tick, and its switches
+    for e in [e for e in events if e.name == "tick"][:3]:
+        print("tick %d: %.0f us long, %.0f us on the CPU; switched out "
+              "%s time(s) by itself, %s by the machine"
+              % (e.meta["tick"], e.dur_s * 1e6, e.meta["cpu_s"] * 1e6,
+                 e.meta.get("nvcsw", "?"), e.meta.get("nivcsw", "?")))
+
     # -- the request's own timeline needs no tracer: it is on the status
     engine2 = build_engine(model)
     for st in run(engine2, prompts[:2], args.tokens):
@@ -152,6 +167,26 @@ def main():
                         seen[ev.name] = seen.get(ev.name, 0) + 1
     print("annotations in the profile's host plane:",
           dict(sorted(seen.items())))
+
+    # -- what the HTTP front did with a request's tokens: one instant
+    import http.client
+    front = ServingHTTPFrontend(engine2).start()
+    tracer = engine2.start_trace(capacity=512)
+    conn = http.client.HTTPConnection(*front.address)
+    conn.request("POST", "/generate", json.dumps({
+        "prompt": prompts[0].tolist(), "max_new_tokens": args.tokens,
+        "request_id": "over-http"}))
+    conn.getresponse().read()
+    conn.close()
+    engine2.stop_trace()
+    front.shutdown()
+    (ev,) = [e for e in tracer.recorder.snapshot()
+             if e.name == "http.stream"]
+    print("http.stream for %s: %d lines, put to flushed %.0f us a line "
+          "(worst %.0f us), %.0f us of the handler's CPU"
+          % (ev.rid, ev.meta["lines"],
+             1e6 * ev.meta["lag_sum_s"] / ev.meta["lines"],
+             1e6 * ev.meta["lag_max_s"], 1e6 * ev.meta["cpu_s"]))
     print("done.")
 
 

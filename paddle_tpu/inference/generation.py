@@ -2910,7 +2910,12 @@ class GenerationPool:
         The hooks find the rows their step is about in ``_rows``.
 
         With a tracer installed (serving/trace.py) each phase is a span
-        — admit (refill incl. per-request prefill), decode (the
+        — admit (refill incl. per-request prefill), prep (the launch's
+        host work: the list of rows that take the step,
+        ``_sync_step_inputs`` and ``tick.decode``'s meta; its own meta
+        says how many ``rows`` it found, 0 where it launched nothing,
+        and whether their vectors were ``uploaded``, 1, or the last
+        launch's stood, 0), decode (``_launch`` alone: the
         dispatches, which return before the device has finished; its
         meta says how many of the ``slots`` rows were ``live`` and
         whether the launch was made ``ahead`` of a step in flight),
@@ -2953,16 +2958,29 @@ class GenerationPool:
 
     def _launch_step(self, tr) -> None:
         """Launch ONE step for every launchable row, if there is one,
-        and put it in flight."""
-        rows = [(slot, st) for slot, st in self._active.items()
-                if self._launchable(slot, st)]
-        if not rows:
-            return
-        self._rows = rows
-        ahead = len(self._flights)
-        inputs = self._sync_step_inputs()
-        with tick_phase(tr, "tick.decode", lambda: dict(
-                self._decode_meta(*inputs), ahead=ahead)):
+        and put it in flight.  ``tick.prep`` is everything before the
+        dispatch, ``tick.decode`` the dispatch; the decode span is made
+        inside the prep with its meta, so without a tracer neither the
+        meta nor a span is built."""
+        with tick_phase(tr, "tick.prep") as prep:
+            rows = [(slot, st) for slot, st in self._active.items()
+                    if self._launchable(slot, st)]
+            if not rows:
+                if prep is not None:
+                    prep.set(rows=0, uploaded=0)
+                return
+            self._rows = rows
+            sig = self._live_sig
+            inputs = self._sync_step_inputs()
+            decode = _NO_SPAN
+            if prep is not None:
+                # a row set that changed was uploaded under a NEW signature
+                prep.set(rows=len(rows),
+                         uploaded=int(self._live_sig is not sig))
+                decode = tr.span("tick.decode",
+                                 **self._decode_meta(*inputs),
+                                 ahead=len(self._flights))
+        with decode:
             handles = self._launch(*inputs)
         self._flights.append((handles, rows))
 

@@ -704,7 +704,10 @@ class ServingEngine:
             "serving_tokens_per_sec",
             "tokens emitted / cumulative step time (StepTimer)")
         self._g_step = m.gauge(
-            "serving_step_time_s", "mean batched decode step wall time")
+            "serving_step_time_s",
+            "mean wall time of pool.step(), the pool's whole turn of a "
+            "tick: launch, the download's wait for the device, "
+            "delivery, admissions and their prefills")
         self._h_ttft = m.histogram(
             "serving_ttft_seconds",
             "admission-to-first-token latency (lock wait excluded; see "
@@ -2398,7 +2401,10 @@ class ServingEngine:
         """The traced twin of the tick: same ``_run_tick`` body inside a
         numbered ``tick`` span that says what the tick did (``queued``
         at its start, requests ``admitted`` to a slot and ``finished``
-        inside it), plus compile-event diffing and the drop-counter
+        inside it) and how often its thread was switched out inside it
+        (``nvcsw`` by itself: it blocked; ``nivcsw`` by the machine: its
+        core was taken; absent where the platform counts neither by
+        thread), plus compile-event diffing and the drop-counter
         mirror.  All tracer bookkeeping writes re-take the
         (reentrant) engine lock the driving thread already holds, so the
         lock discipline stays textual."""
@@ -2415,9 +2421,13 @@ class ServingEngine:
         with tr.span("tick", tick=tr.next_tick(),
                      queued=self._pool.queue_depth) as span:
             admitted, finished = self._n_admitted, self._n_finalized
+            before = trace.thread_switches()
             work = self._run_tick(tr)
             span.set(admitted=self._n_admitted - admitted,
                      finished=self._n_finalized - finished)
+            if before is not None:
+                nvcsw, nivcsw = trace.thread_switches()
+                span.set(nvcsw=nvcsw - before[0], nivcsw=nivcsw - before[1])
         counts = self._pool.compile_counts()
         if counts != self._compile_seen:
             for key, n in counts.items():

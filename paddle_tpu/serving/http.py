@@ -70,7 +70,7 @@ import numpy as np
 from ..core.errors import (InvalidArgumentError, NotFoundError,
                            PreconditionNotMetError)
 from ..inference.generation import DuplicateRequestError
-from . import faults
+from . import faults, trace
 from .engine import (AdmissionTightenedError, DeadlineUnattainableError,
                      QueueFullError, ServingEngine, _normalize_priority)
 
@@ -154,6 +154,36 @@ def parse_generate_request(body: bytes) -> Tuple[np.ndarray, int,
     return (np.asarray(prompt, np.int32), max_new, rid,
             None if deadline is None else float(deadline),
             priority, tenant)
+
+
+class _WriteLag:
+    """What one response stream did with its tokens, kept by its handler
+    thread while a tracer is installed and emitted ONCE, when the stream
+    ends, as the instant ``http.stream`` under the request's id:
+    ``lines``, the token lines whose put was stamped
+    (``ResponseStream._put_token``); ``lag_sum_s`` and ``lag_max_s``,
+    put to flushed over them; ``cpu_s``, this thread's CPU time from
+    the first of them to the end.  One event a request and not one a
+    line: a line's would push the tick's spans out of the ring."""
+
+    __slots__ = ("_tr", "_cpu0", "_lines", "_sum", "_max")
+
+    def __init__(self, tr):
+        self._tr = tr
+        self._cpu0 = tr.cpu_now()
+        self._lines = 0
+        self._sum = self._max = 0.0
+
+    def add(self, lag_s: float) -> None:
+        self._lines += 1
+        self._sum += lag_s
+        if lag_s > self._max:
+            self._max = lag_s
+
+    def emit(self, rid) -> None:
+        self._tr.instant("http.stream", rid=rid, lines=self._lines,
+                         lag_sum_s=self._sum, lag_max_s=self._max,
+                         cpu_s=self._tr.cpu_now() - self._cpu0)
 
 
 def _make_handler(engine: ServingEngine, quiet: bool = True):
@@ -314,6 +344,7 @@ def _make_handler(engine: ServingEngine, quiet: bool = True):
                 self._send_json(503, {"error": str(e),
                                       "retryable": False})
                 return
+            lag = None      # under a tracer: what this stream's lines waited
             try:
                 # header flush is inside the try: a client gone before
                 # end_headers() must cancel, same as one gone mid-stream
@@ -321,14 +352,20 @@ def _make_handler(engine: ServingEngine, quiet: bool = True):
                 self.send_header("Content-Type", "application/x-ndjson")
                 self.send_header("Cache-Control", "no-store")
                 self.end_headers()
-                for tok in stream:
+                for n, tok in enumerate(stream):
                     # `http.write` seam: an injected OSError here is a
                     # client disconnect — the except path below cancels
                     # the request and reclaims its slot/blocks
                     faults.fire("http.write")
+                    tr = trace.active()
+                    put = None if tr is None else stream._take_stamp(n)
+                    if put is not None and lag is None:
+                        lag = _WriteLag(tr)
                     self.wfile.write(
                         (json.dumps({"token": int(tok)}) + "\n").encode())
                     self.wfile.flush()
+                    if put is not None:
+                        lag.add(tr.now() - put)
                 st = stream.result(timeout_s=None)
                 # per token, the denoising step that committed it: only
                 # a block-diffusion engine's line carries the key
@@ -355,6 +392,8 @@ def _make_handler(engine: ServingEngine, quiet: bool = True):
                 # routine, not worth a socketserver traceback
                 pass
             finally:
+                if lag is not None:
+                    lag.emit(stream.request_id)
                 # free the slot and its KV blocks on EVERY exit path,
                 # not just OSError: an engine failure surfacing through
                 # the stream iterator (inline-pump pool.step blowing

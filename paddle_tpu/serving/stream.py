@@ -28,7 +28,7 @@ import threading
 import time
 from typing import Optional
 
-from . import faults
+from . import faults, trace
 
 __all__ = ["RequestState", "ResponseStream", "StreamStatus"]
 
@@ -99,6 +99,15 @@ class ResponseStream:
         self._q: queue.Queue = queue.Queue(maxsize=int(max_new_tokens) + 1)
         self._done = threading.Event()
         self._status: Optional[StreamStatus] = None
+        # under a tracer only (serving/trace.py): when each token was
+        # put, as (the token's place in the stream, the tracer's clock),
+        # for the front to say what a line waited before it was flushed
+        # (``http.stream``).  One writer, the thread that puts (the tick
+        # thread, appending), and one reader, the thread that consumes
+        # the stream (an HTTP handler, popping from the left): a deque
+        # takes both without a lock.  None until a token is put under a
+        # tracer: no tracer, no deque
+        self._stamps: Optional[collections.deque] = None
 
     # -- engine side -----------------------------------------------------
     def _put_token(self, tok: int) -> None:
@@ -107,6 +116,14 @@ class ResponseStream:
         # means recovery regenerates exactly this token (no loss, no
         # duplicate — see ServingEngine._on_tokens)
         faults.fire("stream.deliver")
+        tr = trace.active()
+        if tr is not None:
+            if self._stamps is None:
+                self._stamps = collections.deque()
+            # the queue counts its puts (``unfinished_tasks``: nothing
+            # here calls ``task_done``), so the token about to be put is
+            # the stream's ``unfinished_tasks``-th, counted from 0
+            self._stamps.append((self._q.unfinished_tasks, tr.now()))
         self._q.put_nowait(tok)
 
     def _finalize(self, status: StreamStatus) -> None:
@@ -115,6 +132,19 @@ class ResponseStream:
         self._done.set()
 
     # -- consumer side ---------------------------------------------------
+    def _take_stamp(self, n: int) -> Optional[float]:
+        """When the stream's ``n``-th token (from 0) was put, on the
+        tracer's clock; None for a token put with no tracer installed.
+        For the one consumer, once a token, in order."""
+        stamps = self._stamps
+        if stamps is None:
+            return None
+        while stamps and stamps[0][0] < n:
+            stamps.popleft()        # a token its consumer did not ask about
+        if stamps and stamps[0][0] == n:
+            return stamps.popleft()[1]
+        return None
+
     @property
     def status(self) -> Optional[StreamStatus]:
         """The terminal record, or None while the request is live."""

@@ -30,6 +30,10 @@ host-sync rule stays clean when no tracer is installed.
 **Spans time the HOST.**  An async decode dispatch returns before the
 device finishes, so a phase span brackets python work plus whatever
 sync the phase already contains (the per-tick token download is one).
+Beside its length a span says how long its THREAD ran: ``cpu_s`` in its
+meta is the calling thread's CPU time over the block, so ``dur_s -
+cpu_s`` is what the thread waited (for the device, the interpreter
+lock, a lock, a core).
 Device time is read from the profiler's device trace, never from a
 span — and so that the two can be read together, every span is ALSO a
 ``jax.profiler.TraceAnnotation`` of the same name (meta as keyword
@@ -63,8 +67,15 @@ from jax.profiler import TraceAnnotation
 from ..core.errors import InvalidArgumentError, PreconditionNotMetError
 from ..profiler.visual import chrome_trace_json
 
+try:
+    import resource
+    _RUSAGE_THREAD = resource.RUSAGE_THREAD
+except (ImportError, AttributeError):     # not Linux: no per-thread usage
+    resource = None
+
 __all__ = ["TraceEvent", "FlightRecorder", "Tracer", "active", "install",
-           "uninstall", "tracing", "instant", "export_chrome_trace",
+           "uninstall", "tracing", "instant", "thread_switches",
+           "export_chrome_trace",
            "to_chrome_events", "LIFECYCLE_EVENTS", "TERMINAL_EVENTS"]
 
 # the request-lifecycle event names (engine-emitted): non-terminal marks
@@ -173,13 +184,17 @@ class FlightRecorder:
 
 class _Span:
     """The span context manager ``Tracer.span`` hands out: times the
-    block on the tracer's clock and records ONE complete event at exit
-    (start timestamp + duration), so a span costs two clock reads and
-    one ring append — plus the ``TraceAnnotation`` twin that puts the
-    same span into a running ``jax.profiler`` trace (one flag test when
-    no profiler session is on)."""
+    block on the tracer's clock, and on the calling thread's CPU clock
+    beside it, and records ONE complete event at exit (start timestamp
+    + duration, ``cpu_s`` in its meta), so a span costs four clock reads
+    and one ring append — plus the ``TraceAnnotation`` twin that puts
+    the same span into a running ``jax.profiler`` trace (one flag test
+    when no profiler session is on).  ``dur_s - cpu_s`` is the time the
+    thread neither ran nor could.  ``cpu_s`` rides in the meta, not in a
+    field of its own: every consumer of an event (the exports, the
+    benchmark's readers) is handed the meta already."""
 
-    __slots__ = ("_tr", "_name", "_rid", "_meta", "_t0", "_ann")
+    __slots__ = ("_tr", "_name", "_rid", "_meta", "_t0", "_cpu0", "_ann")
 
     def __init__(self, tr, name, rid, meta):
         self._tr = tr
@@ -200,14 +215,18 @@ class _Span:
         self._ann = TraceAnnotation(self._name, **stats)
         self._ann.__enter__()
         self._t0 = self._tr._clock()
+        self._cpu0 = self._tr._cpu_clock()
         return self
 
     def __exit__(self, *exc):
         tr = self._tr
+        # the CPU readings inside the wall readings: cpu_s <= dur_s
+        cpu = tr._cpu_clock() - self._cpu0
         dur = tr._clock() - self._t0
+        self.set(cpu_s=cpu)
         self._ann.__exit__(*exc)
         tr._emit(TraceEvent(self._t0, self._name, self._rid, dur,
-                            self._meta or None))
+                            self._meta))
         return False
 
 
@@ -217,11 +236,15 @@ class Tracer:
 
     ``clock`` defaults to ``time.perf_counter`` — ALL trace timestamps
     live in this one clock domain, so cross-event ordering is
-    meaningful even on engines driven by an injected deadline clock."""
+    meaningful even on engines driven by an injected deadline clock.
+    ``cpu_clock`` defaults to ``time.thread_time``, the CALLING thread's
+    CPU time: only differences taken on one thread mean anything."""
 
-    def __init__(self, capacity: int = 4096, clock=None):
+    def __init__(self, capacity: int = 4096, clock=None, cpu_clock=None):
         self.recorder = FlightRecorder(capacity)
         self._clock = clock if clock is not None else time.perf_counter
+        self._cpu_clock = cpu_clock if cpu_clock is not None \
+            else time.thread_time
         self._ticks = 0
 
     def now(self) -> float:
@@ -230,6 +253,11 @@ class Tracer:
         engine-clock ``at`` so consumers can align the dumped events'
         ``ts`` with the dump moment across the two clock domains."""
         return self._clock()
+
+    def cpu_now(self) -> float:
+        """A reading of the calling thread's CPU clock, the one a
+        span's ``cpu_s`` is taken on."""
+        return self._cpu_clock()
 
     @property
     def tick(self) -> int:
@@ -313,6 +341,17 @@ def instant(name: str, rid=None, **meta) -> None:
     t = _TRACER
     if t is not None:
         t.instant(name, rid=rid, **meta)
+
+
+def thread_switches():
+    """``(nvcsw, nivcsw)``: the context switches of the CALLING thread
+    so far, those it made itself (it blocked: on the interpreter lock,
+    a lock, the device) and those made for it (its core was taken).
+    None where the platform counts none by thread.  One system call."""
+    if resource is None:
+        return None
+    ru = resource.getrusage(_RUSAGE_THREAD)
+    return ru.ru_nvcsw, ru.ru_nivcsw
 
 
 # -- Chrome/Perfetto export ----------------------------------------------

@@ -112,12 +112,12 @@ class _FakeSocket:
         pass
 
 
-def _http(engine, method, path, body=b""):
+def _http(engine, method, path, body=b"", socket=None):
     """Run ONE request through the front end's handler class in-process;
     returns (status_code, header dict, body bytes)."""
     req = ("%s %s HTTP/1.1\r\nHost: t\r\nContent-Length: %d\r\n\r\n"
            % (method, path, len(body))).encode() + body
-    sock = _FakeSocket(req)
+    sock = (socket or _FakeSocket)(req)
     _make_handler(engine)(sock, ("127.0.0.1", 0), None)
     raw = sock.out.getvalue()
     head, _, payload = raw.partition(b"\r\n\r\n")
@@ -413,7 +413,9 @@ def test_debug_trace_and_flightrec_endpoints(model):
         # the caller's own id ties the wait for the engine lock to
         # the request: its timeline begins before it is queued
         assert names[:2] == ["submit.lock_wait", "req.queued"]
-        assert names[-1] == "req.done"
+        # and ends with what the front did with its tokens, written
+        # once the terminal line was
+        assert names[-2:] == ["req.done", "http.stream"]
         # missing rid -> 400; unknown rid -> 404
         code, _, payload = _http(eng, "GET", "/debug/trace")
         assert code == 400 and b"rid" in payload
@@ -542,6 +544,137 @@ def test_http_write_fault_cancels_like_a_disconnect(model):
     assert eng.cache_stats()["free_blocks"] == free0
     assert eng.metrics.snapshot()[
         "serving_requests_cancelled_total"] == 1
+
+
+# -- the front reports from inside: one ``http.stream`` a request --------
+
+def _generate(eng, rid, n=5, **kw):
+    code, _, payload = _http(
+        eng, "POST", "/generate",
+        json.dumps({"prompt": [3, 1, 4], "max_new_tokens": n,
+                    "request_id": rid}).encode(), **kw)
+    assert code == 200
+    return payload.splitlines(keepends=True)
+
+
+def _streams_of(eng, monkeypatch):
+    """Every stream ``eng.submit`` hands out from here on."""
+    streams, submit = [], eng.submit
+
+    def keeping(*a, **kw):
+        streams.append(submit(*a, **kw))
+        return streams[-1]
+
+    monkeypatch.setattr(eng, "submit", keeping)
+    return streams
+
+
+def _http_streams(tracer):
+    return [e for e in tracer.recorder.snapshot()
+            if e.name == "http.stream"]
+
+
+def _check_lag(ev, rid, lines):
+    assert ev.rid == rid and ev.dur_s is None
+    assert set(ev.meta) == {"lines", "lag_sum_s", "lag_max_s", "cpu_s"}
+    assert ev.meta["lines"] == lines
+    assert 0.0 <= ev.meta["lag_max_s"] <= ev.meta["lag_sum_s"]
+    assert ev.meta["lag_sum_s"] <= lines * ev.meta["lag_max_s"]
+    assert ev.meta["cpu_s"] >= 0.0
+
+
+def test_a_traced_stream_says_what_its_lines_waited(model, monkeypatch):
+    from paddle_tpu.serving import trace
+
+    eng = ServingEngine(model, max_len=64, slots=1, buckets=[16])
+    streams = _streams_of(eng, monkeypatch)
+    # no tracer: no stamp, no deque for stamps, no event
+    plain = _generate(eng, "plain")
+    assert streams[-1]._stamps is None
+    assert streams[-1]._take_stamp(0) is None
+    tracer = eng.start_trace(capacity=512)
+    try:
+        traced = _generate(eng, "traced")
+        # ONE instant a request, after its terminal line, on its timeline
+        (ev,) = _http_streams(tracer)
+        _check_lag(ev, "traced", 5)
+        # every stamp was taken by the line it was for
+        assert not streams[-1]._stamps
+        code, _, payload = _http(eng, "GET", "/debug/trace?rid=traced")
+        names = [e["name"] for e in json.loads(payload)["events"]]
+        assert names[-2:] == ["req.done", "http.stream"]
+        _generate(eng, "second", n=3)
+        assert [e.rid for e in _http_streams(tracer)] == ["traced",
+                                                          "second"]
+        _check_lag(_http_streams(tracer)[-1], "second", 3)
+    finally:
+        eng.stop_trace()
+    # the tokens byte for byte what they are with no tracer installed
+    assert len(plain) == len(traced) == 6
+    assert plain[:5] == traced[:5]
+    # under injected clocks that advance by one a reading: whole numbers,
+    # a reading or more between a put and its flush, and between the
+    # handler's two readings of its CPU clock
+    import itertools
+    wall, cpu = itertools.count(), itertools.count()
+    with trace.tracing(trace.Tracer(
+            capacity=512, clock=lambda: float(next(wall)),
+            cpu_clock=lambda: float(next(cpu)))) as tracer:
+        _generate(eng, "counted", n=4)
+    (ev,) = _http_streams(tracer)
+    _check_lag(ev, "counted", 4)
+    assert ev.meta["lag_max_s"] >= 1.0 and ev.meta["cpu_s"] >= 1.0
+    assert all(v == int(v) for v in ev.meta.values())
+
+
+def test_a_stream_counts_only_lines_put_under_the_tracer(model,
+                                                         monkeypatch):
+    from paddle_tpu.serving import trace
+
+    eng = ServingEngine(model, max_len=64, slots=1, buckets=[16])
+    streams = _streams_of(eng, monkeypatch)
+    tracer = trace.Tracer(capacity=512)
+
+    class InstallsAfterTwoLines(_FakeSocket):
+        sent = 0
+
+        def sendall(self, data):
+            super().sendall(data)
+            self.sent += data.count(b'"token"')
+            if self.sent == 2 and trace.active() is None:
+                trace.install(tracer)
+
+    try:
+        lines = _generate(eng, "late", n=6, socket=InstallsAfterTwoLines)
+    finally:
+        trace.uninstall()
+    assert len(lines) == 7
+    # the two tokens put before the tracer came have no stamp and are
+    # not counted; each later one is, in its own place in the stream
+    (ev,) = _http_streams(tracer)
+    _check_lag(ev, "late", 4)
+    assert not streams[-1]._stamps
+
+
+def test_a_disconnect_ends_the_stream_with_its_event(model):
+    from paddle_tpu.serving import faults
+    from paddle_tpu.serving.faults import FaultPlane, FaultSpec
+
+    eng = ServingEngine(model, max_len=64, slots=1, buckets=[16])
+    tracer = eng.start_trace(capacity=512)
+    plane = FaultPlane([FaultSpec(
+        "http.write", error=ConnectionResetError("injected disconnect"),
+        after=2, times=1)])
+    try:
+        with faults.injected(plane):
+            lines = _generate(eng, "gone", n=30)
+    finally:
+        eng.stop_trace()
+    assert len(lines) == 2
+    # the two lines that were flushed, and no more: the client was gone
+    (ev,) = _http_streams(tracer)
+    _check_lag(ev, "gone", 2)
+    assert eng.live_requests == 0
 
 
 # -- the real server (threaded: slow-marked per the tier-1 budget) -------

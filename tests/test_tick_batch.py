@@ -465,7 +465,8 @@ def test_cache_gauges_read_cache_stats_after_every_tick(request, case):
     observes = [ev.meta for ev in spans if ev.name == "tick.observe"]
     assert len(delivers) == len(observes) == 20
     assert all(m["hook_calls"] == 1 and m["rows"] == 2 for m in delivers)
-    assert all(m == {"refreshed": 0} for m in observes)
+    assert all(set(m) == {"refreshed", "cpu_s"} and m["refreshed"] == 0
+               for m in observes)
     while tick():
         pass
     assert e.done() and f.done()

@@ -20,15 +20,18 @@ The contracts pinned here, in order of load-bearing-ness:
    its status with NO tracer installed, and a tracer adds the
    ``submit.lock_wait`` span; the tick says what it did (``live``,
    ``queued``/``admitted``/``finished``, ``bucket``) and its own work
-   has phases; every span is a ``jax.profiler.TraceAnnotation`` too;
+   has phases; every span is a ``jax.profiler.TraceAnnotation`` too,
+   and says how long its thread ran (``cpu_s``), the ``tick`` how often
+   it was switched out (``nvcsw``, ``nivcsw``);
 6. terminal trace events exist for every request after drain/shutdown
    (timelines never end mid-span);
 7. every compiled step carries the module tree and the hand-placed
    scopes in its operations' ``op_name``, at unchanged compile counts;
 8. the tick is written once: every kind of pool runs
    ``GenerationPool.step``, with the same phases in the same order and
-   its own ``tick.decode`` meta, and without a tracer makes no span and
-   commits the same tokens.
+   its own ``tick.decode`` meta behind a ``tick.prep`` of its own, and
+   without a tracer makes no span, reads no clock and commits the same
+   tokens.
 """
 import json
 import re
@@ -93,8 +96,26 @@ def _prompts(n=3, seed=0):
 
 # -- 1. tracing off is a true no-op ---------------------------------------
 
+def _counting_tracer(calls, capacity=4096):
+    """A tracer whose clocks note every reading in ``calls``."""
+    import itertools
+    wall, cpu = itertools.count(), itertools.count()
+
+    def clock():
+        calls.append("clock")
+        return float(next(wall))
+
+    def cpu_clock():
+        calls.append("cpu")
+        return float(next(cpu))
+
+    return Tracer(capacity=capacity, clock=clock, cpu_clock=cpu_clock)
+
+
 def test_trace_off_buffer_untouched(model, monkeypatch):
-    tracer = Tracer(capacity=64)  # built but never installed
+    # built but never installed; its clocks count their readings
+    calls = []
+    tracer = _counting_tracer(calls, capacity=64)
     # with no tracer, submit(), pool.step() and _run_tick build no span
     # and no profiler annotation: constructing either one fails the run
     built = []
@@ -102,10 +123,21 @@ def test_trace_off_buffer_untouched(model, monkeypatch):
                         lambda self, *a: built.append(a))
     monkeypatch.setattr(trace, "TraceAnnotation",
                         lambda *a, **k: built.append(a))
+    # nor is any thread's CPU clock or usage read
+    monkeypatch.setattr(trace.time, "thread_time",
+                        lambda: calls.append("thread_time") or 0.0)
+    monkeypatch.setattr(trace, "thread_switches",
+                        lambda: calls.append("rusage"))
     eng = _engine(model)
-    statuses = _run(eng, _prompts(2), 5)
+    streams = [eng.submit(p, 5) for p in _prompts(2)]
+    while eng.pump(8):
+        pass
+    statuses = [s.result(timeout_s=0) for s in streams]
     assert all(st.state == RequestState.DONE for st in statuses)
     assert built == []
+    assert calls == []
+    # no token was stamped, no stream grew a deque for stamps
+    assert all(s._stamps is None for s in streams)
     assert len(tracer.recorder) == 0
     assert tracer.recorder.total_events == 0
     assert tracer.recorder.dropped == 0
@@ -115,6 +147,38 @@ def test_trace_off_buffer_untouched(model, monkeypatch):
         "serving_trace_events_dropped_total"] == 0
     # and the output is what it always was: token-identical engine runs
     # need no tracer — pinned elsewhere; here we only pin the no-op
+
+
+def test_trace_on_reads_its_clocks_where_the_docs_say(model):
+    # the count that is 0 above, under a tracer: two readings of each
+    # clock a span, one of the wall clock an instant or a stamped token,
+    # two readings of the thread's usage a tick
+    if trace.thread_switches() is None:
+        pytest.skip("no per-thread usage on this platform")
+    calls, usage = [], []
+    tracer = _counting_tracer(calls)
+    real = trace.resource.getrusage
+    eng = _engine(model)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(trace.resource, "getrusage",
+                   lambda who: usage.append(who) or real(who))
+        with trace.tracing(tracer):
+            streams = [eng.submit(p, 5) for p in _prompts(2)]
+            while eng.pump(8):
+                pass
+    evs = tracer.recorder.snapshot()
+    spans = [e for e in evs if e.dur_s is not None]
+    ticks = [e for e in evs if e.name == "tick"]
+    tokens = sum(len(s.result(timeout_s=0).tokens) for s in streams)
+    assert tokens == 10
+    assert calls.count("cpu") == 2 * len(spans)
+    assert calls.count("clock") == \
+        2 * len(spans) + (len(evs) - len(spans)) + tokens
+    assert len(usage) == 2 * len(ticks) > 0
+    # every token was stamped with its place in its stream, in order
+    assert [list(s._stamps) for s in streams] == [
+        [(i, t) for i, (_, t) in enumerate(s._stamps)] for s in streams]
+    assert all(len(s._stamps) == 5 for s in streams)
 
 
 def test_module_instant_is_noop_when_off():
@@ -134,9 +198,9 @@ def test_lifecycle_and_phase_events(model):
     assert all(st.state == RequestState.DONE for st in statuses)
     evs = tracer.recorder.snapshot()
     names = {e.name for e in evs}
-    for phase in ("tick", "tick.admit", "tick.prefill", "tick.decode",
-                  "tick.sample", "tick.deliver", "tick.govern",
-                  "tick.observe", "tick.journal"):
+    for phase in ("tick", "tick.admit", "tick.prefill", "tick.prep",
+                  "tick.decode", "tick.sample", "tick.deliver",
+                  "tick.govern", "tick.observe", "tick.journal"):
         assert phase in names, phase
     # per-request lifecycle in timestamp order
     for st in statuses:
@@ -286,13 +350,78 @@ def test_housekeeping_phases_lie_inside_their_tick(model):
 
 
 def test_span_set_adds_late_meta():
-    clock = iter([1.0, 3.5])
-    tr = Tracer(capacity=4, clock=lambda: next(clock))
+    clock, cpu = iter([1.0, 3.5]), iter([10.0, 10.75])
+    tr = Tracer(capacity=4, clock=lambda: next(clock),
+                cpu_clock=lambda: next(cpu))
     with tr.span("tick", tick=7) as span:
         span.set(admitted=2, finished=1)
     (ev,) = tr.recorder.snapshot()
     assert (ev.ts, ev.dur_s) == (1.0, 2.5)
-    assert ev.meta == {"tick": 7, "admitted": 2, "finished": 1}
+    # under injected clocks exactly: 0.75 s of the 2.5 on the CPU
+    assert ev.meta == {"tick": 7, "admitted": 2, "finished": 1,
+                       "cpu_s": 0.75}
+
+
+def test_a_span_says_how_long_its_thread_ran():
+    import time
+    tr = Tracer(capacity=4)
+    with tr.span("sleeps"):
+        time.sleep(0.05)
+    with tr.span("spins"):
+        end = time.perf_counter() + 0.05
+        while time.perf_counter() < end:
+            pass
+    sleeps, spins = tr.recorder.snapshot()
+    assert sleeps.dur_s >= 0.05 and 0.0 <= sleeps.meta["cpu_s"] < 0.01
+    # a thread that never waits ran for all of its span, less what the
+    # machine took from it (a loaded test host: half is asked for)
+    assert 0.5 * spins.dur_s <= spins.meta["cpu_s"] <= spins.dur_s
+    # the CPU clock is the calling thread's: a span on another thread
+    # does not see this one's spinning
+    def aside():
+        with tr.span("aside"):
+            time.sleep(0.02)
+    t = threading.Thread(target=aside)
+    t.start()
+    end = time.perf_counter() + 0.02
+    while time.perf_counter() < end:
+        pass
+    t.join(timeout=10.0)
+    assert not t.is_alive()
+    assert tr.recorder.snapshot()[-1].meta["cpu_s"] < 0.01
+
+
+def test_the_tick_says_how_often_its_thread_was_switched_out(
+        model, monkeypatch):
+    eng = _engine(model)
+    tracer = eng.start_trace(capacity=1024)
+    try:
+        _run(eng, _prompts(2), 4)
+    finally:
+        eng.stop_trace()
+    ticks = [e.meta for e in tracer.recorder.snapshot()
+             if e.name == "tick"]
+    assert ticks
+    if trace.thread_switches() is None:
+        assert all("nvcsw" not in m and "nivcsw" not in m for m in ticks)
+    else:
+        for m in ticks:
+            assert isinstance(m["nvcsw"], int) and m["nvcsw"] >= 0
+            assert isinstance(m["nivcsw"], int) and m["nivcsw"] >= 0
+    # where the platform counts no switches by thread the keys are
+    # absent and nothing else of the tick changes
+    monkeypatch.setattr(trace, "resource", None)
+    assert trace.thread_switches() is None
+    eng = _engine(model)
+    tracer = eng.start_trace(capacity=1024)
+    try:
+        _run(eng, _prompts(1), 3)
+    finally:
+        eng.stop_trace()
+    ticks = [e.meta for e in tracer.recorder.snapshot()
+             if e.name == "tick"]
+    assert ticks and all(set(m) == {"tick", "queued", "admitted",
+                                    "finished", "cpu_s"} for m in ticks)
 
 
 def test_every_span_is_a_profiler_annotation_too(model, monkeypatch):
@@ -328,7 +457,15 @@ def test_every_span_is_a_profiler_annotation_too(model, monkeypatch):
     by_name = {}
     for name, stats, _ in seen:
         by_name.setdefault(name, stats)
+    # every annotation's stats end with what its span's meta ends with:
+    # the thread's CPU time, and on the tick its switches
+    cpu = {name: stats.pop("cpu_s") for name, stats in by_name.items()}
+    assert all(isinstance(v, float) and v >= 0.0 for v in cpu.values())
+    switches = {k: by_name["tick"].pop(k) for k in ("nvcsw", "nivcsw")
+                if trace.thread_switches() is not None}
+    assert all(isinstance(v, int) for v in switches.values())
     assert by_name["submit.lock_wait"] == {"rid": "r0"}
+    assert by_name["tick.prep"] == {"rows": 1, "uploaded": 1}
     assert by_name["tick.decode"] == {"live": 1, "slots": 2, "greedy": 1,
                                       "ahead": 0}
     # one call into the engine for the download's tokens (docs 5t)
@@ -876,23 +1013,28 @@ def test_a_ticks_phases_in_order_with_the_pools_meta(model, kind):
     trace.uninstall()
     admit_phase = r"tick\.admit( tick\.prefill)*"
     settle = r"tick\.sample tick\.deliver"
+    # a launch is its preparation, then its dispatch; a preparation that
+    # finds no row to launch (``rows`` 0) stands alone
+    launch = r"tick\.prep( tick\.decode)?"
     if type(pool)._depth:
         # the host a step behind the device: a tick that finds nothing
         # in flight admits first and launches twice; every other
         # launches step t+1, THEN downloads step t, and admits under
         # the step in flight (docs/DESIGN.md 5t)
-        order = (admit_phase + r"( tick\.decode){0,2}( " + settle + ")?"
-                 + r"|(tick\.decode )?" + settle
+        order = (admit_phase + "( " + launch + "){1,2}( " + settle + ")?"
+                 + "|(" + launch + " )?" + settle
                  + "( " + admit_phase + ")?")
     else:
-        order = admit_phase + r"( tick\.decode " + settle + ")?"
-    admitted, aheads = 0, []
+        order = admit_phase + " " + launch + "( " + settle + ")?"
+    admitted, aheads, uploads = 0, [], []
     for events in ticks:
         names = " ".join(e.name for e in events)
         assert re.fullmatch(order, names), names
         admit = next(e for e in events if e.name == "tick.admit") \
             if "tick.admit" in names else None
-        for e in events:
+        for e, after in zip(events, events[1:] + [None]):
+            # every span says how long its thread ran
+            assert 0.0 <= e.meta.pop("cpu_s") <= e.dur_s
             if e.name == "tick.prefill":
                 # nested in the admit phase, one per admitted request
                 assert admit.ts <= e.ts
@@ -901,6 +1043,17 @@ def test_a_ticks_phases_in_order_with_the_pools_meta(model, kind):
                 assert set(e.meta) == {"prompt_tokens", "bucket"}
                 assert e.meta["bucket"] == 32
                 admitted += 1
+            elif e.name == "tick.prep":
+                # once a launch, before its dispatch and closed by then
+                assert set(e.meta) == {"rows", "uploaded"}
+                launched = after is not None and after.name == "tick.decode"
+                assert launched == (e.meta["rows"] > 0)
+                if launched:
+                    assert e.ts + e.dur_s <= after.ts
+                    assert e.meta["rows"] == after.meta["live"]
+                    uploads.append(e.meta["uploaded"])
+                else:
+                    assert e.meta["uploaded"] == 0
             elif e.name == "tick.decode":
                 assert set(e.meta) == _DECODE_META[kind], e.meta
                 assert 1 <= e.meta["live"] <= e.meta["slots"] == 2
@@ -909,8 +1062,16 @@ def test_a_ticks_phases_in_order_with_the_pools_meta(model, kind):
                 assert set(e.meta) == {"rows", "ended", "hook_calls"}
                 assert 0 <= e.meta["ended"] <= e.meta["rows"] <= 2
             else:
-                assert e.meta is None, (e.name, e.meta)
+                assert e.meta == {}, (e.name, e.meta)
     assert admitted == 3
+    assert len(uploads) == len(aheads)
+    if kind == "block":
+        # no row vectors there: the control table rides every launch
+        assert set(uploads) == {0}
+    else:
+        # put on a changed row set, left standing on a repeated one
+        assert uploads[0] == 1 and 0 in uploads
+        assert 2 <= sum(uploads) <= 6
     # a launch that finds a step in flight says so: at depth 1 every one
     # but the first after the pool ran empty (the two requests admitted
     # together end together, the third starts over)
