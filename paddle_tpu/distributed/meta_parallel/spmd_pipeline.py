@@ -623,7 +623,11 @@ class PipelineTrainStep:
                 [True] * len(fakes))
             return loss, new_core, new_core_st, new_outer, new_outer_st
 
-        donate = (0, 1, 2, 3)
+        # no donation on the CPU, as in jit/decode.py: XLA:CPU aborts on the
+        # second call of this multi-device step once the executable was
+        # LOADED from a persistent compile cache with its arguments donated
+        # (compiled fresh it runs; ROADMAP D7)
+        donate = (0, 1, 2, 3) if jax.default_backend() != "cpu" else ()
         self._jitted = jax.jit(step, donate_argnums=donate)
 
     def __call__(self, x, y):

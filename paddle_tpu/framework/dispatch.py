@@ -39,6 +39,11 @@ def _amp_apply(fn: Callable, op_name: str) -> Callable:
     black-listed ops are forced to float32; everything else runs in the
     dtype it was given.  The cast sits INSIDE the differentiated function,
     so vjp transposes it and gradients return in the caller's dtype.
+
+    A black-listed op may carry ``amp_upcasts_inside``, a predicate over its
+    own arguments: where it holds, the op reads its inputs as stored and
+    up-casts them inside its reductions, so no float32 copy is made in
+    front of it (docs/DESIGN.md 4; ``F.cross_entropy`` on class ids).
     """
     st = amp_state.current()
     if not st.enabled:
@@ -49,6 +54,7 @@ def _amp_apply(fn: Callable, op_name: str) -> Callable:
         tgt = jnp.float32
     else:
         return fn
+    inside = op_name in st.black and getattr(fn, "amp_upcasts_inside", None)
 
     def _cast(v):
         if isinstance(v, (jax.Array, np.ndarray)) \
@@ -58,6 +64,8 @@ def _amp_apply(fn: Callable, op_name: str) -> Callable:
 
     @functools.wraps(fn)
     def casted(*a, **k):
+        if inside and inside(*a, **k):
+            return fn(*a, **k)
         a = _tree.tree_map(_cast, a)
         k = _tree.tree_map(_cast, k)
         return fn(*a, **k)

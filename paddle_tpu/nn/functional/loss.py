@@ -1,10 +1,19 @@
 """Loss functionals (reference: python/paddle/nn/functional/loss.py; fused
-kernel parity: softmax_with_cross_entropy_op.cc:325 — the log-softmax + gather
-composition here is a single XLA fusion on TPU, which is exactly what the
-reference's fused CUDA kernel hand-writes).
+kernel parity: softmax_with_cross_entropy_op.cc:325).
+
+The hard-label softmax cross-entropy is ONE op with its own backward
+(``_softmax_xent_rows``, a ``jax.custom_vjp``), as the reference's fused
+CUDA kernel is.  It reads the logits in the type they are stored in,
+up-casts them to float32 inside its reductions (never as an array), keeps
+``logsumexp`` a row and the logits themselves for the backward, and returns
+the gradient in the logits' type.  The log-softmax + gather
+composition it replaces was six device operations on the chip, one of
+them a float32 copy of the logits (PERF.md section 6).  Soft labels,
+``use_softmax=False`` and label smoothing keep that composition.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -27,6 +36,59 @@ def log_loss(input, label, epsilon: float = 1e-4):
     return -label * jnp.log(input + epsilon) - (1 - label) * jnp.log(1 - input + epsilon)
 
 
+def _label_hits(x, lbl):
+    """``[..., V]`` mask of each row's label: a select inside a reduction
+    stands where a gather (and, backward, a scatter) would."""
+    return jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1) \
+        == lbl[..., None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _softmax_xent_rows(x, lbl, ignore_index):
+    """Per-row ``logsumexp(x) - x[lbl]`` over the last axis, 0 where
+    ``lbl == ignore_index``: ``x`` ``[..., V]`` of any float type, ``lbl``
+    ``[...]`` int32.  The loss is float32 (float64 from float64 logits)."""
+    return _xent_rows_fwd(x, lbl, ignore_index)[0]
+
+
+def _xent_rows_fwd(x, lbl, ignore_index):
+    with jax.named_scope("cross_entropy"):
+        xf = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+        m = jnp.max(xf, axis=-1)
+        lse = m + jnp.log(jnp.sum(jnp.exp(xf - m[..., None]), axis=-1))
+        picked = jnp.sum(jnp.where(_label_hits(x, lbl), xf, 0), axis=-1)
+        loss = jnp.where(lbl != ignore_index, lse - picked, 0)
+    return loss, (x, lse, lbl)
+
+
+def _xent_rows_bwd(ignore_index, res, g):
+    x, lse, lbl = res
+    with jax.named_scope("cross_entropy"):
+        p = jnp.exp(x.astype(lse.dtype) - lse[..., None])
+        dx = (p - _label_hits(x, lbl).astype(lse.dtype)) \
+            * g.astype(lse.dtype)[..., None]
+        dx = jnp.where((lbl != ignore_index)[..., None], dx, 0)
+    return dx.astype(x.dtype), None
+
+
+_softmax_xent_rows.defvjp(_xent_rows_fwd, _xent_rows_bwd)
+
+
+def _is_soft(input, label, soft_label) -> bool:
+    return bool(soft_label) or (label.ndim == input.ndim
+                                and label.shape == input.shape)
+
+
+def _takes_fused_core(input, label, weight=None, ignore_index=-100,
+                      reduction="mean", soft_label=False, axis=-1,
+                      use_softmax=True, label_smoothing=0.0) -> bool:
+    """Whether ``cross_entropy`` with these arguments runs the fused core,
+    which up-casts inside itself (``framework/dispatch.py`` asks, so that
+    autocast's black list puts no float32 copy in front of it)."""
+    return bool(use_softmax) and not label_smoothing > 0.0 \
+        and not _is_soft(input, label, soft_label)
+
+
 def cross_entropy(
     input,
     label,
@@ -41,43 +103,72 @@ def cross_entropy(
     """softmax_with_cross_entropy fused semantics.
 
     ``input``: logits (or probabilities when use_softmax=False); ``label``:
-    int class ids (or soft distributions when soft_label=True).
+    int class ids (or soft distributions when soft_label=True).  With class
+    ids, ``use_softmax`` and no smoothing the loss is computed in float32
+    whatever the logits' type, and is float32.
     """
+    if _takes_fused_core(input, label, soft_label=soft_label,
+                         use_softmax=use_softmax,
+                         label_smoothing=label_smoothing):
+        return _hard_label_loss(input, label, weight, ignore_index,
+                                reduction, axis)
     if use_softmax:
         logp = jax.nn.log_softmax(input, axis=axis)
     else:
         logp = jnp.log(jnp.clip(input, 1e-10, 1.0))
-    if soft_label or (label.ndim == input.ndim and label.shape == input.shape):
+    if _is_soft(input, label, soft_label):
         soft = label
         if label_smoothing > 0.0:
             n = input.shape[axis]
             soft = soft * (1.0 - label_smoothing) + label_smoothing / n
         loss = -jnp.sum(soft * logp, axis=axis)
-        valid = None
-    else:
-        lbl = label
-        if lbl.ndim == input.ndim and lbl.shape[axis] == 1:
-            lbl = jnp.squeeze(lbl, axis=axis)
-        lbl = lbl.astype(jnp.int32)
-        valid = lbl != ignore_index
-        safe = jnp.where(valid, lbl, 0)
-        picked = jnp.take_along_axis(logp, jnp.expand_dims(safe, axis), axis=axis)
-        loss = -jnp.squeeze(picked, axis=axis)
-        if label_smoothing > 0.0:
-            n = input.shape[axis]
-            smooth_loss = -jnp.mean(logp, axis=axis)
-            loss = (1.0 - label_smoothing) * loss + label_smoothing * smooth_loss
+        return _reduce(loss, reduction)
+    lbl, valid, safe = _class_ids(input, label, ignore_index, axis)
+    picked = jnp.take_along_axis(logp, jnp.expand_dims(safe, axis), axis=axis)
+    loss = -jnp.squeeze(picked, axis=axis)
+    if label_smoothing > 0.0:
+        smooth_loss = -jnp.mean(logp, axis=axis)
+        loss = (1.0 - label_smoothing) * loss + label_smoothing * smooth_loss
+    return _weigh_and_reduce(loss, valid, safe, weight, reduction)
+
+
+def _class_ids(input, label, ignore_index, axis):
+    lbl = label
+    if lbl.ndim == input.ndim and lbl.shape[axis] == 1:
+        lbl = jnp.squeeze(lbl, axis=axis)
+    lbl = lbl.astype(jnp.int32)
+    valid = lbl != ignore_index
+    return lbl, valid, jnp.where(valid, lbl, 0)
+
+
+def _weigh_and_reduce(loss, valid, safe, weight, reduction):
+    """Class weights, zeros on ignored rows and the reduction of a per-row
+    hard-label loss; ``mean`` is over the valid rows (their weights)."""
+    if weight is not None:
+        w = weight[safe]
+        loss = loss * w
+    loss = jnp.where(valid, loss, 0.0)
+    if reduction == "mean":
         if weight is not None:
-            w = weight[safe]
-            loss = loss * w
-        loss = jnp.where(valid, loss, 0.0)
-        if reduction == "mean":
-            if weight is not None:
-                denom = jnp.sum(jnp.where(valid, weight[safe], 0.0))
-            else:
-                denom = jnp.maximum(jnp.sum(valid.astype(loss.dtype)), 1.0)
-            return jnp.sum(loss) / denom
+            # no valid row: 0 / 1 and not 0 / 0, as without weights
+            denom = jnp.sum(jnp.where(valid, w, 0.0))
+            denom = jnp.where(denom == 0, 1.0, denom)
+        else:
+            denom = jnp.maximum(jnp.sum(valid.astype(loss.dtype)), 1.0)
+        return jnp.sum(loss) / denom
     return _reduce(loss, reduction)
+
+
+def _hard_label_loss(input, label, weight, ignore_index, reduction, axis):
+    lbl, valid, safe = _class_ids(input, label, ignore_index, axis)
+    loss = _softmax_xent_rows(jnp.moveaxis(input, axis, -1), lbl,
+                              ignore_index)
+    if weight is not None:
+        weight = weight.astype(loss.dtype)
+    return _weigh_and_reduce(loss, valid, safe, weight, reduction)
+
+
+cross_entropy.amp_upcasts_inside = _takes_fused_core
 
 
 def softmax_with_cross_entropy(
@@ -92,6 +183,17 @@ def softmax_with_cross_entropy(
     if return_softmax:
         return loss, jax.nn.softmax(logits, axis=axis)
     return loss
+
+
+def _swce_takes_fused_core(logits, label, soft_label=False,
+                           ignore_index=-100, numeric_stable_mode=True,
+                           return_softmax=False, axis=-1) -> bool:
+    # the softmax handed back is computed in the type it is given
+    return not return_softmax and _takes_fused_core(
+        logits, label, soft_label=soft_label)
+
+
+softmax_with_cross_entropy.amp_upcasts_inside = _swce_takes_fused_core
 
 
 def nll_loss(input, label, weight=None, ignore_index: int = -100, reduction: str = "mean"):

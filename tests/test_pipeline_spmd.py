@@ -284,9 +284,13 @@ def test_pipeline_homogeneous_no_prefix():
     x = rng.randn(8, S, D).astype("float32")
     t = rng.randn(8, S, D).astype("float32")
     l0 = float(engine.train_batch((pt.to_tensor(x), pt.to_tensor(t)), opt).value)
+    before = engine._spmd_step._stacked[0]
     l1 = float(engine.train_batch((pt.to_tensor(x), pt.to_tensor(t)), opt).value)
     assert engine._spmd_step is not None
     assert np.isfinite(l0) and l1 < l0
+    # the CPU step donates nothing: loaded from the persistent compile
+    # cache with donated arguments, XLA:CPU aborted on this second call
+    assert not before.is_deleted()
 
 
 def test_pipeline_rank_preserving_prefix_remainder():
