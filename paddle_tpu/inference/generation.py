@@ -682,10 +682,10 @@ class GenerationPool:
             if not self._layout.transferable:
                 raise InvalidArgumentError(
                     "spill_tier='disk' writes one kind of cache entry to "
-                    "a PTKV file (K/V blocks or state rows); "
-                    "cache_layout=%r has both (%s) — keep spill_tier="
-                    "'host', which carries both in memory"
-                    % (cache_layout, self._layout.recurrent_layers()))
+                    "a PTKV file (K/V blocks by head, or state rows); "
+                    "cache_layout=%r %s — keep spill_tier='host', which "
+                    "carries it in memory"
+                    % (cache_layout, self._layout.not_transferable()))
             if spill_dir is None:
                 raise InvalidArgumentError(
                     "spill_tier='disk' needs spill_dir= (the directory "
@@ -811,6 +811,19 @@ class GenerationPool:
         layout without one, naming the layers that keep a recurrent
         state where that is why."""
         lay = self._layout
+        if lay.prompt_from_zero and (prefill_chunk_tokens is not None
+                                     or prefix_sharing):
+            raise InvalidArgumentError(
+                "%s cannot apply to cache_layout=%r: a latent cache entry "
+                "runs the expanded form of latent attention (a prompt "
+                "over its own keys) only for a chunk that starts at "
+                "position 0; one that starts mid-way runs absorbed "
+                "through the XLA composition, whose float32 scores are "
+                "[rows, heads, chunk, context], and no kernel takes a "
+                "prompt-sized chunk against cached latents yet"
+                % ("prefill_chunk_tokens"
+                   if prefill_chunk_tokens is not None
+                   else "prefix_sharing", lay.name))
         if prefill_chunk_tokens is not None \
                 and not (lay.paged and lay.positional):
             # the chunk path writes through the block table (per-slot
@@ -1575,9 +1588,7 @@ class GenerationPool:
         # kinds) gives the slot's state rows whole
         lays = self._layout.layouts(self._cache)
         host = jax.device_get([
-            (c.k[gather], c.v[gather])
-            + ((c.k_scale[gather], c.v_scale[gather])
-               if c.k_scale is not None else ())
+            tuple(getattr(c, f)[gather] for f in lay.payload_fields(c))
             if lay.paged else
             tuple(getattr(c, f)[slot] for f in lay.state_fields(c))
             for lay, c in zip(lays, self._cache)])
@@ -1801,14 +1812,11 @@ class GenerationPool:
             upd = dict(table=c.table.at[slot].set(row),
                        index=c.index.at[slot].set(pos_dev))
             if upload:
-                fields = host_src[layer]
-                upd["k"] = c.k.at[ids_dev].set(jnp.asarray(fields[0][sel]))
-                upd["v"] = c.v.at[ids_dev].set(jnp.asarray(fields[1][sel]))
-                if c.k_scale is not None:
-                    upd["k_scale"] = c.k_scale.at[ids_dev].set(
-                        jnp.asarray(fields[2][sel]))
-                    upd["v_scale"] = c.v_scale.at[ids_dev].set(
-                        jnp.asarray(fields[3][sel]))
+                # the payload fields in the order the spill took them
+                # (K, V and an int8 pool's scales; a latent)
+                for f, arr in zip(lay.payload_fields(c), host_src[layer]):
+                    upd[f] = getattr(c, f).at[ids_dev].set(
+                        jnp.asarray(arr[sel]))
             new_cache.append(c._replace(**upd))
         self._cache = new_cache
         state = _SlotState(sp.rid, sp.ids, sp.tokens, sp.remaining,
@@ -3061,7 +3069,8 @@ class GenerationPool:
         table entries the live slots' positions reach (what the
         attention kernel fetches and computes: ``ops/pallas_decode.py``
         skips the rest), and ``table_blocks``, slots x table width;
-        where entries are recurrent (``state_layers``) ``state_bytes``.
+        where entries are recurrent (``state_layers``) ``state_bytes``;
+        ``latent_layers`` where the paged entries hold latents.
         A model that mixes kinds carries both in the one span, and the
         layer counts that say what each figure is over."""
         meta = {}
@@ -3075,6 +3084,10 @@ class GenerationPool:
                 live_blocks=sum(self._last_position(slot, st) // bs + 1
                                 for slot, st in self._rows),
                 table_blocks=self.slots * self._max_blocks)
+        if "latent" in self._by_kind:
+            # the paged figures above run over latent entries: a block is
+            # one latent a position, not K/V by head
+            meta["latent_layers"] = self._by_kind["latent"][0]
         if len(self._by_kind) > 1:
             meta.update(
                 state_layers=self._by_kind.get("recurrent", (0,))[0],
@@ -3417,18 +3430,17 @@ class GenerationPool:
                 stats["pool_bytes_per_device"] = \
                     state_total // self._mesh.dp
             return stats
-        # the K/V figures run over the entries that hold K/V; a model
-        # that mixes kinds adds its recurrent entries' whole state (the
-        # same at any context) to what is resident and reachable
-        kv = [c for lay, c in zip(self._layout.layouts(self._cache),
-                                  self._cache) if lay.positional]
-        first = kv[0]
+        # the positional figures run over the entries that address
+        # positions; a model that mixes kinds adds its recurrent entries'
+        # whole state (the same at any context) to what is resident and
+        # reachable
         state_total = self._state_bytes_slot * self.slots
-        dims = dict(max_len=self.max_len, num_layers=len(kv),
-                    num_heads=first.k.shape[1], head_dim=first.k.shape[3],
-                    dtype=first.k.dtype)
-        dense_bytes = kv_reachable_bytes([self.max_len] * self.slots,
-                                         layout="dense", **dims)
+        # every slot at max_len over those entries (K/V by head, scales
+        # included, or a latent): what ``kv_reachable_bytes(...,
+        # layout="dense")`` gives for K/V
+        dense_bytes = self.slots * sum(
+            b for kind, (_, b) in self._by_kind.items()
+            if kind != "recurrent")
         # every byte figure below is dtype-aware (int8 caches count the
         # int8 K/V plus the riding fp32 scales — kv_reachable_bytes),
         # and the dtype is stamped so a serving record can never present

@@ -229,15 +229,11 @@ class SpeculativePool(GenerationPool):
 
     def _draft_insert(self, pool_cache, row_cache, slot, length):
         """Splice a batch-1 draft prefill into ``slot`` (dense fp32 —
-        the draft-side half of the base pool's ``_insert``)."""
-        out = []
-        for cp, cr in zip(pool_cache, row_cache):
-            out.append(cp._replace(
-                k=cp.k.at[slot].set(cr.k[0].astype(cp.k.dtype)),
-                v=cp.v.at[slot].set(cr.v[0].astype(cp.v.dtype)),
-                index=cp.index.at[slot].set(
-                    jnp.asarray(length, jnp.int32))))
-        return out
+        the draft-side half of the base pool's ``_insert``; the layout's
+        own splice, so whatever payload the draft's entries keep, K/V or
+        a latent, goes in whole)."""
+        return self._draft_session._layout.insert_row(
+            pool_cache, row_cache, slot, length)
 
     def _pool_verify(self, param_vals, buf_vals, cache, chunk, active,
                      adapter):

@@ -63,7 +63,8 @@ def kv_arg_bytes(cache) -> int:
         # "state" is the recurrent layout's whole payload (jit.cache):
         # positional caches have no such field, so the transformer
         # figures are unchanged
-        for field in ("k", "v", "k_scale", "v_scale", "state", "norm"):
+        for field in ("k", "v", "k_scale", "v_scale", "state", "norm",
+                      "latent"):
             a = getattr(c, field, None)
             if a is not None:
                 total += int(a.size) * a.dtype.itemsize

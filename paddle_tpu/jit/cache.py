@@ -28,6 +28,23 @@ capabilities are the MEET of its entries': it has a block table if any
 entry has one, addresses positions only if every entry does, spills only
 if every entry spills.  The consumers ask the layout, never a string.
 
+==============  ==========================  ================================
+kind             an entry's type says so by  what a position keeps a layer
+==============  ==========================  ================================
+``dense``        neither field below         ``k``, ``v`` ``[B, H, S, D]``
+``paged``        a ``table``                 ``k``, ``v`` blocks by head,
+                                             ``[num_blocks, H, bs, D]``
+``latent``       a ``table`` and a           ONE compressed latent, no head:
+                 ``latent`` field            ``latent [num_blocks, bs, W]``
+``recurrent``    a ``limit``                 nothing: a state of constant size
+==============  ==========================  ================================
+
+What an entry holds besides its bookkeeping (``index``, ``table``,
+``limit``) are its PAYLOAD fields (:meth:`CacheLayout.payload_fields`):
+the splice, the in-memory spill, the dtype stamp and the byte figures
+walk them, whatever they are called, so the latent kind needs no second
+allocator, table mask or preempt path: it is one more kind of paged entry.
+
 ==================  =====================================================
 operation            who calls it / what it decides
 ==================  =====================================================
@@ -97,7 +114,8 @@ import numpy as np
 
 from ..core.errors import InvalidArgumentError
 
-__all__ = ["CacheLayout", "DenseLayout", "PagedLayout", "RecurrentLayout",
+__all__ = ["CacheLayout", "DenseLayout", "PagedLayout", "LatentLayout",
+           "RecurrentLayout",
            "ComposedLayout", "CACHE_LAYOUTS", "get_layout", "entry_layout",
            "layout_of"]
 
@@ -124,6 +142,18 @@ class CacheLayout:
     transferable: bool = False
     #: some entry is a state of constant size
     recurrent: bool = False
+    #: some entry has its prompt form (the latent entry's expanded path)
+    #: only for a chunk that starts at position 0: what starts a prompt's
+    #: chunk mid-way (chunked prefill, prefix sharing) is refused
+    prompt_from_zero: bool = False
+    @staticmethod
+    def payload_fields(entry) -> tuple:
+        """The fields of ``entry`` that hold cache content, in the entry's
+        own order: every field but the bookkeeping (``index``, ``table``,
+        ``limit``) that is not None (a float K/V entry's scales are)."""
+        return tuple(f for f in entry._fields
+                     if f not in ("index", "table", "limit")
+                     and getattr(entry, f) is not None)
 
     def layouts(self, cache) -> tuple:
         """The layout of every entry of ``cache``, in order (a singleton:
@@ -138,6 +168,11 @@ class CacheLayout:
     def recurrent_layers(self) -> str:
         """How a refusal names the layers that keep a recurrent state."""
         return "every layer keeps a state of constant size"
+
+    def not_transferable(self) -> str:
+        """How a refusal says why this layout has no disk tier and no PTKV
+        transfer."""
+        return "has no per-slot granularity to write"
 
     # -- prefill hooks (traced) ------------------------------------------
     def begin_prefill_entry(self, c, true_len):
@@ -200,7 +235,7 @@ class CacheLayout:
             % (field, self.name))
 
     def entry_dtype_str(self, c) -> str:
-        return str(np.dtype(c.k.dtype))
+        return str(np.dtype(getattr(c, self.payload_fields(c)[0]).dtype))
 
     def cache_dtype_str(self, cache) -> str:
         """Payload dtype as provenance (``cache_stats`` /
@@ -216,15 +251,13 @@ class CacheLayout:
         K/V slab (scales included): what admitting one more concurrent
         request costs in HBM when every request can run to max_len."""
         total = 0
-        for field in ("k", "v", "k_scale", "v_scale"):
-            a = getattr(c, field, None)
-            if a is None:
-                continue
+        for field in self.payload_fields(c):
+            a = getattr(c, field)
             per_tok = int(np.prod(a.shape)) * a.dtype.itemsize
             # dense: [slots, H, max_len, D] -> bytes / slots.
             # paged: [blocks, H, bs, D] -> bytes-per-token * max_len
             if self.paged:
-                tokens = int(a.shape[0]) * int(a.shape[2])
+                tokens = int(a.shape[0]) * int(a.shape[self.block_axis])
                 total += per_tok // tokens * max_len
             else:
                 total += per_tok // int(slots)
@@ -252,15 +285,14 @@ class DenseLayout(CacheLayout):
     name = "dense"
 
     def insert_entry(self, cp, cr, slot, length, blocks=None):
-        upd = dict(
-            k=cp.k.at[slot].set(cr.k[0].astype(cp.k.dtype)),
-            v=cp.v.at[slot].set(cr.v[0].astype(cp.v.dtype)),
-            index=cp.index.at[slot].set(jnp.asarray(length, jnp.int32)))
-        if cp.k_scale is not None:
-            upd.update(
-                k_scale=cp.k_scale.at[slot].set(cr.k_scale[0]),
-                v_scale=cp.v_scale.at[slot].set(cr.v_scale[0]))
-        return cp._replace(**upd)
+        # every payload field of the row (K/V and an int8 cache's scales,
+        # or a latent): the slot's slab whole
+        upd = {f: getattr(cp, f).at[slot].set(
+                   getattr(cr, f)[0].astype(getattr(cp, f).dtype))
+               for f in self.payload_fields(cp)}
+        return cp._replace(
+            index=cp.index.at[slot].set(jnp.asarray(length, jnp.int32)),
+            **upd)
 
 
 class PagedLayout(CacheLayout):
@@ -272,30 +304,73 @@ class PagedLayout(CacheLayout):
     paged = True
     spillable = True
     transferable = True
+    #: the axis of a payload field that runs over a block's positions
+    #: (``[num_blocks, H, bs, D]``; the scales lack the last)
+    block_axis = 2
 
     def insert_entry(self, cp, cr, slot, length, blocks=None):
         # the row cache is an identity-tabled batch-1 pool (row block
-        # 1+j holds logical block j), so the splice is ONE scatter
-        # copying every logical block to the physical ids in ``blocks``;
-        # entries past the reservation are 0, harmlessly dumping their
-        # pad-garbage blocks into the scratch block
-        upd = dict(
-            k=cp.k.at[blocks].set(cr.k[1:].astype(cp.k.dtype)),
-            v=cp.v.at[blocks].set(cr.v[1:].astype(cp.v.dtype)),
+        # 1+j holds logical block j), so the splice is ONE scatter a
+        # payload field copying every logical block to the physical ids
+        # in ``blocks``; entries past the reservation are 0, harmlessly
+        # dumping their pad-garbage blocks into the scratch block.  An
+        # int8 cache's per-block scales are payload like K/V: they
+        # splice with their blocks (same ids), so a spliced block can
+        # never be read under another request's scale
+        upd = {f: getattr(cp, f).at[blocks].set(
+                   getattr(cr, f)[1:].astype(getattr(cp, f).dtype))
+               for f in self.payload_fields(cp)}
+        return cp._replace(
             table=cp.table.at[slot].set(blocks),
-            index=cp.index.at[slot].set(jnp.asarray(length, jnp.int32)))
-        if cp.k_scale is not None:
-            # int8 cache: the row's per-block scales splice with
-            # their blocks (same ids), so a spliced block can never
-            # be read under another request's scale
-            upd.update(
-                k_scale=cp.k_scale.at[blocks].set(cr.k_scale[1:]),
-                v_scale=cp.v_scale.at[blocks].set(cr.v_scale[1:]))
-        return cp._replace(**upd)
+            index=cp.index.at[slot].set(jnp.asarray(length, jnp.int32)),
+            **upd)
 
     def fingerprint_extra(self, pool) -> dict:
         return {"block_size": pool._block_size,
                 "num_blocks": pool._num_blocks}
+
+
+class LatentLayout(PagedLayout):
+    """One more kind of paged entry: the compressed latent of latent
+    attention (``nn.LatentAttention``), ONE vector a position shared by
+    every head, in blocks ``latent [num_blocks, bs, W]`` (the K/V latent
+    after its norm, the one rotary key head after its turn, zeros up to
+    whole 128-lane tiles), behind the same ``table`` and ``index`` as K/V.
+    The allocator, the scratch-block masking, ``live_blocks`` and preempt
+    and resume in memory are the paged layout's, unchanged.
+
+    What it does not carry, each refused by a typed error that names the
+    latent entry: an int8 pool (no scale has a head to ride with:
+    ``payload_dtypes``), the disk tier and PTKV transfer (their files hold
+    K/V blocks by head: not ``transferable``), and prefix sharing and
+    chunked prefill (``prompt_from_zero``: only a chunk known to start at
+    position 0 is attended in the EXPANDED form over its own keys; one that
+    starts mid-way runs absorbed through the XLA composition, whose scores
+    are ``[rows, heads, chunk, context]``: right, and not sized for a
+    prompt's chunks)."""
+
+    name = "latent"
+    transferable = False
+    prompt_from_zero = True
+    #: the element types a latent can be stored in (``nn.LatentAttention
+    #: .gen_decode_cache`` refuses the others)
+    payload_dtypes = ("float32", "bfloat16")
+    #: ``[num_blocks, bs, W]``: no head axis
+    block_axis = 1
+
+    def not_transferable(self) -> str:
+        return ("keeps a latent entry a layer (one latent a position, no "
+                "head axis), which the file format does not hold")
+
+    def field_axes(self, field: str):
+        if field == "latent":
+            # slots' blocks over dp; the latent has no head to shard
+            # over mp: every mp shard reads the whole of it
+            return ("dp", None)
+        if field in ("table", "index"):
+            return ("dp",)
+        raise InvalidArgumentError(
+            "unknown decode-cache field %r for layout 'latent'" % (field,))
 
 
 class RecurrentLayout(CacheLayout):
@@ -418,6 +493,8 @@ class ComposedLayout(CacheLayout):
         self.paged = any(lay.paged for lay in self._layouts)
         self.spillable = all(lay.spillable for lay in self._layouts)
         self.recurrent = any(lay.recurrent for lay in self._layouts)
+        self.prompt_from_zero = any(lay.prompt_from_zero
+                                    for lay in self._layouts)
         # a PTKV file carries blocks OR state rows, never both
         self.transferable = False
 
@@ -435,6 +512,11 @@ class ComposedLayout(CacheLayout):
                            ", ".join(map(str, at[:4])),
                            ", ..." if len(at) > 4 else ""))
 
+    def not_transferable(self) -> str:
+        if self.recurrent:
+            return "has both (%s)" % self.recurrent_layers()
+        return "mixes kinds of entry (%s)" % self.name
+
     def fingerprint_extra(self, pool) -> dict:
         out = {}
         for lay in dict.fromkeys(self._layouts):
@@ -446,6 +528,10 @@ CACHE_LAYOUTS = {
     layout.name: layout
     for layout in (DenseLayout(), PagedLayout(), RecurrentLayout())
 }
+
+# no string a caller passes: ``cache_layout="paged"`` gives a model that
+# keeps latents its latent entries, and the entry's type says the rest
+_LATENT = LatentLayout()
 
 
 def get_layout(name: str) -> CacheLayout:
@@ -462,11 +548,12 @@ def get_layout(name: str) -> CacheLayout:
 
 def entry_layout(entry) -> CacheLayout:
     """The layout of ONE layer's cache entry, from its type: a block
-    ``table`` is paged, an update window ``limit`` recurrent, else dense
-    K/V."""
+    ``table`` is paged (latent where what the blocks hold is a ``latent``),
+    an update window ``limit`` recurrent, else dense (K/V, or a latent by
+    slot)."""
     fields = getattr(entry, "_fields", ())
     if "table" in fields:
-        return CACHE_LAYOUTS["paged"]
+        return _LATENT if "latent" in fields else CACHE_LAYOUTS["paged"]
     if "limit" in fields:
         return CACHE_LAYOUTS["recurrent"]
     return CACHE_LAYOUTS["dense"]

@@ -14,6 +14,10 @@ from .hybrid_mamba import (  # noqa: F401
     HybridMambaDecoderLayer,
     HybridMambaLM,
 )
+from .latent_moe import (  # noqa: F401
+    LatentMoEDecoderLayer,
+    LatentMoELM,
+)
 from .power_retention import (  # noqa: F401
     PowerRetentionDecoderLayer,
     PowerRetentionLM,

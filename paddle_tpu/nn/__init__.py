@@ -52,6 +52,9 @@ from .layer.transformer import (  # noqa: F401
 )
 from .layer.retention import PowerRetention, RetentionDecodeCache  # noqa: F401
 from .layer.mamba import MambaDecodeCache, MambaMixer  # noqa: F401
+from .layer.latent_attention import (  # noqa: F401
+    LatentAttention, LatentDecodeCache, PagedLatentDecodeCache,
+)
 from .ssm import GatedSSMBlock, RecurrentDecodeCache, SSMLM  # noqa: F401
 from . import lora  # noqa: F401
 from .lora import attach_lora, load_adapter, unload_adapter  # noqa: F401

@@ -14,8 +14,9 @@ from .activation import (  # noqa: F401
 from .common import (  # noqa: F401
     alpha_dropout, bilinear, diag_embed, dropout, dropout2d, dropout3d,
     embedding, gather_tree, interpolate, label_smooth, linear, one_hot, pad,
-    pixel_shuffle, rotary_embedding, scaled_dot_product_attention,
-    sequence_mask, temporal_shift, unfold, upsample,
+    pixel_shuffle, rotary_embedding, rotary_embedding_pairs,
+    scaled_dot_product_attention, sequence_mask, temporal_shift, unfold,
+    upsample, yarn_inv_freq, yarn_mscale,
 )
 from .conv import (  # noqa: F401
     conv1d, conv1d_transpose, conv2d, conv2d_transpose, conv3d, conv3d_transpose,
@@ -31,7 +32,7 @@ from .norm import (  # noqa: F401
     batch_norm, group_norm, instance_norm, layer_norm, local_response_norm,
     normalize, rms_norm,
 )
-from .moe import sparse_experts  # noqa: F401
+from .moe import route_top_k, sparse_experts  # noqa: F401
 from .vision import affine_grid, grid_sample  # noqa: F401
 from .pooling import (  # noqa: F401
     adaptive_avg_pool1d, adaptive_avg_pool2d, adaptive_avg_pool3d,

@@ -41,6 +41,70 @@ def rotary_embedding(x, positions, theta: float = 10000.0):
                            axis=-1).astype(x.dtype)
 
 
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """YaRN's per-frequency blend (Peng et al. 2023, as the DeepSeek-V3
+    family computes it): ``dim / 2`` inverse frequencies, numpy float32.
+
+    Pair ``i`` turns by ``theta_i = theta^(-2i/dim)`` a position.  The
+    pair that turns ``n`` times in ``original`` positions has the
+    (fractional) number ``d(n) = dim * ln(original / (2 pi n)) / (2 ln
+    theta)``.  Pairs up to ``low = floor(d(beta_fast))`` keep ``theta_i``
+    (they turn often: extrapolated), pairs from ``high = ceil(d(
+    beta_slow))`` on take ``theta_i / factor`` (interpolated), and between
+    the two the blend follows the linear ramp ``(i - low) / (high -
+    low)``."""
+    import math
+
+    import numpy as np
+
+    i = np.arange(0, dim, 2, dtype=np.float32)
+    extra = 1.0 / (np.float32(theta) ** (i / np.float32(dim)))
+    if factor <= 1:
+        return extra.astype(np.float32)
+
+    def turn_dim(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turn_dim(beta_fast)), 0)
+    high = min(math.ceil(turn_dim(beta_slow)), dim - 1)
+    span = (high - low) or 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low) / span,
+                   0.0, 1.0)
+    return (extra / np.float32(factor) * ramp
+            + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature term: ``0.1 * mscale * ln(factor) +
+    1`` (1 where ``factor <= 1``)."""
+    import math
+
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rotary_embedding_pairs(x, positions, inv_freq, scale: float = 1.0):
+    """Rotary positions on INTERLEAVED pairs, as the DeepSeek family
+    stores them: channels ``2i`` and ``2i + 1`` are one pair, turned by
+    ``position * inv_freq[i]`` (``inv_freq``: ``[D/2]``, from
+    ``yarn_inv_freq`` or ``theta^(-2i/D)``).  ``x`` ``[..., L, D]`` with
+    any leading dimensions; ``positions`` ``[L]``, or ``[B, L]`` where
+    ``x`` is ``[B, ..., L, D]`` and every row stands at positions of its
+    own.  ``scale`` multiplies cos and sin (YaRN's ``mscale`` ratio).
+    Angles and the turn in float32; the result has ``x``'s type."""
+    ang = jnp.asarray(positions).astype(jnp.float32)[..., None] \
+        * jnp.asarray(inv_freq, jnp.float32)                 # [(B,) L, D/2]
+    if ang.ndim == 3:
+        ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 3)
+                          + ang.shape[1:])
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    x1, x2 = xf[..., 0], xf[..., 1]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
 def dropout(
     x,
     p: float = 0.5,

@@ -9,6 +9,14 @@ multiply them as grouped matmuls (``jax.lax.ragged_dot``: on a TPU one
 kernel each, whose work follows the routed rows, whatever the imbalance).
 A step of few rows touches every expert anyway and runs every expert on
 every row instead (``sparse_experts`` says where the line is and why).
+
+The router's rule is data (``route_top_k``): softmax over all experts then
+the ``top_k`` largest (the Qwen3 family), or sigmoid scores, a limit to the
+best ``topk_group`` of ``n_group`` groups of consecutive experts, and a
+scale on the renormalised gates (the DeepSeek-V3 family).  A call may HOLD a
+share of the experts (``first_expert``, the stacked weights' leading size):
+it adds its share's part of the sum, and the holders' parts add up to the
+layer.
 """
 from __future__ import annotations
 
@@ -16,14 +24,54 @@ import jax
 import jax.numpy as jnp
 
 
-def route_top_k(logits, top_k: int):
-    """Softmax over ALL experts in float32, the ``top_k`` largest, their
-    probabilities renormalised to sum to 1 (``norm_topk_prob``).  Returns
-    ``(gates [T, k] float32, experts [T, k] int32)``."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top, experts = jax.lax.top_k(probs, top_k)
-    return top / jnp.sum(top, axis=-1, keepdims=True), experts.astype(
-        jnp.int32)
+# Multiply-adds a matrix up to which every held expert runs on every row:
+# 512 rows x 128 experts of 2048 x 768, where the two routes were read to
+# cross on a TPU v5e (``sparse_experts``).
+_EVERY_EXPERT_MACS = 512 * 128 * 2048 * 768
+
+
+def route_top_k(logits, top_k: int, scoring: str = "softmax",
+                n_group: int = 1, topk_group: int = 1,
+                scale: float = 1.0):
+    """The router's choice, ``(gates [T, k] float32, experts [T, k]
+    int32)``, from ``logits`` ``[T, E]`` over ALL experts, in float32.
+
+    ``scoring="softmax"``: softmax over all experts, the ``top_k`` largest,
+    their probabilities renormalised to sum to 1 (``norm_topk_prob``).
+
+    ``scoring="sigmoid"``: a score ``s = sigmoid(logit)`` an expert on its
+    own.  The ``E`` experts are ``n_group`` groups of ``E / n_group``
+    consecutive ones; a group's score is the sum of its 2 largest ``s``;
+    only the ``topk_group`` best groups stay eligible, and the ``top_k``
+    largest ``s`` among them are chosen (``n_group`` 1: plain top-k).  The
+    gates are ``s_e / (sum of the chosen s + 1e-20) * scale``
+    (``norm_topk_prob``, ``routed_scaling_factor``).  This is the
+    DeepSeek-V3 rule without its bias term."""
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        top, experts = jax.lax.top_k(probs, top_k)
+        gates = top / jnp.sum(top, axis=-1, keepdims=True)
+        return (gates if scale == 1.0 else gates * scale), \
+            experts.astype(jnp.int32)
+    if scoring != "sigmoid":
+        raise ValueError("scoring must be 'softmax' or 'sigmoid', got %r"
+                         % (scoring,))
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    rows, n = s.shape
+    choose = s
+    if n_group > 1:
+        by_group = s.reshape(rows, n_group, n // n_group)
+        group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(group_score, topk_group)      # [T, g]
+        eligible = jnp.any(kept[:, :, None] == jnp.arange(n_group),
+                           axis=1)                            # [T, G]
+        # a sigmoid is above 0: an expert outside the kept groups is
+        # never among the largest
+        choose = jnp.where(eligible[:, :, None], by_group, -1.0) \
+            .reshape(rows, n)
+    top, experts = jax.lax.top_k(choose, top_k)
+    gates = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20) * scale
+    return gates, experts.astype(jnp.int32)
 
 
 def _grouped(xt, gates, key, held: int, top_k: int, w_gate, w_up, w_down):
@@ -64,7 +112,9 @@ def _every_expert(xt, gates, key, held: int, top_k: int, w_gate, w_up,
 
 
 def sparse_experts(x, router_w, w_gate, w_up, w_down, top_k: int,
-                   first_expert: int = 0):
+                   first_expert: int = 0, scoring: str = "softmax",
+                   n_group: int = 1, topk_group: int = 1,
+                   routed_scale: float = 1.0):
     """``x`` ``[..., H]`` through a routed gated-SiLU feed-forward.
 
     ``router_w`` ``[H, E]`` scores every one of the ``E`` experts;
@@ -73,21 +123,30 @@ def sparse_experts(x, router_w, w_gate, w_up, w_down, top_k: int,
     .. first_expert + n - 1`` (all of them when ``n == E``).  A pair routed
     to an expert that is not held adds nothing here: its holder adds it,
     and the caller sums the holders.  Routing always runs over all ``E``,
-    so every holder agrees on the gates.
+    so every holder agrees on the gates; ``scoring``, ``n_group``,
+    ``topk_group`` and ``routed_scale`` are the router's rule
+    (``route_top_k``).
 
     ``out[t] = sum over t's top_k experts e of gate[t, e] *
     w_down[e] (silu(x[t] w_gate[e]) * (x[t] w_up[e]))``.
 
-    Two routes, chosen from the shapes alone.  Up to ``4 * n`` rows every
-    held expert runs on every row under a gate that is 0 where it was not
-    chosen; above, the pairs are sorted by expert and go through grouped
-    matmuls whose work follows the routed rows.  Read on a TPU v5e at 128
-    experts of 2048 x 768, 8 a token, one layer, every expert on every
-    row against grouped, in ms (PR 28): 128 rows 1.65 / 4.48 (the
-    weights' read alone is 1.47, and the grouped kernel's groups of 8 rows
-    leave it far from that); 512 rows 3.32 / 4.81; 768 rows 4.90 / 5.05;
-    1,024 rows 7.05 / 5.27; 2,048 rows 14.04 / 6.09.  Neither drops a
-    token.
+    Two routes, chosen from the shapes alone.  While the held experts'
+    matmuls over every row stay under ``_EVERY_EXPERT_MACS`` multiply-adds
+    a matrix (``rows x n x H x F``), every held expert runs on every row
+    under a gate that is 0 where it was not chosen; above, the pairs are
+    sorted by expert and go through grouped matmuls whose work follows the
+    routed rows.  Read on a TPU v5e, one layer, every expert on every row
+    against grouped, in ms.  All 128 experts of 2048 x 768 held, 8 a token
+    (PR 28): 128 rows 1.65 / 4.48 (the weights' read alone is 1.47, and the
+    grouped kernel's groups of 8 rows leave it far from that); 512 rows
+    3.32 / 4.81; 768 rows 4.90 / 5.05; 1,024 rows 7.05 / 5.27; 2,048 rows
+    14.04 / 6.09: the line at 512 rows.  A share of 12 of 192 experts of
+    7168 x 2048 held, 8 a token (PR 40): 32 rows 1.58 / 2.35 (the weights'
+    read alone is 1.29); 64 rows 1.60 / 3.56; 128 rows 1.58 / 3.62; 2,048
+    rows 12.82 / 6.98; 8,192 rows - / 18.58: an expert there is nine times
+    the work a row, so the same count of multiply-adds puts the line at 585
+    rows (a rule in rows a held expert, 4 x 12 = 48, sent 64 and 128 rows
+    to the grouped route at twice the time).  Neither drops a token.
     """
     lead, width = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, width)
@@ -97,12 +156,13 @@ def sparse_experts(x, router_w, w_gate, w_up, w_down, top_k: int,
         # the activations' type would swap experts at the cut
         gates, experts = route_top_k(
             jnp.matmul(xt, router_w, preferred_element_type=jnp.float32),
-            top_k)
+            top_k, scoring, n_group, topk_group, routed_scale)
         # pairs in token-major order under the held experts' own
         # numbering; an expert held elsewhere gets the key ``held``
         local = experts.reshape(-1) - first_expert
         key = jnp.where((local >= 0) & (local < held), local, held)
     with jax.named_scope("experts"):
-        route = _every_expert if xt.shape[0] <= 4 * held else _grouped
+        macs = xt.shape[0] * held * width * w_gate.shape[2]
+        route = _every_expert if macs <= _EVERY_EXPERT_MACS else _grouped
         out = route(xt, gates, key, held, top_k, w_gate, w_up, w_down)
     return out.astype(x.dtype).reshape(*lead, width)

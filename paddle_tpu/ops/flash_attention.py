@@ -28,6 +28,8 @@ __all__ = ["flash_attention", "flash_attention_supported",
            "decode_attention", "decode_attention_supported",
            "paged_decode_attention", "paged_decode_attention_supported",
            "paged_cache_write", "quantize_kv", "dequantize_kv",
+           "latent_decode_attention", "latent_cache_write",
+           "causal_attention", "causal_flash_supported",
            "decode_route", "normalize_decode_route", "DECODE_ROUTES",
            "reset_backend_memo"]
 
@@ -53,6 +55,9 @@ def flash_attention_supported(q_shape, dtype, dropout_p: float = 0.0) -> bool:
     b, h, l, d = q_shape
     if l % 128 != 0 or l < FLASH_MIN_SEQ:
         return False
+    # the kernel takes ONE head size for q, k and v, a whole or half
+    # 128-lane tile or two; other sizes (and a value head narrower than
+    # the score head) reach it zero-padded: ``causal_attention``
     if d not in (64, 128, 256):
         return False
     return jnp.dtype(dtype) in _SUPPORTED_DTYPES
@@ -673,6 +678,143 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
     return decode_attention(q, k, v, bias=bias, sm_scale=sm_scale,
                             k_scale=ks, v_scale=vs, route="composition",
                             score_dtype=score_dtype)
+
+
+# ---------------------------------------------------------------------------
+# latent attention (docs/DESIGN.md section 5w)
+# ---------------------------------------------------------------------------
+
+# The shortest prompt chunk whose causal attention takes the flash kernel
+# where score and value head sizes differ.  Unlike ``FLASH_MIN_SEQ`` this is
+# no crossover of speed: 64 heads x 2,048 x 2,048 float32 scores are 1.07 GB
+# and at 8,192 positions 17 GB, so the composition does not exist there.
+CAUSAL_FLASH_MIN_SEQ = 1024
+_FLASH_HEAD_DIMS = (64, 128, 256)
+# the flash kernel's tiles at a padded head size of 256 (docs 5w)
+_CAUSAL_FLASH_BLOCK = 512
+
+
+def causal_flash_supported(q_shape, v_dim: int, dtype) -> bool:
+    """Whether ``causal_attention`` takes the pallas flash kernel: a TPU,
+    ``[B, H, L, D]`` with ``L`` whole tiles of ``_CAUSAL_FLASH_BLOCK`` and
+    at least ``CAUSAL_FLASH_MIN_SEQ``, and head sizes that fit the largest
+    the kernel takes."""
+    if _cached_backend() != "tpu" or len(q_shape) != 4:
+        return False
+    l, d = q_shape[2], max(q_shape[3], v_dim)
+    return (l % _CAUSAL_FLASH_BLOCK == 0 and l >= CAUSAL_FLASH_MIN_SEQ
+            and d <= _FLASH_HEAD_DIMS[-1]
+            and jnp.dtype(dtype) in _SUPPORTED_DTYPES)
+
+
+def causal_attention(q, k, v, sm_scale: float):
+    """Causal self-attention of a prompt over its own keys: ``q``, ``k``
+    ``[B, H, L, Dk]`` and ``v`` ``[B, H, L, Dv]`` whose sizes may differ
+    (latent attention's expanded form: 192 and 128), result ``[B, H, L,
+    Dv]``.  Where the flash kernel runs, q, k and v are zero-padded to the
+    smallest head size it takes that holds both (256 for 192 / 128): exact
+    (a zero channel adds nothing to a score, and a zero value column is cut
+    off again), at 1.6 times the quadratic operations; the scores never
+    exist in HBM.  Elsewhere the XLA composition."""
+    dk, dv = q.shape[-1], v.shape[-1]
+    if not causal_flash_supported(q.shape, dv, q.dtype):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                       preferred_element_type=jnp.float32) * sm_scale
+        lq = q.shape[2]
+        s = jnp.where(jnp.tril(jnp.ones((lq, lq), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bhkv->bhqv",
+                          jax.nn.softmax(s, axis=-1).astype(v.dtype), v,
+                          preferred_element_type=jnp.float32) \
+            .astype(q.dtype)
+    from jax.experimental.pallas.ops.tpu.flash_attention import (
+        BlockSizes,
+        flash_attention as _pallas_flash,
+    )
+
+    d = min(c for c in _FLASH_HEAD_DIMS if c >= max(dk, dv))
+
+    def pad(x):
+        return jnp.pad(x, ((0, 0),) * 3 + ((0, d - x.shape[-1]),))
+
+    blk = _CAUSAL_FLASH_BLOCK
+    out = _pallas_flash(
+        pad(q), pad(k), pad(v), causal=True, sm_scale=float(sm_scale),
+        block_sizes=BlockSizes(block_q=blk, block_k_major=blk, block_k=blk,
+                               block_b=1))
+    return out[..., :dv]
+
+
+def latent_cache_write(pool, new, phys, off):
+    """``pool[phys[b, l], off[b, l]] = new[b, l]`` for a latent pool
+    ``[num_blocks, bs, W]`` and a chunk ``[B, L, W]``: ``paged_cache_write``
+    without a head axis.  Both leading dimensions are indexed, so the
+    scatter's window is the minor-most ``W`` and the donated pool is
+    updated where it lies; an index outside the pool is dropped."""
+    with jax.named_scope("cache_write"):
+        return pool.at[phys, off].set(new.astype(pool.dtype), mode="drop")
+
+
+def latent_decode_attention(q, latent, table=None, q_pos=None,
+                            value_dim: Optional[int] = None,
+                            sm_scale: float = 1.0, route=None):
+    """A decode step's attention in the ABSORBED form of latent attention.
+
+    ``q`` ``[B, H, Lq, W]``: a head's query through ``W_UK`` (``r``
+    values), then its rotary part, then zeros up to ``W``.  ``latent``
+    holds what every position keeps, laid out the same way (the latent
+    ``c``, the one rotary key every head shares, zeros), either paged
+    (``[num_blocks, bs, W]`` behind ``table`` ``[B, max_blocks]``) or by
+    slot (``[B, S, W]``, ``table`` None).  ``q_pos`` ``[B, Lq]`` or
+    ``[Lq]``: the last position each query sees.  Returns ``sum_i
+    softmax_i(q . entry_i * sm_scale) c_i`` ``[B, H, Lq, r]``, ``r =
+    value_dim``: still a latent.
+
+    Paged, on a TPU and for a geometry Mosaic takes, the fused kernel
+    (``ops.pallas_decode.latent_decode_attention_kernel``): a block is
+    streamed once and serves as keys and as values.  Otherwise the XLA
+    composition over the gathered entries (float32 scores).  ``route`` as
+    in :func:`paged_decode_attention`: ``"pallas"`` runs the kernel (under
+    the interpreter off the TPU) or raises why it cannot."""
+    from .pallas_decode import (MAX_KERNEL_QUERY_CHUNK,
+                                latent_decode_attention_kernel,
+                                latent_mosaic_refusal)
+
+    b, h, lq, width = q.shape
+    r = width if value_dim is None else int(value_dim)
+    q_pos = jnp.asarray(q_pos, jnp.int32)
+    if q_pos.ndim == 1:
+        q_pos = jnp.broadcast_to(q_pos[None], (b, lq))
+    if table is None:
+        refusal = ("a latent cache by slot has no fused kernel (the "
+                   "kernel walks a block table)")
+    elif _cached_backend() == "tpu":
+        refusal = latent_mosaic_refusal(h * lq, width, r, latent.shape[1])
+    else:
+        refusal = None
+    with jax.named_scope("latent_attn"):
+        if _resolve_route(
+                route, q.shape,
+                _cached_backend() == "tpu" and refusal is None
+                and lq <= MAX_KERNEL_QUERY_CHUNK
+                and jnp.dtype(q.dtype) in _SUPPORTED_DTYPES, refusal):
+            return latent_decode_attention_kernel(
+                q, latent, table, q_pos, r, float(sm_scale),
+                interpret=_cached_backend() != "tpu")
+        if table is not None:
+            latent = latent[jnp.asarray(table, jnp.int32)] \
+                .reshape(b, -1, width)
+        s = jnp.einsum("bhlw,bsw->bhls", q.astype(latent.dtype), latent,
+                       preferred_element_type=jnp.float32)
+        seen = jnp.arange(latent.shape[1])[None, None, :] \
+            <= q_pos[:, :, None]                              # [B, Lq, S]
+        s = jnp.where(seen[:, None], s * sm_scale, -jnp.inf)
+        # (a query that sees nothing, q_pos < 0, gives 0 as the kernel
+        # does, not the NaN of a softmax over no key)
+        p = jnp.where(seen[:, None], jax.nn.softmax(s, axis=-1), 0.0)
+        return jnp.einsum("bhls,bsr->bhlr", p.astype(latent.dtype),
+                          latent[..., :r],
+                          preferred_element_type=jnp.float32) \
+            .astype(q.dtype)
 
 
 # id(mask) → (weakref(mask), verdict); masks are immutable jax arrays built

@@ -76,8 +76,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ..core.errors import InvalidArgumentError
 
 __all__ = ["decode_attention_kernel", "paged_decode_attention_kernel",
-           "MAX_KERNEL_QUERY_CHUNK", "bias_streamable", "dense_seq_block",
-           "mosaic_refusal"]
+           "latent_decode_attention_kernel", "latent_mosaic_refusal",
+           "latent_sub_blocks", "MAX_KERNEL_QUERY_CHUNK", "bias_streamable",
+           "dense_seq_block", "mosaic_refusal"]
 
 # The longest query chunk the kernel accepts: 1 for autoregressive
 # decode, spec_k+1 for a speculative verify chunk.  Longer chunks are
@@ -509,3 +510,188 @@ def decode_attention_kernel(q, k, v, q_pos, sm_scale: float,
     return _dense_call(q, k, v, jnp.asarray(q_pos, jnp.int32),
                        k_scale, v_scale, bias,
                        float(sm_scale), bool(interpret))
+
+
+# ---------------------------------------------------------------------------
+# latent attention (docs/DESIGN.md section 5w): every head against ONE latent
+# ---------------------------------------------------------------------------
+
+# Table entries one grid step of the latent kernel takes.  A latent block
+# is small (128 positions x 640 values of bfloat16 = 164 KB: no head axis),
+# so a step of one block would be mostly the grid's own overhead; up to
+# eight ride one step, each an operand of its own whose index map reads its
+# own table entry.
+_LATENT_SUB_BLOCKS = 8
+
+
+def latent_sub_blocks(max_blocks: int) -> int:
+    """Table entries a grid step takes: the largest divisor of the table
+    width up to ``_LATENT_SUB_BLOCKS``.  From shapes alone."""
+    return max(c for c in range(1, _LATENT_SUB_BLOCKS + 1)
+               if max_blocks % c == 0)
+
+
+def latent_mosaic_refusal(rows: int, width: int, value_dim: int,
+                          block_size: int) -> Optional[str]:
+    """Why Mosaic cannot compile the latent kernel at this geometry, or
+    None.  ``rows`` is heads x query positions, the sublanes of the score
+    tile; ``width`` what a position keeps (latent, rotary key, padding),
+    ``value_dim`` the latent alone."""
+    if width % _LANES != 0:
+        return ("a cache entry of %d values a position is not whole "
+                "%d-lane tiles" % (width, _LANES))
+    if value_dim % _LANES != 0:
+        return ("a latent of %d values is not whole %d-lane tiles: the "
+                "kernel reads it as the values without a copy"
+                % (value_dim, _LANES))
+    if block_size % _SUBLANES != 0:
+        return ("a latent block of %d positions is not a multiple of the "
+                "%d sublanes a tile holds" % (block_size, _SUBLANES))
+    if rows % _SUBLANES != 0:
+        return ("%d query rows (heads x positions) are not a multiple of "
+                "the %d sublanes a tile holds" % (rows, _SUBLANES))
+    return None
+
+
+def _latent_body(lq: int, bs: int, sub: int, r: int, sm_scale: float):
+    """One batch row against ``sub`` latent blocks a grid step, on
+    ``_make_body``'s online softmax and ``_last_entry``'s dead-entry
+    skipping.  Refs after the two scalar-prefetch ones: q ``[1, rows, W]``
+    (the queries through ``W_UK``, then their rotary part, then zeros),
+    ``sub`` blocks ``[1, bs, W]`` (latent, rotary key, zeros), out ``[1,
+    rows, r]``, then m/l/acc scratch.  ``rows`` = heads x ``lq``, row ``h *
+    lq + l``: every head reads the SAME block, whole as its keys and its
+    first ``r`` lanes as its values, fetched once.
+
+    The products take the pool's own type with a float32 accumulator: 64
+    heads against one latent are over a hundred operations a byte, so an
+    up-cast to float32 (three passes of the MXU and more) would leave the
+    kernel bound by its arithmetic instead of the read."""
+
+    def body(tbl_ref, qpos_ref, q_ref, *refs):
+        c_refs = refs[:sub]
+        o_ref, m_ref, l_ref, acc_ref = refs[sub:]
+        bi = pl.program_id(0)
+        j = pl.program_id(1)
+        rows = q_ref.shape[1]
+
+        @pl.when(j == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, _M_FLOOR)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        last = _last_entry(qpos_ref, bi, lq, bs)
+        for i in range(sub):
+            entry = j * sub + i
+
+            @pl.when(entry <= last)
+            def _(i=i, entry=entry):
+                cb = c_refs[i][0]                            # [bs, W]
+                s = jax.lax.dot_general(
+                    q_ref[0], cb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)      # [rows, bs]
+                s = s * sm_scale
+                row = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) \
+                    % lq
+                qp = jnp.full((rows, bs), qpos_ref[bi, 0], jnp.int32)
+                for t in range(1, lq):
+                    qp = jnp.where(row == t, qpos_ref[bi, t], qp)
+                pos = entry * bs + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, bs), 1)
+                s = jnp.where(pos <= qp, s, -jnp.inf)
+                m_prev = m_ref[...]                          # [rows, 1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[...] = alpha * l_ref[...] + jnp.sum(
+                    p, axis=1, keepdims=True)
+                acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                    p.astype(cb.dtype), cb[:, :r], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)      # [rows, r]
+                m_ref[...] = m_new
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _():
+            l = l_ref[...]
+            l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+    return body
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("lq", "r", "sm_scale", "interpret"))
+def _latent_call(q, latent, table, q_pos, lq, r, sm_scale, interpret):
+    b, rows, width = q.shape
+    bs = latent.shape[1]
+    mb = table.shape[1]
+    sub = latent_sub_blocks(mb)
+
+    def row_map(bb, j, tbl, qp):
+        return (bb, 0, 0)
+
+    def pool_map(i):
+        # a dead entry names the row's last live block again: nothing
+        # is fetched for it
+        return lambda bb, j, tbl, qp: (
+            tbl[bb, jnp.minimum(j * sub + i,
+                                _last_entry(qp, bb, lq, bs))], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, mb // sub),
+        in_specs=[pl.BlockSpec((1, rows, width), row_map)]
+        + [pl.BlockSpec((1, bs, width), pool_map(i)) for i in range(sub)],
+        out_specs=pl.BlockSpec((1, rows, r), row_map),
+        scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, r), jnp.float32)])
+    return pl.pallas_call(
+        _latent_body(lq, bs, sub, r, sm_scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, rows, r), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(table, q_pos, q, *([latent] * sub))
+
+
+def latent_decode_attention_kernel(q, latent, table, q_pos, value_dim: int,
+                                   sm_scale: float,
+                                   interpret: bool = False):
+    """Fused latent decode attention: ``q`` ``[B, H, Lq, W]`` (a head's
+    query through ``W_UK``, its rotary part, zeros up to ``W``) against a
+    block-table pool ``[num_blocks, bs, W]`` of what every position keeps
+    (its latent, its rotary key, zeros) and every head shares.  Score ``q
+    . entry`` (width ``W``), value the entry's first ``value_dim`` lanes
+    (the latent): the result ``[B, H, Lq, value_dim]`` is still a latent,
+    and the caller takes it through ``W_UV``.  ``table``/``q_pos`` as in
+    the paged K/V kernel."""
+    if q.ndim != 4 or latent.ndim != 3 or latent.shape[2] != q.shape[3] \
+            or not 0 < value_dim <= q.shape[3]:
+        raise InvalidArgumentError(
+            "latent decode kernel needs q [B, H, Lq, W] and a pool "
+            "[num_blocks, bs, W] with the latent in its first %d lanes, "
+            "got %r and %r" % (value_dim, tuple(q.shape),
+                               tuple(latent.shape)))
+    b, h, lq, width = q.shape
+    if lq > MAX_KERNEL_QUERY_CHUNK:
+        raise InvalidArgumentError(
+            "latent decode kernel takes query chunks of at most %d "
+            "positions, got Lq=%d: a longer chunk takes the composition"
+            % (MAX_KERNEL_QUERY_CHUNK, lq))
+    if table.ndim != 2 or table.shape[0] != b:
+        raise InvalidArgumentError(
+            "table must be [B, max_blocks] int32 (got %r for q %r)"
+            % (tuple(table.shape), tuple(q.shape)))
+    if q_pos.shape != (b, lq):
+        raise InvalidArgumentError(
+            "q_pos must be [B, Lq] int32 last-visible-key positions "
+            "(got %r for q %r)" % (tuple(q_pos.shape), tuple(q.shape)))
+    out = _latent_call(
+        q.reshape(b, h * lq, width).astype(latent.dtype), latent,
+        jnp.asarray(table, jnp.int32), jnp.asarray(q_pos, jnp.int32),
+        int(lq), int(value_dim), float(sm_scale), bool(interpret))
+    return out.reshape(b, h, lq, value_dim).astype(q.dtype)

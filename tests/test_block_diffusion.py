@@ -204,10 +204,19 @@ def uneven_case(rows=64):
     return x, wr, mats
 
 
+@pytest.fixture
+def toy_route_line(monkeypatch):
+    """The line between the two routes of the expert layer, brought down to
+    these widths (8 experts of 32 x 16): 32 rows x 8 experts."""
+    from paddle_tpu.nn.functional import moe
+    monkeypatch.setattr(moe, "_EVERY_EXPERT_MACS", 32 * 8 * 32 * 16)
+
+
 @pytest.mark.parametrize("rows", [64, 32])
-def test_expert_layer_drops_nothing_under_uneven_routing(rows):
-    """64 rows go through the grouped matmuls, 32 (4 x the experts held)
-    through every expert on every row: the choice is made from shapes."""
+def test_expert_layer_drops_nothing_under_uneven_routing(rows,
+                                                         toy_route_line):
+    """64 rows go through the grouped matmuls, 32 through every expert on
+    every row: the choice is made from shapes (multiply-adds a matrix)."""
     x, wr, (wg, wu, wd) = uneven_case(rows)
     want, counts = per_token_loop(x, wr, wg, wu, wd, 2)
     assert counts[0] == len(x) // 2 and counts[6] == counts[7] == 0
@@ -222,7 +231,9 @@ def test_expert_layer_drops_nothing_under_uneven_routing(rows):
     assert np.abs(again.reshape(got.shape) - got).max() < 1e-6
 
 
-def test_expert_layer_holding_a_share_adds_its_share_only():
+def test_expert_layer_holding_a_share_adds_its_share_only(toy_route_line):
+    # 64 rows: all 8 held and a share of 5 take the grouped route, a share
+    # of 3 and the 10 rows below every expert on every row
     x, wr, (wg, wu, wd) = uneven_case()
     whole = np.asarray(F.sparse_experts(x, wr, wg, wu, wd, top_k=2))
     parts = [np.asarray(F.sparse_experts(
@@ -230,7 +241,7 @@ def test_expert_layer_holding_a_share_adds_its_share_only():
         for a, b in [(0, 3), (3, 8)]]
     assert np.abs(parts[0] + parts[1] - whole).max() < 1e-5
     few = np.asarray(F.sparse_experts(x[:10], wr, wg[:3], wu[:3], wd[:3],
-                                      top_k=2))      # the other route
+                                      top_k=2))
     assert np.abs(few - parts[0][:10]).max() < 1e-5
     assert np.abs(parts[0]).max() > 0.1 and np.abs(parts[1]).max() > 0.1
     layer = pt.nn.SparseExperts(32, 16, 8, 2, held=(3, 5))

@@ -194,3 +194,133 @@ def test_moe_ep_sharding_survives_training(rng):
             step(xs, ys)
     spec = moe.w1.value.sharding.spec
     assert spec[0] == "ep", spec
+
+
+# -- the serving expert layer's router as data, and a share of the experts -----
+# (``F.route_top_k``, ``F.sparse_experts``, ``nn.SparseExperts``: the softmax
+# rule's own tests are tests/test_block_diffusion.py's)
+
+def _plain_router(scores, top_k, n_group, topk_group, scale):
+    """The sigmoid group-limited rule, one row at a time in plain Python."""
+    gates, chosen = [], []
+    for row in scores:
+        size = len(row) // n_group
+        groups = [sorted(row[g * size:(g + 1) * size])[-2:]
+                  for g in range(n_group)]
+        kept = sorted(range(n_group), key=lambda g: -sum(groups[g]))
+        kept = set(kept[:topk_group])
+        eligible = [(s, e) for e, s in enumerate(row)
+                    if e // size in kept]
+        top = sorted(eligible, key=lambda p: -p[0])[:top_k]
+        total = sum(s for s, _ in top) + 1e-20
+        gates.append([s / total * scale for s, _ in top])
+        chosen.append([e for _, e in top])
+    return np.asarray(gates), np.asarray(chosen)
+
+
+def test_the_sigmoid_group_limited_router_against_a_plain_python_one(rng):
+    logits = (2.0 * rng.normal(size=(40, 24))).astype(np.float32)
+    gates, experts = pt.nn.functional.route_top_k(
+        jnp.asarray(logits), 4, "sigmoid", n_group=4, topk_group=2,
+        scale=2.5)
+    want_gates, want_experts = _plain_router(
+        1.0 / (1.0 + np.exp(-logits.astype(np.float64))), 4, 4, 2, 2.5)
+    np.testing.assert_array_equal(np.sort(experts, -1),
+                                  np.sort(want_experts, -1))
+    np.testing.assert_allclose(np.sort(gates, -1), np.sort(want_gates, -1),
+                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(gates).sum(-1), 2.5, rtol=1e-5)
+    # the limit bites: the plain top-4 of all 24 differs on some rows
+    _, free = pt.nn.functional.route_top_k(jnp.asarray(logits), 4, "sigmoid")
+    assert (np.sort(free, -1) != np.sort(experts, -1)).any()
+    # and every chosen expert lies in one of at most two groups of 6
+    assert all(len({e // 6 for e in row}) <= 2 for row in np.asarray(experts))
+
+
+def test_one_group_is_plain_top_k_and_softmax_stays_as_it_was(rng):
+    logits = jnp.asarray(rng.normal(size=(16, 12)).astype(np.float32))
+    gates, experts = pt.nn.functional.route_top_k(logits, 3, "sigmoid",
+                                                  n_group=1, topk_group=1)
+    s = 1.0 / (1.0 + np.exp(-np.asarray(logits, np.float64)))
+    order = np.argsort(-s, axis=-1)[:, :3]
+    np.testing.assert_array_equal(experts, order)
+    top = np.take_along_axis(s, order, -1)
+    np.testing.assert_allclose(gates, top / top.sum(-1, keepdims=True),
+                               rtol=1e-5)
+    # the softmax rule: the same numbers to the bit as the expression it
+    # was before the rule became data
+    probs = jax.nn.softmax(logits, axis=-1)
+    want, want_e = jax.lax.top_k(probs, 3)
+    got, got_e = pt.nn.functional.route_top_k(logits, 3)
+    np.testing.assert_array_equal(got, want / jnp.sum(want, -1,
+                                                      keepdims=True))
+    np.testing.assert_array_equal(got_e, want_e)
+    with pytest.raises(ValueError, match="scoring"):
+        pt.nn.functional.route_top_k(logits, 3, "tanh")
+
+
+@pytest.mark.parametrize("rows", [8, 200])
+def test_sixteen_shares_and_the_shared_expert_once_are_the_whole_layer(
+        rows, monkeypatch):
+    """192-wide router in miniature: 32 experts in 8 groups of 4, the 4
+    best groups, 8 a token, held 2 a share by 16 holders.  The shares'
+    routed parts plus the shared expert ONCE add up to the uncut layer,
+    which a plain Python loop over tokens and experts gives.  8 rows run
+    every held expert on every row, 200 the grouped matmuls (the line
+    between the routes brought down to these widths)."""
+    from paddle_tpu.core.errors import InvalidArgumentError
+    from paddle_tpu.nn.functional import moe
+
+    monkeypatch.setattr(moe, "_EVERY_EXPERT_MACS", 8 * 32 * 16 * 8)
+
+    h, f, e, k = 16, 8, 32, 8
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(rows, h)).astype(np.float32)
+    whole = pt.nn.SparseExperts(h, f, e, k, scoring="sigmoid", n_group=8,
+                                topk_group=4, routed_scale=2.5,
+                                shared_size=f, initializer_range=0.5)
+    got = np.asarray(whole(pt.to_tensor(x)).value)
+    # the uncut layer, plainly
+    wr, wg, wu, wd = (np.asarray(p.value, np.float64) for p in (
+        whole.router, whole.w_gate, whole.w_up, whole.w_down))
+    silu = lambda a: a / (1.0 + np.exp(-a))
+    gates, chosen = _plain_router(1.0 / (1.0 + np.exp(-(x @ wr))), k, 8, 4,
+                                  2.5)
+    sg, su, sd = (np.asarray(p.weight.value, np.float64) for p in (
+        whole.shared.gate_proj, whole.shared.up_proj,
+        whole.shared.down_proj))
+    want = (silu(x @ sg) * (x @ su)) @ sd
+    for t in range(rows):
+        for g, ex in zip(gates[t], chosen[t]):
+            want[t] += g * ((silu(x[t] @ wg[ex]) * (x[t] @ wu[ex])) @ wd[ex])
+    assert np.abs(got - want).max() < 2e-4 and np.abs(want).max() > 0.5
+    # sixteen holders of two experts each
+    parts = np.zeros_like(got)
+    for first in range(0, e, 2):
+        share = pt.nn.SparseExperts(h, f, e, k, held=(first, 2),
+                                    scoring="sigmoid", n_group=8,
+                                    topk_group=4, routed_scale=2.5,
+                                    shared_size=f)
+        share.router._replace_value(whole.router.value)
+        for name in ("w_gate", "w_up", "w_down"):
+            getattr(share, name)._replace_value(
+                getattr(whole, name).value[first:first + 2])
+        for name in ("gate_proj", "up_proj", "down_proj"):
+            getattr(share.shared, name).weight._replace_value(
+                getattr(whole.shared, name).weight.value)
+        routed = np.asarray(share.routed(pt.to_tensor(x)).value)
+        parts += routed
+        if first == 0:
+            shared = np.asarray(share.shared(pt.to_tensor(x)).value)
+            # a holder's forward is its routed part and the shared expert
+            np.testing.assert_allclose(
+                share(pt.to_tensor(x)).value, routed + shared, atol=1e-6)
+    assert np.abs(parts + shared - want).max() < 2e-4
+    assert np.abs(shared).max() > 0.05 and np.abs(parts).max() > 0.5
+    with pytest.raises(InvalidArgumentError, match="n_group"):
+        pt.nn.SparseExperts(h, f, e, k, scoring="sigmoid", n_group=8,
+                            topk_group=1)        # 4 experts cannot give 8
+    with pytest.raises(InvalidArgumentError, match="n_group"):
+        pt.nn.SparseExperts(h, f, e, k, n_group=8, topk_group=4)  # softmax
+    with pytest.raises(InvalidArgumentError, match="scoring"):
+        pt.nn.SparseExperts(h, f, e, k, scoring="tanh")

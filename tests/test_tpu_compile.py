@@ -281,3 +281,78 @@ def test_hybrid_decode_step_on_the_v5e_updates_both_states_in_place(
         == []
     state_bytes = 2 * 64 * 16 * 5120 * 4
     assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes
+
+
+def test_latent_step_on_the_v5e_moves_no_pool_and_its_kernels_compile(
+        one_chip, monkeypatch):
+    """The latent-attention cell's cache geometry (2,305 blocks of 128
+    positions x 640 values of bfloat16, 32 slots of 72 blocks, 64 heads on
+    one latent of 512 + 64), two layers (one dense, one of experts with a
+    share held) at narrow feed-forwards: one Pallas kernel a layer, the
+    pool written by ONE scatter a layer where it lies and copied nowhere
+    (a separate [.., 128, 64] pool of rotary keys was: the chip prefers
+    positions minor-most for it, the kernel cannot read that, and two
+    pool-sized copies a layer a step went there and back).  Then the
+    kernel alone at a verify chunk, and the prompt's causal attention at
+    head sizes 192 / 128 through the flash kernel padded to 256."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import LatentMoELM
+    from paddle_tpu.ops import pallas_decode
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    pt.seed(0)
+    model = LatentMoELM(
+        vocab_size=512, hidden_size=1024, num_layers=LAYERS, num_heads=64,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, intermediate_size=2048,
+        moe_intermediate_size=256, num_experts=192, top_k=8, n_group=8,
+        topk_group=4, routed_scaling_factor=2.5, held_experts=(0, 12),
+        rope_scaling={"factor": 32, "beta_fast": 32, "beta_slow": 1,
+                      "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 4096})
+    model.eval()
+    pool = GenerationPool(model, max_len=9216, slots=32, buckets=[2048],
+                          cache_layout="paged", block_size=128,
+                          num_blocks=2305, cache_dtype="bfloat16")
+    n = pool.slots
+    params, bufs = pool._session._state_vals()
+    samp = (np.zeros(n, np.float32), np.zeros(n, np.int32),
+            np.ones(n, np.float32), np.zeros(n, np.uint32))
+    args = (params, bufs, pool._cache, np.zeros(n, np.int32),
+            np.ones(n, bool), samp, np.zeros(n, np.uint32),
+            np.zeros(n, np.int32))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip), args)
+    monkeypatch.setattr(fa, "_backend_memo", "tpu")
+    pool_shape = pool._cache[0].latent.shape
+    assert pool_shape == (2305, 128, 640)
+    assert pallas_decode.latent_mosaic_refusal(64, 640, 512, 128) is None
+    assert pallas_decode.latent_sub_blocks(72) == 8
+    text = jax.jit(pool._pool_decode, donate_argnums=(2,)) \
+        .lower(*shapes).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
+    assert chip_smoke.pool_shaped_moves(text, pool_shape) == []
+    made = [op for _, op in chip_smoke.pool_shaped_ops(text, pool_shape)]
+    assert made.count("scatter") == LAYERS
+    assert set(made) <= {"parameter", "scatter", "fusion", "bitcast"}, \
+        sorted(set(made))
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    bf = jnp.bfloat16
+    text = jax.jit(
+        lambda q, c, t, p: pallas_decode.latent_decode_attention_kernel(
+            q, c, t, p, 512, 0.13)).lower(
+        shape((32, 64, 4, 640), bf), shape(pool_shape, bf),
+        shape((32, 72), jnp.int32), shape((32, 4), jnp.int32)) \
+        .compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert fa.causal_flash_supported((1, 64, 2048, 192), 128, bf)
+    text = jax.jit(lambda q, k, v: fa.causal_attention(q, k, v, 0.13)).lower(
+        shape((1, 64, 2048, 192), bf), shape((1, 64, 2048, 192), bf),
+        shape((1, 64, 2048, 128), bf)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
