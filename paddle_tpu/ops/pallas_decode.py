@@ -520,15 +520,32 @@ def decode_attention_kernel(q, k, v, q_pos, sm_scale: float,
 # is small (128 positions x 640 values of bfloat16 = 164 KB: no head axis),
 # so a step of one block would be mostly the grid's own overhead; up to
 # eight ride one step, each an operand of its own whose index map reads its
-# own table entry.
+# own table entry, and are scored together (``_LATENT_SCORE_TILE``).
 _LATENT_SUB_BLOCKS = 8
 
+# Float32 scores one softmax update of the latent kernel may span: the
+# entries of a grid step are scored side by side as ONE ``[rows, entries x
+# bs]`` tile while it stays under this (1 MiB).  Up to 256 rows (64 heads x
+# a chunk of up to four positions) all eight entries of a step, 1,024
+# positions, are one tile; at 512 rows (a verify chunk of eight) four are.
+# Measured on the v5e at 64, 128, 256 and 512 rows, every width this allows
+# and none it does not: the wider tile was the faster at each (the table is
+# in docs/DESIGN.md section 5w).
+_LATENT_SCORE_TILE = 256 * 1024
 
-def latent_sub_blocks(max_blocks: int) -> int:
-    """Table entries a grid step takes: the largest divisor of the table
-    width up to ``_LATENT_SUB_BLOCKS``.  From shapes alone."""
-    return max(c for c in range(1, _LATENT_SUB_BLOCKS + 1)
-               if max_blocks % c == 0)
+
+def latent_sub_blocks(max_blocks: int, rows: int, block_size: int):
+    """``(sub, tile)``.  ``sub``: the table entries a grid step takes, the
+    largest divisor of the table width up to ``_LATENT_SUB_BLOCKS``.
+    ``tile``: how many of them are scored together and share one softmax
+    update, the largest divisor of ``sub`` whose ``rows x tile x
+    block_size`` float32 scores fit ``_LATENT_SCORE_TILE`` (1 where not
+    even one entry's do).  From shapes alone."""
+    sub = max(c for c in range(1, _LATENT_SUB_BLOCKS + 1)
+              if max_blocks % c == 0)
+    tile = max(c for c in range(1, sub + 1) if sub % c == 0
+               and (c == 1 or rows * c * block_size <= _LATENT_SCORE_TILE))
+    return sub, tile
 
 
 def latent_mosaic_refusal(rows: int, width: int, value_dim: int,
@@ -553,15 +570,26 @@ def latent_mosaic_refusal(rows: int, width: int, value_dim: int,
     return None
 
 
-def _latent_body(lq: int, bs: int, sub: int, r: int, sm_scale: float):
-    """One batch row against ``sub`` latent blocks a grid step, on
-    ``_make_body``'s online softmax and ``_last_entry``'s dead-entry
-    skipping.  Refs after the two scalar-prefetch ones: q ``[1, rows, W]``
-    (the queries through ``W_UK``, then their rotary part, then zeros),
-    ``sub`` blocks ``[1, bs, W]`` (latent, rotary key, zeros), out ``[1,
-    rows, r]``, then m/l/acc scratch.  ``rows`` = heads x ``lq``, row ``h *
-    lq + l``: every head reads the SAME block, whole as its keys and its
-    first ``r`` lanes as its values, fetched once.
+def _latent_body(lq: int, bs: int, sub: int, tile: int, r: int,
+                 sm_scale: float):
+    """One batch row against ``sub`` latent blocks a grid step, ``tile`` of
+    them at a time as ONE score tile, on ``_make_body``'s online softmax
+    and ``_last_entry``'s dead-entry skipping.  Refs after the two
+    scalar-prefetch ones: q ``[1, rows, W]`` (the queries through ``W_UK``,
+    then their rotary part, then zeros), ``sub`` blocks ``[1, bs, W]``
+    (latent, rotary key, zeros), out ``[1, rows, r]``, then m/l/acc
+    scratch.  ``rows`` = heads x ``lq``, row ``h * lq + l``: every head
+    reads the SAME block, whole as its keys and its first ``r`` lanes as
+    its values, fetched once.
+
+    A group of ``tile`` entries whose first is live is ONE region: the
+    ``tile`` score products ``q . block^T``, which do not depend on one
+    another, laid side by side as ``[rows, tile x bs]``; one mask, one
+    max, one ``exp``, one update of m and l, one rescale of acc; then the
+    ``tile`` value products, summed.  An entry of the group past the row's
+    last live one holds that last live block again (its index map says so)
+    under positions past every ``q_pos``: finite scores, masked, ``p``
+    exactly 0.  A group whose first entry is dead costs its guard.
 
     The products take the pool's own type with a float32 accumulator: 64
     heads against one latent are over a hundred operations a byte, so an
@@ -582,23 +610,26 @@ def _latent_body(lq: int, bs: int, sub: int, r: int, sm_scale: float):
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
         last = _last_entry(qpos_ref, bi, lq, bs)
-        for i in range(sub):
-            entry = j * sub + i
+        for g in range(0, sub, tile):
+            first = j * sub + g
 
-            @pl.when(entry <= last)
-            def _(i=i, entry=entry):
-                cb = c_refs[i][0]                            # [bs, W]
-                s = jax.lax.dot_general(
-                    q_ref[0], cb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)      # [rows, bs]
+            @pl.when(first <= last)
+            def _(g=g, first=first):
+                blocks = [c_refs[g + i][0] for i in range(tile)]  # [bs, W]
+                s = jnp.concatenate(
+                    [jax.lax.dot_general(
+                        q_ref[0], cb, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                     for cb in blocks], axis=1)           # [rows, tile * bs]
                 s = s * sm_scale
-                row = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) \
-                    % lq
-                qp = jnp.full((rows, bs), qpos_ref[bi, 0], jnp.int32)
+                # a row's last visible position, a column: SMEM serves
+                # scalar reads only
+                row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % lq
+                qp = jnp.full((rows, 1), qpos_ref[bi, 0], jnp.int32)
                 for t in range(1, lq):
                     qp = jnp.where(row == t, qpos_ref[bi, t], qp)
-                pos = entry * bs + jax.lax.broadcasted_iota(
-                    jnp.int32, (rows, bs), 1)
+                pos = first * bs + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1)
                 s = jnp.where(pos <= qp, s, -jnp.inf)
                 m_prev = m_ref[...]                          # [rows, 1]
                 m_new = jnp.maximum(m_prev,
@@ -607,9 +638,13 @@ def _latent_body(lq: int, bs: int, sub: int, r: int, sm_scale: float):
                 alpha = jnp.exp(m_prev - m_new)
                 l_ref[...] = alpha * l_ref[...] + jnp.sum(
                     p, axis=1, keepdims=True)
-                acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                    p.astype(cb.dtype), cb[:, :r], (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)      # [rows, r]
+                p = p.astype(blocks[0].dtype)
+                acc_ref[...] = acc_ref[...] * alpha + sum(
+                    jax.lax.dot_general(
+                        p[:, i * bs:(i + 1) * bs], cb[:, :r],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    for i, cb in enumerate(blocks))          # [rows, r]
                 m_ref[...] = m_new
 
         @pl.when(j == pl.num_programs(1) - 1)
@@ -627,7 +662,7 @@ def _latent_call(q, latent, table, q_pos, lq, r, sm_scale, interpret):
     b, rows, width = q.shape
     bs = latent.shape[1]
     mb = table.shape[1]
-    sub = latent_sub_blocks(mb)
+    sub, tile = latent_sub_blocks(mb, rows, bs)
 
     def row_map(bb, j, tbl, qp):
         return (bb, 0, 0)
@@ -649,7 +684,7 @@ def _latent_call(q, latent, table, q_pos, lq, r, sm_scale, interpret):
                         pltpu.VMEM((rows, 1), jnp.float32),
                         pltpu.VMEM((rows, r), jnp.float32)])
     return pl.pallas_call(
-        _latent_body(lq, bs, sub, r, sm_scale),
+        _latent_body(lq, bs, sub, tile, r, sm_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, r), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -9,7 +9,9 @@
    through a latent cache by slot and a paged one agree with the plain
    reference's full forward (``benchmark/harness/latent_reference.py``);
 3. the kernel under the interpreter against the composition, with dead
-   table entries, a verify chunk, rows that see nothing;
+   table entries, a verify chunk, rows that see nothing, a row's last live
+   entry at every offset of a grid step's tile, and the rule for the
+   tile's width;
 4. every refusal by name.
 """
 import importlib
@@ -294,12 +296,119 @@ def test_the_kernel_matches_the_composition_with_dead_table_entries(lq):
     again = fa.latent_decode_attention(q, dirty, table, q_pos, r, 0.2,
                                        route="pallas")
     np.testing.assert_allclose(again, got, atol=0)
-    assert pd.latent_sub_blocks(6) == 6 and pd.latent_sub_blocks(72) == 8 \
-        and pd.latent_sub_blocks(7) == 7 and pd.latent_sub_blocks(11) == 1
+    # 16 or 4 rows of 8 positions an entry: a step's entries are one tile
+    assert pd.latent_sub_blocks(6, 4 * lq, 8) == (6, 6)
 
 
-def test_the_kernel_in_bfloat16_stays_near_the_float32_composition():
-    q, pool, table, q_pos, r = _kernel_case(1, jnp.bfloat16, seed=2)
+def test_the_tile_width_follows_the_shapes():
+    """``(sub, tile)``: a grid step takes the largest divisor of the table
+    width up to 8 entries, and scores as many of them together as keep
+    ``rows x tile x block_size`` float32 scores within 1 MiB."""
+    assert pd._LATENT_SCORE_TILE == 256 * 1024
+    # the cell (64 heads, one position, blocks of 128, 72 entries a row):
+    # a step's eight entries are ONE tile of 1,024 positions
+    assert pd.latent_sub_blocks(72, 64, 128) == (8, 8)
+    # verify chunks of 2, 4 and 8 positions: the tile narrows once the
+    # rows pass 256, down to an entry a tile (the old schedule)
+    assert pd.latent_sub_blocks(72, 128, 128) == (8, 8)
+    assert pd.latent_sub_blocks(72, 256, 128) == (8, 8)
+    assert pd.latent_sub_blocks(72, 512, 128) == (8, 4)
+    assert pd.latent_sub_blocks(72, 1024, 128) == (8, 2)
+    assert pd.latent_sub_blocks(72, 2048, 128) == (8, 1)
+    # an entry alone past the budget is still a tile of its own
+    assert pd.latent_sub_blocks(72, 4096, 128) == (8, 1)
+    # the tile divides the step: 6 entries at room for 4 go 3 and 3
+    assert pd.latent_sub_blocks(6, 512, 128) == (6, 3)
+    assert pd.latent_sub_blocks(6, 4, 8) == (6, 6)
+    assert pd.latent_sub_blocks(7, 4, 8) == (7, 7)
+    assert pd.latent_sub_blocks(7, 512, 128) == (7, 1)
+    assert pd.latent_sub_blocks(11, 4, 8) == (1, 1)
+    # smaller blocks, more of them a tile, never more than the step has
+    assert pd.latent_sub_blocks(72, 512, 64) == (8, 8)
+    assert pd.latent_sub_blocks(72, 2048, 16) == (8, 8)
+
+
+def _offsets_case(lq, h, bs, dtype=jnp.float32, seed=0):
+    """A table of two grid steps of eight entries, and one batch row for
+    every place a row's last live entry can take in them (``last`` = 0 ...
+    15, so ``last % sub`` = 0 ... 7 in the first step and in the second),
+    then a row that sees nothing and a row that sees the whole table.
+    Dead table entries name block 0.  Returns also the pool with 1e4 in
+    every block that no live entry names."""
+    rng = np.random.default_rng(seed)
+    mb, width, r = 16, 256, 128
+    lengths = [last * bs + 1 + (last * 5) % bs for last in range(mb)] \
+        + [0, mb * bs]
+    b = len(lengths)
+    pool = jnp.asarray(rng.normal(size=(1 + b * mb, bs, width)), dtype)
+    q = jnp.asarray(rng.normal(size=(b, h, lq, width)) * 0.3, dtype)
+    table = np.zeros((b, mb), np.int32)
+    named = np.zeros(1 + b * mb, bool)
+    for row, n in enumerate(lengths):
+        used = -(-n // bs)
+        table[row, :used] = 1 + row * mb + np.arange(used)
+        named[table[row, :used]] = True
+    assert [(n - 1) // bs for n in lengths[:mb]] == list(range(mb))
+    q_pos = np.stack([np.arange(n - lq, n) for n in lengths])
+    dirty = jnp.where(jnp.asarray(named)[:, None, None], pool, 1e4)
+    return (q, pool, dirty, jnp.asarray(table),
+            jnp.asarray(q_pos, jnp.int32), r)
+
+
+@pytest.fixture
+def score_budget(monkeypatch):
+    """Sets the tile's budget for one test: the rule is read as the kernel
+    is traced, so the traced kernels are dropped before and after."""
+    def set_to(elements):
+        monkeypatch.setattr(pd, "_LATENT_SCORE_TILE", elements)
+        pd._latent_call.clear_cache()
+    yield set_to
+    pd._latent_call.clear_cache()
+
+
+@pytest.mark.parametrize("lq,h,bs,budget,tile", [
+    (1, 4, 8, None, 8), (4, 4, 8, None, 8),       # a step's entries one tile
+    (8, 4, 8, None, 8),
+    (1, 64, 128, None, 8),                        # the cell's rows and blocks
+    (8, 64, 128, None, 4),                        # a verify chunk: 4 + 4
+    (4, 64, 128, 64 * 1024, 2),                   # narrower: four tiles of 2
+    (8, 64, 128, 64 * 1024, 1)])                  # an entry a tile
+def test_the_kernel_at_every_offset_of_a_rows_last_live_entry(
+        lq, h, bs, budget, tile, score_budget):
+    """Entries of a tile past the row's last live one are computed and add
+    exactly nothing: whatever the blocks that no live entry names hold,
+    the result is the clean pool's to the bit, and the composition's."""
+    if budget is not None:
+        score_budget(budget)
+    q, pool, dirty, table, q_pos, r = _offsets_case(lq, h, bs)
+    assert pd.latent_sub_blocks(table.shape[1], h * lq, bs) == (8, tile)
+    want = fa.latent_decode_attention(q, pool, table, q_pos, r, 0.2,
+                                      route="composition")
+    got = fa.latent_decode_attention(q, pool, table, q_pos, r, 0.2,
+                                     route="pallas")
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    again = fa.latent_decode_attention(q, dirty, table, q_pos, r, 0.2,
+                                       route="pallas")
+    np.testing.assert_allclose(again, got, atol=0)
+    # the row that sees nothing gives 0 beside the row that sees all
+    assert np.abs(np.asarray(got[-2])).max() == 0.0
+    assert np.abs(np.asarray(got[-1])).max() > 0.0
+    # and so do the queries of a chunk that lie before position 0
+    short = np.asarray(q_pos) < 0
+    assert np.abs(np.asarray(got)[np.broadcast_to(
+        short[:, None, :, None], got.shape)]).max(initial=0.0) == 0.0
+
+
+@pytest.mark.parametrize("lq,offsets", [(1, False), (1, True), (8, True)])
+def test_the_kernel_in_bfloat16_stays_near_the_float32_composition(lq,
+                                                                   offsets):
+    """The probabilities of a whole tile are rounded to the pool's type
+    before their product with the latents, as an entry's were."""
+    if offsets:
+        q, _, pool, table, q_pos, r = _offsets_case(lq, 4, 8, jnp.bfloat16,
+                                                    seed=3)
+    else:
+        q, pool, table, q_pos, r = _kernel_case(lq, jnp.bfloat16, seed=2)
     want = fa.latent_decode_attention(
         q.astype(jnp.float32), pool.astype(jnp.float32), table, q_pos, r,
         0.2, route="composition")

@@ -330,7 +330,7 @@ def test_latent_step_on_the_v5e_moves_no_pool_and_its_kernels_compile(
     pool_shape = pool._cache[0].latent.shape
     assert pool_shape == (2305, 128, 640)
     assert pallas_decode.latent_mosaic_refusal(64, 640, 512, 128) is None
-    assert pallas_decode.latent_sub_blocks(72) == 8
+    assert pallas_decode.latent_sub_blocks(72, 64, 128) == (8, 8)
     text = jax.jit(pool._pool_decode, donate_argnums=(2,)) \
         .lower(*shapes).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
@@ -355,4 +355,33 @@ def test_latent_step_on_the_v5e_moves_no_pool_and_its_kernels_compile(
     text = jax.jit(lambda q, k, v: fa.causal_attention(q, k, v, 0.13)).lower(
         shape((1, 64, 2048, 192), bf), shape((1, 64, 2048, 192), bf),
         shape((1, 64, 2048, 128), bf)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("lq,tile", [(1, 8), (8, 4)])
+def test_the_latent_kernel_compiles_at_the_tile_its_rows_give(one_chip, lq,
+                                                              tile):
+    """The latent kernel alone for the v5e at the cell's geometry (32 rows
+    of 64 heads, one position: a grid step's eight entries scored as ONE
+    tile of 1,024 positions) and at the speculative pool's (a verify chunk
+    of eight, 512 query rows: four entries a tile): one Mosaic kernel
+    each."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import pallas_decode
+
+    assert pallas_decode.latent_mosaic_refusal(64 * lq, 640, 512, 128) is None
+    assert pallas_decode.latent_sub_blocks(72, 64 * lq, 128) == (8, tile)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    bf = jnp.bfloat16
+    text = jax.jit(
+        lambda q, c, t, p: pallas_decode.latent_decode_attention_kernel(
+            q, c, t, p, 512, 0.13)).lower(
+        shape((32, 64, lq, 640), bf), shape((2305, 128, 640), bf),
+        shape((32, 72), jnp.int32), shape((32, lq), jnp.int32)) \
+        .compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
