@@ -553,7 +553,7 @@ class GenerationPool:
              for g in range(self.slots)], np.int32) \
             if self._layout.paged else None
         self._cache = self._new_cache()
-        # layers and bytes a slot, by the entries' kind; what a step
+        # entries and bytes a slot, by the entries' kind; what a step
         # reads and writes of recurrent state is the recurrent entries'
         self._by_kind = self._layout.bytes_per_slot_by_kind(
             self._cache, self.slots, self.max_len)
@@ -561,7 +561,7 @@ class GenerationPool:
         # the same, as ``cache_stats()`` hands it out every tick
         self._by_kind_stats = {
             "bytes_per_slot": {k: b for k, (_, b) in self._by_kind.items()},
-            "cache_layers": {k: n for k, (n, _) in self._by_kind.items()}}
+            "cache_entries": {k: n for k, (n, _) in self._by_kind.items()}}
         if donate is None:
             donate = jax.default_backend() != "cpu"
         self._decode_jit = jax.jit(self._pool_decode,
@@ -715,7 +715,7 @@ class GenerationPool:
                 "not wired for cache_layout=%r (%s): a recurrent "
                 "prefill is one cheap O(L·d_state) scan, so there is "
                 "nothing to disaggregate — run a fused engine"
-                % (cache_layout, self._layout.recurrent_layers()))
+                % (cache_layout, self._layout.recurrent_entries()))
         self._prefill_only = bool(prefill_only)
         # rid -> (slot, _SlotState) for prefill-complete parked
         # requests awaiting export_kv()
@@ -836,7 +836,7 @@ class GenerationPool:
                     "%r (%s): a recurrence has no positional K/V to "
                     "chunk into — its whole prefill is one O(L·d_state) "
                     "scan, already cheap enough to run in-tick"
-                    % (lay.name, lay.recurrent_layers()))
+                    % (lay.name, lay.recurrent_entries()))
             raise InvalidArgumentError(
                 "prefill_chunk_tokens is a paged-cache knob (chunk "
                 "writes route through the block table); pass "
@@ -849,7 +849,7 @@ class GenerationPool:
                     "into one carry, so there are no per-position "
                     "blocks two requests could share — every request's "
                     "state is already O(1)"
-                    % (lay.name, lay.recurrent_layers()))
+                    % (lay.name, lay.recurrent_entries()))
             raise InvalidArgumentError(
                 "prefix_sharing shares physical KV blocks through the "
                 "block table; pass cache_layout='paged' (got %r)"
@@ -3064,15 +3064,16 @@ class GenerationPool:
         return meta
 
     def _block_meta(self) -> dict:
-        """``tick.decode``'s meta, each figure over its own layers: where
-        entries are paged (``kv_layers`` of them) ``live_blocks``, the
+        """``tick.decode``'s meta, each figure over its own entries: where
+        entries are paged (``kv_entries`` of them) ``live_blocks``, the
         table entries the live slots' positions reach (what the
         attention kernel fetches and computes: ``ops/pallas_decode.py``
         skips the rest), and ``table_blocks``, slots x table width;
-        where entries are recurrent (``state_layers``) ``state_bytes``;
-        ``latent_layers`` where the paged entries hold latents.
+        where entries are recurrent (``state_entries``) ``state_bytes``;
+        ``latent_entries`` where the paged entries hold latents.
         A model that mixes kinds carries both in the one span, and the
-        layer counts that say what each figure is over."""
+        counts of ENTRIES that say what each figure is over (a layer may
+        own an entry of each kind)."""
         meta = {}
         if self._layout.recurrent:
             # a recurrent state: what the step reads AND writes of it,
@@ -3087,11 +3088,11 @@ class GenerationPool:
         if "latent" in self._by_kind:
             # the paged figures above run over latent entries: a block is
             # one latent a position, not K/V by head
-            meta["latent_layers"] = self._by_kind["latent"][0]
+            meta["latent_entries"] = self._by_kind["latent"][0]
         if len(self._by_kind) > 1:
             meta.update(
-                state_layers=self._by_kind.get("recurrent", (0,))[0],
-                kv_layers=sum(n for k, (n, _) in self._by_kind.items()
+                state_entries=self._by_kind.get("recurrent", (0,))[0],
+                kv_entries=sum(n for k, (n, _) in self._by_kind.items()
                               if k != "recurrent"))
         return meta
 
@@ -3408,7 +3409,7 @@ class GenerationPool:
                 "cache_dtype": self._layout.cache_dtype_str(self._cache),
                 "decode_route": self._session.route,
                 "d_state": self._layout.fingerprint_extra(self)["d_state"],
-                "num_layers": len(self._cache),
+                "num_entries": len(self._cache),
                 "state_bytes_per_slot": per_slot,
                 "reachable_bytes": state_total,
                 "pool_bytes": state_total,

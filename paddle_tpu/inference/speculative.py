@@ -113,7 +113,7 @@ class SpeculativePool(GenerationPool):
             # a model that mixes kinds: asked for by its K/V layout, so
             # only its cache entries say that some layers cannot rewind
             _refuse_recurrent(self.cache_layout,
-                              self._layout.recurrent_layers())
+                              self._layout.recurrent_entries())
         buckets, donate, mesh, route = (
             pool_kwargs.get("buckets"), pool_kwargs.get("donate"),
             pool_kwargs.get("mesh"), pool_kwargs.get("route", "auto"))

@@ -14,10 +14,12 @@ recurrence carry instead of an attention prefix).
 This module names the operations those consumers actually perform as a
 :class:`CacheLayout` protocol and registers one singleton per layout.
 
-**The layout is a property of a layer's cache ENTRY, not of the model.**
-A decode cache is a list with one entry a layer, and an entry's own type
-says which layout it is (:func:`entry_layout`: a ``table`` field is paged,
-a ``limit`` field recurrent, else dense).  Every hook below is written
+**The layout is a property of a cache ENTRY, not of the model.**
+A decode cache is a flat list of entries (one a layer in most models; a
+layer that keeps K/V AND a state of constant size, ``nn.CCAttention``, owns
+two, side by side), and an entry's own type says which layout it is
+(:func:`entry_layout`: a ``table`` field is paged, a ``limit`` field
+recurrent, else dense).  Every hook below is written
 for ONE entry (``*_entry``), and the list-level hooks the consumers call
 walk the list and hand each entry to its own layout.  A model whose
 layers all keep one kind gets the registered singleton
@@ -73,7 +75,7 @@ operation            who calls it / what it decides
                      vector replicated within an mp group)
 ``cache_dtype_str``  cache_stats()/config_fingerprint() provenance — the
                      payload dtype without assuming a ``.k`` field
-``bytes_per_slot_by_kind``  cache_stats() — layers and the decode-state
+``bytes_per_slot_by_kind``  cache_stats() — entries and the decode-state
                      HBM one slot pins at full span, by the entries'
                      kind: the figure the slots-per-GB capacity
                      comparison is made of
@@ -165,9 +167,10 @@ class CacheLayout:
         return [c for lay, c in zip(self.layouts(cache), cache)
                 if lay.name == kind]
 
-    def recurrent_layers(self) -> str:
-        """How a refusal names the layers that keep a recurrent state."""
-        return "every layer keeps a state of constant size"
+    def recurrent_entries(self) -> str:
+        """How a refusal names the entries that are a recurrent state (a
+        layer may own more than one entry: what is counted is entries)."""
+        return "every cache entry is a state of constant size"
 
     def not_transferable(self) -> str:
         """How a refusal says why this layout has no disk tier and no PTKV
@@ -264,7 +267,8 @@ class CacheLayout:
         return total
 
     def bytes_per_slot_by_kind(self, cache, slots: int, max_len: int) -> dict:
-        """``{layout name: (layers, bytes a slot)}`` over the entries."""
+        """``{layout name: (entries, bytes a slot)}`` over the entries (a
+        layer that owns two entries counts under each one's kind)."""
         out: dict = {}
         for lay, c in zip(self.layouts(cache), cache):
             n, b = out.get(lay.name, (0, 0))
@@ -505,16 +509,16 @@ class ComposedLayout(CacheLayout):
                 % (len(cache), len(self._layouts)))
         return self._layouts
 
-    def recurrent_layers(self) -> str:
+    def recurrent_entries(self) -> str:
         at = [i for i, lay in enumerate(self._layouts) if lay.recurrent]
-        return ("%d of the %d layers keep a state of constant size (layers "
-                "%s%s)" % (len(at), len(self._layouts),
+        return ("%d of the %d cache entries are states of constant size "
+                "(entries %s%s)" % (len(at), len(self._layouts),
                            ", ".join(map(str, at[:4])),
                            ", ..." if len(at) > 4 else ""))
 
     def not_transferable(self) -> str:
         if self.recurrent:
-            return "has both (%s)" % self.recurrent_layers()
+            return "has both (%s)" % self.recurrent_entries()
         return "mixes kinds of entry (%s)" % self.name
 
     def fingerprint_extra(self, pool) -> dict:

@@ -173,7 +173,7 @@ class SpeculativeDecodeSession:
                 "rejected drafts, and a recurrent state has no earlier "
                 "position to go back to"
                 % (self._target._layout.name,
-                   self._target._layout.recurrent_layers()))
+                   self._target._layout.recurrent_entries()))
         self._draft = DecodeSession(
             draft_model, max_len, buckets=buckets, temperature=0.0,
             donate=donate, route=route)

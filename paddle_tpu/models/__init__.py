@@ -10,6 +10,10 @@ from .block_diffusion import (  # noqa: F401
     BlockDiffusionDecoderLayer,
     BlockDiffusionMoELM,
 )
+from .cca_moe import (  # noqa: F401
+    CCAMoEDecoderLayer,
+    CCAMoELM,
+)
 from .hybrid_mamba import (  # noqa: F401
     HybridMambaDecoderLayer,
     HybridMambaLM,

@@ -45,7 +45,7 @@ from .layer.rnn import (  # noqa: F401
     GRU, LSTM, RNN, BiRNN, GRUCell, LSTMCell, RNNCellBase, SimpleRNN,
     SimpleRNNCell,
 )
-from .layer.moe import SparseExperts  # noqa: F401
+from .layer.moe import DepthMLPRouter, SparseExperts  # noqa: F401
 from .layer.transformer import (  # noqa: F401
     GatedMLP, GroupedQueryAttention, MultiHeadAttention, Transformer, TransformerDecoder, TransformerDecoderLayer,
     TransformerEncoder, TransformerEncoderLayer,
@@ -55,6 +55,7 @@ from .layer.mamba import MambaDecodeCache, MambaMixer  # noqa: F401
 from .layer.latent_attention import (  # noqa: F401
     LatentAttention, LatentDecodeCache, PagedLatentDecodeCache,
 )
+from .layer.cca_attention import CCADecodeCache, CCAttention  # noqa: F401
 from .ssm import GatedSSMBlock, RecurrentDecodeCache, SSMLM  # noqa: F401
 from . import lora  # noqa: F401
 from .lora import attach_lora, load_adapter, unload_adapter  # noqa: F401

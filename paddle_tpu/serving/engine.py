@@ -627,16 +627,17 @@ class ServingEngine:
             "dtype-aware: int8 caches count int8 K/V + fp32 scales)")
         self._g_state_slot = m.gauge(
             "serving_state_bytes_per_slot",
-            "bytes of recurrent state one slot holds, every layer (a "
+            "bytes of recurrent state one slot holds, every entry (a "
             "constant of the model, whatever the context)") \
             if self._pool._layout.recurrent else None
-        self._g_cache_layers = {
+        self._g_cache_entries = {
             kind: m.gauge(
-                "serving_cache_layers",
-                "layers whose decode cache is of each layout (a model "
-                "that mixes kinds has more than one series)",
+                "serving_cache_entries",
+                "decode-cache entries of each layout (a model that mixes "
+                "kinds has more than one series; a layer may own one of "
+                "each)",
                 labels={"layout": kind})
-            for kind in self._pool.cache_stats()["cache_layers"]}
+            for kind in self._pool.cache_stats()["cache_entries"]}
         self._g_kv_free = m.gauge(
             "serving_kv_free_blocks",
             "paged allocator free blocks") \
@@ -2567,8 +2568,8 @@ class ServingEngine:
         self._g_kv_resident.set(stats["pool_bytes"])
         if self._g_state_slot is not None:
             self._g_state_slot.set(stats["bytes_per_slot"]["recurrent"])
-        for kind, g in self._g_cache_layers.items():
-            g.set(stats["cache_layers"][kind])
+        for kind, g in self._g_cache_entries.items():
+            g.set(stats["cache_entries"][kind])
         if self._g_kv_free is not None:
             self._g_kv_free.set(stats["free_blocks"])
         if self._g_kv_resident_shard is not None:

@@ -221,13 +221,14 @@ def test_expert_layer_drops_nothing_under_uneven_routing(rows,
     want, counts = per_token_loop(x, wr, wg, wu, wd, 2)
     assert counts[0] == len(x) // 2 and counts[6] == counts[7] == 0
     assert counts.sum() == 2 * len(x)
+    # the scores are the caller's: here the one matrix
     got = np.asarray(jax.jit(
-        lambda *a: F.sparse_experts(*a, top_k=2))(x, wr, wg, wu, wd))
+        lambda *a: F.sparse_experts(*a, top_k=2))(x, x @ wr, wg, wu, wd))
     # float32 sums in another order, values of order 1
     assert np.abs(got - want).max() < 1e-4
-    # a [B, L, H] input keeps its shape
-    again = np.asarray(F.sparse_experts(x.reshape(4, rows // 4, -1), wr, wg, wu,
-                                        wd, top_k=2))
+    # a [B, L, H] input keeps its shape (and the scores have its rows)
+    x3 = x.reshape(4, rows // 4, -1)
+    again = np.asarray(F.sparse_experts(x3, x3 @ wr, wg, wu, wd, top_k=2))
     assert np.abs(again.reshape(got.shape) - got).max() < 1e-6
 
 
@@ -235,13 +236,13 @@ def test_expert_layer_holding_a_share_adds_its_share_only(toy_route_line):
     # 64 rows: all 8 held and a share of 5 take the grouped route, a share
     # of 3 and the 10 rows below every expert on every row
     x, wr, (wg, wu, wd) = uneven_case()
-    whole = np.asarray(F.sparse_experts(x, wr, wg, wu, wd, top_k=2))
+    whole = np.asarray(F.sparse_experts(x, x @ wr, wg, wu, wd, top_k=2))
     parts = [np.asarray(F.sparse_experts(
-        x, wr, wg[a:b], wu[a:b], wd[a:b], top_k=2, first_expert=a))
+        x, x @ wr, wg[a:b], wu[a:b], wd[a:b], top_k=2, first_expert=a))
         for a, b in [(0, 3), (3, 8)]]
     assert np.abs(parts[0] + parts[1] - whole).max() < 1e-5
-    few = np.asarray(F.sparse_experts(x[:10], wr, wg[:3], wu[:3], wd[:3],
-                                      top_k=2))
+    few = np.asarray(F.sparse_experts(x[:10], x[:10] @ wr, wg[:3], wu[:3],
+                                      wd[:3], top_k=2))
     assert np.abs(few - parts[0][:10]).max() < 1e-5
     assert np.abs(parts[0]).max() > 0.1 and np.abs(parts[1]).max() > 0.1
     layer = pt.nn.SparseExperts(32, 16, 8, 2, held=(3, 5))

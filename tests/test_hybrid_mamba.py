@@ -248,7 +248,7 @@ def test_the_layout_is_composed_from_the_entries(model):
     assert (layout.paged, layout.positional, layout.spillable,
             layout.transferable, layout.recurrent) \
         == (True, False, True, False, True)
-    assert "3 of the 4 layers" in layout.recurrent_layers()
+    assert "3 of the 4 cache entries" in layout.recurrent_entries()
     assert len(layout.entries(cache, "paged")) == 1
     # one kind throughout gives the registered singleton back
     assert layout_of([cache[0], cache[2]]) is get_layout("recurrent")
@@ -389,7 +389,7 @@ def test_preempt_and_resume_carry_both_kinds(model):
 
 REFUSED = {
     "prefix_sharing": (dict(prefix_sharing=True, prefill_chunk_tokens=8),
-                       "prefill_chunk_tokens.*3 of the 4 layers"),
+                       "prefill_chunk_tokens.*3 of the 4 cache entries"),
     "prefix_sharing_alone": (dict(prefix_sharing=True),
                              "prefix_sharing.*paged\\+recurrent.*3 of the 4"),
     "chunked_prefill": (dict(prefill_chunk_tokens=8),
@@ -424,7 +424,7 @@ def test_speculative_pools_and_sessions_name_the_recurrent_layers(model):
         SpeculativePool(model, draft, max_len=64, cache_layout="paged",
                         block_size=8, buckets=[16])
     with pytest.raises(InvalidArgumentError,
-                       match="speculative.*3 of the 4 layers"):
+                       match="speculative.*3 of the 4 cache entries"):
         SpeculativeDecodeSession(model, draft, max_len=64, buckets=[16])
     with pytest.raises(InvalidArgumentError, match="recurrent state"):
         model.gen_decode_cache(1, 64, layout="recurrent")
@@ -457,8 +457,8 @@ def test_no_retrace_over_joins_and_leaves_and_the_step_runs_ahead(model):
     decodes = [m for n, m in spans if n == "tick.decode"]
     per_slot = 3 * (SSM_BYTES + CONV_BYTES)
     assert decodes and all(
-        m["state_bytes"] == m["live"] * per_slot and m["state_layers"] == 3
-        and m["kv_layers"] == 1 and m["table_blocks"] == 3 * 8
+        m["state_bytes"] == m["live"] * per_slot and m["state_entries"] == 3
+        and m["kv_entries"] == 1 and m["table_blocks"] == 3 * 8
         and 1 <= m["live_blocks"] <= m["live"] * 8 for m in decodes)
     assert any(m["ahead"] == 1 for m in decodes)
     prefills = {m["bucket"]: m["chunks"] for n, m in spans
@@ -471,7 +471,7 @@ def test_cache_stats_report_each_kind(model):
     pool = _pool(model)
     stats = pool.cache_stats()
     assert stats["cache_layout"] == pool.cache_layout == "paged+recurrent"
-    assert stats["cache_layers"] == {"recurrent": 3, "paged": 1}
+    assert stats["cache_entries"] == {"recurrent": 3, "paged": 1}
     assert stats["bytes_per_slot"] == {
         "recurrent": 3 * (SSM_BYTES + CONV_BYTES), "paged": 64 * KV_BYTES}
     assert stats["state_bytes_per_slot"] \
@@ -492,7 +492,7 @@ def test_cache_stats_report_each_kind(model):
     p = GenerationPool(plain, max_len=64, slots=2, buckets=[16],
                        cache_layout="paged", block_size=8)
     assert p._layout is get_layout("paged")
-    assert p.cache_stats()["cache_layers"] == {"paged": 1}
+    assert p.cache_stats()["cache_entries"] == {"paged": 1}
 
 
 def test_served_over_http_through_the_engine(model):
@@ -521,9 +521,9 @@ def test_served_over_http_through_the_engine(model):
         text = engine.metrics.render_prometheus().replace(".0\n", "\n")
         assert "serving_state_bytes_per_slot %d\n" % (
             3 * (SSM_BYTES + CONV_BYTES)) in text
-        assert 'serving_cache_layers{layout="recurrent"} 3\n' in text
-        assert 'serving_cache_layers{layout="paged"} 1\n' in text
-        assert text.count("# TYPE serving_cache_layers gauge") == 1
+        assert 'serving_cache_entries{layout="recurrent"} 3\n' in text
+        assert 'serving_cache_entries{layout="paged"} 1\n' in text
+        assert text.count("# TYPE serving_cache_entries gauge") == 1
         assert "serving_kv_free_blocks" in text
     finally:
         front.shutdown()

@@ -246,10 +246,10 @@ def test_served_over_http_through_the_engine(model, weights):
         assert len(toks) == 7
         _assert_best(weights, prompt, toks)
         text = engine.metrics.render_prometheus().replace(".0\n", "\n")
-        assert 'serving_cache_layers{layout="latent"} 3\n' in text
-        assert text.count("# TYPE serving_cache_layers gauge") == 1
+        assert 'serving_cache_entries{layout="latent"} 3\n' in text
+        assert text.count("# TYPE serving_cache_entries gauge") == 1
         assert "serving_kv_free_blocks" in text
-        assert engine.cache_stats()["cache_layers"] == {"latent": 3}
+        assert engine.cache_stats()["cache_entries"] == {"latent": 3}
     finally:
         front.shutdown()
         engine.shutdown(drain=False)
@@ -305,14 +305,14 @@ def test_cache_stats_spans_and_no_retrace(model):
     decodes = [e.meta for e in tracer.recorder.snapshot()
                if e.name == "tick.decode"]
     assert decodes and all(
-        m["latent_layers"] == 3 and m["table_blocks"] == 3 * 8
+        m["latent_entries"] == 3 and m["table_blocks"] == 3 * 8
         and 1 <= m["live_blocks"] <= m["live"] * 8
-        and "state_bytes" not in m and "kv_layers" not in m
+        and "state_bytes" not in m and "kv_entries" not in m
         for m in decodes)
     assert any(m["ahead"] == 1 for m in decodes)
     stats = pool.cache_stats()
     assert stats["cache_layout"] == pool.cache_layout == "latent"
-    assert stats["cache_layers"] == {"latent": 3}
+    assert stats["cache_entries"] == {"latent": 3}
     assert stats["bytes_per_slot"] == {"latent": 3 * 64 * ENTRY_BYTES}
     assert stats["state_bytes_per_slot"] == 3 * 64 * ENTRY_BYTES
     assert stats["dense_equiv_bytes"] == 3 * 3 * 64 * ENTRY_BYTES

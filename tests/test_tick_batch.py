@@ -368,8 +368,8 @@ def cache_gauges(eng):
             eng._g_kv_resident: stats["pool_bytes"]}
     if eng._g_state_slot is not None:
         want[eng._g_state_slot] = stats["bytes_per_slot"]["recurrent"]
-    for kind_, g in eng._g_cache_layers.items():
-        want[g] = stats["cache_layers"][kind_]
+    for kind_, g in eng._g_cache_entries.items():
+        want[g] = stats["cache_entries"][kind_]
     if eng._g_kv_free is not None:
         want[eng._g_kv_free] = stats["free_blocks"]
     if eng._g_spilled_blocks is not None:

@@ -169,11 +169,12 @@ def test_grouped_heads_kernel_and_grouped_matmul_on_the_v5e(one_chip,
         shape((641, 4, 128, 128), bf), shape((32, 20), jnp.int32),
         shape((32, 4), jnp.int32)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
-    experts = [shape((2048, 128), bf), shape((128, 2048, 768), bf),
-               shape((128, 2048, 768), bf), shape((128, 768, 2048), bf)]
-    layer = jax.jit(lambda x, *w: moe.sparse_experts(x, *w, top_k=8))
+    experts = [shape((128, 2048, 768), bf), shape((128, 2048, 768), bf),
+               shape((128, 768, 2048), bf)]
+    layer = jax.jit(lambda x, s, *w: moe.sparse_experts(x, s, *w, top_k=8))
     for rows, grouped in ((2048, 3), (128, 0)):
         text = layer.lower(shape((rows, 2048), bf),
+                           shape((rows, 128), jnp.float32),
                            *experts).compile().as_text()
         assert len(set(re.findall(r"ragged-dot-none[\w.]* = ",
                                   text))) == grouped, rows
@@ -281,6 +282,65 @@ def test_hybrid_decode_step_on_the_v5e_updates_both_states_in_place(
         == []
     state_bytes = 2 * 64 * 16 * 5120 * 4
     assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes
+
+
+def test_cca_step_on_the_v5e_keeps_both_entries_where_they_lie(
+        one_chip, monkeypatch):
+    """``zaya1-8b``'s step at its widths and cache geometry, two layers, a
+    small vocabulary: 64 slots of 24 blocks of 128 positions, 2 K/V heads of
+    128 under 8 query heads (bfloat16), and beside each K/V entry a state
+    of 1,280 + 1,280 + 128 values a slot.  One paged kernel a layer, the
+    K/V pool written where it lies, 16 experts on 64 rows through the
+    every-expert route (no grouped matmul).  Then the 1,024 bucket's prefill: the
+    flash kernel over the prompt's own keys, a layer."""
+    import jax
+
+    from paddle_tpu.models import CCAMoELM
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    pt.seed(0)
+    model = CCAMoELM(vocab_size=512, hidden_size=2048, num_layers=LAYERS,
+                     num_heads=8, num_kv_heads=2, head_dim=128,
+                     conv_taps=(2, 2), moe_intermediate_size=2048,
+                     num_experts=16, top_k=1, router_hidden_size=256,
+                     rope_theta=5e6, partial_rotary_factor=0.5)
+    model.eval()
+    pool = GenerationPool(model, max_len=3072, slots=64, buckets=[1024],
+                          cache_layout="paged", block_size=128,
+                          num_blocks=1537, cache_dtype="bfloat16")
+    assert pool.cache_layout == "paged+recurrent"
+    n = pool.slots
+    params, bufs = pool._session._state_vals()
+    samp = (np.zeros(n, np.float32), np.zeros(n, np.int32),
+            np.ones(n, np.float32), np.zeros(n, np.uint32))
+    args = (params, bufs, pool._cache, np.zeros(n, np.int32),
+            np.ones(n, bool), samp, np.zeros(n, np.uint32),
+            np.zeros(n, np.int32))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip), args)
+    monkeypatch.setattr(fa, "_backend_memo", "tpu")
+    kv_shape = pool._cache[0].k.shape
+    assert kv_shape == (1537, 2, 128, 128)
+    assert [f.shape for f in pool._cache[1][:3]] == [(64, 1280), (64, 1280),
+                                                     (64, 128)]
+    text = jax.jit(pool._pool_decode, donate_argnums=(2,)) \
+        .lower(*shapes).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
+    assert chip_smoke.pool_shaped_moves(text, kv_shape) == []
+    assert "ragged-dot" not in text
+    sess = pool._session
+    ids = jax.ShapeDtypeStruct((1, 1024), np.int32, sharding=one_chip)
+    true_len = jax.ShapeDtypeStruct((), np.int32, sharding=one_chip)
+    row_samp = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip),
+        sess.sampling_state(1))
+    text = jax.jit(sess._prefill).lower(
+        shapes[0], shapes[1], ids, true_len, row_samp).compile().as_text()
+    # the flash kernel, once a layer, and no paged kernel: the prompt
+    # attends its own keys
+    assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
 
 
 def test_latent_step_on_the_v5e_moves_no_pool_and_its_kernels_compile(
