@@ -277,16 +277,15 @@ def reset_backend_memo() -> None:
     _backend_memo = None
 
 
-def _kernel_refusal(q_shape, dtype, tile, seq_len: int, has_bias: bool):
+def _kernel_refusal(q_shape, dtype, tile, mosaic):
     """Why the fused kernel cannot take this call, or None — what
     ``route='pallas'`` may force.  Structure first (4-D float queries),
     then, when the kernel would be COMPILED (TPU backend), Mosaic's
-    layout rules (``pallas_decode.mosaic_refusal``); the interpreter
-    has no layout to refuse.  The crossover conditions live in the
-    ``*_supported`` gates — they decide WINNING, this decides
+    layout rules (``mosaic()``: ``pallas_decode.mosaic_refusal`` for
+    the dense kernel, ``paged_mosaic_refusal`` for the paged one); the
+    interpreter has no layout to refuse.  The crossover conditions live
+    in the ``*_supported`` gates — they decide WINNING, this decides
     EXISTING."""
-    from .pallas_decode import mosaic_refusal
-
     if len(q_shape) != 4:
         return "queries must be 4-D [B, H, Lq, D], got %r" % (
             tuple(q_shape),)
@@ -295,7 +294,7 @@ def _kernel_refusal(q_shape, dtype, tile, seq_len: int, has_bias: bool):
             jnp.dtype(dtype).name,)
     if _cached_backend() != "tpu" and tile is not None:
         return None
-    return mosaic_refusal(q_shape[3], tile, seq_len, has_bias)
+    return mosaic()
 
 
 def _bias_kernel_compatible(bias, b, h, lq, s) -> bool:
@@ -472,15 +471,17 @@ def decode_attention(q, k, v, bias=None, sm_scale: Optional[float] = None,
                                score_dtype=jnp.float32)
         return out.reshape(q.shape)
     s = k.shape[2]
-    from .pallas_decode import dense_seq_block
+    from .pallas_decode import dense_seq_block, mosaic_refusal
 
     if _resolve_route(
             route, q.shape,
             decode_attention_supported(q.shape, s, q.dtype)
             and _bias_kernel_compatible(bias, q.shape[0], q.shape[1],
                                         q.shape[2], s),
-            _kernel_refusal(q.shape, q.dtype, dense_seq_block(s), s,
-                            bias is not None)):
+            _kernel_refusal(q.shape, q.dtype, dense_seq_block(s),
+                            lambda: mosaic_refusal(
+                                d, dense_seq_block(s), s,
+                                bias is not None))):
         # fused pallas route (docs/DESIGN.md §5l): stream cache tiles
         # through VMEM with an online softmax — int8 tiles dequantize
         # in VMEM, so the HBM read stays int8 and the gathered fp32
@@ -525,10 +526,11 @@ def paged_decode_attention_supported(q_shape, block_size: int,
     ``decode_attention_supported``: TPU backend, short query chunk, a
     pool big enough that the hand-tiled gather kernel beats the XLA
     gather+composition, and a geometry Mosaic compiles
-    (``mosaic_refusal``: head_dim, and a ``block_size`` of whole
-    sublanes).  The "auto" route's decision;
+    (``paged_mosaic_refusal``: a head_dim of whole lanes, a
+    ``block_size`` of whole sublanes; an int8 pool's scales and a bias
+    add no rule).  The "auto" route's decision;
     ``route="pallas"``/``"composition"`` override it."""
-    from .pallas_decode import MAX_KERNEL_QUERY_CHUNK, mosaic_refusal
+    from .pallas_decode import MAX_KERNEL_QUERY_CHUNK, paged_mosaic_refusal
 
     if _cached_backend() != "tpu":
         return False
@@ -538,8 +540,7 @@ def paged_decode_attention_supported(q_shape, block_size: int,
         return False
     if jnp.dtype(dtype) not in _SUPPORTED_DTYPES:
         return False
-    return mosaic_refusal(q_shape[3], block_size,
-                          block_size * num_blocks) is None
+    return paged_mosaic_refusal(q_shape[3], block_size) is None
 
 
 def paged_cache_write(pool, new, phys, off):
@@ -614,6 +615,9 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
     [B, H, S, D] gathered (and, for int8, fp32-up-cast) K/V is exactly
     the traffic the kernel deletes.
     """
+    from .pallas_decode import (paged_decode_attention_kernel,
+                                paged_mosaic_refusal)
+
     b, mb = table.shape
     nb, h, bs, d = k_pool.shape
     s = mb * bs
@@ -629,9 +633,8 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
             paged_decode_attention_supported(q.shape, bs, nb, q.dtype)
             and _bias_kernel_compatible(bias, b, q.shape[1], q.shape[2],
                                         s),
-            _kernel_refusal(q.shape, q.dtype, bs, s, bias is not None)):
-        from .pallas_decode import paged_decode_attention_kernel
-
+            _kernel_refusal(q.shape, q.dtype, bs,
+                            lambda: paged_mosaic_refusal(d, bs))):
         qp = _effective_qpos(q_pos, lengths, b, q.shape[2], s)
         return paged_decode_attention_kernel(
             q, k_pool, v_pool, jnp.asarray(table, jnp.int32), qp,

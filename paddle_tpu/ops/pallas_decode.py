@@ -9,31 +9,52 @@ the paged path's data-dependent table gather MATERIALIZES the gathered
 dequantize up-casts the whole gathered cache to fp32 there too — 4-8x
 the bytes the cache actually holds.
 
-This kernel crosses both boundaries by hand.  Per ``(batch row, chunk
-of heads, logical block)`` grid step it
+This kernel crosses both boundaries by hand.  The PAGED kernel
+(``_paged_call``) runs one ``(batch row, chunk of heads)`` a grid step and
+WALKS the row's block table inside it:
 
-- reads the row's block table (a scalar-prefetch operand, so the block
-  index feeds the DMA descriptor *before* the body runs) and streams
-  that ONE physical K/V block from the pool in HBM into VMEM, for every
-  head of the chunk at once (``head_chunk``: all of them where they fit;
-  the pool keeps a block's heads contiguous);
-- costs what the live K/V costs: a block past the row's last visible
-  position (``_last_entry``, from ``q_pos``) is neither fetched (the
-  index maps name the last live block again) nor computed;
-- dequantizes int8 rows in VMEM — the per-head scales are gathered
-  through the SAME table row, so a remapped block always carries its
-  own scales;
-- applies the lengths/bias masking in-register (``q_pos`` names each
-  query's last visible key position; an optional additive bias streams
-  block-by-block alongside K/V);
-- accumulates attention with an ONLINE softmax across the block axis
-  (running max / normalizer / weighted-V in VMEM scratch that persists
-  over the sequential grid), so neither the gathered fp32 K/V nor the
-  ``[Lq, S]`` score row ever exists in HBM.
+- the pools stay in HBM (``memory_space=pl.ANY``); the table and ``q_pos``
+  are scalar-prefetch operands in SMEM.  A row reaches
+  ``max(q_pos) // block_size + 1`` entries (``_live_entries``), the loop
+  runs ``ceil(that / tile)`` steps, and the row's work ends there: an
+  entry past the row's reach is neither fetched nor computed nor visited,
+  and a row that sees nothing (``q_pos`` < 0) runs no step and emits 0;
+- a step takes ``tile`` entries at once (``paged_tile_entries``: from
+  shapes alone, up to eight).  Entry ``e`` is copied by hand
+  (``pltpu.make_async_copy``), ``pool[table[b, e]]`` for every head of the
+  chunk at once (``head_chunk``: the pool keeps a block's heads
+  contiguous) to its ``block_size`` rows of a ``[hc, tile * block_size,
+  D]`` VMEM buffer.  Two buffers: tile ``t + 1`` is in flight while tile
+  ``t`` is computed, and the first tile of the NEXT grid step is started
+  before the last tile of this one is computed, so a row does not wait
+  for a copy it has just asked for;
+- a tile is ONE score tile: one product ``q . K^T`` over ``[rows, tile *
+  block_size]``, one mask, one max, one ``exp``, one update of the
+  ONLINE softmax's running max / normalizer / weighted-V (VMEM scratch),
+  one product with V.  Neither the gathered fp32 K/V nor the ``[Lq, S]``
+  score row ever exists in HBM;
+- int8 rows are dequantized in VMEM, the HBM read stays int8.  Their
+  per-head scales are small (1/32 of the pool's bytes at a head of 128)
+  with the positions on the lane axis, ``[num_blocks, H, block_size]``,
+  and Mosaic slices no HBM
+  array whose minor dimension is not whole 128-lane tiles: so
+  ``_paged_call`` gathers the scales of the row's entries through the
+  table (XLA, outside the kernel; V's set to 0 past the row's reach) and
+  lays them out by the walk's steps, ``[B, steps, H, lanes]`` with a
+  step's ``tile * block_size`` positions in a row of whole lanes
+  (``_by_steps``).  A step's scales are then ONE hand copy beside its K
+  and V entries, at any block size;
+- the lengths/bias masking is applied in-register (``q_pos`` names each
+  query's last visible key position; an optional additive bias is laid
+  out by the walk's steps as the scales are, and copied a step at a
+  time).
 
-``decode_attention_kernel`` is the dense-cache variant on the same
-inner loop: the "table" is the identity walk of the ``[B, H, S, D]``
-buffer, chunked into sequence tiles.
+``decode_attention_kernel`` is the dense-cache variant: the "table" is the
+identity walk of the ``[B, H, S, D]`` buffer, chunked into sequence tiles,
+one tile a step of a ``(row, head chunk, tile)`` grid under the pipeline's
+own copies (``_make_body``); a tile past the row's last visible position
+is dead there: its index map names the last live tile again, so nothing
+is fetched, and its arithmetic is skipped.
 
 Shapes are static; query chunks are short (``Lq <= 8`` — single-token
 decode and the speculative verify chunk).  ``interpret=True`` runs the
@@ -43,23 +64,30 @@ TPU (tests/test_pallas_decode.py), while the routing gates in
 ``flash_attention.py`` keep compiled-mode engagement TPU-only and
 measured-crossover honest.
 
-Compiled under Mosaic the body is held to the TPU's layout rules, and
-``mosaic_refusal`` names every geometry it cannot meet so the routing
-layer excludes it by reason instead of finding out from the compiler:
+Compiled under Mosaic the bodies are held to the TPU's layout rules, and
+``mosaic_refusal`` / ``paged_mosaic_refusal`` name every geometry they
+cannot meet so the routing layer excludes it by reason instead of finding
+out from the compiler:
 
 - a block's last two dims are whole ``(8, 128)`` tiles or the full
-  array dims.  K/V blocks always span the full ``[bs, D]`` dims, which
-  is why Mosaic also takes int8 and bf16 blocks below their own 32- and
-  16-sublane tiles (compiled and matched on a v5e at ``bs`` 8 and 16);
-  the floor on ``block_size`` and the dense tile is 8 sublanes;
+  array dims; the floor on ``block_size`` and the dense tile is 8
+  sublanes;
+- the paged walk copies out of HBM by hand, and Mosaic takes no slice of
+  an HBM array whose minor dimension is not whole 128-lane tiles: a
+  ``head_dim`` of 64 is refused there (the one-entry-a-step kernel this
+  walk replaced took it through the pipeline's copies: PERF.md section 6,
+  PR 43, has what the composition costs there).  An int8 pool's scales
+  and a bias reach the kernel in rows of whole lanes (above) and add no
+  rule.  An entry lands at row ``e * block_size`` of a tile, which has to
+  be a whole sublane tile of the pool's type (8 float32, 16 bfloat16, 32
+  int8 rows): where it is not, a tile is one entry;
 - ``q_pos`` is a scalar-prefetch operand in SMEM, which serves scalar
-  reads only: the ``[Lq, bs]`` mask threshold is assembled from ``Lq``
-  scalar reads, never a vector load;
-- int8 scales stream as one ``[hc, bs]`` block (the chunk's heads on
-  the sublanes: a multiple of 8 or all of them, lane-major) and a
-  head's ``[1, bs]`` row multiplies its ``[Lq, bs]`` score and
-  probability rows — ``(q·k)·s == q·(k·s)`` — so no lane vector is
-  ever turned into a sublane column;
+  reads only: the mask threshold is assembled from ``Lq`` scalar reads,
+  never a vector load;
+- int8 scales are ``[hc, positions]`` (the chunk's heads on the sublanes: a
+  multiple of 8 or all of them, lane-major) and a head's row multiplies
+  its score and probability rows: ``(q.k).s == q.(k.s)``, so no lane
+  vector is ever turned into a sublane column;
 - the dense tile is bounded (``_DENSE_TILES``): a cache length no tile
   divides is refused, never run as one whole-sequence VMEM block.
 """
@@ -78,7 +106,8 @@ from ..core.errors import InvalidArgumentError
 __all__ = ["decode_attention_kernel", "paged_decode_attention_kernel",
            "latent_decode_attention_kernel", "latent_mosaic_refusal",
            "latent_sub_blocks", "MAX_KERNEL_QUERY_CHUNK", "bias_streamable",
-           "dense_seq_block", "mosaic_refusal"]
+           "dense_seq_block", "mosaic_refusal", "paged_mosaic_refusal",
+           "paged_tile_entries"]
 
 # The longest query chunk the kernel accepts: 1 for autoregressive
 # decode, spec_k+1 for a speculative verify chunk.  Longer chunks are
@@ -145,6 +174,26 @@ def mosaic_refusal(head_dim: int, tile: Optional[int], seq_len: int,
     return None
 
 
+def paged_mosaic_refusal(head_dim: int, block_size: int) -> Optional[str]:
+    """Why Mosaic cannot compile the PAGED kernel at this geometry, or
+    None: ``mosaic_refusal``'s rules for a K/V block, and the walk's own.
+    The pools stay in HBM and the kernel copies a table entry by hand; a
+    copy out of an HBM array is whole 128-lane tiles in its minor
+    dimension (Mosaic refuses the slice of a narrower one: compiled for
+    the v5e, ``tests/test_tpu_compile.py``), and the minor dimension of a
+    K/V pool is ``head_dim``.  An int8 pool's scales and a bias do not
+    add a rule: ``_paged_call`` lays them out by the walk's steps in rows
+    of whole lanes (``_by_steps``) whatever the block."""
+    why = mosaic_refusal(head_dim, block_size, block_size)
+    if why is not None:
+        return why
+    if head_dim % _LANES != 0:
+        return ("head_dim %d is not whole %d-lane tiles: the pools stay in "
+                "HBM and an entry is copied by hand, which a narrower "
+                "minor dimension does not allow" % (head_dim, _LANES))
+    return None
+
+
 def bias_streamable(bias_shape, b: int, h: int, lq: int, s: int) -> bool:
     """Whether an additive bias can stream block-wise through the
     kernel: 4-D [B|1, H|1, Lq, S].  THE shape rule — the routing layer
@@ -187,6 +236,13 @@ def _check_common(q, q_pos, bias, s: int):
 _KV_VMEM_BUDGET = 4 * 1024 * 1024
 
 
+def _kv_vmem_bytes(bs: int, d: int, itemsize: int) -> int:
+    """VMEM one head of a K and a V block of ``bs`` positions takes: two
+    buffers each in the cache dtype, plus the float32 copy the body
+    makes of each."""
+    return 2 * bs * d * (2 * itemsize + 4)
+
+
 def head_chunk(h: int, bs: int, d: int, itemsize: int,
                quant: bool = False) -> int:
     """How many heads of a K/V block one grid step takes: the largest
@@ -197,43 +253,66 @@ def head_chunk(h: int, bs: int, d: int, itemsize: int,
     An int8 cache's scale block is ``[hc, bs]`` with ``hc`` on the
     sublanes: a multiple of 8 or all of ``h`` (the smallest such when
     none fits)."""
-    per_head = 2 * bs * d * (2 * itemsize + 4)
+    per_head = _kv_vmem_bytes(bs, d, itemsize)
     legal = [c for c in range(1, h + 1) if h % c == 0
              and (not quant or c % _SUBLANES == 0 or c == h)]
     fit = [c for c in legal if c * per_head <= _KV_VMEM_BUDGET]
     return max(fit) if fit else min(legal)
 
 
-def _last_entry(qpos_ref, bi, lq: int, bs: int):
-    """The last K/V block any query of batch row ``bi`` may see, from
-    ``q_pos`` in SMEM.  0 for a row that sees nothing (``q_pos`` < 0):
-    its one block is computed, wholly masked."""
+def _top_position(qpos_ref, bi, lq: int):
+    """The last position any query of batch row ``bi`` may see, from
+    ``q_pos`` in SMEM (scalar reads); negative for a row that sees
+    nothing."""
     top = qpos_ref[bi, 0]
     for r in range(1, lq):
         top = jnp.maximum(top, qpos_ref[bi, r])
+    return top
+
+
+def _last_entry(qpos_ref, bi, lq: int, bs: int):
+    """The last K/V block any query of batch row ``bi`` may see.  0 for a
+    row that sees nothing (``q_pos`` < 0): its one block is computed,
+    wholly masked."""
     # (not negative, so the truncating division is the floor)
-    return jax.lax.div(jnp.maximum(top, 0), jnp.int32(bs))
+    return jax.lax.div(jnp.maximum(_top_position(qpos_ref, bi, lq), 0),
+                       jnp.int32(bs))
+
+
+def _softmax_update(s, vb, v_scale, m_ref, l_ref, acc_ref):
+    """One step of the online softmax: masked scores ``s`` ``[hc, rows,
+    keys]`` (-inf where masked) and values ``vb`` ``[hc, keys, D]`` into
+    the running max / normalizer / weighted-V, the old sums rescaled by
+    ``exp(m_old - m_new)``.  ``v_scale`` ``[hc, keys]``: an int8 pool's V
+    scales, folded into the probability rows (``p.(v.s)``)."""
+    m_prev = m_ref[...]                                 # [hc, rows, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    p = jnp.exp(s - m_new)                              # masked -> 0
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
+    if v_scale is not None:
+        p = p * v_scale[:, None, :]
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, vb, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)             # [hc, rows, D]
+    m_ref[...] = m_new
 
 
 def _make_body(n_scalar: int, lq: int, bs: int, sm_scale: float,
-               quant: bool, has_bias: bool, group: int = 1):
-    """The shared inner loop over a chunk of ``hc`` heads.  Ref order
-    after the ``n_scalar`` scalar-prefetch refs (q_pos always last among
-    them): q ``[1, hc, rows, D]``, k, v ``[1, hc, bs, D]``, [k_scale,
-    v_scale ``[1, hc, bs]``,] [bias ``[1, hc|1, Lq, bs]``,] out, then
-    m/l/acc VMEM scratch with a leading ``hc``.
+               quant: bool, has_bias: bool):
+    """The dense kernel's inner loop over a chunk of ``hc`` heads, one
+    sequence tile a grid step.  Ref order after the ``n_scalar``
+    scalar-prefetch refs (q_pos always last among them): q ``[1, hc, Lq,
+    D]``, k, v ``[1, hc, bs, D]``, [k_scale, v_scale ``[1, hc, bs]``,]
+    [bias ``[1, hc|1, Lq, bs]``,] out, then m/l/acc VMEM scratch with a
+    leading ``hc``.
 
-    ``group`` > 1: the q block holds the ``group`` query heads that
-    share each K/V head, ``lq`` rows each (row ``i * lq + l``), and
-    ``q_pos`` still has ``lq`` entries a batch row: row ``r`` is held to
-    entry ``r % lq``.
-
-    Blocks past the row's last visible one (``_last_entry``) are dead:
-    the index maps name the last live block again, so nothing is
-    fetched, and the arithmetic is skipped.  A wholly masked block
+    Tiles past the row's last visible one (``_last_entry``) are dead:
+    the index maps name the last live tile again, so nothing is
+    fetched, and the arithmetic is skipped.  A wholly masked tile
     leaves m/l/acc as they are (``alpha`` = 1, ``p`` = 0), so the result
     is the same to the bit."""
-    rows = group * lq
+    rows = lq
 
     def body(*refs):
         qpos_ref = refs[n_scalar - 1]
@@ -280,28 +359,14 @@ def _make_body(n_scalar: int, lq: int, bs: int, sm_scale: float,
             # as q_pos).  q_pos sits in SMEM: one scalar read per query
             # row, spread over that row's lanes
             row = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0)
-            if group > 1:
-                row = row % lq
             qp = jnp.full((rows, bs), qpos_ref[bi, 0], jnp.int32)
             for r in range(1, lq):
                 qp = jnp.where(row == r, qpos_ref[bi, r], qp)
             pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs),
                                                     1)
             s = jnp.where((pos <= qp)[None], s, -jnp.inf)
-            # online softmax: rescale the running sums by
-            # exp(m_old - m_new)
-            m_prev = m_ref[...]                         # [hc, rows, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-            p = jnp.exp(s - m_new)                      # masked -> 0
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2,
-                                                      keepdims=True)
-            if quant:
-                p = p * vs_ref[0][:, None, :]           # p·(v·s)
-            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                p, vb, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)     # [hc, rows, D]
-            m_ref[...] = m_new
+            _softmax_update(s, vb, vs_ref[0] if quant else None,
+                            m_ref, l_ref, acc_ref)
 
         @pl.when(j == pl.num_programs(2) - 1)
         def _():
@@ -342,6 +407,222 @@ def _bias_spec(bias_shape, hc: int, lq: int, bs: int, live):
                               live(b, j, *sc)))
 
 
+# Table entries one step of the paged walk takes at most.  The entries
+# of a tile are copied one by one (each its own descriptor: the table
+# scatters them over the pool), so the width also bounds the copies a
+# step issues and waits for.
+_PAGED_TILE_ENTRIES = 8
+
+# Float32 scores one softmax update of the paged walk may span, as
+# ``_LATENT_SCORE_TILE``: 256 Ki values, 1 MiB.
+_PAGED_SCORE_TILE = 256 * 1024
+
+
+def paged_tile_entries(hc: int, rows: int, bs: int, d: int, itemsize: int,
+                       max_blocks: int) -> int:
+    """How many table entries one step of the paged walk takes, scored
+    as ONE ``[hc, rows, tile * bs]`` tile: the largest count, up to
+    ``_PAGED_TILE_ENTRIES`` and the table's width, whose K and V tiles
+    (two buffers each in the cache dtype plus the float32 copy the body
+    makes: ``head_chunk``'s arithmetic) fit ``_KV_VMEM_BUDGET`` and whose
+    float32 scores fit ``_PAGED_SCORE_TILE``.  From shapes alone.
+
+    An entry lands at row ``i * bs`` of the tile, so ``bs`` has to be
+    whole sublane tiles of the cache dtype (8 float32, 16 bfloat16, 32
+    int8 rows).  Where it is not, an entry is a tile of its own: the
+    buffer is the entry."""
+    if bs % (4 * _SUBLANES // itemsize):
+        return 1
+    kv = hc * _kv_vmem_bytes(bs, d, itemsize)
+    padded = -(-rows // _SUBLANES) * _SUBLANES
+    return max(1, min(_PAGED_TILE_ENTRIES, max_blocks,
+                      _KV_VMEM_BUDGET // kv,
+                      _PAGED_SCORE_TILE // (hc * padded * bs)))
+
+
+def _live_entries(qpos_ref, bi, lq: int, bs: int, mb: int):
+    """How many table entries batch row ``bi`` reaches, from ``q_pos`` in
+    SMEM: 0 for a row that sees nothing (``q_pos`` < 0 everywhere)."""
+    top = _top_position(qpos_ref, bi, lq)
+    reach = jnp.minimum(jax.lax.div(jnp.maximum(top, 0), jnp.int32(bs)) + 1,
+                        jnp.int32(mb))
+    return jnp.where(top < 0, 0, reach)
+
+
+def _by_steps(x, steps: int, width: int):
+    """``x`` ``[B, .., S]``, positions on the lane axis (an int8 pool's
+    scales gathered through the table, a bias), as ``[B, steps, ..,
+    lanes]``: the ``width`` positions one step of the walk scores, in a
+    row of whole 128-lane tiles.  A step's share is then ONE hand copy
+    whatever the block's size (Mosaic slices no narrower HBM array), and
+    lands where the step's scores lie."""
+    lanes = -(-width // _LANES) * _LANES
+    lead = [(0, 0)] * (x.ndim - 1)
+    x = jnp.pad(x, lead + [(0, steps * width - x.shape[-1])])
+    x = x.reshape(x.shape[:-1] + (steps, width))
+    x = jnp.pad(x, lead + [(0, 0), (0, lanes - width)])
+    return jnp.moveaxis(x, -2, 1)
+
+
+def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
+                sm_scale: float, quant: bool, bias_dims, group: int):
+    """One ``(batch row, head chunk)`` a grid step: a loop over the row's
+    LIVE table entries, ``tile`` of them a step.
+
+    Refs after the two scalar-prefetch ones (``table``, ``q_pos``): q
+    ``[1, hc, rows, D]`` (VMEM, the pipeline's); the K and V pools, [the
+    int8 pool's scales of the row's entries ``[B, steps, H, lanes]``,
+    twice,] [the bias ``[B|1, steps, H|1, Lq, lanes]``,] (``_by_steps``)
+    all left in HBM; out; then scratch: two K and two V buffers ``[2, hc,
+    tile * bs, D]`` in the pool's type, [scales ``[2, hc, lanes]``,]
+    [bias ``[2, hc|1, Lq, lanes]``,] DMA semaphores ``[2, streams]``, two
+    int32 of SMEM that outlive a grid step (which buffer the next tile
+    lands in; whether this step's first tile was started by the step
+    before), and m / l / acc as ``_make_body`` has them.
+
+    Entry ``e`` of the row is copied by hand, ``pool[table[b, e], chunk]``
+    to rows ``(e % tile) * bs ...`` of a buffer; entries of the last tile
+    past the row's reach are not copied and sit under positions past every
+    ``q_pos``: masked, ``p`` exactly 0 (the V buffers start as zeros, and
+    hold live entries' values ever after: 0 x finite).  The scales and the
+    bias of a tile are one copy each.  Tile ``t + 1`` is
+    in flight while tile ``t`` is computed, and the first tile of the NEXT
+    grid step is started before the last tile of this one is computed, so
+    only the first live row of a call, and a live row behind an empty one,
+    wait for a copy they have just asked for.  A row that sees nothing
+    starts nothing, runs no tile and emits zeros."""
+    rows = group * lq
+    width = tile * bs
+    has_bias = bias_dims is not None
+
+    def body(tbl_ref, qpos_ref, q_ref, k_hbm, v_hbm, *refs):
+        # the streams that follow the walk's steps, then out, then a
+        # buffer a stream: K, V, [K scales, V scales,] [bias]
+        n_side = 2 * quant + has_bias
+        side_hbm = refs[:n_side]
+        o_ref, k_buf, v_buf = refs[n_side:n_side + 3]
+        side_buf = refs[n_side + 3:2 * n_side + 3]
+        sems, state, m_ref, l_ref, acc_ref = refs[2 * n_side + 3:]
+
+        bi, hh = pl.program_id(0), pl.program_id(1)
+        b, nh = pl.num_programs(0), pl.num_programs(1)
+
+        def each_copy(row, chunk, n, t, slot, act):
+            """``act`` on every copy of tile ``t`` of ``(row, chunk)``,
+            a row of ``n`` live entries, into buffer ``slot``: one K and
+            one V descriptor a live entry [, the tile's scales and
+            bias]."""
+            heads = pl.ds(chunk * hc, hc)
+            for s, (src, dst) in enumerate(zip(side_hbm, side_buf)):
+                if has_bias and s == n_side - 1:
+                    # the bias may be one row or one head for all
+                    src = src.at[row if bias_dims[0] > 1 else 0, t,
+                                 heads if bias_dims[1] > 1 else pl.ds(0, 1)]
+                else:
+                    src = src.at[row, t, heads]
+                act(pltpu.make_async_copy(src, dst.at[slot],
+                                          sems.at[slot, 2 + s]))
+            for j in range(tile):
+                entry = t * tile + j
+                at = pl.ds(j * bs, bs)
+
+                @pl.when(entry < n)
+                def _(entry=entry, at=at):
+                    blk = tbl_ref[row, entry]
+                    for s, (src, dst) in enumerate(((k_hbm, k_buf),
+                                                    (v_hbm, v_buf))):
+                        act(pltpu.make_async_copy(
+                            src.at[blk, heads], dst.at[slot, :, at, :],
+                            sems.at[slot, s]))
+
+        @pl.when(jnp.logical_and(bi == 0, hh == 0))
+        def _():
+            state[0] = 0
+            state[1] = 0
+            v_buf[...] = jnp.zeros_like(v_buf)
+
+        m_ref[...] = jnp.full_like(m_ref, _M_FLOOR)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        n_live = _live_entries(qpos_ref, bi, lq, bs, mb)
+        n_tiles = jax.lax.div(n_live + (tile - 1), jnp.int32(tile))
+        first = state[0]
+        # the grid step after this one, and whether it has a tile
+        wraps = hh == nh - 1
+        nrow = jnp.minimum(jnp.where(wraps, bi + 1, bi), b - 1)
+        nchunk = jnp.where(wraps, 0, hh + 1)
+        n_next = _live_entries(qpos_ref, nrow, lq, bs, mb)
+        follows = jnp.logical_and(
+            jnp.logical_not(jnp.logical_and(wraps, bi == b - 1)),
+            n_next > 0)
+
+        @pl.when(jnp.logical_and(n_tiles > 0, state[1] == 0))
+        def _():
+            each_copy(bi, hh, n_live, 0, first, lambda c: c.start())
+
+        qb = q_ref[0].astype(jnp.float32)               # [hc, rows, D]
+        # a row's last visible position, a column: SMEM serves scalar
+        # reads only
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        if group > 1:
+            row = row % lq
+        qp = jnp.full((rows, 1), qpos_ref[bi, 0], jnp.int32)
+        for r in range(1, lq):
+            qp = jnp.where(row == r, qpos_ref[bi, r], qp)
+
+        def tile_step(t, carry):
+            slot = jnp.bitwise_and(first + t, 1)
+            more = t + 1 < n_tiles
+
+            @pl.when(jnp.logical_or(more, follows))
+            def _():
+                each_copy(jnp.where(more, bi, nrow),
+                          jnp.where(more, hh, nchunk),
+                          jnp.where(more, n_live, n_next),
+                          jnp.where(more, t + 1, 0), 1 - slot,
+                          lambda c: c.start())
+
+            each_copy(bi, hh, n_live, t, slot, lambda c: c.wait())
+            # the HBM read was the cache dtype: the up-cast happens here
+            # in VMEM, on one tile, never on the gathered cache
+            kb = k_buf[slot].astype(jnp.float32)        # [hc, width, D]
+            vb = v_buf[slot].astype(jnp.float32)
+            # (a buffer's lanes past the tile's width are padding)
+            side = [buf[slot][..., :width] for buf in side_buf]
+            s = jax.lax.dot_general(
+                qb, kb, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)     # [hc, rows, width]
+            if quant:
+                # int8 dequant folded into the score rows: (q.k).s ==
+                # q.(k.s) per key, and a head's scale row is lane-major
+                # like its scores
+                s = s * side[0][:, None, :]
+            s = s * sm_scale
+            if has_bias:
+                s = s + side[-1].astype(jnp.float32)
+            pos = t * width + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, width), 1)
+            s = jnp.where((pos <= qp)[None], s, -jnp.inf)
+            _softmax_update(s, vb, side[1] if quant else None,
+                            m_ref, l_ref, acc_ref)
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, tile_step, 0)
+
+        @pl.when(n_tiles > 0)
+        def _():
+            state[0] = jnp.bitwise_and(first + n_tiles, 1)
+            state[1] = follows.astype(jnp.int32)
+
+        l = l_ref[...]
+        # a row with no visible key emits 0 rather than NaN
+        l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+    return body
+
+
 @functools.partial(jax.jit,
                    static_argnames=("sm_scale", "interpret", "group"))
 def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
@@ -355,39 +636,49 @@ def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
     quant = k_scale is not None
     has_bias = bias is not None
     hc = head_chunk(h, bs, d, k_pool.dtype.itemsize, quant)
-    live = _live_block(lq, bs)
+    tile = paged_tile_entries(hc, rows, bs, d, k_pool.dtype.itemsize, mb)
+    steps = -(-mb // tile)
+    lanes = -(-tile * bs // _LANES) * _LANES
 
-    def pool_map(bb, hh, j, tbl, qp):
-        return (tbl[bb, live(bb, j, tbl, qp)], hh, 0, 0)
-
-    def row_map(bb, hh, j, tbl, qp):
+    def row_map(bb, hh, tbl, qp):
         return (bb, hh, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, hc, rows, d), row_map),
-        pl.BlockSpec((1, hc, bs, d), pool_map),
-        pl.BlockSpec((1, hc, bs, d), pool_map),
-    ]
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     args = [q, k_pool, v_pool]
+    buffers = [pltpu.VMEM((2, hc, tile * bs, d), k_pool.dtype)] * 2
     if quant:
-        in_specs += [pl.BlockSpec(
-            (1, hc, bs), lambda bb, hh, j, tbl, qp:
-            (tbl[bb, live(bb, j, tbl, qp)], hh, 0))] * 2
-        args += [k_scale, v_scale]
+        # the scales of the row's own entries, [B, H, S] as the
+        # composition gathers them; V's are 0 past the row's reach, where
+        # a stale entry's may be anything and p is exactly 0
+        seen = jnp.arange(mb * bs) <= jnp.max(q_pos, axis=1, keepdims=True)
+        for scale, keep in ((k_scale, None), (v_scale, seen[:, None])):
+            scale = scale[table].transpose(0, 2, 1, 3).reshape(b, h, -1)
+            if keep is not None:
+                scale = jnp.where(keep, scale, 0.0)
+            args.append(_by_steps(scale, steps, tile * bs))
+        buffers += [pltpu.VMEM((2, hc, lanes), k_scale.dtype)] * 2
     if has_bias:
-        in_specs.append(_bias_spec(bias.shape, hc, lq, bs, live))
-        args.append(bias)
+        args.append(_by_steps(bias, steps, tile * bs))
+        buffers.append(pltpu.VMEM(
+            (2, hc if bias.shape[1] > 1 else 1, lq, lanes), bias.dtype))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h // hc, mb),
-        in_specs=in_specs,
+        grid=(b, h // hc),
+        in_specs=[pl.BlockSpec((1, hc, rows, d), row_map)]
+        + [in_hbm] * (len(args) - 1),
         out_specs=pl.BlockSpec((1, hc, rows, d), row_map),
-        scratch_shapes=_scratch(hc, rows, d))
+        scratch_shapes=buffers
+        + [pltpu.SemaphoreType.DMA((2, len(args) - 1)),
+           pltpu.SMEM((2,), jnp.int32)] + _scratch(hc, rows, d))
     return pl.pallas_call(
-        _make_body(2, lq, bs, sm_scale, quant, has_bias, group),
+        _paged_body(hc, mb, lq, bs, tile, sm_scale, quant,
+                    bias.shape if has_bias else None, group),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, rows, d), q.dtype),
-        compiler_params=_GRID_SEMANTICS,
+        # a copy in flight and the buffer it lands in pass from one grid
+        # step to the next: the steps run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(table, q_pos, *args)
 
@@ -400,15 +691,20 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, q_pos,
     block-table pool [num_blocks, H, bs, D], never materializing the
     gathered K/V.
 
-    ``table``: [B, max_blocks] int32 — fed as a scalar-prefetch operand
-    so each grid step's DMA streams pool row ``table[b, j]`` directly.
+    ``table``: [B, max_blocks] int32, a scalar-prefetch operand: the
+    kernel walks a row's LIVE entries, several a step, and copies pool
+    row ``table[b, e]`` by hand from the pools, which stay in HBM.
     ``q_pos``: [B, Lq] int32, the last key position each query may
     attend (the causal-prefix / lengths mask in index form; stale table
-    rows and the scratch block sit past it and are never read into the
-    softmax).  ``k_scale``/``v_scale`` ([num_blocks, H, bs] fp32) mark
-    an int8 pool; dequantization happens in VMEM on the streamed block.
-    ``bias``: optional additive [B|1, H|1, Lq, S] streamed block-wise.
-    """
+    rows and the scratch block sit past it and are neither fetched nor
+    computed; a row with every ``q_pos`` < 0 emits zeros).
+    ``k_scale``/``v_scale`` ([num_blocks, H, bs] fp32) mark an int8
+    pool; dequantization happens in VMEM on the copied tile, the scales
+    gathered through the table and laid out by the walk's steps first
+    (``_by_steps``: any ``bs`` of whole sublanes).  ``bias``: optional
+    additive [B|1, H|1, Lq, S], laid out the same way.  Compiled, the
+    pools' ``head_dim`` is whole 128-lane tiles
+    (``paged_mosaic_refusal``)."""
     nb, h, bs, d = k_pool.shape
     s = table.shape[1] * bs
     _check_common(q, q_pos, bias, s)

@@ -399,7 +399,7 @@ def test_paged_decode_attention_gate_conditions(monkeypatch):
     import jax.numpy as jnp
 
     fa = importlib.import_module("paddle_tpu.ops.flash_attention")
-    ok_q, bs = (1, 8, 1, 64), 128
+    ok_q, bs = (1, 8, 1, 128), 128
     nb = fa.DECODE_FLASH_MIN_CACHE // bs
     # the gate memoizes the backend lookup; clear it around the
     # monkeypatch so the fake backend is seen and cannot leak
@@ -419,9 +419,17 @@ def test_paged_decode_attention_gate_conditions(monkeypatch):
         assert not fa.paged_decode_attention_supported(ok_q, 12, nb,
                                                        jnp.bfloat16)
         # long query chunks belong to the prefill kernel path
-        assert not fa.paged_decode_attention_supported((1, 8, 9, 64),
+        assert not fa.paged_decode_attention_supported((1, 8, 9, 128),
                                                        bs, nb,
                                                        jnp.bfloat16)
+        # the pools stay in HBM and an entry is copied by hand: a head
+        # of half a lane tile cannot be; blocks of 32 can, with or
+        # without an int8 pool's scales or a bias (no argument of the
+        # gate: they add no rule)
+        assert not fa.paged_decode_attention_supported((1, 8, 1, 64), bs,
+                                                       nb, jnp.bfloat16)
+        assert fa.paged_decode_attention_supported(ok_q, 32, 4 * nb,
+                                                   jnp.bfloat16)
     finally:
         fa.reset_backend_memo()
 

@@ -433,6 +433,15 @@ def test_forced_pallas_refuses_by_name(monkeypatch):
     for args in ((128, 32, 1024), (64, 8, 1024), (256, 128, 128, True),
                  (128, 64, 64, True)):
         assert pd.mosaic_refusal(*args) is None
+    # the paged walk copies an entry out of HBM by hand: whole lanes in
+    # the pools' minor dimension (an int8 pool's scales and a bias are
+    # laid out by the walk's steps, and add no rule)
+    for args, match in (((64, 32), "head_dim 64 is not whole"),
+                        ((128, 12), "multiple of the 8"),
+                        ((48, 32), "head_dim 48")):
+        assert match in pd.paged_mosaic_refusal(*args)
+    for args in ((128, 32), (256, 8), (128, 128)):
+        assert pd.paged_mosaic_refusal(*args) is None
     # compiled mode: the same call that the interpreter takes is refused
     pool = jnp.zeros((3, 2, 12, 16), jnp.float32)     # block of 12, D=16
     table = jnp.zeros((1, 2), jnp.int32)
@@ -640,3 +649,201 @@ def test_auto_route_on_cpu_is_composition(model):
                          route="composition")
     np.testing.assert_array_equal(auto.generate(ids, 6),
                                   comp.generate(ids, 6))
+
+
+# ---------------------------------------------------------------------------
+# the walk over a row's live entries, several a step (docs/DESIGN.md 5l)
+# ---------------------------------------------------------------------------
+
+# a benchmark cell's call, scaled down in rows and heads, not in the block,
+# the group or the chunk: (query heads, K/V heads, Lq, block, head size,
+# table entries, pool type, the tile its shapes give)
+_CELLS = {
+    "zaya": (8, 2, 1, 128, 128, 24, "bfloat16", 8),
+    "gpt": (4, 4, 1, 32, 128, 32, "float32", 8),
+    "jamba": (20, 1, 1, 128, 128, 18, "bfloat16", 8),
+    "sdar": (16, 2, 4, 128, 128, 20, "bfloat16", 8),
+}
+
+
+def _cell_case(rng, name, b):
+    import jax.numpy as jnp
+
+    hq, hkv, lq, bs, d, mb, dtype, tile = _CELLS[name]
+    nb = 1 + b * mb
+    q = jnp.asarray(rng.randn(b, hq, lq, d), dtype)
+    k_pool, v_pool = (jnp.asarray(rng.randn(nb, hkv, bs, d), dtype)
+                      for _ in range(2))
+    # a row's blocks lie anywhere in the pool
+    table = jnp.asarray(1 + rng.permutation(b * mb).reshape(b, mb),
+                        jnp.int32)
+    rows = (hq // hkv) * lq
+    assert pd.paged_tile_entries(
+        pd.head_chunk(hkv, bs, d, q.dtype.itemsize), rows, bs, d,
+        q.dtype.itemsize, mb) == tile
+    return q, k_pool, v_pool, table, lq, bs, mb, tile
+
+
+@pytest.mark.parametrize("ends", ["entry", "tile", "ragged", "whole"])
+@pytest.mark.parametrize("cell", sorted(_CELLS))
+def test_the_walk_matches_the_composition_at_the_cells_geometries(cell,
+                                                                  ends):
+    # lengths that end exactly on an entry's boundary, on a tile's, in
+    # the middle of an entry, and at the table's end (jamba's 18 and
+    # sdar's 20 entries are widths no tile of 8 divides)
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(21)
+    b = 4
+    q, k_pool, v_pool, table, lq, bs, mb, tile = _cell_case(rng, cell, b)
+    held = {"entry": [bs, 3 * bs, (tile + 1) * bs, 2 * bs],
+            "tile": [tile * bs, 2 * tile * bs, tile * bs, tile * bs],
+            "ragged": [lq, bs + 7, tile * bs + bs // 2, mb * bs - 5],
+            "whole": [mb * bs] * b}[ends]
+    q_pos = jnp.asarray([[n - lq + t for t in range(lq)] for n in held],
+                        jnp.int32)
+    got = np.asarray(fa.paged_decode_attention(
+        q, k_pool, v_pool, table, q_pos=q_pos,
+        route="pallas").astype(jnp.float32))
+    want = np.asarray(fa.paged_decode_attention(
+        q, k_pool, v_pool, table, q_pos=q_pos,
+        route="composition").astype(jnp.float32))
+    # bfloat16: both sides round float32 sums at the end, an ulp is 2**-8
+    # of a value
+    tol = 4e-6 if q.dtype == jnp.float32 else 2.0 ** -6
+    np.testing.assert_allclose(got, want, atol=tol)
+
+
+@pytest.mark.parametrize("lq", [1, 4, 8])
+@pytest.mark.parametrize("form,bs,tile", [
+    ("int8", 128, 5), ("bias", 128, 5), ("bias-one-head", 128, 5),
+    ("chunks", 128, 2), ("int8", 32, 5), ("bias", 32, 5),
+    ("bias-one-head", 8, 5), ("int8", 8, 1)])
+def test_the_walk_with_scales_bias_and_part_head_chunks(monkeypatch, form,
+                                                        bs, tile, lq):
+    # the side streams: an int8 pool's scales (gathered through the
+    # table) and a bias reach the kernel laid out by the walk's steps, a
+    # step's share in a row of whole lanes whatever the block: blocks of
+    # 128, of 32 (five entries are 160 positions in 256 lanes) and of 8
+    # (an int8 entry of 8 rows is a tile of its own: 8 positions in 128
+    # lanes); and a chunk of one head of three, two entries a tile (the
+    # budget holds two heads' blocks, 3 has no divisor 2)
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(22)
+    b, d, mb = 3, 16, 5
+    h = 3 if form == "chunks" else 8
+    quant = form == "int8"
+    if form == "chunks":
+        _kv_budget(monkeypatch, 2, bs, d, 4)
+        assert pd.head_chunk(h, bs, d, 4) == 1
+    hc = pd.head_chunk(h, bs, d, 1 if quant else 4, quant)
+    assert pd.paged_tile_entries(hc, lq, bs, d, 1 if quant else 4,
+                                 mb) == tile
+    q, k_pool, v_pool, table, ks, vs = _paged_case(rng, b, h, bs, d, mb, lq,
+                                                   quant)
+    held = np.array([bs + 3, mb * bs, 2 * bs], np.int32)
+    kwargs = dict(k_scale=ks, v_scale=vs, q_pos=jnp.asarray(
+        held[:, None] - lq + np.arange(lq, dtype=np.int32)))
+    if form.startswith("bias"):
+        hb = 1 if form == "bias-one-head" else h
+        bias = rng.randn(b, hb, lq, mb * bs).astype(np.float32)
+        bias[rng.rand(*bias.shape) < 0.2] = np.finfo(np.float32).min
+        bias[..., 0] = 0.0
+        kwargs["bias"] = jnp.asarray(bias)
+    got = np.asarray(fa.paged_decode_attention(
+        q, k_pool, v_pool, table, route="pallas", **kwargs))
+    want = np.asarray(fa.paged_decode_attention(
+        q, k_pool, v_pool, table, route="composition", **kwargs))
+    np.testing.assert_allclose(got, want, atol=4e-6)
+    pd._paged_call.clear_cache()
+
+
+@pytest.mark.parametrize("hc,rows,bs,d,itemsize,mb,want", [
+    (2, 4, 128, 128, 2, 24, 8),     # zaya: 512 KB an entry
+    (16, 1, 32, 128, 4, 32, 2),     # gpt: 1.5 MB an entry
+    (1, 20, 128, 128, 2, 18, 8),    # jamba: the cap, not the budget
+    (4, 32, 128, 128, 2, 20, 4),    # sdar: 1 MB an entry
+    (4, 64, 128, 128, 2, 20, 4),    # a verify chunk of 8 there
+    (16, 1, 32, 128, 1, 32, 5),     # gpt's pool in int8: 768 KB an entry
+    (8, 4, 128, 128, 1, 16, 2),     # int8 in blocks of 128: 1.5 MB
+    (8, 1, 16, 128, 1, 16, 1),      # int8 rows off its sublanes of 32
+    (4, 1, 8, 128, 2, 8, 1),        # bfloat16 rows off its sublanes
+    (2, 1, 8, 16, 4, 4, 4),         # the toy: the table's width
+    (32, 8, 128, 256, 4, 64, 1),    # an entry that fills the budget
+], ids=["zaya", "gpt", "jamba", "sdar", "sdar-verify", "int8-32",
+        "int8-128", "int8-16", "bf16-8", "toy", "full"])
+def test_tile_entries_come_from_shapes(hc, rows, bs, d, itemsize, mb, want):
+    assert pd.paged_tile_entries(hc, rows, bs, d, itemsize, mb) == want
+
+
+def test_a_row_that_sees_nothing_between_two_live_rows():
+    # the first tile of the row after is started while the row before
+    # computes its last: with an empty row between them no copy may land
+    # in it, and none may be lost.  Every buffer's turn is taken in order
+    # (an odd number of tiles before the gap, an even one after)
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(23)
+    b, h, bs, d, mb, lq = 6, 2, 8, 16, 12, 1
+    q, k_pool, v_pool, table, _, _ = _paged_case(rng, b, h, bs, d, mb, lq,
+                                                 False)
+    assert pd.paged_tile_entries(2, 1, bs, d, 4, mb) == 8
+    held = np.array([0, 70, 0, 0, 90, 30], np.int32)
+    q_pos = jnp.asarray(held[:, None] - 1)
+    got = np.asarray(fa.paged_decode_attention(
+        q, k_pool, v_pool, table, q_pos=q_pos, route="pallas"))
+    want = np.asarray(fa.paged_decode_attention(
+        q, k_pool, v_pool, table, q_pos=jnp.maximum(q_pos, 0),
+        route="composition"))
+    assert np.all(got[held == 0] == 0.0)
+    np.testing.assert_allclose(got[held > 0], want[held > 0], atol=2e-6)
+    # and a call of empty rows alone copies nothing and emits zeros
+    none = np.asarray(fa.paged_decode_attention(
+        q, jnp.full_like(k_pool, jnp.nan), jnp.full_like(v_pool, jnp.nan),
+        table, q_pos=jnp.full((b, lq), -1, jnp.int32), route="pallas"))
+    assert np.all(none == 0.0)
+
+
+@pytest.mark.parametrize("case", ["float", "int8", "bias"])
+def test_an_entry_of_the_last_tile_past_the_rows_reach_changes_no_bit(case):
+    # rows that end inside their FIRST tile, the entries behind them NaN
+    # in K, V, scales and bias.  No such entry of K or V is copied (0 x
+    # NaN would be in the sums): what a buffer holds there is an older
+    # entry's values under a probability of exactly 0.  The scales and
+    # the bias come a step's width at a time, dead positions with them:
+    # K's scales and the bias fall to the mask's select, V's scales are
+    # 0 past the row's reach.  The result is the clean pool's to the bit
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(24)
+    b, h, bs, d, mb, lq = 3, 8, 128, 16, 6, 2
+    quant = case == "int8"
+    q, k_pool, v_pool, table, ks, vs = _paged_case(rng, b, h, bs, d, mb, lq,
+                                                   quant)
+    assert pd.paged_tile_entries(8, lq, bs, d, 1 if quant else 4,
+                                 mb) == 6
+    last = np.array([3, 0, 4])
+    q_pos = jnp.asarray(last[:, None] * bs + np.array([[5, 6]]), jnp.int32)
+    kwargs = dict(q_pos=q_pos, k_scale=ks, v_scale=vs)
+    bias = rng.randn(b, 1, lq, mb * bs).astype(np.float32)
+    if case == "bias":
+        kwargs["bias"] = jnp.asarray(bias)
+    clean = fa.paged_decode_attention(q, k_pool, v_pool, table,
+                                      route="pallas", **kwargs)
+    dead_cols = np.arange(mb)[None, :] > last[:, None]
+    dead = np.asarray(table)[dead_cols]
+    if quant:
+        kwargs.update(k_scale=ks.at[dead].set(jnp.nan),
+                      v_scale=vs.at[dead].set(jnp.nan))
+    else:
+        k_pool = k_pool.at[dead].set(jnp.nan)
+        v_pool = v_pool.at[dead].set(jnp.nan)
+    if case == "bias":
+        bias[np.broadcast_to(np.repeat(dead_cols, bs, 1)[:, None, None, :],
+                             bias.shape)] = np.nan
+        kwargs["bias"] = jnp.asarray(bias)
+    got = np.asarray(fa.paged_decode_attention(q, k_pool, v_pool, table,
+                                               route="pallas", **kwargs))
+    assert np.isfinite(got).all(), "a dead entry reached the arithmetic"
+    np.testing.assert_array_equal(got, np.asarray(clean))

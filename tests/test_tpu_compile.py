@@ -99,10 +99,16 @@ def test_pool_decode_on_the_v5e_moves_no_pool(one_chip, model, monkeypatch,
     made = [op for _, op in chip_smoke.pool_shaped_ops(text, pool_shape)]
     assert made.count("scatter") == 2 * LAYERS
     # (copy-start/copy-done: the scheduler's prefetch of an int8 pool
-    # into another memory space for the kernel, the parent's too; no
-    # layout changes hands there)
+    # into another memory space for the kernel, the parent's too; since
+    # the kernel takes the pool whole it comes in slices, joined by a
+    # ``ConcatBitcast`` custom call; no layout changes hands there)
     assert set(made) <= {"parameter", "scatter", "fusion", "bitcast",
-                         "copy-start", "copy-done"}, sorted(set(made))
+                         "copy-start", "copy-done",
+                         "custom-call"}, sorted(set(made))
+    for name, op in chip_smoke.pool_shaped_ops(text, pool_shape):
+        if op == "custom-call":
+            line = text[text.index("%%%s = " % name):].split("\n", 1)[0]
+            assert 'custom_call_target="ConcatBitcast"' in line, line
     assert made.count("fusion") == made.count("scatter")
     # the sampler's sort over [slots, vocabulary] stays inside the
     # conditional's drawing branch: the TPU's compiler keeps the
@@ -117,10 +123,11 @@ def test_pool_decode_on_the_v5e_moves_no_pool(one_chip, model, monkeypatch,
 @pytest.mark.parametrize("cache_dtype", ["float32", "int8"])
 def test_paged_kernel_at_the_gpt_cells_geometry_on_the_v5e(one_chip, lq,
                                                            cache_dtype):
-    """The kernel alone at ``gpt-1p3b``'s cache: every head of a block in
-    one grid step ([16, 32, 128] K and V blocks, the int8 pool's [16, 32]
-    scale blocks), one row and a verify chunk's five, the index maps
-    clamped by ``q_pos``.  One custom call each."""
+    """The kernel alone at ``gpt-1p3b``'s cache: every head of an entry
+    in one copy ([16, 32, 128] of K and of V: two float32 entries a tile
+    of the walk, five int8 ones with their scales a step's 160 positions
+    in a row of 256 lanes), one row and a verify chunk's five, the pools
+    left in HBM.  One custom call each."""
     import jax
     import jax.numpy as jnp
 
@@ -136,11 +143,60 @@ def test_paged_kernel_at_the_gpt_cells_geometry_on_the_v5e(one_chip, lq,
         args += [shape((512, 16, 32), jnp.float32)] * 2
     assert pallas_decode.head_chunk(16, 32, 128, pool.dtype.itemsize,
                                     cache_dtype == "int8") == 16
+    assert pallas_decode.paged_tile_entries(
+        16, lq, 32, 128, pool.dtype.itemsize, 32) == (
+            5 if cache_dtype == "int8" else 2)
     text = jax.jit(
         lambda q, k, v, t, p, *scales:
         pallas_decode.paged_decode_attention_kernel(
             q, k, v, t, p, 128 ** -0.5, *scales)).lower(
         *args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize(
+    "cell,rows,heads,kv_heads,lq,block,entries,blocks,tile", [
+        ("zaya", 64, 8, 2, 1, 128, 24, 1537, 8),
+        ("zaya-verify", 64, 8, 2, 8, 128, 24, 1537, 8),
+        ("jamba", 64, 20, 1, 1, 128, 18, 1153, 8),
+        ("bias", 8, 8, 8, 4, 128, 8, 65, 2),
+        ("bias-32", 8, 8, 8, 4, 32, 32, 257, 8)])
+def test_the_paged_walk_compiles_at_the_bfloat16_cells_on_the_v5e(
+        one_chip, cell, rows, heads, kv_heads, lq, block, entries, blocks,
+        tile):
+    """The walk over a row's live entries under Mosaic at ``zaya1-8b``'s
+    call (2 K/V heads under 8 query heads, blocks of 128, bfloat16: eight
+    entries a tile, copied by hand into [2, 1024, 128] buffers) at one
+    position and at a verify chunk's eight, at ``jamba2-3b``'s (one K/V
+    head under 20 query heads: 20 rows, a table of 18 that no tile of 8
+    divides), and with an additive bias laid out by the walk's steps, at
+    blocks of 128 and of 32 (a quarter of a lane tile an entry).
+    ``sdar-30b-a3b``'s call is the grouped test's, below."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import pallas_decode
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    bf = jnp.bfloat16
+    has_bias = cell.startswith("bias")
+    group = heads // kv_heads
+    assert pallas_decode.paged_mosaic_refusal(128, block) is None
+    assert pallas_decode.paged_tile_entries(
+        pallas_decode.head_chunk(kv_heads, block, 128, 2), group * lq,
+        block, 128, 2, entries) == tile
+    pool = shape((blocks, kv_heads, block, 128), bf)
+    args = [shape((rows, heads, lq, 128), bf), pool, pool,
+            shape((rows, entries), jnp.int32), shape((rows, lq), jnp.int32)]
+    if has_bias:
+        args.append(shape((rows, 1, lq, entries * block), jnp.float32))
+    text = jax.jit(
+        lambda q, k, v, t, p, *bias:
+        pallas_decode.paged_decode_attention_kernel(
+            q, k, v, t, p, 128 ** -0.5, bias=bias[0] if bias else None)
+    ).lower(*args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
