@@ -108,6 +108,8 @@ def sizes(toy: bool) -> dict:
                        num_heads=2, intermediate_size=128,
                        max_position=128, causal=True),
             flash_seq=128,
+            experts=dict(rows=8, held=4, experts=16, groups=4, kept=2,
+                         top_k=2, width=32, size=16),
             retention=dict(slots=2, q_heads=4, kv_heads=2, head_dim=16,
                            chunk_tokens=24, timed_steps=2))
     lm = gpt_1p3b_config()          # 24 layers, width 2048, 16 heads x 128
@@ -130,6 +132,11 @@ def sizes(toy: bool) -> dict:
                    num_heads=8, intermediate_size=4096,
                    max_position=8192, causal=True),
         flash_seq=8192,
+        # ax-k1's expert layer as one chip holds it: 12 of 192 experts of
+        # 7168 x 2048, 8 groups of 24 of which the 4 best, 8 a token, a
+        # decode step's 32 rows
+        experts=dict(rows=32, held=12, experts=192, groups=8, kept=4,
+                     top_k=8, width=7168, size=2048),
         # brumby-14b's state: 40 query heads on 8 K/V heads of 128, 16
         # slots; 256 positions are two chunks of the prefill scan
         retention=dict(slots=16, q_heads=40, kv_heads=8, head_dim=128,
@@ -730,6 +737,59 @@ def _grouped_kernel(jax, sz: dict, tol: float, platform: str) -> None:
                    diff / scale, tol))
 
 
+def _expert_routes(jax, sz: dict, tol: float, platform: str) -> None:
+    """The expert layer's few-row routes on one draw of the router, in
+    bfloat16: only the experts some row chose (``_touched``) against
+    every held expert on every row (``_every_expert``)."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional import moe
+
+    g = sz["experts"]
+    rows, held, k = g["rows"], g["held"], g["top_k"]
+    shape = (rows, held, g["experts"], k, g["width"], g["size"])
+    picked = moe.expert_route(*shape, 2)
+    # the toy's experts are too small for a skipped read to pay
+    check(picked == ("touched" if g["width"] >= 2048 else "every"),
+          "the rule picks %r at rows, held, experts, top_k, width, size "
+          "= %r" % (picked, shape))
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    x = jax.random.normal(ks[0], (rows, g["width"]), jnp.bfloat16)
+    scores = 1.7 * jax.random.normal(ks[1], (rows, g["experts"]),
+                                     jnp.float32)
+    w_gate, w_up, w_down = (
+        (0.02 * jax.random.normal(k_, s, jnp.float32)).astype(jnp.bfloat16)
+        for k_, s in zip(ks[2:], [(held, g["width"], g["size"])] * 2
+                         + [(held, g["size"], g["width"])]))
+
+    def both(x, scores, w_gate, w_up, w_down):
+        gates, experts = moe.route_top_k(scores, k, "sigmoid", g["groups"],
+                                         g["kept"], 2.5)
+        # this share: the second ``held`` experts of the first group
+        local = experts.reshape(-1) - held
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        touched = jnp.sum(jnp.bincount(key, length=held + 1)[:held] > 0)
+        return [route(x, gates, key, held, k, w_gate, w_up, w_down)
+                for route in (moe._touched, moe._every_expert)] + [touched]
+
+    got, want, touched = (np.asarray(a, np.float32) for a in
+                          jax.jit(both)(x, scores, w_gate, w_up, w_down))
+    check(np.isfinite(got).all(), "experts/bf16: touched route not finite")
+    # one side sums its experts in float32, the other inside one matmul
+    # over bfloat16 products: a bfloat16 ulp of the widest product
+    tol = max(tol, 2.0 ** -6)
+    scale = max(1.0, float(np.abs(want).max()))
+    diff = float(np.abs(got - want).max())
+    check(diff <= tol * scale and float(np.abs(want).max()) > 0,
+          "experts/bf16: touched vs every expert differ by %.3g (> %.3g)"
+          % (diff, tol * scale))
+    say("[kernels] experts/bf16   %d rows, %d of %d experts of %d x %d "
+        "held, %d touched: the rule picks %r; max |diff| of the touched "
+        "route vs every expert %.3g of scale (tolerance %.3g)"
+        % (rows, held, g["experts"], g["width"], g["size"], int(touched),
+           picked, diff / scale, tol))
+
+
 def _flash_step(pt, jax, sz: dict, platform: str) -> None:
     """One forward-and-backward step of the long-sequence configuration
     through the library's Pallas flash attention."""
@@ -803,6 +863,7 @@ def phase_kernels(pt, jax, sz: dict, tol: float, state: dict) -> None:
         for dtype in ("float32", "int8"):
             _kernel_variant(jax, model, sz, layout, dtype, tol, platform)
     _grouped_kernel(jax, sz, tol, platform)
+    _expert_routes(jax, sz, tol, platform)
     # a geometry the kernel cannot take: the forced route must refuse
     # it by name — never decode on the composition instead
     heads = sz["kernel_lm"]["num_heads"]

@@ -149,7 +149,7 @@ class BlockDiffusionPool(GenerationPool):
             raise InvalidArgumentError(
                 "cache_dtype='int8': generation by diffusion over blocks "
                 "keeps a float K/V cache (no grouped-head int8 kernel)")
-        self._B = int(model.block_length)
+        self._B = self._rows_a_slot = int(model.block_length)
         self._T = int(model.denoise_steps)
         self._mask_id = int(model.mask_token_id)
         if int(max_len) % self._B:
@@ -372,6 +372,7 @@ class BlockDiffusionPool(GenerationPool):
                  + bl * (1 - int(ctl[slot, 2 * bl + 1]))) // bs + 1
                 for slot, _ in self._rows)
             meta["table_blocks"] = self.slots * self._max_blocks
+        meta.update(self._expert_meta(live))
         return meta
 
     def _launch(self, params, bufs, ctl):

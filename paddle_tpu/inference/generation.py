@@ -107,7 +107,9 @@ from ..jit import aot
 from ..jit.cache import get_layout
 from ..jit.decode import (DecodeSession, check_sampling, classify_finish,
                           make_sampling_state, sample_logits_data)
+from ..nn import functional as F
 from ..nn import lora as _lora_mod
+from ..nn.layer.moe import SparseExperts
 from ..jit.mesh import DecodeMesh
 
 __all__ = ["GenerationPool", "kv_reachable_bytes",
@@ -562,6 +564,16 @@ class GenerationPool:
         self._by_kind_stats = {
             "bytes_per_slot": {k: b for k, (_, b) in self._by_kind.items()},
             "cache_entries": {k: n for k, (n, _) in self._by_kind.items()}}
+        # the model's routed expert layers as ``(held, experts, top_k)``,
+        # and the route the step's rows compile them to: from shapes
+        experts = [layer for layer in model.sublayers()
+                   if isinstance(layer, SparseExperts)]
+        self._experts = [(layer.held[1], layer.num_experts, layer.top_k)
+                         for layer in experts]
+        self._experts_held = sum(held for held, _, _ in self._experts)
+        self._expert_route = "+".join(sorted(
+            {layer.route_at(self.slots * self._rows_a_slot)
+             for layer in experts}))
         if donate is None:
             donate = jax.default_backend() != "cpu"
         self._decode_jit = jax.jit(self._pool_decode,
@@ -2889,6 +2901,9 @@ class GenerationPool:
     # reads what the last download brought.  A property of the KIND of
     # pool, read by the skeleton below: no option sets it
     _depth = 1
+    # rows of a layer's input that a slot takes in the compiled step (the
+    # block pool: a block's positions)
+    _rows_a_slot = 1
     # the step that committed the token being handed on, where a pool
     # commits a token some steps after it was first computed (the block
     # pool's ``_leaving`` sets it a token); it rides beside each token
@@ -3096,11 +3111,35 @@ class GenerationPool:
                               if k != "recurrent"))
         return meta
 
+    def _expert_meta(self, live: int) -> dict:
+        """What a step with ``live`` live slots reads of the model's routed
+        expert layers, from shapes (nothing is asked of the device):
+        ``moe_route``, the route the step's rows compile the layers to
+        (``F.expert_route``); ``experts_held``, the experts held, summed
+        over the layers; ``experts_read_expected``, those whose weights
+        the step is expected to read: all of them where every expert runs
+        on every row, else the share that the live rows' even choices
+        touch (``F.touched_share``).  An inactive slot's row is routed
+        too, so a sparse pool reads more than this.  Empty for a model
+        with no such layer."""
+        if not self._experts:
+            return {}
+        rows = live * self._rows_a_slot
+        return dict(
+            moe_route=self._expert_route,
+            experts_held=self._experts_held,
+            experts_read_expected=sum(
+                held * (1.0 if self._expert_route == "every"
+                        else F.touched_share(rows, n, k))
+                for held, n, k in self._experts))
+
     def _decode_meta(self, *inputs) -> dict:
         """``tick.decode``'s meta, from what ``_launch`` is about to be
         given; built only under a tracer."""
-        return dict(live=len(self._rows), slots=self.slots,
-                    greedy=int(not self._draws), **self._block_meta())
+        live = len(self._rows)
+        return dict(live=live, slots=self.slots,
+                    greedy=int(not self._draws), **self._block_meta(),
+                    **self._expert_meta(live))
 
     def _launch(self, params, bufs):
         """The one batched decode dispatch (cache donated and rebound in
@@ -3393,8 +3432,10 @@ class GenerationPool:
         preallocation of the same pool would pin — the paged win,
         quantified from the allocator state rather than asserted."""
         # bytes a slot and layers, by the entries' kind (a model with one
-        # kind of entry has one key)
-        by_kind = self._by_kind_stats
+        # kind of entry has one key); the expert layers' route and read
+        # at the slots live now
+        by_kind = {**self._by_kind_stats,
+                   **self._expert_meta(len(self._active))}
         if set(self._by_kind) == {"recurrent"}:
             # O(1)-state accounting: the whole cache is [slots, d_state]
             # per layer — no positional axis, so reachable == resident

@@ -160,6 +160,14 @@ class SparseExperts(Layer):
         self.shared = GatedMLP(hidden_size, int(shared_size)) \
             if shared_size else None
 
+    def route_at(self, rows: int) -> str:
+        """The route ``F.sparse_experts`` takes when this layer is given
+        ``rows`` rows (``F.expert_route``: from shapes alone)."""
+        _, width, size = self.w_gate.shape
+        return F.expert_route(rows, self.held[1], self.num_experts,
+                              self.top_k, width, size,
+                              self.w_gate.value.dtype.itemsize)
+
     def routed(self, x, r_prev=None):
         """The held experts' part of the routed sum; under a router layer
         ``(part, r)``, ``r`` the router's state for the next layer."""

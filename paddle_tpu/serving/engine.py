@@ -630,6 +630,16 @@ class ServingEngine:
             "bytes of recurrent state one slot holds, every entry (a "
             "constant of the model, whatever the context)") \
             if self._pool._layout.recurrent else None
+        stats = self._pool.cache_stats()
+        self._g_experts_read = m.gauge(
+            "serving_moe_experts_read_expected",
+            "held experts whose weights a decode step is expected to read "
+            "at the slots live now, over the model's routed expert layers "
+            "(from shapes; the series names the route the step compiled "
+            "to and the experts held)",
+            labels={"route": stats["moe_route"],
+                    "held": str(stats["experts_held"])}) \
+            if "moe_route" in stats else None
         self._g_cache_entries = {
             kind: m.gauge(
                 "serving_cache_entries",
@@ -637,7 +647,7 @@ class ServingEngine:
                 "kinds has more than one series; a layer may own one of "
                 "each)",
                 labels={"layout": kind})
-            for kind in self._pool.cache_stats()["cache_entries"]}
+            for kind in stats["cache_entries"]}
         self._g_kv_free = m.gauge(
             "serving_kv_free_blocks",
             "paged allocator free blocks") \
@@ -2570,6 +2580,8 @@ class ServingEngine:
             self._g_state_slot.set(stats["bytes_per_slot"]["recurrent"])
         for kind, g in self._g_cache_entries.items():
             g.set(stats["cache_entries"][kind])
+        if self._g_experts_read is not None:
+            self._g_experts_read.set(stats["experts_read_expected"])
         if self._g_kv_free is not None:
             self._g_kv_free.set(stats["free_blocks"])
         if self._g_kv_resident_shard is not None:

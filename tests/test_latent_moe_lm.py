@@ -247,6 +247,8 @@ def test_served_over_http_through_the_engine(model, weights):
         _assert_best(weights, prompt, toks)
         text = engine.metrics.render_prometheus().replace(".0\n", "\n")
         assert 'serving_cache_entries{layout="latent"} 3\n' in text
+        assert ('serving_moe_experts_read_expected{held="16",'
+                'route="every"} 16\n') in text
         assert text.count("# TYPE serving_cache_entries gauge") == 1
         assert "serving_kv_free_blocks" in text
         assert engine.cache_stats()["cache_entries"] == {"latent": 3}
@@ -310,7 +312,13 @@ def test_cache_stats_spans_and_no_retrace(model):
         and "state_bytes" not in m and "kv_entries" not in m
         for m in decodes)
     assert any(m["ahead"] == 1 for m in decodes)
+    # two expert layers of 8 held: at these widths a skipped read saves
+    # nothing, every expert runs on every row and all 16 are read
+    assert all(m["moe_route"] == "every" and m["experts_held"] == 16
+               and m["experts_read_expected"] == 16.0 for m in decodes)
     stats = pool.cache_stats()
+    assert (stats["moe_route"], stats["experts_held"],
+            stats["experts_read_expected"]) == ("every", 16, 16.0)
     assert stats["cache_layout"] == pool.cache_layout == "latent"
     assert stats["cache_entries"] == {"latent": 3}
     assert stats["bytes_per_slot"] == {"latent": 3 * 64 * ENTRY_BYTES}
@@ -330,6 +338,40 @@ def test_cache_stats_spans_and_no_retrace(model):
                        cache_layout="paged", block_size=8)
     assert p.cache_stats()["dense_equiv_bytes"] \
         == 2 * 64 * 2 * 2 * 16 * 4
+    assert "moe_route" not in p.cache_stats()
+
+
+def test_served_tokens_are_the_same_on_the_touched_route(model, weights,
+                                                         monkeypatch):
+    """Where a skipped read is given a price of nothing the expert layers
+    run only the experts some row chose, prompts and steps: the served
+    tokens are those of every expert on every row, and the reference's
+    best; the span says which route the step compiled to and what its
+    live rows are expected to read."""
+    from paddle_tpu.nn.functional import moe
+    from paddle_tpu.serving import trace as engine_trace
+
+    prompts = _prompts([13, 27, 9], seed=4)
+    every, _ = _serve(model, prompts, new=10)
+    monkeypatch.setattr(moe, "_SKIP_COST_S", 0.0)
+    tracer = engine_trace.Tracer(capacity=4096)
+    engine_trace.install(tracer)
+    try:
+        touched, pool = _serve(model, prompts, new=10)
+    finally:
+        engine_trace.uninstall()
+    for i, prompt in enumerate(prompts):
+        assert [int(t) for t in touched[i]] == [int(t) for t in every[i]]
+        _assert_best(weights, prompt, [int(t) for t in touched[i]])
+    decodes = [e.meta for e in tracer.recorder.snapshot()
+               if e.name == "tick.decode"]
+    # 4 of 16 a token: a row leaves 3/4 of the experts alone
+    assert decodes and all(
+        m["moe_route"] == "touched" and m["experts_held"] == 16
+        and m["experts_read_expected"]
+        == pytest.approx(16 * (1 - 0.75 ** m["live"])) for m in decodes)
+    assert {m["live"] for m in decodes} == {1, 2}
+    assert pool.cache_stats()["experts_read_expected"] == 0.0   # idle
 
 
 # -- 4. refusals -----------------------------------------------------------------

@@ -259,21 +259,28 @@ def test_one_group_is_plain_top_k_and_softmax_stays_as_it_was(rng):
         pt.nn.functional.route_top_k(logits, 3, "tanh")
 
 
-@pytest.mark.parametrize("rows", [8, 200])
+@pytest.mark.parametrize("rows,route", [(8, "every"), (200, "grouped"),
+                                        (8, "touched")])
 def test_sixteen_shares_and_the_shared_expert_once_are_the_whole_layer(
-        rows, monkeypatch):
+        rows, route, monkeypatch):
     """192-wide router in miniature: 32 experts in 8 groups of 4, the 4
     best groups, 8 a token, held 2 a share by 16 holders.  The shares'
     routed parts plus the shared expert ONCE add up to the uncut layer,
     which a plain Python loop over tokens and experts gives.  8 rows run
-    every held expert on every row, 200 the grouped matmuls (the line
-    between the routes brought down to these widths)."""
+    every held expert on every row, or only the experts some row chose
+    where a skipped read is given a price of nothing; 200 the grouped
+    matmuls (the lines between the routes brought down to these
+    widths)."""
     from paddle_tpu.core.errors import InvalidArgumentError
     from paddle_tpu.nn.functional import moe
 
     monkeypatch.setattr(moe, "_EVERY_EXPERT_MACS", 8 * 32 * 16 * 8)
+    if route == "touched":
+        monkeypatch.setattr(moe, "_SKIP_COST_S", 0.0)
 
     h, f, e, k = 16, 8, 32, 8
+    assert moe.expert_route(rows, e, e, k, h, f, 4) == route \
+        == moe.expert_route(rows, 2, e, k, h, f, 4)
     rng = np.random.default_rng(3)
     x = rng.normal(size=(rows, h)).astype(np.float32)
     whole = pt.nn.SparseExperts(h, f, e, k, scoring="sigmoid", n_group=8,
@@ -324,3 +331,187 @@ def test_sixteen_shares_and_the_shared_expert_once_are_the_whole_layer(
         pt.nn.SparseExperts(h, f, e, k, n_group=8, topk_group=4)  # softmax
     with pytest.raises(InvalidArgumentError, match="scoring"):
         pt.nn.SparseExperts(h, f, e, k, scoring="tanh")
+
+
+# -- the route between the expert layer's three forms ---------------------------
+# (``F.expert_route``: a function of the shapes alone; ``_touched`` runs the
+# held experts that some row chose)
+
+def _parent_route(rows, held, experts, top_k, width, size, itemsize=2):
+    """The rule before there was a third route (PR 40)."""
+    return "every" if rows * held * width * size <= 512 * 128 * 2048 * 768 \
+        else "grouped"
+
+
+# rows, held, experts, top_k, width, an expert's size: the cells' steps
+SDAR, ZAYA, AXK1 = ((128, 128, 8, 2048, 768), (16, 16, 1, 2048, 2048),
+                    (12, 192, 8, 7168, 2048))
+SHAPES = {
+    "sdar-block-step": (128,) + SDAR, "zaya-decode": (64,) + ZAYA,
+    "axk1-decode": (32,) + AXK1, "axk1-verify-chunk": (256,) + AXK1,
+    **{"sdar-prefill-%d" % n: (n,) + SDAR for n in (512, 1024, 2048)},
+    **{"zaya-prefill-%d" % n: (n,) + ZAYA for n in (256, 512, 1024)},
+    **{"axk1-prefill-%d" % n: (n,) + AXK1 for n in (2048, 4096, 8192)},
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_the_rule_picks_the_touched_route_at_axk1s_decode_step_alone(shape):
+    """At every other shape a cell runs, prefills among them, the route is
+    the one the parent's rule gave."""
+    from paddle_tpu.nn.functional import moe
+
+    got = moe.expert_route(*SHAPES[shape], 2)
+    if shape == "axk1-decode":
+        assert got == "touched" and _parent_route(*SHAPES[shape]) == "every"
+    else:
+        assert got == _parent_route(*SHAPES[shape])
+    # the expected saving an expert against the one constant
+    rows, held, experts, top_k, width, size = SHAPES[shape]
+    saved = (1 - top_k / experts) ** rows * 3 * width * size * 2 / 819e9
+    assert (got == "touched") == (saved > moe._SKIP_COST_S
+                                  and got != "grouped")
+    assert moe.touched_share(rows, experts, top_k) == pytest.approx(
+        1 - (1 - top_k / experts) ** rows)
+
+
+def test_axk1s_rows_are_expected_to_touch_nine_of_the_twelve():
+    from paddle_tpu.nn.functional import moe
+
+    assert 12 * moe.touched_share(32, 192, 8) == pytest.approx(8.93, abs=0.01)
+    assert moe.touched_share(0, 192, 8) == 0.0
+
+
+def _parents_every_expert(x, scores, w_gate, w_up, w_down, top_k,
+                          renormalise):
+    """``sparse_experts`` as the parent traced it under the multiply-add
+    line, written out (PR 40-42)."""
+    from paddle_tpu.nn.functional import moe
+
+    xt = x.reshape(-1, x.shape[-1])
+    held = w_gate.shape[0]
+    gates, experts = moe.route_top_k(
+        scores.reshape(-1, scores.shape[-1]), top_k, "softmax", 1, 1, 1.0,
+        **({} if renormalise else {"renormalise": False}))
+    local = experts.reshape(-1) - 0
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    rows = xt.shape[0]
+    gate_of = jnp.sum(
+        jnp.where(key.reshape(rows, top_k, 1) == jnp.arange(held),
+                  gates[..., None], 0.0), axis=1)
+    act = jax.nn.silu(jnp.einsum("th,ehf->tef", xt, w_gate)) \
+        * jnp.einsum("th,ehf->tef", xt, w_up)
+    act = (act.astype(jnp.float32) * gate_of[..., None]).astype(xt.dtype)
+    out = jnp.einsum("tef,efh->th", act, w_down,
+                     preferred_element_type=jnp.float32)
+    return out.astype(x.dtype).reshape(x.shape)
+
+
+@pytest.mark.parametrize("cell", ["sdar-block-step", "zaya-decode"])
+def test_the_other_cells_expert_layers_trace_the_parents_program(cell):
+    """Equation for equation, at the cells' own shapes in bfloat16 (shapes
+    alone: nothing is allocated)."""
+    rows, held, experts, top_k, width, size = SHAPES[cell]
+    bf = jnp.bfloat16
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((rows, width), bf), ((rows, experts), jnp.float32),
+        ((held, width, size), bf), ((held, width, size), bf),
+        ((held, size, width), bf))]
+    renormalise = cell != "zaya-decode"
+    got = jax.make_jaxpr(lambda *a: pt.nn.functional.sparse_experts(
+        *a, top_k=top_k, renormalise=renormalise))(*args)
+    want = jax.make_jaxpr(lambda *a: _parents_every_expert(
+        *a, top_k, renormalise))(*args)
+    assert str(got) == str(want)
+    assert "while" not in str(got)
+
+
+def _touch_case(touch):
+    """12 rows over 8 experts, 2 a token, a share that holds experts 4, 5
+    and 6: rows that choose none of the three, all of them, or 4 and 6."""
+    rng = np.random.default_rng(5)
+    pairs = {"none": [(0, 1), (2, 3), (7, 0)],
+             "all": [(4, 5), (5, 6), (6, 4)],
+             "some": [(4, 0), (6, 4), (1, 2)]}[touch]
+    scores = rng.normal(size=(12, 8)).astype(np.float32)
+    for t in range(12):
+        scores[t, list(pairs[t % 3])] += 20.0
+    x = rng.normal(size=(12, 32)).astype(np.float32)
+    mats = [(0.3 * rng.normal(size=s)).astype(np.float32)
+            for s in [(3, 32, 16), (3, 32, 16), (3, 16, 32)]]
+    return x, scores, mats
+
+
+@pytest.mark.parametrize("touch", ["none", "all", "some"])
+def test_the_touched_route_reads_what_its_rows_chose(touch, monkeypatch):
+    """Rows that touch no held expert give exact zeros; rows that touch
+    every held expert, or some, what every expert on every row gives."""
+    from paddle_tpu.nn.functional import moe
+
+    x, scores, mats = _touch_case(touch)
+    call = lambda: np.asarray(jax.jit(
+        lambda *a: moe.sparse_experts(*a, top_k=2, first_expert=4))(
+            x, scores, *mats))
+    assert moe.expert_route(12, 3, 8, 2, 32, 16, 4) == "every"
+    every = call()
+    monkeypatch.setattr(moe, "_SKIP_COST_S", 0.0)
+    assert moe.expert_route(12, 3, 8, 2, 32, 16, 4) == "touched"
+    touched = call()
+    text = str(jax.make_jaxpr(lambda *a: moe.sparse_experts(
+        *a, top_k=2, first_expert=4))(x, scores, *mats))
+    assert "while" in text and "ragged_dot" not in text
+    if touch == "none":
+        assert not touched.any() and not every.any()
+    else:
+        # float32 sums in another order, values of order 1
+        assert np.abs(touched - every).max() < 1e-5
+        assert np.abs(every).max() > 0.5
+    # the rows that chose nothing held are zeros to the bit in both
+    chose = np.isin(np.argsort(-scores, -1)[:, :2], [4, 5, 6]).any(-1)
+    assert not touched[~chose].any() and chose.any() == (touch != "none")
+
+
+def test_a_gradient_through_the_touched_route_fails_by_name(monkeypatch):
+    """The loop's trip count is data: nothing differentiates through it,
+    and jax says so where the layer is traced (``nn.SparseExperts`` stops
+    the gradient at its output)."""
+    from paddle_tpu.nn.functional import moe
+
+    x, scores, mats = _touch_case("some")
+    loss = lambda x, scores: moe.sparse_experts(
+        x, scores, *mats, top_k=2, first_expert=4).sum()
+    every = jax.jit(jax.grad(loss))(x, scores)
+    assert np.isfinite(np.asarray(every)).all() and np.asarray(every).any()
+    monkeypatch.setattr(moe, "_SKIP_COST_S", 0.0)
+    with pytest.raises(ValueError, match="[Rr]everse-mode"):
+        jax.jit(jax.grad(loss))(x, scores)
+
+
+def test_the_bench_tool_forces_the_touched_count_and_rehearses_here(capsys):
+    """``tools/expert_route_bench.py``: the keys it hands a route touch
+    exactly the experts asked for, with the pairs the cell's routing sends
+    this share; off the chip it rehearses its control flow and prints no
+    time."""
+    import json
+
+    from tools import expert_route_bench as bench
+
+    for name, (rows, held, experts, k, _, _) in bench.GEOMETRIES.items():
+        counts = bench.touched_counts(rows, held, experts, k)
+        assert counts["one"] == 1 and counts["all"] == held
+        assert counts["draw"] == {"axk1": 9, "zaya": 16, "sdar": 128}[name]
+        for touched in counts.values():
+            key = bench.keys_for(touched, rows, held, experts, k)
+            assert key.shape == (rows * k,)
+            mine = key[key < held]
+            assert sorted(set(mine)) == list(range(touched))
+            assert len(mine) == max(touched, rows * k * held // experts)
+    assert bench.main(["--cpu-toy", "--geometry", "axk1", "--touched",
+                       "half"]) == 0
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+             if l.startswith("{")]
+    assert [l["route"] for l in lines] == ["every", "touched", "grouped"]
+    assert all(l["geometry"] == "toy" and l["touched"] == 2
+               and "ms_a_call" not in l and l["max_abs_diff"] < 1e-5
+               for l in lines)
+    assert bench.main(["--geometry", "axk1"]) == 1      # no chip, no time

@@ -236,6 +236,56 @@ def test_grouped_heads_kernel_and_grouped_matmul_on_the_v5e(one_chip,
                                   text))) == grouped, rows
 
 
+def test_the_touched_route_on_the_v5e_copies_no_expert(one_chip):
+    """ax-k1's expert layer at a decode step's shapes (32 rows, 12 of 192
+    experts of 7168 x 2048 held, 8 a token): a loop on the device over the
+    touched experts, whose three slices of the stacked weights are
+    operands of their products (beside it, in a conditional, every expert
+    on every row for the step whose rows chose them all).  A slice taken outside the loop's body
+    materialises 88 MB a matrix and costs more than a skipped expert
+    saves: no instruction outside a fusion makes an expert-sized matrix,
+    and the program's temporaries stay under one."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional import moe
+
+    rows, held, experts, k, width, size = 32, 12, 192, 8, 7168, 2048
+    assert moe.expert_route(rows, held, experts, k, width, size, 2) \
+        == "touched"
+    bf = jnp.bfloat16
+    args = [jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+            for dims, dtype in (
+                ((rows, width), bf), ((rows, experts), jnp.float32),
+                ((held, width, size), bf), ((held, width, size), bf),
+                ((held, size, width), bf))]
+    compiled = jax.jit(lambda x, s, *w: moe.sparse_experts(
+        x, s, *w, top_k=k, first_expert=24, scoring="sigmoid", n_group=8,
+        topk_group=4, routed_scale=2.5)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 1 and "ragged-dot" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < width * size * 2
+    matrix = re.compile(r" = bf16\[(1,)?(%d,%d|%d,%d)\]\S* (?!parameter|"
+                        r"get-tuple-element|fusion)"
+                        % (width, size, size, width))
+    outside = [line.strip()[:160]
+               for block in re.split(r"\n(?=%|ENTRY )", text)
+               if not block.startswith("%fused_computation")
+               for line in block.split("\n") if matrix.search(line)]
+    assert outside == []
+    # the products that take the stacked weights whole: a turn's three,
+    # each with the turn's expert (a scalar operand) sliced inside it, and
+    # the three of every expert on every row, the branch taken where the
+    # rows chose all twelve
+    products = [b.split("\n", 1) for b in re.split(r"\n(?=%|ENTRY )", text)
+                if b.startswith("%fused_computation")
+                and "bf16[%d," % held in b.split("\n", 1)[0]
+                and " convolution(" in b]
+    turn = [body for head, body in products if "s32[]" in head]
+    assert len(turn) == 3 and all(" fusion(" in body for body in turn)
+    assert len(products) == 6 and text.count(" conditional(") == 1
+
+
 def test_retention_step_on_the_v5e_updates_the_state_where_it_lies(
         one_chip, monkeypatch):
     """The power-retention cell's decode step at its real state geometry

@@ -973,7 +973,9 @@ _DECODE_META = {
     "speculative": {"spec_k", "live", "slots", "ahead"},
     "block": {"live", "slots", "ahead", "kind", "rows", "store", "denoise",
               "committed", "tokens_per_forward", "live_blocks",
-              "table_blocks"},
+              "table_blocks",
+              # its model has an expert layer (PR 45)
+              "moe_route", "experts_held", "experts_read_expected"},
 }
 _KINDS = sorted(_DECODE_META)
 
