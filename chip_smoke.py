@@ -127,7 +127,7 @@ def sizes(toy: bool) -> dict:
         # of 4 query positions
         grouped=dict(slots=32, q_heads=32, kv_heads=4, rows=4, block=128,
                      head_dim=128, table=20),
-        # bench_longseq_flash's on-chip configuration (head_dim 128)
+        # a long-sequence training configuration (head_dim 128)
         flash=dict(vocab_size=32000, hidden_size=1024, num_layers=4,
                    num_heads=8, intermediate_size=4096,
                    max_position=8192, causal=True),
@@ -571,9 +571,8 @@ def phase_serve(pt, jax, sz: dict, tol: float, state: dict) -> None:
 # ---------------------------------------------------------------------------
 
 def build_train_step(pt, cfg: dict, shift_labels: bool):
-    """A training leg exactly as bench.py's ``_lm_leg_runner`` builds it:
-    AdamW under bf16 O2 (fp32 master weights) through the donated
-    TrainStep."""
+    """A training step as a user builds it: AdamW under bf16 O2 (fp32
+    master weights) through the donated TrainStep."""
     from paddle_tpu.jit import TrainStep
     from paddle_tpu.models import TransformerLM, TransformerLMCriterion
 
@@ -838,7 +837,7 @@ def _flash_step(pt, jax, sz: dict, platform: str) -> None:
     check(diff <= TOL_TPU,     # bf16 inputs on either backend
           "flash output differs from the reference by %.3g" % diff)
 
-    # the model step of that configuration (bench_longseq_flash)
+    # the model step of that configuration
     _, _, step = build_train_step(pt, cfg, shift_labels=True)
     ids = jax.device_put(rng.randint(0, cfg["vocab_size"],
                                      (1, seq)).astype("int32"))

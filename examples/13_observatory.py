@@ -17,10 +17,11 @@ HARDWARE was asked to do and whether the engine KEPT ITS PROMISES:
    ``GET /slo`` / ``health()`` carry the state throughout;
 3. **structured logs** (``serving/log.py``): one JSON line per
    admission / terminal / recovery / shed / SLO flip, correlated with
-   trace tick numbers — a no-op when unconfigured;
-4. **bench regression reporting** (``tools/bench_report.py``): the
-   perf history diffed and gated (run separately:
-   ``python -m tools.bench_report --check``).
+   trace tick numbers — a no-op when unconfigured.
+
+What a deployment's speed IS comes from none of these: that is the
+benchmark's (``python3 benchmark/run.py --workload <cell> --seed <n>
+--seconds 45 --trace <0|1>``, on a TPU; cells in ``BENCHMARK.json``).
 
 Run: python examples/13_observatory.py [--tokens 8]
 """

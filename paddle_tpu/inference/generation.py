@@ -211,8 +211,8 @@ def kv_reachable_bytes(tokens, max_len: int, num_layers: int,
     softmax — the cap keeps the "paged <= dense below full occupancy"
     contract even for block sizes that do not divide max_len).  This is
     the quantity the ROADMAP item names — cache HBM scaling with actual
-    tokens, not max_len × slots — and what bench.py's decode leg
-    records per layout.
+    tokens, not max_len × slots — and what ``cache_stats()`` reports
+    per layout.
 
     ``dtype="int8"`` (the quantized cache) counts the TRUE bytes: int8
     K/V plus the per-head fp32 scales that ride alongside (4 bytes per
@@ -624,7 +624,7 @@ class GenerationPool:
         # prefix index: chain-hash key -> resident full block (entries
         # removed the moment their block's refcount hits 0), plus the
         # reverse map used for that removal.  Hit accounting is
-        # cumulative (the serving gauges and bench legs read it)
+        # cumulative (the serving gauges read it)
         self._prefix_index: Dict[int, _PrefixEntry] = {}
         self._block_keys: Dict[int, int] = {}
         # head-of-queue match memo: a blocked FIFO head would otherwise
@@ -1871,7 +1871,7 @@ class GenerationPool:
 
     def spill_stats(self) -> dict:
         """Host-side spill-tier accounting — what the serving gauges
-        (``serving_spilled_*``) and the overload bench leg stamp.
+        (``serving_spilled_*``) read.
         ``spilled_blocks_device`` counts reclaimable device-resident
         spilled copies (part of the exact free/resident/spilled/scratch
         partition of ``num_blocks``); ``spilled_blocks_host`` counts
@@ -2300,10 +2300,10 @@ class GenerationPool:
         return sum(r - 1 for r in self._block_refs.values() if r > 1)
 
     def reset_prefix_stats(self) -> None:
-        """Zero the cumulative hit/query/chunk counters — bench legs
-        and sweeps call this between warmup and the timed region so the
-        stamped hit rate covers exactly the measured traffic (the warm
-        request is an admission query that can never hit)."""
+        """Zero the cumulative hit/query/chunk counters: a caller that
+        warms the pool calls this so the hit rate covers only the
+        traffic after it (the warm request is an admission query that
+        can never hit)."""
         self._prefix_queries = self._prefix_hits = 0
         self._prefix_tokens_matched = self._prefix_blocks_matched = 0
         self._chunks_total = self._chunk_tokens_total = 0
@@ -2312,7 +2312,7 @@ class GenerationPool:
         """Host-side prefix-sharing / chunked-prefill accounting: the
         quantities the serving gauges (``serving_prefix_hit_rate``,
         ``serving_prefix_blocks_shared``,
-        ``serving_prefill_chunks_total``) and the bench leg stamp.
+        ``serving_prefill_chunks_total``) read.
         Queries/hits are cumulative over admissions;
         ``blocks_shared_now`` is the live count of references beyond
         each block's first owner (HBM being saved right now)."""
@@ -3409,8 +3409,7 @@ class GenerationPool:
         bytes-accessed divided over the ``slots`` tokens it commits —
         the per-token cost model the serving gauges surface
         (``serving_step_flops`` / ``serving_step_bytes_accessed`` /
-        ``serving_hbm_reserved_bytes``) and bench legs stamp next to
-        their measured figures.  ``kv_cache_bytes`` (the decode
+        ``serving_hbm_reserved_bytes``).  ``kv_cache_bytes`` (the decode
         executable's cache-argument payload) reconciles exactly with
         ``cache_stats()['pool_bytes']`` for every layout x dtype
         (test-pinned)."""
@@ -3442,7 +3441,7 @@ class GenerationPool:
             # == the state pytree, independent of sequence length (the
             # model-class argument, quantified).  state_bytes_per_slot
             # is the capacity planner's figure: slots/GB falls out as
-            # 2**30 // it (the bench leg's slots_per_gb stamp).
+            # 2**30 // it.
             per_slot = self._state_bytes_slot
             state_total = per_slot * self.slots
             stats = {
@@ -3492,7 +3491,7 @@ class GenerationPool:
                  # the decode-attention route (§5l) is provenance the
                  # same way layout/dtype are: a tok/s or byte figure
                  # from the fused kernel must never be presented as a
-                 # composition number (bench legs stamp this)
+                 # composition number
                  "decode_route": self._session.route,
                  # worst-case cache bytes one slot pins at max_len —
                  # comparable across model classes (the recurrent

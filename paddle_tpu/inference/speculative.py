@@ -32,8 +32,6 @@ slots verbatim; the engine only gains an ``acceptance_rate`` gauge.
 """
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -73,14 +71,10 @@ class SpeculativePool(GenerationPool):
     knobs; the draft keeps a dense fp32 slot cache (it is small by
     design — the paged/int8 machinery earns its complexity on the
     target's HBM bill, not the draft's).
-
-    ``time_split=True`` accumulates a wall-clock draft/verify split
-    (blocking on each phase — measurement mode for bench.py, not for
-    serving, where blocking would serialize the dispatch pipeline).
     """
 
     def __init__(self, model, draft_model, max_len: int, spec_k: int = 4,
-                 time_split: bool = False, **pool_kwargs):
+                 **pool_kwargs):
         temperature = pool_kwargs.pop("temperature", 0.0)
         if float(temperature) != 0.0:
             raise InvalidArgumentError(
@@ -184,9 +178,6 @@ class SpeculativePool(GenerationPool):
         self._drafted = 0
         self._accepted = 0
         self._rounds = 0
-        self._time_split = bool(time_split)
-        self._draft_time_s = 0.0
-        self._verify_time_s = 0.0
 
     # -- traced bodies ---------------------------------------------------
     def _draft_decode(self, param_vals, buf_vals, cache, toks, active):
@@ -417,7 +408,6 @@ class SpeculativePool(GenerationPool):
         in turn would pay two round trips a round over a thin
         transport."""
         k = self._spec_k_active
-        t0 = time.perf_counter() if self._time_split else 0.0
         d_toks = []
         tok = self._tok_dev
         for _ in range(k):
@@ -428,10 +418,6 @@ class SpeculativePool(GenerationPool):
         chunk = jnp.concatenate(
             [self._tok_dev[:, None]] + [x[:, None] for x in d_toks],
             axis=1)
-        if self._time_split:
-            jax.block_until_ready(chunk)
-            t1 = time.perf_counter()
-            self._draft_time_s += t1 - t0
         # the pending vector (each row's last emitted token) is next
         # round's draft input, fed straight back on-device: it stands
         # while every slot commits its full round, and a slot that
@@ -440,9 +426,6 @@ class SpeculativePool(GenerationPool):
         self._cache, emitted_dev, m_dev, self._tok_dev = self._verify_jit(
             params, bufs, self._cache, chunk, self._active_dev,
             self._adapter_dev)
-        if self._time_split:
-            jax.block_until_ready(m_dev)
-            self._verify_time_s += time.perf_counter() - t1
         # catch-up + rewind for the draft cache (one dispatch; d_K is
         # the catch-up token, rows that rewind ignore its write; the
         # round's k rides as traced data)
@@ -487,23 +470,17 @@ class SpeculativePool(GenerationPool):
 
     def acceptance_stats(self) -> dict:
         """{'spec_k', 'rounds', 'drafted', 'accepted',
-        'acceptance_rate'} (+ the wall-clock ``draft_time_s`` /
-        ``verify_time_s`` split when ``time_split=True``) — the
-        measured quantities the serving gauge and the bench leg stamp."""
+        'acceptance_rate', 'spec_k_active'} — the rate is what the
+        ``serving_acceptance_rate`` gauge reads."""
         stats = acceptance_summary(self.spec_k, self._rounds,
                                    self._drafted, self._accepted)
         stats["spec_k_active"] = self._spec_k_active
-        if self._time_split:
-            stats["draft_time_s"] = self._draft_time_s
-            stats["verify_time_s"] = self._verify_time_s
         return stats
 
     def reset_acceptance_stats(self) -> None:
-        """Zero the acceptance/time accounting — bench legs call this
-        between warmup and the timed region so the stamped rate covers
-        exactly what was measured."""
+        """Zero the acceptance accounting: a caller that warms the pool
+        calls this before the traffic it wants the rate to cover."""
         self._drafted = self._accepted = self._rounds = 0
-        self._draft_time_s = self._verify_time_s = 0.0
 
     def compile_counts(self) -> dict:
         """Base pool accounting plus the speculative executables: the

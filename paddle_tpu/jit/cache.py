@@ -541,7 +541,7 @@ _LATENT = LatentLayout()
 def get_layout(name: str) -> CacheLayout:
     """The registered :class:`CacheLayout` singleton for ``name``; a
     typed error naming the registry otherwise — the single validation
-    every cache consumer (session, pool, sweep, bench) routes through."""
+    every cache consumer (session, pool) routes through."""
     layout = CACHE_LAYOUTS.get(name)
     if layout is None:
         raise InvalidArgumentError(

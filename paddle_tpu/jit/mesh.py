@@ -66,8 +66,8 @@ class DecodeMesh:
     conftest does this), so dp=2 / mp=2 / dp×mp meshes are exercisable
     without an accelerator.
 
-    ``DecodeMesh(1, 1)`` is a valid single-device mesh (the bench leg's
-    scaling baseline); ``mesh=None`` on the pool/session side is the
+    ``DecodeMesh(1, 1)`` is a valid single-device mesh (the
+    baseline a dp/mp mesh is compared with); ``mesh=None`` on the pool/session side is the
     fully-unsharded legacy path — the two are numerically identical but
     compile different (mesh-annotated) executables.
     """
@@ -230,7 +230,7 @@ class DecodeMesh:
         return sharded
 
     def describe(self) -> dict:
-        """JSON-safe mesh description (cache_stats / bench stamps)."""
+        """JSON-safe mesh description (``cache_stats()["mesh"]``)."""
         return {"dp": self.dp, "mp": self.mp, "devices": self.devices_n,
                 "collective_quant": self.collective_quant,
                 "collective_quant_scale": self.collective_quant_scale}

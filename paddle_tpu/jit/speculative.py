@@ -113,8 +113,8 @@ def acceptance_summary(spec_k: int, rounds: int, drafted: int,
                        accepted: int) -> dict:
     """The shared ``acceptance_stats()`` record: {'spec_k', 'rounds',
     'drafted', 'accepted', 'acceptance_rate'} — accepted draft tokens /
-    drafted, the measured quantity the bench leg and serving gauge
-    stamp (0.0 before any round)."""
+    drafted, what the ``serving_acceptance_rate`` gauge reads (0.0
+    before any round)."""
     return {
         "spec_k": spec_k,
         "rounds": rounds,
@@ -322,8 +322,7 @@ class SpeculativeDecodeSession:
         return out
 
     def acceptance_stats(self) -> dict:
-        """The shared :func:`acceptance_summary` record — the measured
-        quantity the bench leg stamps."""
+        """The shared :func:`acceptance_summary` record."""
         return acceptance_summary(self.spec_k, self._rounds,
                                   self._drafted, self._accepted)
 

@@ -234,7 +234,7 @@ def unload_adapter(model, idx: int) -> None:
 def random_adapter(model, seed: int, scale: float = 0.02) \
         -> Dict[str, tuple]:
     """A deterministic random adapter weight dict for the attached bank
-    (tests/bench/examples) — keyed like :func:`load_adapter` expects."""
+    (tests and examples) — keyed like :func:`load_adapter` expects."""
     cfg = lora_config(model)
     if cfg is None:
         raise InvalidArgumentError(
@@ -254,8 +254,8 @@ def random_adapter(model, seed: int, scale: float = 0.02) \
 
 def adapter_bank_bytes(model) -> int:
     """Total HBM bytes of the attached adapter bank (all rows, both
-    factors) — the weight-memory delta the ``serving_lora`` bench leg
-    stamps against N dedicated engines' full weight copies."""
+    factors) — the weight memory N fine-tunes cost beside N dedicated
+    engines' full weight copies."""
     total = 0
     for _, lin in lora_linears(model):
         for pname in ("lora_a", "lora_b"):

@@ -195,9 +195,9 @@ def dequantize_kv(q, scale, dtype=jnp.float32):
 # batched GEMV + softmax + GEMV that XLA fuses into one HBM pass over the
 # cache, and below this cache length no measurement has shown the fused
 # pallas decode kernel (ops/pallas_decode.py) beating it.  Above it the
-# "auto" route engages the kernel on TPU; ``tools/decode_sweep.py
-# --route`` measures both paths so this constant is replaceable by a
-# sweep, not a guess (the same way FLASH_MIN_SEQ was established).
+# "auto" route engages the kernel on TPU; ``tools/paged_kernel_bench.py
+# --composition`` times the paged kernel beside the composition on the
+# chip, which is the measurement that may move this constant.
 DECODE_FLASH_MIN_CACHE = 16384
 
 # -- decode routing ----------------------------------------------------

@@ -5,7 +5,7 @@ Reference parity: ``python/paddle/fluid/profiler.py`` —
 with the sorted-summary table the reference prints from its C++ event
 tracer.  TPU-native additions: ``xla_trace`` wraps ``jax.profiler``
 (TensorBoard-consumable device traces — the nvprof analog), and ``StepTimer``
-computes step time + MFU (BASELINE.md's metric) the way bench.py reports it.
+computes step time + MFU (BASELINE.md's metric) against ``DEVICE_PEAKS``.
 
 Consumes ``FLAGS_benchmark``: while profiling (or when the flag is set) each
 dispatched op is timed host-side with a block-until-ready, trading pipelining
@@ -137,8 +137,8 @@ class StepTimer:
 
 
 # THE peaks table: published per-chip peaks keyed by jax's
-# ``device_kind``.  bench.py's MFU and StepTimer.mfu both divide by it,
-# and a device that is not listed is an error, never a default — a
+# ``device_kind``.  ``StepTimer.mfu``, ``chip_smoke.py`` and the expert
+# route's cost rule (``nn/functional/moe.py``) all read it, and a device that is not listed is an error, never a default — a
 # utilisation against an assumed peak is not a measurement.
 # Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s in bf16,
 # 16 GB of HBM at 819 GB/s.

@@ -93,8 +93,8 @@ new compiles — torn tails truncate (never crash), fingerprint
 mismatches raise typed errors naming both sides, and the RESTORING
 state answers ``/healthz`` 503 + Retry-After while deferring (never
 dropping) admissions.  ``journal.append``/``spill.write`` are fault
-seams, and the ``serving_restart`` bench leg stamps the measured RTO
-with ``tokens_lost == 0`` required for promotion.
+seams; ``health()["last_restore_s"]`` is the restore's wall time
+(``tests/test_durable_serving.py``).
 
 Disaggregated serving (docs/DESIGN.md §5n): ``transfer`` is the
 versioned K/V hand-off contract — a magic+version+fingerprint-headered,

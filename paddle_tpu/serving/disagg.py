@@ -22,10 +22,10 @@ a hand-off can never change tokens, only where they are computed.
 
 The front is deliberately pump-mode only: one thread drives
 ``pump()`` → prefill tick → bridge → decode tick → bridge, which keeps
-every test deterministic and matches how the bench leg measures it.
+every test deterministic.
 Front-observed ``serving_ttft_seconds`` / ``serving_inter_token_seconds``
-include the hand-off wait — end-to-end honest, what the ``serving_disagg``
-bench leg reads — while each tier's own registry keeps its local view.
+include the hand-off wait (end to end, what a client feels), while each
+tier's own registry keeps its local view.
 """
 from __future__ import annotations
 

@@ -15,8 +15,8 @@ Design points (docs/DESIGN.md §5c):
 
 - **One tick, two drive modes.** A scheduling tick = deadline sweep +
   one batched ``pool.step()`` + gauge refresh.  ``pump(n)`` runs ticks
-  inline (single-threaded, deterministic — what every tier-1 test and
-  the bench leg use); ``start()`` runs the SAME ``_tick`` in an owned
+  inline (single-threaded, deterministic — what every tier-1 test
+  uses); ``start()`` runs the SAME ``_tick`` in an owned
   background thread for real serving.  The modes share one code path,
   so they cannot diverge.
 - **Fail-fast admission.** The wait queue is bounded (``max_queue``);
@@ -2593,7 +2593,7 @@ class ServingEngine:
         if self._g_spilled_blocks is not None:
             self._g_spilled_blocks.set(stats["spilled_blocks"])
 
-    # -- drive mode 1: synchronous pump (deterministic, test/bench) ------
+    # -- drive mode 1: synchronous pump (deterministic, tests) -----------
     def pump(self, steps: int = 1) -> bool:
         """Run up to ``steps`` scheduling ticks INLINE on the calling
         thread; True while live requests remain.  The deterministic
@@ -3010,7 +3010,7 @@ class ServingEngine:
         """Prefix-sharing / chunked-prefill accounting
         (``GenerationPool.prefix_stats``): hit rate, matched tokens /
         blocks, live shared blocks, chunk totals — what the
-        ``serving_prefix_*`` gauges and the bench leg stamp."""
+        ``serving_prefix_*`` gauges read."""
         return self._pool.prefix_stats()
 
     def resident_prefix_digest(self, since_epoch=None):
@@ -3024,9 +3024,9 @@ class ServingEngine:
             return self._pool.prefix_digest(since_epoch)
 
     def reset_prefix_stats(self) -> None:
-        """Zero the pool's cumulative prefix/chunk counters — bench
-        legs call this between warmup and the timed region so the
-        stamped hit rate covers exactly the measured traffic."""
+        """Zero the pool's cumulative prefix/chunk counters: a caller
+        that warms the engine calls this so the hit rate covers only
+        the traffic after it."""
         with self._lock:
             self._pool.reset_prefix_stats()
             # the chunk-counter watermark must restart with the pool's
@@ -3039,7 +3039,7 @@ class ServingEngine:
         (``GenerationPool.spill_stats``): preempt/resume totals, parked
         requests, device-resident spilled blocks vs host-only copies,
         spill/upload byte totals — what the ``serving_spilled_*``
-        gauges and the overload bench leg stamp."""
+        gauges read."""
         return self._pool.spill_stats()
 
     def acceptance_stats(self) -> Optional[dict]:

@@ -257,9 +257,8 @@ class MetricsRegistry:
         to 0, histogram counts cleared — keeping the registrations,
         bucket layouts, and metric object identities (the engine holds
         direct references).  Test isolation for suites sharing one
-        registry/engine, and the warm-outside-the-timed-region
-        discipline bench legs apply per-histogram, available for a whole
-        registry at once."""
+        registry/engine, and for a caller that warms an engine before
+        the traffic its histograms should cover."""
         for m in self._metrics.values():
             if isinstance(m, Histogram):
                 m.reset()

@@ -93,9 +93,7 @@ class EngineHealth:
         """A journal restore completed on this engine (docs §5m): the
         count and the last restore's wall time ride every health
         snapshot, so a probe can tell "slow because it just adopted a
-        journal" from "slow, period" — the RTO figure the
-        serving_restart bench leg stamps is this same quantity measured
-        end-to-end."""
+        journal" from "slow, period"."""
         self.restores += 1
         self.last_restore_s = duration_s
 

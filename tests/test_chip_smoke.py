@@ -7,7 +7,8 @@ a result line that says ``"platform": "cpu"`` — and each failure the
 script promises is shown: no TPU, a request that is not ``DONE``, a
 recovery the engine absorbed, a forced kernel missing from the compiled
 decode program, a phase that raises.  The compile-cache helper and the
-peaks table it shares with ``bench.py`` are unit-tested beside it.
+peaks table (``profiler.DEVICE_PEAKS``, held equal to the benchmark's
+own) are unit-tested beside it.
 """
 import json
 import os
@@ -21,6 +22,7 @@ import chip_smoke
 from tools import compile_cache
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(_REPO, "benchmark"))  # harness.device
 
 
 def _last_json(text: str) -> dict:
@@ -154,9 +156,6 @@ def test_compile_cache_helper(monkeypatch, env_dir):
 
 
 def test_peaks_table_is_shared_and_raises_for_unknown_devices():
-    import jax
-
-    import bench
     from paddle_tpu.core.errors import NotFoundError
     from paddle_tpu.profiler import (DEVICE_PEAKS, StepTimer,
                                      device_peak_flops, device_peaks)
@@ -168,17 +167,32 @@ def test_peaks_table_is_shared_and_raises_for_unknown_devices():
         device_peaks("TPU v9 imaginary")
     with pytest.raises(NotFoundError, match="'cpu'"):
         device_peak_flops()         # this machine's device is not listed
-    # bench reads the same table on the chip and has no peak off it
-    with pytest.raises(NotFoundError):
-        bench._peak_flops(jax, True)
-    assert bench._peak_flops(jax, False) is None
-    assert bench._mfu(1e12, None) is None
     # timing steps needs no peak; asking for MFU on this device raises
     t = StepTimer(flops_per_step=1e9)
     with t:
         pass
     with pytest.raises(NotFoundError):
         t.mfu
+
+
+def test_program_peaks_equal_the_benchmarks_own_row():
+    # two tables state the chip's peaks: the program's and the one the
+    # benchmark keeps for its rooflines.  An edit of either fails here.
+    from harness import device
+    from paddle_tpu.profiler import DEVICE_PEAKS
+
+    assert sorted(DEVICE_PEAKS) == sorted(device.PEAKS)
+    ours, theirs = DEVICE_PEAKS["TPU v5 lite"], device.peaks("TPU v5 lite")
+    assert ours["bf16_flops"] == theirs["bf16_flops_per_s"] == 197e12
+    assert ours["hbm_bytes_per_sec"] == theirs["hbm_bytes_per_s"] == 819e9
+
+
+def test_expert_route_cost_rule_streams_at_the_tables_hbm_rate():
+    from paddle_tpu.nn.functional import moe
+    from paddle_tpu.profiler import DEVICE_PEAKS
+
+    assert moe._HBM_BYTES_PER_S \
+        == DEVICE_PEAKS["TPU v5 lite"]["hbm_bytes_per_sec"]
 
 
 def test_a_pool_shaped_copy_in_the_decode_program_fails_on_the_tpu():

@@ -53,40 +53,40 @@ def test_parse_generate_request_valid():
         b'{"prompt": [7], "max_new_tokens": 1, "priority": -3}')[4] == -3
 
 
-def test_parse_generate_request_malformed():
-    for body, why in ((b"not json", "JSON"),
-                      (b'[1, 2]', "object"),
-                      (b'{"max_new_tokens": 3}', "prompt"),
-                      (b'{"prompt": [], "max_new_tokens": 3}', "prompt"),
-                      (b'{"prompt": "abc", "max_new_tokens": 3}',
-                       "prompt"),
-                      (b'{"prompt": [1, true], "max_new_tokens": 3}',
-                       "prompt"),
-                      (b'{"prompt": [1]}', "max_new_tokens"),
-                      (b'{"prompt": [1], "max_new_tokens": 0}',
-                       "max_new_tokens"),
-                      (b'{"prompt": [1], "max_new_tokens": 2.5}',
-                       "max_new_tokens"),
-                      (b'{"prompt": [1], "max_new_tokens": 2, '
-                       b'"deadline_s": "soon"}', "deadline_s"),
-                      (b'{"prompt": [1], "max_new_tokens": 2, '
-                       b'"deadline_s": true}', "deadline_s"),
-                      (b'{"prompt": [34359738368], '
-                       b'"max_new_tokens": 2}', "int32"),
-                      (b'{"prompt": [1], "max_new_tokens": 2, '
-                       b'"request_id": {"a": 1}}', "request_id"),
-                      (b'{"prompt": [1], "max_new_tokens": 2, '
-                       b'"request_id": [1]}', "request_id"),
-                      (b'{"prompt": [1], "max_new_tokens": 2, '
-                       b'"priority": "urgent"}', "priority"),
-                      (b'{"prompt": [1], "max_new_tokens": 2, '
-                       b'"priority": true}', "priority"),
-                      (b'{"prompt": [1], "max_new_tokens": 2, '
-                       b'"priority": 1.5}', "priority"),
-                      (b'{"prompt": [1], "max_new_tokens": 2, '
-                       b'"tenant": 7}', "tenant")):
-        with pytest.raises(InvalidArgumentError, match=why):
-            parse_generate_request(body)
+_OK = b'{"prompt": [1], "max_new_tokens": 2, '
+MALFORMED = {
+    "not_json": (b"not json", "JSON"),
+    "not_an_object": (b'[1, 2]', "object"),
+    "no_prompt": (b'{"max_new_tokens": 3}', "prompt"),
+    "empty_prompt": (b'{"prompt": [], "max_new_tokens": 3}', "prompt"),
+    "prompt_a_string": (b'{"prompt": "abc", "max_new_tokens": 3}',
+                        "prompt"),
+    "prompt_holds_a_bool": (b'{"prompt": [1, true], "max_new_tokens": 3}',
+                            "prompt"),
+    "no_max_new_tokens": (b'{"prompt": [1]}', "max_new_tokens"),
+    "max_new_tokens_zero": (b'{"prompt": [1], "max_new_tokens": 0}',
+                            "max_new_tokens"),
+    "max_new_tokens_a_float": (b'{"prompt": [1], "max_new_tokens": 2.5}',
+                               "max_new_tokens"),
+    "deadline_a_string": (_OK + b'"deadline_s": "soon"}', "deadline_s"),
+    "deadline_a_bool": (_OK + b'"deadline_s": true}', "deadline_s"),
+    "token_past_int32": (b'{"prompt": [34359738368], "max_new_tokens": 2}',
+                         "int32"),
+    "request_id_an_object": (_OK + b'"request_id": {"a": 1}}',
+                             "request_id"),
+    "request_id_a_list": (_OK + b'"request_id": [1]}', "request_id"),
+    "priority_unknown": (_OK + b'"priority": "urgent"}', "priority"),
+    "priority_a_bool": (_OK + b'"priority": true}', "priority"),
+    "priority_a_float": (_OK + b'"priority": 1.5}', "priority"),
+    "tenant_a_number": (_OK + b'"tenant": 7}', "tenant"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MALFORMED))
+def test_parse_generate_request_malformed(case):
+    body, why = MALFORMED[case]
+    with pytest.raises(InvalidArgumentError, match=why):
+        parse_generate_request(body)
 
 
 # -- the handler against an in-memory socket (single-threaded) -----------

@@ -1,7 +1,8 @@
 """Fused-op tests (pallas kernels + their gates/fallbacks).
 
 The pallas kernel itself needs a real TPU; CPU CI exercises the gate and the
-XLA fallback, and bench.py exercises the kernel on hardware.
+XLA fallback, and ``chip_smoke.py --phases kernels`` runs the kernel on
+the chip.
 """
 import numpy as np
 import pytest

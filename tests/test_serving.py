@@ -380,8 +380,8 @@ def test_metrics_registry_typing_and_quantile():
     assert h.quantile(1.0) == float("inf")
     snap = h.snapshot()
     assert snap["count"] == 5 and snap["buckets"]["+Inf"] == 5
-    # reset() zeros counts but keeps the bucket layout (bench.py uses it
-    # to drop warmup-compile gaps from the serving ITL quantiles)
+    # reset() zeros counts but keeps the bucket layout (it drops a
+    # warm-up's compile gaps from the serving ITL quantiles)
     h.reset()
     assert h.quantile(0.5) is None and h.count == 0 and h.sum == 0.0
     h.observe(0.5)

@@ -364,36 +364,3 @@ def test_engine_speculative_deadline_expiry_frees_slot(target, draft):
     assert st.state == RequestState.EXPIRED
     assert 0 < st.new_tokens < 40
     assert eng.cache_stats()["free_blocks"] == baseline
-
-
-# -- the sweep axis (sweep-sized: slow-marked per the tier-1 budget) ------
-
-@pytest.mark.slow
-def test_decode_sweep_speculate_axis(tmp_path):
-    import json
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = tmp_path / "sweep.json"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "decode_sweep.py"),
-         "--cpu-smoke", "--batches", "1", "--buckets", "16", "--gen",
-         "8", "--block-sizes", "8", "--cache-dtypes", "float32",
-         "--speculate", "2", "--out", str(out)],
-        capture_output=True, text=True, timeout=600,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=repo)
-    assert proc.returncode == 0, (proc.stdout[-1500:],
-                                  proc.stderr[-1500:])
-    report = json.loads(out.read_text())
-    assert report["spec_k"] == 2
-    legs = report["speculative_legs"]
-    assert legs, "speculative axis wrote no rows"
-    for leg in legs:
-        # the satellite contract: every speculative row carries BOTH
-        # the tok/s and the measured acceptance-rate column
-        assert leg["decode_tokens_per_sec"] > 0
-        assert 0.0 <= leg["acceptance_rate"] <= 1.0
-        assert leg["plain_tokens_per_sec"] > 0
-        assert leg["spec_k"] == 2

@@ -25,24 +25,36 @@ The contracts pinned here:
 6. the serving engine's recovery invariants (chaos drain, byte-identity,
    counter reconciliation, zero recompiles) and the SIGKILL journal
    restore hold for the recurrent pool exactly as for paged.
+
+What is not about ``SSMLM``'s own equations runs over the deployed
+recurrences too (the ``served`` fixture): ``PowerRetentionLM``, whose
+state is a matrix a head, and ``HybridMambaLM``, whose cache holds two
+kinds of entry and goes through no file.
 """
+import io
 import json
 import os
 import signal
 import subprocess
 import sys
+from typing import NamedTuple
 
 import numpy as np
 import pytest
+import test_hybrid_mamba as hybrid_toy
+import test_power_retention as retention_toy
 
 import paddle_tpu as pt
-from paddle_tpu.core.errors import InvalidArgumentError
+from paddle_tpu.core.errors import (InvalidArgumentError,
+                                    PreconditionNotMetError)
 from paddle_tpu.inference import GenerationPool, SpeculativePool
 from paddle_tpu.jit.cache import CACHE_LAYOUTS, get_layout
 from paddle_tpu.jit.decode import DecodeSession
 from paddle_tpu.jit.mesh import DecodeMesh
-from paddle_tpu.models import TransformerLM
+from paddle_tpu.models import (HybridMambaLM, PowerRetentionLM,
+                               TransformerLM)
 from paddle_tpu.nn import SSMLM
+from paddle_tpu.ops import power_retention
 from paddle_tpu.serving import RequestState, ServingEngine, faults
 from paddle_tpu.serving import log as slog
 from paddle_tpu.serving.faults import FaultPlane
@@ -68,9 +80,61 @@ def model():
     return _ssm()
 
 
-def _prompts(seed, lens):
+class Served(NamedTuple):
+    """A model with what a pool or an engine over it is given and reads."""
+    model: object
+    kw: dict            # the cache arguments of its pool
+    vocab: int
+    layout: str         # what ``pool.cache_layout`` reads
+    state_bytes: int    # one slot's recurrent state
+    d_state: int        # the fingerprint's: the first state field's width
+    through_a_file: bool
+
+
+def _retention():
+    cfg = retention_toy.CFG
+    pt.seed(0)
+    m = PowerRetentionLM(**retention_toy.rw.model_kwargs(cfg))
+    m.eval()
+    retention_toy.rw.load_into(m, cfg, retention_toy.SEED)
+    per_layer = power_retention.state_bytes(
+        cfg["num_key_value_heads"], cfg["head_dim"], cfg["head_dim"])
+    return Served(m, dict(cache_layout="recurrent"), cfg["vocab_size"],
+                  "recurrent", cfg["num_layers"] * per_layer,
+                  power_retention.phi_size(cfg["head_dim"]), True)
+
+
+def _hybrid():
+    cfg = hybrid_toy.CFG
+    pt.seed(0)
+    m = HybridMambaLM(**hybrid_toy.mw.model_kwargs(cfg))
+    m.eval()
+    hybrid_toy.mw.load_into(m, cfg, hybrid_toy.SEED)
+    mamba_layers = m.attention_layers.count(False)
+    inner = cfg["mamba_expand"] * cfg["hidden_size"]
+    return Served(
+        m, dict(cache_layout="paged", block_size=8), cfg["vocab_size"],
+        "paged+recurrent",
+        mamba_layers * (hybrid_toy.SSM_BYTES + hybrid_toy.CONV_BYTES),
+        (cfg["mamba_d_conv"] - 1) * inner, False)
+
+
+_SERVED = {
+    "ssm": lambda: Served(_ssm(), dict(cache_layout="recurrent"), 128,
+                          "recurrent", 2 * 48 * 4, 48, True),
+    "retention": _retention,
+    "hybrid": _hybrid,
+}
+
+
+@pytest.fixture(scope="module", params=sorted(_SERVED))
+def served(request):
+    return _SERVED[request.param]()
+
+
+def _prompts(seed, lens, vocab=128):
     rng = np.random.RandomState(seed)
-    return [rng.randint(0, 128, (n,)).astype("int32") for n in lens]
+    return [rng.randint(0, vocab, (n,)).astype("int32") for n in lens]
 
 
 def _eager_cached(model, ids, n):
@@ -124,13 +188,13 @@ def test_exactly_two_compiles(model):
     assert sess.compile_counts() == {"prefill": 1, "decode": 1}
 
 
-def test_pool_matches_session_and_compile_pin(model):
-    p = _prompts(3, (5, 9, 7))
-    sess = DecodeSession(model, max_len=64, buckets=[32],
-                         cache_layout="recurrent")
+def test_pool_matches_session_and_compile_pin(served):
+    p = _prompts(3, (5, 9, 7), served.vocab)
+    sess = DecodeSession(served.model, max_len=64, buckets=[32],
+                         **served.kw)
     want = [np.ravel(sess.generate(ids[None], 8)) for ids in p]
-    pool = GenerationPool(model, max_len=64, slots=2, buckets=[32],
-                          cache_layout="recurrent")
+    pool = GenerationPool(served.model, max_len=64, slots=2, buckets=[32],
+                          **served.kw)
     got = pool.generate(p, 8)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
@@ -183,12 +247,40 @@ def test_preempt_spill_resume_byte_identity(model, tier, tmp_path):
         == info["state_bytes"]
 
 
-def test_detach_and_adopt_cross_engine(model, tmp_path):
-    p = _prompts(3, (5, 9, 7))
+def _a_cache_of_two_kinds_goes_through_no_file(served, p, tmp_path):
+    """The disk tier is refused by name; a victim parked in memory has no
+    file to hand over, and a peer adopts nothing: the caller resubmits."""
+    def mk(**kw):
+        return GenerationPool(served.model, max_len=64, slots=2,
+                              buckets=[32], **served.kw, **kw)
+
+    with pytest.raises(InvalidArgumentError,
+                       match="spill_tier='disk' writes one kind of cache "
+                             "entry.*%s.*has both.*keep spill_tier='host'"
+                             % served.layout.replace("+", "\\+")):
+        mk(spill_tier="disk", spill_dir=str(tmp_path))
+    a = mk()
+    for i, ids in enumerate(p):
+        a.submit(ids, 8, request_id="r%d" % i)
+    a.step()
+    a.step()
+    a.preempt("r0")
+    committed = list(a._spilled["r0"].tokens)
+    with pytest.raises(PreconditionNotMetError, match="host tier"):
+        a.detach_spilled("r0")
+    assert not mk().adopt_spill("r0", p[0], committed, 8)
+    assert not os.listdir(str(tmp_path))
+
+
+def test_detach_and_adopt_cross_engine(served, tmp_path):
+    p = _prompts(3, (5, 9, 7), served.vocab)
+    if not served.through_a_file:
+        return _a_cache_of_two_kinds_goes_through_no_file(served, p,
+                                                          tmp_path)
 
     def mk():
-        return GenerationPool(model, max_len=64, slots=2, buckets=[32],
-                              cache_layout="recurrent",
+        return GenerationPool(served.model, max_len=64, slots=2,
+                              buckets=[32], **served.kw,
                               spill_tier="disk", spill_dir=str(tmp_path))
 
     ref = mk()
@@ -204,7 +296,7 @@ def test_detach_and_adopt_cross_engine(model, tmp_path):
     a.preempt("r0")
     committed = list(a._spilled["r0"].tokens)
     handoff = a.detach_spilled("r0")
-    assert handoff["spill_bytes"] == 2 * 48 * 4
+    assert handoff["spill_bytes"] == served.state_bytes
 
     b = mk()
     assert b.adopt_spill("r0", p[0], committed, 8)
@@ -214,75 +306,77 @@ def test_detach_and_adopt_cross_engine(model, tmp_path):
     for rid in want:
         np.testing.assert_array_equal(got[rid], want[rid])
     # the adopted victim resumed via the carry upload, not a re-prefill
-    assert b.spill_stats()["upload_bytes_total"] == 2 * 48 * 4
+    assert b.spill_stats()["upload_bytes_total"] == served.state_bytes
 
 
-def test_cross_model_class_spill_rejected(model, tmp_path):
+def _parked(pool, p):
+    """Park request "v" after three steps; its committed tokens."""
+    pool.submit(p, 8, request_id="v")
+    for _ in range(3):
+        pool.step()
+    pool.preempt("v")
+    return list(pool._spilled["v"].tokens)
+
+
+def _try_adopt(pool, p, committed):
+    buf = io.StringIO()
+    with slog.logging_to(buf):
+        ok = pool.adopt_spill("v", p, committed, 8)
+    rej = [json.loads(l) for l in buf.getvalue().splitlines()
+           if json.loads(l)["event"] == "xfer.reject"]
+    return ok, rej
+
+
+def test_cross_model_class_spill_rejected(served, tmp_path):
     """A transformer engine must never adopt a recurrent engine's spill
     file (and vice versa): the fingerprint carries cache_layout (and
     d_state), so the stale-file triage is an ``xfer.reject`` with
     ``reason="fingerprint"`` — the file is another deployment's
-    property, left on disk, and the caller resubmits."""
+    property, left on disk, and the caller resubmits.  A pool whose
+    cache goes through no file looks at none."""
     spill = str(tmp_path)
-    tf = _transformer()
-    p = _prompts(4, (9,))[0]
+    p = _prompts(4, (9,), served.vocab)[0]
+    tier = dict(spill_tier="disk", spill_dir=spill) \
+        if served.through_a_file else {}
 
-    rec_pool = GenerationPool(model, max_len=64, slots=2, buckets=[32],
-                              cache_layout="recurrent",
-                              spill_tier="disk", spill_dir=spill)
-    rec_pool.submit(p, 8, request_id="v")
-    for _ in range(3):
-        rec_pool.step()
-    rec_pool.preempt("v")
-    committed = list(rec_pool._spilled["v"].tokens)
-    path = rec_pool._spilled["v"].host_path
-    assert path is not None and os.path.exists(path)
+    def own(slots=2, **kw):
+        return GenerationPool(served.model, max_len=64, slots=slots,
+                              buckets=[32], **served.kw, **kw)
 
-    def try_adopt(pool):
-        import io
-        buf = io.StringIO()
-        with slog.logging_to(buf):
-            ok = pool.adopt_spill("v", p, committed, 8)
-        rej = [json.loads(l) for l in buf.getvalue().splitlines()
-               if json.loads(l)["event"] == "xfer.reject"]
-        return ok, rej
+    paged = GenerationPool(_transformer(), max_len=64, slots=2,
+                           buckets=[32], cache_layout="paged",
+                           block_size=8, spill_tier="disk",
+                           spill_dir=spill)
+    if served.through_a_file:
+        rec_pool = own(**tier)
+        committed = _parked(rec_pool, p)
+        path = rec_pool._spilled["v"].host_path
+        assert path is not None and os.path.exists(path)
+        ok, rej = _try_adopt(paged, p, committed)
+        assert not ok
+        assert len(rej) == 1 and rej[0]["reason"] == "fingerprint"
+        assert "cache_layout" in rej[0]["keys"]
+        # not ours to judge: the recurrent engine's file stays on disk...
+        assert os.path.exists(path)
+        # ...and the OWNING pool still adopts it byte-identically
+        ref = own(slots=1)
+        ref.submit(p, 8, request_id="v")
+        want = ref.run()["v"]
+        fresh = own(**tier)
+        assert fresh.adopt_spill("v", p, committed, 8)
+        np.testing.assert_array_equal(fresh.run()["v"], want)
 
-    paged = GenerationPool(tf, max_len=64, slots=2, buckets=[32],
-                           cache_layout="paged", block_size=8,
-                           spill_tier="disk", spill_dir=spill)
-    ok, rej = try_adopt(paged)
-    assert not ok
-    assert len(rej) == 1 and rej[0]["reason"] == "fingerprint"
-    assert "cache_layout" in rej[0]["keys"]
-    # not ours to judge: the recurrent engine's file stays on disk...
-    assert os.path.exists(path)
-    # ...and the OWNING pool still adopts it byte-identically
-    ref = GenerationPool(model, max_len=64, slots=1, buckets=[32],
-                         cache_layout="recurrent")
-    ref.submit(p, 8, request_id="v")
-    want = ref.run()["v"]
-    fresh = GenerationPool(model, max_len=64, slots=2, buckets=[32],
-                           cache_layout="recurrent",
-                           spill_tier="disk", spill_dir=spill)
-    assert fresh.adopt_spill("v", p, committed, 8)
-    np.testing.assert_array_equal(fresh.run()["v"], want)
-
-    # the mirror direction: a paged spill rejected by a recurrent pool
-    paged.submit(p, 8, request_id="v")
-    for _ in range(3):
-        paged.step()
-    paged.preempt("v")
-    committed_tf = list(paged._spilled["v"].tokens)
-    assert paged.detach_spilled("v")["path"]
-    rec2 = GenerationPool(model, max_len=64, slots=2, buckets=[32],
-                          cache_layout="recurrent",
-                          spill_tier="disk", spill_dir=spill)
-    ok, rej = try_adopt(rec2)
-    # the committed counts may coincide; only the fingerprint matters
-    del committed_tf
-    assert not ok
-    assert len(rej) == 1 and rej[0]["reason"] == "fingerprint"
-    assert "cache_layout" in rej[0]["keys"]
+    # the mirror direction: a paged spill offered to the served pool (the
+    # committed counts may coincide; only the fingerprint matters)
+    committed = _parked(paged, p)
+    path = paged.detach_spilled("v")["path"]
+    ok, rej = _try_adopt(own(**tier), p, committed)
+    assert not ok and os.path.exists(path)
+    if served.through_a_file:
+        assert len(rej) == 1 and rej[0]["reason"] == "fingerprint"
+        assert "cache_layout" in rej[0]["keys"]
+    else:
+        assert rej == []        # no file was opened, so none was judged
 
 
 # -- typed construction errors -------------------------------------------
@@ -336,38 +430,47 @@ def test_model_layout_compatibility_is_checked(model):
 
 # -- accounting stamps ---------------------------------------------------
 
-def test_cache_stats_and_fingerprint_stamps(model):
-    pool = GenerationPool(model, max_len=64, slots=4, buckets=[32],
-                          cache_layout="recurrent")
+def test_cache_stats_and_fingerprint_stamps(served):
+    pool = GenerationPool(served.model, max_len=64, slots=4, buckets=[32],
+                          **served.kw)
     stats = pool.cache_stats()
-    assert stats["cache_layout"] == "recurrent"
+    assert stats["cache_layout"] == pool.cache_layout == served.layout
     assert stats["cache_dtype"] == "float32"
-    assert stats["d_state"] == 48
-    # the model-class claim, quantified: one slot's decode state is
-    # layers × d_state × 4 bytes, independent of max_len
-    assert stats["state_bytes_per_slot"] == 2 * 48 * 4
-    assert stats["reachable_bytes"] == stats["pool_bytes"] \
-        == 4 * stats["state_bytes_per_slot"]
+    # the model-class claim, quantified: one slot's recurrent state is a
+    # constant of the model's shapes (for ``SSMLM`` layers × d_state × 4
+    # bytes), independent of max_len, and with nothing mapped it is all
+    # a pool can reach
+    assert stats["bytes_per_slot"]["recurrent"] == served.state_bytes
+    assert stats["reachable_bytes"] == 4 * served.state_bytes
     fp = pool.config_fingerprint()
-    assert fp["cache_layout"] == "recurrent" and fp["d_state"] == 48
-    assert "block_size" not in fp
+    assert fp["cache_layout"] == served.layout
+    assert fp["d_state"] == served.d_state
+    if served.layout == "recurrent":
+        assert stats["d_state"] == served.d_state
+        assert stats["pool_bytes"] == stats["reachable_bytes"]
+        assert stats["state_bytes_per_slot"] == served.state_bytes
+        assert "block_size" not in fp
+    else:
+        assert stats["pool_bytes"] > stats["reachable_bytes"]
+        assert stats["state_bytes_per_slot"] > served.state_bytes
+        assert fp["block_size"] == served.kw["block_size"]
     # the positional layouts stamp the SAME per-slot key so capacity
-    # comparisons across model classes read one field
+    # comparisons across model classes read one field: a row's K and V
+    # at max_len, one layer of width 32 in float32
     paged = GenerationPool(_transformer(), max_len=64, slots=4,
                            buckets=[32], cache_layout="paged",
                            block_size=8)
-    pstats = paged.cache_stats()
-    assert pstats["state_bytes_per_slot"] > stats["state_bytes_per_slot"]
+    assert paged.cache_stats()["state_bytes_per_slot"] == 64 * 2 * 32 * 4
 
 
-def test_dp2_mesh_identity(model):
-    p = _prompts(6, (5, 9, 7, 4))
-    plain = GenerationPool(model, max_len=64, slots=2, buckets=[32],
-                           cache_layout="recurrent")
+def test_dp2_mesh_identity(served):
+    p = _prompts(6, (5, 9, 7, 4), served.vocab)
+    plain = GenerationPool(served.model, max_len=64, slots=2, buckets=[32],
+                           **served.kw)
     want = plain.generate(p, 6)
     mesh = DecodeMesh(dp=2, mp=1)
-    sharded = GenerationPool(model, max_len=64, slots=2, buckets=[32],
-                             cache_layout="recurrent", mesh=mesh)
+    sharded = GenerationPool(served.model, max_len=64, slots=2,
+                             buckets=[32], mesh=mesh, **served.kw)
     got = sharded.generate(p, 6)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
@@ -378,14 +481,13 @@ def test_dp2_mesh_identity(model):
 # -- serving-engine invariants under chaos -------------------------------
 
 @pytest.mark.parametrize("seed", [0, 1, 2, 3, 4])
-def test_chaos_invariants_hold_for_recurrent(model, seed):
-    rng = np.random.RandomState(seed)
-    lens, budgets = (5, 9, 7, 4), (6, 5, 7, 4)
-    prompts = [rng.randint(0, 128, (n,)).astype("int32") for n in lens]
+def test_chaos_invariants_hold_for_recurrent(served, seed):
+    budgets = (6, 5, 7, 4)
+    prompts = _prompts(seed, (5, 9, 7, 4), served.vocab)
 
     def mk():
-        return ServingEngine(model, max_len=64, slots=2, buckets=[32],
-                             cache_layout="recurrent", max_retries=8)
+        return ServingEngine(served.model, max_len=64, slots=2,
+                             buckets=[32], max_retries=8, **served.kw)
 
     def drive(eng):
         streams = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
@@ -413,8 +515,7 @@ def test_chaos_invariants_hold_for_recurrent(model, seed):
         assert st.state == RequestState.DONE, (seed, st.state, st.error)
         np.testing.assert_array_equal(st.tokens, w)
     assert eng.live_requests == 0 and eng.queue_depth == 0
-    stats = eng.cache_stats()
-    assert stats["cache_layout"] == "recurrent"
+    assert eng.cache_stats()["cache_layout"] == served.layout
     snap = eng.metrics.snapshot()
     assert snap["serving_requests_submitted_total"] == len(prompts)
     assert snap["serving_requests_completed_total"] == len(prompts)

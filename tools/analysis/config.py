@@ -5,10 +5,10 @@ counts as the decode hot path, which dynamic dispatch points exist) is
 reviewable in one place instead of buried in rule code.
 """
 
-# Trees the engine walks, relative to the repo root.  bench.py is a
-# single file; missing entries are skipped (fixture trees in tests pass
-# a bare tmp directory, which falls back to "every .py under root").
-WALK_ROOTS = ("paddle_tpu", "tools", "tests", "bench.py")
+# Trees the engine walks, relative to the repo root.  Missing entries
+# are skipped (fixture trees in tests pass a bare tmp directory, which
+# falls back to "every .py under root").
+WALK_ROOTS = ("paddle_tpu", "tools", "tests")
 
 # Directories never walked (caches, VCS).
 SKIP_DIRS = {".git", "__pycache__", ".jax_cache", ".pytest_cache"}

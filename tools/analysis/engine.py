@@ -422,9 +422,6 @@ class RepoIndex:
             roots = ["."]  # fixture tree: walk everything under root
         for r in roots:
             full = os.path.join(self.root, r)
-            if os.path.isfile(full):
-                out.append(r)
-                continue
             for dirpath, dirnames, filenames in os.walk(full):
                 dirnames[:] = [d for d in dirnames
                                if d not in config.SKIP_DIRS]

@@ -1,7 +1,6 @@
 """The one place an entry point chooses JAX's persistent compile cache.
 
-``chip_smoke.py``, ``bench.py`` and the ``tools/*.py`` scripts that
-touch JAX call :func:`ensure_compile_cache` before their first compile.
+``chip_smoke.py`` and the ``tools/*.py`` scripts that touch JAX call :func:`ensure_compile_cache` before their first compile.
 ``import paddle_tpu`` never does: a library import chooses no directory.
 (It does put metadata into the cache's key, ``nn/layer/layers.py``: the
 named scopes a profile is read by are metadata, and an executable cached
