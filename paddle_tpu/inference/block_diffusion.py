@@ -372,6 +372,7 @@ class BlockDiffusionPool(GenerationPool):
                  + bl * (1 - int(ctl[slot, 2 * bl + 1]))) // bs + 1
                 for slot, _ in self._rows)
             meta["table_blocks"] = self.slots * self._max_blocks
+            meta.update(self._entries_meta)
         meta.update(self._expert_meta(live))
         return meta
 

@@ -559,11 +559,31 @@ class GenerationPool:
         # reads and writes of recurrent state is the recurrent entries'
         self._by_kind = self._layout.bytes_per_slot_by_kind(
             self._cache, self.slots, self.max_len)
+        # K/V planes an entry holds: one a pass for a model that runs its
+        # stack several times (``models.LoopedLM.cache_planes``), side by
+        # side on the entry's head axis, so every figure by block or by
+        # slot above has them in it already; what is counted apart is
+        # how many there are, and the passes a step makes
+        self._planes = int(getattr(model, "cache_planes", 1))
+        self.loop_passes = 0
+        # what a paged pool's block counts run over (``tick.decode``'s
+        # meta): the entries behind a block table, the K/V planes they
+        # hold in all, the passes where an entry holds more than one
+        paged = sum(n for k, (n, _) in self._by_kind.items()
+                    if k != "recurrent")
+        self._entries_meta = dict(kv_entries=paged,
+                                  kv_planes=paged * self._planes)
+        if self._planes > 1:
+            self._entries_meta["passes"] = self._planes
         self._state_bytes_slot = self._by_kind.get("recurrent", (0, 0))[1]
         # the same, as ``cache_stats()`` hands it out every tick
         self._by_kind_stats = {
             "bytes_per_slot": {k: b for k, (_, b) in self._by_kind.items()},
             "cache_entries": {k: n for k, (n, _) in self._by_kind.items()}}
+        if self._planes > 1:
+            self._by_kind_stats.update(
+                passes=self._planes,
+                cache_planes=self._entries_meta["kv_planes"])
         # the model's routed expert layers as ``(held, experts, top_k)``,
         # and the route the step's rows compile them to: from shapes
         experts = [layer for layer in model.sublayers()
@@ -684,6 +704,13 @@ class GenerationPool:
                 "spill_tier must be 'host' (process-RAM, dies with the "
                 "engine) or 'disk' (crash-durable .npz files under "
                 "spill_dir), got %r" % (spill_tier,))
+        if spill_tier == "disk" and self._planes > 1:
+            raise InvalidArgumentError(
+                "spill_tier='disk' writes K/V blocks by head to a PTKV "
+                "file, whose header states one plane of heads a layer; "
+                "this model's cache entries hold %d K/V planes (one a "
+                "pass): keep spill_tier='host', which carries a block's "
+                "planes together in memory" % self._planes)
         if spill_tier == "disk":
             if not self._layout.spillable:
                 raise InvalidArgumentError(
@@ -2290,6 +2317,10 @@ class GenerationPool:
         # recurrent engine's spill file or journal, and vice versa
         # (check_fingerprint treats these as identity, not capacity)
         fp.update(self._layout.fingerprint_extra(self))
+        if self._planes > 1:
+            # an entry's head axis is planes x heads: a peer that reads
+            # it as one plane of more heads is another model
+            fp["cache_planes"] = self._planes
         return fp
 
     def _shared_block_count(self) -> int:
@@ -3080,10 +3111,13 @@ class GenerationPool:
 
     def _block_meta(self) -> dict:
         """``tick.decode``'s meta, each figure over its own entries: where
-        entries are paged (``kv_entries`` of them) ``live_blocks``, the
-        table entries the live slots' positions reach (what the
-        attention kernel fetches and computes: ``ops/pallas_decode.py``
-        skips the rest), and ``table_blocks``, slots x table width;
+        entries are paged (``kv_entries`` of them, ``kv_planes`` K/V
+        planes in all: an entry of a stack run several times holds one a
+        pass, and ``passes`` says how many) ``live_blocks``, the
+        table entries the live slots' positions reach, counted ONCE a
+        position whatever the planes (what one attention call fetches and
+        computes: ``ops/pallas_decode.py`` skips the rest), and
+        ``table_blocks``, slots x table width;
         where entries are recurrent (``state_entries``) ``state_bytes``;
         ``latent_entries`` where the paged entries hold latents.
         A model that mixes kinds carries both in the one span, and the
@@ -3099,16 +3133,14 @@ class GenerationPool:
             meta.update(
                 live_blocks=sum(self._last_position(slot, st) // bs + 1
                                 for slot, st in self._rows),
-                table_blocks=self.slots * self._max_blocks)
+                table_blocks=self.slots * self._max_blocks,
+                **self._entries_meta)
         if "latent" in self._by_kind:
             # the paged figures above run over latent entries: a block is
             # one latent a position, not K/V by head
             meta["latent_entries"] = self._by_kind["latent"][0]
         if len(self._by_kind) > 1:
-            meta.update(
-                state_entries=self._by_kind.get("recurrent", (0,))[0],
-                kv_entries=sum(n for k, (n, _) in self._by_kind.items()
-                              if k != "recurrent"))
+            meta["state_entries"] = self._by_kind.get("recurrent", (0,))[0]
         return meta
 
     def _expert_meta(self, live: int) -> dict:
@@ -3152,6 +3184,7 @@ class GenerationPool:
         for _, st in self._rows:
             st.ahead += 1
         self.steps_drawing += self._draws
+        self.loop_passes += self._planes
         return self._tok_dev
 
     def _deliver(self, tok) -> None:

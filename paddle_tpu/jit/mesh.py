@@ -165,6 +165,15 @@ class DecodeMesh:
                 "head-granular (each mp shard owns whole heads so the "
                 "cache's head axis aligns with the q/k/v projection "
                 "sharding)" % (self.mp, heads))
+        planes = int(getattr(model, "cache_planes", 1))
+        if planes > 1 and self.mp > 1:
+            raise InvalidArgumentError(
+                "mp=%d cannot shard a cache entry of %d K/V planes: the "
+                "planes lie side by side on the entry's head axis (one a "
+                "pass of a stack run several times), and the mp axis "
+                "would split that axis into runs of planes, not into "
+                "each plane's heads; serve this model with mp=1"
+                % (self.mp, planes))
         inter = getattr(model, "intermediate_size", None)
         if inter is not None and inter % self.mp != 0:
             raise InvalidArgumentError(

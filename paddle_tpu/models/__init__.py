@@ -22,6 +22,10 @@ from .latent_moe import (  # noqa: F401
     LatentMoEDecoderLayer,
     LatentMoELM,
 )
+from .looped_lm import (  # noqa: F401
+    LoopedDecoderLayer,
+    LoopedLM,
+)
 from .power_retention import (  # noqa: F401
     PowerRetentionDecoderLayer,
     PowerRetentionLM,

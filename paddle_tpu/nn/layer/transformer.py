@@ -219,7 +219,8 @@ class MultiHeadAttention(Layer):
     def gen_decode_cache(self, batch_size: int, max_length: int,
                          dtype="float32", per_slot: bool = False,
                          layout: str = "dense", block_size: int = 32,
-                         num_blocks: Optional[int] = None):
+                         num_blocks: Optional[int] = None,
+                         planes: int = 1):
         """Preallocated decode cache; leaves are RAW jax arrays (not
         Tensors) so the cache threads through jitted prefill/decode as a
         donated pytree.  The index is 0 (scalar, or [B] when
@@ -242,7 +243,21 @@ class MultiHeadAttention(Layer):
         aligned batches).  An EXPLICIT ``num_blocks`` means an external
         allocator (inference.GenerationPool) owns the mapping: the table
         starts all-zeros (everything unmapped → scratch) and the
-        allocator writes rows as it maps blocks."""
+        allocator writes rows as it maps blocks.
+
+        ``planes`` > 1: the entry holds that many K/V PLANES, one for
+        each time the layer runs in a forward (``models.LoopedLM`` runs
+        its stack several times on shared weights), side by side on the
+        HEAD axis: dense ``[B, planes * H, max_len, D]``, paged
+        ``[num_blocks, planes * H, block_size, D]``.  One ``index`` and
+        one ``table``: a position's planes lie in the same block, so
+        whatever takes, splices, masks, spills or frees a block moves
+        them together and the allocator counts what it counted; a block's
+        BYTES are ``planes`` times a plane's.  The cached forward is told
+        which plane it attends and writes (``plane=``, a traced index).
+        An int8 plane is refused: its scales would ride the head axis
+        too, and neither the kernel nor the composition takes a plane of
+        them."""
         import jax.numpy as jnp
 
         if layout not in ("dense", "paged"):
@@ -251,10 +266,23 @@ class MultiHeadAttention(Layer):
                 % (layout,))
         dtype = normalize_cache_dtype(dtype)
         quant = dtype == "int8"
+        planes = int(planes)
+        if planes < 1:
+            raise InvalidArgumentError(
+                "a cache entry holds planes >= 1 K/V planes, got %d"
+                % planes)
+        if planes > 1 and quant:
+            raise InvalidArgumentError(
+                "cache_dtype='int8' does not exist for an entry of %d K/V "
+                "planes (one a pass of a stack run several times): the "
+                "scales of a plane would be addressed by the pass index "
+                "like its K/V, which no attention route does; keep the "
+                "planes in float32 or bfloat16" % planes)
+        heads = planes * self.kv_heads
         index = (jnp.zeros((batch_size,), jnp.int32) if per_slot
                  else jnp.zeros((), jnp.int32))
         if layout == "dense":
-            shape = (batch_size, self.kv_heads, max_length, self.head_dim)
+            shape = (batch_size, heads, max_length, self.head_dim)
             scales = ((jnp.zeros(shape[:-1], jnp.float32),) * 2 if quant
                       else (None, None))
             return self.DecodeCache(jnp.zeros(shape, dtype),
@@ -277,18 +305,23 @@ class MultiHeadAttention(Layer):
                     "paged cache needs num_blocks >= 2 (block 0 is the "
                     "reserved scratch block), got %d" % num_blocks)
             table = jnp.zeros((batch_size, max_blocks), jnp.int32)
-        shape = (num_blocks, self.kv_heads, block_size, self.head_dim)
+        shape = (num_blocks, heads, block_size, self.head_dim)
         scales = ((jnp.zeros(shape[:-1], jnp.float32),) * 2 if quant
                   else (None, None))
         return self.PagedDecodeCache(jnp.zeros(shape, dtype),
                                      jnp.zeros(shape, dtype), table, index,
                                      *scales)
 
-    def _decode_forward(self, q, k_new, v_new, attn_mask, cache):
+    def _decode_forward(self, q, k_new, v_new, attn_mask, cache,
+                        plane=None):
         """Shape-static cached attention: write the new K/V chunk into the
         preallocated buffers at ``cache.index``, attend the queries over
         the valid prefix (causal across prefix + chunk), advance the
-        index.  Returns (raw attention out [B, H, L, D], new cache)."""
+        index.  Returns (raw attention out [B, H, L, D], new cache).
+
+        ``plane`` (an int32 scalar, traced): the buffers hold several
+        planes of ``kv_heads`` heads (``gen_decode_cache(planes=)``) and
+        this call writes and attends plane ``plane`` alone."""
         import jax
         import jax.numpy as jnp
 
@@ -310,12 +343,15 @@ class MultiHeadAttention(Layer):
             v_new, v_s = quantize_kv(v_new)
         idx = jnp.asarray(cache.index, jnp.int32)
         b, _, length, _ = q_.shape
+        # the first head written: 0, or the plane's
+        base = 0 if plane is None \
+            else jnp.asarray(plane, jnp.int32) * self.kv_heads
         if idx.ndim == 0:
             # aligned batch (DecodeSession): one slice write for the chunk
             k_buf = jax.lax.dynamic_update_slice(
-                k_buf, k_new.astype(k_buf.dtype), (0, 0, idx, 0))
+                k_buf, k_new.astype(k_buf.dtype), (0, base, idx, 0))
             v_buf = jax.lax.dynamic_update_slice(
-                v_buf, v_new.astype(v_buf.dtype), (0, 0, idx, 0))
+                v_buf, v_new.astype(v_buf.dtype), (0, base, idx, 0))
             if quant:
                 ks_buf = jax.lax.dynamic_update_slice(ks_buf, k_s,
                                                       (0, 0, idx))
@@ -331,10 +367,16 @@ class MultiHeadAttention(Layer):
             # are DROPPED by the scatter, never clamped onto valid rows.
             rows = jnp.arange(b)[:, None]                       # [B,1]
             pos = idx[:, None] + jnp.arange(length)[None, :]    # [B,L]
-            k_buf = k_buf.at[rows, :, pos, :].set(
+            if plane is None:
+                at = (rows, slice(None), pos)
+            else:
+                # the plane's heads, indexed beside (row, pos): [B,L,H]
+                at = (rows[..., None], base + jnp.arange(self.kv_heads),
+                      pos[..., None])
+            k_buf = k_buf.at[at].set(
                 k_new.transpose(0, 2, 1, 3).astype(k_buf.dtype),
                 mode="drop")
-            v_buf = v_buf.at[rows, :, pos, :].set(
+            v_buf = v_buf.at[at].set(
                 v_new.transpose(0, 2, 1, 3).astype(v_buf.dtype),
                 mode="drop")
             if quant:
@@ -357,12 +399,21 @@ class MultiHeadAttention(Layer):
         # visible key): the composition route rebuilds the exact
         # additive causal-prefix mask this code used to build inline,
         # while the fused pallas route masks in-register (§5l)
-        out = decode_attention(q_, k_buf, v_buf, q_pos=q_pos,
+        if plane is None:
+            k_read, v_read = k_buf, v_buf
+        else:
+            # a dense cache is read whole by the composition: the plane
+            # is its slice (a slot's slab, not a pool under a table; the
+            # paged entry's plane is never sliced out)
+            k_read, v_read = (jax.lax.dynamic_slice_in_dim(
+                x, base, self.kv_heads, axis=1) for x in (k_buf, v_buf))
+        out = decode_attention(q_, k_read, v_read, q_pos=q_pos,
                                k_scale=ks_buf, v_scale=vs_buf)
         return out, self.DecodeCache(k_buf, v_buf, idx + length,
                                      ks_buf, vs_buf)
 
-    def _paged_decode_forward(self, q, k_new, v_new, attn_mask, cache):
+    def _paged_decode_forward(self, q, k_new, v_new, attn_mask, cache,
+                              plane=None):
         """Block-table cached attention: the new K/V chunk is scattered
         into the global block pool THROUGH the row's block table, queries
         attend over the gathered valid prefix, the index advances.  Same
@@ -370,7 +421,12 @@ class MultiHeadAttention(Layer):
         are token-identical under greedy decoding — but writes address
         ``pool[table[row, pos // bs], :, pos % bs, :]`` so the bytes a
         step touches are the row's MAPPED blocks, not a dense
-        [B, H, max_len, D] slab."""
+        [B, H, max_len, D] slab.
+
+        ``plane`` (an int32 scalar, traced): the pools hold several planes
+        of ``kv_heads`` heads an entry (``gen_decode_cache(planes=)``);
+        the write and the attention address plane ``plane`` through the
+        one table, by a head offset (``ops.flash_attention``)."""
         import jax.numpy as jnp
 
         from ...framework.tensor import Tensor as _T
@@ -420,8 +476,11 @@ class MultiHeadAttention(Layer):
             logical = jnp.minimum(q_pos // bs, table.shape[1] - 1)
             phys = jnp.where(q_pos < s, table[rows, logical], 0)
             off = q_pos % bs
-        k_pool = paged_cache_write(k_pool, k_new, phys, off)
-        v_pool = paged_cache_write(v_pool, v_new, phys, off)
+        # the plane's first head, for the write and for the attention
+        at = {} if plane is None else {
+            "head_base": jnp.asarray(plane, jnp.int32) * self.kv_heads}
+        k_pool = paged_cache_write(k_pool, k_new, phys, off, **at)
+        v_pool = paged_cache_write(v_pool, v_new, phys, off, **at)
         if quant:
             ks_pool = paged_cache_write(ks_pool, k_s, phys, off)
             vs_pool = paged_cache_write(vs_pool, v_s, phys, off)
@@ -430,7 +489,9 @@ class MultiHeadAttention(Layer):
         # fused route walks the table in-kernel and masks in-register
         out = paged_decode_attention(q_, k_pool, v_pool, table,
                                      q_pos=self._last_visible(q_pos),
-                                     k_scale=ks_pool, v_scale=vs_pool)
+                                     k_scale=ks_pool, v_scale=vs_pool,
+                                     **(dict(at, plane_heads=self.kv_heads)
+                                        if at else {}))
         return out, cache._replace(
             k=k_pool, v=v_pool, k_scale=ks_pool, v_scale=vs_pool,
             index=idx + length)
@@ -595,7 +656,10 @@ class GroupedQueryAttention(MultiHeadAttention):
                 k = F.rotary_embedding(k, pos, self.rope_theta)
         return q, k, v, pos
 
-    def forward(self, x, attn_mask=None, cache=None):
+    def forward(self, x, attn_mask=None, cache=None, plane=None):
+        """``plane`` (with a cache of several planes,
+        ``gen_decode_cache(planes=)``): the plane this call writes and
+        attends, an int32 scalar that may be traced."""
         from ...framework.tensor import Tensor as _T
 
         if attn_mask is not None:
@@ -608,7 +672,8 @@ class GroupedQueryAttention(MultiHeadAttention):
             fwd = (self._decode_forward
                    if isinstance(cache, self.DecodeCache)
                    else self._paged_decode_forward)
-            out, cache = fwd(q, k, v, None, cache)
+            out, cache = fwd(q, k, v, None, cache,
+                             **({} if plane is None else {"plane": plane}))
             out = self.out_proj(self._merge_heads(
                 _T(out, stop_gradient=True)))
             return out, cache

@@ -520,7 +520,8 @@ def decode_attention(q, k, v, bias=None, sm_scale: Optional[float] = None,
 
 
 def paged_decode_attention_supported(q_shape, block_size: int,
-                                     num_blocks: int, dtype) -> bool:
+                                     num_blocks: int, dtype,
+                                     planes: int = 1) -> bool:
     """Gate for the fused pallas PAGED decode kernel
     (``ops.pallas_decode.paged_decode_attention_kernel``), mirroring
     ``decode_attention_supported``: TPU backend, short query chunk, a
@@ -529,21 +530,24 @@ def paged_decode_attention_supported(q_shape, block_size: int,
     (``paged_mosaic_refusal``: a head_dim of whole lanes, a
     ``block_size`` of whole sublanes; an int8 pool's scales and a bias
     add no rule).  The "auto" route's decision;
-    ``route="pallas"``/``"composition"`` override it."""
+    ``route="pallas"``/``"composition"`` override it.  A pool of several
+    ``planes`` (``paged_decode_attention``'s ``head_base``) is as big as
+    the positions of all its planes: each is walked by a call of its own
+    against the same table."""
     from .pallas_decode import MAX_KERNEL_QUERY_CHUNK, paged_mosaic_refusal
 
     if _cached_backend() != "tpu":
         return False
     if len(q_shape) != 4 or q_shape[2] > MAX_KERNEL_QUERY_CHUNK:
         return False
-    if block_size * num_blocks < DECODE_FLASH_MIN_CACHE:
+    if block_size * num_blocks * planes < DECODE_FLASH_MIN_CACHE:
         return False
     if jnp.dtype(dtype) not in _SUPPORTED_DTYPES:
         return False
     return paged_mosaic_refusal(q_shape[3], block_size) is None
 
 
-def paged_cache_write(pool, new, phys, off):
+def paged_cache_write(pool, new, phys, off, head_base=None):
     """Write a chunk's rows into a block pool where the pool lies.
 
     ``pool``: ``[num_blocks, H, block_size, D]`` K or V pool, or the
@@ -566,8 +570,19 @@ def paged_cache_write(pool, new, phys, off):
     An index outside the pool is DROPPED, never clamped onto a live
     block.  Indices may repeat (inactive slots all write the scratch
     block, several at one offset): which row lands there is not
-    defined, and nothing reads it."""
-    heads = jnp.arange(pool.shape[1], dtype=jnp.int32)
+    defined, and nothing reads it.
+
+    ``head_base`` (an int32 scalar, traced or not): the pool holds several
+    PLANES of ``new.shape[1]`` heads side by side on its head axis (a
+    stack run several times keeps one plane a pass,
+    ``models.LoopedLM``), and the chunk goes to heads ``head_base ...``:
+    the same scatter, the head index offset, nothing of a plane's size
+    read or copied."""
+    if head_base is None:
+        heads = jnp.arange(pool.shape[1], dtype=jnp.int32)
+    else:
+        heads = jnp.asarray(head_base, jnp.int32) \
+            + jnp.arange(new.shape[1], dtype=jnp.int32)
     with jax.named_scope("cache_write"):
         return pool.at[phys[..., None], heads, off[..., None]].set(
             jnp.moveaxis(new, 1, 2).astype(pool.dtype), mode="drop")
@@ -576,7 +591,8 @@ def paged_cache_write(pool, new, phys, off):
 def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
                            sm_scale: Optional[float] = None,
                            k_scale=None, v_scale=None, q_pos=None,
-                           route=None, score_dtype=None):
+                           route=None, score_dtype=None, head_base=None,
+                           plane_heads: Optional[int] = None):
     """Decode-step attention against a BLOCK-TABLE KV cache.
 
     ``q``: [B, H, Lq, D] queries (Lq = 1 for autoregressive decode,
@@ -614,12 +630,31 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
     and runs the online softmax — so the composition's HBM-materialized
     [B, H, S, D] gathered (and, for int8, fp32-up-cast) K/V is exactly
     the traffic the kernel deletes.
+
+    ``head_base`` / ``plane_heads``: the pools hold several PLANES of
+    ``plane_heads`` K/V heads side by side on their head axis and the
+    queries attend the plane that starts at head ``head_base`` (an int32
+    scalar, traced: the pass index of ``models.LoopedLM`` times the
+    plane's heads).  The kernel takes the offset as one more scalar and
+    copies that plane's heads of an entry; the composition gathers them
+    alone.  A float pool only.
     """
     from .pallas_decode import (paged_decode_attention_kernel,
                                 paged_mosaic_refusal)
 
     b, mb = table.shape
     nb, h, bs, d = k_pool.shape
+    planed = head_base is not None
+    if planed:
+        if k_scale is not None or bias is not None:
+            raise InvalidArgumentError(
+                "a pool of several K/V planes is a float pool attended "
+                "without an additive bias")
+        h = int(plane_heads)
+        head_base = jnp.asarray(head_base, jnp.int32)
+        plane = {"head_base": head_base, "plane_heads": h}
+    else:
+        plane = {}
     s = mb * bs
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
@@ -630,7 +665,9 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
             "an additive bias")
     if _resolve_route(
             route, q.shape,
-            paged_decode_attention_supported(q.shape, bs, nb, q.dtype)
+            paged_decode_attention_supported(
+                q.shape, bs, nb, q.dtype,
+                **({"planes": k_pool.shape[1] // h} if planed else {}))
             and _bias_kernel_compatible(bias, b, q.shape[1], q.shape[2],
                                         s),
             _kernel_refusal(q.shape, q.dtype, bs,
@@ -639,7 +676,7 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
         return paged_decode_attention_kernel(
             q, k_pool, v_pool, jnp.asarray(table, jnp.int32), qp,
             float(sm_scale), k_scale=k_scale, v_scale=v_scale,
-            bias=bias, interpret=_cached_backend() != "tpu")
+            bias=bias, interpret=_cached_backend() != "tpu", **plane)
     if grouped:
         # the composition on the folded rows: [B, Hkv, g * Lq, D]
         # against the gathered [B, Hkv, S, D], the mask tiled per head
@@ -648,14 +685,19 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
         out = paged_decode_attention(
             qf, k_pool, v_pool, table, sm_scale=sm_scale, k_scale=k_scale,
             v_scale=v_scale, q_pos=qp, route="composition",
-            score_dtype=jnp.float32)
+            score_dtype=jnp.float32, **plane)
         return out.reshape(q.shape)
     # gather the row's blocks: [B, MB, H, bs, D] -> [B, H, MB*bs, D];
     # XLA lowers the fancy-index to one gather over the pool's leading
     # axis, the only data-dependent op in the step
     tbl = jnp.asarray(table, jnp.int32)
-    k = k_pool[tbl].transpose(0, 2, 1, 3, 4).reshape(b, h, s, d)
-    v = v_pool[tbl].transpose(0, 2, 1, 3, 4).reshape(b, h, s, d)
+    if planed:
+        # the attended plane's heads of the row's blocks, and no other's
+        at = (tbl[:, :, None], head_base + jnp.arange(h, dtype=jnp.int32))
+    else:
+        at = tbl
+    k = k_pool[at].transpose(0, 2, 1, 3, 4).reshape(b, h, s, d)
+    v = v_pool[at].transpose(0, 2, 1, 3, 4).reshape(b, h, s, d)
     ks = vs = None
     if k_scale is not None:
         ks = k_scale[tbl].transpose(0, 2, 1, 3).reshape(b, h, s)

@@ -465,7 +465,8 @@ def _by_steps(x, steps: int, width: int):
 
 
 def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
-                sm_scale: float, quant: bool, bias_dims, group: int):
+                sm_scale: float, quant: bool, bias_dims, group: int,
+                planed: bool = False):
     """One ``(batch row, head chunk)`` a grid step: a loop over the row's
     LIVE table entries, ``tile`` of them a step.
 
@@ -490,12 +491,21 @@ def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
     grid step is started before the last tile of this one is computed, so
     only the first live row of a call, and a live row behind an empty one,
     wait for a copy they have just asked for.  A row that sees nothing
-    starts nothing, runs no tile and emits zeros."""
+    starts nothing, runs no tile and emits zeros.
+
+    ``planed``: a third scalar-prefetch ref follows ``q_pos``, one int32:
+    the first head of the PLANE of the pools that this call attends (the
+    pools hold several planes of the queries' K/V heads side by side on
+    their head axis).  A copy takes ``pool[table[b, e], base + chunk]``:
+    the same contiguous bytes of an entry as an unplaned pool's, further
+    along."""
     rows = group * lq
     width = tile * bs
     has_bias = bias_dims is not None
 
-    def body(tbl_ref, qpos_ref, q_ref, k_hbm, v_hbm, *refs):
+    def body(tbl_ref, qpos_ref, *refs):
+        base = refs[0][0] if planed else 0
+        q_ref, k_hbm, v_hbm, *refs = refs[int(planed):]
         # the streams that follow the walk's steps, then out, then a
         # buffer a stream: K, V, [K scales, V scales,] [bias]
         n_side = 2 * quant + has_bias
@@ -513,6 +523,7 @@ def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
             one V descriptor a live entry [, the tile's scales and
             bias]."""
             heads = pl.ds(chunk * hc, hc)
+            pool_heads = pl.ds(base + chunk * hc, hc) if planed else heads
             for s, (src, dst) in enumerate(zip(side_hbm, side_buf)):
                 if has_bias and s == n_side - 1:
                     # the bias may be one row or one head for all
@@ -532,7 +543,7 @@ def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
                     for s, (src, dst) in enumerate(((k_hbm, k_buf),
                                                     (v_hbm, v_buf))):
                         act(pltpu.make_async_copy(
-                            src.at[blk, heads], dst.at[slot, :, at, :],
+                            src.at[blk, pool_heads], dst.at[slot, :, at, :],
                             sems.at[slot, s]))
 
         @pl.when(jnp.logical_and(bi == 0, hh == 0))
@@ -626,9 +637,11 @@ def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
 @functools.partial(jax.jit,
                    static_argnames=("sm_scale", "interpret", "group"))
 def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
-                sm_scale, interpret, group=1):
+                sm_scale, interpret, group=1, head_base=None):
     # grouped K/V heads: ``q`` comes folded, [B, Hkv, group * Lq, D],
-    # a K/V head's whole group its block of rows
+    # a K/V head's whole group its block of rows.  ``head_base`` (int32
+    # [1], traced): the pools hold planes of Hkv heads and this call
+    # attends the one that starts there
     b, h, rows, d = q.shape
     lq = rows // group
     _, _, bs, _ = k_pool.shape
@@ -640,9 +653,11 @@ def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
     steps = -(-mb // tile)
     lanes = -(-tile * bs // _LANES) * _LANES
 
-    def row_map(bb, hh, tbl, qp):
+    def row_map(bb, hh, *scalars):
         return (bb, hh, 0, 0)
 
+    planed = head_base is not None
+    scalars = (table, q_pos) + ((head_base,) if planed else ())
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     args = [q, k_pool, v_pool]
     buffers = [pltpu.VMEM((2, hc, tile * bs, d), k_pool.dtype)] * 2
@@ -662,7 +677,7 @@ def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
         buffers.append(pltpu.VMEM(
             (2, hc if bias.shape[1] > 1 else 1, lq, lanes), bias.dtype))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(b, h // hc),
         in_specs=[pl.BlockSpec((1, hc, rows, d), row_map)]
         + [in_hbm] * (len(args) - 1),
@@ -672,7 +687,7 @@ def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
            pltpu.SMEM((2,), jnp.int32)] + _scratch(hc, rows, d))
     return pl.pallas_call(
         _paged_body(hc, mb, lq, bs, tile, sm_scale, quant,
-                    bias.shape if has_bias else None, group),
+                    bias.shape if has_bias else None, group, planed),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, rows, d), q.dtype),
         # a copy in flight and the buffer it lands in pass from one grid
@@ -680,13 +695,14 @@ def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(table, q_pos, *args)
+    )(*scalars, *args)
 
 
 def paged_decode_attention_kernel(q, k_pool, v_pool, table, q_pos,
                                   sm_scale: float,
                                   k_scale=None, v_scale=None, bias=None,
-                                  interpret: bool = False):
+                                  interpret: bool = False, head_base=None,
+                                  plane_heads: Optional[int] = None):
     """Fused paged decode attention: ``q`` [B, H, Lq, D] against a
     block-table pool [num_blocks, H, bs, D], never materializing the
     gathered K/V.
@@ -704,8 +720,21 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, q_pos,
     (``_by_steps``: any ``bs`` of whole sublanes).  ``bias``: optional
     additive [B|1, H|1, Lq, S], laid out the same way.  Compiled, the
     pools' ``head_dim`` is whole 128-lane tiles
-    (``paged_mosaic_refusal``)."""
+    (``paged_mosaic_refusal``).
+
+    ``head_base`` (an int32 scalar, traced) with ``plane_heads``: the
+    pools are ``[num_blocks, planes * plane_heads, bs, D]``, several
+    planes of K/V heads an entry, and the queries attend the plane whose
+    first head is ``head_base``; one more scalar-prefetch operand, the
+    walk and its copies otherwise the same.  A float pool, no bias."""
     nb, h, bs, d = k_pool.shape
+    planed = head_base is not None
+    if planed:
+        if k_scale is not None or bias is not None:
+            raise InvalidArgumentError(
+                "a pool of several K/V planes is a float pool attended "
+                "without an additive bias")
+        h = int(plane_heads)
     s = table.shape[1] * bs
     _check_common(q, q_pos, bias, s)
     group = 1
@@ -732,7 +761,10 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, q_pos,
                           jnp.asarray(table, jnp.int32),
                           jnp.asarray(q_pos, jnp.int32),
                           k_scale, v_scale, bias,
-                          float(sm_scale), bool(interpret), group=group)
+                          float(sm_scale), bool(interpret), group=group,
+                          **({"head_base": jnp.reshape(jnp.asarray(
+                              head_base, jnp.int32), (1,))}
+                             if planed else {}))
         return out.reshape(q.shape)
 
 

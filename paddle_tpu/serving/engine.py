@@ -648,6 +648,19 @@ class ServingEngine:
                 "each)",
                 labels={"layout": kind})
             for kind in stats["cache_entries"]}
+        # a stack run several times (models.LoopedLM): the K/V planes its
+        # entries hold in all, and the passes its decode steps have made
+        looped = "cache_planes" in stats
+        self._g_cache_planes = m.gauge(
+            "serving_cache_planes",
+            "K/V planes the decode-cache entries hold in all: entries x "
+            "the passes of a stack run several times (one block table "
+            "and one allocation a position for all of them)") \
+            if looped else None
+        self._c_loop_passes = m.counter(
+            "serving_loop_passes_total",
+            "passes through the stack made by decode steps: steps "
+            "launched x the model's passes a step") if looped else None
         self._g_kv_free = m.gauge(
             "serving_kv_free_blocks",
             "paged allocator free blocks") \
@@ -2536,6 +2549,9 @@ class ServingEngine:
         # the pool's totals only grow: a counter moves by what its
         # total gained since the counter last read it
         self._c_drawing.inc(pool.steps_drawing - self._c_drawing.value)
+        if self._c_loop_passes is not None:
+            self._c_loop_passes.inc(
+                pool.loop_passes - self._c_loop_passes.value)
         if self._c_block is not None:
             for key, now in pool.block_stats().items():
                 self._c_block[key].inc(now - self._c_block[key].value)
@@ -2580,6 +2596,8 @@ class ServingEngine:
             self._g_state_slot.set(stats["bytes_per_slot"]["recurrent"])
         for kind, g in self._g_cache_entries.items():
             g.set(stats["cache_entries"][kind])
+        if self._g_cache_planes is not None:
+            self._g_cache_planes.set(stats["cache_planes"])
         if self._g_experts_read is not None:
             self._g_experts_read.set(stats["experts_read_expected"])
         if self._g_kv_free is not None:

@@ -551,3 +551,94 @@ def test_the_latent_kernel_compiles_at_the_tile_its_rows_give(one_chip, lq,
         shape((32, 72), jnp.int32), shape((32, lq), jnp.int32)) \
         .compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_looped_step_on_the_v5e_is_one_loop_that_moves_no_plane(
+        one_chip, monkeypatch):
+    """``ouro-2p6b``'s decode step and prefill at the cell's cache (81 blocks
+    of 64 positions, FOUR planes of 16 heads of 128 an entry, bfloat16, 16
+    slots), two of its 48 layers, a small vocabulary: the passes are ONE
+    ``while`` with the layers' two paged kernels in its body (not eight in
+    a row), each pool is written by a scatter where it lies (K and V of
+    every layer, inside the loop), and nothing of a pool's size, nor of a
+    plane's, is copied, sliced or re-laid, in the step or in the prefill's
+    row cache."""
+    import jax
+
+    from paddle_tpu.models import LoopedLM
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    pt.seed(0)
+    looped = LoopedLM(vocab_size=512, hidden_size=2048, num_layers=LAYERS,
+                      num_heads=16, num_kv_heads=16, head_dim=128,
+                      intermediate_size=5632, total_ut_steps=4,
+                      dtype="bfloat16")
+    looped.eval()
+    pool = GenerationPool(looped, max_len=320, slots=16, buckets=[128],
+                          cache_layout="paged", block_size=64,
+                          num_blocks=81, cache_dtype="bfloat16")
+    n = pool.slots
+    params, bufs = pool._session._state_vals()
+    samp = (np.zeros(n, np.float32), np.zeros(n, np.int32),
+            np.ones(n, np.float32), np.zeros(n, np.uint32))
+    args = (params, bufs, pool._cache, np.zeros(n, np.int32),
+            np.ones(n, bool), samp, np.zeros(n, np.uint32),
+            np.zeros(n, np.int32))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip), args)
+    monkeypatch.setattr(fa, "_backend_memo", "tpu")
+    text = jax.jit(pool._pool_decode, donate_argnums=(2,)) \
+        .lower(*shapes).compile().as_text()
+    pool_shape = pool._cache[0].k.shape
+    assert pool_shape == (81, 4 * 16, 64, 128)
+    plane_shape = (81, 16, 64, 128)
+    assert text.count(" while(") == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
+    for shape in (pool_shape, plane_shape):
+        assert chip_smoke.pool_shaped_moves(text, shape) == []
+    made = [op for _, op in chip_smoke.pool_shaped_ops(text, pool_shape)]
+    assert made.count("scatter") == 2 * LAYERS
+    assert set(made) <= {"parameter", "scatter", "fusion", "bitcast",
+                         "get-tuple-element"}, sorted(set(made))
+    assert [op for _, op in chip_smoke.pool_shaped_ops(text, plane_shape)] \
+        == []
+    # the prefill: a row cache of 1 + 5 blocks, the same loop
+    sess = pool._session
+    pargs = (params, bufs, np.zeros((1, 128), np.int32), np.int32(100),
+             sess.sampling_state(1))
+    pshapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
+                                       sharding=one_chip), pargs)
+    text = jax.jit(sess._prefill).lower(*pshapes).compile().as_text()
+    assert text.count(" while(") == 1
+    for shape in ((6, 64, 64, 128), (6, 16, 64, 128)):
+        assert chip_smoke.pool_shaped_moves(text, shape) == []
+
+
+@pytest.mark.parametrize("lq", [1, 5])
+def test_the_planed_kernel_at_the_looped_cells_geometry_on_the_v5e(one_chip,
+                                                                    lq):
+    """The kernel at ``ouro-2p6b``'s cache: the pools hold four planes of
+    16 heads an entry, the call attends the plane at a TRACED head offset
+    (one more scalar-prefetch operand), an entry's copy is [16, 64, 128]
+    bfloat16 of K and of V: gpt-1p3b's 524,288 B."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import pallas_decode
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = shape((81, 64, 64, 128), jnp.bfloat16)
+    args = [shape((16, 16, lq, 128), jnp.bfloat16), pool, pool,
+            shape((16, 5), jnp.int32), shape((16, lq), jnp.int32),
+            shape((), jnp.int32)]
+    assert pallas_decode.head_chunk(16, 64, 128, 2) == 16
+    text = jax.jit(
+        lambda q, k, v, t, p, base:
+        pallas_decode.paged_decode_attention_kernel(
+            q, k, v, t, p, 128 ** -0.5, head_base=base,
+            plane_heads=16)).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1

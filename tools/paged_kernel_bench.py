@@ -12,6 +12,8 @@ the table in PERF.md section 6, PR 43, is this script's).
 | ``gpt`` | float32, 16 heads, blocks of 32, 32 entries, 16 rows | ``gpt1p3b-batch-closed``; its ``chat`` context is ``gpt1p3b-chat-r60``'s |
 | ``jamba`` | bfloat16, 1 K/V head x group 20, blocks of 128, 18 entries, 64 rows | ``jamba3b-batch-closed`` |
 | ``sdar`` | bfloat16, 4 K/V heads x group 8, 4 positions, blocks of 128, 20 entries, 32 rows | ``sdar30b-batch-closed`` |
+| ``ouro`` | bfloat16, 16 heads, blocks of 64, 5 entries, 16 rows, FOUR planes of heads an entry, the chained calls attending plane 0, 1, 2, 3, 0 ... by a head offset | ``ouro2p6b-batch-closed`` |
+| ``ouro-blocks`` | the same calls with the planes laid as further BLOCKS (``table + r * num_blocks`` on a pool of four times the blocks, the unplaned kernel) | off the benchmark: the layout not chosen (docs/DESIGN.md, "a stack run several times") |
 | ``gpt-int8`` | ``gpt``'s call on an int8 pool with its float32 scales (blocks of 32) | off the benchmark: ``cache_dtype="int8"`` at the default block |
 | ``gpt-d64`` | ``gpt``'s call at 32 heads of 64 | off the benchmark: a head of half a lane tile, which the walk refuses |
 
@@ -52,10 +54,14 @@ GEOMETRIES = {
     "gpt": (16, 16, 16, 1, 32, 128, 32, "float32", (150, 560)),
     "jamba": (64, 20, 1, 1, 128, 128, 18, "bfloat16", (140, 1900)),
     "sdar": (32, 32, 4, 4, 128, 128, 20, "bfloat16", (400, 2300)),
+    "ouro": (16, 16, 16, 1, 64, 128, 5, "bfloat16", (100, 315)),
+    "ouro-blocks": (16, 16, 16, 1, 64, 128, 5, "bfloat16", (100, 315)),
     "gpt-int8": (16, 16, 16, 1, 32, 128, 32, "int8", (150, 560)),
     "gpt-d64": (16, 32, 32, 1, 32, 64, 32, "float32", (150, 560)),
 }
 TOY = (4, 4, 2, 2, 8, 16, 6, "float32", (5, 40))
+# geometries whose pool holds several K/V planes: how many, and how laid
+PLANES = {"ouro": (4, "heads"), "ouro-blocks": (4, "blocks")}
 
 
 def contexts_of(name: str, b: int, lq: int, mb: int, bs: int, mix) -> dict:
@@ -112,11 +118,15 @@ def main(argv=None) -> int:
     if args.kv_mib is not None and hasattr(pd, "_PAGED_TILE_ENTRIES"):
         pd._KV_VMEM_BUDGET = args.kv_mib * 1024 * 1024
 
-    def chain(route):
+    def chain(route, planes=1, laid=None, hkv=None, nb=None):
         def run(q, k, v, table, q_pos, *scales):
-            for _ in range(calls):
+            for i in range(calls):
+                r = i % planes
+                at = {"head_base": r * hkv, "plane_heads": hkv} \
+                    if laid == "heads" else {}
                 q = fa.paged_decode_attention(
-                    q, k, v, table, q_pos=q_pos, route=route,
+                    q, k, v, table + r * nb if laid == "blocks" else table,
+                    q_pos=q_pos, route=route, **at,
                     **dict(zip(("k_scale", "v_scale"), scales)))
             return q
         return jax.jit(run)
@@ -138,6 +148,11 @@ def main(argv=None) -> int:
         dtype = jnp.dtype(dtype)
         quant = dtype == jnp.int8
         nb = 1 + b * mb
+        planes, laid = PLANES.get(name, (1, None))
+        # the pool's blocks and heads with the planes in them
+        pnb = nb * planes if laid == "blocks" else nb
+        phkv = hkv * planes if laid == "heads" else hkv
+        how = dict(planes=planes, laid=laid, hkv=hkv, nb=nb)
         key = jax.random.PRNGKey(len(name))
         kq, kk, kv, ks = jax.random.split(key, 4)
         q = jax.random.normal(kq, (b, hq, lq, d), jnp.float32)
@@ -153,15 +168,15 @@ def main(argv=None) -> int:
         else:
             q = q.astype(dtype)
             k_pool, v_pool = (jax.random.normal(
-                k_, (nb, hkv, bs, d), jnp.float32).astype(dtype)
+                k_, (pnb, phkv, bs, d), jnp.float32).astype(dtype)
                 for k_ in (kk, kv))
         table = jnp.asarray(1 + np.arange(b * mb).reshape(b, mb), jnp.int32)
-        plain = chain("composition")
+        plain = chain("composition", **how)
         for cap in args.entries or [None]:
             if cap is not None and hasattr(pd, "_PAGED_TILE_ENTRIES"):
                 pd._PAGED_TILE_ENTRIES = cap
                 pd._paged_call.clear_cache()
-            kernel = chain("pallas")
+            kernel = chain("pallas", **how)
             for label, ctx in contexts_of(name, b, lq, mb, bs, mix).items():
                 if args.context and label not in args.context:
                     continue
