@@ -110,6 +110,7 @@ from ..jit.decode import (DecodeSession, check_sampling, classify_finish,
 from ..nn import functional as F
 from ..nn import lora as _lora_mod
 from ..nn.layer.moe import SparseExperts
+from ..ops.flash_attention import decode_route, paged_kv_write_route
 from ..jit.mesh import DecodeMesh
 
 __all__ = ["GenerationPool", "kv_reachable_bytes",
@@ -575,6 +576,16 @@ class GenerationPool:
                                   kv_planes=paged * self._planes)
         if self._planes > 1:
             self._entries_meta["passes"] = self._planes
+        # how a step's new K/V rows reach their pools (one in-place
+        # kernel a layer, or a scatter a pool), from shapes, types and
+        # the session's route, as the step's trace decides it; an entry
+        # of latents has no K/V pool and says nothing
+        with decode_route(self._session.route):
+            writes = {paged_kv_write_route(entry.k, self._rows_a_slot)
+                      for entry in self._cache
+                      if hasattr(entry, "table") and hasattr(entry, "k")}
+        if writes:
+            self._entries_meta["kv_write"] = "+".join(sorted(writes))
         self._state_bytes_slot = self._by_kind.get("recurrent", (0, 0))[1]
         # the same, as ``cache_stats()`` hands it out every tick
         self._by_kind_stats = {

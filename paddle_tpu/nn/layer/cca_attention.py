@@ -212,7 +212,7 @@ class CCAttention(Layer):
     def _write(self, kv, k, v, pos):
         """The chunk's ``k`` and ``v`` ``[B, n_kv, L, d]`` into the K/V
         entry at ``pos`` ``[B, L]``."""
-        from ...ops.flash_attention import paged_cache_write
+        from ...ops.flash_attention import paged_kv_write
 
         b = k.shape[0]
         rows = jnp.arange(b)[:, None]
@@ -227,8 +227,8 @@ class CCAttention(Layer):
         # every paged write routes it
         logical = jnp.minimum(pos // bs, table.shape[1] - 1)
         phys = jnp.where(pos < table.shape[1] * bs, table[rows, logical], 0)
-        return kv._replace(k=paged_cache_write(kv.k, k, phys, pos % bs),
-                           v=paged_cache_write(kv.v, v, phys, pos % bs))
+        k_pool, v_pool = paged_kv_write(kv.k, kv.v, k, v, phys, pos % bs)
+        return kv._replace(k=k_pool, v=v_pool)
 
     def forward(self, a, cache=None):
         from ...ops.flash_attention import (causal_attention,

@@ -432,7 +432,7 @@ class MultiHeadAttention(Layer):
         from ...framework.tensor import Tensor as _T
         from ...ops.flash_attention import (paged_cache_write,
                                             paged_decode_attention,
-                                            quantize_kv)
+                                            paged_kv_write, quantize_kv)
 
         def raw(x):
             return x.value if isinstance(x, _T) else jnp.asarray(x)
@@ -479,8 +479,10 @@ class MultiHeadAttention(Layer):
         # the plane's first head, for the write and for the attention
         at = {} if plane is None else {
             "head_base": jnp.asarray(plane, jnp.int32) * self.kv_heads}
-        k_pool = paged_cache_write(k_pool, k_new, phys, off, **at)
-        v_pool = paged_cache_write(v_pool, v_new, phys, off, **at)
+        # K and V together: one kernel for both pools where the chunk is
+        # decode-sized and the pools float, else a scatter each
+        k_pool, v_pool = paged_kv_write(k_pool, v_pool, k_new, v_new, phys,
+                                        off, **at)
         if quant:
             ks_pool = paged_cache_write(ks_pool, k_s, phys, off)
             vs_pool = paged_cache_write(vs_pool, v_s, phys, off)

@@ -19,6 +19,7 @@ from .flash_attention import (  # noqa: F401
     paged_cache_write,
     paged_decode_attention,
     paged_decode_attention_supported,
+    paged_kv_write,
     quantize_kv,
     reset_backend_memo,
 )
@@ -41,7 +42,8 @@ from .selective_scan import (  # noqa: F401
 __all__ = ["flash_attention", "flash_attention_supported",
            "decode_attention", "decode_attention_supported",
            "paged_decode_attention", "paged_decode_attention_supported",
-           "paged_cache_write", "quantize_kv", "dequantize_kv",
+           "paged_cache_write", "paged_kv_write", "quantize_kv",
+           "dequantize_kv",
            "decode_attention_kernel", "paged_decode_attention_kernel",
            "decode_route", "normalize_decode_route", "DECODE_ROUTES",
            "reset_backend_memo", "power_retention_chunked",

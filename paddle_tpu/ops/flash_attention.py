@@ -27,7 +27,8 @@ from ..core.errors import InvalidArgumentError
 __all__ = ["flash_attention", "flash_attention_supported",
            "decode_attention", "decode_attention_supported",
            "paged_decode_attention", "paged_decode_attention_supported",
-           "paged_cache_write", "quantize_kv", "dequantize_kv",
+           "paged_cache_write", "paged_kv_write", "paged_kv_write_route",
+           "quantize_kv", "dequantize_kv",
            "latent_decode_attention", "latent_cache_write",
            "causal_attention", "causal_flash_supported",
            "decode_route", "normalize_decode_route", "DECODE_ROUTES",
@@ -586,6 +587,58 @@ def paged_cache_write(pool, new, phys, off, head_base=None):
     with jax.named_scope("cache_write"):
         return pool.at[phys[..., None], heads, off[..., None]].set(
             jnp.moveaxis(new, 1, 2).astype(pool.dtype), mode="drop")
+
+
+def paged_kv_write_route(pool, chunk: int, route=None) -> str:
+    """``"kernel"`` or ``"scatter"``: how a chunk of ``chunk`` positions
+    is written into the K and V pools shaped and typed as ``pool`` (an
+    array or a ``ShapeDtypeStruct``, ``[num_blocks, H, block_size, D]``).
+    Decided from what the call can see, as the attention's route is
+    (``_resolve_route``): under ``composition`` the scatter; under
+    ``pallas`` the kernel, or its refusal raised
+    (``pallas_decode.kv_write_mosaic_refusal``); under ``auto`` the kernel
+    on a TPU where the geometry compiles.  Two cases keep the scatter
+    under every route, by design: a prefill-shaped chunk (more than
+    ``MAX_KERNEL_QUERY_CHUNK`` positions: its rows are many and its index
+    rows few a row), and an int8 pool (its ``[num_blocks, H,
+    block_size]`` scale pools have no ``D`` window to copy and are
+    scattered whatever is done, so all four writes stay one kind).
+    ``GenerationPool`` puts the answer in ``tick.decode``'s meta
+    (``kv_write``)."""
+    from .pallas_decode import (MAX_KERNEL_QUERY_CHUNK,
+                                kv_write_mosaic_refusal)
+
+    _, _, bs, d = pool.shape
+    dtype = jnp.dtype(pool.dtype)
+    if chunk > MAX_KERNEL_QUERY_CHUNK or dtype not in _SUPPORTED_DTYPES:
+        return "scatter"
+    on_tpu = _cached_backend() == "tpu"
+    refusal = kv_write_mosaic_refusal(d, bs, dtype.itemsize) \
+        if on_tpu else None
+    return "kernel" if _resolve_route(
+        route, (1, 1, chunk, d), on_tpu and refusal is None,
+        refusal) else "scatter"
+
+
+def paged_kv_write(k_pool, v_pool, k_new, v_new, phys, off, head_base=None,
+                   route=None):
+    """A chunk's K and V rows into their block pools, where the pools
+    lie: ``paged_cache_write``'s result for each, to the bit, as
+    ``(k_pool, v_pool)``.  A decode-sized chunk into float pools goes by
+    ONE in-place kernel for both pools
+    (``pallas_decode.paged_kv_write_kernel``: a scatter of one index row
+    a (slot, head) costs the same whatever the row's bytes, and a decode
+    step made two a layer); anything else by the two scatters
+    (``paged_kv_write_route`` decides, from shapes, types and the ambient
+    ``decode_route``)."""
+    if paged_kv_write_route(k_pool, k_new.shape[2], route) == "kernel":
+        from .pallas_decode import paged_kv_write_kernel
+
+        return paged_kv_write_kernel(
+            k_pool, v_pool, k_new, v_new, phys, off,
+            interpret=_cached_backend() != "tpu", head_base=head_base)
+    return (paged_cache_write(k_pool, k_new, phys, off, head_base),
+            paged_cache_write(v_pool, v_new, phys, off, head_base))
 
 
 def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,

@@ -49,6 +49,14 @@ WALKS the row's block table inside it:
   out by the walk's steps as the scales are, and copied a step at a
   time).
 
+``paged_kv_write_kernel`` (``_kv_write_call``) is the walk's counterpart
+on the way IN: a decode step's new K and V rows go into their pools by one
+kernel a layer, the pools left in HBM and aliased input to output, each
+row read and written back with the tile of rows it lies in
+(``write_group``; ``_kv_write_body`` has why, docs/DESIGN.md §5l "The
+write").  The XLA scatter it replaces costs one index row a (slot, head)
+whatever the row's bytes.
+
 ``decode_attention_kernel`` is the dense-cache variant: the "table" is the
 identity walk of the ``[B, H, S, D]`` buffer, chunked into sequence tiles,
 one tile a step of a ``(row, head chunk, tile)`` grid under the pipeline's
@@ -107,7 +115,8 @@ __all__ = ["decode_attention_kernel", "paged_decode_attention_kernel",
            "latent_decode_attention_kernel", "latent_mosaic_refusal",
            "latent_sub_blocks", "MAX_KERNEL_QUERY_CHUNK", "bias_streamable",
            "dense_seq_block", "mosaic_refusal", "paged_mosaic_refusal",
-           "paged_tile_entries"]
+           "paged_tile_entries", "paged_kv_write_kernel",
+           "kv_write_mosaic_refusal", "write_group"]
 
 # The longest query chunk the kernel accepts: 1 for autoregressive
 # decode, spec_k+1 for a speculative verify chunk.  Longer chunks are
@@ -766,6 +775,219 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, q_pos,
                               head_base, jnp.int32), (1,))}
                              if planed else {}))
         return out.reshape(q.shape)
+
+
+def _tile_rows(itemsize: int) -> int:
+    """The rows of a sublane tile of values of ``itemsize`` bytes: 8
+    float32, 16 bfloat16, 32 int8."""
+    return 4 * _SUBLANES // itemsize
+
+
+def write_group(block_size: int, itemsize: int) -> int:
+    """The rows of a block that the K/V write reads and writes back around
+    ONE new row: a sublane tile of the pool's type (8 float32 rows, 16
+    bfloat16: a pool lies tiled so in HBM, and Mosaic copies no slice of
+    fewer rows).  A block that no such tile divides is one group (the
+    interpreter's; ``kv_write_mosaic_refusal`` names it on a TPU)."""
+    g = _tile_rows(itemsize)
+    return block_size if block_size % g else g
+
+
+def kv_write_mosaic_refusal(head_dim: int, block_size: int,
+                            itemsize: int) -> Optional[str]:
+    """Why Mosaic cannot compile the K/V write kernel at this geometry, or
+    None.  The pools stay in HBM and a row's group is copied by hand: the
+    pool's minor dimension is whole 128-lane tiles (as
+    ``paged_mosaic_refusal``) and a block is whole sublane tiles of the
+    pool's type (``write_group``)."""
+    if head_dim % _LANES != 0:
+        return ("head_dim %d is not whole %d-lane tiles: the pools stay in "
+                "HBM and a row's group is copied by hand, which a narrower "
+                "minor dimension does not allow" % (head_dim, _LANES))
+    g = _tile_rows(itemsize)
+    if block_size % g != 0:
+        return ("K/V block of %d positions is not a multiple of the %d rows "
+                "a tile of %d-byte values holds: a new row is written with "
+                "its tile's other rows" % (block_size, g, itemsize))
+    return None
+
+
+def _kv_write_body(n: int, lq: int, h: int, g: int, nb: int, planed: bool):
+    """``n`` slots a grid step, ``lq`` positions a slot: every new row
+    into its pools where they lie, by a read-modify-write of the row's
+    GROUP (``write_group``; a pool's tile in HBM is ``g`` rows, and no
+    copy is narrower).
+
+    Refs: scalar prefetch ``phys`` and ``off`` ``[B * lq]`` [, the
+    plane's first head ``[1]``]; the step's new K and V rows ``[n * lq, h,
+    D]`` (VMEM, the pipeline's); the K and V pools twice, in and out, ONE
+    buffer each (``input_output_aliases``), left in HBM; scratch: a group
+    a new row and pool ``[n * lq, h, g, D]``, four DMA semaphores (K and V,
+    in and out).
+
+    Row ``i``'s group is ``pool[phys[i], base:base + h, off[i] // g * g
+    ...]``.  The positions of ONE slot may share a group (a chunk of
+    ``lq`` > 1) and must not lose each other's rows, so a group is read
+    and written back by the first of the slot's rows that names it (its
+    LEADER, ``lead``) and the rows that follow are put into the leader's
+    buffer.  Two slots share no block but the scratch block, where what
+    lands is not defined.  Every read is started before the first is
+    waited for, then the new rows are put in (a select on the row's
+    index in its group), then every write is started before the first is
+    waited for.  A row whose block lies outside ``[0, nb)`` is DROPPED: no
+    copy is made for it."""
+    def body(phys_ref, off_ref, *refs):
+        base = refs[0][0] if planed else 0
+        k_new, v_new, _, _, k_hbm, v_hbm, k_buf, v_buf, sems = \
+            refs[int(planed):]
+        first = pl.program_id(0) * (n * lq)
+
+        def lead(slot, l):
+            """The buffer (this step's) that row ``l`` of ``slot`` is
+            put into: its own, or that of the slot's first row in the
+            same group."""
+            i = slot * lq + l
+            at = first + i
+            j = i
+            for m in range(l - 1, -1, -1):
+                same = jnp.logical_and(
+                    phys_ref[at - l + m] == phys_ref[at],
+                    off_ref[at - l + m] // g == off_ref[at] // g)
+                j = jnp.where(same, i - l + m, j)
+            return i, j
+
+        def each_row(act, which=lambda i, j: True):
+            """``act(i, j)`` on every in-pool row of the step that
+            ``which`` keeps."""
+            def slot_rows(slot, carry):
+                for l in range(lq):
+                    i, j = lead(slot, l)
+                    blk = phys_ref[first + i]
+                    ok = jnp.logical_and(blk >= 0, blk < nb)
+
+                    @pl.when(jnp.logical_and(ok, which(i, j)))
+                    def _(i=i, j=j):
+                        act(i, j)
+                return carry
+            jax.lax.fori_loop(0, n, slot_rows, 0)
+
+        def leaders(i, j):
+            return i == j
+
+        def copies(back: bool, act):
+            """``act`` on the K and the V copy of leader ``i``'s group,
+            pool to buffer or ``back``."""
+            def row(i, j):
+                rows = off_ref[first + i] // g * g
+                for s, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf))):
+                    group = pool.at[phys_ref[first + i], pl.ds(base, h),
+                                    pl.ds(pl.multiple_of(rows, g), g), :]
+                    act(pltpu.make_async_copy(buf.at[i], group,
+                                              sems.at[2 + s])
+                        if back else
+                        pltpu.make_async_copy(group, buf.at[i], sems.at[s]))
+            return row
+
+        def put(i, j):
+            at = jax.lax.broadcasted_iota(jnp.int32, k_buf.shape[1:], 1) \
+                == off_ref[first + i] % g
+            for new, buf in ((k_new, k_buf), (v_new, v_buf)):
+                buf[j] = jnp.where(at, new[i][:, None, :], buf[j])
+
+        def start(c):
+            c.start()
+
+        def wait(c):
+            c.wait()
+
+        each_row(copies(False, start), leaders)
+        each_row(copies(False, wait), leaders)
+        each_row(put)
+        each_row(copies(True, start), leaders)
+        each_row(copies(True, wait), leaders)
+
+    return body
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kv_write_call(k_pool, v_pool, k_new, v_new, phys, off, interpret,
+                   head_base=None):
+    # ``k_new`` / ``v_new`` [B, L, H, D] in the pools' type, ``phys`` /
+    # ``off`` [B, L]; ``head_base`` (int32 [1], traced): the pools hold
+    # planes of H heads and the rows go to the one that starts there
+    b, lq, h, d = k_new.shape
+    nb, _, bs, _ = k_pool.shape
+    g = write_group(bs, k_pool.dtype.itemsize)
+    # the slots a grid step takes: as many as leave a group a new row of
+    # both pools inside the budget, and a divisor of the rows
+    fit = _KV_VMEM_BUDGET // (2 * lq * h * g * d * k_pool.dtype.itemsize)
+    n = max(c for c in range(1, b + 1) if b % c == 0 and c <= max(fit, 1))
+    planed = head_base is not None
+    scalars = (phys.reshape(-1), off.reshape(-1)) \
+        + ((head_base,) if planed else ())
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    rows = pl.BlockSpec((n * lq, h, d), lambda i, *scalars: (i, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(b // n,),
+        in_specs=[rows, rows, in_hbm, in_hbm],
+        out_specs=[in_hbm, in_hbm],
+        scratch_shapes=[pltpu.VMEM((n * lq, h, g, d), k_pool.dtype)] * 2
+        + [pltpu.SemaphoreType.DMA((4,))])
+    return pl.pallas_call(
+        _kv_write_body(n, lq, h, g, nb, planed),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype)] * 2,
+        # the pools are updated where they lie: operand (scalars first)
+        # to result
+        input_output_aliases={len(scalars) + 2: 0, len(scalars) + 3: 1},
+        # a step's writes land before the next step's reads start
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*scalars, k_new.reshape(b * lq, h, d), v_new.reshape(b * lq, h, d),
+      k_pool, v_pool)
+
+
+def paged_kv_write_kernel(k_pool, v_pool, k_new, v_new, phys, off,
+                          interpret: bool = False, head_base=None):
+    """A chunk's K and V rows into their block pools by ONE in-place
+    kernel: ``pool[phys[b, l], head_base + h, off[b, l]] = new[b, h, l]``
+    for both pools ``[num_blocks, H_pool, bs, D]`` (float32 or bfloat16,
+    one type), ``k_new`` / ``v_new`` ``[B, H, L, D]`` with ``L <=
+    MAX_KERNEL_QUERY_CHUNK``, ``phys`` / ``off`` ``[B, L]`` int32.  What
+    ``ops.flash_attention.paged_cache_write`` documents, to the bit: an
+    index outside the pool is dropped, rows that repeat (the scratch
+    block's) land in no defined order.  The pools stay in HBM and are
+    aliased input to output; a row travels with its group of rows
+    (``_kv_write_body``).  Returns ``(k_pool, v_pool)``."""
+    if k_pool.shape != v_pool.shape or k_pool.dtype != v_pool.dtype \
+            or k_new.shape != v_new.shape:
+        raise InvalidArgumentError(
+            "the K and V pools (and the K and V rows) are written by one "
+            "kernel and have one shape and type: got %r %s and %r %s"
+            % (tuple(k_pool.shape), k_pool.dtype, tuple(v_pool.shape),
+               v_pool.dtype))
+    if k_new.shape[2] > MAX_KERNEL_QUERY_CHUNK:
+        raise InvalidArgumentError(
+            "the K/V write kernel takes chunks of at most %d positions, "
+            "got %d: a longer chunk is prefill work, the scatter's"
+            % (MAX_KERNEL_QUERY_CHUNK, k_new.shape[2]))
+    planed = head_base is not None
+    if not planed and k_new.shape[1] != k_pool.shape[1]:
+        raise InvalidArgumentError(
+            "a chunk of %d heads into a pool of %d needs the plane's "
+            "head_base" % (k_new.shape[1], k_pool.shape[1]))
+    with jax.named_scope("cache_write"):
+        return _kv_write_call(
+            k_pool, v_pool,
+            *(jnp.moveaxis(x, 1, 2).astype(k_pool.dtype)
+              for x in (k_new, v_new)),
+            jnp.asarray(phys, jnp.int32), jnp.asarray(off, jnp.int32),
+            bool(interpret),
+            **({"head_base": jnp.reshape(jnp.asarray(
+                head_base, jnp.int32), (1,))} if planed else {}))
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))

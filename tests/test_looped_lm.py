@@ -304,14 +304,15 @@ def test_the_passes_are_one_loop_in_the_compiled_decode_step(model):
         params, bufs, pool._cache, np.zeros(n, np.int32), np.ones(n, bool),
         samp, np.zeros(n, np.uint32), np.zeros(n, np.int32)).jaxpr
     calls, loops = _count(jaxpr, "pallas_call")
-    # one attention call site a layer, not a (pass, layer)
-    assert calls == LAYERS
+    # one attention call site a layer, not a (pass, layer), and beside it
+    # the layer's K/V write (``ops.pallas_decode._kv_write_call``)
+    assert calls == 2 * LAYERS
     over_passes = [(e, sub) for e, sub in loops
                    if _count(sub, "pallas_call")[0]]
     assert len(over_passes) == 1
     eqn, body = over_passes[0]
     assert eqn.primitive.name == "scan" and eqn.params["length"] == PASSES
-    assert _count(body, "pallas_call")[0] == LAYERS
+    assert _count(body, "pallas_call")[0] == 2 * LAYERS
     # the pools ride the loop as carries: K and V of every entry, whole
     pool_shape = pool._cache[0].k.shape
     carried = [v.aval.shape for v in body.invars[eqn.params["num_consts"]:]]

@@ -18,7 +18,9 @@ Pins the contracts the paged layout lives on:
   differ by float-reduction noise);
 - ``paged_cache_write`` puts the bytes where the plain
   ``pool.at[phys, :, off, :].set`` put them, and the decode step makes
-  nothing else of a pool's shape.
+  nothing else of a pool's shape; ``paged_kv_write``'s in-place kernel
+  (a decode-sized chunk into float pools, K and V in one call) leaves
+  the same bytes, in every case and in served pools.
 """
 import numpy as np
 import pytest
@@ -269,6 +271,91 @@ _WRITE_CASES = {
 }
 
 
+# The same writes by the in-place kernel (``ops.paged_kv_write`` under
+# ``route="pallas"``, interpreted off the chip): K and V in ONE call, a
+# row with its GROUP of ``g`` rows (8 float32, 16 bfloat16) of blocks of
+# 32.  ``idx(g, bs)`` puts rows at offsets 0, g - 1, g and bs - 1, chunks
+# across a group's edge and across a block's, a chunk past the table's
+# span; each case in float32 and bfloat16, on a pool of the chunk's heads
+# and on plane 1 of a pool of three planes
+_KERNEL_CASES = {
+    "L1_offsets_0_and_the_edges": dict(
+        idx=lambda g, bs: [0, g - 1, g, bs - 1, 2 * bs + 3], length=1),
+    "L4_across_a_group_and_a_block_edge": dict(
+        idx=lambda g, bs: [g - 2, bs - 2, 0, g], length=4),
+    "L8_the_longest_chunk": dict(
+        idx=lambda g, bs: [g - 3, bs - 5, 1, 2 * bs - 8], length=8),
+    "L4_past_the_tables_span": dict(
+        idx=lambda g, bs: [3 * bs - 2, g - 1, 5], length=4, past_span=2),
+    "inactive_rows_one_scratch_offset": dict(
+        idx=lambda g, bs: [6, 6, 1, 6], length=1, scratch=(1, 3)),
+}
+for _name, _spec in _KERNEL_CASES.items():
+    for _dtype in ("float32", "bfloat16"):
+        for _planes in (1, 3):
+            _WRITE_CASES["kernel_%s_%s_%s" % (
+                _name, _dtype, "planed" if _planes > 1 else "whole")] = \
+                dict(_spec, kernel=True, dtype=_dtype, planes=_planes, bs=32)
+
+
+def _kernel_write_case(spec, rng):
+    """A case of ``_KERNEL_CASES``: both pools through ``paged_kv_write``
+    forced onto the kernel, against the scatter of each and the same
+    thing said row by row."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import paged_kv_write
+    from paddle_tpu.ops.pallas_decode import write_group
+
+    dtype, planes, bs, length = (spec[k] for k in
+                                 ("dtype", "planes", "bs", "length"))
+    g = write_group(bs, jnp.dtype(dtype).itemsize)
+    assert g == {"float32": 8, "bfloat16": 16}[dtype]
+    idx = np.asarray(spec["idx"](g, bs), np.int32)
+    b, h, d, mb = len(idx), 2, 8, 3
+    nb = 1 + b * mb
+    table = 1 + np.arange(b * mb, dtype=np.int32).reshape(b, mb)
+    table[list(spec.get("scratch", ()))] = 0
+    phys, off = _write_addresses(table, idx, length, bs)
+    assert (phys == 0).sum() == spec.get("past_span", 0) \
+        + len(spec.get("scratch", ())) * length
+    repeats = "scratch" in spec
+    base = None if planes == 1 else h        # plane 1 of three
+    heads = slice(None) if base is None else slice(base, base + h)
+    pools = [jnp.asarray(rng.randn(nb, planes * h, bs, d) * 50, dtype)
+             for _ in range(2)]
+    news = [jnp.asarray(rng.randn(b, h, length, d), jnp.float32)
+            for _ in range(2)]
+    got = paged_kv_write(*pools, *news, jnp.asarray(phys), jnp.asarray(off),
+                         head_base=base, route="pallas")
+    plain = paged_kv_write(*pools, *news, jnp.asarray(phys),
+                           jnp.asarray(off), head_base=base,
+                           route="composition")
+    for before, new, out, scattered in zip(pools, news, got, plain):
+        assert out.dtype == before.dtype and out.shape == before.shape
+        want = np.array(before)
+        new = np.asarray(new.astype(before.dtype))
+        for bi in range(b):
+            for li in range(length):
+                want[phys[bi, li], heads, off[bi, li]] = new[bi, :, li]
+        out, scattered = np.asarray(out), np.asarray(scattered)
+        rows = np.arange(1 if repeats else 0, nb)
+        np.testing.assert_array_equal(out[rows], scattered[rows])
+        np.testing.assert_array_equal(out[rows], want[rows])
+        untouched = np.setdiff1d(np.arange(nb), phys.ravel())
+        np.testing.assert_array_equal(out[untouched],
+                                      np.asarray(before)[untouched])
+        if repeats:
+            # the scratch row holds one of the rows sent there, and the
+            # group's other rows are as they were
+            at = off[spec["scratch"][0], 0]
+            assert any(np.array_equal(out[0, heads, at], new[bi, :, 0])
+                       for bi in spec["scratch"])
+            others = np.setdiff1d(np.arange(bs), [at])
+            np.testing.assert_array_equal(
+                out[0][:, others], np.asarray(before)[0][:, others])
+
+
 @pytest.mark.parametrize("case", sorted(_WRITE_CASES))
 def test_paged_cache_write_is_the_plain_scatter_bit_for_bit(case):
     import jax.numpy as jnp
@@ -277,6 +364,8 @@ def test_paged_cache_write_is_the_plain_scatter_bit_for_bit(case):
 
     spec = _WRITE_CASES[case]
     rng = np.random.RandomState(sorted(_WRITE_CASES).index(case))
+    if spec.get("kernel"):
+        return _kernel_write_case(spec, rng)
     idx, length = spec["idx"], spec["length"]
     b = 3 if np.ndim(idx) == 0 else len(idx)
     h, bs, d, mb = 2, 4, 8, 3
@@ -335,22 +424,172 @@ def test_paged_cache_write_is_the_plain_scatter_bit_for_bit(case):
                        for r in sent)
 
 
-def test_paged_cache_write_drops_an_index_outside_the_pool():
+@pytest.mark.parametrize("how", ["scatter", "kernel-float32",
+                                 "kernel-bfloat16"])
+def test_paged_cache_write_drops_an_index_outside_the_pool(how):
     import jax.numpy as jnp
 
-    from paddle_tpu.ops import paged_cache_write
+    from paddle_tpu.ops import paged_cache_write, paged_kv_write
 
     rng = np.random.RandomState(0)
-    before = rng.randn(5, 2, 4, 8).astype(np.float32)
-    new = rng.randn(2, 2, 1, 8).astype(np.float32)
+    dtype = how.partition("-")[2] or "float32"
+    before = jnp.asarray(rng.randn(5, 2, 4, 8), dtype)
+    new = jnp.asarray(rng.randn(2, 2, 1, 8), dtype)
     phys = np.array([[5], [2]], np.int32)       # 5 is one past the pool
     off = np.array([[1], [3]], np.int32)
-    got = np.asarray(paged_cache_write(
-        jnp.asarray(before), jnp.asarray(new), jnp.asarray(phys),
-        jnp.asarray(off)))
-    want = before.copy()
-    want[2, :, 3] = new[1, :, 0]                # nothing clamped onto 4
-    np.testing.assert_array_equal(got, want)
+    if how == "scatter":
+        got = paged_cache_write(before, new, jnp.asarray(phys),
+                                jnp.asarray(off))
+    else:
+        # the kernel guards the copy: no group is read or written for
+        # the row, in either pool
+        got, other = paged_kv_write(before, before + 1, new, new,
+                                    jnp.asarray(phys), jnp.asarray(off),
+                                    route="pallas")
+        np.testing.assert_array_equal(np.asarray(other)[[0, 1, 3, 4]],
+                                      np.asarray(before + 1)[[0, 1, 3, 4]])
+    want = np.array(before)
+    want[2, :, 3] = np.asarray(new)[1, :, 0]    # nothing clamped onto 4
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_the_write_kernel_takes_its_slots_a_grid_step_at_a_time(monkeypatch):
+    # more groups than the kernel's VMEM budget holds (a verify chunk on a
+    # wide pool: ``tests/test_tpu_compile.py``'s gpt-verify) go a few
+    # slots a grid step; a budget of one slot's groups makes every slot a
+    # step of its own, and the pools come out the same
+    from paddle_tpu.ops import pallas_decode
+
+    spec = _WRITE_CASES[
+        "kernel_L4_across_a_group_and_a_block_edge_float32_planed"]
+    whole = lambda: _kernel_write_case(spec, np.random.RandomState(3))
+    whole()
+    h, g, d, length = 2, 8, 8, spec["length"]
+    monkeypatch.setattr(pallas_decode, "_KV_VMEM_BUDGET",
+                        2 * length * h * g * d * 4)
+    pallas_decode._kv_write_call.clear_cache()
+    try:
+        whole()
+    finally:
+        monkeypatch.undo()
+        pallas_decode._kv_write_call.clear_cache()
+
+
+def test_the_write_keeps_the_scatter_where_the_kernel_is_not_for(
+        monkeypatch):
+    # the route, from what the call can see: a prefill-shaped chunk and an
+    # int8 pool keep the scatter under every route; a float pool's
+    # decode-sized chunk takes the kernel when forced, and under "auto"
+    # on a TPU where the geometry compiles (a head of half a lane tile
+    # does not: the forced route raises its refusal)
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+
+    def pool(dtype, bs=32, d=128):
+        return jax.ShapeDtypeStruct((9, 2, bs, d), jnp.dtype(dtype))
+
+    fa.reset_backend_memo()
+    try:
+        for route, chunk, dtype, want in (
+                ("auto", 1, "float32", "scatter"),        # the CPU
+                ("composition", 1, "float32", "scatter"),
+                ("pallas", 1, "float32", "kernel"),
+                ("pallas", 8, "bfloat16", "kernel"),
+                ("pallas", 9, "bfloat16", "scatter"),
+                ("pallas", 1, "int8", "scatter")):
+            assert fa.paged_kv_write_route(pool(dtype), chunk, route) \
+                == want, (route, chunk, dtype)
+        with fa.decode_route("pallas"):
+            assert fa.paged_kv_write_route(pool("float32"), 4) == "kernel"
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        fa.reset_backend_memo()
+        assert fa.paged_kv_write_route(pool("bfloat16"), 1) == "kernel"
+        assert fa.paged_kv_write_route(pool("bfloat16"), 128) == "scatter"
+        assert fa.paged_kv_write_route(pool("int8"), 1) == "scatter"
+        # blocks of 8 are half a packed tile of bfloat16 rows
+        assert fa.paged_kv_write_route(pool("bfloat16", bs=8), 1) \
+            == "scatter"
+        assert fa.paged_kv_write_route(pool("float32", d=64), 1) \
+            == "scatter"
+        with pytest.raises(InvalidArgumentError, match="lane"):
+            fa.paged_kv_write_route(pool("float32", d=64), 1, "pallas")
+    finally:
+        fa.reset_backend_memo()
+
+
+def _served_model(kind):
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    if kind == "gpt":
+        return _tiny_model(), {}
+    from harness import cca_weights, looped_weights
+    from paddle_tpu.models import CCAMoELM, LoopedLM
+
+    name, build, weights = {
+        "looped": ("toy-looped.json", LoopedLM, looped_weights),
+        "cca": ("toy-cca.json", CCAMoELM, cca_weights)}[kind]
+    with open(os.path.join(bench, "configs", name)) as f:
+        cfg = json.load(f)
+    pt.seed(0)
+    m = build(**weights.model_kwargs(cfg))
+    m.eval()
+    weights.load_into(m, cfg, 11)
+    return m, {"vocab": cfg["vocab_size"]}
+
+
+@pytest.mark.parametrize("kind", ["gpt", "looped", "cca"])
+def test_a_served_run_on_the_write_kernel_leaves_the_scatters_bytes(
+        kind, monkeypatch):
+    # a pool served under ``route="pallas"`` (the attention AND the write
+    # on their kernels, interpreted) against the same pool with the write
+    # alone sent back to the scatters: every token and every byte of
+    # every K/V pool but the scratch block (where inactive slots land in
+    # no defined order).  Blocks of 16 and float32 pools: a group is half
+    # a block.  Against ``route="composition"`` the tokens are the same
+    # too; its pools differ in the last bits by the attention's route, not
+    # the write's
+    import importlib
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    model, info = _served_model(kind)
+    rng = np.random.RandomState(8)
+    prompts = [rng.randint(0, info.get("vocab", 128), (n,)).astype("int32")
+               for n in (5, 17, 9, 14)]
+
+    def serve(route):
+        pool = GenerationPool(model, max_len=64, slots=3, buckets=[32],
+                              cache_layout="paged", block_size=16,
+                              route=route)
+        outs = pool.generate(prompts, 12)
+        pools = [np.asarray(x)[1:] for entry in pool._cache
+                 if hasattr(entry, "table") for x in (entry.k, entry.v)]
+        return outs, pools, pool
+
+    toks, pools, pool = serve("pallas")
+    assert pool._entries_meta["kv_write"] == "kernel"
+    assert len(pools) == 2 * pool._entries_meta["kv_entries"]
+    monkeypatch.setattr(fa, "paged_kv_write_route",
+                        lambda *a, **k: "scatter")
+    want_toks, want_pools, _ = serve("pallas")
+    for got, want in zip(toks, want_toks):
+        np.testing.assert_array_equal(got, want)
+    for got, want in zip(pools, want_pools):
+        np.testing.assert_array_equal(got, want)
+    monkeypatch.undo()
+    plain_toks, _, plain = serve("composition")
+    assert plain._entries_meta["kv_write"] == "scatter"
+    for got, want in zip(toks, plain_toks):
+        np.testing.assert_array_equal(got, want)
 
 
 def test_pool_decode_makes_nothing_pool_shaped_but_the_writes(model):

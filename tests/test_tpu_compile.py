@@ -91,20 +91,30 @@ def test_pool_decode_on_the_v5e_moves_no_pool(one_chip, model, monkeypatch,
     monkeypatch.setattr(fa, "_backend_memo", "tpu")
     text = jax.jit(pool._pool_decode, donate_argnums=(2,)) \
         .lower(*shapes).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
+    # a float pool's K and V rows go by ONE kernel a layer beside the
+    # attention's (``ops.pallas_decode._kv_write_call``: both pools
+    # aliased in and out, so all that has a pool's shape is the step's
+    # parameters and the elements of the kernels' results); an int8
+    # pool keeps its four scatters a layer (K, V and their scales)
+    quant = cache_dtype == "int8"
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == LAYERS * (1 if quant else 2)
     pool_shape = pool._cache[0].k.shape
     assert pool_shape == (512, 16, 32, 128)
     assert chip_smoke.pool_shaped_moves(text, pool_shape) == []
     # and the writes are there, on the pool as the step was given it
     made = [op for _, op in chip_smoke.pool_shaped_ops(text, pool_shape)]
-    assert made.count("scatter") == 2 * LAYERS
+    assert made.count("scatter") == (2 * LAYERS if quant else 0)
+    assert made.count("get-tuple-element") == (0 if quant else 2 * LAYERS)
     # (copy-start/copy-done: the scheduler's prefetch of an int8 pool
     # into another memory space for the kernel, the parent's too; since
     # the kernel takes the pool whole it comes in slices, joined by a
     # ``ConcatBitcast`` custom call; no layout changes hands there)
-    assert set(made) <= {"parameter", "scatter", "fusion", "bitcast",
-                         "copy-start", "copy-done",
-                         "custom-call"}, sorted(set(made))
+    assert set(made) <= ({"parameter", "scatter", "fusion", "bitcast",
+                          "copy-start", "copy-done", "custom-call"}
+                         if quant else
+                         {"parameter", "get-tuple-element",
+                          "bitcast"}), sorted(set(made))
     for name, op in chip_smoke.pool_shaped_ops(text, pool_shape):
         if op == "custom-call":
             line = text[text.index("%%%s = " % name):].split("\n", 1)[0]
@@ -379,7 +389,9 @@ def test_hybrid_decode_step_on_the_v5e_updates_both_states_in_place(
     compiled = jax.jit(pool._pool_decode, donate_argnums=(2,)) \
         .lower(*shapes).compile()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # a scan step a Mamba layer, the attention layer's paged kernel and
+    # its K/V write
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
     made = {op for _, op in chip_smoke.pool_shaped_ops(text, ssm_shape)}
     assert made <= {"parameter", "get-tuple-element", "custom-call",
                     "bitcast"}, sorted(made)
@@ -396,7 +408,7 @@ def test_cca_step_on_the_v5e_keeps_both_entries_where_they_lie(
     small vocabulary: 64 slots of 24 blocks of 128 positions, 2 K/V heads of
     128 under 8 query heads (bfloat16), and beside each K/V entry a state
     of 1,280 + 1,280 + 128 values a slot.  One paged kernel a layer, the
-    K/V pool written where it lies, 16 experts on 64 rows through the
+    K/V pools written where they lie by one more, 16 experts on 64 rows through the
     every-expert route (no grouped matmul).  Then the 1,024 bucket's prefill: the
     flash kernel over the prompt's own keys, a layer."""
     import jax
@@ -432,8 +444,16 @@ def test_cca_step_on_the_v5e_keeps_both_entries_where_they_lie(
                                                      (64, 128)]
     text = jax.jit(pool._pool_decode, donate_argnums=(2,)) \
         .lower(*shapes).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
+    # the paged kernel and the K/V write's (64 rows of 2 heads, K and V
+    # in one call), a layer
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * LAYERS
     assert chip_smoke.pool_shaped_moves(text, kv_shape) == []
+    # (copy-start / copy-done and a ``ConcatBitcast``: at two layers the
+    # scheduler has room to prefetch one pool into another memory space
+    # for the kernel, the parent's program too; no layout changes hands)
+    made = {op for _, op in chip_smoke.pool_shaped_ops(text, kv_shape)}
+    assert made <= {"parameter", "get-tuple-element", "bitcast",
+                    "copy-start", "copy-done", "custom-call"}, sorted(made)
     assert "ragged-dot" not in text
     sess = pool._session
     ids = jax.ShapeDtypeStruct((1, 1024), np.int32, sharding=one_chip)
@@ -559,10 +579,11 @@ def test_looped_step_on_the_v5e_is_one_loop_that_moves_no_plane(
     of 64 positions, FOUR planes of 16 heads of 128 an entry, bfloat16, 16
     slots), two of its 48 layers, a small vocabulary: the passes are ONE
     ``while`` with the layers' two paged kernels in its body (not eight in
-    a row), each pool is written by a scatter where it lies (K and V of
-    every layer, inside the loop), and nothing of a pool's size, nor of a
-    plane's, is copied, sliced or re-laid, in the step or in the prefill's
-    row cache."""
+    a row), a layer's K and V pools are written by one kernel where they
+    lie, on the loop's own carry (a plane's rows at a traced head offset),
+    and nothing of a pool's size, nor of a plane's, is copied, sliced or
+    re-laid, in the step or in the prefill's row cache, whose longer chunk
+    keeps the scatters."""
     import jax
 
     from paddle_tpu.models import LoopedLM
@@ -594,12 +615,12 @@ def test_looped_step_on_the_v5e_is_one_loop_that_moves_no_plane(
     assert pool_shape == (81, 4 * 16, 64, 128)
     plane_shape = (81, 16, 64, 128)
     assert text.count(" while(") == 1
-    assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
+    # a layer's paged kernel and its K/V write, in the loop's body
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * LAYERS
     for shape in (pool_shape, plane_shape):
         assert chip_smoke.pool_shaped_moves(text, shape) == []
     made = [op for _, op in chip_smoke.pool_shaped_ops(text, pool_shape)]
-    assert made.count("scatter") == 2 * LAYERS
-    assert set(made) <= {"parameter", "scatter", "fusion", "bitcast",
+    assert set(made) <= {"parameter", "bitcast",
                          "get-tuple-element"}, sorted(set(made))
     assert [op for _, op in chip_smoke.pool_shaped_ops(text, plane_shape)] \
         == []
@@ -642,3 +663,67 @@ def test_the_planed_kernel_at_the_looped_cells_geometry_on_the_v5e(one_chip,
             q, k, v, t, p, 128 ** -0.5, head_base=base,
             plane_heads=16)).lower(*args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize(
+    "cell,pool_shape,rows,heads,lq,dtype,planed", [
+        ("gpt", (512, 16, 32, 128), 16, 16, 1, "float32", False),
+        ("gpt-verify", (512, 16, 32, 128), 16, 16, 8, "float32", False),
+        ("ouro", (81, 64, 64, 128), 16, 16, 1, "bfloat16", True),
+        ("zaya", (1537, 2, 128, 128), 64, 2, 1, "bfloat16", False),
+        ("jamba", (1153, 1, 128, 128), 64, 1, 1, "bfloat16", False),
+        ("sdar", (641, 4, 128, 128), 32, 4, 4, "bfloat16", False)])
+def test_the_kv_write_kernel_compiles_at_the_cells_on_the_v5e(
+        one_chip, cell, pool_shape, rows, heads, lq, dtype, planed):
+    """The K/V write alone under Mosaic at every cell that writes a float
+    paged pool: ``gpt-1p3b``'s (float32, blocks of 32: a group of 8 rows)
+    at one position and at a verify chunk's eight (more groups than the
+    budget holds: four slots a grid step), ``ouro-2p6b``'s (bfloat16, a
+    group of 16 rows, the plane at a TRACED head offset of a pool of four),
+    ``zaya1-8b``'s (64 rows of 2 heads), ``jamba2-3b``'s (one head) and
+    ``sdar-30b-a3b``'s (a block's four positions a slot, which share a
+    group).  One custom call, both donated pools aliased whole into the
+    results, nothing of a pool's shape made."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import pallas_decode
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+
+    dt = jnp.dtype(dtype)
+    assert pallas_decode.kv_write_mosaic_refusal(
+        pool_shape[3], pool_shape[2], dt.itemsize) is None
+    assert pallas_decode.write_group(pool_shape[2], dt.itemsize) \
+        == {"float32": 8, "bfloat16": 16}[dtype]
+    new = shape((rows, heads, lq, 128), dt)
+    at = shape((rows, lq), jnp.int32)
+    compiled = jax.jit(
+        lambda k, v, kn, vn, phys, off, base:
+        pallas_decode.paged_kv_write_kernel(
+            k, v, kn, vn, phys, off,
+            head_base=base if planed else None),
+        donate_argnums=(0, 1)).lower(
+        shape(pool_shape, dt), shape(pool_shape, dt), new, new, at, at,
+        shape((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert chip_smoke.pool_shaped_moves(text, pool_shape) == []
+    made = {op for _, op in chip_smoke.pool_shaped_ops(text, pool_shape)}
+    assert made <= {"parameter", "get-tuple-element", "bitcast"}, \
+        sorted(made)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == 2 * int(np.prod(pool_shape)) * dt.itemsize
+
+
+def test_the_kv_write_kernel_names_what_mosaic_refuses():
+    """A pool whose head is half a lane tile, and a bfloat16 pool of
+    blocks of 8 (half a packed tile of rows): refused by name, so the
+    auto route keeps the scatter there and a forced route raises."""
+    from paddle_tpu.ops import pallas_decode
+
+    assert "lane" in pallas_decode.kv_write_mosaic_refusal(64, 32, 4)
+    assert "rows" in pallas_decode.kv_write_mosaic_refusal(128, 8, 2)
+    assert pallas_decode.kv_write_mosaic_refusal(128, 8, 4) is None
+    assert pallas_decode.write_group(8, 2) == 8     # the interpreter's

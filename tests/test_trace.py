@@ -969,11 +969,11 @@ def test_recorder_tail_dicts_bounded():
 # ``tick.decode``'s meta by kind of pool (paged, so the block counts ride)
 _DECODE_META = {
     "plain": {"live", "slots", "greedy", "ahead", "live_blocks",
-              "table_blocks", "kv_entries", "kv_planes"},
+              "table_blocks", "kv_entries", "kv_planes", "kv_write"},
     "speculative": {"spec_k", "live", "slots", "ahead"},
     "block": {"live", "slots", "ahead", "kind", "rows", "store", "denoise",
               "committed", "tokens_per_forward", "live_blocks",
-              "table_blocks", "kv_entries", "kv_planes",
+              "table_blocks", "kv_entries", "kv_planes", "kv_write",
               # its model has an expert layer (PR 45)
               "moe_route", "experts_held", "experts_read_expected"},
 }

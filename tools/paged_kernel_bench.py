@@ -34,6 +34,18 @@ take at 819 GB/s as a share of the call's.  A time comes from a TPU
 only: anywhere else the script stops, unless ``--cpu-toy`` asks for a
 rehearsal of its control flow at toy sizes under the interpreter, which
 prints no time.
+
+``--write`` times the K/V WRITE alone instead (``ops.flash_attention.
+paged_kv_write``: a layer's K and V rows into their donated pools, the
+``--calls`` writes chained on the pools as a model's layers are not, so
+each waits for the one before): the two scatters (``scatter_ms``) against
+the one kernel (``kernel_ms``; ``refused`` or ``scatter by design`` where
+it does not run; a tree without the kernel, ``--tree _checkout/parent``,
+times its scatters alone), each beside the bytes it moves at 819 GB/s
+(the scatter the new rows once, the kernel the rows' groups there and
+back) as a share of its time, at the cell's mix of positions and for
+``gpt`` at ``chat`` (fifteen inactive slots on the scratch block at one
+offset).  PERF.md section 6, PR 48, has its table.
 """
 from __future__ import annotations
 
@@ -97,6 +109,8 @@ def main(argv=None) -> int:
                     help="kernels chained in one program")
     ap.add_argument("--repeats", type=int, default=20)
     ap.add_argument("--cpu-toy", action="store_true")
+    ap.add_argument("--write", action="store_true",
+                    help="time the K/V write alone, scatter against kernel")
     args = ap.parse_args(argv)
 
     sys.path.insert(0, os.path.abspath(args.tree))
@@ -117,6 +131,36 @@ def main(argv=None) -> int:
     calls = 2 if args.cpu_toy else args.calls
     if args.kv_mib is not None and hasattr(pd, "_PAGED_TILE_ENTRIES"):
         pd._KV_VMEM_BUDGET = args.kv_mib * 1024 * 1024
+
+    def write_chain(route, planes=1, laid=None, hkv=None, nb=None):
+        """``calls`` writes of K and V rows, each into the pools the one
+        before returned (donated: updated where they lie)."""
+        write = getattr(fa, "paged_kv_write", None)
+
+        def run(k, v, k_new, v_new, phys, off):
+            for i in range(calls):
+                r = i % planes
+                at = {"head_base": jnp.int32(r * hkv)} \
+                    if laid == "heads" else {}
+                where = phys + r * nb if laid == "blocks" else phys
+                if write is None:   # a tree from before the kernel
+                    k, v = (fa.paged_cache_write(p, x, where, off, **at)
+                            for p, x in ((k, k_new), (v, v_new)))
+                else:
+                    k, v = write(k, v, k_new, v_new, where, off,
+                                 route=route, **at)
+            return k, v
+        return jax.jit(run, donate_argnums=(0, 1))
+
+    def write_ms(fn, pools, rest):
+        pools = fn(*pools, *rest)
+        jax.block_until_ready(pools)
+        start = time.perf_counter()
+        for _ in range(args.repeats):
+            pools = fn(*pools, *rest)
+        jax.block_until_ready(pools)
+        return pools, \
+            (time.perf_counter() - start) / (args.repeats * calls) * 1e3
 
     def chain(route, planes=1, laid=None, hkv=None, nb=None):
         def run(q, k, v, table, q_pos, *scales):
@@ -171,6 +215,65 @@ def main(argv=None) -> int:
                 k_, (pnb, phkv, bs, d), jnp.float32).astype(dtype)
                 for k_ in (kk, kv))
         table = jnp.asarray(1 + np.arange(b * mb).reshape(b, mb), jnp.int32)
+        if args.write:
+            # each row's next ``lq`` positions, as the cached forwards
+            # address them; an inactive slot writes the scratch block
+            pools = (k_pool, v_pool)
+            k_new, v_new = (jax.random.normal(
+                k_, (b, hkv, lq, d), jnp.float32).astype(dtype)
+                for k_ in jax.random.split(kq))
+            for label, ctx in contexts_of(name, b, lq, mb, bs, mix).items():
+                if label not in (args.context or ("mix", "chat")):
+                    continue
+                pos = np.asarray([[min(c, mb * bs - lq) + t
+                                   for t in range(lq)] for c in ctx])
+                live = np.asarray([c > lq for c in ctx])[:, None]
+                phys = np.where(live, np.asarray(table)[
+                    np.arange(b)[:, None], pos // bs], 0).astype(np.int32)
+                off = np.where(live, pos % bs, 0).astype(np.int32)
+                rest = (k_new, v_new, jnp.asarray(phys), jnp.asarray(off))
+                rows_bytes = 2 * b * lq * hkv * d * dtype.itemsize
+                line = {
+                    "tree": os.path.relpath(os.path.abspath(args.tree), ROOT),
+                    "device": "%s %s" % (device.platform, device.device_kind),
+                    "geometry": "toy" if args.cpu_toy else name,
+                    "what": "kv_write", "context": label, "calls": calls,
+                    "rows": b * lq, "heads": hkv, "rows_bytes": rows_bytes}
+                takes = "absent"
+                if hasattr(fa, "paged_kv_write_route"):
+                    with fa.decode_route("pallas"):
+                        try:
+                            takes = fa.paged_kv_write_route(k_pool, lq)
+                        except Exception as e:  # noqa: BLE001 - by name
+                            takes = "refused: " + str(e)[:200]
+                line["kernel"] = takes if takes != "scatter" \
+                    else "scatter by design"
+                if takes == "kernel":
+                    line["group_bytes"] = 2 * rows_bytes * pd.write_group(
+                        bs, dtype.itemsize)
+                wrote = {}
+                for key, route in (("scatter", "composition"),
+                                   ("kernel", "pallas")):
+                    if key == "kernel" and takes != "kernel":
+                        continue
+                    fn = write_chain(route, **how)
+                    # what one chain leaves in the pools, the scratch
+                    # block left out (rows that repeat land in no order)
+                    wrote[key] = [p[1:] for p in fn(
+                        *(jnp.copy(p) for p in pools), *rest)]
+                    pools, ms = write_ms(fn, pools, rest)
+                    if device.platform == "tpu":
+                        moved = line["group_bytes"] if key == "kernel" \
+                            else rows_bytes
+                        line[key + "_ms"] = ms
+                        line[key + "_bytes_share"] = \
+                            moved / HBM_BYTES_PER_S * 1e3 / ms
+                if len(wrote) == 2:
+                    line["same_bits"] = all(
+                        bool(jnp.array_equal(a, b)) for a, b in
+                        zip(wrote["scatter"], wrote["kernel"]))
+                print(json.dumps(line), flush=True)
+            continue
         plain = chain("composition", **how)
         for cap in args.entries or [None]:
             if cap is not None and hasattr(pd, "_PAGED_TILE_ENTRIES"):
