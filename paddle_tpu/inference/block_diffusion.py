@@ -6,39 +6,64 @@ A sequence is cut into blocks of ``B = model.block_length`` positions
 aligned to position 0.  The prompt's whole blocks are prefilled once
 (bucketed, batch 1, under the block-causal mask) and spliced into the
 pool's cache.  Every further block starts as the prompt's remaining
-tokens, held fixed, and mask ids; the pool's ONE step executable runs the
-model over every slot's current block, ``[slots, B]`` positions against
-the cache of all earlier blocks, and is of two kinds by its per-slot
-control data:
+tokens, held fixed, and mask ids, and is denoised in place: of the
+positions still masked, the ``n`` with the largest softmax probability
+take their argmax token (static low-confidence remasking: a committed
+token is never masked again).  A block of ``m`` positions to fill takes
+``min(T, m)`` such steps (``T = model.denoise_steps``), step ``t``
+committing ``m // steps`` tokens, one more in the first ``m % steps``.
 
-- **denoise**: of the positions still masked, the ``n`` with the largest
-  softmax probability take their argmax token (static low-confidence
-  remasking: a committed token is never masked again).  The block's noisy
-  K/V is written at the slot's cache index, which does not advance, so
-  the next step overwrites it.  A block of ``m`` positions to fill takes
-  ``min(T, m)`` such steps (``T = model.denoise_steps``), step ``t``
-  committing ``m // steps`` tokens, one more in the first ``m % steps``.
-- **store**: the block is clean; one more forward writes its K/V for
-  good and the index advances by ``B``.  A request's last block is not
-  stored: nothing comes after it.
+The pool's ONE step executable runs the model over ``[slots, 2B]``
+positions, two blocks a slot at the slot's cache index, against the cache
+of all earlier blocks.  There is one kind of forward; a flag of the
+per-slot control data says what its front half is:
 
-Slots are not in lockstep: in one tick some denoise and some store.  One
-packed upload and one packed download a tick.  Tokens leave in position
-order as the committed prefix of the block grows; budgets, EOS and finish
-count tokens, not steps.  A last block commits only the positions that
-were asked for; the rest stay mask ids and are never delivered.
+- it **carries a store**: rows ``[clean block n | first state of block
+  n + 1]`` at the index, which stands at block ``n``'s start, where its
+  denoising left it.  All ``2B`` rows' K/V are written before the
+  attention reads the pools, so block ``n + 1``'s rows see block ``n``'s
+  clean K/V under the block-causal mask, the index advances by ``B``
+  (block ``n`` is kept for good), and the logits, the confidence ranking
+  and the commit are those of the back ``B`` rows.  A clean block's store
+  costs no forward of its own: it rides the next block's first step.
+- it **carries none** (a block's later denoising steps; a request's first
+  block, whose predecessors the prefill wrote): rows ``[current block |
+  dead rows]``.  The block's noisy K/V is written at the index, which does
+  not advance, so the next step overwrites it.  The dead rows repeat the
+  block's tokens a block further on.  Nothing of theirs is read: the head
+  runs on the front ``B`` rows alone, and their K/V lies past the current
+  block, where no live row's mask reaches and where the next carried
+  store writes ``2B`` rows over it (past the table's span or the dense
+  cache's end it goes to the scratch block or is dropped, as a
+  speculative tail's does).  They stay finite on every route: a dead row
+  sees what its position sees, its own K/V included, never nothing.
+
+A request's last block is not stored: nothing comes after it.  Slots are
+not in lockstep: in one tick some forwards carry a store and some do not.
+One packed upload and one packed download a tick.  Tokens leave in
+position order as the committed prefix of the block grows; budgets, EOS
+and finish count tokens, not steps.  A last block commits only the
+positions that were asked for; the rest stay mask ids and are never
+delivered.
+
+The chunk is ``2B`` positions whatever ``B``.  At ``B = 4`` its 8 rows
+are what the fused paged kernel and the in-place K/V write take
+(``ops.pallas_decode.MAX_KERNEL_QUERY_CHUNK`` is 8); at a ``B`` above 4
+the gates that are there send the chunk to the composition and the
+scatters (no benchmark cell runs such a ``B``).
 
 The host runs one step behind the device (``GenerationPool.step``,
 docs/DESIGN.md §5t), so a block's state, its tokens and what of it is
 still masked, feeds back ON THE DEVICE: the step's packed output is the
-next step's input.  The upload holds only what the host knows ahead of
-the download: how many tokens the step commits, whether it stores, which
-rows are live, and the rows that START a block (a prompt's trailing
-partial block, the all-masked block after a store), which the step takes
-from the host.  With a fixed number of denoising steps and no confidence
-threshold all of that is settled when a request is admitted: ``_Cursor``
-walks it on the launch side, a step ahead of ``_Block``, which follows
-the downloads.
+next step's input, and after a block's last denoising step it holds the
+clean tokens the carried store needs.  The upload holds only what the
+host knows ahead of the download: how many tokens the step commits,
+whether it carries a store, which rows are live, and the rows that START
+a block (a prompt's trailing partial block, the all-masked block after a
+clean one), which the step takes from the host.  With a fixed number of
+denoising steps and no confidence threshold all of that is settled when a
+request is admitted: ``_Cursor`` walks it on the launch side, a step
+ahead of ``_Block``, which follows the downloads.
 
 What this variant does not do is refused at construction with a typed
 error (``docs/DESIGN.md``): prefix sharing, chunked prefill, preemption
@@ -96,7 +121,10 @@ class _Cursor:
     denoising steps still to launch; ``left`` is what the request may
     fill after this block; ``row`` is the block's first state, which
     the next launch hands to the device (None once it has: the state
-    then feeds back there); ``pos`` is where the block starts."""
+    then feeds back there); ``pos`` is where the block starts.  Once
+    ``plan`` is empty the block is clean, and the next launch, if the
+    request may fill more, is the NEXT block's first step with the store
+    of this one on it."""
 
     __slots__ = ("plan", "left", "row", "pos")
 
@@ -149,7 +177,9 @@ class BlockDiffusionPool(GenerationPool):
             raise InvalidArgumentError(
                 "cache_dtype='int8': generation by diffusion over blocks "
                 "keeps a float K/V cache (no grouped-head int8 kernel)")
-        self._B = self._rows_a_slot = int(model.block_length)
+        self._B = int(model.block_length)
+        # the step's chunk: two blocks a slot (module docstring)
+        self._rows_a_slot = 2 * self._B
         self._T = int(model.denoise_steps)
         self._mask_id = int(model.mask_token_id)
         if int(max_len) % self._B:
@@ -190,7 +220,7 @@ class BlockDiffusionPool(GenerationPool):
         self._no_row = [0] * (2 * self._B)
         # what the step did, ever: read by the engine's counters
         self.forwards_denoise = 0
-        self.forwards_store = 0
+        self.stores_carried = 0
         self.tokens_committed = 0
 
     # -- traced bodies ---------------------------------------------------
@@ -211,28 +241,39 @@ class BlockDiffusionPool(GenerationPool):
             cache, jnp.asarray(whole, jnp.int32), self.max_len)
 
     def _block_step(self, param_vals, buf_vals, cache, carry, ctl):
-        """One forward over every slot's current block.  ``carry`` is
-        the last step's packed output, int32 ``[slots, 2B]``: every
-        block's tokens and which of them are still to fill.  ``ctl`` is
-        the tick's packed upload, int32 ``[slots, 2B + 4]``: a block's
-        first state in ``carry``'s form, how many tokens to commit (0
-        for a store), whether the index advances (a store), whether the
-        slot is live, and whether the slot takes its state from ``ctl``
-        (it starts a block) and not from ``carry``.  Returns the cache
-        and the packed output ``[slots, 2B]``, the tokens after the
-        commit and what is still masked: the download, and the next
-        step's ``carry``."""
+        """One forward over two blocks a slot, ``[slots, 2B]`` rows at
+        the cache index.  ``carry`` is the last step's packed output,
+        int32 ``[slots, 2B]``: every block's tokens and which of them
+        are still to fill.  ``ctl`` is the tick's packed upload, int32
+        ``[slots, 2B + 4]``: a block's first state in ``carry``'s form,
+        how many tokens to commit, whether the forward carries the store
+        of the block in ``carry``, whether the slot is live, and whether
+        the slot takes its state from ``ctl`` (it starts a block) and not
+        from ``carry``.  A forward that carries a store runs ``carry``'s
+        clean tokens in front of the new block, denoises the back rows
+        and moves the index past the stored block; one that carries none
+        denoises the front rows and repeats them behind as dead rows
+        (module docstring).  Returns the cache and the packed output
+        ``[slots, 2B]``, the denoised block's tokens after the commit and
+        what is still masked: the download, and the next step's
+        ``carry``."""
         bl = self._B
         state = jnp.where(ctl[:, 2 * bl + 3:] != 0, ctl[:, :2 * bl], carry)
         toks, masked = state[:, :bl], state[:, bl:] != 0
         count = ctl[:, 2 * bl]
-        advance = (ctl[:, 2 * bl + 1] != 0) & (ctl[:, 2 * bl + 2] != 0)
         active = ctl[:, 2 * bl + 2] != 0
+        store = (ctl[:, 2 * bl + 1] != 0) & active
+        ids = jnp.concatenate(
+            [jnp.where(store[:, None], carry[:, :bl], toks), toks], axis=1)
+        # how far behind the index the denoised block stands: a stored
+        # block's length, which is also what the index gains
+        behind = jnp.where(store, bl, 0)
         given = cache
         if self._layout.paged:
             cache = self._masked_tables(cache, active)
+        # the head on the denoised block's rows alone
         logits, new_cache = self._session._run_model(
-            param_vals, buf_vals, toks, cache)
+            param_vals, buf_vals, ids, cache, last=behind)
         with jax.named_scope("sample"):
             lf = logits.astype(jnp.float32)
             best = jnp.argmax(lf, axis=-1).astype(jnp.int32)
@@ -250,9 +291,10 @@ class BlockDiffusionPool(GenerationPool):
                 [jnp.where(commit, best, toks),
                  (masked & ~commit).astype(jnp.int32)], axis=1)
         with jax.named_scope("cache_freeze"):
-            # the forward moved every index by B: keep that for a live
-            # slot's store and undo it everywhere else
-            new_cache = self._layout.freeze_step(new_cache, given, advance)
+            # the forward moved every index by 2B: a carried store keeps
+            # B of it, the block now stored, and nothing else moves
+            new_cache = [c._replace(index=g.index + behind)
+                         for c, g in zip(new_cache, given)]
         if cache is not given:
             new_cache = [c._replace(table=g.table)
                          for c, g in zip(new_cache, given)]
@@ -321,7 +363,7 @@ class BlockDiffusionPool(GenerationPool):
 
     def _launchable(self, slot: int, state) -> bool:
         # until the last denoising step of the request's last block has
-        # been launched (that block is not stored)
+        # been launched (that block is never stored)
         cur = self._cursors[slot]
         return bool(cur.plan) or cur.left > 0
 
@@ -331,18 +373,18 @@ class BlockDiffusionPool(GenerationPool):
         rows = [self._no_row + [0, 0, 0, 0]] * self.slots
         for slot, st in self._rows:
             cur = self._cursors[slot]
+            store = 0
+            if not cur.plan:
+                # the block is clean: the next starts all masked a block
+                # further on, and its first step carries this one's store
+                blk = self._new_block((), cur.left)
+                cur.plan, cur.left = list(blk.plan), cur.left - sum(blk.plan)
+                cur.row, cur.pos = blk.toks + blk.masked, cur.pos + self._B
+                store = 1
             front = self._no_row if cur.row is None else cur.row
             take = 0 if cur.row is None else 1
             cur.row = None
-            if cur.plan:
-                rows[slot] = front + [cur.plan.pop(0), 0, 1, take]
-                continue
-            # the block is clean: store it; the next starts all masked
-            # where the index will stand
-            rows[slot] = front + [0, 1, 1, take]
-            blk = self._new_block((), cur.left)
-            cur.plan, cur.left = list(blk.plan), cur.left - sum(blk.plan)
-            cur.row, cur.pos = blk.toks + blk.masked, cur.pos + self._B
+            rows[slot] = front + [cur.plan.pop(0), store, 1, take]
         return np.array(rows, np.int32)
 
     # -- the tick's hooks: ONE forward over every live slot's block ------
@@ -353,23 +395,23 @@ class BlockDiffusionPool(GenerationPool):
 
     def _decode_meta(self, params, bufs, ctl) -> dict:
         # a denoising step commits exactly what its plan asks: known
-        # before the dispatch, so the span carries it
+        # before the dispatch, so the span carries it.  ``store`` counts
+        # the slot-forwards that do nothing but store: there is none
         bl, live = self._B, len(self._rows)
-        stores = int(ctl[:, 2 * bl + 1].sum())
         committed = int(ctl[:, 2 * bl].sum())
-        kind = "store" if stores == live else \
-            "denoise" if not stores else "mixed"
-        meta = dict(live=live, slots=self.slots, kind=kind,
-                    rows=live * bl, store=stores, denoise=live - stores,
+        meta = dict(live=live, slots=self.slots, rows=live * 2 * bl,
+                    store=0, stores_carried=int(ctl[:, 2 * bl + 1].sum()),
                     committed=committed,
                     tokens_per_forward=committed / live)
         if self._layout.paged:
-            # every row of a block sees to the block's end; the cursor
-            # of a row that stores stands a block further already
+            # the chunk's last row sees to the end of its block, two
+            # blocks from the index; the cursor of a row that carries a
+            # store stands a block further already
             bs = self._block_size
             meta["live_blocks"] = sum(
-                (self._cursors[slot].pos - 1
-                 + bl * (1 - int(ctl[slot, 2 * bl + 1]))) // bs + 1
+                min((self._cursors[slot].pos - 1
+                     + bl * (2 - int(ctl[slot, 2 * bl + 1]))) // bs + 1,
+                    self._max_blocks)
                 for slot, _ in self._rows)
             meta["table_blocks"] = self.slots * self._max_blocks
             meta.update(self._entries_meta)
@@ -383,22 +425,20 @@ class BlockDiffusionPool(GenerationPool):
 
     def _deliver(self, out: np.ndarray) -> None:
         """Take the step's download into the block of every row of it
-        that is still live: note the commits, let the tokens of the
-        grown committed prefix go in position order (``_commit``
-        finishes on EOS or budget), and start the next block after a
-        store."""
+        that is still live: start the next block where the last was
+        clean (the step carried its store), note the commits, and let
+        the tokens of the grown committed prefix go in position order
+        (``_commit`` finishes on EOS or budget)."""
         bl = self._B
         rows = out.tolist()
-        live, stores, committed = len(self._rows), 0, 0
         for slot, st in self._rows:
             blk = self._blocks[slot]
             if not blk.plan:
-                # that was the store: the block's K/V is kept, the index
-                # stands at the next block, which starts all masked
-                stores += 1
-                self._blocks[slot] = self._new_block((), st.remaining)
-                continue
-            committed += blk.plan.pop(0)
+                # the block was clean: this step kept its K/V, the index
+                # stands at the next block, which started all masked
+                self.stores_carried += 1
+                blk = self._blocks[slot] = self._new_block((), st.remaining)
+            self.tokens_committed += blk.plan.pop(0)
             blk.step += 1
             toks, masked = rows[slot][:bl], rows[slot][bl:]
             for i in range(bl):
@@ -407,9 +447,7 @@ class BlockDiffusionPool(GenerationPool):
             blk.toks, blk.masked = toks, masked
             self._commit(slot, self._leaving(blk))
             self.token_commit_step = None
-        self.forwards_store += stores
-        self.forwards_denoise += live - stores
-        self.tokens_committed += committed
+        self.forwards_denoise += len(self._rows)
 
     def _leaving(self, blk: _Block):
         """The tokens of ``blk``'s committed prefix that have not left
@@ -447,7 +485,8 @@ class BlockDiffusionPool(GenerationPool):
                 + self._insert_jit.compiles)
 
     def block_stats(self) -> dict:
-        """Forwards by kind and tokens committed, ever."""
+        """Slot-forwards, those of them that carried a store, and tokens
+        committed, ever."""
         return {"forwards_denoise": self.forwards_denoise,
-                "forwards_store": self.forwards_store,
+                "stores_carried": self.stores_carried,
                 "tokens_committed": self.tokens_committed}

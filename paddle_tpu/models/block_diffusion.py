@@ -18,10 +18,13 @@ where ``j // block_length <= i // block_length``.
 Generation is the pool's (``inference.BlockDiffusionPool``): a block of
 ``block_length`` positions starts as mask ids, a denoising step runs this
 model over the block against the cache of the earlier blocks and commits
-the most confident positions, and once the block is clean one more
-forward stores its K/V.  The model declares that through ``generation``
-and ``block_length``/``mask_token_id``/``denoise_steps``; the serving
-engine reads them and picks the pool.
+the most confident positions, and once the block is clean its K/V is
+written by the forward that first denoises the NEXT block: that forward
+runs the clean block and the new one side by side, ``2 * block_length``
+rows under the one block-causal mask, and asks the head for the new
+block's rows alone (``forward(..., last=)``).  The model declares that
+through ``generation`` and ``block_length``/``mask_token_id``/
+``denoise_steps``; the serving engine reads them and picks the pool.
 
 Built from ``nn.Layer``s, so a compiled step carries the module tree as
 scopes (``layers/3/self_attn/q_proj``, ``layers/3/moe/experts``,
@@ -84,7 +87,11 @@ class BlockDiffusionMoELM(Layer):
     """See the module docstring.  ``forward(ids, cache=None)`` gives
     logits ``[B, L, V]`` under the block-causal mask, and with a
     ``gen_decode_cache`` pytree ``(logits, new_cache)`` for the chunk at
-    the cache index, as ``TransformerLM`` does."""
+    the cache index, as ``TransformerLM`` does.  ``last`` (with a cache;
+    int32 ``[B]``, may be traced): the head runs on the ``block_length``
+    rows of the chunk that start at row ``last[b]`` and on no other,
+    logits ``[B, block_length, V]``: a chunk of two blocks of which one
+    is denoised pays the vocabulary's product for that one."""
 
     #: the engine picks its pool by this (``inference.BlockDiffusionPool``)
     generation = "block_diffusion"
@@ -173,8 +180,14 @@ class BlockDiffusionMoELM(Layer):
             new.append(c)
         return self.final_norm(h), new
 
-    def forward(self, input_ids, cache=None):
+    def forward(self, input_ids, cache=None, last=None):
         if cache is None:
             return self.lm_head(self.encode(input_ids))
         h, cache = self.encode(input_ids, cache)
+        if last is not None:
+            with jax.named_scope("lm_head"):
+                rows = jnp.asarray(last, jnp.int32)[:, None] \
+                    + jnp.arange(self.block_length, dtype=jnp.int32)
+                h = Tensor(jnp.take_along_axis(h.value, rows[:, :, None],
+                                               axis=1), stop_gradient=True)
         return self.lm_head(h), cache

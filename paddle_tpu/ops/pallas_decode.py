@@ -842,17 +842,24 @@ def _kv_write_body(n: int, lq: int, h: int, g: int, nb: int, planed: bool):
             refs[int(planed):]
         first = pl.program_id(0) * (n * lq)
 
-        def lead(slot, l):
+        def lead(slot, l, named):
             """The buffer (this step's) that row ``l`` of ``slot`` is
             put into: its own, or that of the slot's first row in the
-            same group."""
+            same group.  ``named`` holds the (block, group) of the
+            slot's rows before ``l`` and takes row ``l``'s: each is read
+            ONCE, not once a pair of rows (a chunk of 8 compares 28 pairs
+            a slot, and every ``//`` is lowered through a traced
+            ``sign``: an offset is not negative, so the truncating
+            division is the floor).  A chunk of one row names nothing."""
             i = slot * lq + l
             at = first + i
             j = i
+            if lq > 1:
+                named.append((phys_ref[at],
+                              jax.lax.div(off_ref[at], jnp.int32(g))))
             for m in range(l - 1, -1, -1):
-                same = jnp.logical_and(
-                    phys_ref[at - l + m] == phys_ref[at],
-                    off_ref[at - l + m] // g == off_ref[at] // g)
+                same = jnp.logical_and(named[m][0] == named[l][0],
+                                       named[m][1] == named[l][1])
                 j = jnp.where(same, i - l + m, j)
             return i, j
 
@@ -860,8 +867,9 @@ def _kv_write_body(n: int, lq: int, h: int, g: int, nb: int, planed: bool):
             """``act(i, j)`` on every in-pool row of the step that
             ``which`` keeps."""
             def slot_rows(slot, carry):
+                named = []
                 for l in range(lq):
-                    i, j = lead(slot, l)
+                    i, j = lead(slot, l, named)
                     blk = phys_ref[first + i]
                     ok = jnp.logical_and(blk >= 0, blk < nb)
 

@@ -710,17 +710,19 @@ class ServingEngine:
             "serving_acceptance_rate",
             "accepted draft tokens / drafted (speculative pool)") \
             if hasattr(self._pool, "acceptance_stats") else None
-        # block-diffusion pools: forwards by kind and tokens committed
-        # (a step commits 0..block_length tokens a sequence)
+        # block-diffusion pools: slot forwards, those that carried a
+        # clean block's store, and tokens committed (a step commits
+        # 0..block_length tokens a sequence)
         self._c_block = None
         if hasattr(self._pool, "block_stats"):
             self._c_block = {
                 "forwards_denoise": m.counter(
                     "serving_block_forwards_denoise_total",
                     "slot forwards that denoised a block"),
-                "forwards_store": m.counter(
-                    "serving_block_forwards_store_total",
-                    "slot forwards that stored a clean block's K/V"),
+                "stores_carried": m.counter(
+                    "serving_block_stores_carried_total",
+                    "slot forwards that also stored the clean block "
+                    "before theirs"),
                 "tokens_committed": m.counter(
                     "serving_block_tokens_committed_total",
                     "tokens committed by denoising steps")}

@@ -88,15 +88,28 @@ def pool_of(model, layout, **kw):
                               **kw)
 
 
+def prefilled(pool, prompt):
+    """``(params, bufs, cache)``: the batch-1 cache of the prompt's whole
+    blocks, through the pool's own bucketed prefill."""
+    params, bufs = pool._session._state_vals()
+    padded = np.zeros((1, pool._session._bucket_for(len(prompt))), np.int32)
+    padded[0, :len(prompt)] = prompt
+    return params, bufs, pool._prefill_jit(
+        params, bufs, jnp.asarray(padded), len(prompt) // 4 * 4)
+
+
 @pytest.mark.parametrize("prompt_len", [8, 9, 11])
 @pytest.mark.parametrize("layout", ["dense", "paged"])
-def test_prefill_denoise_and_store_equal_the_references_replay(
+def test_prefill_and_the_two_block_forward_equal_the_references_replay(
         model, weights, layout, prompt_len):
     """Through the pool's own executables and caches: the bucketed
     prefill, then for every state of every block the forward that
-    denoises it, then the forward that stores it, each block's logits
-    against the reference's one masked forward.  The output (10 tokens)
-    ends inside a block for every prompt length here."""
+    denoises it, two blocks of rows as the step runs them: a block's
+    first state behind the clean block before it, whose store it carries
+    (the index then moves past that block), every other state in front
+    of its own repeat.  The head is asked for the state's rows alone, and
+    they are held against the reference's one masked forward.  The output
+    (10 tokens) ends inside a block for every prompt length here."""
     prompt, max_new = prompt_of(prompt_len), 10
     tokens, steps = ref.generate(weights, prompt, max_new, SIZES)
     rows = ref.replay_rows(prompt, tokens, steps, SIZES)
@@ -104,38 +117,164 @@ def test_prefill_denoise_and_store_equal_the_references_replay(
         weights, rows["ids"], SIZES, pos=rows["pos"],
         allow=jnp.asarray(rows["allow"])))
     pool = pool_of(model, layout)
-    params, bufs = pool._session._state_vals()
-    bucket = pool._session._bucket_for(prompt_len)
-    padded = np.zeros((1, bucket), np.int32)
-    padded[0, :prompt_len] = prompt
+    params, bufs, cache = prefilled(pool, prompt)
     whole = prompt_len // 4 * 4
-    cache = pool._prefill_jit(params, bufs, jnp.asarray(padded), whole)
-    run = jax.jit(lambda ids, c: pool._session._run_model(params, bufs,
-                                                          ids, c))
-    seen, start = 0, whole
+    run = jax.jit(lambda ids, c, last: pool._session._run_model(
+        params, bufs, ids, c, last=last))
+    seen, start, carried = 0, whole, 0
     clean = list(prompt) + tokens
     for st in rows["states"]:
         at = st["offset"]
         block_start = int(rows["pos"][at])
-        if block_start != start:
-            # the block before is clean: store it, the index advances
-            _, cache = run(jnp.asarray([clean[start:start + 4]]), cache)
-            start = block_start
+        state = rows["ids"][at:at + 4].tolist()
         assert int(np.asarray(cache[0].index).reshape(-1)[0]) == start
-        logits, noisy = run(jnp.asarray(rows["ids"][None, at:at + 4]),
-                            cache)
+        if block_start != start:
+            # the block before is clean: this forward carries its store
+            logits, new = run(jnp.asarray([clean[start:start + 4] + state]),
+                              cache, jnp.asarray([4]))
+            start, carried = block_start, carried + 1
+        else:
+            logits, new = run(jnp.asarray([state + state]), cache,
+                              jnp.asarray([0]))
+        assert logits.shape == (1, 4, CFG["vocab_size"])
         assert np.abs(np.asarray(logits)[0] - want[at:at + 4]).max() < TOL
         seen += 1
-        # as the pool's step leaves it: the noisy K/V written, the index
-        # where it was, so that the next forward overwrites it
-        cache = [c._replace(k=n.k, v=n.v) for c, n in zip(cache, noisy)]
-    assert seen == len(rows["states"]) >= 5
+        # as the pool's step leaves it: all eight rows' K/V written, the
+        # index at the state's block, so that the next forward overwrites
+        # what was noisy or dead
+        cache = [n._replace(index=jnp.full_like(n.index, start))
+                 for n in new]
+    assert seen == len(rows["states"]) >= 5 and carried >= 2
     # and the pool itself serves the plain loop's tokens and steps
     got_steps = []
     pool.on_token = lambda rid, t: got_steps.append(pool.token_commit_step)
     rid = pool.submit(prompt, max_new)
     assert pool.run()[rid].tolist() == tokens
     assert got_steps == steps
+
+
+# what the carried store must get right, each case a list of (prompt
+# length, tokens asked for) served in turn, the slots of the pool, and the
+# token (by its place in the first request's output) that is the EOS
+_CASES = {
+    # no whole block to the left of the first block: the prefill's cache
+    # holds nothing that is attended
+    "prompt_shorter_than_a_block": ([(3, 9)], 3, None),
+    # the first block starts all masked, with a store before it that the
+    # prefill made, not the step
+    "prompt_of_whole_blocks": ([(8, 12)], 3, None),
+    # no store at all: the request's one block is its last
+    "request_of_one_block": ([(9, 3), (8, 4), (2, 1)], 3, None),
+    "partial_last_block": ([(8, 10), (6, 7)], 3, None),
+    # one slot: the second request's first forward finds the first's
+    # clean last block in ``carry`` and must not store it
+    "slot_refilled_after_a_clean_block": ([(8, 8), (5, 9), (4, 4), (7, 6)],
+                                          1, None),
+    "eos_inside_a_block": ([(9, 12), (8, 6)], 3, 5),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_carried_store_serves_the_references_tokens_and_commit_steps(
+        model, weights, case):
+    reqs, slots, eos_at = _CASES[case]
+    want = [ref.generate(weights, prompt_of(n, 3), k, SIZES)
+            for n, k in reqs]
+    eos = None
+    if eos_at is not None:
+        eos = want[0][0][eos_at]
+        assert eos_at % 4 != 3 and eos not in want[1][0]
+        cut = want[0][0].index(eos) + 1
+        want[0] = (want[0][0][:cut], want[0][1][:cut])
+    pool = BlockDiffusionPool(model, 64, slots=slots, buckets=[16, 32],
+                              cache_layout="paged", block_size=8,
+                              cache_dtype="float32", eos_id=eos)
+    got_steps = {}
+    pool.on_token = lambda rid, t: got_steps.setdefault(rid, []).append(
+        pool.token_commit_step)
+    rids = [pool.submit(prompt_of(n, 3), k) for n, k in reqs]
+    out = pool.run()
+    for rid, (tokens, steps) in zip(rids, want):
+        assert out[rid].tolist() == tokens
+        assert got_steps[rid] == steps
+    assert pool.compile_counts()["block_step"] == 1
+    assert len(pool._free_blocks) == pool._num_blocks - 1
+
+
+@pytest.mark.parametrize("prompt_len,max_new,blocks", [(8, 12, 3),
+                                                       (9, 11, 3),
+                                                       (6, 4, 2)])
+def test_a_block_costs_its_denoising_steps_and_no_forward_more(
+        model, weights, prompt_len, max_new, blocks):
+    """A request of n generated blocks, each with two or more positions to
+    fill, takes ``denoise_steps x n`` slot-forwards: the n - 1 stores ride
+    the first steps of the blocks after them, and the last block is not
+    stored.  Counted twice: by the launches' spans and by the downloads."""
+    from paddle_tpu.serving import trace
+
+    pool = pool_of(model, "paged")
+    tracer = trace.Tracer(capacity=1024)
+    with trace.tracing(tracer):
+        rid = pool.submit(prompt_of(prompt_len), max_new)
+        out = pool.run()[rid].tolist()
+    assert out == ref.generate(weights, prompt_of(prompt_len), max_new,
+                               SIZES)[0]
+    spans = [e.meta for e in tracer.recorder.snapshot()
+             if e.name == "tick.decode"]
+    forwards = CFG["denoise_steps"] * blocks
+    assert sum(m["live"] for m in spans) == forwards
+    assert sum(m["stores_carried"] for m in spans) == blocks - 1
+    assert all(m["store"] == 0 and m["rows"] == 8 * m["live"]
+               for m in spans)
+    assert sum(m["committed"] for m in spans) == max_new
+    assert pool.block_stats() == {"forwards_denoise": forwards,
+                                  "stores_carried": blocks - 1,
+                                  "tokens_committed": max_new}
+    assert pool.compile_counts()["block_step"] == 1
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_a_carried_store_writes_what_the_block_alone_writes(model, weights,
+                                                            layout):
+    """The pools after a request: at the positions of every block that a
+    later block's first step stored, K and V are what a forward of that
+    clean block alone, at its own index, writes (float32 here: the sums
+    of the projections in another order, nothing like a bfloat16 step).
+    The positions after them, the unstored last block's and the dead
+    rows', are held to nothing."""
+    prompt, max_new = prompt_of(9), 14
+    tokens, _ = ref.generate(weights, prompt, max_new, SIZES)
+    pool = pool_of(model, layout)
+    rid = pool.submit(prompt, max_new)
+    pool.step()
+    (slot,) = pool._active
+    table = np.asarray(pool._cache[0].table)[slot] if layout == "paged" \
+        else None
+    assert pool.run()[rid].tolist() == tokens
+    params, bufs, cache = prefilled(pool, prompt)
+    run = jax.jit(lambda ids, c: pool._session._run_model(params, bufs, ids,
+                                                          c))
+    clean = list(prompt) + tokens
+    stored = (9 + max_new - 1) // 4 * 4          # the last block's start
+    for start in range(8, stored, 4):
+        _, cache = run(jnp.asarray([clean[start:start + 4]]), cache)
+
+    def row(entry, which, at, tbl):
+        x = np.asarray(getattr(entry, which))
+        if tbl is None:
+            return x[at]                               # [H, S, D]
+        h, d = x.shape[1], x.shape[3]
+        return x[tbl].transpose(1, 0, 2, 3).reshape(h, -1, d)
+
+    alone_table = np.asarray(cache[0].table)[0] if layout == "paged" \
+        else None
+    assert stored - 8 >= 8
+    for got, want in zip(pool._cache, cache):
+        for which in ("k", "v"):
+            a = row(got, which, slot, table)[:, 8:stored]
+            b = row(want, which, 0, alone_table)[:, 8:stored]
+            assert np.abs(b).max() > 1e-2
+            assert np.abs(a - b).max() < 1e-5
 
 
 def test_pool_interleaves_requests_of_every_alignment(model, weights):
@@ -150,7 +289,7 @@ def test_pool_interleaves_requests_of_every_alignment(model, weights):
                                      "slot_insert": 1}
     stats = pool.block_stats()
     assert stats["tokens_committed"] == sum(k for _, k in reqs)
-    assert stats["forwards_store"] > 0 and stats["forwards_denoise"] > 0
+    assert 0 < stats["stores_carried"] < stats["forwards_denoise"]
     assert len(pool._free_blocks) == pool._num_blocks - 1
 
 
@@ -357,19 +496,22 @@ def test_engine_serves_the_plain_loops_tokens_over_http(model, weights):
                     for l in text.splitlines()
                     if l.startswith("serving_block_")}
         assert counters["serving_block_tokens_committed_total"] == 20
-        assert counters["serving_block_forwards_store_total"] >= 3
-        assert counters["serving_block_forwards_denoise_total"] >= 10
+        # 7, 8 and 5 tokens after prompts that end 1, 0 and 1 into a
+        # block: two blocks each, the second never stored
+        assert counters["serving_block_stores_carried_total"] == 3
+        assert counters["serving_block_forwards_denoise_total"] == 12
     finally:
         front.shutdown()
         engine.shutdown(drain=False)
     spans = [e.meta for e in tracer.recorder.snapshot()
              if e.name == "tick.decode"]
     assert spans and all(
-        {"kind", "rows", "committed", "store", "denoise", "live"}
+        {"rows", "committed", "store", "stores_carried", "live"}
         <= set(m) for m in spans)
-    assert {m["kind"] for m in spans} <= {"denoise", "store", "mixed"}
     assert sum(m["committed"] for m in spans) == 20
-    assert all(m["rows"] == 4 * m["live"] for m in spans)
+    assert sum(m["stores_carried"] for m in spans) == 3
+    assert all(m["rows"] == 8 * m["live"] and m["store"] == 0
+               for m in spans)
 
 
 def test_a_plain_models_terminal_line_has_no_commit_steps():

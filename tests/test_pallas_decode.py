@@ -662,7 +662,7 @@ _CELLS = {
     "zaya": (8, 2, 1, 128, 128, 24, "bfloat16", 8),
     "gpt": (4, 4, 1, 32, 128, 32, "float32", 8),
     "jamba": (20, 1, 1, 128, 128, 18, "bfloat16", 8),
-    "sdar": (16, 2, 4, 128, 128, 20, "bfloat16", 8),
+    "sdar": (16, 2, 8, 128, 128, 20, "bfloat16", 8),     # two blocks of 4
 }
 
 

@@ -246,6 +246,71 @@ def test_grouped_heads_kernel_and_grouped_matmul_on_the_v5e(one_chip,
                                   text))) == grouped, rows
 
 
+def test_block_step_on_the_v5e_runs_two_blocks_a_slot_through_the_kernels(
+        one_chip, monkeypatch):
+    """``sdar-30b-a3b``'s block step at the cell's cache (641 blocks of 128
+    positions, 4 K/V heads of 128, bfloat16, 32 slots), its six layers at
+    their widths, 8 of the 128 experts held and a small vocabulary
+    (neither changes a write, a route or a row count; the whole model is
+    8.7 GB): the chunk is two blocks of 4, so a layer's attention is ONE
+    ``_paged_call`` on 8 query rows a slot and its K and V rows go by ONE
+    in-place write kernel, 256 rows; every expert runs on every row (no
+    grouped matmul, no loop); nothing of a pool's size is copied; and the
+    head's product has ``slots x B`` rows, not ``slots x 2B``."""
+    import jax
+
+    from paddle_tpu.inference import BlockDiffusionPool
+    from paddle_tpu.models import BlockDiffusionMoELM
+    from paddle_tpu.nn.functional import moe
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    layers, slots, bl = 6, 32, 4
+    pt.seed(0)
+    sdar = BlockDiffusionMoELM(
+        vocab_size=512, hidden_size=2048, num_layers=layers, num_heads=32,
+        num_kv_heads=4, head_dim=128, expert_size=768, num_experts=128,
+        top_k=8, block_length=bl, mask_token_id=511, denoise_steps=2,
+        dtype="bfloat16", held_experts=(0, 8))
+    sdar.eval()
+    monkeypatch.setattr(fa, "_backend_memo", "tpu")
+    pool = BlockDiffusionPool(sdar, 2560, slots=slots, buckets=[512],
+                              cache_layout="paged", block_size=128,
+                              num_blocks=641, cache_dtype="bfloat16")
+    assert pool._rows_a_slot == 2 * bl
+    assert pool._entries_meta["kv_write"] == "kernel"
+    # from shapes alone, at the share held here and at the cell's whole
+    assert pool._expert_route == "every" == moe.expert_route(
+        slots * 2 * bl, 128, 128, 8, 2048, 768, 2)
+    params, bufs = pool._session._state_vals()
+    args = (params, bufs, pool._cache, np.zeros((slots, 2 * bl), np.int32),
+            np.zeros((slots, 2 * bl + 4), np.int32))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip), args)
+    text = jax.jit(pool._block_step, donate_argnums=(2,)) \
+        .lower(*shapes).compile().as_text()
+    calls = [line for line in text.split("\n")
+             if 'custom_call_target="tpu_custom_call"' in line]
+    attn = [c for c in calls if "_paged_call" in c.split(" = ")[0]]
+    write = [c for c in calls if "_kv_write_call" in c.split(" = ")[0]]
+    assert len(attn) == len(write) == layers and len(calls) == 2 * layers
+    # ``q_pos`` a row of the chunk; the group's 8 heads x 8 rows folded
+    assert all("s32[%d,%d]{1,0}, bf16[%d,4,64,128]"
+               % (slots, 2 * bl, slots) in c for c in attn)
+    assert all("s32[%d]{0}, s32[%d]{0}, bf16[%d,4,128]"
+               % ((slots * 2 * bl,) * 3) in c for c in write)
+    assert "ragged-dot" not in text and " while(" not in text
+    pool_shape = pool._cache[0].k.shape
+    assert pool_shape == (641, 4, 128, 128)
+    assert chip_smoke.pool_shaped_moves(text, pool_shape) == []
+    assert {op for _, op in chip_smoke.pool_shaped_ops(text, pool_shape)} \
+        <= {"parameter", "get-tuple-element", "bitcast"}
+    head = [line for line in text.split("\n")
+            if "/lm_head/dot_general" in line and " convolution(" in line]
+    assert head and all(" = bf16[%d,%d,512]" % (slots, bl) in line
+                        for line in head)
+
+
 def test_the_touched_route_on_the_v5e_copies_no_expert(one_chip):
     """ax-k1's expert layer at a decode step's shapes (32 rows, 12 of 192
     experts of 7168 x 2048 held, 8 a token): a loop on the device over the

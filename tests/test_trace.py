@@ -971,7 +971,7 @@ _DECODE_META = {
     "plain": {"live", "slots", "greedy", "ahead", "live_blocks",
               "table_blocks", "kv_entries", "kv_planes", "kv_write"},
     "speculative": {"spec_k", "live", "slots", "ahead"},
-    "block": {"live", "slots", "ahead", "kind", "rows", "store", "denoise",
+    "block": {"live", "slots", "ahead", "rows", "store", "stores_carried",
               "committed", "tokens_per_forward", "live_blocks",
               "table_blocks", "kv_entries", "kv_planes", "kv_write",
               # its model has an expert layer (PR 45)

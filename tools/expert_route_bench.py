@@ -11,7 +11,7 @@ PR 45, is this script's).
 | --- | --- | --- |
 | ``axk1`` | 32 rows, 12 of 192 experts of 7168 x 2048 held, 8 a token | ``axk1-batch-closed``, a decode step |
 | ``zaya`` | 64 rows, 16 of 16 experts of 2048 x 2048, 1 a token | ``zaya8b-batch-closed``, a decode step |
-| ``sdar`` | 128 rows, 128 of 128 experts of 2048 x 768, 8 a token | ``sdar30b-batch-closed``, a block step |
+| ``sdar`` | 256 rows (32 slots x two blocks of 4), 128 of 128 experts of 2048 x 768, 8 a token | ``sdar30b-batch-closed``, a block step |
 
 Each route the tree has (``every``: every held expert on every row;
 ``touched``: a loop over the experts some row chose; ``grouped``: the
@@ -52,7 +52,7 @@ HBM_BYTES_PER_S = 819e9           # TPU v5e, as benchmark/harness has it
 GEOMETRIES = {
     "axk1": (32, 12, 192, 8, 7168, 2048),
     "zaya": (64, 16, 16, 1, 2048, 2048),
-    "sdar": (128, 128, 128, 8, 2048, 768),
+    "sdar": (256, 128, 128, 8, 2048, 768),
 }
 TOY = (8, 4, 16, 2, 32, 16)
 ROUTES = {"every": "_every_expert", "touched": "_touched",

@@ -11,7 +11,7 @@ the table in PERF.md section 6, PR 43, is this script's).
 | ``zaya`` | bfloat16, 2 K/V heads x group 4, blocks of 128, 24 entries, 64 rows | ``zaya8b-batch-closed`` |
 | ``gpt`` | float32, 16 heads, blocks of 32, 32 entries, 16 rows | ``gpt1p3b-batch-closed``; its ``chat`` context is ``gpt1p3b-chat-r60``'s |
 | ``jamba`` | bfloat16, 1 K/V head x group 20, blocks of 128, 18 entries, 64 rows | ``jamba3b-batch-closed`` |
-| ``sdar`` | bfloat16, 4 K/V heads x group 8, 4 positions, blocks of 128, 20 entries, 32 rows | ``sdar30b-batch-closed`` |
+| ``sdar`` | bfloat16, 4 K/V heads x group 8, 8 positions (the block step's two blocks of 4), blocks of 128, 20 entries, 32 rows | ``sdar30b-batch-closed`` |
 | ``ouro`` | bfloat16, 16 heads, blocks of 64, 5 entries, 16 rows, FOUR planes of heads an entry, the chained calls attending plane 0, 1, 2, 3, 0 ... by a head offset | ``ouro2p6b-batch-closed`` |
 | ``ouro-blocks`` | the same calls with the planes laid as further BLOCKS (``table + r * num_blocks`` on a pool of four times the blocks, the unplaned kernel) | off the benchmark: the layout not chosen (docs/DESIGN.md, "a stack run several times") |
 | ``gpt-int8`` | ``gpt``'s call on an int8 pool with its float32 scales (blocks of 32) | off the benchmark: ``cache_dtype="int8"`` at the default block |
@@ -65,7 +65,7 @@ GEOMETRIES = {
     "zaya": (64, 8, 2, 1, 128, 128, 24, "bfloat16", (140, 2500)),
     "gpt": (16, 16, 16, 1, 32, 128, 32, "float32", (150, 560)),
     "jamba": (64, 20, 1, 1, 128, 128, 18, "bfloat16", (140, 1900)),
-    "sdar": (32, 32, 4, 4, 128, 128, 20, "bfloat16", (400, 2300)),
+    "sdar": (32, 32, 4, 8, 128, 128, 20, "bfloat16", (400, 2300)),
     "ouro": (16, 16, 16, 1, 64, 128, 5, "bfloat16", (100, 315)),
     "ouro-blocks": (16, 16, 16, 1, 64, 128, 5, "bfloat16", (100, 315)),
     "gpt-int8": (16, 16, 16, 1, 32, 128, 32, "int8", (150, 560)),
