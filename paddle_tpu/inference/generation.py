@@ -571,11 +571,26 @@ class GenerationPool:
         # meta): the entries behind a block table, the K/V planes they
         # hold in all, the passes where an entry holds more than one
         paged = sum(n for k, (n, _) in self._by_kind.items()
-                    if k != "recurrent")
+                    if k not in ("recurrent", "window"))
         self._entries_meta = dict(kv_entries=paged,
                                   kv_planes=paged * self._planes)
         if self._planes > 1:
             self._entries_meta["passes"] = self._planes
+        # window entries (``jit.cache.WindowLayout``): a ring of blocks a
+        # slot, in pools of their own that no allocator maps; the block
+        # counts above are then the OTHER paged entries' alone.  ``_ring``
+        # is ``(window, ring blocks)``, None for a model with no such
+        # entry; ``window_blocks_overwritten`` counts, from positions,
+        # the ring entries a slot's position has lapped (once a slot,
+        # whatever the number of window entries, as ``live_blocks`` is)
+        rings = self._layout.entries(self._cache, "window")
+        self._ring = (int(rings[0].window), int(rings[0].table.shape[1])) \
+            if rings else None
+        self.window_blocks_overwritten = 0
+        if rings:
+            self._entries_meta.update(window_entries=len(rings),
+                                      window=self._ring[0],
+                                      ring_blocks=self._ring[1])
         # how a step's new K/V rows reach their pools (one in-place
         # kernel a layer, or a scatter a pool), from shapes, types and
         # the session's route, as the step's trace decides it; an entry
@@ -587,6 +602,8 @@ class GenerationPool:
         if writes:
             self._entries_meta["kv_write"] = "+".join(sorted(writes))
         self._state_bytes_slot = self._by_kind.get("recurrent", (0, 0))[1]
+        # a window entry's ring is a constant of the slot too
+        self._window_bytes_slot = self._by_kind.get("window", (0, 0))[1]
         # the same, as ``cache_stats()`` hands it out every tick
         self._by_kind_stats = {
             "bytes_per_slot": {k: b for k, (_, b) in self._by_kind.items()},
@@ -723,6 +740,12 @@ class GenerationPool:
                 "pass): keep spill_tier='host', which carries a block's "
                 "planes together in memory" % self._planes)
         if spill_tier == "disk":
+            if self._layout.windowed:
+                raise InvalidArgumentError(
+                    "spill_tier='disk' writes K/V blocks by head to a PTKV "
+                    "file from the allocator's block lists; "
+                    "cache_layout=%r %s — no tier carries a window entry "
+                    "yet" % (cache_layout, self._layout.not_transferable()))
             if not self._layout.spillable:
                 raise InvalidArgumentError(
                     "spill_tier='disk' spills per-slot decode state "
@@ -861,6 +884,21 @@ class GenerationPool:
         layout without one, naming the layers that keep a recurrent
         state where that is why."""
         lay = self._layout
+        if lay.windowed and (prefill_chunk_tokens is not None
+                             or prefix_sharing):
+            raise InvalidArgumentError(
+                "%s cannot apply to cache_layout=%r: a window entry keeps "
+                "a slot's last positions on a ring of blocks of its own%s"
+                % (("prefix_sharing", lay.name,
+                    ", so the blocks of a shared prefix that lie behind "
+                    "the window do not exist to be shared (a ring drawn "
+                    "from the allocator, and sharing that knows which "
+                    "layers share, are not built)")
+                   if prefix_sharing else
+                   ("prefill_chunk_tokens", lay.name,
+                    ", and a prompt's chunk that starts mid-way may ask "
+                    "for keys the ring has already overwritten: a prompt "
+                    "is prefilled whole, in one bucket")))
         if lay.prompt_from_zero and (prefill_chunk_tokens is not None
                                      or prefix_sharing):
             raise InvalidArgumentError(
@@ -1598,8 +1636,7 @@ class GenerationPool:
         if not self._layout.spillable:
             raise PreconditionNotMetError(
                 "preemption spills per-slot decode state to the host "
-                "tier; a dense pool has no spill granularity — use "
-                "cache_layout='paged' (or 'recurrent')")
+                "tier; " + self._layout.not_spillable())
         # the spill reads the victim's K/V and its committed count: both
         # must be level with the device
         self._settle()
@@ -2809,6 +2846,11 @@ class GenerationPool:
                 padded[:need] = blocks
                 args += (jnp.asarray(padded),)
             self._cache = self._insert_jit(*args)
+            if self._ring is not None:
+                # the prompt's blocks that lie behind the ring were never
+                # copied: as many ring entries lapped
+                self.window_blocks_overwritten += max(
+                    0, (length - 1) // self._block_size + 1 - self._ring[1])
             self.last_admit_prefix_tokens = None
             if self.on_admit is not None:
                 self.on_admit(req.rid, slot, len(req.ids))
@@ -3146,12 +3188,22 @@ class GenerationPool:
                                 for slot, st in self._rows),
                 table_blocks=self.slots * self._max_blocks,
                 **self._entries_meta)
+            if self._ring is not None:
+                # the ring entries ONE windowed call walks, over the live
+                # slots: from the entry of the band's first position to
+                # the entry of the last, never more than the ring
+                window, ring = self._ring
+                meta["window_live_blocks"] = sum(
+                    min(top // bs - max(top - window + 1, 0) // bs + 1,
+                        ring)
+                    for top in (self._last_position(slot, st)
+                                for slot, st in self._rows))
         if "latent" in self._by_kind:
             # the paged figures above run over latent entries: a block is
             # one latent a position, not K/V by head
             meta["latent_entries"] = self._by_kind["latent"][0]
-        if len(self._by_kind) > 1:
-            meta["state_entries"] = self._by_kind.get("recurrent", (0,))[0]
+        if len(self._by_kind) > 1 and "recurrent" in self._by_kind:
+            meta["state_entries"] = self._by_kind["recurrent"][0]
         return meta
 
     def _expert_meta(self, live: int) -> dict:
@@ -3192,6 +3244,14 @@ class GenerationPool:
         self._cache, self._tok_dev, self._step_dev = self._decode_jit(
             params, bufs, self._cache, self._tok_dev, self._active_dev,
             self._samp_dev, self._step_dev, self._adapter_dev)
+        if self._ring is not None:
+            # a row whose step writes the first position of a block at or
+            # past the ring's length laps one ring entry
+            bs, ring = self._block_size, self._ring[1]
+            for slot, st in self._rows:
+                top = self._last_position(slot, st)
+                self.window_blocks_overwritten += \
+                    top % bs == 0 and top // bs >= ring
         for _, st in self._rows:
             st.ahead += 1
         self.steps_drawing += self._draws
@@ -3519,13 +3579,18 @@ class GenerationPool:
         # positions; a model that mixes kinds adds its recurrent entries'
         # whole state (the same at any context) to what is resident and
         # reachable
-        state_total = self._state_bytes_slot * self.slots
+        # (so does a window entry's ring, the same at any context: its
+        # pools are held whole, slots x ring blocks and a scratch block)
+        state_total = (self._state_bytes_slot
+                       + self._window_bytes_slot) * self.slots
+        if self._ring is not None:
+            state_total += self._window_bytes_slot // self._ring[1]
         # every slot at max_len over those entries (K/V by head, scales
         # included, or a latent): what ``kv_reachable_bytes(...,
         # layout="dense")`` gives for K/V
         dense_bytes = self.slots * sum(
             b for kind, (_, b) in self._by_kind.items()
-            if kind != "recurrent")
+            if kind not in ("recurrent", "window"))
         # every byte figure below is dtype-aware (int8 caches count the
         # int8 K/V plus the riding fp32 scales — kv_reachable_bytes),
         # and the dtype is stamped so a serving record can never present

@@ -103,6 +103,13 @@ class SpeculativePool(GenerationPool):
         # small by design, and its prompt forward runs once at
         # activation, not per tick
         super().__init__(model, max_len, **pool_kwargs)
+        if self._layout.windowed:
+            raise InvalidArgumentError(
+                "speculative decoding does not support cache_layout=%r: a "
+                "verify chunk of several positions that starts mid-way "
+                "may ask a window entry for keys its ring has already "
+                "overwritten, and a rewind cannot bring them back (the "
+                "windowed kernel takes one query a row)" % (self.cache_layout,))
         if not self._layout.positional:
             # a model that mixes kinds: asked for by its K/V layout, so
             # only its cache entries say that some layers cannot rewind

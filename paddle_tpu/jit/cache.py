@@ -38,6 +38,10 @@ kind             an entry's type says so by  what a position keeps a layer
                                              ``[num_blocks, H, bs, D]``
 ``latent``       a ``table`` and a           ONE compressed latent, no head:
                  ``latent`` field            ``latent [num_blocks, bs, W]``
+``window``       a ``table`` and a           ``k``, ``v`` blocks by head for
+                 ``window`` field            the last ``window`` positions
+                                             only: the table is a RING of
+                                             ``window / bs + 1`` blocks
 ``recurrent``    a ``limit``                 nothing: a state of constant size
 ==============  ==========================  ================================
 
@@ -102,6 +106,8 @@ layouts, so a pool kwarg that silently no-ops is impossible:
   layout is not).
 - ``recurrent``: some entry is a state of constant size (it has a
   ``limit`` window, a ``state_bytes`` figure and no block).
+- ``windowed``: some entry is a ring of blocks (``WindowLayout``): a
+  chunk of several positions that starts mid-way is refused.
 
 The traced-method bodies (``insert_row``/``freeze_step``/the prefill
 hooks) are the EXACT code the pool and session inlined before this
@@ -117,7 +123,7 @@ import numpy as np
 from ..core.errors import InvalidArgumentError
 
 __all__ = ["CacheLayout", "DenseLayout", "PagedLayout", "LatentLayout",
-           "RecurrentLayout",
+           "WindowLayout", "RecurrentLayout",
            "ComposedLayout", "CACHE_LAYOUTS", "get_layout", "entry_layout",
            "layout_of"]
 
@@ -148,13 +154,16 @@ class CacheLayout:
     #: only for a chunk that starts at position 0: what starts a prompt's
     #: chunk mid-way (chunked prefill, prefix sharing) is refused
     prompt_from_zero: bool = False
+    #: some entry is a ring of blocks (``WindowLayout``)
+    windowed: bool = False
+
     @staticmethod
     def payload_fields(entry) -> tuple:
         """The fields of ``entry`` that hold cache content, in the entry's
         own order: every field but the bookkeeping (``index``, ``table``,
         ``limit``) that is not None (a float K/V entry's scales are)."""
         return tuple(f for f in entry._fields
-                     if f not in ("index", "table", "limit")
+                     if f not in ("index", "table", "limit", "window")
                      and getattr(entry, f) is not None)
 
     def layouts(self, cache) -> tuple:
@@ -176,6 +185,12 @@ class CacheLayout:
         """How a refusal says why this layout has no disk tier and no PTKV
         transfer."""
         return "has no per-slot granularity to write"
+
+    def not_spillable(self) -> str:
+        """How a refusal says why a slot of this layout cannot be preempted
+        into the host tier."""
+        return ("a dense pool has no spill granularity — use "
+                "cache_layout='paged' (or 'recurrent')")
 
     # -- prefill hooks (traced) ------------------------------------------
     def begin_prefill_entry(self, c, true_len):
@@ -377,6 +392,94 @@ class LatentLayout(PagedLayout):
             "unknown decode-cache field %r for layout 'latent'" % (field,))
 
 
+class WindowLayout(PagedLayout):
+    """One more kind of paged entry: the K/V of a WINDOW layer
+    (``nn.GroupedQueryAttention(window=)``), blocks by head as the paged
+    kind holds them, behind a ``table`` ``[slots, ring]`` that is a RING:
+    position ``p`` lives at entry ``(p // bs) % ring``, ``ring = window /
+    bs + 1``, so a block behind the window is overwritten by a later one,
+    never freed, and a slot pins ``ring`` blocks whatever its context or
+    ``max_len``.
+
+    **The ring's blocks are the slot's own**, fixed when the entry is made
+    (slot ``s`` owns blocks ``1 + s * ring ...`` of the entry's own pools,
+    block 0 the scratch): the worst case of a window pool is ``slots x
+    ring`` blocks, small enough to hold outright, and a free list that can
+    never refuse is bookkeeping.  So the allocator, ``_blocks_needed``, the
+    free list and admission stay the PAGED entries' alone; what the pool
+    does to a paged entry's table a step (an inactive slot's row routed to
+    the scratch block, the row restored after) it does to this one's.
+
+    ``insert_entry`` copies a prefilled row's LAST ring of blocks to their
+    ring places and no others.  What the kind does not carry, each refused
+    by a typed error that names the window entry: preempt and resume (not
+    ``spillable``: a ring has no block list to park), the disk tier and
+    PTKV transfer, an int8 pool, prefix sharing (a shared prefix's blocks
+    behind the window do not exist), chunked prefill and speculative
+    decoding (``windowed``: a chunk that starts mid-way may ask for keys
+    the ring overwrote), and ``mp`` / ``dp`` meshes."""
+
+    name = "window"
+    spillable = False
+    transferable = False
+    windowed = True
+
+    def not_transferable(self) -> str:
+        return ("keeps a window entry (a ring of blocks a slot, in pools "
+                "of its own), which the file format does not hold")
+
+    def not_spillable(self) -> str:
+        return ("a window entry keeps a slot's last positions in a ring of "
+                "blocks of its own, which no block list names: preempt and "
+                "resume of a window entry are not built")
+
+    def insert_entry(self, cp, cr, slot, length, blocks=None):
+        # the row was prefilled into a ring that spans it whole (never
+        # wrapped: ``gen_decode_cache(num_blocks=None)``), so its logical
+        # block ``j`` is found through its own table.  Ring place ``c`` of
+        # the slot takes the LAST logical block at or under the row's top
+        # block that is ``c`` modulo the ring; a place no block has
+        # reached yet takes the row's scratch block, which nothing reads
+        # (the walk starts at the band's first entry).  The slot's places
+        # are ``ring`` consecutive blocks: one gather from the row, one
+        # slice written where the pool lies
+        import jax
+
+        from ..ops.flash_attention import ring_blocks_held
+
+        ring, bs = cp.table.shape[1], cp.k.shape[2]
+        length = jnp.asarray(length, jnp.int32)
+        logical = ring_blocks_held(jnp.maximum(length - 1, 0) // bs, ring)
+        src = jnp.where(logical >= 0,
+                        cr.table[0][jnp.maximum(logical, 0)
+                                    % cr.table.shape[1]], 0)
+        first = cp.table[slot, 0]
+        upd = {f: jax.lax.dynamic_update_slice_in_dim(
+                   getattr(cp, f),
+                   getattr(cr, f)[src].astype(getattr(cp, f).dtype),
+                   first, axis=0)
+               for f in self.payload_fields(cp)}
+        return cp._replace(
+            index=cp.index.at[slot].set(length), **upd)
+
+    def entry_bytes_per_slot(self, c, slots: int, max_len: int) -> int:
+        # the ring, whatever ``max_len``: the point of the kind
+        ring = int(c.table.shape[1])
+        return sum(int(np.prod(getattr(c, f).shape[1:]))
+                   * getattr(c, f).dtype.itemsize * ring
+                   for f in self.payload_fields(c))
+
+    def field_axes(self, field: str):
+        if field == "window":
+            return ()  # the band's width: a scalar, replicated
+        return super().field_axes(field)
+
+    def fingerprint_extra(self, pool) -> dict:
+        first = pool._layout.entries(pool._cache, self.name)[0]
+        return {"window": int(first.window),
+                "ring": int(first.table.shape[1])}
+
+
 class RecurrentLayout(CacheLayout):
     """Constant-size recurrence carry: O(1) state per token, no block
     table, no paging, no prefix tree.  Three caches live on it:
@@ -499,6 +602,7 @@ class ComposedLayout(CacheLayout):
         self.recurrent = any(lay.recurrent for lay in self._layouts)
         self.prompt_from_zero = any(lay.prompt_from_zero
                                     for lay in self._layouts)
+        self.windowed = any(lay.windowed for lay in self._layouts)
         # a PTKV file carries blocks OR state rows, never both
         self.transferable = False
 
@@ -519,7 +623,14 @@ class ComposedLayout(CacheLayout):
     def not_transferable(self) -> str:
         if self.recurrent:
             return "has both (%s)" % self.recurrent_entries()
+        if self.windowed:
+            return _WINDOW.not_transferable()
         return "mixes kinds of entry (%s)" % self.name
+
+    def not_spillable(self) -> str:
+        return "; ".join(dict.fromkeys(
+            lay.not_spillable() for lay in self._layouts
+            if not lay.spillable))
 
     def fingerprint_extra(self, pool) -> dict:
         out = {}
@@ -536,6 +647,8 @@ CACHE_LAYOUTS = {
 # no string a caller passes: ``cache_layout="paged"`` gives a model that
 # keeps latents its latent entries, and the entry's type says the rest
 _LATENT = LatentLayout()
+# nor this: a layer built with ``window=`` hands out window entries
+_WINDOW = WindowLayout()
 
 
 def get_layout(name: str) -> CacheLayout:
@@ -552,11 +665,14 @@ def get_layout(name: str) -> CacheLayout:
 
 def entry_layout(entry) -> CacheLayout:
     """The layout of ONE layer's cache entry, from its type: a block
-    ``table`` is paged (latent where what the blocks hold is a ``latent``),
-    an update window ``limit`` recurrent, else dense (K/V, or a latent by
+    ``table`` is paged (latent where what the blocks hold is a ``latent``,
+    window where the table is a ring: a ``window`` field), an update
+    window ``limit`` recurrent, else dense (K/V, or a latent by
     slot)."""
     fields = getattr(entry, "_fields", ())
     if "table" in fields:
+        if "window" in fields:
+            return _WINDOW
         return _LATENT if "latent" in fields else CACHE_LAYOUTS["paged"]
     if "limit" in fields:
         return CACHE_LAYOUTS["recurrent"]

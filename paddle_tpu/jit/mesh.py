@@ -174,6 +174,18 @@ class DecodeMesh:
                 "would split that axis into runs of planes, not into "
                 "each plane's heads; serve this model with mp=1"
                 % (self.mp, planes))
+        windows = [layer.window for layer in model.sublayers()
+                   if getattr(layer, "window", None) is not None]
+        if windows and (self.mp > 1 or self.dp > 1):
+            raise InvalidArgumentError(
+                "mp=%d, dp=%d cannot place a model with window entries "
+                "(%d layers of window=%d): a window entry's ring is blocks "
+                "of the slot's own in pools of its own, laid out for one "
+                "device (mp > 1 would split its heads under a table that "
+                "is not the allocator's; dp > 1 would split a pool whose "
+                "blocks are numbered by slot, with one scratch block); "
+                "serve this model with mp=1, dp=1"
+                % (self.mp, self.dp, len(windows), windows[0]))
         inter = getattr(model, "intermediate_size", None)
         if inter is not None and inter % self.mp != 0:
             raise InvalidArgumentError(

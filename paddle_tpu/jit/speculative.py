@@ -166,6 +166,13 @@ class SpeculativeDecodeSession:
             cache_dtype=cache_dtype, donate=donate,
             cache_layout=cache_layout, block_size=block_size,
             route=route)
+        if self._target._layout.windowed:
+            raise InvalidArgumentError(
+                "speculative decoding does not support cache_layout=%r: a "
+                "verify chunk of several positions that starts mid-way "
+                "may ask a window entry for keys its ring has already "
+                "overwritten, and a rewind cannot bring them back (the "
+                "windowed kernel takes one query a row)" % (self._target._layout.name,))
         if not self._target._layout.positional:
             raise InvalidArgumentError(
                 "speculative decoding does not support cache_layout=%r "

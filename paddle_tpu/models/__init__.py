@@ -30,6 +30,10 @@ from .power_retention import (  # noqa: F401
     PowerRetentionDecoderLayer,
     PowerRetentionLM,
 )
+from .window_moe import (  # noqa: F401
+    WindowMoEDecoderLayer,
+    WindowMoELM,
+)
 from .language_model import (  # noqa: F401
     TransformerForSequenceClassification,
     TransformerLM,

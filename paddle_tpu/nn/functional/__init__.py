@@ -32,7 +32,8 @@ from .norm import (  # noqa: F401
     batch_norm, group_norm, instance_norm, layer_norm, local_response_norm,
     normalize, rms_norm,
 )
-from .moe import (expert_route, route_top_k, sparse_experts,  # noqa: F401
+from .moe import (EXPERT_ACTIVATIONS, expert_route, route_top_k,  # noqa: F401
+                  sparse_experts,
                   touched_share)
 from .vision import affine_grid, grid_sample  # noqa: F401
 from .pooling import (  # noqa: F401

@@ -118,16 +118,22 @@ def route_top_k(logits, top_k: int, scoring: str = "softmax",
     return top * scale, experts.astype(jnp.int32)
 
 
-def _grouped(xt, gates, key, held: int, top_k: int, w_gate, w_up, w_down):
+# What gates an expert's up-projection: the name a layer is built with
+# (``nn.SparseExperts(activation=)``), so the three routes take it as data.
+EXPERT_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _grouped(xt, gates, key, held: int, top_k: int, w_gate, w_up, w_down,
+             act=jax.nn.silu):
     """The pairs sorted by expert, three grouped matmuls over the stacked
     weights, un-sorted and summed with their gates in float32."""
     rows, width = xt.shape
     order = jnp.argsort(key, stable=True)
     sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
     xs = xt[order // top_k]
-    act = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, sizes)) \
+    hid = act(jax.lax.ragged_dot(xs, w_gate, sizes)) \
         * jax.lax.ragged_dot(xs, w_up, sizes)
-    ys = jax.lax.ragged_dot(act.astype(xt.dtype), w_down, sizes)
+    ys = jax.lax.ragged_dot(hid.astype(xt.dtype), w_down, sizes)
     with jax.named_scope("combine"):
         per_pair = ys[jnp.argsort(order)].reshape(rows, top_k, width)
         # a pair of an expert held elsewhere lies past the last group,
@@ -147,21 +153,22 @@ def _gate_of(gates, key, held: int):
 
 
 def _every_expert(xt, gates, key, held: int, top_k: int, w_gate, w_up,
-                  w_down):
+                  w_down, act=jax.nn.silu):
     """Every held expert on every row, an unchosen one under a gate of
     0: one batched matmul in, and one matmul out that sums over experts
     and their channels at once.  Many rows touch every expert anyway, and
     then the weights' read is the cost and this reads them once."""
     gate_of = _gate_of(gates, key, held)                        # [T, n]
-    act = jax.nn.silu(jnp.einsum("th,ehf->tef", xt, w_gate)) \
+    hid = act(jnp.einsum("th,ehf->tef", xt, w_gate)) \
         * jnp.einsum("th,ehf->tef", xt, w_up)
     with jax.named_scope("combine"):
-        act = (act.astype(jnp.float32) * gate_of[..., None]).astype(xt.dtype)
-        return jnp.einsum("tef,efh->th", act, w_down,
+        hid = (hid.astype(jnp.float32) * gate_of[..., None]).astype(xt.dtype)
+        return jnp.einsum("tef,efh->th", hid, w_down,
                           preferred_element_type=jnp.float32)
 
 
-def _touched(xt, gates, key, held: int, top_k: int, w_gate, w_up, w_down):
+def _touched(xt, gates, key, held: int, top_k: int, w_gate, w_up, w_down,
+             act=jax.nn.silu):
     """Only the held experts that some row chose, each on every row under
     its column of the gates: the touched experts' numbers stand first in
     ``order`` and a loop on the device runs as many turns as there are.
@@ -188,11 +195,11 @@ def _touched(xt, gates, key, held: int, top_k: int, w_gate, w_up, w_down):
                 jax.lax.dynamic_index_in_dim(a, e, axis, keepdims=False)
                 for a, axis in ((w_gate, 0), (w_up, 0), (w_down, 0),
                                 (gate_of, 1)))
-            act = jax.nn.silu(jnp.matmul(xt, w_g)) * jnp.matmul(xt, w_u)
+            hid = act(jnp.matmul(xt, w_g)) * jnp.matmul(xt, w_u)
             with jax.named_scope("combine"):
-                act = (act.astype(jnp.float32) * gate[:, None]) \
+                hid = (hid.astype(jnp.float32) * gate[:, None]) \
                     .astype(xt.dtype)
-                return out + jnp.matmul(act, w_d,
+                return out + jnp.matmul(hid, w_d,
                                         preferred_element_type=jnp.float32)
 
         return jax.lax.fori_loop(0, touched, one_expert,
@@ -201,7 +208,7 @@ def _touched(xt, gates, key, held: int, top_k: int, w_gate, w_up, w_down):
     return jax.lax.cond(
         touched == held,
         lambda: _every_expert(xt, gates, key, held, top_k, w_gate, w_up,
-                              w_down),
+                              w_down, act),
         the_touched)
 
 
@@ -211,8 +218,11 @@ _ROUTES = {"grouped": _grouped, "every": _every_expert, "touched": _touched}
 def sparse_experts(x, scores, w_gate, w_up, w_down, top_k: int,
                    first_expert: int = 0, scoring: str = "softmax",
                    n_group: int = 1, topk_group: int = 1,
-                   routed_scale: float = 1.0, renormalise: bool = True):
-    """``x`` ``[..., H]`` through a routed gated-SiLU feed-forward.
+                   routed_scale: float = 1.0, renormalise: bool = True,
+                   activation: str = "silu"):
+    """``x`` ``[..., H]`` through a routed gated feed-forward whose gate is
+    ``activation`` (``"silu"``: the Qwen3 and DeepSeek families'; ``"relu"``:
+    SmallThinker's sparse ReGLU, computed in full, zeros and all).
 
     ``scores`` ``[..., E]`` float32 are the router's logits of every one of
     the ``E`` experts, a row for each row of ``x``, computed by the caller
@@ -228,7 +238,7 @@ def sparse_experts(x, scores, w_gate, w_up, w_down, top_k: int,
     rule (``route_top_k``).
 
     ``out[t] = sum over t's top_k experts e of gate[t, e] *
-    w_down[e] (silu(x[t] w_gate[e]) * (x[t] w_up[e]))``.
+    w_down[e] (activation(x[t] w_gate[e]) * (x[t] w_up[e]))``.
 
     Three routes, chosen from the shapes alone (``expert_route``).  While
     the held experts' matmuls over every row stay under
@@ -291,5 +301,6 @@ def sparse_experts(x, scores, w_gate, w_up, w_down, top_k: int,
         route = _ROUTES[expert_route(
             xt.shape[0], held, scores.shape[-1], top_k, width,
             w_gate.shape[2], w_gate.dtype.itemsize)]
-        out = route(xt, gates, key, held, top_k, w_gate, w_up, w_down)
+        out = route(xt, gates, key, held, top_k, w_gate, w_up, w_down,
+                    act=EXPERT_ACTIVATIONS[activation])
     return out.astype(x.dtype).reshape(*lead, width)

@@ -95,8 +95,9 @@ class DepthMLPRouter(Layer):
 
 
 class SparseExperts(Layer):
-    """``num_experts`` gated-SiLU experts of width ``expert_size``,
-    ``top_k`` a token, no bias.
+    """``num_experts`` gated experts of width ``expert_size``, ``top_k`` a
+    token, no bias; the gate is ``activation`` (``"silu"``, or ``"relu"``:
+    ``F.EXPERT_ACTIVATIONS``).
     Parameters: ``router`` ``[H, E]`` (or the layer given as ``router=``:
     the module docstring), ``w_gate`` and ``w_up`` ``[n, H,
     F]``, ``w_down`` ``[n, F, H]`` for the ``n`` experts ``held = (first,
@@ -114,8 +115,14 @@ class SparseExperts(Layer):
                  scoring: str = "softmax", n_group: int = 1,
                  topk_group: int = 1, routed_scale: float = 1.0,
                  shared_size: int = 0, renormalise: bool = True,
-                 router: Optional[Layer] = None):
+                 router: Optional[Layer] = None,
+                 activation: str = "silu"):
         super().__init__()
+        if activation not in F.EXPERT_ACTIVATIONS:
+            raise InvalidArgumentError(
+                "activation must be one of %s, got %r"
+                % (sorted(F.EXPERT_ACTIVATIONS), activation))
+        self.activation = activation
         first, count = (0, num_experts) if held is None else held
         if not 1 <= top_k <= num_experts:
             raise InvalidArgumentError(
@@ -168,12 +175,34 @@ class SparseExperts(Layer):
                               self.top_k, width, size,
                               self.w_gate.value.dtype.itemsize)
 
-    def routed(self, x, r_prev=None):
+    def scores_of(self, x):
+        """The router matrix's logits of every expert for the rows of
+        ``x`` ``[.., H]``, ``[rows, E]`` float32: what ``routed`` computes
+        from its own input, for a caller whose router reads ANOTHER tensor
+        than the experts do (``routed(x, scores=)``)."""
+        if isinstance(self.router, Layer):
+            raise InvalidArgumentError(
+                "scores_of is the router MATRIX's product; this layer's "
+                "router is a layer with a state of its own")
+        with jax.named_scope("router"):
+            return jnp.matmul(x.value.reshape(-1, x.shape[-1]),
+                              self.router.value,
+                              preferred_element_type=jnp.float32)
+
+    def routed(self, x, r_prev=None, scores=None):
         """The held experts' part of the routed sum; under a router layer
-        ``(part, r)``, ``r`` the router's state for the next layer."""
+        ``(part, r)``, ``r`` the router's state for the next layer.
+        ``scores`` ``[rows, E]`` float32: the router's logits computed by
+        the caller from another tensor than ``x`` (``scores_of``); the
+        layer's own router is then not run."""
         carried = isinstance(self.router, Layer)
         xt = x.value.reshape(-1, x.shape[-1])
-        if carried:
+        if scores is not None:
+            if carried or r_prev is not None:
+                raise InvalidArgumentError(
+                    "scores= replaces the router matrix's product; a "
+                    "router layer computes its own from its state")
+        elif carried:
             scores, r = self.router(x, r_prev)
         else:
             with jax.named_scope("router"):
@@ -185,13 +214,14 @@ class SparseExperts(Layer):
                                scoring=self.scoring, n_group=self.n_group,
                                topk_group=self.topk_group,
                                routed_scale=self.routed_scale,
-                               renormalise=self.renormalise)
+                               renormalise=self.renormalise,
+                               activation=self.activation)
         out = Tensor(out.reshape(x.shape), stop_gradient=True)
         return (out, r) if carried else out
 
-    def forward(self, x, r_prev=None):
+    def forward(self, x, r_prev=None, scores=None):
         """The layer's output; under a router layer ``(output, r)``."""
-        out = self.routed(x, r_prev)
+        out = self.routed(x, r_prev, scores)
         if self.shared is None:
             return out
         if isinstance(out, tuple):

@@ -12,6 +12,7 @@ from __future__ import annotations
 import collections
 from typing import Optional
 
+import jax
 import numpy as np
 
 from ...core.errors import InvalidArgumentError
@@ -97,6 +98,17 @@ class MultiHeadAttention(Layer):
         "PagedDecodeCache", ["k", "v", "table", "index",
                              "k_scale", "v_scale"],
         defaults=(None, None))
+
+    # Window decode cache (``GroupedQueryAttention(window=)`` under the
+    # paged layout): K/V blocks as ``PagedDecodeCache`` holds them, behind
+    # a ``table`` ``[B, ring]`` that is a RING: position ``p`` lives at
+    # entry ``(p // block_size) % ring``, so a block behind the window is
+    # overwritten by a later one and a row pins ``ring`` blocks whatever
+    # its context.  ``window`` (an int32 scalar) is the band's width; the
+    # field is what tells the entry's kind (``jit.cache.entry_layout``).
+    # Float pools only.
+    WindowDecodeCache = collections.namedtuple(
+        "WindowDecodeCache", ["k", "v", "table", "index", "window"])
 
     def __init__(
         self,
@@ -407,8 +419,11 @@ class MultiHeadAttention(Layer):
             # paged entry's plane is never sliced out)
             k_read, v_read = (jax.lax.dynamic_slice_in_dim(
                 x, base, self.kv_heads, axis=1) for x in (k_buf, v_buf))
+        # (a window layer's dense cache keeps every position: the band is
+        # the mask's lower edge)
         out = decode_attention(q_, k_read, v_read, q_pos=q_pos,
-                               k_scale=ks_buf, v_scale=vs_buf)
+                               k_scale=ks_buf, v_scale=vs_buf,
+                               window=getattr(self, "window", None))
         return out, self.DecodeCache(k_buf, v_buf, idx + length,
                                      ks_buf, vs_buf)
 
@@ -445,7 +460,8 @@ class MultiHeadAttention(Layer):
                 "attn_mask=None, or use the uncached forward")
         q_, k_new, v_new = raw(q), raw(k_new), raw(v_new)
         k_pool, v_pool = raw(cache.k), raw(cache.v)
-        ks_pool, vs_pool = cache.k_scale, cache.v_scale
+        ks_pool = getattr(cache, "k_scale", None)
+        vs_pool = getattr(cache, "v_scale", None)
         quant = ks_pool is not None
         if quant:
             # quantize-on-write; scales scatter into the per-block scale
@@ -458,6 +474,8 @@ class MultiHeadAttention(Layer):
         b, _, length, _ = q_.shape
         bs = k_pool.shape[2]
         s = table.shape[1] * bs
+        if isinstance(cache, self.WindowDecodeCache):
+            return self._window_decode_forward(q_, k_new, v_new, cache)
         if idx.ndim == 0:
             # aligned batch (DecodeSession): every row writes the same
             # chunk positions through its own table row
@@ -574,13 +592,40 @@ class GroupedQueryAttention(MultiHeadAttention):
     ``block_length`` set: generation by diffusion over blocks.  Positions
     ``[k * block_length, (k + 1) * block_length)`` are one block whose
     rows see each other and every earlier block: the mask is block-causal,
-    with and without a cache."""
+    with and without a cache.
+
+    ``window`` set: WINDOW attention.  Position ``i`` sees the ``window``
+    positions that end at its own, ``i - window < j <= i`` (the band's
+    edge is the mask ``i - j < window``: its own key is one of the
+    ``window``), with and without a cache.  Under the paged layout the
+    layer's cache entry is then a ``WindowDecodeCache``: a RING of
+    ``window / block_size + 1`` blocks a row (``gen_decode_cache``), the
+    decode step's kernel walking from the band's first entry to its last
+    (``ops.pallas_decode``); a dense cache keeps every position under the
+    banded mask.
+
+    A PROMPT (a chunk of more than one position against a cache whose
+    index is known to be 0 while the program is traced, as
+    ``nn.LatentAttention`` tells one) of a window layer, and of any causal
+    layer where the flash kernel runs, attends its OWN keys
+    (``ops.flash_attention.prompt_attention``): no ``L x L`` scores in HBM
+    from ``CAUSAL_FLASH_MIN_SEQ`` positions up.  A longer chunk that
+    starts mid-way against a window entry is refused: the ring may have
+    lost part of its band."""
 
     def __init__(self, embed_dim: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, rope_theta: Optional[float] = 10000.0,
                  qk_norm: bool = True, norm_epsilon: float = 1e-6,
-                 block_length: Optional[int] = None):
+                 block_length: Optional[int] = None,
+                 window: Optional[int] = None):
         Layer.__init__(self)
+        if window is not None and (int(window) < 1
+                                   or block_length is not None):
+            raise InvalidArgumentError(
+                "window=%r: a band of at least one position, and not "
+                "beside block_length (a block-causal mask has no band)"
+                % (window,))
+        self.window = None if window is None else int(window)
         if num_kv_heads < 1 or num_heads % num_kv_heads:
             raise InvalidArgumentError(
                 "num_heads %d is not a whole multiple of num_kv_heads %d"
@@ -616,6 +661,90 @@ class GroupedQueryAttention(MultiHeadAttention):
                                  causal: bool = False):
         raise InvalidArgumentError(
             "GroupedQueryAttention has no sequence-parallel form")
+
+    def gen_decode_cache(self, batch_size: int, max_length: int,
+                         dtype="float32", per_slot: bool = False,
+                         layout: str = "dense", block_size: int = 32,
+                         num_blocks: Optional[int] = None,
+                         planes: int = 1):
+        """``MultiHeadAttention.gen_decode_cache``; a WINDOW layer under
+        the paged layout hands out a ``WindowDecodeCache`` instead: pools
+        ``[1 + batch_size * ring, Hkv, block_size, D]`` (block 0 the
+        scratch), a table ``[batch_size, ring]`` whose row ``s`` is blocks
+        ``1 + s * ring ...``: the ring's blocks are the ROW'S OWN, fixed
+        here, and no allocator maps them.  With an explicit ``num_blocks``
+        (a pool's allocator owns the OTHER layers' tables; the number
+        itself is not this entry's) ``ring = window / block_size + 1``,
+        held to the blocks ``max_length`` spans; without (a session's
+        self-managed cache, a prefill's row) the ring spans ``max_length``
+        whole and never wraps, so a prompt of any length is written in
+        order and ``jit.cache.WindowLayout.insert_entry`` takes its last
+        ring of blocks.  The index of a single sequence is a numpy zero,
+        which no trace stages: a chunk against it is known to be a
+        prompt."""
+        if self.window is None or layout != "paged":
+            return super().gen_decode_cache(
+                batch_size, max_length, dtype, per_slot, layout,
+                block_size, num_blocks, planes)
+        import jax.numpy as jnp
+
+        dtype = normalize_cache_dtype(dtype)
+        block_size = int(block_size)
+        if dtype == "int8" or planes != 1:
+            raise InvalidArgumentError(
+                "a window entry (window=%d) is a float pool of one K/V "
+                "plane: cache_dtype='int8' has no windowed attention route "
+                "(its scales would ride the ring too)" % self.window)
+        if block_size < 1 or self.window % block_size:
+            raise InvalidArgumentError(
+                "window=%d is not whole blocks of block_size=%d: the ring "
+                "of a window entry is window / block_size + 1 blocks, and "
+                "the band's first block is found by a division"
+                % (self.window, block_size))
+        span = -(-int(max_length) // block_size)
+        ring = span if num_blocks is None \
+            else min(self.window // block_size + 1, span)
+        shape = (1 + batch_size * ring, self.kv_heads, block_size,
+                 self.head_dim)
+        table = 1 + jnp.arange(batch_size * ring, dtype=jnp.int32) \
+            .reshape(batch_size, ring)
+        index = jnp.zeros((batch_size,), jnp.int32) if per_slot \
+            else np.zeros((), np.int32)
+        return self.WindowDecodeCache(
+            jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), table, index,
+            np.asarray(self.window, np.int32))
+
+    def _window_decode_forward(self, q, k_new, v_new, cache):
+        """``_paged_decode_forward`` for a window entry: the chunk's K/V
+        rows go to ``pool[table[row, (pos // bs) % ring], :, pos % bs]``
+        and the queries attend the band that ends at each.  One position a
+        row (the decode step); a longer chunk only as a prompt from
+        position 0 that the ring holds whole, which ``forward`` has
+        attended over its own keys: it hands ``q`` None and only the write
+        is made."""
+        import jax.numpy as jnp
+
+        from ...ops.flash_attention import (paged_decode_attention,
+                                            paged_kv_write)
+
+        table = jnp.asarray(cache.table, jnp.int32)
+        idx = jnp.asarray(cache.index, jnp.int32)
+        b, _, length, _ = k_new.shape
+        bs, ring = cache.k.shape[2], table.shape[1]
+        steps = jnp.arange(length, dtype=jnp.int32)
+        if idx.ndim == 0:
+            q_pos = idx + steps                                 # [L]
+            phys = table[:, (q_pos // bs) % ring]               # [B, L]
+            off = jnp.broadcast_to((q_pos % bs)[None, :], (b, length))
+        else:
+            q_pos = idx[:, None] + steps[None, :]               # [B, L]
+            phys = table[jnp.arange(b)[:, None], (q_pos // bs) % ring]
+            off = q_pos % bs
+        k_pool, v_pool = paged_kv_write(cache.k, cache.v, k_new, v_new,
+                                        phys, off)
+        out = None if q is None else paged_decode_attention(
+            q, k_pool, v_pool, table, q_pos=q_pos, window=self.window)
+        return out, cache._replace(k=k_pool, v=v_pool, index=idx + length)
 
     def _last_visible(self, pos):
         if self.block_length is None:
@@ -670,7 +799,40 @@ class GroupedQueryAttention(MultiHeadAttention):
                 "(causal, or block-causal with block_length); pass "
                 "attn_mask=None")
         q, k, v, pos = self._qkv(x, cache)
+        if cache is not None and self._is_prompt(q, cache, plane):
+            # a prompt from position 0: written to the cache, attended
+            # over its own keys (a window entry's ring may be shorter than
+            # the prompt once it lies in a pool; the row it is prefilled
+            # into spans it whole)
+            from ...ops.flash_attention import prompt_attention
+
+            out = prompt_attention(q.value, k.value, v.value,
+                                   self.head_dim ** -0.5, self.window)
+            if isinstance(cache, self.WindowDecodeCache):
+                if x.shape[1] > cache.table.shape[1] * cache.k.shape[2]:
+                    raise InvalidArgumentError(
+                        "a prompt of %d positions into a ring of %d blocks "
+                        "of %d: a window entry takes a prompt whole only "
+                        "where its ring spans it (a self-managed cache, "
+                        "gen_decode_cache(num_blocks=None))"
+                        % (x.shape[1], cache.table.shape[1],
+                           cache.k.shape[2]))
+                _, cache = self._window_decode_forward(
+                    None, k.value, v.value, cache)
+            else:
+                cache = self._write_only(k, v, cache)
+            return self.out_proj(self._merge_heads(
+                _T(out, stop_gradient=True))), cache
         if cache is not None:
+            if isinstance(cache, self.WindowDecodeCache) and x.shape[1] > 1:
+                raise InvalidArgumentError(
+                    "a chunk of %d positions that starts mid-way against a "
+                    "window entry (window=%d behind a ring of %d blocks): a "
+                    "speculative verify chunk, a chunk of a chunked "
+                    "prefill or a block of diffusion rows may ask for keys "
+                    "the ring has already overwritten; only a decode step "
+                    "and a prompt from position 0 are built"
+                    % (x.shape[1], self.window, cache.table.shape[1]))
             fwd = (self._decode_forward
                    if isinstance(cache, self.DecodeCache)
                    else self._paged_decode_forward)
@@ -679,14 +841,58 @@ class GroupedQueryAttention(MultiHeadAttention):
             out = self.out_proj(self._merge_heads(
                 _T(out, stop_gradient=True)))
             return out, cache
-        from ...ops.flash_attention import decode_attention
+        from ...ops.flash_attention import decode_attention, prompt_attention
 
-        # no cache: the same composition over the sequence's own K/V
-        out = decode_attention(q.value, k.value, v.value,
-                               q_pos=self._last_visible(pos),
-                               route="composition")
+        if self.window is not None:
+            out = prompt_attention(q.value, k.value, v.value,
+                                   self.head_dim ** -0.5, self.window)
+        else:
+            # no cache: the same composition over the sequence's own K/V
+            out = decode_attention(q.value, k.value, v.value,
+                                   q_pos=self._last_visible(pos),
+                                   route="composition")
         return self.out_proj(self._merge_heads(_T(out,
                                                   stop_gradient=True)))
+
+    def _is_prompt(self, q, cache, plane) -> bool:
+        """Whether the chunk ``q`` against ``cache`` is attended over its
+        own keys: more than one position, a causal mask (no
+        ``block_length``), one plane, a cache KNOWN to stand at 0 while the
+        program is traced, and either a window layer (whose ring need not
+        hold the prompt) or shapes the flash kernel takes."""
+        from ...ops.flash_attention import prompt_flash_supported
+        from .latent_attention import _starts_at_zero
+
+        if q.shape[2] < 2 or self.block_length is not None \
+                or plane is not None \
+                or getattr(cache, "k_scale", None) is not None \
+                or not _starts_at_zero(cache.index):
+            return False
+        return self.window is not None \
+            or prompt_flash_supported(q.shape, q.value.dtype)
+
+    def _write_only(self, k, v, cache):
+        """A prompt's K/V rows into a dense or a paged cache at positions
+        0 .. L - 1, the index advanced; nothing is attended."""
+        import jax.numpy as jnp
+
+        from ...ops.flash_attention import paged_kv_write
+
+        length = k.shape[2]
+        idx = jnp.asarray(cache.index, jnp.int32)
+        if isinstance(cache, self.DecodeCache):
+            upd = {f: jax.lax.dynamic_update_slice(
+                getattr(cache, f), x.value.astype(getattr(cache, f).dtype),
+                (0, 0, 0, 0)) for f, x in (("k", k), ("v", v))}
+            return cache._replace(index=idx + length, **upd)
+        bs = cache.k.shape[2]
+        pos = jnp.arange(length, dtype=jnp.int32)
+        table = jnp.asarray(cache.table, jnp.int32)
+        phys = table[:, pos // bs]
+        off = jnp.broadcast_to((pos % bs)[None, :], phys.shape)
+        k_pool, v_pool = paged_kv_write(cache.k, cache.v, k.value, v.value,
+                                        phys, off)
+        return cache._replace(k=k_pool, v=v_pool, index=idx + length)
 
 
 class GatedMLP(Layer):

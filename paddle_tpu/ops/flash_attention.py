@@ -15,6 +15,7 @@ call works on CPU test meshes and odd shapes.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 from typing import Optional
 
@@ -31,6 +32,7 @@ __all__ = ["flash_attention", "flash_attention_supported",
            "quantize_kv", "dequantize_kv",
            "latent_decode_attention", "latent_cache_write",
            "causal_attention", "causal_flash_supported",
+           "prompt_attention", "prompt_flash_supported",
            "decode_route", "normalize_decode_route", "DECODE_ROUTES",
            "reset_backend_memo"]
 
@@ -349,6 +351,27 @@ def _qpos_bias(q_pos, s_len: int, dtype):
     return jnp.where(allow, 0.0, neg)[:, None]
 
 
+def _band_bias(q_pos, key_pos, window: int, dtype):
+    """The composition's additive mask of a WINDOW layer: query ``i`` sees
+    key ``j`` where ``i - window < j <= i``, the ``window`` positions that
+    end at its own.  ``q_pos`` ``[L]`` or ``[B, L]`` as ``_qpos_bias``
+    takes it; ``key_pos`` the position each key of the score axis holds,
+    ``[S]`` (keys in order: a dense cache, a prompt's own) or ``[B, S]``
+    (a ring's blocks as gathered; negative: nothing written there yet)."""
+    qp = jnp.asarray(q_pos, jnp.int32)
+    kp = jnp.asarray(key_pos, jnp.int32)
+    neg = jnp.asarray(jnp.finfo(jnp.float32).min, dtype)
+    if qp.ndim == 1 and kp.ndim == 1:
+        qp, kp = qp[:, None], kp[None, :]                       # [L, S]
+        lead = (None, None)
+    else:
+        qp = (qp[None] if qp.ndim == 1 else qp)[:, :, None]     # [B, L, 1]
+        kp = (kp[None] if kp.ndim == 1 else kp)[:, None, :]     # [B, 1, S]
+        lead = (slice(None), None)
+    allow = (kp <= qp) & (kp > qp - window) & (kp >= 0)
+    return jnp.where(allow, 0.0, neg)[lead]
+
+
 def _effective_qpos(q_pos, lengths, b: int, lq: int, s: int):
     """The kernel's [B, Lq] mask-index form of whatever masking the
     caller expressed: ``q_pos`` (per-query last visible key) and/or
@@ -419,7 +442,7 @@ def decode_attention_supported(q_shape, kv_len: int, dtype) -> bool:
 
 def decode_attention(q, k, v, bias=None, sm_scale: Optional[float] = None,
                      k_scale=None, v_scale=None, q_pos=None, route=None,
-                     score_dtype=None):
+                     score_dtype=None, window: Optional[int] = None):
     """Decode-step attention: [B, H, Lq, D] queries against a FULL
     preallocated cache [B, H, S, D] (S = max_len), with ``bias`` masking
     the invalid tail (positions at or beyond the cache index) to -inf.
@@ -453,10 +476,28 @@ def decode_attention(q, k, v, bias=None, sm_scale: Optional[float] = None,
     only; None keeps the queries' type) is the type the scores and the
     softmax are taken in: the grouped-head callers pass float32, as the
     fused kernel computes, so that a bfloat16 model's attention does not
-    round its probabilities to eight bits."""
+    round its probabilities to eight bits.
+
+    ``window`` (with ``q_pos``): a WINDOW layer's band, a query sees keys
+    ``q_pos - window < j <= q_pos``.  The composition only: a dense cache
+    keeps every position and the band is its mask's lower edge."""
     d = q.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(d))
+    if window is not None:
+        if q_pos is None or bias is not None:
+            raise InvalidArgumentError(
+                "a window's band is taken from q_pos, without a bias")
+        if q.shape[1] != k.shape[1]:
+            q_f, q_pos = fold_query_groups(q, k.shape[1], q_pos)
+        else:
+            q_f = q
+        out = decode_attention(
+            q_f, k, v, bias=_band_bias(q_pos, jnp.arange(k.shape[2]),
+                                       int(window), jnp.float32),
+            sm_scale=sm_scale, k_scale=k_scale, v_scale=v_scale,
+            route="composition", score_dtype=jnp.float32)
+        return out.reshape(q.shape)
     if q.ndim == 4 and k.ndim == 4 and q.shape[1] != k.shape[1]:
         # grouped K/V heads: the heads that share a K/V head fold into
         # the chunk axis, and the chunk is then past the kernel's
@@ -645,7 +686,8 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
                            sm_scale: Optional[float] = None,
                            k_scale=None, v_scale=None, q_pos=None,
                            route=None, score_dtype=None, head_base=None,
-                           plane_heads: Optional[int] = None):
+                           plane_heads: Optional[int] = None,
+                           window: Optional[int] = None):
     """Decode-step attention against a BLOCK-TABLE KV cache.
 
     ``q``: [B, H, Lq, D] queries (Lq = 1 for autoregressive decode,
@@ -691,12 +733,32 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
     plane's heads).  The kernel takes the offset as one more scalar and
     copies that plane's heads of an entry; the composition gathers them
     alone.  A float pool only.
+
+    ``window``: a WINDOW entry (``jit.cache.WindowLayout``).  ``table``
+    ``[B, ring]`` is a RING: position ``p`` lives at entry ``(p // bs) %
+    ring``, a block behind the window is overwritten by a later one, and a
+    query sees keys ``q_pos - window < j <= q_pos``.  The kernel (one
+    query a row) walks from the entry of ``q_pos - window + 1`` to the
+    entry of ``q_pos``; the composition gathers the ring and takes the band
+    as a bias from the position each ring place holds under the row's last
+    query (any chunk whose window the ring still holds whole: the caller
+    answers for that).  A float pool, ``q_pos`` and no bias.
     """
     from .pallas_decode import (paged_decode_attention_kernel,
                                 paged_mosaic_refusal)
 
     b, mb = table.shape
     nb, h, bs, d = k_pool.shape
+    if window is not None:
+        if (head_base is not None or k_scale is not None or bias is not None
+                or lengths is not None or q_pos is None):
+            raise InvalidArgumentError(
+                "a window entry is a float pool of one plane attended "
+                "under q_pos alone (no lengths, no additive bias)")
+        return _windowed_paged_attention(
+            q, k_pool, v_pool, jnp.asarray(table, jnp.int32), q_pos,
+            int(window), float(1.0 / np.sqrt(q.shape[-1])
+                               if sm_scale is None else sm_scale), route)
     planed = head_base is not None
     if planed:
         if k_scale is not None or bias is not None:
@@ -776,6 +838,141 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths=None, bias=None,
     return decode_attention(q, k, v, bias=bias, sm_scale=sm_scale,
                             k_scale=ks, v_scale=vs, route="composition",
                             score_dtype=score_dtype)
+
+
+def ring_blocks_held(top, ring: int):
+    """The logical block each of a ring's ``ring`` places holds once block
+    ``top`` (an int32 scalar, or ``[B]``) is the last one written: place
+    ``c`` holds the last block at or under ``top`` that is ``c`` modulo the
+    ring; negative where nothing has been written there yet.  ``[ring]`` or
+    ``[B, ring]``."""
+    top = jnp.asarray(top, jnp.int32)[..., None]
+    return top - (top - jnp.arange(ring, dtype=jnp.int32)) % ring
+
+
+def _windowed_paged_attention(q, k_pool, v_pool, table, q_pos, window: int,
+                              sm_scale: float, route):
+    """``paged_decode_attention`` against a window entry's ring (its
+    docstring has the contract)."""
+    from .pallas_decode import (paged_decode_attention_kernel,
+                                paged_mosaic_refusal)
+
+    b, ring = table.shape
+    nb, h, bs, d = k_pool.shape
+    lq = q.shape[2]
+    qp = _effective_qpos(q_pos, None, b, lq, ring * bs)
+    # the kernel's walk takes one query a row: a longer chunk (no cell
+    # runs one) is the composition's under every route
+    if lq == 1 and _resolve_route(
+            route, q.shape,
+            paged_decode_attention_supported(q.shape, bs, nb, q.dtype),
+            _kernel_refusal(q.shape, q.dtype, bs,
+                            lambda: paged_mosaic_refusal(d, bs))):
+        return paged_decode_attention_kernel(
+            q, k_pool, v_pool, table, qp, sm_scale,
+            interpret=_cached_backend() != "tpu", window=window)
+    # the ring as it lies: [B, ring, H, bs, D] -> [B, H, ring * bs, D],
+    # and the position each place holds under the row's top block
+    # (negative: not written yet)
+    k = k_pool[table].transpose(0, 2, 1, 3, 4).reshape(b, h, ring * bs, d)
+    v = v_pool[table].transpose(0, 2, 1, 3, 4).reshape(b, h, ring * bs, d)
+    block = ring_blocks_held(jnp.maximum(jnp.max(qp, axis=1), 0) // bs,
+                             ring)                               # [B, ring]
+    key_pos = (block[:, :, None] * bs
+               + jnp.arange(bs, dtype=jnp.int32)).reshape(b, ring * bs)
+    key_pos = jnp.where(jnp.repeat(block, bs, axis=1) < 0, -1, key_pos)
+    qf, qpf = fold_query_groups(q, h, qp)
+    out = decode_attention(
+        qf, k, v, bias=_band_bias(qpf, key_pos, window, jnp.float32),
+        sm_scale=sm_scale, route="composition", score_dtype=jnp.float32)
+    return out.reshape(q.shape)
+
+
+# ---------------------------------------------------------------------------
+# a prompt's own attention under grouped heads, causal or banded
+# ---------------------------------------------------------------------------
+
+# the splash kernel's tiles: queries and keys by 512, as the latent
+# model's flash prefill (docs 5w)
+_PROMPT_FLASH_BLOCK = 512
+
+
+def prompt_flash_supported(q_shape, dtype) -> bool:
+    """Whether ``prompt_attention`` takes the splash kernel: a TPU, ``[B,
+    H, L, D]`` with ``L`` whole tiles of ``_PROMPT_FLASH_BLOCK`` and at
+    least ``CAUSAL_FLASH_MIN_SEQ``, a head of whole 128-lane tiles."""
+    if _cached_backend() != "tpu" or len(q_shape) != 4:
+        return False
+    l, d = q_shape[2], q_shape[3]
+    return (l % _PROMPT_FLASH_BLOCK == 0 and l >= CAUSAL_FLASH_MIN_SEQ
+            and d % 128 == 0 and jnp.dtype(dtype) in _SUPPORTED_DTYPES)
+
+
+@functools.lru_cache(maxsize=64)
+def _splash_kernel(length: int, group: int, window: Optional[int],
+                   block: int, interpret: bool):
+    """The splash kernel of one K/V head's ``group`` query heads over
+    ``length`` positions, causal (``window`` None) or a causal band.  Its
+    mask is processed block by block on the host, once a shape (0.3-0.7 s
+    at 12,288 positions: kept), into the lists of blocks a row of tiles
+    visits: a tile outside the band is neither fetched nor computed."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as _sk, splash_attention_mask as _sm)
+
+    one = _sm.CausalMask((length, length)) if window is None else \
+        _sm.LocalMask((length, length), (window - 1, 0), 0)
+    # (built outside whatever trace asks for it: the kernel object holds
+    # the mask's block lists as arrays and outlives the trace)
+    with jax.ensure_compile_time_eval():
+        return _sk.make_splash_mqa_single_device(
+            _sm.MultiHeadMask([one] * group),
+            block_sizes=_sk.BlockSizes(block_q=block, block_kv=block,
+                                       block_kv_compute=block),
+            interpret=interpret)
+
+
+def prompt_attention(q, k, v, sm_scale: float, window: Optional[int] = None):
+    """A prompt's attention over its OWN keys under grouped heads: ``q``
+    ``[B, Hq, L, D]``, ``k``, ``v`` ``[B, Hkv, L, D]``, positions 0 ..
+    ``L - 1``, query head ``n`` on K/V head ``n // (Hq / Hkv)``; causal, or
+    with ``window`` the band ``i - window < j <= i``.  Result ``[B, Hq, L,
+    D]``.
+
+    From ``CAUSAL_FLASH_MIN_SEQ`` positions up on a TPU the splash kernel
+    (``jax.experimental.pallas.ops.tpu.splash_attention``): the scores
+    never exist in HBM (28 heads x 12,288^2 float32 would be 16.9 GB), a
+    K/V head is read by its group without being repeated, and a tile
+    outside the band is skipped, so a window layer's prompt costs ``L x
+    window`` and not ``L^2 / 2``.  Chosen over ``causal_attention``'s
+    flash kernel because that one knows no band (a bias would be the
+    ``L x L`` array again) and takes K/V repeated to the query heads.
+    Below, and off the TPU, the XLA composition with the band in its bias
+    (``prompt_flash_supported`` decides; off the TPU the kernel would run
+    under the interpreter)."""
+    b, hq, length, d = q.shape
+    hkv = k.shape[1]
+    with jax.named_scope("prefill_attn"), jax.named_scope(
+            "causal" if window is None else "band"):
+        if not prompt_flash_supported(q.shape, q.dtype):
+            pos = jnp.arange(length, dtype=jnp.int32)
+            if window is None:
+                return decode_attention(q, k, v, q_pos=pos,
+                                        sm_scale=sm_scale,
+                                        route="composition")
+            return decode_attention(q, k, v, q_pos=pos, sm_scale=sm_scale,
+                                    window=window)
+        if hq % hkv:
+            raise InvalidArgumentError(
+                "query heads %d are not a whole multiple of the K/V heads "
+                "%d" % (hq, hkv))
+        block = min(_PROMPT_FLASH_BLOCK, length)
+        kernel = _splash_kernel(length, hq // hkv, window, block,
+                                _cached_backend() != "tpu")
+        # the kernel has no scale of its own: it rides the queries
+        qs = (q * jnp.asarray(sm_scale, q.dtype)).reshape(
+            b, hkv, hq // hkv, length, d)
+        out = jax.vmap(jax.vmap(kernel))(qs, k, v)
+        return out.reshape(q.shape).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -101,6 +101,7 @@ out from the compiler:
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional
 
@@ -458,6 +459,20 @@ def _live_entries(qpos_ref, bi, lq: int, bs: int, mb: int):
     return jnp.where(top < 0, 0, reach)
 
 
+def _window_entries(qpos_ref, bi, bs: int, mb: int, window: int):
+    """``(first, count)``: the logical table entries a row of ONE query
+    reads against a WINDOW entry, whose keys are the ``window`` positions
+    that end at the query's own: from the entry that holds position ``top
+    - window + 1`` (0 while the context is shorter than the window) to the
+    one that holds ``top``.  At most ``window / bs + 1`` of them, a ring's
+    length, whatever the context; 0 for a row that sees nothing."""
+    top = qpos_ref[bi, 0]
+    first = jax.lax.div(jnp.maximum(top - (window - 1), 0), jnp.int32(bs))
+    last = jax.lax.div(jnp.maximum(top, 0), jnp.int32(bs))
+    return first, jnp.where(top < 0, 0,
+                            jnp.minimum(last - first + 1, jnp.int32(mb)))
+
+
 def _by_steps(x, steps: int, width: int):
     """``x`` ``[B, .., S]``, positions on the lane axis (an int8 pool's
     scales gathered through the table, a bias), as ``[B, steps, ..,
@@ -475,7 +490,7 @@ def _by_steps(x, steps: int, width: int):
 
 def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
                 sm_scale: float, quant: bool, bias_dims, group: int,
-                planed: bool = False):
+                planed: bool = False, window: Optional[int] = None):
     """One ``(batch row, head chunk)`` a grid step: a loop over the row's
     LIVE table entries, ``tile`` of them a step.
 
@@ -507,7 +522,17 @@ def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
     pools hold several planes of the queries' K/V heads side by side on
     their head axis).  A copy takes ``pool[table[b, e], base + chunk]``:
     the same contiguous bytes of an entry as an unplaned pool's, further
-    along."""
+    along.
+
+    ``window`` (one query a row, a float pool, no bias): the table is a
+    RING of ``mb`` entries, position ``p`` lives at entry ``(p // bs) %
+    mb``, and the query sees the ``window`` positions that end at its own.
+    The walk then has a FIRST live entry beside the last
+    (``_window_entries``): tile ``t`` takes logical entries ``first + t *
+    tile ...``, looked up at their ring places, and the positions at or
+    before ``q_pos - window``, which lie inside the first entry, are masked
+    like those past ``q_pos``.  With ``window`` None none of this is
+    traced: the body is what it was."""
     rows = group * lq
     width = tile * bs
     has_bias = bias_dims is not None
@@ -526,11 +551,12 @@ def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
         bi, hh = pl.program_id(0), pl.program_id(1)
         b, nh = pl.num_programs(0), pl.num_programs(1)
 
-        def each_copy(row, chunk, n, t, slot, act):
+        def each_copy(row, chunk, n, t, slot, act, start=0):
             """``act`` on every copy of tile ``t`` of ``(row, chunk)``,
             a row of ``n`` live entries, into buffer ``slot``: one K and
             one V descriptor a live entry [, the tile's scales and
-            bias]."""
+            bias].  ``start``: the row's first live entry (a window's
+            walk; the table is then a ring)."""
             heads = pl.ds(chunk * hc, hc)
             pool_heads = pl.ds(base + chunk * hc, hc) if planed else heads
             for s, (src, dst) in enumerate(zip(side_hbm, side_buf)):
@@ -548,7 +574,9 @@ def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
 
                 @pl.when(entry < n)
                 def _(entry=entry, at=at):
-                    blk = tbl_ref[row, entry]
+                    blk = tbl_ref[row, entry] if window is None else \
+                        tbl_ref[row, jax.lax.rem(start + entry,
+                                                 jnp.int32(mb))]
                     for s, (src, dst) in enumerate(((k_hbm, k_buf),
                                                     (v_hbm, v_buf))):
                         act(pltpu.make_async_copy(
@@ -565,21 +593,32 @@ def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        n_live = _live_entries(qpos_ref, bi, lq, bs, mb)
+        # a row's first live entry: 0 without a window
+        first_live = first_next = 0
+        if window is None:
+            n_live = _live_entries(qpos_ref, bi, lq, bs, mb)
+        else:
+            first_live, n_live = _window_entries(qpos_ref, bi, bs, mb,
+                                                 window)
         n_tiles = jax.lax.div(n_live + (tile - 1), jnp.int32(tile))
         first = state[0]
         # the grid step after this one, and whether it has a tile
         wraps = hh == nh - 1
         nrow = jnp.minimum(jnp.where(wraps, bi + 1, bi), b - 1)
         nchunk = jnp.where(wraps, 0, hh + 1)
-        n_next = _live_entries(qpos_ref, nrow, lq, bs, mb)
+        if window is None:
+            n_next = _live_entries(qpos_ref, nrow, lq, bs, mb)
+        else:
+            first_next, n_next = _window_entries(qpos_ref, nrow, bs, mb,
+                                                 window)
         follows = jnp.logical_and(
             jnp.logical_not(jnp.logical_and(wraps, bi == b - 1)),
             n_next > 0)
 
         @pl.when(jnp.logical_and(n_tiles > 0, state[1] == 0))
         def _():
-            each_copy(bi, hh, n_live, 0, first, lambda c: c.start())
+            each_copy(bi, hh, n_live, 0, first, lambda c: c.start(),
+                      first_live)
 
         qb = q_ref[0].astype(jnp.float32)               # [hc, rows, D]
         # a row's last visible position, a column: SMEM serves scalar
@@ -601,9 +640,12 @@ def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
                           jnp.where(more, hh, nchunk),
                           jnp.where(more, n_live, n_next),
                           jnp.where(more, t + 1, 0), 1 - slot,
-                          lambda c: c.start())
+                          lambda c: c.start(),
+                          0 if window is None
+                          else jnp.where(more, first_live, first_next))
 
-            each_copy(bi, hh, n_live, t, slot, lambda c: c.wait())
+            each_copy(bi, hh, n_live, t, slot, lambda c: c.wait(),
+                      first_live)
             # the HBM read was the cache dtype: the up-cast happens here
             # in VMEM, on one tile, never on the gathered cache
             kb = k_buf[slot].astype(jnp.float32)        # [hc, width, D]
@@ -623,7 +665,14 @@ def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
                 s = s + side[-1].astype(jnp.float32)
             pos = t * width + jax.lax.broadcasted_iota(
                 jnp.int32, (rows, width), 1)
-            s = jnp.where((pos <= qp)[None], s, -jnp.inf)
+            if window is None:
+                seen = pos <= qp
+            else:
+                # the tile's positions start at the row's first live
+                # entry; the band's lower edge lies inside that entry
+                pos = pos + first_live * bs
+                seen = jnp.logical_and(pos <= qp, pos > qp - window)
+            s = jnp.where(seen[None], s, -jnp.inf)
             _softmax_update(s, vb, side[1] if quant else None,
                             m_ref, l_ref, acc_ref)
             return carry
@@ -644,13 +693,16 @@ def _paged_body(hc: int, mb: int, lq: int, bs: int, tile: int,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "interpret", "group"))
+                   static_argnames=("sm_scale", "interpret", "group",
+                                    "window"))
 def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
-                sm_scale, interpret, group=1, head_base=None):
+                sm_scale, interpret, group=1, head_base=None, window=None):
     # grouped K/V heads: ``q`` comes folded, [B, Hkv, group * Lq, D],
     # a K/V head's whole group its block of rows.  ``head_base`` (int32
     # [1], traced): the pools hold planes of Hkv heads and this call
-    # attends the one that starts there
+    # attends the one that starts there.  ``window`` (static): the table
+    # is a ring and a query sees the ``window`` positions that end at its
+    # own (``_paged_body``); None traces the call as it was
     b, h, rows, d = q.shape
     lq = rows // group
     _, _, bs, _ = k_pool.shape
@@ -696,7 +748,8 @@ def _paged_call(q, k_pool, v_pool, table, q_pos, k_scale, v_scale, bias,
            pltpu.SMEM((2,), jnp.int32)] + _scratch(hc, rows, d))
     return pl.pallas_call(
         _paged_body(hc, mb, lq, bs, tile, sm_scale, quant,
-                    bias.shape if has_bias else None, group, planed),
+                    bias.shape if has_bias else None, group, planed,
+                    window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, rows, d), q.dtype),
         # a copy in flight and the buffer it lands in pass from one grid
@@ -711,7 +764,8 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, q_pos,
                                   sm_scale: float,
                                   k_scale=None, v_scale=None, bias=None,
                                   interpret: bool = False, head_base=None,
-                                  plane_heads: Optional[int] = None):
+                                  plane_heads: Optional[int] = None,
+                                  window: Optional[int] = None):
     """Fused paged decode attention: ``q`` [B, H, Lq, D] against a
     block-table pool [num_blocks, H, bs, D], never materializing the
     gathered K/V.
@@ -735,9 +789,23 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, q_pos,
     pools are ``[num_blocks, planes * plane_heads, bs, D]``, several
     planes of K/V heads an entry, and the queries attend the plane whose
     first head is ``head_base``; one more scalar-prefetch operand, the
-    walk and its copies otherwise the same.  A float pool, no bias."""
+    walk and its copies otherwise the same.  A float pool, no bias.
+
+    ``window``: the entry is a WINDOW entry (``jit.cache.WindowLayout``):
+    ``table`` ``[B, ring]`` is a ring, position ``p`` lives at entry ``(p
+    // bs) % ring``, and a query sees positions ``q_pos - window < j <=
+    q_pos``.  The walk runs from the entry of ``q_pos - window + 1`` to the
+    entry of ``q_pos``, at most ``window / bs + 1`` entries whatever the
+    context.  One query a row, a float pool, no bias, no planes."""
     nb, h, bs, d = k_pool.shape
     planed = head_base is not None
+    if window is not None and (q.shape[2] != 1 or planed or bias is not None
+                               or k_scale is not None):
+        raise InvalidArgumentError(
+            "the windowed walk takes ONE query a row against a float pool "
+            "of one plane, no additive bias (got a chunk of %d%s): a "
+            "longer chunk against a ring of blocks takes the composition"
+            % (q.shape[2], ", planes" if planed else ""))
     if planed:
         if k_scale is not None or bias is not None:
             raise InvalidArgumentError(
@@ -763,7 +831,10 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, q_pos,
     if (k_scale is None) != (v_scale is None):
         raise InvalidArgumentError(
             "int8 pools carry BOTH k_scale and v_scale (got one)")
-    with jax.named_scope("paged_attn"):
+    # a window entry's call is a child of the scope: ``paged_attn/window``
+    with jax.named_scope("paged_attn"), (
+            contextlib.nullcontext() if window is None
+            else jax.named_scope("window")):
         # one head a head: both reshapes are the identity
         b, _, lq, _ = q.shape
         out = _paged_call(q.reshape(b, h, group * lq, d), k_pool, v_pool,
@@ -773,7 +844,8 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, q_pos,
                           float(sm_scale), bool(interpret), group=group,
                           **({"head_base": jnp.reshape(jnp.asarray(
                               head_base, jnp.int32), (1,))}
-                             if planed else {}))
+                             if planed else {}),
+                          window=None if window is None else int(window))
         return out.reshape(q.shape)
 
 

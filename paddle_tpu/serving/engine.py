@@ -661,6 +661,15 @@ class ServingEngine:
             "serving_loop_passes_total",
             "passes through the stack made by decode steps: steps "
             "launched x the model's passes a step") if looped else None
+        # window entries (jit.cache.WindowLayout): a ring of blocks a
+        # slot that a context longer than the window laps
+        self._c_window_lapped = m.counter(
+            "serving_window_blocks_overwritten_total",
+            "ring entries of the window cache entries that a slot's "
+            "position has lapped (a block behind the window overwritten "
+            "by a later one, or never copied from a prompt), counted once "
+            "a slot from positions, whatever the number of window "
+            "entries") if "window" in stats["cache_entries"] else None
         self._g_kv_free = m.gauge(
             "serving_kv_free_blocks",
             "paged allocator free blocks") \
@@ -2554,6 +2563,9 @@ class ServingEngine:
         if self._c_loop_passes is not None:
             self._c_loop_passes.inc(
                 pool.loop_passes - self._c_loop_passes.value)
+        if self._c_window_lapped is not None:
+            self._c_window_lapped.inc(pool.window_blocks_overwritten
+                                      - self._c_window_lapped.value)
         if self._c_block is not None:
             for key, now in pool.block_stats().items():
                 self._c_block[key].inc(now - self._c_block[key].value)

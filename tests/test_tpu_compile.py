@@ -792,3 +792,102 @@ def test_the_kv_write_kernel_names_what_mosaic_refuses():
     assert "rows" in pallas_decode.kv_write_mosaic_refusal(128, 8, 2)
     assert pallas_decode.kv_write_mosaic_refusal(128, 8, 4) is None
     assert pallas_decode.write_group(8, 2) == 8     # the interpreter's
+
+
+def _window_model(layers: int):
+    from paddle_tpu.models import WindowMoELM
+
+    pt.seed(0)
+    # smallthinker-21b-a3b's attention at its published widths (28 heads on
+    # 4 of 128, window 4,096, the two layouts), two small experts and a
+    # small vocabulary: neither changes a cache entry or a kernel
+    layout = ([0, 1, 1, 1] * 3)[:layers]
+    model = WindowMoELM(vocab_size=512, hidden_size=2560, num_layers=layers,
+                        num_heads=28, num_kv_heads=4, head_dim=128,
+                        expert_size=768, num_experts=2, top_k=1,
+                        window=4096, sliding_window_layout=layout,
+                        rope_layout=layout, dtype="bfloat16")
+    model.eval()
+    return model
+
+
+def _custom_calls(text):
+    return [line for line in text.split("\n")
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def test_window_step_on_the_v5e_walks_two_kinds_of_entry(one_chip,
+                                                         monkeypatch):
+    """``smallthinker-21b-a3b``'s decode step at the cell's cache (16 slots,
+    blocks of 128, 1,281 blocks under the three global entries' table, a
+    ring of 33 blocks a slot in each of the nine window entries' own pools
+    of 529, bfloat16): 3 ``_paged_call`` without a window and 9 with, 12
+    K/V write kernels, and nothing of either pool's size copied or
+    re-laid."""
+    import jax
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    pool = GenerationPool(_window_model(12), max_len=16384, slots=16,
+                          buckets=[2048], cache_layout="paged",
+                          block_size=128, num_blocks=1281,
+                          cache_dtype="bfloat16")
+    assert pool.cache_layout == "paged+window"
+    shapes_of = {tuple(c.k.shape) for c in pool._cache}
+    assert shapes_of == {(1281, 4, 128, 128), (529, 4, 128, 128)}
+    assert pool.cache_stats()["bytes_per_slot"] == {
+        "paged": 3 * 16384 * 2048, "window": 9 * 33 * 128 * 2048}
+    n = pool.slots
+    params, bufs = pool._session._state_vals()
+    samp = (np.zeros(n, np.float32), np.zeros(n, np.int32),
+            np.ones(n, np.float32), np.zeros(n, np.uint32))
+    args = (params, bufs, pool._cache, np.zeros(n, np.int32),
+            np.ones(n, bool), samp, np.zeros(n, np.uint32),
+            np.zeros(n, np.int32))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip), args)
+    monkeypatch.setattr(fa, "_backend_memo", "tpu")
+    text = jax.jit(pool._pool_decode, donate_argnums=(2,)) \
+        .lower(*shapes).compile().as_text()
+    calls = _custom_calls(text)
+    windowed = [c for c in calls if "paged_attn/window/" in c]
+    plain = [c for c in calls if "paged_attn/jit(_paged_call)" in c]
+    writes = [c for c in calls if "jit(_kv_write_call)" in c]
+    assert (len(plain), len(windowed), len(writes)) == (3, 9, 12)
+    assert len(calls) == 24
+    for shape in shapes_of:
+        assert chip_smoke.pool_shaped_moves(text, shape) == []
+        made = {op for _, op in chip_smoke.pool_shaped_ops(text, shape)}
+        assert made <= {"parameter", "get-tuple-element",
+                        "bitcast"}, sorted(made)
+
+
+def test_a_prompt_of_12288_on_the_v5e_holds_no_square_of_scores(
+        one_chip, monkeypatch):
+    """The prefill at the cell's longest bucket, one period of the layers
+    (a global layer and three window layers): every layer's prompt
+    attention is a splash kernel (causal or banded: the band's block lists
+    are 9 wide where the causal ones are 24), and no array of the program
+    has two dimensions of 12,288: 28 heads of float32 scores would be
+    16.9 GB."""
+    import jax
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    pool = GenerationPool(_window_model(4), max_len=16384, slots=16,
+                          buckets=[12288], cache_layout="paged",
+                          block_size=128, num_blocks=1281,
+                          cache_dtype="bfloat16")
+    sess = pool._session
+    params, bufs = sess._state_vals()
+    pargs = (params, bufs, np.zeros((1, 12288), np.int32), np.int32(11936),
+             sess.sampling_state(1))
+    pshapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
+                                       sharding=one_chip), pargs)
+    monkeypatch.setattr(fa, "_backend_memo", "tpu")
+    text = jax.jit(sess._prefill).lower(*pshapes).compile().as_text()
+    splash = [c for c in _custom_calls(text) if "splash" in c]
+    assert len(splash) == 4
+    assert sum("s8[1,24,9]" in c for c in splash) == 3      # the band
+    assert sum("s8[1,24,24]" in c for c in splash) == 1     # causal
+    assert re.findall(r"\[[0-9,]*12288,12288[0-9,]*\]", text) == []

@@ -543,6 +543,68 @@ def test_pool_decode_carries_the_module_tree_and_hand_scopes():
     assert "jit(_prefill)/sample" in _scopes(pre.as_text())
 
 
+def _window_lm():
+    from paddle_tpu.models import WindowMoELM
+
+    pt.seed(0)
+    model = WindowMoELM(vocab_size=128, hidden_size=32, num_layers=2,
+                        num_heads=2, num_kv_heads=1, head_dim=16,
+                        expert_size=16, num_experts=4, top_k=2, window=8,
+                        sliding_window_layout=[0, 1], rope_layout=[0, 1],
+                        dtype="float32")
+    model.eval()
+    return model
+
+
+def test_a_window_models_steps_carry_its_scopes_router_first():
+    """The scopes a window / global expert model adds: the router's product
+    under ``moe/router`` BEFORE ``self_attn`` in a layer's program text,
+    ``paged_attn`` with a child ``window`` for a window layer's call (the
+    kernel's route), ``rope`` on the layer that turns, ``cache_write``,
+    ``moe/experts``, ``lm_head``; in the prefill ``prefill_attn/band``."""
+    from paddle_tpu.inference import GenerationPool
+    pool = GenerationPool(_window_lm(), max_len=32, slots=2,
+                          cache_layout="paged", block_size=4,
+                          num_blocks=17, buckets=[16], route="pallas",
+                          cache_dtype="float32")
+    pool.submit(np.arange(11, dtype=np.int32), 4)
+    pool.run()
+    (exe,) = pool._decode_jit._exes.values()
+    text = exe.as_text()
+    scopes = _scopes(text)
+    step = "jit(_pool_decode)/layers/%d/"
+    for want in (step % 0 + "moe/router", step % 1 + "moe/router",
+                 step % 0 + "self_attn/paged_attn",
+                 step % 1 + "self_attn/paged_attn/window",
+                 step % 1 + "self_attn/rope",
+                 step % 1 + "self_attn/cache_write",
+                 step % 1 + "moe/experts", "jit(_pool_decode)/lm_head"):
+        assert any(s.startswith(want) for s in scopes), want
+    assert not any(s.startswith(step % 0 + "self_attn/paged_attn/window")
+                   or s.startswith(step % 0 + "self_attn/rope")
+                   for s in scopes)
+    (pre,) = pool._session._prefill_jit._exes.values()
+    pre_scopes = _scopes(pre.as_text())
+    # (a global layer's prompt is ``prefill_attn/causal`` where the flash
+    # kernel runs: tests/test_tpu_compile.py; here it attends its cache)
+    assert any(s.startswith("jit(_prefill)/layers/1/self_attn/"
+                            "prefill_attn/band") for s in pre_scopes)
+    # the router reads the layer's input: in a layer's program its
+    # product stands before the attention's first operation
+    import jax
+
+    layer = pool._model.layers[1]
+    text = jax.jit(lambda x: layer(pt.to_tensor(x)).value).lower(
+        np.zeros((1, 6, 32), np.float32)).as_text(debug_info=True)
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    order = [names.get(m) for m in re.findall(r"loc\((#loc\d+)\)$", text,
+                                              re.M)]
+    order = [n for n in order if n]
+    first = lambda part: next(i for i, n in enumerate(order) if part in n)
+    assert first("moe/router/dot_general") < first("self_attn/") \
+        < first("moe/experts")
+
+
 def test_train_step_carries_loss_optimizer_and_layer_scopes():
     from paddle_tpu.jit import TrainStep
     from paddle_tpu.models import TransformerLMCriterion
@@ -970,6 +1032,13 @@ def test_recorder_tail_dicts_bounded():
 _DECODE_META = {
     "plain": {"live", "slots", "greedy", "ahead", "live_blocks",
               "table_blocks", "kv_entries", "kv_planes", "kv_write"},
+    # a model with window entries (PR 50): the ring's figures beside the
+    # block table's, and its expert layers'
+    "window": {"live", "slots", "greedy", "ahead", "live_blocks",
+               "table_blocks", "kv_entries", "kv_planes", "kv_write",
+               "window_entries", "window", "ring_blocks",
+               "window_live_blocks", "moe_route", "experts_held",
+               "experts_read_expected"},
     "speculative": {"spec_k", "live", "slots", "ahead"},
     "block": {"live", "slots", "ahead", "rows", "store", "stores_carried",
               "committed", "tokens_per_forward", "live_blocks",
@@ -984,6 +1053,9 @@ def _pool_of(kind, model):
     kw = dict(slots=2, buckets=[32], cache_layout="paged", block_size=8)
     if kind == "plain":
         return GenerationPool(model, 64, **kw)
+    if kind == "window":
+        return GenerationPool(_window_lm(), 64, cache_dtype="float32",
+                              **kw)
     if kind == "speculative":
         return SpeculativePool(model, model, 64, spec_k=2, **kw)
     pt.seed(0)

@@ -14,6 +14,7 @@ the table in PERF.md section 6, PR 43, is this script's).
 | ``sdar`` | bfloat16, 4 K/V heads x group 8, 8 positions (the block step's two blocks of 4), blocks of 128, 20 entries, 32 rows | ``sdar30b-batch-closed`` |
 | ``ouro`` | bfloat16, 16 heads, blocks of 64, 5 entries, 16 rows, FOUR planes of heads an entry, the chained calls attending plane 0, 1, 2, 3, 0 ... by a head offset | ``ouro2p6b-batch-closed`` |
 | ``ouro-blocks`` | the same calls with the planes laid as further BLOCKS (``table + r * num_blocks`` on a pool of four times the blocks, the unplaned kernel) | off the benchmark: the layout not chosen (docs/DESIGN.md, "a stack run several times") |
+| ``smallthinker`` | bfloat16, 4 K/V heads x group 7, blocks of 128, 128 entries, 16 rows: a GLOBAL layer's call; with ``--window`` a WINDOW layer's: the table a ring of 4,096 / 128 + 1 = 33 entries, the walk from the band's first entry to its last | ``smallthinker21b-batch-closed`` |
 | ``gpt-int8`` | ``gpt``'s call on an int8 pool with its float32 scales (blocks of 32) | off the benchmark: ``cache_dtype="int8"`` at the default block |
 | ``gpt-d64`` | ``gpt``'s call at 32 heads of 64 | off the benchmark: a head of half a lane tile, which the walk refuses |
 
@@ -68,10 +69,14 @@ GEOMETRIES = {
     "sdar": (32, 32, 4, 8, 128, 128, 20, "bfloat16", (400, 2300)),
     "ouro": (16, 16, 16, 1, 64, 128, 5, "bfloat16", (100, 315)),
     "ouro-blocks": (16, 16, 16, 1, 64, 128, 5, "bfloat16", (100, 315)),
+    "smallthinker": (16, 28, 4, 1, 128, 128, 128, "bfloat16", (1500, 13000)),
     "gpt-int8": (16, 16, 16, 1, 32, 128, 32, "int8", (150, 560)),
     "gpt-d64": (16, 32, 32, 1, 32, 64, 32, "float32", (150, 560)),
 }
 TOY = (4, 4, 2, 2, 8, 16, 6, "float32", (5, 40))
+# geometries with window layers: the band's width (``--window`` times the
+# windowed call against a ring; the toy's band is 16)
+WINDOWS = {"smallthinker": 4096}
 # geometries whose pool holds several K/V planes: how many, and how laid
 PLANES = {"ouro": (4, "heads"), "ouro-blocks": (4, "blocks")}
 
@@ -111,6 +116,10 @@ def main(argv=None) -> int:
     ap.add_argument("--cpu-toy", action="store_true")
     ap.add_argument("--write", action="store_true",
                     help="time the K/V write alone, scatter against kernel")
+    ap.add_argument("--window", action="store_true",
+                    help="time the WINDOWED call of the geometries that "
+                         "have a window: a ring of window / block + 1 "
+                         "entries a row")
     args = ap.parse_args(argv)
 
     sys.path.insert(0, os.path.abspath(args.tree))
@@ -132,7 +141,8 @@ def main(argv=None) -> int:
     if args.kv_mib is not None and hasattr(pd, "_PAGED_TILE_ENTRIES"):
         pd._KV_VMEM_BUDGET = args.kv_mib * 1024 * 1024
 
-    def write_chain(route, planes=1, laid=None, hkv=None, nb=None):
+    def write_chain(route, planes=1, laid=None, hkv=None, nb=None,
+                    window=None):
         """``calls`` writes of K and V rows, each into the pools the one
         before returned (donated: updated where they lie)."""
         write = getattr(fa, "paged_kv_write", None)
@@ -162,12 +172,14 @@ def main(argv=None) -> int:
         return pools, \
             (time.perf_counter() - start) / (args.repeats * calls) * 1e3
 
-    def chain(route, planes=1, laid=None, hkv=None, nb=None):
+    def chain(route, planes=1, laid=None, hkv=None, nb=None, window=None):
         def run(q, k, v, table, q_pos, *scales):
             for i in range(calls):
                 r = i % planes
                 at = {"head_base": r * hkv, "plane_heads": hkv} \
                     if laid == "heads" else {}
+                if window is not None:
+                    at["window"] = window
                 q = fa.paged_decode_attention(
                     q, k, v, table + r * nb if laid == "blocks" else table,
                     q_pos=q_pos, route=route, **at,
@@ -191,12 +203,23 @@ def main(argv=None) -> int:
             dtype, hq = "int8", hkv
         dtype = jnp.dtype(dtype)
         quant = dtype == jnp.int8
+        window = None
+        if args.window:
+            if name not in WINDOWS:
+                continue
+            window = 16 if args.cpu_toy else WINDOWS[name]
+        # the contexts are the cell's whatever the table: a ring wraps
+        span = mb
+        if window is not None:
+            mb = window // bs + 1
         nb = 1 + b * mb
         planes, laid = PLANES.get(name, (1, None))
         # the pool's blocks and heads with the planes in them
         pnb = nb * planes if laid == "blocks" else nb
         phkv = hkv * planes if laid == "heads" else hkv
         how = dict(planes=planes, laid=laid, hkv=hkv, nb=nb)
+        if window is not None:
+            how["window"] = window
         key = jax.random.PRNGKey(len(name))
         kq, kk, kv, ks = jax.random.split(key, 4)
         q = jax.random.normal(kq, (b, hq, lq, d), jnp.float32)
@@ -280,7 +303,8 @@ def main(argv=None) -> int:
                 pd._PAGED_TILE_ENTRIES = cap
                 pd._paged_call.clear_cache()
             kernel = chain("pallas", **how)
-            for label, ctx in contexts_of(name, b, lq, mb, bs, mix).items():
+            for label, ctx in contexts_of(name, b, lq, span, bs,
+                                          mix).items():
                 if args.context and label not in args.context:
                     continue
                 # a chunk's last query sees the row's last position
@@ -289,7 +313,10 @@ def main(argv=None) -> int:
                      for c in ctx], jnp.int32)
                 operands = (q, k_pool, v_pool, table, q_pos) + scales
                 want = plain(*operands)
-                live = sum((c - 1) // bs + 1 for c in ctx if c)
+                live = sum((c - 1) // bs + 1 for c in ctx if c) \
+                    if window is None else sum(
+                        (c - 1) // bs - max(c - window, 0) // bs + 1
+                        for c in ctx if c)
                 line = {
                     "tree": os.path.relpath(os.path.abspath(args.tree), ROOT),
                     "device": "%s %s" % (device.platform, device.device_kind),
@@ -297,6 +324,7 @@ def main(argv=None) -> int:
                     "context": label, "calls": calls,
                     "tile_cap": getattr(pd, "_PAGED_TILE_ENTRIES", None),
                     "live_entries": live, "table_entries": b * mb,
+                    **({} if window is None else {"window": window}),
                     "kv_budget": pd._KV_VMEM_BUDGET}
                 try:
                     got = kernel(*operands)
